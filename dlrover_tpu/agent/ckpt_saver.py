@@ -299,6 +299,13 @@ class AsyncCheckpointSaver:
                         "persisting the staged one", staged_step, step,
                     )
                     step = staged_step
+                if (
+                    event.get("breakpoint")
+                    and self._persisted.get(lr, -1) >= step
+                ):
+                    # The event loop persisted this step while the
+                    # breakpoint save waited for the fencing lock.
+                    return
                 # The arena's CRC covers the meta blob only; validate the
                 # staged state's own layout metadata before it becomes a
                 # durable shard — a torn/mismatched stage must never be

@@ -2,9 +2,9 @@
 
 Parity with reference ``elastic_agent/monitor/resource.py:86``
 (``ResourceMonitor``: psutil + pynvml -> ``report_used_resource``) and
-``monitor/training.py:77`` (``TorchTrainingMonitor``).  TPU notes: chip
-utilisation comes from the jax runtime when available (device memory stats)
-rather than NVML; the heartbeat itself lives in the training agent.
+``monitor/training.py:77`` (``TorchTrainingMonitor``).  Host metrics only:
+the agent never opens the device runtime (see :func:`current_usage`); the
+heartbeat itself lives in the training agent.
 """
 
 from __future__ import annotations
@@ -27,25 +27,15 @@ def _psutil():
 
 
 def current_usage() -> dict:
-    """Snapshot of host CPU/memory usage (+ TPU device memory if a live
-    backend exposes it)."""
-    out = {"cpu_percent": 0.0, "memory_mb": 0.0, "device_memory_mb": 0.0}
+    """Snapshot of host CPU/memory usage.  The agent asks JAX nothing: a
+    chip belongs to the worker, and a parent that opens the device runtime
+    takes it from its own child — device memory is the worker's to
+    report."""
+    out = {"cpu_percent": 0.0, "memory_mb": 0.0}
     ps = _psutil()
     if ps is not None:
         out["cpu_percent"] = ps.cpu_percent(interval=None)
         out["memory_mb"] = ps.virtual_memory().used / (1 << 20)
-    try:  # device stats only when jax is already imported and live
-        import sys
-
-        jax = sys.modules.get("jax")
-        if jax is not None:
-            stats = jax.local_devices()[0].memory_stats() or {}
-            out["device_memory_mb"] = stats.get("bytes_in_use", 0) / (1 << 20)
-    # graftcheck: disable=CC104 -- device stats are optional telemetry:
-    # no live jax backend is an expected state and the report simply
-    # omits the field
-    except Exception:  # noqa: BLE001
-        pass
     return out
 
 

@@ -22,13 +22,13 @@ from dlrover_tpu.common.constants import RendezvousName
 from dlrover_tpu.common.log import logger
 from dlrover_tpu.common.rpc import find_free_port, local_ip
 
-# The check payload runs in a subprocess so a wedged TPU runtime cannot hang
-# the agent (reference runs it via the elastic agent's worker spawner).
+# The check payload runs in a subprocess: it needs the chip, and the agent
+# must never open the device runtime itself (a chip belongs to one process
+# at a time).  ``subprocess.run`` returns only after the child is gone, so
+# the chip is free again before the workers start.
 _PAYLOAD = r"""
 import os, sys, time
-from dlrover_tpu.common.jax_env import ensure_platform
 import jax
-ensure_platform()
 coord = os.environ.get("DLROVER_TPU_CHECK_COORD", "")
 nproc = int(os.environ.get("DLROVER_TPU_CHECK_NPROC", "1"))
 pid = int(os.environ.get("DLROVER_TPU_CHECK_PID", "0"))

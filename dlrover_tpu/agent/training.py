@@ -38,6 +38,10 @@ from dlrover_tpu.common.constants import (
 )
 from dlrover_tpu.common.env import worker_env
 from dlrover_tpu.common.global_context import get_context
+from dlrover_tpu.common.jax_env import (
+    device_runtime_opened,
+    host_chip_count,
+)
 from dlrover_tpu.common.log import logger
 from dlrover_tpu.common.rpc import find_free_port, local_ip
 
@@ -66,10 +70,24 @@ class ElasticLaunchConfig:
     node_role: str = "worker"
 
     def auto_configure(self) -> None:
-        """Fill derived params from env (chips per host etc.)."""
-        env_chips = os.environ.get("TPU_ACCELERATOR_TYPE", "")
+        """Fill derived params from env."""
         if self.slice_id == "":
             self.slice_id = os.environ.get("TPU_WORKER_HOSTNAMES", "")
+
+
+def check_one_process_per_chip(nproc_per_node: int) -> None:
+    """A chip belongs to one process at a time, and one JAX process drives
+    every chip of its host: N workers on a chip host are N processes asking
+    the runtime for the same chips (one wins, the rest die in libtpu's lock
+    or hang in topology exchange).  Refuse up front, by the rule's name."""
+    chips = host_chip_count()
+    if chips and nproc_per_node > 1:
+        raise ValueError(
+            f"--nproc_per_node={nproc_per_node} on a host with {chips} TPU "
+            "chip(s): one process per chip host — a node runs ONE worker "
+            "that drives all local chips.  Use --nproc_per_node=1 here; >1 "
+            "is for the virtual CPU mesh (JAX_PLATFORMS=cpu)."
+        )
 
 
 class WorkerProcess:
@@ -661,6 +679,12 @@ class ElasticTrainingAgent:
             self._stop_workers("agent exiting")
             if self.saver is not None:
                 self.saver.stop()
+            # The chip is the workers': say whether this process ever
+            # took it (it must not — chip_smoke.py reads this line).
+            logger.info(
+                "agent exit: device runtime opened by the agent: %s",
+                device_runtime_opened(),
+            )
 
 
 def launch_agent(
@@ -669,5 +693,7 @@ def launch_agent(
     master_addr: str,
 ) -> int:
     """Build and run the agent (reference ``launch_agent :1098``)."""
+    # again after master-pushed overrides of the launch config
+    check_one_process_per_chip(config.nproc_per_node)
     agent = ElasticTrainingAgent(config, entrypoint, master_addr)
     return agent.run()

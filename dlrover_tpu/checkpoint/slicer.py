@@ -187,6 +187,11 @@ def plan_persist(
     written = 0
     logical = 0
     holder_alive: Dict[int, bool] = {}
+    # A holder AT the step being written is the very file this write
+    # replaces (one step persisted twice, e.g. a breakpoint save racing
+    # the event-loop persist): a ref to it would point at itself and
+    # destroy the only copy of the bytes.
+    cur_step = extra.get("step")
     for key, arr in tensors.items():
         arr = np.asarray(arr)
         n = int(arr.nbytes)
@@ -221,6 +226,7 @@ def plan_persist(
         h = tracker.holder(key) if tracker is not None else None
         if (
             h is not None
+            and h.step != cur_step
             and (h.lo, h.hi, h.full_nbytes) == (lo, hi, n)
             and hi > lo
         ):

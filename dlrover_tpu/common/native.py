@@ -1,13 +1,15 @@
 """Loader for the C++ native runtime pieces (built from ``native/``).
 
-Auto-builds ``libshm_arena.so`` with ``make`` on first use (cached); every
-consumer has a pure-Python fallback so the framework degrades gracefully on
-hosts without a toolchain.
+Every load asks ``make`` whether the ``.so`` matches its tracked ``.cc``
+source (a no-op when it does), so a stale binary left in the tree is never
+used.  Consumers that have a pure-Python equivalent fall back to it when
+the toolchain is missing — the load says which backend is in use.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -15,26 +17,37 @@ from typing import Optional
 
 from dlrover_tpu.common.log import logger
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
+_NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "native")
+)
 _LOCK = threading.Lock()
 _LIBS: dict = {}
 
 
 def _build(lib: str) -> Optional[str]:
-    path = os.path.abspath(os.path.join(_NATIVE_DIR, lib))
-    if os.path.exists(path):
-        return path
+    """``make <lib>``: rebuilds only when the source is newer.  Concurrent
+    processes (agent + workers) may race here; g++ writes the output in
+    place, so serialize on a lock file beside the Makefile."""
+    path = os.path.join(_NATIVE_DIR, lib)
     try:
-        subprocess.run(
-            ["make", "-C", os.path.abspath(_NATIVE_DIR), lib],
-            check=True,
-            capture_output=True,
-            timeout=120,
+        with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            subprocess.run(
+                ["make", "-C", _NATIVE_DIR, lib],
+                check=True,
+                capture_output=True,
+                timeout=120,
+            )
+    except subprocess.CalledProcessError as e:
+        logger.warning(
+            "native build of %s failed: %s", lib,
+            e.stderr.decode(errors="replace")[-400:],
         )
-        return path if os.path.exists(path) else None
+        return None
     except (subprocess.SubprocessError, OSError) as e:
         logger.warning("native build of %s failed: %s", lib, e)
         return None
+    return path if os.path.exists(path) else None
 
 
 def load_library(lib: str) -> Optional[ctypes.CDLL]:
@@ -48,6 +61,11 @@ def load_library(lib: str) -> Optional[ctypes.CDLL]:
                 handle = ctypes.CDLL(path)
             except OSError as e:
                 logger.warning("loading %s failed: %s", path, e)
+        logger.info(
+            "native %s: %s", lib,
+            "loaded (built from native/ by make)" if handle is not None
+            else "UNAVAILABLE, using the pure-Python backend",
+        )
         _LIBS[lib] = handle
         return handle
 

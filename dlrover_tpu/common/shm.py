@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import ctypes
 import dataclasses
+import errno
 import os
 import struct
 from typing import Dict, Optional, Tuple
@@ -194,7 +195,28 @@ def _shm_stat(name: str):
         return None
 
 
+def _check_shm_space(name: str, size: int) -> None:
+    """tmpfs hands out sparse files: creating a segment larger than what
+    /dev/shm has left succeeds, and the process dies of SIGBUS in the
+    middle of the copy.  Refuse up front with a plain message."""
+    try:
+        st = os.statvfs("/dev/shm")
+    except OSError:
+        return  # no tmpfs mount to ask (non-Linux)
+    free = st.f_bavail * st.f_frsize
+    have = (_shm_stat(name) or (0, 0))[1]
+    if size - have > free:
+        raise OSError(
+            errno.ENOSPC,
+            f"/dev/shm has {free >> 20} MiB free but checkpoint arena "
+            f"{name} needs {(size - have) >> 20} MiB more: enlarge "
+            "/dev/shm or checkpoint less state per host",
+        )
+
+
 def _open_segment(name: str, size: int, create: bool):
+    if create:
+        _check_shm_space(name, size)
     if shm_lib() is not None:
         try:
             return _NativeSegment(name, size, create)

@@ -1859,10 +1859,9 @@ class DecodeServer:
         self._step = jax.jit(step)
 
         def chunk_step(params, cache, toks, active, sub):
-            # decode_chunk steps under ONE dispatch (lax.scan): on a
-            # tunneled/async backend each dispatch costs real latency,
-            # and the host emit loop costs more — K tokens per round
-            # divides both by K.
+            # decode_chunk steps under ONE dispatch (lax.scan): each
+            # dispatch costs host latency, and the host emit loop costs
+            # more — K tokens per round divides both by K.
             def body(carry, key):
                 cache, toks = carry
                 cache, nxt = step(params, cache, toks, active, key)

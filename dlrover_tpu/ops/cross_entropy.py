@@ -15,6 +15,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.ops.per_shard import P, per_shard, shard_axes
+
 
 def _reference(logits, labels):
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
@@ -51,6 +53,7 @@ def _pallas_loss(logits2d, labels1d, block_rows, interpret):
         out_specs=pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
         interpret=interpret,
+        name="softmax_xent_fwd",
     )(logits2d, labels1d[:, None])
     return out[:, 0]
 
@@ -94,7 +97,16 @@ def softmax_cross_entropy(
     """[..., V] logits x [...] int labels -> [...] per-token loss (fp32)."""
     if backend is None:
         backend = "pallas" if jax.default_backend() == "tpu" else "reference"
-    return _xent(logits, labels, backend == "pallas", interpret)
+    if backend != "pallas":
+        return _xent(logits, labels, False, interpret)
+    # One kernel call per shard of the mesh in scope: rows split on the
+    # leading (batch) dim, the whole vocab in every shard.
+    free, batch_axes, _ = shard_axes(labels.shape[0])
+    rows = P(batch_axes, *([None] * (labels.ndim - 1)))
+    return per_shard(
+        lambda lg, lb: _xent(lg, lb, True, interpret),
+        free, (P(*rows, None), rows), rows,
+    )(logits, labels)
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dlrover_tpu.ops.per_shard import P, per_shard, shard_axes
+
 # Defaults tuned on v5e at [8,16,2048,64]: large blocks amortize MXU
 # pipeline fill (128x128 blocks ran at ~5% of peak; 512x512 at ~17%).
 # Env overrides (read once at import) let a hardware tuning sweep try
@@ -272,6 +274,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((B * H, 1, S_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*inputs)
     return (
         out.reshape(B, H, S_pad, D)[:, :, :S],
@@ -374,7 +377,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     # the whole group), revisiting the same compact [1, block_k, D]
     # dk/dv output block — r==0 initializes it, r>0 accumulates (fp32
     # output; cast to the param dtype happens outside).
-    # lse_ref/delta_ref: [1, 1, S_pad]; seg_ref: [1, 1, S_pad] int32.
+    # lse_ref/delta_ref: [1, 1, 1, S_pad] (the unit dim keeps the block's
+    # last two dims (1, S_pad) whole-axis, which Mosaic requires when
+    # rep > 1); seg_ref: [1, 1, S_pad] int32.
     if segmented:
         seg_ref, dk_ref, dv_ref = rest
     else:
@@ -411,8 +416,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         )
         qb = q_ref[0, 0, pl.ds(q_start, block_q), :].astype(jnp.float32)
         gb = g_ref[0, 0, pl.ds(q_start, block_q), :].astype(jnp.float32)
-        lse_b = lse_ref[0, 0, pl.ds(q_start, block_q)]
-        delta_b = delta_ref[0, 0, pl.ds(q_start, block_q)]
+        lse_b = lse_ref[0, 0, 0, pl.ds(q_start, block_q)]
+        delta_b = delta_ref[0, 0, 0, pl.ds(q_start, block_q)]
         s = jax.lax.dot_general(
             qb, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -512,6 +517,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S_pad, D), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*common)
 
     # dkv: grid (B*KV, k_blocks, rep) — the innermost axis streams the
@@ -520,9 +526,9 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
     # in HBM and per-program VMEM stays at one head's footprint.
     q4 = q3.reshape(B * KV, rep, S_pad, D)
     g4 = g3.reshape(B * KV, rep, S_pad, D)
-    lse3 = lse2.reshape(B * KV, rep, S_pad)
-    delta3 = delta2.reshape(B * KV, rep, S_pad)
-    dkv_in = [q4, k3, v3, g4, lse3, delta3]
+    lse4 = lse2.reshape(B * KV, rep, 1, S_pad)
+    delta4 = delta2.reshape(B * KV, rep, 1, S_pad)
+    dkv_in = [q4, k3, v3, g4, lse4, delta4]
     dkv_seg_spec = []
     if segmented:
         dkv_in.append(common[-1])
@@ -541,8 +547,8 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, D), lambda b, i, r: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, r: (b, i, 0)),
             pl.BlockSpec((1, 1, S_pad, D), lambda b, i, r: (b, r, 0, 0)),
-            pl.BlockSpec((1, 1, S_pad), lambda b, i, r: (b, r, 0)),
-            pl.BlockSpec((1, 1, S_pad), lambda b, i, r: (b, r, 0)),
+            pl.BlockSpec((1, 1, 1, S_pad), lambda b, i, r: (b, r, 0, 0)),
+            pl.BlockSpec((1, 1, 1, S_pad), lambda b, i, r: (b, r, 0, 0)),
         ] + dkv_seg_spec,
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, i, r: (b, i, 0)),
@@ -553,6 +559,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
             jax.ShapeDtypeStruct((B * KV, S_pad, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*dkv_in)
 
     return (
@@ -702,10 +709,21 @@ def flash_attention(
         backend = "pallas" if jax.default_backend() == "tpu" else "reference"
     if backend == "reference":
         return reference_attention(q, k, v, causal, segment_ids, window)
+    statics = (causal, block_q, block_k, bwd_block_q, bwd_block_k,
+               interpret, window)
+    # One kernel call per shard of the mesh in scope: batch over
+    # dp/fsdp, heads over tp (q and kv heads split alike, so each tp
+    # shard keeps whole GQA groups).
+    free, batch_axes, head_axis = shard_axes(
+        q.shape[0], (q.shape[1], k.shape[1])
+    )
+    qkv = P(batch_axes, head_axis, None, None)
     if segment_ids is not None:
-        return _flash_attention_seg(
-            q, k, v, segment_ids, causal, block_q, block_k, bwd_block_q,
-            bwd_block_k, interpret, window,
-        )
-    return _flash_attention(q, k, v, causal, block_q, block_k, bwd_block_q,
-                            bwd_block_k, interpret, window)
+        return per_shard(
+            lambda q, k, v, seg: _flash_attention_seg(q, k, v, seg, *statics),
+            free, (qkv, qkv, qkv, P(batch_axes, None)), qkv,
+        )(q, k, v, segment_ids)
+    return per_shard(
+        lambda q, k, v: _flash_attention(q, k, v, *statics),
+        free, (qkv, qkv, qkv), qkv,
+    )(q, k, v)

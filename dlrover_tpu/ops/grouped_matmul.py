@@ -10,8 +10,7 @@ TPU-first formulations (both MXU-friendly, no scalar loops):
   buffers -> one batched einsum (the default; pairs with
   ``parallel.moe.moe_layer``).
 - ``grouped_matmul_ragged``: flat [T, K] tokens + group sizes, via
-  ``jax.lax.ragged_dot`` (XLA's native ragged GEMM on TPU) with a
-  masked-einsum fallback where unavailable.
+  ``jax.lax.ragged_dot`` (XLA's native ragged GEMM on TPU).
 """
 
 from __future__ import annotations
@@ -34,14 +33,4 @@ def grouped_matmul_ragged(
 ) -> jax.Array:
     """Ragged grouped GEMM: rows [offset_e : offset_e + size_e] x weights[e].
     """
-    if hasattr(jax.lax, "ragged_dot"):
-        return jax.lax.ragged_dot(tokens, weights, group_sizes)
-    # Fallback: one-hot group membership -> masked batched matmul.
-    T = tokens.shape[0]
-    E = weights.shape[0]
-    ends = jnp.cumsum(group_sizes)
-    starts = ends - group_sizes
-    row = jnp.arange(T)[:, None]
-    member = (row >= starts[None, :]) & (row < ends[None, :])  # [T, E]
-    per_e = jnp.einsum("tk,ekn->etn", tokens, weights)
-    return jnp.einsum("etn,te->tn", per_e, member.astype(tokens.dtype))
+    return jax.lax.ragged_dot(tokens, weights, group_sizes)

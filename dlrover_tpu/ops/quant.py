@@ -21,6 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from dlrover_tpu.ops.per_shard import free_axes
+
 BLOCK = 128
 
 
@@ -69,6 +71,7 @@ def _quantize_pallas(
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="quantize_blockwise",
     )(blocks)
     return codes, scale[:, 0]
 
@@ -99,6 +102,14 @@ def quantize_blockwise(
         and jax.default_backend() == "tpu"
     )
     if use_pallas:
+        if free_axes()[0]:
+            # The flattened [blocks, 128] view has no per-shard form (a
+            # shard boundary need not fall on a block boundary), and
+            # GSPMD cannot partition the kernel.
+            raise NotImplementedError(
+                "quantize_blockwise: the Pallas kernel cannot run on "
+                "operands sharded over a mesh; pass backend='jnp' there"
+            )
         return _quantize_pallas(blocks, interpret=interpret)
     scale = jnp.max(jnp.abs(blocks), axis=-1) / 127.0
     scale = jnp.maximum(scale, 1e-12)

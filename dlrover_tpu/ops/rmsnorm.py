@@ -14,6 +14,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.ops.per_shard import P, per_shard, shard_axes
+
 
 def _reference(x, w, eps):
     xf = x.astype(jnp.float32)
@@ -46,6 +48,7 @@ def _pallas_fwd(x2d, w, eps, block_rows, interpret):
         out_specs=pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, D), x2d.dtype),
         interpret=interpret,
+        name="rmsnorm_fwd",
     )(x2d, w[None, :])
 
 
@@ -100,4 +103,13 @@ def rmsnorm(
     """RMSNorm over the last dim; ``w`` is the [D] gain."""
     if backend is None:
         backend = "pallas" if jax.default_backend() == "tpu" else "reference"
-    return _rmsnorm(x, w, eps, backend == "pallas", interpret)
+    if backend != "pallas":
+        return _rmsnorm(x, w, eps, False, interpret)
+    # One kernel call per shard of the mesh in scope: rows split on the
+    # leading (batch) dim, the normalized dim whole in every shard.
+    free, batch_axes, _ = shard_axes(x.shape[0])
+    rows = P(batch_axes, *([None] * (x.ndim - 1)))
+    return per_shard(
+        lambda x, w: _rmsnorm(x, w, eps, True, interpret),
+        free, (rows, P(None)), rows,
+    )(x, w)

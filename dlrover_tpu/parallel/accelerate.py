@@ -24,6 +24,7 @@ here into mesh/partition-spec generation (SURVEY.md §7 step 6):
 from __future__ import annotations
 
 import dataclasses
+import re
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
@@ -212,6 +213,37 @@ class AcceleratedJob:
     memory: Optional[dict] = None
     abstract_batch: Any = None  # ShapeDtypeStruct tree of the sample batch
     has_frozen: bool = False
+    # What the compiled step contains, counted from its text
+    # (:func:`program_summary`): Pallas kernels by name and collectives
+    # by kind — evidence of which program runs, not inferred from the
+    # platform.
+    program: Optional[dict] = None
+
+
+_COLLECTIVES = (
+    "all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+    "collective-permute",
+)
+
+
+def program_summary(hlo_text: str) -> dict:
+    """``{"kernels": {name: n}, "collectives": {kind: n}}`` of a compiled
+    program's text: every Mosaic kernel is a ``tpu_custom_call`` whose
+    ``op_name`` ends in ``<pallas_call name>/pallas_call`` (wrapped as
+    ``jvp(<name>)`` under differentiation); collectives
+    are counted by opcode (async ``-start`` forms included once)."""
+    kernels: dict = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line)
+        name = m.group(1) if m else "unnamed"
+        kernels[name] = kernels.get(name, 0) + 1
+    collectives = {
+        kind: len(re.findall(rf"\s{kind}(?:-start)?\(", hlo_text))
+        for kind in _COLLECTIVES
+    }
+    return {"kernels": kernels, "collectives": collectives}
 
 
 def _build_train_step(
@@ -262,18 +294,16 @@ def _build_train_step(
         exactly as under DDP, and differ from the single-global-mean
         GSPMD path by that same factor.
 
-        The shard_map is FULL-manual over a dp-only view of the mesh
-        (same devices, same order): partial-manual (axis_names=) with
-        any extra mesh axis — even size 1 — hard-crashes this XLA
-        build's partitioner ("Invalid binary instruction opcode copy"),
-        which is why quant_grads requires a pure-dp mesh; hybrid/fsdp
-        layouts get compressed DCN sync via local_sgd's outer step
-        instead."""
+        The shard_map is FULL-manual over the job's mesh (every other
+        axis has size 1): partial-manual (axis_names=) with any extra
+        mesh axis — even size 1 — hard-crashes this XLA build's
+        partitioner ("Invalid binary instruction opcode copy"), which is
+        why quant_grads requires a pure-dp mesh; hybrid/fsdp layouts get
+        compressed DCN sync via local_sgd's outer step instead."""
         from dlrover_tpu.ops.quant_collectives import (
             tree_quantized_pmean,
         )
 
-        dp_mesh = Mesh(np.asarray(mesh.devices).reshape(-1), ("dp",))
         A = strategy.grad_accum
 
         def local(params, b_local, frozen):
@@ -333,10 +363,10 @@ def _build_train_step(
             )
 
         def dp_only(spec):
-            # Honor the caller's batch placement, translated to the
-            # dp-only inner mesh: axes entries containing 'dp' keep it
-            # (('dp','fsdp') == 'dp' here: the mesh is pure-dp), all
-            # others are replicated.  Force-sharding every leaf P('dp')
+            # Honor the caller's batch placement, reduced to 'dp': axes
+            # entries containing 'dp' keep it (('dp','fsdp') == 'dp'
+            # here: the mesh is pure-dp, and the step's reductions name
+            # 'dp' alone), all others are replicated.  Force-sharding every leaf P('dp')
             # would silently split replicated batch leaves.
             parts = []
             for part in spec:
@@ -354,7 +384,7 @@ def _build_train_step(
         frozen_arg = frozen if has_frozen else jnp.zeros(())
         return jax.shard_map(
             local,
-            mesh=dp_mesh,
+            mesh=mesh,
             in_specs=(P(), mb_specs, P()),
             out_specs=(P(), P()),
         )(params, batch, frozen_arg)
@@ -842,14 +872,18 @@ def _compile_candidate(
         jit_kwargs.pop("out_shardings")
     jitted = jax.jit(step_fn, **jit_kwargs)
 
-    if frozen is not None:
-        def public_step(state, batch, _jitted=jitted):
+    # The mesh is in scope (``jax.set_mesh``) whenever the step is traced
+    # — the AOT lowering below and every call, since the trace cache is
+    # keyed on it: the model's Pallas kernels read it to run once per
+    # shard (``ops/per_shard.py``), GSPMD cannot partition them.
+    def public_step(state, batch):
+        with jax.set_mesh(mesh):
+            if frozen is None:
+                return jitted(state, batch)
             inner = {k: v for k, v in state.items() if k != "frozen"}
-            new_inner, metrics = _jitted(inner, batch, state["frozen"])
+            new_inner, metrics = jitted(inner, batch, state["frozen"])
             new_inner["frozen"] = state["frozen"]
             return new_inner, metrics
-    else:
-        public_step = jitted
 
     def create_state(rng, frozen_values=None):
         """``frozen_values``: concrete tree for state['frozen'] (e.g.
@@ -934,7 +968,8 @@ def _compile_candidate(
     lower_args = (abstract_inner, abstract_batch)
     if frozen is not None:
         lower_args += (abstract_state["frozen"],)
-    compiled = jitted.lower(*lower_args).compile()
+    with jax.set_mesh(mesh):
+        compiled = jitted.lower(*lower_args).compile()
     try:
         cost = compiled.cost_analysis()
         if isinstance(cost, list):
@@ -966,6 +1001,7 @@ def _compile_candidate(
         memory=memory,
         abstract_batch=abstract_batch,
         has_frozen=frozen is not None,
+        program=program_summary(compiled.as_text()),
     )
 
 

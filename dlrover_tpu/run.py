@@ -31,7 +31,11 @@ from typing import List, Optional, Tuple
 
 from dlrover_tpu import chaos
 from dlrover_tpu.agent.master_client import MasterClient
-from dlrover_tpu.agent.training import ElasticLaunchConfig, launch_agent
+from dlrover_tpu.agent.training import (
+    ElasticLaunchConfig,
+    check_one_process_per_chip,
+    launch_agent,
+)
 from dlrover_tpu.common.log import logger, set_role
 from dlrover_tpu.common.rpc import addr_connectable
 
@@ -532,6 +536,10 @@ def _gc_shm_arenas(
 
 def run(args: argparse.Namespace) -> int:
     set_role(f"agent-{args.node_rank}")
+    try:  # before any process is started
+        check_one_process_per_chip(args.nproc_per_node)
+    except ValueError as e:
+        raise SystemExit(f"dlrover_tpu.run: {e}")
     os.environ["DLROVER_TPU_NODE_ROLE"] = args.node_role
     # One id per launcher invocation: namespaces host-local IPC (shm
     # arenas/queues/locks) so stale state from a previous launch of the

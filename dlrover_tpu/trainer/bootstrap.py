@@ -19,7 +19,8 @@ from dlrover_tpu import chaos
 from dlrover_tpu.agent.master_client import MasterClient, build_master_client
 from dlrover_tpu.common import env as env_utils
 from dlrover_tpu.common.jax_env import (
-    ensure_platform,
+    compilation_cache_dir,
+    enable_compilation_cache,
     initialize_distributed_from_env,
 )
 from dlrover_tpu.common.log import logger, set_role
@@ -153,11 +154,10 @@ def init(connect_master: bool = True) -> ElasticContext:
         return _ctx
     ctx = ElasticContext()
     set_role(f"worker-{ctx.process_id}")
-    ensure_platform()
-    from dlrover_tpu.common.jax_env import enable_compilation_cache
-
     if enable_compilation_cache():
-        logger.info("persistent XLA compilation cache enabled")
+        logger.info(
+            "persistent XLA compilation cache at %s", compilation_cache_dir()
+        )
     ctx.distributed = initialize_distributed_from_env()
     if ctx.distributed:
         import jax

@@ -59,9 +59,9 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    from dlrover_tpu.common.jax_env import ensure_platform
+    from dlrover_tpu.common.jax_env import enable_compilation_cache
 
-    ensure_platform()
+    enable_compilation_cache()
     import numpy as np
 
     import jax

@@ -330,9 +330,9 @@ def drive(args, transport, core=None, client=None):
 def main() -> int:
     args = parse_args()
 
-    from dlrover_tpu.common.jax_env import ensure_platform
+    from dlrover_tpu.common.jax_env import enable_compilation_cache
 
-    ensure_platform()
+    enable_compilation_cache()
 
     # Name this process's flight recorder after its role (ISSUE 12):
     # merged traces and postmortems read "gw-g1"/"rep-r0", not pids.

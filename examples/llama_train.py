@@ -6,8 +6,15 @@ remat x dtype, layout planner), fused lm-head loss, elastic sampler fed
 by the master's task manager, and flash checkpointing — all launched
 under the elastic agent::
 
-    python -m dlrover_tpu.run --standalone --nproc_per_node=2 \
+    python -m dlrover_tpu.run --standalone --nproc_per_node=1 \
         examples/llama_train.py -- --steps 20
+
+On a chip host a node runs ONE worker that drives all local chips;
+``--nproc_per_node=2`` is for the virtual CPU mesh (``JAX_PLATFORMS=cpu``).
+The worker prints what it runs on (``DEVICE``), what its compiled step
+contains (``PROGRAM``: Pallas kernels and collectives counted from the
+program text), its time to the first step and its peak device memory —
+``chip_smoke.py`` reads these lines.
 
 Scale knobs: ``--model {tiny,300m,800m}`` picks the config;
 ``--strategy auto`` searches mesh factorizations instead of pure DP.
@@ -17,7 +24,9 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import json
 import sys
+import time
 
 import dlrover_tpu.trainer as trainer_sdk
 
@@ -54,6 +63,7 @@ def parse_args():
     p.add_argument("--dataset_size", type=int, default=4096)
     p.add_argument("--ckpt_dir", default="")
     p.add_argument("--ckpt_interval", type=int, default=5)
+    p.add_argument("--log_interval", type=int, default=10)
     return p.parse_args()
 
 
@@ -79,6 +89,7 @@ def synth_tokens(indices, seq_len, vocab):
 
 
 def main() -> int:
+    t_start = time.monotonic()
     args = parse_args()
     ctx = trainer_sdk.init()
 
@@ -86,11 +97,14 @@ def main() -> int:
     import numpy as np
     import optax
 
+    from dlrover_tpu.common.jax_env import device_summary
     from dlrover_tpu.models import llama
     from dlrover_tpu.parallel.accelerate import Strategy, accelerate
     from dlrover_tpu.parallel.mesh import MeshSpec
     from dlrover_tpu.trainer.sampler import ElasticSampler
 
+    tag = f"[worker {ctx.process_id}]"
+    print(f"{tag} DEVICE {json.dumps(device_summary())}", flush=True)
     cfg = build_config(args)
     local_dev = jax.local_device_count()
     if args.batch_per_proc % local_dev:
@@ -177,6 +191,7 @@ def main() -> int:
         if args.fp8 else None,
         frozen=frozen,
     )
+    print(f"{tag} PROGRAM {json.dumps(job.program)}", flush=True)
     if args.lora_rank > 0 and args.init_from:
         # Stream the checkpoint leaf-by-leaf onto the compiled frozen
         # sharding: peak host memory ~ one tensor, device memory only
@@ -219,8 +234,7 @@ def main() -> int:
                 got = dict(got, frozen=state["frozen"])
             state = got
             start_step = int(meta.get("step", 0))
-            print(f"[worker {ctx.process_id}] restored step={start_step}",
-                  flush=True)
+            print(f"{tag} restored step={start_step}", flush=True)
 
     sampler = ElasticSampler(
         args.dataset_size,
@@ -247,16 +261,24 @@ def main() -> int:
         }
         state, metrics = job.train_step(state, batch)
         loss = float(metrics["loss"])
+        if step == start_step:
+            print(
+                f"{tag} FIRST_STEP seconds="
+                f"{time.monotonic() - t_start:.1f} "
+                f"restart_count={ctx.restart_count}", flush=True,
+            )
         step += 1
         ctx.report_step(step)
         if ckpt is not None and step % args.ckpt_interval == 0:
             ckpt.save(split_ckpt(state), meta={"step": step})
-        if step % 10 == 0 or step == args.steps:
-            print(f"[worker {ctx.process_id}] step {step} loss "
-                  f"{loss:.4f}", flush=True)
+        if step % args.log_interval == 0 or step == args.steps:
+            print(f"{tag} step {step} loss {loss:.4f}", flush=True)
     if ckpt is not None:
         ckpt.save(split_ckpt(state), meta={"step": step}, storage=True)
         ckpt.wait()
+    mem = jax.local_devices()[0].memory_stats() or {}
+    print(f"{tag} MEMORY peak_bytes_in_use="
+          f"{mem.get('peak_bytes_in_use', 'n/a')}", flush=True)
     print(f"TRAIN_DONE step={step} loss={loss:.4f}", flush=True)
     return 0
 

@@ -1,99 +1,52 @@
-"""The bench's CPU-fallback number of record.
+"""bench.py's device-facing head and its CPU bench-smoke schema gates.
 
-Round-4 failure mode: the driver's bench silently fell back to CPU and
-published a meaningless 0.01%-MFU headline while real hardware numbers
-sat (un-created) in the durable artifact.  `_tpu_number_of_record`
-resolves the best TPU-measured candidate across the append-per-run
-``BENCH_TPU_VERIFIED.json`` history so a fallback run can cite hardware
-data instead of noise (reference analogue: the benchmark tables the
-reference publishes are always hardware-measured,
-atorch/examples/llama2/README.md).
+``main()`` measures on a TPU or fails: there is no CPU fallback, no stale
+"number of record", and the peak a utilization is divided by comes from one
+table keyed by ``device_kind`` — a device that is not in it is an error, not
+a default.
 """
 
 import json
 import sys
 from pathlib import Path
 
+import pytest
+
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 import bench  # noqa: E402
 
 
-def test_no_file_returns_none(tmp_path):
-    assert bench._tpu_number_of_record(str(tmp_path / "nope.json")) is None
+def test_peak_is_looked_up_by_device_kind():
+    # what jax.devices()[0].device_kind says on a v5e chip
+    assert bench.peak_bf16_flops("TPU v5 lite") == 197e12
 
 
-def test_malformed_file_returns_none(tmp_path):
-    p = tmp_path / "BENCH_TPU_VERIFIED.json"
-    p.write_text("{not json")
-    assert bench._tpu_number_of_record(str(p)) is None
-    p.write_text(json.dumps({"runs": "oops"}))
-    assert bench._tpu_number_of_record(str(p)) is None
+@pytest.mark.parametrize("kind", ["cpu", "TPU v9", "", "tpu v5 lite"])
+def test_unknown_device_kind_is_an_error_not_a_default(kind):
+    with pytest.raises(ValueError, match="no published bf16 peak"):
+        bench.peak_bf16_flops(kind)
 
 
-def test_best_row_across_runs_newest_wins_ties(tmp_path):
-    p = tmp_path / "BENCH_TPU_VERIFIED.json"
-    p.write_text(json.dumps({
-        "runs": [
-            {"started": "2026-07-30T00:00:00Z", "candidates": [
-                {"model": "a", "mfu_pct": 43.2, "step_time_s": 0.31,
-                 "batch": 8, "remat": "none"},
-                {"model": "b", "error": "OOM"},
-            ]},
-            {"started": "2026-07-31T00:00:00Z", "candidates": [
-                {"model": "c", "mfu_pct": 50.8, "step_time_s": 0.27,
-                 "batch": 8, "remat": "none"},
-                {"model": "d", "mfu_pct": 50.8, "step_time_s": 0.28,
-                 "batch": 16, "remat": "block"},
-            ]},
-        ]
-    }))
-    rec = bench._tpu_number_of_record(str(p))
-    assert rec is not None
-    assert rec["mfu_pct"] == 50.8
-    # ties broken toward the later-listed (newer) row
-    assert rec["model"] == "d"
-    assert rec["run_started"] == "2026-07-31T00:00:00Z"
+def test_main_fails_without_a_tpu(capsys):
+    """On the CPU (where the tests run) ``main()`` refuses before it
+    measures anything and says which device it found."""
+    assert bench.main() == 1
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["device"]["platform"] == "cpu"
+    assert "value" not in out and "TPU" in out["error"]
 
 
-def test_error_only_history_returns_none(tmp_path):
-    p = tmp_path / "BENCH_TPU_VERIFIED.json"
-    p.write_text(json.dumps({
-        "runs": [{"started": "x", "candidates": [{"error": "wedged"}]}]
-    }))
-    assert bench._tpu_number_of_record(str(p)) is None
+def test_goodput_is_its_own_command_that_imports_no_jax():
+    """Its worker needs the chip, so the process that launches the tree
+    must not hold it: the probe left ``main()`` for ``--goodput``."""
+    import inspect
 
-
-def test_non_numeric_mfu_rows_are_skipped(tmp_path):
-    p = tmp_path / "BENCH_TPU_VERIFIED.json"
-    p.write_text(json.dumps({
-        "runs": [{"started": "x", "candidates": [
-            {"model": "a", "mfu_pct": None},
-            {"model": "b", "mfu_pct": "50.8"},
-            {"model": "c", "mfu_pct": True},
-            {"model": "d", "mfu_pct": 43.2, "step_time_s": 0.3},
-        ]}]
-    }))
-    rec = bench._tpu_number_of_record(str(p))
-    assert rec is not None and rec["model"] == "d"
-
-
-def test_flush_and_read_share_schema(tmp_path, monkeypatch):
-    """The writer (_flush_partial) and reader (_tpu_number_of_record)
-    must agree on path + schema — both ride _load_tpu_history."""
-    monkeypatch.setattr(
-        bench, "_tpu_history_path",
-        lambda: str(tmp_path / "BENCH_TPU_VERIFIED.json"),
-    )
-    monkeypatch.setattr(bench, "_TPU_RUN_ID", None)
-    monkeypatch.setattr(
-        bench, "_partial_path", lambda: str(tmp_path / "p.json")
-    )
-    bench._flush_partial(
-        [{"model": "m", "mfu_pct": 51.0, "step_time_s": 0.2}], tpu=True
-    )
-    rec = bench._tpu_number_of_record()
-    assert rec is not None and rec["mfu_pct"] == 51.0
+    assert bench.SUBCOMMANDS["--goodput"] is bench.goodput_main
+    assert "measure_goodput" not in inspect.getsource(bench.main)
+    for fn in (bench.goodput_main, bench.measure_goodput):
+        assert "import jax" not in inspect.getsource(fn)
+    assert bench.goodput_main(["gpu"]) == 2  # usage
 
 
 def test_ckpt_bench_smoke_schema(tmp_path):
@@ -157,25 +110,6 @@ def test_ckpt_bench_smoke_schema(tmp_path):
     assert metric["metric"] == "ckpt_persist_speedup"
     assert metric["artifact"] == str(out)
     assert isinstance(metric["value"], (int, float))
-
-
-def test_progress_handles_closed_after_measurement(tmp_path):
-    """_progress_mark caches its handle for the timed window, but the
-    cache must drain when the measurement completes — a long-lived
-    process reusing _progress_mark must not leak one fd per sidecar."""
-    sidecar = str(tmp_path / "m.progress")
-    bench._progress_mark(sidecar, "spec read")
-    bench._progress_mark(sidecar, "imports done")
-    f = bench._PROGRESS_FILES[sidecar]
-    bench._progress_close()
-    assert not bench._PROGRESS_FILES
-    assert f.closed
-    lines = open(sidecar).read().strip().split("\n")
-    assert len(lines) == 2 and lines[0].endswith("spec read")
-    # Reuse after close reopens cleanly (append mode).
-    bench._progress_mark(sidecar, "again")
-    bench._progress_close()
-    assert sum(1 for _ in open(sidecar)) == 3
 
 
 def test_serve_bench_smoke_schema(tmp_path):

@@ -328,6 +328,31 @@ class TestIncrementalSaves:
         assert not fsck_mod.fsck(eng.ckpt_dir, eng.storage).damaged
         eng.close()
 
+    def test_same_step_persisted_twice_never_refs_itself(self, tmp_path):
+        """A breakpoint save can race the event-loop persist of the SAME
+        step (seen on the chip at a 2.6 GB state): the rewrite found
+        every fence untripped against the holder it was replacing and
+        wrote a shard of refs to itself — the only copy of the bytes
+        gone, fsck 'ref chain exceeds depth'."""
+        from dlrover_tpu.checkpoint import fsck as fsck_mod
+
+        eng = self._std_engine(tmp_path)
+        state = {f"t{i}": np.arange(5000, dtype=np.float32) + i
+                 for i in range(4)}
+        for _ in range(2):
+            eng.save_to_storage(1, dict(state))
+            assert eng.wait(timeout=60)
+        man = shard_file.read_shard_manifest(eng.storage, eng.ckpt_dir, 1, 0)
+        assert not [k for k, tm in man.tensors.items()
+                    if isinstance(tm.get("ref"), dict)]
+        assert not fsck_mod.fsck(eng.ckpt_dir, eng.storage).damaged
+        restored, meta = eng.load(
+            {k: np.zeros_like(v) for k, v in state.items()})
+        assert meta["step"] == 1
+        for k, v in state.items():
+            np.testing.assert_array_equal(np.asarray(restored[k]), v)
+        eng.close()
+
     def test_rotation_protects_holder_steps(self, tmp_path):
         """max_to_keep=2 would GC step 1 after steps 2 and 3 commit —
         unless live steps still reference its bytes."""

@@ -371,41 +371,91 @@ class TestPublicAPI:
 
 
 class TestCompilationCache:
-    def test_enable_compilation_cache_modes(self, tmp_path, monkeypatch):
-        """Order-independent by design: the cache backend latches its
-        directory at the first compile in the process, so this test used
-        to pass only when nothing had jitted before it (tier-1 ordering);
-        ``enable_compilation_cache`` now drops that latch itself, and the
-        teardown drops it again so the NEXT test never inherits a cache
-        pointed at this test's deleted tmp dir."""
-        import jax
+    """One way to place the compile cache, JAX's own: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set the code sets no directory; unset,
+    one fixed path inside the checkout."""
 
+    def test_off_switch(self, monkeypatch):
         from dlrover_tpu.common.jax_env import enable_compilation_cache
 
+        monkeypatch.setenv("DLROVER_TPU_COMPILE_CACHE", "0")
+        assert enable_compilation_cache() is False
+
+    def test_unset_env_uses_the_fixed_in_checkout_dir(self, monkeypatch):
+        import jax
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from conftest import REPO_ROOT
+        from dlrover_tpu.common import jax_env
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.delenv("DLROVER_TPU_COMPILE_CACHE", raising=False)
+        d = jax_env.compilation_cache_dir()
+        # fixed: not $HOME, not a temp name, a pid or a time
+        assert d == os.path.join(REPO_ROOT, ".jax_cache")
+        assert d == jax_env.DEFAULT_COMPILE_CACHE_DIR
         prev = jax.config.jax_compilation_cache_dir
         try:
-            monkeypatch.setenv("DLROVER_TPU_COMPILE_CACHE", "0")
-            assert enable_compilation_cache() is False
-
-            d = str(tmp_path / "xla")
-            monkeypatch.setenv("DLROVER_TPU_COMPILE_CACHE", d)
-            assert enable_compilation_cache() is True
+            assert jax_env.enable_compilation_cache() is True
             assert jax.config.jax_compilation_cache_dir == d
-            assert (tmp_path / "xla").is_dir()
-
-            # A compiled program actually lands in the cache dir — a
-            # FRESH computation (unique shape) so neither the in-memory
-            # executable cache nor an earlier persistent entry can
-            # satisfy it without writing here.
-            n = 32 + (os.getpid() % 17)
-            jax.jit(lambda x: x * 2 + 1)(jax.numpy.ones((n,))
-                                         ).block_until_ready()
-            assert any((tmp_path / "xla").iterdir())
+            assert os.path.isdir(d)
         finally:
+            # the cache backend latches its directory: hand the next
+            # test the setting it had
             jax.config.update("jax_compilation_cache_dir", prev)
-            try:
-                from jax._src import compilation_cache as _cc
+            compilation_cache.reset_cache()
 
-                _cc.reset_cache()
-            except Exception:  # noqa: BLE001 - best-effort unlatch
-                pass
+    def test_env_places_the_cache_and_the_code_sets_no_dir(self, tmp_path):
+        """A fresh process, as every entry point is: the cache is written
+        under ``JAX_COMPILATION_CACHE_DIR`` and ``enable_compilation_cache``
+        never touches ``jax_compilation_cache_dir``."""
+        import json
+        import subprocess
+        import sys
+
+        from conftest import REPO_ROOT
+
+        code = (
+            "import json, os, jax\n"
+            "seen = []\n"
+            "real = jax.config.update\n"
+            "jax.config.update = lambda k, v: (seen.append(k), "
+            "real(k, v))[1]\n"
+            "from dlrover_tpu.common import jax_env\n"
+            "assert jax_env.enable_compilation_cache() is True\n"
+            "jax.jit(lambda x: x * 2 + 1)(jax.numpy.ones((37,)))"
+            ".block_until_ready()\n"
+            "print(json.dumps({'seen': seen, "
+            "'dir': jax.config.jax_compilation_cache_dir, "
+            "'where': jax_env.compilation_cache_dir()}))\n"
+        )
+        want = str(tmp_path / "x")
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=want,
+                   JAX_PLATFORMS="cpu", PYTHONPATH=REPO_ROOT)
+        env.pop("DLROVER_TPU_COMPILE_CACHE", None)
+        proc = subprocess.run([sys.executable, "-c", code], env=env,
+                              capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        out = json.loads(proc.stdout.strip().splitlines()[-1])
+        assert "jax_compilation_cache_dir" not in out["seen"]
+        assert out["dir"] == want and out["where"] == want
+        assert any((tmp_path / "x").iterdir())
+
+    def test_exactly_one_site_names_the_cache_dir_option(self):
+        """``grep -rn jax_compilation_cache_dir dlrover_tpu bench.py
+        examples``: one guarded site."""
+        import glob
+
+        from conftest import REPO_ROOT
+
+        files = [os.path.join(REPO_ROOT, "bench.py")]
+        for top in ("dlrover_tpu", "examples"):
+            files += glob.glob(
+                os.path.join(REPO_ROOT, top, "**", "*.py"), recursive=True)
+        hits = [
+            (os.path.relpath(f, REPO_ROOT), n)
+            for f in files
+            for n, line in enumerate(open(f, encoding="utf-8"), 1)
+            if "jax_compilation_cache_dir" in line
+        ]
+        assert [f for f, _ in hits] == ["dlrover_tpu/common/jax_env.py"]

@@ -1254,8 +1254,7 @@ class TestTpServer:
 
 class TestChunkedDecodeServer:
     """decode_chunk > 1: K tokens per dispatch through one lax.scan —
-    K x fewer device round-trips (the dominant cost on a tunneled
-    backend).  The emitted law must be EXACTLY the unchunked server's
+    K x fewer device round-trips.  The emitted law must be EXACTLY the unchunked server's
     (same per-slot math, batched differently in time)."""
 
     def _setup(self, n=5):
