@@ -1,0 +1,175 @@
+"""Reads the program's own flight recorder (``dlrover_tpu/obs``): the spans
+it records inside save, restore, persist, restart, bootstrap and build, and
+the scope table of its compiled step.  JAX-free.
+
+Where the records are:
+
+- a cell that runs in this process (steady training): the process
+  recorder's ring;
+- the elastic cell: the journal files its launcher gave the job without
+  being asked — ``<tmp>/dlrover_tpu_obs/bench-<this pid>-<run id>/
+  flight-<process>-<pid>.jsonl``, one per process (``agent-n0``,
+  ``worker-r0-i0``, ``worker-r0-i1``), each line written as its span
+  ended, so that they are whole although every process was SIGKILLed.
+  The directory is removed when this process exits.
+
+A program that records no such span (the parent of the PR that added them)
+yields no records, and every reader built on this returns None.
+"""
+
+from __future__ import annotations
+
+import atexit
+import glob
+import os
+import re
+import shutil
+import sys
+import tempfile
+from typing import Dict, Iterable, List, Optional
+
+_removed_at_exit = set()
+
+
+def _job_dirs() -> List[str]:
+    root = os.path.join(tempfile.gettempdir(), "dlrover_tpu_obs")
+    job = f"bench-{os.getpid()}"
+    # the separator keeps bench-12 from matching bench-123's
+    found = [d for d in glob.glob(os.path.join(root, job + "*"))
+             if os.path.basename(d) == job
+             or os.path.basename(d).startswith(job + "-")]
+    for d in found:
+        if d not in _removed_at_exit:
+            _removed_at_exit.add(d)
+            atexit.register(_remove, d, root)
+    return found
+
+
+def _remove(path: str, root: str) -> None:
+    shutil.rmtree(path, ignore_errors=True)
+    try:
+        os.rmdir(root)  # only when nothing else is in it
+    except OSError:
+        pass
+
+
+def records(spans: dict) -> List[dict]:
+    """Every span and event the program recorded in this run, each with
+    the recording process under ``_proc``; nothing where the runner handed
+    over no stamps at all (no run happened)."""
+    if not spans:
+        return []
+    dirs = _job_dirs()
+    if dirs:
+        # the program's own reader of its files: a line cut by the kill
+        # is skipped, the last meta line names the process
+        from dlrover_tpu.obs.collect import load_dir
+
+        return [dict(rec, _proc=str(dump["meta"].get("process", "")))
+                for d in dirs for dump in load_dir(d)
+                for rec in dump["events"]]
+    recorder = sys.modules.get("dlrover_tpu.obs.recorder")
+    if recorder is None:
+        return []
+    ring, _, _ = recorder.get_recorder().snapshot()
+    return [dict(rec, _proc="") for rec in ring]
+
+
+# -- picking spans ----------------------------------------------------------
+
+
+def incarnation(rec: dict) -> Optional[int]:
+    """The worker incarnation (the agent's restart count at its start) a
+    record comes from, None for any other process."""
+    m = re.fullmatch(r"worker-r\d+-i(\d+)", rec.get("_proc", ""))
+    return int(m.group(1)) if m else None
+
+
+def last_incarnation(recs: Iterable[dict]) -> List[dict]:
+    """The records of the newest worker incarnation where the job ran
+    under the launcher (the resumed worker: the one ``resume_s`` waits
+    for), else all of them (one process, no launcher)."""
+    recs = list(recs)
+    newest = max((i for i in map(incarnation, recs) if i is not None),
+                 default=None)
+    return recs if newest is None else [
+        r for r in recs if incarnation(r) == newest]
+
+
+def named(recs: Iterable[dict], name: str) -> List[dict]:
+    return sorted((r for r in recs
+                   if r.get("k") == "span" and r.get("name") == name),
+                  key=lambda r: r["ts"])
+
+
+def children(recs: Iterable[dict], parent: dict,
+             name: str = "") -> List[dict]:
+    return [r for r in recs
+            if r.get("k") == "span" and r.get("psid") == parent["sid"]
+            and r.get("_proc") == parent.get("_proc")
+            and (not name or r.get("name") == name)]
+
+
+def descendants(recs: List[dict], parent: dict, name: str) -> List[dict]:
+    out, frontier = [], [parent]
+    while frontier:
+        kids = [k for p in frontier for k in children(recs, p)]
+        out += [k for k in kids if k.get("name") == name]
+        frontier = kids
+    return out
+
+
+def seconds(spans_: Iterable[dict]) -> Optional[float]:
+    """Summed duration in seconds; None of no span."""
+    spans_ = list(spans_)
+    return sum(s["dur"] for s in spans_) * 1e-6 if spans_ else None
+
+
+def child_seconds(recs: List[dict], parents: List[dict],
+                  *names: str) -> Optional[float]:
+    """Seconds of the first parent's children of these names."""
+    if not parents:
+        return None
+    return seconds(k for n in names for k in children(recs, parents[0], n))
+
+
+# -- the compiled step's scope table against a device trace -----------------
+
+
+def scope_shares(recs: List[dict], trace: dict) -> Optional[dict]:
+    """Device self time by ``(phase, scope)`` as shares of busy time: the
+    trace's ``op_self_s`` (keys ``<instruction name> <result shape>``, or a
+    Pallas kernel's name) joined to the ``scopes`` table the program
+    journals once with its ``accelerate.program`` event.  ``unphased`` is
+    what the table does not name."""
+    tables = [r["scopes"] for r in last_incarnation(recs)
+              if r.get("kind") == "accelerate.program" and r.get("scopes")]
+    ops = trace.get("op_self_s") if trace else None
+    if not tables or not ops or not trace.get("busy_s"):
+        return None
+    table = tables[-1]
+    by: Dict[tuple, float] = {}
+    for label, secs in ops.items():
+        name = label.split(" ", 1)[0]
+        verdict = table.get(name)
+        if verdict is None:
+            # a kernel's label is its pallas_call name, which its
+            # instructions' names carry (jvp_flash_fwd_.2)
+            hits = {tuple(v) for k, v in table.items() if name in k}
+            if hits:
+                verdict = tuple(
+                    vals.pop() if len(vals) == 1 else "mixed"
+                    for vals in ({h[0] for h in hits},
+                                 {h[1] for h in hits}))
+        key = tuple(verdict) if verdict else ("", "unphased")
+        by[key] = by.get(key, 0.0) + secs
+    busy = trace["busy_s"]
+    return {"by": {k: 100.0 * v / busy for k, v in by.items()},
+            "unphased_pct": 100.0 * by.get(("", "unphased"), 0.0) / busy}
+
+
+def print_scope_shares(shares: dict) -> None:
+    rows = sorted(shares["by"].items(), key=lambda kv: -kv[1])
+    print("SCOPES pct_of_busy " + " ".join(
+        f"{phase or '-'}/{scope}={pct:.2f}" for (phase, scope), pct in rows)
+        + f" unphased_pct={shares['unphased_pct']:.3f}", flush=True)

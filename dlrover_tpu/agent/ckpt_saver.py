@@ -38,7 +38,7 @@ from dlrover_tpu.common.multi_process import (
 )
 from dlrover_tpu.common.shm import SharedMemoryArena, arena_name
 from dlrover_tpu.common.storage import PosixDiskStorage
-from dlrover_tpu.obs import journal
+from dlrover_tpu.obs import journal, span
 
 
 class AsyncCheckpointSaver:
@@ -260,6 +260,13 @@ class AsyncCheckpointSaver:
                 logger.exception("ckpt save event failed: %s", event)
 
     def _handle_save(self, event: dict) -> None:
+        with span("ckpt.persist", "ckpt", step=int(event.get("step", 0)),
+                  rank=int(event.get("local_rank", 0)),
+                  reason="breakpoint" if event.get("breakpoint")
+                  else "save") as sp:
+            self._persist_event(event, sp)
+
+    def _persist_event(self, event: dict, persist_span) -> None:
         lr = int(event.get("local_rank", 0))
         step = int(event.get("step", 0))
         pid = int(event.get("process_id", lr))
@@ -267,7 +274,9 @@ class AsyncCheckpointSaver:
         ckpt_dir = event["ckpt_dir"]
         keep_last = shard_file.resolve_keep_last(event.get("max_to_keep"))
         lock = self._locks[lr] if lr < len(self._locks) else None
-        if lock is not None and not lock.acquire(timeout=60.0):
+        with span("ckpt.persist.lock_wait", "ckpt"):
+            locked = lock is None or lock.acquire(timeout=60.0)
+        if not locked:
             logger.warning("saver: lock for rank %d busy; skipping", lr)
             return
         # Zero-copy fast path: stream the arena's mapped bytes straight to
@@ -299,6 +308,7 @@ class AsyncCheckpointSaver:
                         "persisting the staged one", staged_step, step,
                     )
                     step = staged_step
+                    persist_span.set(step=step)
                 if (
                     event.get("breakpoint")
                     and self._persisted.get(lr, -1) >= step
@@ -363,7 +373,8 @@ class AsyncCheckpointSaver:
             # Commit waits for the OTHER ranks' shards — never block the
             # event loop on it (they may be persisted by this same loop).
             self._pool.submit(
-                self._commit, ckpt_dir, step, nproc_global, keep_last
+                self._commit, ckpt_dir, step, nproc_global, keep_last,
+                parent_span=persist_span.sid,
             )
 
     def _tracker(
@@ -386,6 +397,16 @@ class AsyncCheckpointSaver:
         leave restorable FULL shards, not orphan slices) and refs
         tensors whose dirty fence has not tripped since their holder
         step."""
+        with span("ckpt.persist.write", "ckpt", step=step) as sp:
+            stats = self._write_shard(
+                ckpt_dir, step, pid, tensors, extra, lr, sliced, world)
+            sp.set(bytes=int(stats["total_bytes"]),
+                   mbps=round(stats["mbps"], 1),
+                   skipped=int(stats["skipped"]))
+        return stats
+
+    def _write_shard(self, ckpt_dir, step, pid, tensors, extra, lr,
+                     sliced, world) -> dict:
         t0 = time.perf_counter()
         chaos.inject("ckpt.slow_storage", step=step, rank=pid)
         world = int(world or extra.get("num_processes") or self.nproc)
@@ -493,7 +514,16 @@ class AsyncCheckpointSaver:
         )
 
     def _commit(self, ckpt_dir: str, step: int, world: int,
-                keep_last: int = 3, timeout: float = 600.0) -> None:
+                keep_last: int = 3, timeout: float = 600.0,
+                parent_span: str = "") -> None:
+        # on a pool thread: the persist that asked for it is the parent
+        with span("ckpt.persist.commit", "ckpt", parent=parent_span,
+                  step=step):
+            self._commit_when_ready(
+                ckpt_dir, step, world, keep_last, timeout)
+
+    def _commit_when_ready(self, ckpt_dir: str, step: int, world: int,
+                           keep_last: int, timeout: float) -> None:
         deadline = time.time() + timeout
         if not shard_file.wait_sync_barrier(
             self.client, step, min(60.0, timeout / 4), self._stop

@@ -44,6 +44,7 @@ from dlrover_tpu.common.jax_env import (
 )
 from dlrover_tpu.common.log import logger
 from dlrover_tpu.common.rpc import find_free_port, local_ip
+from dlrover_tpu.obs import ENV_DIR, ENV_PROCESS, get_recorder, span
 
 
 @dataclasses.dataclass
@@ -206,6 +207,14 @@ class ElasticTrainingAgent:
 
     # -- rendezvous (reference MasterRendezvousHandler.next_rendezvous) ----
     def _rendezvous(self) -> dict:
+        with span("agent.rendezvous", "agent",
+                  restart_count=self._restart_count) as sp:
+            world_info = self._join_world()
+            sp.set(round=world_info["round"],
+                   num_processes=world_info["num_processes"])
+            return world_info
+
+    def _join_world(self) -> dict:
         """Join + poll until this node is in a completed world.
 
         Returns {round, world, my_rank, coordinator, num_processes}.
@@ -353,6 +362,13 @@ class ElasticTrainingAgent:
 
     # -- worker lifecycle ---------------------------------------------------
     def _start_workers(self, world_info: dict) -> None:
+        with span("agent.start_workers", "agent",
+                  round=world_info["round"],
+                  restart_count=self._restart_count) as sp:
+            self._spawn_workers(world_info)
+            sp.set(pids=[w.proc.pid for w in self._workers])
+
+    def _spawn_workers(self, world_info: dict) -> None:
         cfg = self.config
         world = world_info["world"]
         my = world[world_info["my_rank"]]
@@ -424,6 +440,13 @@ class ElasticTrainingAgent:
             env["DLROVER_TPU_LOCAL_WORLD_SIZE"] = str(cfg.nproc_per_node)
             env["DLROVER_TPU_RDZV_ROUND"] = str(world_info["round"])
             env["DLROVER_TPU_NODE_ROLE"] = cfg.node_role or "worker"
+            # the flight recorder: this process's dump directory (the
+            # launcher's default, or the operator's), one journal file
+            # per worker incarnation
+            if get_recorder().out_dir:
+                env[ENV_DIR] = get_recorder().out_dir
+            env[ENV_PROCESS] = (
+                f"worker-r{base + lr}-i{self._restart_count}")
             log_file = None
             stdout = stderr = None
             if cfg.log_dir:
@@ -451,6 +474,10 @@ class ElasticTrainingAgent:
     def _stop_workers(self, reason: str = "", grace: float = 10.0) -> None:
         if not self._workers:
             return
+        with span("agent.stop_workers", "agent", reason=str(reason)):
+            self._halt_workers(reason, grace)
+
+    def _halt_workers(self, reason: str, grace: float) -> None:
         if self.on_workers_stopping is not None:
             try:
                 self.on_workers_stopping(reason)
@@ -617,12 +644,27 @@ class ElasticTrainingAgent:
                 self.on_workers_stopping = self.saver.save_shm_to_storage
             except Exception:  # noqa: BLE001
                 logger.exception("could not start async checkpoint saver")
+        # failure seen by _monitor -> workers started again, across one
+        # turn of the loop below
+        restart = None
         try:
             while True:
                 world_info = self._rendezvous()
                 self._report_status(NodeStatus.RUNNING)
                 self._start_workers(world_info)
+                if restart is not None:
+                    restart.end(round=world_info["round"])
+                    restart = None
                 result = self._monitor()
+                if result not in (RunResult.SUCCEEDED, RunResult.STOP_JOB,
+                                  RunResult.RELAUNCH_REQUESTED):
+                    restart = span(
+                        "agent.restart", "agent", reason=str(result),
+                        restart_count=self._restart_count + (
+                            result == RunResult.FAILED),
+                        exit_codes=[c for _, c in self._last_failures]
+                        if result == RunResult.FAILED else [],
+                    ).start()
                 if result == RunResult.SUCCEEDED:
                     self._stop_workers("success", grace=5.0)
                     self._report_status(NodeStatus.SUCCEEDED)
@@ -675,6 +717,8 @@ class ElasticTrainingAgent:
                     self._stop_workers(result)
                 # loop -> new rendezvous round
         finally:
+            if restart is not None:
+                restart.end(gave_up=True)
             self._stop_evt.set()
             self._stop_workers("agent exiting")
             if self.saver is not None:

@@ -18,6 +18,7 @@ Two runtime modes, auto-detected:
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
@@ -44,7 +45,16 @@ from dlrover_tpu.common.multi_process import (
 )
 from dlrover_tpu.common.shm import SharedMemoryArena, arena_name
 from dlrover_tpu.common.storage import CheckpointStorage, PosixDiskStorage
-from dlrover_tpu.obs import journal
+from dlrover_tpu.obs import journal, span
+
+
+@contextlib.contextmanager
+def _released_after(lock):
+    """Release an already-held lock on the way out."""
+    try:
+        yield
+    finally:
+        lock.release()
 
 
 def ckpt_queue_name(job_name: str) -> str:
@@ -94,6 +104,8 @@ class CheckpointEngine:
         # the step loop (the paper's headline "second-scale stall").
         self.last_stall_ms = 0.0
         self._last_staged_bytes = 0
+        self._first_touch = False
+        self._load_span = None  # the ckpt.load span while load() runs
         self._stat_client: Optional[SharedDict] = None
         # step -> "a corrupt shard was seen while reading this step's
         # candidates" (populated per load; drives quarantine decisions).
@@ -145,11 +157,13 @@ class CheckpointEngine:
                     pass
             return None
 
-        jax.tree_util.tree_map(_prefetch, state)
-        tensors, info = tree_utils.flatten_to_shards(state)
-        self._last_staged_bytes = sum(
-            int(np.asarray(a).nbytes) for a in tensors.values()
-        )
+        with span("ckpt.save.d2h", "ckpt") as sp:
+            jax.tree_util.tree_map(_prefetch, state)
+            tensors, info = tree_utils.flatten_to_shards(state)
+            self._last_staged_bytes = sum(
+                int(np.asarray(a).nbytes) for a in tensors.values()
+            )
+            sp.set(bytes=self._last_staged_bytes, tensors=len(tensors))
         extra = {
             "step": step,
             "meta": meta or {},
@@ -168,19 +182,27 @@ class CheckpointEngine:
         # holds its lock for a WHOLE streamed storage write, which can
         # exceed a minute on slow storage — waiting is correct; crashing
         # the trainer's save (or hanging it silently) is not.
-        if self._lock is not None:
-            self._acquire_patiently(
-                self._lock.acquire, "shm fencing lock"
-            )
-        try:
-            self._acquire_patiently(
-                self._arena_mu.acquire, "arena mutex"
-            )
+        with span("ckpt.save.lock_wait", "ckpt"):
+            if self._lock is not None:
+                self._acquire_patiently(
+                    self._lock.acquire, "shm fencing lock"
+                )
             try:
+                self._acquire_patiently(
+                    self._arena_mu.acquire, "arena mutex"
+                )
+            except BaseException:
+                if self._lock is not None:
+                    self._lock.release()
+                raise
+        try:
+            self._first_touch = self._arena.will_allocate(tensors)
+            with span("ckpt.save.arena_write", "ckpt",
+                      bytes=self._last_staged_bytes,
+                      first_touch=self._first_touch):
                 self._arena.write_state(tensors, extra=extra)
-            finally:
-                self._arena_mu.release()
         finally:
+            self._arena_mu.release()
             if self._lock is not None:
                 self._lock.release()
         self._last_saved_step = step
@@ -206,29 +228,37 @@ class CheckpointEngine:
     ) -> None:
         """Stage into shm only — the synchronous train stall; the state
         survives worker crash/restart on this host."""
-        t0 = time.perf_counter()
-        self._stage(step, state, meta)
-        self._note_stall(step, time.perf_counter() - t0)
+        with span("ckpt.save", "ckpt", step=step,
+                  rank=self.process_id) as sp:
+            t0 = time.perf_counter()
+            self._stage(step, state, meta)
+            self._note_stall(step, time.perf_counter() - t0, sp)
 
-    def _note_stall(self, step: int, seconds: float) -> None:
-        """Surface the measured train stall: local gauge, the agent's
-        shared stat dict (scraped as ``ckpt_stall_ms_last``), and the
-        master's goodput accounting — the stall is real lost train time
-        even though no restart happened."""
+    def _note_stall(self, step: int, seconds: float, save_span) -> None:
+        """Surface the measured train stall: the ``ckpt.save`` span's
+        args, the local gauge, the agent's shared stat dict (scraped as
+        ``ckpt_stall_ms_last``), and the master's goodput accounting —
+        the stall is real lost train time even though no restart
+        happened."""
         self.last_stall_ms = seconds * 1000.0
         staged_mbps = (
             self._last_staged_bytes / max(seconds, 1e-9) / (1 << 20)
         )
         perf_stats.set("ckpt_stall_ms_last", self.last_stall_ms)
         perf_stats.set("ckpt_staged_mbps", staged_mbps)
-        journal("ckpt.stage", step=step, rank=self.process_id,
-                stall_ms=round(self.last_stall_ms, 1),
-                mbps=round(staged_mbps, 1))
+        save_span.set(stall_ms=round(self.last_stall_ms, 1),
+                      mbps=round(staged_mbps, 1),
+                      bytes=self._last_staged_bytes,
+                      first_touch=self._first_touch)
         logger.info(
             "flash ckpt: staged step %d to shm in %.3fs (%.0f MB/s, "
             "train stalled %.1fms)",
             step, seconds, staged_mbps, self.last_stall_ms,
         )
+        with span("ckpt.save.report", "ckpt"):
+            self._report_stall(step, staged_mbps)
+
+    def _report_stall(self, step: int, staged_mbps: float) -> None:
         if self.agent_mode:
             try:
                 # One round trip for both stats, short timeout: this sits
@@ -268,9 +298,11 @@ class CheckpointEngine:
         self, step: int, state: Any, meta: Optional[dict] = None
     ) -> None:
         """Stage into shm + request async persistence."""
-        t0 = time.perf_counter()
-        tensors, extra = self._stage(step, state, meta)
-        self._note_stall(step, time.perf_counter() - t0)
+        with span("ckpt.save", "ckpt", step=step, rank=self.process_id,
+                  storage=True) as sp:
+            t0 = time.perf_counter()
+            self._stage(step, state, meta)
+            self._note_stall(step, time.perf_counter() - t0, sp)
         if self.agent_mode:
             self._queue.put(
                 {
@@ -299,9 +331,16 @@ class CheckpointEngine:
         re-staging for the duration of the zero-copy stream (the
         ``ckpt_zero_copy=False`` knob trades that hold for one copy,
         exactly like the agent saver)."""
+        with span("ckpt.persist", "ckpt", step=step, reason="save",
+                  rank=self.process_id):
+            self._persist_staged(step)
+
+    def _persist_staged(self, step: int) -> None:
         try:
             zero_copy = self._ctx.ckpt_zero_copy
-            with self._arena_mu:
+            with span("ckpt.persist.lock_wait", "ckpt"):
+                self._arena_mu.acquire()
+            with _released_after(self._arena_mu):
                 read = self._arena.read_state(copy=not zero_copy)
                 if read is None:
                     logger.error(
@@ -335,7 +374,8 @@ class CheckpointEngine:
                 self._stream_shard(step, tensors, extra)
             self._last_persist_step = step
             if self.process_id == 0:
-                self._commit_when_ready(step)
+                with span("ckpt.persist.commit", "ckpt", step=step) as sp:
+                    sp.set(ok=self._commit_when_ready(step))
         except Exception:  # noqa: BLE001
             logger.exception("checkpoint persist of step %d failed", step)
 
@@ -345,6 +385,10 @@ class CheckpointEngine:
         bandwidth scales with world size) and skips tensors whose dirty
         fence has not tripped since their holder step (a meta ref
         instead of a rewrite)."""
+        with span("ckpt.persist.write", "ckpt", step=step) as sp:
+            self._write_shard(step, tensors, extra, sp)
+
+    def _write_shard(self, step: int, tensors, extra, write_span) -> None:
         chaos.inject("ckpt.slow_storage", step=step, rank=self.process_id)
         t0 = time.perf_counter()
         plan = slicer.plan_persist(
@@ -368,10 +412,9 @@ class CheckpointEngine:
             stats["total_bytes"]
             / max(time.perf_counter() - t0, 1e-9) / (1 << 20)
         )
-        journal("ckpt.persist", step=step, rank=self.process_id,
-                mbps=round(mbps, 1),
-                bytes=int(stats["total_bytes"]),
-                skipped=int(plan.skipped))
+        write_span.set(rank=self.process_id, mbps=round(mbps, 1),
+                       bytes=int(stats["total_bytes"]),
+                       skipped=int(plan.skipped))
         perf_stats.set("ckpt_persist_mbps", mbps)
         # Standalone = one rank per process: its own persist rate IS its
         # contribution to the fleet aggregate the bench/master sum up.
@@ -476,6 +519,16 @@ class CheckpointEngine:
         saved by any M-process world restores onto whatever mesh the new
         world has.  The storage path then reads only the source shards
         the reshard plan proves it needs (see :meth:`_select_pids`)."""
+        with span("ckpt.load", "ckpt", rank=self.process_id) as sp:
+            self._load_span = sp
+            result = self._load(target, target_mesh)
+            if result is None:
+                sp.set(source="none")  # nothing restorable: a fresh start
+            else:
+                sp.set(step=int(result[1].get("step", -1)))
+            return result
+
+    def _load(self, target: Any, target_mesh):
         if target is not None and target_mesh is not None:
             target = self._retarget(target, target_mesh)
         self._restore_boxes = (
@@ -495,7 +548,9 @@ class CheckpointEngine:
         got = self._load_from_shm(
             copy=target is None or self.agent_mode
         )
-        got = self._agree_shm_step(got)  # collective: same branch all ranks
+        with span("ckpt.load.agree", "ckpt"):
+            # collective: same branch all ranks
+            got = self._agree_shm_step(got)
         if got is not None:
             source, extra = got
             try:
@@ -507,8 +562,12 @@ class CheckpointEngine:
                 )
             # Collective: if any rank's shm assembly failed, all ranks
             # fall back together (collective-count symmetry).
-            if self._all_ranks_ok(result is not None):
+            with span("ckpt.load.agree", "ckpt"):
+                ok = self._all_ranks_ok(result is not None)
+            if ok:
+                self._load_span.set(source="shm")
                 return result
+        self._load_span.set(source="storage")
         # Storage: committed step first, then newer uncommitted steps whose
         # available shards still cover the target (e.g. a breakpoint save
         # from a partial world with replicated state).  Corruption is
@@ -540,7 +599,8 @@ class CheckpointEngine:
                 )
             if self._step_had_corruption.get(cand_step):
                 self._quarantine(cand_step)
-        return self._agree_storage_step(result, chosen, target)
+        with span("ckpt.load.agree", "ckpt"):
+            return self._agree_storage_step(result, chosen, target)
 
     def _assemble_candidate(
         self, source, extra, target, selective: bool, step: int
@@ -648,7 +708,10 @@ class CheckpointEngine:
         meta.setdefault("step", extra.get("step", 0))
         if target is None:
             return source, meta
-        state = tree_utils.restore_to_target(target, source)
+        with span("ckpt.load.device_put", "ckpt"):
+            state = tree_utils.restore_to_target(target, source)
+            # device_put returns before the bytes are on the device
+            jax.block_until_ready(state)
         return state, meta
 
     def _agree_shm_step(self, got):
@@ -684,20 +747,24 @@ class CheckpointEngine:
         return None
 
     def _load_from_shm(self, copy: bool = True):
-        try:
-            # reopen() munmaps: fence against a concurrent standalone
-            # persist thread streaming from the current mapping.
-            with self._arena_mu:
-                self._arena.reopen()
-                read = self._arena.read_state(copy=copy)
-        except (FileNotFoundError, OSError):
-            return None  # no arena yet: first run on this host
-        except Exception:  # noqa: BLE001
-            logger.exception("shm restore failed; trying storage")
-            return None
-        if read is None:
-            return None
-        tensors, extra = read
+        with span("ckpt.load.shm_read", "ckpt", copy=copy) as sp:
+            try:
+                # reopen() munmaps: fence against a concurrent standalone
+                # persist thread streaming from the current mapping.
+                with self._arena_mu:
+                    self._arena.reopen()
+                    read = self._arena.read_state(copy=copy)
+            except (FileNotFoundError, OSError):
+                return None  # no arena yet: first run on this host
+            except Exception:  # noqa: BLE001
+                logger.exception("shm restore failed; trying storage")
+                return None
+            if read is None:
+                return None
+            tensors, extra = read
+            nbytes = sum(int(a.nbytes) for a in tensors.values())
+            sp.set(bytes=nbytes)
+            self._load_span.set(bytes=nbytes)
         info = extra.get("tensors_info", {})
         if not info:
             return None
@@ -902,6 +969,11 @@ class CheckpointEngine:
         A shard that fails verification is skipped like an absent one
         (the step may still cover the target from other ranks' shards).
         """
+        with span("ckpt.load.storage_read", "ckpt", step=step,
+                  selective=selective):
+            return self._read_step_shards(step, selective)
+
+    def _read_step_shards(self, step: int, selective: bool):
         source = tree_utils.ShardSource()
         extra_out = None
         corrupt = False

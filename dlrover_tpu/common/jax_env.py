@@ -74,12 +74,20 @@ def device_summary() -> dict:
     that runs the model."""
     import jax
 
-    devs = jax.devices()
-    return {
-        "platform": devs[0].platform,
-        "kind": devs[0].device_kind,
-        "count": len(devs),
-    }
+    from dlrover_tpu.obs import span
+
+    # the first jax.devices() of a process initialises the backend and
+    # takes the chip; a later call is a lookup
+    with span("bootstrap.backend_init", "bootstrap",
+              first=not device_runtime_opened()) as sp:
+        devs = jax.devices()
+        summary = {
+            "platform": devs[0].platform,
+            "kind": devs[0].device_kind,
+            "count": len(devs),
+        }
+        sp.set(**summary)
+    return summary
 
 
 def host_chip_count() -> int:
@@ -93,6 +101,22 @@ def host_chip_count() -> int:
     return len(glob.glob("/dev/accel[0-9]*")) or len(
         glob.glob("/dev/vfio/[0-9]*")
     )
+
+
+def process_age_s() -> float:
+    """Seconds since this process started, by the kernel's account
+    (``/proc/self/stat`` start time against the boot clock): what the
+    interpreter's start-up and the imports took before any code of this
+    package could stamp a clock.  -1 where ``/proc`` does not say."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command may hold spaces: count fields from its ')'
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime_s = float(f.read().split()[0])
+        return uptime_s - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return -1.0
 
 
 def device_runtime_opened() -> bool:
@@ -123,9 +147,13 @@ def initialize_distributed_from_env() -> bool:
         return False
     import jax
 
-    jax.distributed.initialize(
-        coordinator_address=coordinator,
-        num_processes=nproc,
-        process_id=get_process_id(),
-    )
+    from dlrover_tpu.obs import span
+
+    with span("bootstrap.distributed_init", "bootstrap",
+              num_processes=nproc, rank=get_process_id()):
+        jax.distributed.initialize(
+            coordinator_address=coordinator,
+            num_processes=nproc,
+            process_id=get_process_id(),
+        )
     return True

@@ -260,6 +260,17 @@ class SharedMemoryArena:
             self._seg = _open_segment(name, size, create=True)
 
     # -- writer side --------------------------------------------------------
+    def will_allocate(self, flat: Dict[str, np.ndarray]) -> bool:
+        """Whether :meth:`write_state` of ``flat`` has to create the
+        segment (none yet, or too small): the write then also touches
+        every page for the first time, which is what a job's first save
+        pays and no later one (a restarted worker finds the pages)."""
+        need = _required_size(flat, self._meta_capacity)
+        if self._seg is not None:
+            return self._seg.size < need
+        have = _shm_stat(self.name)
+        return have is None or have[1] < need
+
     def write_state(
         self, flat: Dict[str, np.ndarray], extra: Optional[dict] = None
     ) -> None:

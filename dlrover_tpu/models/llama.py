@@ -432,41 +432,49 @@ def block_apply(
     projections (the bulk of the layer's FLOPs) go through the batched
     fp8 grouped dot as well — only the router and dispatch/combine stay
     in the compute dtype."""
-    h = rmsnorm(x, layer["ln1"], eps=cfg.rms_eps)
-    if attn_fn is not None:
-        if fp8_layer is not None:
-            raise ValueError(
-                "block_apply: fp8_layer is not supported with a custom "
-                "attn_fn (fp8 is a training-path strategy; the KV-cache "
-                "decode path stays in the compute dtype)"
+    # The scopes (``attention``, ``mlp``/``moe``; each with its norm and
+    # its residual add) go into every instruction's ``op_name`` of the
+    # compiled step: ``accelerate.program_summary`` reads them back.
+    with jax.named_scope("attention"):
+        h = rmsnorm(x, layer["ln1"], eps=cfg.rms_eps)
+        if attn_fn is not None:
+            if fp8_layer is not None:
+                raise ValueError(
+                    "block_apply: fp8_layer is not supported with a "
+                    "custom attn_fn (fp8 is a training-path strategy; "
+                    "the KV-cache decode path stays in the compute dtype)"
+                )
+            attn, new_fp8_attn = attn_fn(h, layer, cfg, positions), None
+        else:
+            attn, new_fp8_attn = _attention(
+                h, layer, cfg, positions, attn_impl, mesh, segment_ids,
+                fp8_layer=fp8_layer,
             )
-        attn, new_fp8_attn = attn_fn(h, layer, cfg, positions), None
-    else:
-        attn, new_fp8_attn = _attention(
-            h, layer, cfg, positions, attn_impl, mesh, segment_ids,
-            fp8_layer=fp8_layer,
-        )
-    x = x + attn
-    h = rmsnorm(x, layer["ln2"], eps=cfg.rms_eps)
+        x = x + attn
     if "moe" in layer:
-        res = _moe_swiglu(
-            h, layer["moe"], cfg, capacity=moe_capacity,
-            valid=None if segment_ids is None else segment_ids >= 0,
-            fp8_moe=None if fp8_layer is None else fp8_layer["moe"],
+        with jax.named_scope("moe"):
+            h = rmsnorm(x, layer["ln2"], eps=cfg.rms_eps)
+            res = _moe_swiglu(
+                h, layer["moe"], cfg, capacity=moe_capacity,
+                valid=None if segment_ids is None else segment_ids >= 0,
+                fp8_moe=None if fp8_layer is None else fp8_layer["moe"],
+            )
+            if fp8_layer is not None:
+                delta, aux, new_fp8_attn["moe"] = res
+                return x + delta, aux, new_fp8_attn
+            delta, aux = res
+            return x + delta, aux
+    with jax.named_scope("mlp"):
+        h = rmsnorm(x, layer["ln2"], eps=cfg.rms_eps)
+        out_m, new_fp8_mlp = _swiglu(
+            h, layer["mlp"], cfg.dtype,
+            fp8_mlp=None if fp8_layer is None else fp8_layer["mlp"],
         )
-        if fp8_layer is not None:
-            delta, aux, new_fp8_attn["moe"] = res
-            return x + delta, aux, new_fp8_attn
-        delta, aux = res
-        return x + delta, aux
-    out_m, new_fp8_mlp = _swiglu(
-        h, layer["mlp"], cfg.dtype,
-        fp8_mlp=None if fp8_layer is None else fp8_layer["mlp"],
-    )
+        x = x + out_m
     if fp8_layer is not None:
         new_fp8_attn["mlp"] = new_fp8_mlp
-        return x + out_m, jnp.zeros((), jnp.float32), new_fp8_attn
-    return x + out_m, jnp.zeros((), jnp.float32)
+        return x, jnp.zeros((), jnp.float32), new_fp8_attn
+    return x, jnp.zeros((), jnp.float32)
 
 
 def segment_positions(segment_ids: jax.Array) -> jax.Array:
@@ -532,7 +540,8 @@ def forward_hidden(
     adds the updated states to the aux dict as ``aux["fp8_states"]``."""
     B, S = tokens.shape
     dt = cfg.dtype
-    x = params["embed"].astype(dt)[tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]
     if segment_ids is not None:
         positions = segment_positions(segment_ids)
     else:
@@ -560,7 +569,8 @@ def forward_hidden(
         # inside the block rematerializes.
         x = checkpoint_name(x, "block_out")
         moe_aux = moe_aux + aux
-    x = rmsnorm(x, params["ln_f"], eps=cfg.rms_eps)
+    with jax.named_scope("final_norm"):
+        x = rmsnorm(x, params["ln_f"], eps=cfg.rms_eps)
     out_aux = {"moe_aux": moe_aux}
     if new_fp8 is not None:
         out_aux["fp8_states"] = new_fp8
@@ -582,7 +592,11 @@ def forward(
         params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
         segment_ids=segment_ids, fp8_states=fp8_states,
     )
-    logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    with jax.named_scope("lm_head_loss"):  # the head's matmul is the
+        # unfused loss's larger half
+        logits = (
+            x @ params["lm_head"].astype(cfg.dtype)
+        ).astype(jnp.float32)
     return logits, aux
 
 
@@ -655,19 +669,23 @@ def loss_fn(
             params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
             segment_ids=seg, fp8_states=fp8_states,
         )
-        per_tok = linear_softmax_cross_entropy(
-            x, params["lm_head"].astype(cfg.dtype), targets
-        )
+        with jax.named_scope("lm_head_loss"):
+            per_tok = linear_softmax_cross_entropy(
+                x, params["lm_head"].astype(cfg.dtype), targets
+            )
     else:
         logits, aux = forward(
             params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
             segment_ids=seg, fp8_states=fp8_states,
         )
-        per_tok = softmax_cross_entropy(logits, targets)
-    if valid is not None:
-        ce = jnp.sum(per_tok * valid) / jnp.maximum(jnp.sum(valid), 1.0)
-    else:
-        ce = jnp.mean(per_tok)
+        with jax.named_scope("lm_head_loss"):
+            per_tok = softmax_cross_entropy(logits, targets)
+    with jax.named_scope("lm_head_loss"):
+        if valid is not None:
+            ce = jnp.sum(per_tok * valid) / jnp.maximum(
+                jnp.sum(valid), 1.0)
+        else:
+            ce = jnp.mean(per_tok)
     loss = ce + moe_aux_weight * aux["moe_aux"]
     if fp8_states is not None:
         # (loss, new_fp8_states): use under value_and_grad(has_aux=True)

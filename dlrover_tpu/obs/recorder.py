@@ -1,8 +1,14 @@
-"""The per-process flight recorder (ISSUE 12).
+"""The per-process flight recorder (ISSUE 12; training path ISSUE 23).
 
-A bounded ring of structured events — request spans and control-plane
-journal entries — that SURVIVES the process's death:
+A bounded ring of structured events — request spans, the training
+path's spans (save, restore, persist, restart, bootstrap, build) and
+control-plane journal entries — that SURVIVES the process's death:
 
+- SIGKILL (the OOM killer, a preempted host): the low-rate spans of
+  :func:`span` are appended to the process's journal file as they end
+  (one ``write`` + ``flush``, no ``fsync``) when a dump directory is
+  set, so what a killed worker or agent did up to its death is on disk
+  without any hook having run;
 - normal exit: an ``atexit`` hook spills the ring as fsync'd JSONL;
 - SIGTERM: a handler (installed only when the process had no handler of
   its own — embedders' handlers are never displaced) spills, restores
@@ -26,6 +32,7 @@ import atexit
 import json
 import os
 import signal
+import sys
 import threading
 import time
 from collections import deque
@@ -61,6 +68,12 @@ class FlightRecorder:
         self.spans = 0
         self.events = 0
         self._dumped_reason: Optional[str] = None
+        # Write-through journal (SIGKILL survival): records already on
+        # disk, kept so that the exit dump's rewrite cannot lose the
+        # ones the ring has since evicted.
+        self._io_mu = threading.Lock()
+        self._durable: deque = deque(maxlen=self.capacity)
+        self._journal_file = None
 
     # -- recording --------------------------------------------------------
 
@@ -73,9 +86,11 @@ class FlightRecorder:
 
     def span(self, name: str, cat: str, start_s: float, end_s: float,
              trace_id: str = "", span_id: Optional[str] = None,
-             parent: str = "", args: Optional[dict] = None) -> str:
+             parent: str = "", args: Optional[dict] = None,
+             durable: bool = False) -> str:
         """Record one completed span (monotonic instants in, anchored
-        microseconds stored).  Returns the span id."""
+        microseconds stored).  Returns the span id.  ``durable`` also
+        appends it to the journal file now (low-rate spans only)."""
         sid = span_id or new_span_id()
         rec: Dict[str, Any] = {
             "k": "span", "name": name, "cat": cat,
@@ -90,9 +105,12 @@ class FlightRecorder:
         with self._mu:
             self.spans += 1
             self._append_locked(rec)
+        if durable:
+            self._write_through(rec)
         return sid
 
-    def event(self, kind: str, **fields: Any) -> None:
+    def event(self, kind: str, durable: bool = False,
+              **fields: Any) -> None:
         """Record one control-plane journal event (reshard transition,
         checkpoint commit verdict, reconcile decision, chaos firing,
         ...).  ``fields`` must be JSON/msgpack-safe scalars/containers."""
@@ -104,6 +122,45 @@ class FlightRecorder:
         with self._mu:
             self.events += 1
             self._append_locked(rec)
+        if durable:
+            self._write_through(rec)
+
+    def _meta(self, reason: str, chaos_site: str = "",
+              events: int = 0) -> Dict[str, Any]:
+        return {
+            "k": "meta", "process": self.process,
+            "pid": os.getpid(), "anchor": EPOCH_ANCHOR,
+            "reason": reason, "chaos_site": chaos_site,
+            "dumped_at": round(anchored_us(self._clock()), 1),
+            "dropped": self.dropped, "events": events,
+        }
+
+    def _write_through(self, rec: Dict[str, Any]) -> None:
+        """Append one record to the journal file (no dump directory: the
+        ring alone).  The file opens with a meta line whose reason,
+        ``journal``, is what a reader sees when the process died with no
+        hook run; any later :meth:`dump` rewrites the file whole."""
+        path = self.dump_path()
+        if path is None:
+            return
+        with self._io_mu:
+            self._durable.append(rec)
+            try:
+                if self._journal_file is None:
+                    os.makedirs(os.path.dirname(path), exist_ok=True)
+                    self._journal_file = open(path, "a")
+                    self._journal_file.write(
+                        json.dumps(self._meta("journal")) + "\n")
+                self._journal_file.write(json.dumps(rec) + "\n")
+                self._journal_file.flush()
+            except OSError as e:
+                logger.warning("flight recorder journal %s: %s", path, e)
+
+    def close(self) -> None:
+        with self._io_mu:
+            if self._journal_file is not None:
+                self._journal_file.close()
+                self._journal_file = None
 
     # -- reading ----------------------------------------------------------
 
@@ -143,13 +200,12 @@ class FlightRecorder:
             return None
         with self._mu:
             evs = list(self._ring)
-            meta = {
-                "k": "meta", "process": self.process,
-                "pid": os.getpid(), "anchor": EPOCH_ANCHOR,
-                "reason": reason, "chaos_site": chaos_site,
-                "dumped_at": round(anchored_us(self._clock()), 1),
-                "dropped": self.dropped, "events": len(evs),
-            }
+            # journalled records the ring has evicted stay in the file
+            oldest = evs[0]["seq"] if evs else self._seq + 1
+            with self._io_mu:
+                evs = [r for r in self._durable
+                       if r["seq"] < oldest] + evs
+            meta = self._meta(reason, chaos_site, len(evs))
             self._dumped_reason = reason
         tmp = f"{path}.tmp.{os.getpid()}"
         try:
@@ -165,6 +221,8 @@ class FlightRecorder:
             logger.warning("flight recorder dump to %s failed: %s",
                            path, e)
             return None
+        if path == self.dump_path():
+            self.close()  # the journal's handle names the replaced file
         return path
 
 
@@ -225,6 +283,39 @@ def _install_hooks(rec: FlightRecorder) -> None:
         logger.debug("obs: SIGTERM hook not installed: %s", e)
 
 
+def job_dir(job_name: str, run_id: str = "") -> str:
+    """Where a launched job's processes journal when the operator named
+    no ``DLROVER_TPU_OBS_DIR``: one directory per launcher invocation
+    under the temp dir (``tempfile.gettempdir()`` honours ``TMPDIR``)."""
+    import tempfile
+
+    safe = job_name.replace("/", "_")
+    return os.path.join(
+        tempfile.gettempdir(), "dlrover_tpu_obs",
+        f"{safe}-{run_id}" if run_id else safe,
+    )
+
+
+def gc_job_dirs(max_age_s: float = 7 * 86400.0) -> None:
+    """Launch-time retention for :func:`job_dir`'s directories: a job
+    that did not end well keeps its journals for the postmortem, not for
+    ever.  Only directories nothing has written to for ``max_age_s``."""
+    import glob
+    import shutil
+
+    now = time.time()
+    for path in glob.glob(os.path.join(os.path.dirname(job_dir("x")), "*")):
+        try:
+            newest = max(os.stat(p).st_mtime for p in
+                         [path] + glob.glob(os.path.join(path, "*")))
+        except OSError:
+            continue
+        # graftcheck: disable=OB301 -- compared against files' wall-clock
+        # mtimes; wall time is the point here
+        if now - newest > max_age_s:
+            shutil.rmtree(path, ignore_errors=True)
+
+
 def get_recorder() -> FlightRecorder:
     """The process recorder, created on first use from the environment
     (``DLROVER_TPU_OBS_DIR`` / ``_PROCESS`` / ``_CAPACITY``)."""
@@ -255,6 +346,8 @@ def configure(out_dir: Optional[str] = None, process: str = "",
     CURRENT recorder, so replacement never dangles a hook."""
     global _RECORDER
     with _mu:
+        if _RECORDER is not None:
+            _RECORDER.close()
         _RECORDER = FlightRecorder(
             capacity=capacity, process=process, out_dir=out_dir,
         )
@@ -266,6 +359,8 @@ def reset() -> None:
     """Drop the process recorder (tests).  The next use re-reads env."""
     global _RECORDER
     with _mu:
+        if _RECORDER is not None:
+            _RECORDER.close()
         _RECORDER = None
 
 
@@ -276,10 +371,103 @@ def set_process(name: str) -> None:
         get_recorder().process = name
 
 
-def journal(kind: str, **fields: Any) -> None:
+def journal(kind: str, durable: bool = False, **fields: Any) -> None:
     """Record one control-plane event on the process recorder — the
-    one-liner the fleet/reshard/checkpoint/autoscale layers call."""
-    get_recorder().event(kind, **fields)
+    one-liner the fleet/reshard/checkpoint/autoscale layers call.
+    ``durable`` also appends it to the journal file now (the training
+    path's low-rate events: they must survive a SIGKILL)."""
+    get_recorder().event(kind, durable=durable, **fields)
+
+
+class Span:
+    """One span of the program's own work, recorded where the work
+    happens: ``with obs.span("ckpt.save", "ckpt", step=3) as sp:``.
+
+    Monotonic start and end; the parent is the span open on this thread
+    when this one starts (``psid``), so self time is duration minus
+    children; ``args`` carry the counts taken at the same boundary
+    (``sp.set(bytes=n)`` adds what is only known inside).  Where a span
+    outlives one block (the agent's restart runs across loop turns) use
+    :meth:`start` / :meth:`end`.
+
+    When — and only when — JAX is already loaded in this process the
+    span also enters ``jax.profiler.TraceAnnotation(name)``, so under
+    any profiler session it lies on the trace's host plane beside the
+    device ops, on the profiler's clock.  This module never imports
+    JAX: the launcher and the agent use it."""
+
+    __slots__ = ("name", "cat", "args", "sid", "psid", "ring_only",
+                 "_t0", "_annotation")
+
+    def __init__(self, name: str, cat: str, ring_only: bool = False,
+                 parent: str = "", **args: Any):
+        self.name, self.cat, self.args = name, cat, args
+        self.ring_only = ring_only
+        self.sid = new_span_id()
+        self.psid = parent
+        self._t0: Optional[float] = None
+        self._annotation = None
+
+    def set(self, **args: Any) -> None:
+        self.args.update(args)
+
+    def start(self) -> "Span":
+        stack = _open_spans()
+        if not self.psid and stack:
+            self.psid = stack[-1]
+        stack.append(self.sid)
+        if "jax" in sys.modules:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation(self.name)
+            self._annotation.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def end(self, **args: Any) -> None:
+        if self._t0 is None:
+            return  # never started, or ended already
+        t1 = time.monotonic()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        stack = _open_spans()
+        if self.sid in stack:
+            del stack[stack.index(self.sid):]
+        self.args.update(args)
+        get_recorder().span(
+            self.name, self.cat, self._t0, t1, span_id=self.sid,
+            parent=self.psid, args=self.args or None,
+            durable=not self.ring_only,
+        )
+        self._t0 = None
+
+    __enter__ = start
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if exc_type is not None:
+            self.args["error"] = exc_type.__name__
+        self.end()
+
+
+_tls = threading.local()
+
+
+def _open_spans() -> List[str]:
+    """Ids of the spans open on this thread, outermost first."""
+    stack = getattr(_tls, "stack", None)
+    if stack is None:
+        stack = _tls.stack = []
+    return stack
+
+
+def span(name: str, cat: str, ring_only: bool = False, parent: str = "",
+         **args: Any) -> Span:
+    """The span primitive (see :class:`Span`).  Low-rate spans (save,
+    load, persist, restart, bootstrap, build) are journalled to disk as
+    they end; ``ring_only`` keeps a per-step span in the ring alone.
+    ``parent`` names the causing span's ``sid`` across threads."""
+    return Span(name, cat, ring_only=ring_only, parent=parent, **args)
 
 
 def record_span(name: str, cat: str, start_s: float, end_s: float,

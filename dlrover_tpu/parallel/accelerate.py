@@ -24,6 +24,7 @@ here into mesh/partition-spec generation (SURVEY.md §7 step 6):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import re
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
@@ -35,6 +36,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlrover_tpu.common.log import logger
+from dlrover_tpu.obs import journal, span
 from dlrover_tpu.parallel.mesh import MeshSpec, build_mesh, candidate_specs
 from dlrover_tpu.parallel.sharding import (
     Rules,
@@ -226,12 +228,140 @@ _COLLECTIVES = (
 )
 
 
+#: the scope ``train_step`` puts around ``tx.update`` + ``apply_updates``;
+#: :func:`scope_table` makes it the phase of the same name
+OPTIMIZER_SCOPE = "optimizer"
+#: path components of an ``op_name`` that JAX's transforms and control
+#: flow add and that are no scope of the program
+_NOT_A_SCOPE = frozenset((
+    "checkpoint", "rematted_computation", "while", "body", "cond",
+    "branch", "closed_call", "custom_vjp_call", "custom_jvp_call",
+    "shard_map", "pallas_call", "scan",
+))
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_NO_DEVICE_OP = re.compile(
+    r" (?:parameter|constant|get-tuple-element|tuple|bitcast)\(")
+
+
+def phase_and_scope(op_name: str) -> Optional[list]:
+    """``[phase, scope]`` of one instruction's ``op_name``
+    (``jit(train_step)/transpose(jvp(attention))/dot_general``): the
+    scope is the outermost ``jax.named_scope`` of the program on the
+    path, the phase what JAX's transforms wrapped around it — ``jvp(..)``
+    forward, ``transpose(..)`` backward, ``rematted_computation``
+    recompute — or ``optimizer`` under :data:`OPTIMIZER_SCOPE`, else
+    ``other``.  None where the path names no scope."""
+    parts = op_name.split("/")[:-1]  # the last one is the primitive
+    scope = ""
+    for part in parts:
+        inner = part.replace("transpose(", "").replace(
+            "jvp(", "").rstrip(")")
+        if (inner and "(" not in inner and "," not in inner
+                and inner not in _NOT_A_SCOPE):
+            scope = inner
+            break
+    if not scope:
+        return None
+    if scope == OPTIMIZER_SCOPE:
+        phase = "optimizer"
+    elif "rematted_computation" in parts:
+        phase = "recompute"
+    elif any(p.startswith("transpose(") for p in parts):
+        phase = "backward"
+    elif any(p.startswith("jvp(") for p in parts):
+        phase = "forward"
+    else:
+        phase = "other"
+    return [phase, scope]
+
+
+def scope_table(hlo_text: str) -> dict:
+    """``{instruction name: [phase, scope]}`` for every instruction of
+    the compiled text that runs as a device op of its own (the entry
+    computation's, a loop body's; not those inside a fusion): what joins
+    a device trace's names (``fusion.129``) to the program's.  A fusion
+    carries one ``op_name`` — its root's; where XLA fused a weight
+    gradient with the optimizer's update, the table says what the root
+    says.  A fusion whose root has none takes the most frequent verdict
+    of the instructions fused into it, and an instruction that still has
+    none (a copy, a convert, a prefetch) that of its first operand that
+    has one, else of its first user that has one."""
+    computations: Dict[str, list] = {}  # name -> [(instr, rest)]
+    current: Optional[list] = None
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and " -> " in line and " = " not in line:
+            name = line.split("(", 1)[0].replace("ENTRY", "").strip()
+            current = computations.setdefault(name.lstrip("%"), [])
+            continue
+        m = _INSTRUCTION.match(line)
+        if m and current is not None:
+            current.append((m.group(1), m.group(2)))
+
+    def verdict(rest: str) -> Optional[list]:
+        m = re.search(r'op_name="([^"]*)"', rest)
+        return phase_and_scope(m.group(1)) if m else None
+
+    fused = {
+        m.group(1)
+        for body in computations.values() for _, rest in body
+        if " fusion(" in rest
+        for m in [re.search(r"calls=%?([\w.\-]+)", rest)] if m
+    }
+    table: dict = {}
+    for comp, body in computations.items():
+        if comp in fused:
+            continue
+        for name, rest in body:
+            if _NO_DEVICE_OP.search(rest):
+                continue
+            found = verdict(rest)
+            if found is None and " fusion(" in rest:
+                m = re.search(r"calls=%?([\w.\-]+)", rest)
+                votes: Dict[tuple, int] = {}
+                for _, inner in computations.get(m.group(1), []) if m else []:
+                    v = verdict(inner)
+                    if v is not None:
+                        votes[tuple(v)] = votes.get(tuple(v), 0) + 1
+                if votes:
+                    found = list(max(votes, key=votes.get))
+            if found is None:
+                # a copy or a convert XLA added names nothing: it goes
+                # with the first operand that does (text is in def order)
+                found = next(
+                    (table[o] for o in re.findall(
+                        r"%([\w.\-]+)", rest.split("(", 1)[-1])
+                     if o in table), None)
+            if found is not None:
+                table[name] = found
+        # What the compiler adds in front of an instruction (a prefetch
+        # of its operand: copy-start/-done, slice-start/-done) names
+        # nothing and reads only parameters: it goes with its first
+        # user that has a verdict, through the chain of such moves.
+        operands = {
+            name: re.findall(r"%([\w.\-]+)", rest.split("(", 1)[-1])
+            for name, rest in body if not _NO_DEVICE_OP.search(rest)
+        }
+        changed = True
+        while changed:
+            changed = False
+            for user, ops in operands.items():
+                if user not in table:
+                    continue
+                for o in ops:
+                    if o in operands and o not in table:
+                        table[o] = table[user]
+                        changed = True
+    return table
+
+
 def program_summary(hlo_text: str) -> dict:
-    """``{"kernels": {name: n}, "collectives": {kind: n}}`` of a compiled
+    """``{"kernels": {name: n}, "collectives": {kind: n}, "scopes":
+    {instruction: [phase, scope]}}`` of a compiled
     program's text: every Mosaic kernel is a ``tpu_custom_call`` whose
     ``op_name`` ends in ``<pallas_call name>/pallas_call`` (wrapped as
     ``jvp(<name>)`` under differentiation); collectives
-    are counted by opcode (async ``-start`` forms included once)."""
+    are counted by opcode (async ``-start`` forms included once);
+    ``scopes`` is :func:`scope_table`."""
     kernels: dict = {}
     for line in hlo_text.splitlines():
         if 'custom_call_target="tpu_custom_call"' not in line:
@@ -243,7 +373,8 @@ def program_summary(hlo_text: str) -> dict:
         kind: len(re.findall(rf"\s{kind}(?:-start)?\(", hlo_text))
         for kind in _COLLECTIVES
     }
-    return {"kernels": kernels, "collectives": collectives}
+    return {"kernels": kernels, "collectives": collectives,
+            "scopes": scope_table(hlo_text)}
 
 
 def _build_train_step(
@@ -450,10 +581,13 @@ def _build_train_step(
             loss, grads, new_fp8 = _value_and_grad(params, batch, fp8,
                                                    frozen)
 
-        updates, opt_state = tx.update(grads, state["opt_state"], params)
         import optax
 
-        params = optax.apply_updates(params, updates)
+        # read back from the compiled step's op_names by program_summary
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            updates, opt_state = tx.update(
+                grads, state["opt_state"], params)
+            params = optax.apply_updates(params, updates)
         new_state = {
             "params": params,
             "opt_state": opt_state,
@@ -461,12 +595,60 @@ def _build_train_step(
         }
         if fp8_on:
             new_state["fp8"] = new_fp8
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("grad_norm"):
+            gnorm = optax.global_norm(grads)
         return new_state, {"loss": loss, "grad_norm": gnorm}
 
     return train_step
 
 
+class _CacheWatch:
+    """JAX's own persistent-cache events while a compile runs:
+    ``cache_hit`` is True when every executable asked for came from the
+    cache, False when one was compiled, None when the cache was not
+    asked (disabled, or nothing compiled)."""
+
+    HIT = "/jax/compilation_cache/cache_hits"
+    MISS = "/jax/compilation_cache/cache_misses"
+
+    def __enter__(self) -> "_CacheWatch":
+        self.hits = self.misses = 0
+        jax.monitoring.register_event_listener(self._on)
+        return self
+
+    def _on(self, event: str, **_kw) -> None:
+        self.hits += event == self.HIT
+        self.misses += event == self.MISS
+
+    def __exit__(self, *exc) -> None:
+        jax.monitoring.unregister_event_listener(self._on)
+
+    @property
+    def cache_hit(self) -> Optional[bool]:
+        if not self.hits and not self.misses:
+            return None
+        return self.misses == 0
+
+
+def _build_span(fn: Callable) -> Callable:
+    """``accelerate.build`` around the whole of :func:`accelerate`, and
+    the compiled step's summary journalled once (``accelerate.program``:
+    kernels, collectives and the scope table that names a device
+    trace's instructions)."""
+
+    @functools.wraps(fn)
+    def build(*args, **kwargs) -> "AcceleratedJob":
+        with span("accelerate.build", "accelerate") as sp:
+            job = fn(*args, **kwargs)
+            sp.set(strategy=job.strategy.describe())
+        journal("accelerate.program", durable=True,
+                strategy=job.strategy.describe(), **(job.program or {}))
+        return job
+
+    return build
+
+
+@_build_span
 def accelerate(
     *,
     loss_fn: Callable,  # (params, batch) -> scalar loss
@@ -876,7 +1058,7 @@ def _compile_candidate(
     # — the AOT lowering below and every call, since the trace cache is
     # keyed on it: the model's Pallas kernels read it to run once per
     # shard (``ops/per_shard.py``), GSPMD cannot partition them.
-    def public_step(state, batch):
+    def run_step(state, batch):
         with jax.set_mesh(mesh):
             if frozen is None:
                 return jitted(state, batch)
@@ -884,6 +1066,23 @@ def _compile_candidate(
             new_inner, metrics = jitted(inner, batch, state["frozen"])
             new_inner["frozen"] = state["frozen"]
             return new_inner, metrics
+
+    called: List[bool] = []
+
+    def public_step(state, batch):
+        if called:
+            return run_step(state, batch)
+        # The first call traces, lowers and compiles once more (the AOT
+        # executable below serves the analysis only): a cache read where
+        # the cache is on.  The span ends when the call returns, not
+        # when the step has run.
+        called.append(True)
+        with span("accelerate.first_call", "accelerate",
+                  strategy=strategy.describe()) as sp, \
+                _CacheWatch() as watch:
+            out = run_step(state, batch)
+            sp.set(cache_hit=watch.cache_hit)
+        return out
 
     def create_state(rng, frozen_values=None):
         """``frozen_values``: concrete tree for state['frozen'] (e.g.
@@ -968,8 +1167,35 @@ def _compile_candidate(
     lower_args = (abstract_inner, abstract_batch)
     if frozen is not None:
         lower_args += (abstract_state["frozen"],)
+    described = strategy.describe()
     with jax.set_mesh(mesh):
-        compiled = jitted.lower(*lower_args).compile()
+        with span("accelerate.lower", "accelerate", strategy=described):
+            lowered = jitted.lower(*lower_args)
+        with span("accelerate.compile", "accelerate",
+                  strategy=described) as sp, _CacheWatch() as watch:
+            compiled = lowered.compile()
+            sp.set(cache_hit=watch.cache_hit)
+    with span("accelerate.analyze", "accelerate", strategy=described):
+        cost, memory = _cost_and_memory(compiled)
+        program = program_summary(compiled.as_text())
+
+    return AcceleratedJob(
+        mesh=mesh,
+        strategy=strategy,
+        train_step=public_step,
+        create_state=create_state,
+        state_sharding=state_sharding,
+        batch_sharding=batch_sharding,
+        cost=cost,
+        memory=memory,
+        abstract_batch=abstract_batch,
+        has_frozen=frozen is not None,
+        program=program,
+    )
+
+
+def _cost_and_memory(compiled) -> tuple:
+    """XLA's cost analysis and buffer assignment of a compiled step."""
     try:
         cost = compiled.cost_analysis()
         if isinstance(cost, list):
@@ -989,20 +1215,7 @@ def _compile_candidate(
         }
     except Exception:  # noqa: BLE001
         memory = None
-
-    return AcceleratedJob(
-        mesh=mesh,
-        strategy=strategy,
-        train_step=public_step,
-        create_state=create_state,
-        state_sharding=state_sharding,
-        batch_sharding=batch_sharding,
-        cost=cost,
-        memory=memory,
-        abstract_batch=abstract_batch,
-        has_frozen=frozen is not None,
-        program=program_summary(compiled.as_text()),
-    )
+    return cost, memory
 
 
 def search(

@@ -21,6 +21,7 @@ from __future__ import annotations
 import argparse
 import atexit
 import os
+import shutil
 import subprocess
 import sys
 import tempfile
@@ -29,7 +30,7 @@ import time
 import uuid
 from typing import List, Optional, Tuple
 
-from dlrover_tpu import chaos
+from dlrover_tpu import chaos, obs
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.agent.training import (
     ElasticLaunchConfig,
@@ -545,6 +546,19 @@ def run(args: argparse.Namespace) -> int:
     # arenas/queues/locks) so stale state from a previous launch of the
     # same job name can't leak into this one.
     os.environ.setdefault("DLROVER_TPU_RUN_ID", uuid.uuid4().hex[:8])
+    # A training job has a flight-recorder journal without asking: the
+    # agent (this process) and, through it, every worker incarnation
+    # write their spans there as they end, so a job that died by SIGKILL
+    # can be read back (python -m dlrover_tpu.obs.postmortem <dir>).  The
+    # operator's DLROVER_TPU_OBS_DIR wins; ours goes when the job ends
+    # well.
+    own_obs_dir = "" if os.environ.get(obs.ENV_DIR) else obs.job_dir(
+        args.job_name, os.environ["DLROVER_TPU_RUN_ID"])
+    obs.gc_job_dirs()
+    obs.configure(out_dir=own_obs_dir or os.environ[obs.ENV_DIR],
+                  process=f"agent-n{args.node_rank}")
+    logger.info("flight recorder journals: %s",
+                obs.get_recorder().out_dir)
     # Run-scoped arenas would otherwise accumulate in RAM-backed /dev/shm,
     # one multi-GB set per launch: GC leftovers of earlier launches of this
     # job now, and unlink our own at exit.  Durable state lives in storage
@@ -685,6 +699,13 @@ def run(args: argparse.Namespace) -> int:
             logger.warning("local master did not exit in 30s; terminating")
             master_holder[0].terminate()
     client.close()
+    if own_obs_dir and rc == 0:
+        obs.configure()  # ring only from here: no exit spill re-creates it
+        shutil.rmtree(own_obs_dir, ignore_errors=True)
+        try:
+            os.rmdir(os.path.dirname(own_obs_dir))  # if it is empty now
+        except OSError:
+            pass
     return rc
 
 

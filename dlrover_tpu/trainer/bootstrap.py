@@ -22,8 +22,10 @@ from dlrover_tpu.common.jax_env import (
     compilation_cache_dir,
     enable_compilation_cache,
     initialize_distributed_from_env,
+    process_age_s,
 )
 from dlrover_tpu.common.log import logger, set_role
+from dlrover_tpu.obs import journal, span
 
 
 class ElasticContext:
@@ -66,6 +68,12 @@ class ElasticContext:
         chaos.inject("worker.kill", rank=self.process_id, step=step)
         if self.client is None:
             return
+        # per step: the ring alone, never the journal file
+        with span("trainer.report_step", "trainer", ring_only=True,
+                  step=step):
+            self._report_step(step)
+
+    def _report_step(self, step: int) -> None:
         if self.is_leader:
             try:
                 self.client.report_global_step(step)
@@ -154,6 +162,18 @@ def init(connect_master: bool = True) -> ElasticContext:
         return _ctx
     ctx = ElasticContext()
     set_role(f"worker-{ctx.process_id}")
+    # what the new interpreter and its imports took before this line
+    journal("bootstrap.process_start", durable=True,
+            since_process_start_s=round(process_age_s(), 3),
+            rank=ctx.process_id, restart_count=ctx.restart_count)
+    with span("bootstrap.init", "bootstrap", rank=ctx.process_id,
+              restart_count=ctx.restart_count):
+        _bring_up(ctx, connect_master)
+    _ctx = ctx
+    return ctx
+
+
+def _bring_up(ctx: ElasticContext, connect_master: bool) -> None:
     if enable_compilation_cache():
         logger.info(
             "persistent XLA compilation cache at %s", compilation_cache_dir()
@@ -170,8 +190,6 @@ def init(connect_master: bool = True) -> ElasticContext:
         atexit.register(_shutdown)
     if connect_master and ctx.master_addr:
         ctx.client = build_master_client(ctx.master_addr, ctx.node_id)
-    _ctx = ctx
-    return ctx
 
 
 def get_elastic_context() -> Optional[ElasticContext]:

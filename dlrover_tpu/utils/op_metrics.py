@@ -31,10 +31,11 @@ import os
 import shutil
 import tempfile
 import time
-from typing import Dict, Optional
+from collections import deque
+from typing import Deque, Dict, Optional
 
 from dlrover_tpu.common.log import logger
-from dlrover_tpu.utils.prof import StepProfiler
+from dlrover_tpu.utils.prof import percentile
 
 # XLA HLO name prefixes per class (TPU device tracks); the CPU test
 # backend emits primitive names (dot_general, ...), covered too.
@@ -57,6 +58,43 @@ def classify_op(name: str) -> str:
         if n.startswith(p):
             return "matmul"
     return "other"
+
+
+class _StepTimes:
+    """Per-step wall-time stats over a rolling window.  :meth:`step` is
+    called once per training step; the first call is counted apart as
+    warmup (XLA compile)."""
+
+    def __init__(self, window: int = 200):
+        self._times: Deque[float] = deque(maxlen=window)
+        self._last: Optional[float] = None
+        self.warmup_s: Optional[float] = None
+        self._created = time.perf_counter()
+        self.total_steps = 0
+
+    def step(self) -> None:
+        now = time.perf_counter()
+        if self._last is None:
+            self.warmup_s = now - self._created
+        else:
+            self._times.append(now - self._last)
+        self._last = now
+        self.total_steps += 1
+
+    def summary(self) -> Dict[str, float]:
+        if not self._times:
+            return {"steps": float(self.total_steps)}
+        xs = sorted(self._times)
+        return {
+            "steps": float(self.total_steps),
+            "mean_s": sum(xs) / len(xs),
+            "p50_s": percentile(xs, 0.5),
+            "p90_s": percentile(xs, 0.9),
+            "p99_s": percentile(xs, 0.99),
+            "max_s": xs[-1],
+            "warmup_s": self.warmup_s or 0.0,
+            "steps_per_s": len(xs) / sum(xs) if sum(xs) > 0 else 0.0,
+        }
 
 
 class OpMetricsCollector:
@@ -82,7 +120,7 @@ class OpMetricsCollector:
         top_k: int = 5,
         publish_every: int = 20,
     ):
-        self.prof = StepProfiler(window)
+        self.prof = _StepTimes(window)
         self.capture_every = int(capture_every)
         self.registry = registry
         self.metrics_path = metrics_path

@@ -1,148 +1,12 @@
-"""Profiling: step timing, chrome-trace events, jax profiler capture.
-
-Parity with reference ``atorch/atorch/utils/prof.py`` (step/op profiler),
-``utils/tracer.py`` (event tracer) and the xpu-timer scrape path —
-TPU-native on top of ``jax.profiler`` (XLA traces viewable in
-Perfetto/TensorBoard) instead of CUDA kernel hooks.
-"""
+"""Percentiles for the step statistics (``utils/op_metrics.py``) and the
+trace rollups (``utils/trace_analysis.py``).  Spans are recorded by
+``dlrover_tpu.obs`` (one recorder, bridged to ``jax.profiler``), not
+here."""
 
 from __future__ import annotations
-
-import contextlib
-import json
-import os
-import threading
-import time
-from collections import deque
-from typing import Deque, Dict, Optional
-
-from dlrover_tpu.common.log import logger
 
 
 def percentile(sorted_xs, p: float) -> float:
     """Nearest-rank percentile over a pre-sorted sequence."""
     n = len(sorted_xs)
     return sorted_xs[min(n - 1, int(p * n))]
-
-
-class StepProfiler:
-    """Per-step wall-time stats with percentile summaries.
-
-    Call :meth:`step` once per training step; the first call after
-    construction (or after :meth:`reset`) is counted separately as warmup
-    (XLA compile)."""
-
-    def __init__(self, window: int = 200):
-        self._times: Deque[float] = deque(maxlen=window)
-        self._last: Optional[float] = None
-        self.warmup_s: Optional[float] = None
-        self._created = time.perf_counter()
-        self.total_steps = 0
-
-    def step(self) -> Optional[float]:
-        now = time.perf_counter()
-        dt: Optional[float] = None
-        if self._last is None:
-            self.warmup_s = now - self._created
-        else:
-            dt = now - self._last
-            self._times.append(dt)
-        self._last = now
-        self.total_steps += 1
-        return dt
-
-    def reset(self) -> None:
-        self._times.clear()
-        self._last = None
-        self._created = time.perf_counter()
-
-    def summary(self) -> Dict[str, float]:
-        if not self._times:
-            return {"steps": float(self.total_steps)}
-        xs = sorted(self._times)
-        n = len(xs)
-
-        def pct(p: float) -> float:
-            return percentile(xs, p)
-
-        return {
-            "steps": float(self.total_steps),
-            "mean_s": sum(xs) / n,
-            "p50_s": pct(0.5),
-            "p90_s": pct(0.9),
-            "p99_s": pct(0.99),
-            "max_s": xs[-1],
-            "warmup_s": self.warmup_s or 0.0,
-            "steps_per_s": n / sum(xs) if sum(xs) > 0 else 0.0,
-        }
-
-
-class Tracer:
-    """Chrome-trace (catapult) event recorder (reference ``tracer.py`` /
-    ``parse_trace_json.py`` counterpart).  Thread-safe; dump with
-    :meth:`save` and load the file in Perfetto."""
-
-    def __init__(self, max_events: int = 100000):
-        self._events: Deque[dict] = deque(maxlen=max_events)
-        self._lock = threading.Lock()
-        self._t0 = time.perf_counter()
-
-    def _us(self) -> int:
-        return int((time.perf_counter() - self._t0) * 1e6)
-
-    @contextlib.contextmanager
-    def span(self, name: str, category: str = "train", **args):
-        start = self._us()
-        try:
-            yield
-        finally:
-            end = self._us()
-            with self._lock:
-                self._events.append(
-                    {
-                        "name": name,
-                        "cat": category,
-                        "ph": "X",
-                        "ts": start,
-                        "dur": end - start,
-                        "pid": os.getpid(),
-                        "tid": threading.get_ident() % 1_000_000,
-                        "args": args,
-                    }
-                )
-
-    def instant(self, name: str, **args) -> None:
-        with self._lock:
-            self._events.append(
-                {
-                    "name": name,
-                    "ph": "i",
-                    "ts": self._us(),
-                    "pid": os.getpid(),
-                    "tid": threading.get_ident() % 1_000_000,
-                    "s": "p",
-                    "args": args,
-                }
-            )
-
-    def save(self, path: str) -> None:
-        with self._lock:
-            events = list(self._events)
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        with open(path, "w") as f:
-            json.dump({"traceEvents": events}, f)
-        logger.info("tracer: wrote %d events to %s", len(events), path)
-
-
-@contextlib.contextmanager
-def profile_trace(log_dir: str, host_tracer_level: int = 2):
-    """Capture an XLA/JAX profiler trace around a code block
-    (view in TensorBoard / xprof; replaces xpu-timer kernel traces)."""
-    import jax
-
-    jax.profiler.start_trace(log_dir, create_perfetto_link=False)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        logger.info("jax profiler trace written to %s", log_dir)

@@ -3,7 +3,8 @@
 Parity with the reference's trace tooling
 (``atorch/utils/trace/`` timeline parsing, the xpu-timer's per-kernel
 aggregation, and ``analyse``-stage reporting): given a chrome-trace JSON
-— from :class:`~dlrover_tpu.utils.prof.Tracer`, ``jax.profiler``'s
+— the flight recorder's merged dumps
+(:func:`dlrover_tpu.obs.collect.write_chrome_trace`), ``jax.profiler``'s
 trace-viewer export, or any Perfetto-compatible producer — compute
 per-op/per-category time rollups, top-k hotspots, concurrency-corrected
 busy time, and step statistics, and render a text report.  Pure host
@@ -147,7 +148,7 @@ class TraceAnalysis:
         self, step_event: str = "train_step"
     ) -> List[Tuple[float, float]]:
         """(start, dur) of every event named ``step_event`` — the step
-        markers the Tracer/trainer emit."""
+        markers the trainer's spans carry."""
         return [
             (e.start_us, e.dur_us)
             for e in self.events

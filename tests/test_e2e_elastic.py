@@ -25,6 +25,9 @@ def _launch(tmp_path, job_name, extra_args, env_extra=None, steps=15):
             "JAX_PLATFORMS": "cpu",
             "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
             "PYTHONPATH": REPO,
+            # what the job leaves in the temp dir (the journals of a
+            # job that was killed) goes with the test's own directory
+            "TMPDIR": str(tmp_path),
         }
     )
     if env_extra:
@@ -61,13 +64,21 @@ class TestEndToEnd:
         assert rc == 0, content[-3000:]
         assert content.count("TRAIN_DONE step=8") == 2, content[-3000:]
         assert "jax.distributed up: process 0/2" in content
+        # the job had a flight-recorder journal without asking for one,
+        # and a job that ends well leaves none behind
+        m = re.search(r"flight recorder journals: (\S+)", content)
+        assert m and m.group(1).startswith(
+            str(tmp_path / "dlrover_tpu_obs" / "e2e-happy-")), content[:2000]
+        assert not os.path.exists(m.group(1))
 
     def test_kill_worker_restore(self, tmp_path):
         ckpt_dir = str(tmp_path / "ckpt")
+        obs_dir = str(tmp_path / "obs")
         proc, log = _launch(
             tmp_path, "e2e-kill",
             [f"--ckpt_dir={ckpt_dir}", "--ckpt_interval=3"],
             steps=2000,  # long enough that the kill lands mid-run
+            env_extra={"DLROVER_TPU_OBS_DIR": obs_dir},
         )
         # Wait for a checkpoint to be staged (step >= 10 reported).
         deadline = time.time() + 300
@@ -106,12 +117,66 @@ class TestEndToEnd:
         # And specifically the warm path: same host, staged shm state —
         # restore must come from shm, not a storage round trip.
         assert "warm restore from shm" in content, content[-3000:]
-        proc.send_signal(signal.SIGTERM)
         try:
-            proc.wait(timeout=60)
-        except subprocess.TimeoutExpired:
-            proc.kill()
-            proc.wait()
+            _check_restart_journals(obs_dir)
+        finally:
+            proc.send_signal(signal.SIGTERM)
+            try:
+                proc.wait(timeout=60)
+            except subprocess.TimeoutExpired:
+                proc.kill()
+                proc.wait()
+
+
+def _check_restart_journals(obs_dir):
+    """What the flight recorder holds of the restart while the job still
+    runs (nothing has been spilled: every line was written as its span
+    ended): the agent's ``agent.restart`` with its three children and the
+    breakpoint persist inside ``agent.stop_workers``, the killed worker's
+    saves, the new incarnation's warm load."""
+    from dlrover_tpu.obs.collect import load_dir
+
+    deadline = time.time() + 60
+    while time.time() < deadline:
+        dumps = {d["meta"]["process"]: d["events"]
+                 for d in load_dir(obs_dir)}
+        done = [e for e in dumps.get("agent-n0", [])
+                if e.get("name") == "agent.restart"]
+        loads = [e for name, evs in dumps.items() if name.endswith("-i1")
+                 for e in evs if e.get("name") == "ckpt.load"]
+        if done and len(loads) == 2:
+            break
+        time.sleep(1.0)
+    assert {"agent-n0", "worker-r0-i0", "worker-r1-i0", "worker-r0-i1",
+            "worker-r1-i1"} <= set(dumps), sorted(dumps)
+    agent = [e for e in dumps["agent-n0"] if e.get("k") == "span"]
+    (restart,) = [e for e in agent if e["name"] == "agent.restart"]
+    assert restart["args"]["reason"] == "failed"
+    assert restart["args"]["restart_count"] == 1
+    assert restart["args"]["exit_codes"] == [-9]
+    kids = {e["name"]: e for e in agent if e.get("psid") == restart["sid"]}
+    assert set(kids) == {"agent.stop_workers", "agent.rendezvous",
+                         "agent.start_workers"}
+    for kid in kids.values():
+        assert kid["ts"] >= restart["ts"] - 1
+        assert kid["ts"] + kid["dur"] <= restart["ts"] + restart["dur"] + 1
+    persists = [e for e in agent if e["name"] == "ckpt.persist"
+                and e.get("psid") == kids["agent.stop_workers"]["sid"]]
+    assert persists and all(
+        p["args"]["reason"] == "breakpoint" for p in persists)
+    # the worker that was SIGKILLed left its saves behind all the same
+    killed = [e for e in dumps["worker-r1-i0"]
+              if e.get("name") == "ckpt.save"]
+    assert killed and killed[0]["args"]["first_touch"] is True
+    # Both ranks take the same branch.  (The log's "warm restore from
+    # shm" line is written before the ranks agree; the span says where
+    # the state really came from — storage, when the kill left the two
+    # arenas a step apart.)
+    assert len({ld["args"]["source"] for ld in loads}) == 1, loads
+    assert loads[0]["args"]["source"] in ("shm", "storage")
+    assert all(ld["args"]["step"] >= 3 for ld in loads)
+    assert any(e.get("kind") == "bootstrap.process_start"
+               for e in dumps["worker-r0-i1"])
 
 
 def _free_port() -> int:
@@ -143,6 +208,7 @@ def _start_node(tmp_path, job_name, master_port, node_rank, script_args,
             "JAX_PLATFORMS": "cpu",
             "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
             "PYTHONPATH": REPO,
+            "TMPDIR": str(tmp_path),  # a killed node's journals go with it
         }
     )
     if env_extra:

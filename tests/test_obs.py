@@ -68,8 +68,11 @@ def _dumps_of(events, process="p0", pid=1):
 class TestTraceWireCompat:
     def test_traceless_messages_are_byte_identical_to_legacy(self):
         """The msgpack fast path's bytes must not change for messages
-        that carry no trace: the legacy encoding is ALL fields, no
-        ``trace`` key — rebuilt by hand here and compared."""
+        that carry no trace: the legacy encoding is every field the
+        message had before it grew its ``_WIRE_OPTIONAL`` ones (``trace``,
+        and since ISSUE 17 ``spill_from``/``spill_hops``), which stay off
+        the wire while at their defaults — rebuilt by hand here from the
+        message's own declaration and compared."""
         for msg in (
             wire.ServeSubmit(req_id="r", prompt=[1, 2, 3],
                              max_new_tokens=7, prefix_len=2,
@@ -87,9 +90,12 @@ class TestTraceWireCompat:
                 "f": {
                     f.name: getattr(msg, f.name)
                     for f in dataclasses.fields(msg)
-                    if f.name != "trace"
+                    if f.name not in type(msg)._WIRE_OPTIONAL
                 },
             }
+            assert "trace" in type(msg)._WIRE_OPTIONAL
+            assert not any(
+                getattr(msg, name) for name in type(msg)._WIRE_OPTIONAL)
             got = wire.serialize(msg)
             assert got == msgpack.packb(legacy, use_bin_type=True)
             assert b"trace" not in got

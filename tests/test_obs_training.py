@@ -1,0 +1,512 @@
+"""The training path's flight recorder (ISSUE 23): the span primitive, its
+SIGKILL-proof journal, the spans inside save / load / persist / build, and
+the scope table of the compiled step.  CPU, fast."""
+
+import functools
+import json
+import os
+import signal
+import subprocess
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from dlrover_tpu import obs
+from dlrover_tpu.obs.collect import load_dir, load_dump
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def recorder(tmp_path):
+    rec = obs.configure(out_dir=str(tmp_path / "obs"), process="ut")
+    yield rec
+    obs.reset()
+
+
+def _spans(rec=None, prefix=""):
+    evs, _, _ = (rec or obs.get_recorder()).snapshot()
+    return [e for e in evs
+            if e["k"] == "span" and e["name"].startswith(prefix)]
+
+
+def _inside(child, parent) -> bool:
+    return (child["ts"] >= parent["ts"] - 0.2
+            and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + 0.2)
+
+
+def _unlink_arena(job: str) -> None:
+    from dlrover_tpu.common.shm import arena_name
+
+    try:
+        os.unlink(f"/dev/shm/{arena_name(job, 0)}")
+    except FileNotFoundError:
+        pass
+
+
+def _run_python(code: str, **env):
+    return subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO, capture_output=True,
+        text=True, timeout=120,
+        env=dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu", **env))
+
+
+class TestSpan:
+    def test_child_names_its_parent_per_thread(self, recorder):
+        other = {}
+
+        def elsewhere():
+            with obs.span("t.other", "t") as sp:
+                other["psid"] = sp.psid
+
+        with obs.span("t.outer", "t", step=3) as outer:
+            with obs.span("t.inner", "t") as inner:
+                assert inner.psid == outer.sid
+            th = threading.Thread(target=elsewhere)
+            th.start()
+            th.join()
+        by = {s["name"]: s for s in _spans(recorder, "t.")}
+        assert by["t.inner"]["psid"] == outer.sid == by["t.outer"]["sid"]
+        assert "psid" not in by["t.outer"]
+        # another thread's span is no child of this thread's open span
+        assert other["psid"] == "" and "psid" not in by["t.other"]
+        assert _inside(by["t.inner"], by["t.outer"])
+        with obs.span("t.after", "t") as after:  # the stack is empty again
+            assert after.psid == ""
+
+    def test_args_set_inside_and_errors_are_recorded(self, recorder):
+        with pytest.raises(KeyError):
+            with obs.span("t.args", "t", step=7, rank=0) as sp:
+                sp.set(bytes=1024)
+                raise KeyError("x")
+        (rec,) = _spans(recorder, "t.args")
+        assert rec["args"] == {"step": 7, "rank": 0, "bytes": 1024,
+                               "error": "KeyError"}
+        assert rec["cat"] == "t" and rec["dur"] >= 0
+
+    def test_start_end_across_blocks_and_explicit_parent(self, recorder):
+        restart = obs.span("t.restart", "t", reason="failed").start()
+        with obs.span("t.stop", "t"):
+            pass
+        restart.end(round=2)
+        restart.end()  # a second end records nothing
+        with obs.span("t.commit", "t", parent=restart.sid):
+            pass
+        by = {s["name"]: s for s in _spans(recorder, "t.")}
+        assert len(_spans(recorder, "t.restart")) == 1
+        assert by["t.stop"]["psid"] == restart.sid
+        assert by["t.commit"]["psid"] == restart.sid
+        assert by["t.restart"]["args"] == {"reason": "failed", "round": 2}
+
+    def test_never_imports_jax(self):
+        res = _run_python(
+            "import sys\n"
+            "from dlrover_tpu import obs\n"
+            "with obs.span('a.b', 'a', n=1):\n"
+            "    with obs.span('a.c', 'a'):\n"
+            "        pass\n"
+            "obs.journal('e', durable=True)\n"
+            "assert 'jax' not in sys.modules, 'obs imported jax'\n"
+            "print(len(obs.get_recorder().snapshot()[0]))\n")
+        assert res.returncode == 0, res.stderr
+        assert res.stdout.strip() == "3"
+
+    def test_bridges_to_trace_annotation_only_when_jax_is_loaded(
+            self, recorder, monkeypatch):
+        import jax.profiler
+
+        seen = []
+
+        class FakeAnnotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                seen.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                seen.append(("exit", self.name))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+        with obs.span("t.bridged", "t"):
+            assert seen == [("enter", "t.bridged")]
+        assert seen == [("enter", "t.bridged"), ("exit", "t.bridged")]
+        # a process that has not loaded JAX is never made to
+        monkeypatch.delitem(sys.modules, "jax")
+        with obs.span("t.plain", "t"):
+            pass
+        assert len(seen) == 2
+        assert {s["name"] for s in _spans(recorder, "t.")} == {
+            "t.bridged", "t.plain"}
+
+
+class TestJournalSurvivesSigkill:
+    def test_low_rate_spans_are_on_disk_when_the_process_is_killed(
+            self, tmp_path):
+        res = _run_python(
+            "import os, signal\n"
+            "from dlrover_tpu import obs\n"
+            "with obs.span('ckpt.save', 'ckpt', step=2, bytes=10):\n"
+            "    with obs.span('ckpt.save.d2h', 'ckpt'):\n"
+            "        pass\n"
+            "with obs.span('trainer.report_step', 'trainer',\n"
+            "              ring_only=True, step=3):\n"
+            "    pass\n"
+            "obs.journal('bootstrap.process_start', durable=True, x=1)\n"
+            "obs.journal('ring.only', x=2)\n"
+            "os.kill(os.getpid(), signal.SIGKILL)\n",
+            DLROVER_TPU_OBS_DIR=str(tmp_path),
+            DLROVER_TPU_OBS_PROCESS="worker-r0-i0")
+        assert res.returncode == -signal.SIGKILL
+        (dump,) = load_dir(str(tmp_path))
+        assert dump["meta"]["process"] == "worker-r0-i0"
+        assert dump["meta"]["reason"] == "journal"  # no hook ever ran
+        names = [e.get("name") or e.get("kind") for e in dump["events"]]
+        assert names == ["ckpt.save.d2h", "ckpt.save",
+                         "bootstrap.process_start"]
+        save = dump["events"][1]
+        assert save["args"] == {"step": 2, "bytes": 10}
+        assert dump["events"][0]["psid"] == save["sid"]
+        assert set(save) >= {"k", "name", "cat", "ts", "dur", "sid"}
+
+    def test_exit_dump_keeps_what_the_ring_has_evicted(self, tmp_path):
+        rec = obs.configure(out_dir=str(tmp_path), process="p", capacity=4)
+        try:
+            with obs.span("ckpt.load", "ckpt", step=1):
+                pass
+            for n in range(10):  # per-step spans push it out of the ring
+                with obs.span("trainer.report_step", "trainer",
+                              ring_only=True, step=n):
+                    pass
+            assert not _spans(rec, "ckpt.load")
+            path = rec.dump(reason="exit")
+            dump = load_dump(path)
+            names = [e["name"] for e in dump["events"]]
+            assert names[0] == "ckpt.load" and len(names) == 5
+            assert dump["meta"]["reason"] == "exit"
+            # the journal goes on after a dump, in the file the dump left
+            with obs.span("ckpt.save", "ckpt", step=2):
+                pass
+            again = load_dump(path)
+            assert [e["name"] for e in again["events"]][-1] == "ckpt.save"
+        finally:
+            obs.reset()
+
+    def test_ring_only_without_a_dump_directory(self):
+        rec = obs.configure()
+        try:
+            with obs.span("ckpt.save", "ckpt"):
+                pass
+            assert rec.dump_path() is None and _spans(rec, "ckpt.save")
+        finally:
+            obs.reset()
+
+
+class TestCheckpointSpans:
+    CHILDREN = {
+        "ckpt.save": {"ckpt.save.d2h", "ckpt.save.lock_wait",
+                      "ckpt.save.arena_write", "ckpt.save.report"},
+        "ckpt.load": {"ckpt.load.shm_read", "ckpt.load.agree",
+                      "ckpt.load.device_put"},
+    }
+
+    def test_save_and_load_leave_their_spans(self, tmp_path, monkeypatch,
+                                             recorder):
+        import jax.numpy as jnp
+
+        from dlrover_tpu.checkpoint.checkpointer import FlashCheckpointer
+
+        job = f"obs-ckpt-{os.getpid()}"
+        monkeypatch.setenv("DLROVER_TPU_JOB_NAME", job)
+        monkeypatch.setenv("DLROVER_TPU_PROCESS_ID", "0")
+        monkeypatch.setenv("DLROVER_TPU_NUM_PROCESSES", "1")
+        ckpt = FlashCheckpointer(str(tmp_path / "ckpt"), job_name=job)
+        state = {"params": {"w": jnp.arange(4096.0).reshape(64, 64)},
+                 "count": jnp.array(3)}
+        nbytes = 64 * 64 * 4 + np.asarray(state["count"]).nbytes
+        try:
+            ckpt.save(state, meta={"step": 5})
+            ckpt.save(state, meta={"step": 6})
+            restored, meta = ckpt.load(target=state)
+            assert meta["step"] == 6
+        finally:
+            ckpt.close()
+            _unlink_arena(job)
+        spans = _spans(recorder, "ckpt.")
+        for parent_name, want in self.CHILDREN.items():
+            parents = [s for s in spans if s["name"] == parent_name]
+            assert parents, parent_name
+            for parent in parents:
+                kids = [s for s in spans
+                        if s.get("psid") == parent["sid"]]
+                assert {k["name"] for k in kids} == want
+                assert all(_inside(k, parent) for k in kids)
+                assert sum(k["dur"] for k in kids) <= parent["dur"] + 1
+                assert parent["args"]["bytes"] == nbytes
+        first, second = [s for s in spans if s["name"] == "ckpt.save"]
+        assert first["args"]["step"] == 5 and second["args"]["step"] == 6
+        assert first["args"]["first_touch"] is True
+        assert second["args"]["first_touch"] is False
+        assert {"stall_ms", "mbps", "rank"} <= set(first["args"])
+        d2h = [s for s in spans if s["name"] == "ckpt.save.d2h"][0]
+        assert d2h["args"] == {"bytes": nbytes, "tensors": 2}
+        load = [s for s in spans if s["name"] == "ckpt.load"][0]
+        assert load["args"]["source"] == "shm"
+        assert load["args"]["step"] == 6
+        read = [s for s in spans if s["name"] == "ckpt.load.shm_read"][0]
+        assert read["args"] == {"copy": False, "bytes": nbytes}
+        # the one record that replaced the ckpt.stage event
+        evs, _, _ = recorder.snapshot()
+        assert not [e for e in evs if e.get("kind") == "ckpt.stage"]
+
+    def test_storage_save_and_cold_load(self, tmp_path, monkeypatch,
+                                        recorder):
+        import jax.numpy as jnp
+
+        from dlrover_tpu.checkpoint.checkpointer import FlashCheckpointer
+
+        job = f"obs-cold-{os.getpid()}"
+        monkeypatch.setenv("DLROVER_TPU_JOB_NAME", job)
+        ckpt = FlashCheckpointer(str(tmp_path / "ckpt"), job_name=job)
+        state = {"w": jnp.ones((32, 32)) * 2.5}
+        ckpt.save(state, meta={"step": 9}, storage=True)
+        assert ckpt.wait(timeout=60)
+        ckpt.close()
+        _unlink_arena(job)  # the host restarted: shared memory is gone
+        ckpt2 = FlashCheckpointer(str(tmp_path / "ckpt"), job_name=job)
+        try:
+            _, meta = ckpt2.load(target={"w": jnp.zeros((32, 32))})
+            assert meta["step"] == 9
+        finally:
+            ckpt2.close()
+        spans = _spans(recorder, "ckpt.")
+        persist = [s for s in spans if s["name"] == "ckpt.persist"][0]
+        assert persist["args"]["reason"] == "save"
+        kids = {s["name"]: s for s in spans
+                if s.get("psid") == persist["sid"]}
+        assert set(kids) == {"ckpt.persist.lock_wait",
+                             "ckpt.persist.write", "ckpt.persist.commit"}
+        assert kids["ckpt.persist.write"]["args"]["bytes"] > 0
+        assert kids["ckpt.persist.commit"]["args"]["ok"] is True
+        load = [s for s in spans if s["name"] == "ckpt.load"][-1]
+        assert load["args"]["source"] == "storage"
+        assert [s for s in spans if s["name"] == "ckpt.load.storage_read"
+                and s.get("psid") == load["sid"]]
+
+
+class TestAgentSaverSpans:
+    def test_breakpoint_persist_in_the_agent_process(self, tmp_path,
+                                                     monkeypatch,
+                                                     recorder):
+        import jax.numpy as jnp
+
+        from dlrover_tpu.agent.ckpt_saver import AsyncCheckpointSaver
+        from dlrover_tpu.checkpoint.checkpointer import FlashCheckpointer
+
+        job = f"obs-agent-{os.getpid()}"
+        monkeypatch.setenv("DLROVER_TPU_JOB_NAME", job)
+        saver = AsyncCheckpointSaver(job, nproc_per_node=1)
+        saver.start()
+        try:
+            ckpt = FlashCheckpointer(str(tmp_path), job_name=job)
+            ckpt.save({"w": jnp.full((8, 8), 1.5)}, meta={"step": 8})
+            with obs.span("agent.stop_workers", "agent") as stop:
+                saver.save_shm_to_storage("test-breakpoint")
+            deadline = time.monotonic() + 30
+            while time.monotonic() < deadline and not _spans(
+                    recorder, "ckpt.persist.commit"):
+                time.sleep(0.1)
+            ckpt.close()
+        finally:
+            saver.stop()
+            _unlink_arena(job)
+        spans = _spans(recorder, "ckpt.persist")
+        persist = [s for s in spans if s["name"] == "ckpt.persist"][0]
+        assert persist["psid"] == stop.sid
+        assert persist["args"]["reason"] == "breakpoint"
+        assert persist["args"]["step"] == 8
+        kids = {s["name"] for s in spans
+                if s.get("psid") == persist["sid"]}
+        # the commit runs on a pool thread and still names its persist
+        assert kids == {"ckpt.persist.lock_wait", "ckpt.persist.write",
+                        "ckpt.persist.commit"}
+
+
+class TestBuildSpansAndScopes:
+    @pytest.fixture(scope="class")
+    def built(self):
+        import jax
+        import optax
+
+        from dlrover_tpu.models import llama
+        from dlrover_tpu.parallel.accelerate import Strategy, accelerate
+        from dlrover_tpu.parallel.mesh import MeshSpec
+
+        rec = obs.configure()
+        cfg = llama.LlamaConfig.tiny(vocab_size=4096, remat_block=True)
+        job = accelerate(
+            loss_fn=functools.partial(llama.loss_fn, cfg=cfg),
+            init_fn=functools.partial(llama.init_params, cfg=cfg),
+            optimizer=optax.adamw(1e-3),
+            sample_batch={"tokens": np.zeros((2, 33), np.int32)},
+            strategy=Strategy(mesh=MeshSpec(dp=1)),
+            devices=jax.devices()[:1])
+        state = job.create_state(jax.random.PRNGKey(0))
+        batch = {"tokens": jax.device_put(
+            np.zeros((2, 33), np.int32), job.batch_sharding["tokens"])}
+        state, _ = job.train_step(state, batch)
+        job.train_step(state, batch)
+        evs, _, _ = rec.snapshot()
+        obs.reset()
+        return job, evs
+
+    def test_build_has_its_children_and_one_first_call(self, built):
+        _, evs = built
+        spans = {e["name"]: e for e in evs if e["k"] == "span"}
+        build = spans["accelerate.build"]
+        for child in ("accelerate.lower", "accelerate.compile",
+                      "accelerate.analyze"):
+            assert spans[child]["psid"] == build["sid"], child
+            assert _inside(spans[child], build)
+        assert "cache_hit" in spans["accelerate.compile"]["args"]
+        assert "strategy" in build["args"]
+        firsts = [e for e in evs if e.get("name") == "accelerate.first_call"]
+        assert len(firsts) == 1  # the second train_step call records none
+        assert "psid" not in firsts[0]
+
+    def test_program_event_carries_the_scope_table(self, built):
+        job, evs = built
+        (ev,) = [e for e in evs if e.get("kind") == "accelerate.program"]
+        assert ev["scopes"] == job.program["scopes"]
+        assert set(ev) >= {"kernels", "collectives", "strategy"}
+        json.dumps(ev)  # the journal's line
+
+    def test_scope_table_names_phase_and_scope(self, built):
+        job, _ = built
+        table = job.program["scopes"]
+        verdicts = {tuple(v) for v in table.values()}
+        for want in (("forward", "lm_head_loss"),
+                     ("backward", "lm_head_loss"),
+                     ("optimizer", "optimizer"),
+                     ("forward", "attention"), ("backward", "attention"),
+                     ("recompute", "attention"), ("backward", "mlp"),
+                     ("forward", "embed"), ("other", "grad_norm")):
+            assert want in verdicts, want
+        assert {p for p, _ in verdicts} <= {
+            "forward", "backward", "recompute", "optimizer", "other"}
+
+    @pytest.mark.parametrize("op_name,want", [
+        ("jit(train_step)/jvp(attention)/dot_general",
+         ["forward", "attention"]),
+        ("jit(train_step)/transpose(jvp(lm_head_loss))/while/body/"
+         "closed_call/dot_general", ["backward", "lm_head_loss"]),
+        ("jit(train_step)/transpose(jvp(jvp()))/checkpoint/"
+         "rematted_computation/mlp/jit(silu)/logistic",
+         ["recompute", "mlp"]),
+        ("jit(train_step)/transpose(jvp(jvp()))/checkpoint/attention/"
+         "bhqk,bhkd->bhqd/dot_general", ["backward", "attention"]),
+        ("jit(train_step)/optimizer/jit(_where)/select_n",
+         ["optimizer", "optimizer"]),
+        ("jit(train_step)/grad_norm/reduce_sum", ["other", "grad_norm"]),
+        ("jit(train_step)/add", None),
+        ("reduce_sum", None),
+    ])
+    def test_phase_and_scope_of_an_op_name(self, op_name, want):
+        from dlrover_tpu.parallel.accelerate import phase_and_scope
+
+        assert phase_and_scope(op_name) == want
+
+    def test_scope_table_of_a_text(self):
+        from dlrover_tpu.parallel.accelerate import scope_table
+
+        text = """\
+HloModule jit_train_step
+
+%fused_computation (p0: f32[4]) -> f32[4] {
+  %p0 = f32[4]{0} parameter(0)
+  ROOT %mul.1 = f32[4]{0} multiply(%p0, %p0), metadata={op_name="jit(train_step)/optimizer/mul"}
+}
+
+%body (arg: f32[4]) -> f32[4] {
+  %arg = f32[4]{0} parameter(0)
+  ROOT %dot.7 = f32[4]{0} add(%arg, %arg), metadata={op_name="jit(train_step)/transpose(jvp(lm_head_loss))/while/body/closed_call/dot_general"}
+}
+
+ENTRY %main.9 (a: f32[4]) -> f32[4] {
+  %a = f32[4]{0} parameter(0)
+  %fusion.3 = f32[4]{0} fusion(%a), kind=kLoop, calls=%fused_computation
+  %copy.2 = f32[4]{0} copy(%fusion.3)
+  %loose.1 = f32[4]{0} negate(%a)
+  ROOT %while.4 = f32[4]{0} while(%copy.2), body=%body, metadata={op_name="jit(train_step)/transpose(jvp(lm_head_loss))/while"}
+}
+"""
+        assert scope_table(text) == {
+            "fusion.3": ["optimizer", "optimizer"],  # voted by its body
+            "copy.2": ["optimizer", "optimizer"],  # its operand's
+            "dot.7": ["backward", "lm_head_loss"],
+            "while.4": ["backward", "lm_head_loss"],
+        }
+
+
+class TestBootstrapSpans:
+    def test_backend_init_and_process_age(self, recorder):
+        from dlrover_tpu.common.jax_env import (
+            device_summary,
+            process_age_s,
+        )
+
+        summary = device_summary()
+        (span,) = _spans(recorder, "bootstrap.backend_init")
+        assert span["args"]["platform"] == summary["platform"] == "cpu"
+        assert span["args"]["count"] == summary["count"]
+        assert 0 <= process_age_s() < 24 * 3600
+
+    def test_init_journals_the_interpreter_start(self, tmp_path):
+        res = _run_python(
+            "import dlrover_tpu.trainer as t\n"
+            "ctx = t.init(connect_master=False)\n"
+            "ctx.report_step(1)\n",
+            DLROVER_TPU_OBS_DIR=str(tmp_path),
+            DLROVER_TPU_OBS_PROCESS="worker-r0-i1",
+            DLROVER_TPU_RESTART_COUNT="1")
+        assert res.returncode == 0, res.stderr
+        (dump,) = load_dir(str(tmp_path))
+        by = {e.get("name") or e.get("kind"): e for e in dump["events"]}
+        start = by["bootstrap.process_start"]
+        assert start["restart_count"] == 1
+        assert 0 < start["since_process_start_s"] < 120
+        assert by["bootstrap.init"]["args"]["restart_count"] == 1
+
+
+class TestLauncherJournalDirectory:
+    def test_job_dir_follows_the_temp_dir(self, tmp_path, monkeypatch):
+        import tempfile
+
+        monkeypatch.setenv("TMPDIR", str(tmp_path))
+        monkeypatch.setattr(tempfile, "tempdir", None)
+        assert obs.job_dir("bench-12", "ab12") == str(
+            tmp_path / "dlrover_tpu_obs" / "bench-12-ab12")
+        assert obs.job_dir("a/b") == str(
+            tmp_path / "dlrover_tpu_obs" / "a_b")
+
+    def test_retention_removes_only_what_is_old(self, tmp_path,
+                                                 monkeypatch):
+        import tempfile
+
+        monkeypatch.setenv("TMPDIR", str(tmp_path))
+        monkeypatch.setattr(tempfile, "tempdir", None)
+        old, new = obs.job_dir("old", "1"), obs.job_dir("new", "2")
+        for d in (old, new):
+            os.makedirs(d)
+            with open(os.path.join(d, "flight-agent-n0-1.jsonl"), "w"):
+                pass
+        stale = time.time() - 30 * 86400
+        os.utime(old, (stale, stale))
+        os.utime(os.path.join(old, "flight-agent-n0-1.jsonl"),
+                 (stale, stale))
+        obs.gc_job_dirs()
+        assert not os.path.exists(old) and os.path.exists(new)

@@ -1,9 +1,7 @@
-"""Checkpoint replica + utils tests: ring backup over real RPC, step
-profiler, loss-spike detection, metrics endpoint."""
+"""Checkpoint replica + utils tests: ring backup over real RPC,
+loss-spike detection, metrics endpoint."""
 
-import json
 import math
-import time
 import urllib.request
 
 import numpy as np
@@ -15,7 +13,6 @@ from dlrover_tpu.checkpoint.replica import (
     ReplicaStore,
 )
 from dlrover_tpu.utils.loss_spike import LossSpikeDetector
-from dlrover_tpu.utils.prof import StepProfiler, Tracer
 
 
 class TestReplicaStore:
@@ -171,33 +168,6 @@ class TestSaverSeeding:
             peer.stop()
             if saver is not None:
                 saver.stop()
-
-
-class TestStepProfiler:
-    def test_warmup_and_percentiles(self):
-        p = StepProfiler()
-        p.step()  # warmup
-        for _ in range(10):
-            time.sleep(0.001)
-            p.step()
-        s = p.summary()
-        assert s["steps"] == 11
-        assert s["warmup_s"] >= 0
-        assert s["p50_s"] > 0
-        assert s["steps_per_s"] > 0
-
-
-class TestTracer:
-    def test_span_and_save(self, tmp_path):
-        tr = Tracer()
-        with tr.span("step", step=1):
-            pass
-        tr.instant("ckpt", step=1)
-        out = tmp_path / "trace.json"
-        tr.save(str(out))
-        data = json.loads(out.read_text())
-        names = [e["name"] for e in data["traceEvents"]]
-        assert names == ["step", "ckpt"]
 
 
 class TestLossSpike:

@@ -1,5 +1,5 @@
 """Trace-analysis tests: synthetic traces with known answers, plus a
-real round trip through the Tracer (test model: the reference's trace
+real round trip through the flight recorder (test model: the reference's trace
 tooling unit tests)."""
 
 import gzip
@@ -7,7 +7,8 @@ import json
 
 import pytest
 
-from dlrover_tpu.utils.prof import Tracer
+from dlrover_tpu import obs
+from dlrover_tpu.obs.collect import write_chrome_trace
 from dlrover_tpu.utils.trace_analysis import (
     TraceAnalysis,
     TraceEvent,
@@ -97,14 +98,18 @@ class TestLoadTrace:
             json.dump(events["traceEvents"], f)
         assert len(load_trace(str(pz))) == 1
 
-    def test_round_trip_through_tracer(self, tmp_path):
-        tracer = Tracer()
-        with tracer.span("train_step", category="step"):
-            with tracer.span("fwd", category="compute"):
-                pass
-        tracer.instant("ckpt", step=3)
-        path = str(tmp_path / "trace.json")
-        tracer.save(path)
+    def test_round_trip_through_the_flight_recorder(self, tmp_path):
+        """obs.span -> dump -> merged chrome trace -> TraceAnalysis."""
+        rec = obs.configure(out_dir=str(tmp_path), process="t")
+        try:
+            with obs.span("train_step", "step"):
+                with obs.span("fwd", "compute"):
+                    pass
+            obs.journal("ckpt", step=3)
+            rec.dump()
+        finally:
+            obs.reset()
+        path = write_chrome_trace(str(tmp_path), str(tmp_path / "t.json"))
         ta = TraceAnalysis.from_file(path)
         names = {e.name for e in ta.events}
         assert names == {"train_step", "fwd"}
