@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """First contact: prove the system still starts on the chip.
 
-    python chip_smoke.py            # one chip: train+kill+resume, serve, kernels
+    python chip_smoke.py            # one chip: train+kill+resume, restore, serve, kernels
     python chip_smoke.py --chips 4  # four chips: the sharded step, nothing else
 
 Drives the main path once through the entry points a user would call, at the
@@ -17,6 +17,10 @@ a seed), and checks what comes out by the repo's own means:
   ``generate`` and the teacher-forced training forward for one request.
 - ``kernels``: ``ops/smoke.py`` — every Pallas kernel compiled by Mosaic
   (``interpret=False``), executed, value- and grad-checked.
+- ``restore``: the warm restore hands views of the shm arena straight to
+  ``device_put``; state A is saved and restored, state B is saved over it,
+  and the restored arrays must still be A bit for bit — only a chip can
+  show that its ``device_put`` keeps no alias of the host buffer.
 - ``mesh4`` (``--chips 4`` only): ``accelerate`` on a ``fsdp=2 x tp=2`` mesh
   against the same seed and batches on a one-device mesh.
 
@@ -294,11 +298,84 @@ def mesh4_phase(cfg=None, *, batch=8, seq=2048, steps=3, seed=0,
     }
 
 
+def restore_phase(*, leaves=4, leaf_mib=64, seed=0) -> dict:
+    """Save state A to shared memory, ``load()`` it onto the device, save
+    a different state B into the same arena, and compare what was
+    restored with A bit for bit.  On a TPU every piece must have gone to
+    ``device_put`` as a view of the arena (``copied_bytes`` 0), so this
+    is the proof that the restored arrays keep no alias of the mapping
+    once ``block_until_ready`` has returned; on the CPU backend, which
+    may alias a numpy buffer, the pieces are copied first."""
+    import jax
+    import numpy as np
+
+    from dlrover_tpu import obs
+    from dlrover_tpu.checkpoint.engine import CheckpointEngine
+    from dlrover_tpu.common.jax_env import device_summary
+    from dlrover_tpu.common.shm import arena_name
+
+    job = f"{JOB}-restore-{os.getpid()}"
+    n = (leaf_mib << 20) // 4
+    rng = np.random.RandomState(seed)
+    host_a = {f"w{i}": rng.standard_normal(n).astype(np.float32)
+              for i in range(leaves)}
+    host_a["count"] = np.int32(7)
+    host_b = {k: (v + 1).astype(v.dtype) for k, v in host_a.items()}
+    dev = jax.local_devices()[0]
+    state_a = jax.device_put(host_a, dev)
+    nbytes = sum(int(v.nbytes) for v in host_a.values())
+    eng = CheckpointEngine(os.path.join(WORK, "restore_ckpt"), job_name=job)
+    try:
+        eng.save_to_memory(1, state_a)
+        t0 = time.monotonic()
+        restored, meta = eng.load(target=state_a)
+        load_s = time.monotonic() - t0
+        eng.save_to_memory(2, jax.device_put(host_b, dev))
+        # B is in the arena now: an alias would read B here
+        got = {k: np.asarray(v) for k, v in restored.items()}
+        same = all(
+            got[k].dtype == host_a[k].dtype
+            and np.array_equal(got[k], host_a[k])
+            for k in host_a
+        )
+        again, meta_b = eng.load(target=state_a)
+        staged_b = meta_b.get("step") == 2 and all(
+            np.array_equal(np.asarray(again[k]), host_b[k]) for k in host_b
+        )
+    finally:
+        eng.close()
+        try:
+            os.unlink(f"/dev/shm/{arena_name(job, 0)}")
+        except FileNotFoundError:
+            pass
+    evs, _, _ = obs.get_recorder().snapshot()
+    spans = {e["name"]: e.get("args", {}) for e in evs if e["k"] == "span"}
+    read, put = spans["ckpt.load.shm_read"], spans["ckpt.load.device_put"]
+    on_cpu = dev.platform == "cpu"
+    want_copied = nbytes if on_cpu else 0
+    counted = (read.get("copy") is False
+               and put.get("copied_bytes") == want_copied
+               and put.get("in_place_bytes") == nbytes - want_copied)
+    say(f"restore: {nbytes} bytes in {len(host_a)} leaves on "
+        f"{dev.platform}; load {load_s:.3f}s; shm_read {json.dumps(read)}; "
+        f"device_put {json.dumps(put)}")
+    say(f"restore: step {meta.get('step')} restored; arena overwritten "
+        f"with step 2 (restored in turn: {staged_b}); the first restore "
+        f"still equals state A bit for bit: {same}; counts as expected: "
+        f"{counted}")
+    return {
+        "ok": bool(same and staged_b and counted and meta.get("step") == 1),
+        "device": device_summary(),
+        "peak_bytes_in_use": _peak_bytes(),
+    }
+
+
 CHILD_PHASES = {
     "device": device_phase,
     "serve": serve_phase,
     "kernels": kernels_phase,
     "mesh4": mesh4_phase,
+    "restore": restore_phase,
 }
 
 
@@ -495,6 +572,7 @@ def main() -> int:
     if args.chips == 1:
         plan += [
             ("train", train_phase),
+            ("restore", lambda: run_child("restore", 300.0)),
             ("serve", lambda: run_child("serve", 900.0)),
             ("kernels", lambda: run_child("kernels", 600.0)),
         ]

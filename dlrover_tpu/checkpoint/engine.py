@@ -177,24 +177,8 @@ class CheckpointEngine:
             # absent, not just torn slices of shared ones.
             "tree_paths": sorted({m["path"] for m in info.values()}),
         }
-        # A zero-copy persist (agent saver on the fencing lock, or the
-        # standalone persist thread on the arena mutex) legitimately
-        # holds its lock for a WHOLE streamed storage write, which can
-        # exceed a minute on slow storage — waiting is correct; crashing
-        # the trainer's save (or hanging it silently) is not.
         with span("ckpt.save.lock_wait", "ckpt"):
-            if self._lock is not None:
-                self._acquire_patiently(
-                    self._lock.acquire, "shm fencing lock"
-                )
-            try:
-                self._acquire_patiently(
-                    self._arena_mu.acquire, "arena mutex"
-                )
-            except BaseException:
-                if self._lock is not None:
-                    self._lock.release()
-                raise
+            self._fence_arena()
         try:
             self._first_touch = self._arena.will_allocate(tensors)
             with span("ckpt.save.arena_write", "ckpt",
@@ -202,25 +186,47 @@ class CheckpointEngine:
                       first_touch=self._first_touch):
                 self._arena.write_state(tensors, extra=extra)
         finally:
-            self._arena_mu.release()
-            if self._lock is not None:
-                self._lock.release()
+            self._unfence_arena()
         self._last_saved_step = step
         return tensors, extra
+
+    def _fence_arena(self) -> None:
+        """Take the rank's cross-process fencing lock (agent mode) and,
+        inside it, the in-process arena mutex: whoever writes the arena,
+        reads views of it or remaps it holds both for as long as that
+        lasts.  A zero-copy persist (agent saver on the fencing lock, or
+        the standalone persist thread on the arena mutex) legitimately
+        holds its lock for a WHOLE streamed storage write, which can
+        exceed a minute on slow storage — waiting is correct; crashing
+        the trainer (or hanging it silently) is not.  A holder that dies
+        is covered by ``SharedLock``'s dead-holder steal."""
+        if self._lock is not None:
+            self._acquire_patiently(self._lock.acquire, "shm fencing lock")
+        try:
+            self._acquire_patiently(self._arena_mu.acquire, "arena mutex")
+        except BaseException:
+            if self._lock is not None:
+                self._lock.release()
+            raise
+
+    def _unfence_arena(self) -> None:
+        self._arena_mu.release()
+        if self._lock is not None:
+            self._lock.release()
 
     @staticmethod
     def _acquire_patiently(
         acquire, what: str, budget: float = 600.0
     ) -> None:
-        """Bounded lock wait for the save path: warn each minute, raise
-        only after the persist path's own 600s budget — one home for the
-        deadline arithmetic both save-path locks share."""
+        """Bounded lock wait: warn each minute, raise only after the
+        persist path's own 600s budget — one home for the deadline
+        arithmetic both arena locks share."""
         deadline = time.time() + budget
         while not acquire(timeout=60.0):
             if time.time() >= deadline:
                 raise TimeoutError(f"could not acquire {what}")
             logger.warning(
-                "save: %s still held (persist in flight?); waiting", what
+                "%s still held (persist in flight?); waiting", what
             )
 
     def save_to_memory(
@@ -535,38 +541,10 @@ class CheckpointEngine:
             self._target_boxes(target) if target is not None else None
         )
         self._man_cache = {}
-        # Zero-copy shm read when the tree is materialized HERE and this
-        # process is provably the arena's only writer: with a target,
-        # restore_to_target device_puts every piece before load() returns,
-        # while the arena stays mapped and (standalone mode) nothing else
-        # can write it — so views never outlive their mapping.  In AGENT
-        # mode the saver process may concurrently write_state the same
-        # arena (replica seed_from_replicas after a re-rendezvous) and
-        # this unlocked read would see torn bytes, so it copies.  Without
-        # a target the ShardSource escapes to the caller with unbounded
-        # lifetime: copy.
-        got = self._load_from_shm(
-            copy=target is None or self.agent_mode
-        )
-        with span("ckpt.load.agree", "ckpt"):
-            # collective: same branch all ranks
-            got = self._agree_shm_step(got)
-        if got is not None:
-            source, extra = got
-            try:
-                result = self._finish_load(source, extra, target)
-            except KeyError:
-                result = None
-                logger.warning(
-                    "shm restore incomplete; falling back to storage"
-                )
-            # Collective: if any rank's shm assembly failed, all ranks
-            # fall back together (collective-count symmetry).
-            with span("ckpt.load.agree", "ckpt"):
-                ok = self._all_ranks_ok(result is not None)
-            if ok:
-                self._load_span.set(source="shm")
-                return result
+        result = self._restore_from_shm(target)
+        if result is not None:
+            self._load_span.set(source="shm")
+            return result
         self._load_span.set(source="storage")
         # Storage: committed step first, then newer uncommitted steps whose
         # available shards still cover the target (e.g. a breakpoint save
@@ -601,6 +579,51 @@ class CheckpointEngine:
                 self._quarantine(cand_step)
         with span("ckpt.load.agree", "ckpt"):
             return self._agree_storage_step(result, chosen, target)
+
+    def _restore_from_shm(self, target: Any):
+        """Warm restore from this rank's arena, or ``None`` for "go to
+        storage" (the same answer on every rank).
+
+        The arena is read as VIEWS into the mapping and each view goes
+        straight to ``jax.device_put``: no host copy of the state is
+        made in between.  What makes that safe is the hold, not a copy:
+        the rank's fencing lock keeps every other writer out (the agent
+        saver's ``seed_from_replicas`` may ``write_state`` this arena
+        after a re-rendezvous) and the arena mutex keeps the mapping
+        where it is (``reopen()`` is inside the hold, and the standalone
+        persist thread streams from the same mapping), from before the
+        views are taken until ``block_until_ready`` of the restored
+        state has returned.  After that no piece of the state refers to
+        the arena: a device that could alias host memory was given
+        copies (``tree_utils.restore_to_target``).
+
+        Without a target the ``ShardSource`` escapes to the caller with
+        unbounded lifetime, so it holds copies, made under the same
+        hold.  The saver never waits on a collective, so holding a
+        per-rank lock across the ranks' agreement cannot cycle."""
+        self._fence_arena()
+        try:
+            got = self._load_from_shm(copy=target is None)
+            with span("ckpt.load.agree", "ckpt"):
+                # collective: same branch all ranks
+                got = self._agree_shm_step(got)
+            if got is None:
+                return None
+            source, extra = got
+            try:
+                result = self._finish_load(source, extra, target)
+            except KeyError:
+                result = None
+                logger.warning(
+                    "shm restore incomplete; falling back to storage"
+                )
+        finally:
+            self._unfence_arena()
+        # Collective: if any rank's shm assembly failed, all ranks
+        # fall back together (collective-count symmetry).
+        with span("ckpt.load.agree", "ckpt"):
+            ok = self._all_ranks_ok(result is not None)
+        return result if ok else None
 
     def _assemble_candidate(
         self, source, extra, target, selective: bool, step: int
@@ -708,10 +731,13 @@ class CheckpointEngine:
         meta.setdefault("step", extra.get("step", 0))
         if target is None:
             return source, meta
-        with span("ckpt.load.device_put", "ckpt"):
-            state = tree_utils.restore_to_target(target, source)
-            # device_put returns before the bytes are on the device
+        with span("ckpt.load.device_put", "ckpt") as sp:
+            tally: Dict[str, int] = {}
+            state = tree_utils.restore_to_target(target, source, tally)
+            # device_put returns before the bytes are on the device, and
+            # until they are it may still read the piece it was given
             jax.block_until_ready(state)
+            sp.set(**tally)
         return state, meta
 
     def _agree_shm_step(self, got):
@@ -746,14 +772,14 @@ class CheckpointEngine:
             )
         return None
 
-    def _load_from_shm(self, copy: bool = True):
+    def _load_from_shm(self, copy: bool):
+        """Read the staged state; the caller holds :meth:`_fence_arena`
+        (``reopen()`` munmaps, and with ``copy=False`` the tensors are
+        views that are valid only inside that hold)."""
         with span("ckpt.load.shm_read", "ckpt", copy=copy) as sp:
             try:
-                # reopen() munmaps: fence against a concurrent standalone
-                # persist thread streaming from the current mapping.
-                with self._arena_mu:
-                    self._arena.reopen()
-                    read = self._arena.read_state(copy=copy)
+                self._arena.reopen()
+                read = self._arena.read_state(copy=copy)
             except (FileNotFoundError, OSError):
                 return None  # no arena yet: first run on this host
             except Exception:  # noqa: BLE001

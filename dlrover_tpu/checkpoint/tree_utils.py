@@ -21,6 +21,8 @@ import numpy as np
 import jax
 from jax.tree_util import keystr, tree_flatten_with_path, tree_unflatten
 
+from dlrover_tpu.common.byte_audit import audit
+
 
 def _norm_index(index, shape) -> Tuple[Tuple[int, int], ...]:
     """Normalize a shard index (tuple of slices) to ((start, stop), ...)."""
@@ -212,16 +214,35 @@ def _owned(piece: np.ndarray) -> np.ndarray:
     """Ensure a restored piece owns its bytes.
 
     ``assemble()``'s exact-match fast path returns the source array
-    itself, which on the zero-copy shm restore is a VIEW into the live
-    arena — it must not reach the restored tree (directly, or via
-    ``jax.device_put``, which on the CPU backend may alias an aligned
-    numpy buffer instead of copying): the next ``save_to_memory`` would
-    rewrite the bytes underfoot.  ``base is not None`` is exactly "this
-    array borrows someone else's buffer"; storage-restored pieces
-    (``unpack_shard`` copies) and overlap-assembled pieces (fresh
-    ``np.empty``) pass through untouched."""
+    itself, which on the warm shm restore is a VIEW into the live arena.
+    Such a view is valid only while ``CheckpointEngine`` holds the rank's
+    fencing lock and the arena mutex, so it must not reach the restored
+    tree.  ``base is not None`` is exactly "this array borrows someone
+    else's buffer"; storage-restored pieces (``unpack_shard`` copies) and
+    overlap-assembled pieces (fresh ``np.empty``) pass through
+    untouched."""
     piece = np.asarray(piece)
     return np.array(piece) if piece.base is not None else piece
+
+
+def _may_alias_host(device) -> bool:
+    """Whether an array ``device_put`` to ``device`` can go on referring
+    to the host buffer it was given.  The CPU backend may adopt an
+    aligned numpy buffer as the array's own storage; on an accelerator
+    the bytes leave the host, and once the transfer is over
+    (``block_until_ready``) the array never refers to them again."""
+    return device.platform == "cpu"
+
+
+def _hand_over(piece, keep: bool, tally: Dict[str, int]) -> np.ndarray:
+    """One piece on its way into the restored tree: made to own its
+    bytes when the destination would ``keep`` referring to them, as it
+    is otherwise.  ``tally`` counts both kinds."""
+    piece = np.asarray(piece)
+    out = _owned(piece) if keep else piece
+    kind = "in_place_bytes" if out is piece else "copied_bytes"
+    tally[kind] += int(out.nbytes)
+    return out
 
 
 def _leaf_placements(leaf):
@@ -242,13 +263,25 @@ def _leaf_placements(leaf):
 
 
 def restore_to_target(
-    target: Any, source: ShardSource
+    target: Any, source: ShardSource,
+    tally: Optional[Dict[str, int]] = None,
 ) -> Any:
     """Fill ``target`` (pytree of jax.Array / ShapeDtypeStruct / np arrays)
     from ``source``.  Sharding-bearing targets (live arrays, or
     ShapeDtypeStructs with an explicit sharding — e.g. placeholders for a
     mesh the saving world never had) are rebuilt shard-by-shard on their
-    devices; others become full np arrays."""
+    devices; others become full np arrays.
+
+    A piece that borrows its bytes (a view into the shm arena) is copied
+    only where the restored tree would otherwise keep referring to them:
+    bound for a host leaf, or for a device that may alias host memory
+    (:func:`_may_alias_host`).  Every other piece goes to ``device_put``
+    as it is, so the caller keeps borrowed bytes valid and unwritten
+    until ``jax.block_until_ready`` of the result has returned.
+    ``tally``, when given, receives ``in_place_bytes`` and
+    ``copied_bytes``: what was handed on as it was, and what was copied
+    on the host first."""
+    count = {"in_place_bytes": 0, "copied_bytes": 0}
     flat, treedef = jax.tree_util.tree_flatten(target)
     paths_leaves = tree_flatten_with_path(target)[0]
     out_leaves = []
@@ -265,7 +298,10 @@ def restore_to_target(
                     raise KeyError(
                         f"checkpoint missing data for {name} index {idx}"
                     )
-                arrays.append(jax.device_put(_owned(piece), device))
+                arrays.append(jax.device_put(
+                    _hand_over(piece, _may_alias_host(device), count),
+                    device,
+                ))
             restored = jax.make_array_from_single_device_arrays(
                 gshape, sharding, arrays
             )
@@ -278,5 +314,9 @@ def restore_to_target(
             )
             if piece is None:
                 raise KeyError(f"checkpoint missing data for {name}")
-            out_leaves.append(_owned(piece))
+            out_leaves.append(_hand_over(piece, True, count))
+    if count["copied_bytes"]:
+        audit.record_copy(count["copied_bytes"], "restore_owned_copy")
+    if tally is not None:
+        tally.update(count)
     return tree_unflatten(treedef, out_leaves)

@@ -426,14 +426,23 @@ class SharedMemoryArena:
         """Read the staged state.
 
         ``copy=False`` returns **views into the live shm mapping** — the
-        flash-checkpoint zero-copy fast path.  Lifetime contract: the
-        views are valid only while (a) this arena object stays mapped (no
-        concurrent :meth:`reopen`/:meth:`close` — callers serialize on
-        their arena mutex) and (b) the writer is fenced out (the per-rank
+        flash-checkpoint zero-copy fast path (the saver's streamed
+        persist, the reshard mover, and the warm restore, which hands
+        each view to ``jax.device_put``).  Lifetime contract: the views
+        are valid only while (a) this arena object stays mapped (no
+        concurrent :meth:`reopen`/:meth:`close` — callers hold their
+        arena mutex) and (b) every writer is fenced out (the per-rank
         SharedLock), since a concurrent :meth:`write_state` would rewrite
-        the bytes under them.  Use ``copy=True`` whenever the consumer
-        outlives those guarantees (e.g. the replica push, whose payload
-        is shipped after the lock is released)."""
+        the bytes under them.  The caller takes both BEFORE this call
+        and keeps them until the last consumer is done with the views
+        (for a ``device_put``: until ``block_until_ready`` has returned).
+
+        ``copy=True`` is for a consumer that outlives that hold (the
+        replica push, whose payload is shipped after the lock is
+        released; a ``ShardSource`` returned to the caller of
+        ``load()``).  The copy is itself a read of the live bytes: it
+        needs the same hold for as long as it runs — ``dirty`` is looked
+        at once, before the first tensor."""
         meta = self.metadata()
         if meta is None:
             return None

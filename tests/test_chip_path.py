@@ -199,6 +199,15 @@ class TestChipSmokeContract:
         assert res["ok"] is True
         assert res["device"]["platform"] == "cpu"
 
+    def test_restore_phase_tiny(self, tmp_path, monkeypatch):
+        """The no-alias check at a toy size: on the CPU backend, which may
+        alias a numpy buffer, every piece is copied first and the phase
+        says so; the claim it exists for is made on the chip."""
+        monkeypatch.setattr(chip_smoke, "WORK", str(tmp_path))
+        res = chip_smoke.restore_phase(leaves=2, leaf_mib=1)
+        assert res["ok"] is True
+        assert res["device"]["platform"] == "cpu"
+
     def test_mesh4_phase_tiny_with_kernels_per_shard(self, monkeypatch):
         """fsdp2 x tp2 against one device, the model's kernels steered to
         Pallas in interpret mode (here, in the test: on the CPU the

@@ -583,6 +583,242 @@ class TestEngineAndSaverFastPath:
         np.testing.assert_array_equal(piece, np.full(32, 3.0, np.float32))
 
 
+class _StandInDevice:
+    """All ``restore_to_target`` asks of a destination: its platform."""
+
+    def __init__(self, platform):
+        self.platform = platform
+
+
+class _StandInSharding:
+    def __init__(self, device):
+        self._device = device
+
+    def addressable_devices_indices_map(self, gshape):
+        return {self._device: tuple(slice(None) for _ in gshape)}
+
+
+class _StandInLeaf:
+    """A sharding-bearing target leaf on a device this host has not."""
+
+    def __init__(self, like, device):
+        self.shape, self.dtype = like.shape, like.dtype
+        self.sharding = _StandInSharding(device)
+
+
+class TestRestoreCopiesOnlyWhereItProtects:
+    """ISSUE 24: a borrowed piece (a view into the shm arena) is copied
+    on the host only where the restored tree would keep referring to it —
+    a host leaf, or a device that may alias host memory (the CPU
+    backend).  The decision is read off the destination, by no flag."""
+
+    @pytest.mark.parametrize("leaf_kind", ["device", "host"])
+    @pytest.mark.parametrize("piece_kind", ["borrowed", "owned"])
+    @pytest.mark.parametrize("platform", ["cpu", "tpu"])
+    def test_copy_or_not(self, monkeypatch, platform, piece_kind, leaf_kind):
+        from dlrover_tpu.checkpoint import tree_utils
+
+        backing = np.arange(96, dtype=np.float32)
+        piece = backing[16:80] if piece_kind == "borrowed" else (
+            np.arange(16, 80, dtype=np.float32))
+        assert (piece.base is not None) == (piece_kind == "borrowed")
+        put_on = []
+
+        def device_put(x, device):
+            put_on.append(device)
+            return x  # whatever reaches the device, as it was given
+
+        monkeypatch.setattr(tree_utils.jax, "device_put", device_put)
+        monkeypatch.setattr(
+            tree_utils.jax, "make_array_from_single_device_arrays",
+            lambda shape, sharding, arrays: arrays[0])
+        device = _StandInDevice(platform)
+        target = {"w": _StandInLeaf(piece, device)
+                  if leaf_kind == "device" else np.zeros(64, np.float32)}
+        source = tree_utils.ShardSource()
+        source.add({"['w']|0": piece},
+                   {"['w']|0": {"path": "['w']", "index": [[0, 64]]}})
+        tally = {}
+        audit.enable()
+        try:
+            out = tree_utils.restore_to_target(target, source, tally)["w"]
+            snap = audit.snapshot()
+        finally:
+            audit.disable()
+        copied = piece_kind == "borrowed" and (
+            leaf_kind == "host" or platform == "cpu")
+        np.testing.assert_array_equal(out, np.arange(16, 80, dtype=np.float32))
+        assert np.shares_memory(out, piece) == (not copied)
+        if piece_kind == "borrowed":
+            assert np.shares_memory(out, backing) == (not copied)
+        assert put_on == ([device] if leaf_kind == "device" else [])
+        assert tally == {
+            "in_place_bytes": 0 if copied else piece.nbytes,
+            "copied_bytes": piece.nbytes if copied else 0,
+        }
+        assert tally["in_place_bytes"] + tally["copied_bytes"] == piece.nbytes
+        assert snap["copied_by_site"] == (
+            {"restore_owned_copy": piece.nbytes} if copied else {})
+
+
+class _OtherHolder:
+    """A second holder of a rank's fencing lock — in production the
+    agent's saver, another process.  ``SharedLock`` names its holder by
+    pid, so a second one inside this process needs a name of its own."""
+
+    def __init__(self, job):
+        from dlrover_tpu.checkpoint.engine import ckpt_lock_name
+        from dlrover_tpu.common.multi_process import SharedLock
+
+        self.lock = SharedLock(ckpt_lock_name(job, 0))
+        self.lock._holder = "the-agents-saver"
+
+    def try_acquire(self, timeout=0.1):
+        got = self.lock.acquire(timeout=timeout)
+        if got:
+            self.lock.release()
+        return got
+
+
+class TestWarmRestoreIsFenced:
+    """ISSUE 24: under an agent, ``load(target=...)`` reads the arena as
+    views and holds the rank's ``SharedLock`` from before the views are
+    taken until the restored state is ready."""
+
+    @pytest.fixture
+    def agent_engine(self, tmp_path, monkeypatch):
+        from dlrover_tpu.agent.ckpt_saver import AsyncCheckpointSaver
+        from dlrover_tpu.checkpoint.engine import CheckpointEngine
+        from dlrover_tpu.common.shm import arena_name
+
+        job = f"ckpt-fenced-{os.getpid()}"
+        monkeypatch.setenv("DLROVER_TPU_JOB_NAME", job)
+        monkeypatch.setenv("DLROVER_TPU_PROCESS_ID", "0")
+        monkeypatch.setenv("DLROVER_TPU_NUM_PROCESSES", "1")
+        saver = AsyncCheckpointSaver(job, nproc_per_node=1)  # the servers
+        eng = CheckpointEngine(str(tmp_path), job_name=job)
+        try:
+            assert eng.agent_mode
+            yield eng, job
+        finally:
+            eng.close()
+            saver.stop()
+            try:
+                os.unlink(f"/dev/shm/{arena_name(job, 0)}")
+            except FileNotFoundError:
+                pass
+
+    @staticmethod
+    def _state(value):
+        import jax.numpy as jnp
+
+        return {"a": jnp.full(512, value, jnp.float32),
+                "b": jnp.full((8, 64), value, jnp.float32)}
+
+    @pytest.mark.parametrize("fault", [None, KeyError, RuntimeError])
+    def test_lock_held_while_pieces_are_put(self, agent_engine, monkeypatch,
+                                            fault):
+        import time as _time
+
+        from dlrover_tpu.checkpoint import tree_utils
+
+        eng, job = agent_engine
+        other = _OtherHolder(job)
+        eng.save_to_memory(5, self._state(1.0))
+        assert other.try_acquire()  # nobody holds it between calls
+        during = []
+        real_put = tree_utils.jax.device_put
+
+        def slow_put(x, device):
+            _time.sleep(0.05)
+            during.append(other.try_acquire(timeout=0.1))
+            if fault is not None:
+                raise fault("injected into restore_to_target")
+            return real_put(x, device)
+
+        monkeypatch.setattr(tree_utils.jax, "device_put", slow_put)
+        target = self._state(0.0)
+        if fault is RuntimeError:
+            with pytest.raises(RuntimeError):
+                eng.load(target=target)
+        else:
+            got = eng.load(target=target)
+            # a KeyError is "shm incomplete": the ladder goes to storage,
+            # which holds nothing
+            assert (got is None) == (fault is KeyError)
+            if got is not None:
+                state, meta = got
+                assert meta["step"] == 5
+                np.testing.assert_array_equal(
+                    np.asarray(state["b"]), np.full((8, 64), 1.0, np.float32))
+        assert during == [False] * (2 if fault is None else 1)
+        assert other.try_acquire()  # released, also on the way out of a raise
+        assert eng._arena_mu.acquire(timeout=1.0)
+        eng._arena_mu.release()
+
+    def test_write_during_load_lands_after_it(self, agent_engine,
+                                              monkeypatch):
+        """A writer that honours the lock (the saver's
+        ``seed_from_replicas``) cannot tear a restore that is reading
+        views: its ``write_state`` waits for the load, and what was
+        restored is the earlier step, whole."""
+        import threading
+        import time as _time
+
+        from dlrover_tpu.checkpoint import tree_utils
+        from dlrover_tpu.common.shm import arena_name
+
+        eng, job = agent_engine
+        other = _OtherHolder(job)
+        eng.save_to_memory(5, self._state(1.0))
+        staged, extra = eng._arena.read_state(copy=True)
+        newer = {k: np.full_like(v, 9.0) for k, v in staged.items()}
+        loading, written = threading.Event(), threading.Event()
+        seen_written = []
+
+        def writer():
+            assert loading.wait(timeout=30)
+            arena = SharedMemoryArena(arena_name(job, 0))
+            assert other.lock.acquire(timeout=30)
+            try:
+                arena.write_state(newer, extra=dict(extra, step=6))
+                written.set()
+            finally:
+                other.lock.release()
+                arena.close()
+
+        real_put = tree_utils.jax.device_put
+
+        def slow_put(x, device):
+            loading.set()
+            _time.sleep(0.3)  # the writer is waiting on the lock by now
+            seen_written.append(written.is_set())
+            return real_put(x, device)
+
+        monkeypatch.setattr(tree_utils.jax, "device_put", slow_put)
+        th = threading.Thread(target=writer)
+        th.start()
+        try:
+            state, meta = eng.load(target=self._state(0.0))
+        finally:
+            th.join(timeout=30)
+        assert not th.is_alive() and written.is_set()
+        assert seen_written == [False, False]
+        assert meta["step"] == 5
+        for k, shape in (("a", (512,)), ("b", (8, 64))):
+            np.testing.assert_array_equal(
+                np.asarray(state[k]), np.full(shape, 1.0, np.float32))
+        # and the write did land: the arena holds the newer step now
+        monkeypatch.setattr(tree_utils.jax, "device_put", real_put)
+        state6, meta6 = eng.load(target=self._state(0.0))
+        assert meta6["step"] == 6
+        np.testing.assert_array_equal(
+            np.asarray(state6["a"]), np.full(512, 9.0, np.float32))
+        # the first restore still holds the earlier step's values
+        np.testing.assert_array_equal(
+            np.asarray(state["a"]), np.full(512, 1.0, np.float32))
+
+
 class TestSpeedMonitorStall:
     def test_ckpt_stall_folds_into_goodput(self):
         import time as _time
