@@ -10,9 +10,13 @@ here and edits none.  An adapter gives the harness:
   object, or ``ValueError`` for a key it does not know — never a silent drop;
 - ``init_fn(mc)``, ``loss_fn(mc)``: what ``accelerate()`` is given;
 - ``hidden_and_loss(params, tokens, mc)``: the system's forward as the step
-  runs it, for the comparison with the plain reference;
+  runs it, for the comparison with the plain reference: ``(hidden, loss)``
+  for a block like this one; ``(hidden, loss, extra)`` for one that makes
+  discrete choices (a routed block), with the three limits that judge them
+  as constants of that adapter (the contract: ``benchmark/run.py``);
 - ``grad_leaves(params)`` / ``with_leaves``: the few parameter leaves whose
-  gradients the comparison reads;
+  gradients the comparison reads (a routed adapter adds its router and
+  expert weights);
 - ``model_flops_per_token`` and ``flash_least_seconds``: the yardstick's
   count of what this architecture's algorithm needs.
 """

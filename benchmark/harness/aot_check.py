@@ -1,9 +1,10 @@
 #!/usr/bin/env python3
 """Compile a cell's real step for a DESCRIBED ``v5e:2x2`` — no chip attached
 (``on-chip-measurement`` guide, section 2, rehearsal 3) — and print XLA's
-memory analysis and what the program contains, then the same for the two
+memory analysis and what the program contains, then the same for the
 programs of the comparison with the reference (system forward + backward;
-reference forward + ``jax.grad``), which must fit BESIDE the training state.
+reference forward + ``jax.grad``; for a routed block the reference's forward
+choosing for itself), which must fit BESIDE the training state.
 Run it here, on the CPU,
 before a cell's first chip run: what the TPU compiler refuses (a Mosaic
 block shape, a program that does not fit HBM) costs no chip time this way.
@@ -80,13 +81,16 @@ def main(argv) -> int:
         (comparison_sequences(cell), cell["traffic_data"]["seq_len"] + 1),
         "int32",
         sharding=job.batch_sharding["tokens"])
-    system, against_reference = comparison_programs(cell, mc)
+    system, against_reference, independent = comparison_programs(cell, mc)
     with jax.set_mesh(job.mesh):
-        sys_c = jax.jit(system).lower(params, toks).compile()
-        outs = jax.eval_shape(system, params, toks)
-        ref_c = jax.jit(against_reference).lower(
-            params, toks, *outs).compile()
-    for name, c in (("system", sys_c), ("against_reference", ref_c)):
+        compiled = {"system": jax.jit(system).lower(params, toks).compile()}
+        loss, hidden, grads, extra = jax.eval_shape(system, params, toks)
+        compiled["against_reference"] = jax.jit(against_reference).lower(
+            params, toks, loss, hidden, grads, extra).compile()
+        if extra is not None:
+            compiled["independent"] = jax.jit(independent).lower(
+                params, toks, loss, hidden, extra).compile()
+    for name, c in compiled.items():
         m = c.memory_analysis()
         live = (m.argument_size_in_bytes + m.temp_size_in_bytes
                 + m.output_size_in_bytes + opt_bytes)

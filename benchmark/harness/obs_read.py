@@ -11,7 +11,9 @@ Where the records are:
   flight-<process>-<pid>.jsonl``, one per process (``agent-n0``,
   ``worker-r0-i0``, ``worker-r0-i1``), each line written as its span
   ended, so that they are whole although every process was SIGKILLed.
-  The directory is removed when this process exits.
+  A traced run's directory is removed when this process exits (the
+  readers run after the runner has returned), an untraced run's by the
+  runner itself.
 
 A program that records no such span (the parent of the PR that added them)
 yields no records, and every reader built on this returns None.
@@ -31,18 +33,24 @@ from typing import Dict, Iterable, List, Optional
 _removed_at_exit = set()
 
 
-def _job_dirs() -> List[str]:
-    root = os.path.join(tempfile.gettempdir(), "dlrover_tpu_obs")
+def _root() -> str:
+    return os.path.join(tempfile.gettempdir(), "dlrover_tpu_obs")
+
+
+def job_dirs() -> List[str]:
+    """The journal directories of the job this process launched."""
     job = f"bench-{os.getpid()}"
     # the separator keeps bench-12 from matching bench-123's
-    found = [d for d in glob.glob(os.path.join(root, job + "*"))
-             if os.path.basename(d) == job
-             or os.path.basename(d).startswith(job + "-")]
-    for d in found:
-        if d not in _removed_at_exit:
-            _removed_at_exit.add(d)
-            atexit.register(_remove, d, root)
-    return found
+    return [d for d in glob.glob(os.path.join(_root(), job + "*"))
+            if os.path.basename(d) == job
+            or os.path.basename(d).startswith(job + "-")]
+
+
+def remove_job_dirs() -> None:
+    """What a run that nothing will read (an untraced one) calls once its
+    job has ended."""
+    for d in job_dirs():
+        _remove(d, _root())
 
 
 def _remove(path: str, root: str) -> None:
@@ -59,7 +67,11 @@ def records(spans: dict) -> List[dict]:
     over no stamps at all (no run happened)."""
     if not spans:
         return []
-    dirs = _job_dirs()
+    dirs = job_dirs()
+    for d in dirs:  # read now, by every reader that asks; removed at exit
+        if d not in _removed_at_exit:
+            _removed_at_exit.add(d)
+            atexit.register(_remove, d, _root())
     if dirs:
         # the program's own reader of its files: a line cut by the kill
         # is skipped, the last meta line names the process

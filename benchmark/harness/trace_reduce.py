@@ -45,8 +45,15 @@ COLLECTIVE_PREFIXES = (
     "collective-permute", "collective-broadcast", "send", "recv",
     "async-collective",  # -start/-done wrappers XLA:TPU puts around them
 )
-#: spans the benchmark's own loop writes (jax.profiler.TraceAnnotation)
+#: spans the benchmark's own loop writes (jax.profiler.TraceAnnotation);
+#: they alone set the traced window
 HOST_SPANS = ("batch_build", "dispatch", "loss_sync", "ckpt_save")
+#: The program's own spans (``obs.span``, which enters a TraceAnnotation of
+#: the same name where JAX is loaded) are named ``<category>.<what>``; the
+#: categories are those of the README's "Observability" table of training
+#: spans.  They label idle gaps and take no part in any other number.
+PROGRAM_SPAN_PREFIXES = ("ckpt.", "agent.", "bootstrap.", "accelerate.",
+                         "trainer.")
 PALLAS_TARGET = 'custom_call_target="tpu_custom_call"'
 
 
@@ -68,10 +75,15 @@ def split_instruction(text: str) -> Tuple[str, str]:
     return head.lstrip("%"), tag
 
 
+def is_program_span(name: str) -> bool:
+    return name.startswith(PROGRAM_SPAN_PREFIXES)
+
+
 def load_xplane(path: str, keep_host: Sequence[str] = HOST_SPANS) -> dict:
     """Plain data of the device planes' op lines and of the host events
-    named in ``keep_host`` (everything else on the host is dropped: the
-    runtime's own events are many and nothing reads them)."""
+    named in ``keep_host`` or by the program's own spans (everything else
+    on the host is dropped: the runtime's own events are many and nothing
+    reads them)."""
     from jax.profiler import ProfileData
 
     planes = []
@@ -87,7 +99,7 @@ def load_xplane(path: str, keep_host: Sequence[str] = HOST_SPANS) -> dict:
             for ev in line.events:
                 if is_dev:
                     name, tag = split_instruction(ev.name)
-                elif ev.name in keep_host:
+                elif ev.name in keep_host or is_program_span(ev.name):
                     name, tag = ev.name, ""
                 else:
                     continue
@@ -158,10 +170,25 @@ def op_events(plane: dict) -> List[list]:
             for ev in line["events"]]
 
 
-def host_spans(trace: dict) -> List[list]:
+def host_spans(trace: dict, program: bool = False) -> List[list]:
+    """The loop's own spans; with ``program`` the program's beside them."""
     return [ev for p in trace["planes"] if p["name"].startswith("/host:")
             for line in p["lines"] for ev in line["events"]
-            if ev[0] in HOST_SPANS]
+            if ev[0] in HOST_SPANS or (program and is_program_span(ev[0]))]
+
+
+def span_of_gap(gap: Interval, spans: List[list]) -> str:
+    """The innermost span that covers most of an idle gap: of the spans
+    that hold more than half of it the shortest (a span nested in another
+    is the shorter one); where none does, the one that holds most."""
+    held = [(overlap(gap, (sp[1], sp[1] + sp[2])), sp) for sp in spans]
+    held = [(ov, sp) for ov, sp in held if ov > 0]
+    if not held:
+        return "no_span"
+    most = [sp for ov, sp in held if ov > (gap[1] - gap[0]) / 2]
+    if most:
+        return min(most, key=lambda sp: sp[2])[0]
+    return max(held, key=lambda h: h[0])[1][0]
 
 
 def is_collective(name: str) -> bool:
@@ -233,7 +260,7 @@ def reduce_trace(trace: dict) -> dict:
     window_s = (win[1] - win[0]) * 1e-9
     if window_s <= 0:
         return {}
-    spans = host_spans(trace)
+    spans = host_spans(trace, program=True)
     busy_s = coll_s = exposed_s = 0.0
     kernel_s: Dict[str, float] = {}
     op_self: Dict[str, float] = {}
@@ -261,12 +288,8 @@ def reduce_trace(trace: dict) -> dict:
             label = k or f"{ev[0]} {ev[3]}".strip()
             op_self[label] = op_self.get(label, 0.0) + self_s
         for gap in subtract([win], busy):
-            best, best_ov = "no_span", 0.0
-            for sp in spans:
-                ov = overlap(gap, (sp[1], sp[1] + sp[2]))
-                if ov > best_ov:
-                    best, best_ov = sp[0], ov
-            gap_s[best] = gap_s.get(best, 0.0) + (gap[1] - gap[0]) * 1e-9
+            label = span_of_gap(gap, spans)
+            gap_s[label] = gap_s.get(label, 0.0) + (gap[1] - gap[0]) * 1e-9
     n = len(planes)
     avg = lambda d: {k: v / n for k, v in d.items()}  # noqa: E731
     return {
@@ -284,7 +307,8 @@ def reduce_trace(trace: dict) -> dict:
 
 def breakdown(reduced: dict, k: int = 10) -> dict:
     """The ``breakdown`` of a traced result line: the ``k`` device
-    operations with most self time and the idle time by host span."""
+    operations with most self time and the idle time by host span (the
+    loop's own and the program's)."""
     top = lambda d: [  # noqa: E731
         [name, sec] for name, sec in sorted(
             d.items(), key=lambda kv: -kv[1])[:k]]

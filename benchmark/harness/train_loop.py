@@ -9,6 +9,7 @@ from __future__ import annotations
 import time
 
 import jax
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from benchmark.harness import trace_reduce
@@ -25,6 +26,9 @@ class TrainSession:
         #: host-clock stamps, seconds; lists are one entry per step or save
         self.spans = {"input_wait_s": [], "step_s": [], "save_stall_s": []}
         self.losses = []
+        #: what the newest step returned beside the loss, still on the
+        #: device: a runner fetches it after its window (``step_metrics``)
+        self.last_metrics = {}
         self.step_no = 0
         self.job = self.model_config = self.state = self._it = None
 
@@ -98,6 +102,7 @@ class TrainSession:
         with TraceAnnotation("loss_sync"):
             loss = float(metrics["loss"])
         t2 = time.monotonic()
+        self.last_metrics = metrics
         self.step_no += 1
         if record:
             self.spans["input_wait_s"].append(t1 - t0)
@@ -115,6 +120,15 @@ class TrainSession:
         if record:
             self.spans["save_stall_s"].append(stall)
         return stall
+
+    def step_metrics(self) -> dict:
+        """The counters the jitted step itself computed in its newest call
+        (``grad_norm`` today; tokens per expert, dropped tokens where a
+        later step returns them), as plain numbers and lists.  Fetches from
+        the device: call it outside every timed span."""
+        return {k: np.asarray(v).tolist()
+                for k, v in jax.device_get(self.last_metrics).items()
+                if k != "loss"}
 
     @property
     def tokens_per_step(self) -> int:

@@ -11,6 +11,32 @@ metric is read by ``layer_metrics/<name>.py``.  Adding a cell, a
 configuration, a traffic mix or a per-layer metric adds files and entries
 and edits none.
 
+What decides ``correct`` of a steady cell belongs to the configuration too
+(``harness/model.py::check_against_reference``).  Its adapter's
+``hidden_and_loss(params, tokens, mc)`` returns ``(hidden, loss)``, and the
+system is held to its reference's ``hidden_and_loss(params, tokens, cfg)``
+in hidden states, loss and the gradients of the adapter's ``grad_leaves``,
+at the three standing tolerances of ``harness/model.py``.  A block that
+makes DISCRETE CHOICES (a routed one: which experts) returns ``(hidden,
+loss, extra)`` instead, ``extra = {"choices": {name: ints [..., k]},
+"scalars": {name: scalar}}``: what the system chose, and the further scalars
+of its loss (auxiliary and z-losses).  Its reference then takes
+``given=None``: with ``given`` set to the system's ``choices`` it computes
+THOSE in place of its own selection (weighted by its own float32
+probabilities of them), and either way it returns ``extra`` with the
+``choices`` it would have made itself, its ``scalars``, and ``probs``
+(``{name: float32 [..., n]}``), the probabilities its choices were made
+from.  The harness then judges (i) hidden states, loss and every gradient
+leaf under the system's choices at the SAME standing tolerances, which no
+adapter can replace; (ii) the choices: the share of tokens whose set differs
+from the reference's own and how far under the reference's weakest pick the
+probability of anything the system took lies, against the adapter's
+``CHOICE_DIFF_SHARE_TOL_PER_SQRT_LAYER`` and
+``CHOICE_PROB_GAP_TOL_PER_SQRT_LAYER``; (iii) each further scalar within the
+adapter's ``SCALAR_REL_TOL``; and it reports, and judges nothing by, the
+distances to the reference choosing for itself (``*_independent``): rounding
+flips a near tie, and a token with another expert is far away.
+
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` and, traced,
 ``breakdown``).  Without a TPU, with an unknown ``device_kind`` or with

@@ -8,8 +8,9 @@ steps after its save, reads ``resume_s`` off the restarted worker's first
 completed step, lets it run the window (whole periods of steps and one
 memory save, which feed ``correct``, the trace and the printed stall and
 goodput: both bounded metrics of this cell are over before it opens), then
-ends the whole process tree, checks the persisted checkpoint with ``checkpoint.fsck`` and removes
-what the run left in ``/dev/shm`` and on disk.
+ends the whole process tree, checks the persisted checkpoint with
+``checkpoint.fsck`` and removes what the run left in ``/dev/shm`` and on disk
+(the job's journal directory too; a traced run's after its readers).
 """
 
 from __future__ import annotations
@@ -29,7 +30,7 @@ import tempfile
 import threading
 import time
 
-from benchmark.harness import common, trace_reduce
+from benchmark.harness import common, obs_read, trace_reduce
 
 #: seconds to wait for the next expected line before the run is given up
 #: (the first run of a checkout compiles inside these)
@@ -188,6 +189,10 @@ def run(cell: dict, args, t_start: float) -> dict:
         for seg in _arenas(job):
             os.unlink(seg)
         shutil.rmtree(sock_dir, ignore_errors=True)
+        if not args.trace:
+            # a traced run's journals are read after this returns
+            # (layer_metrics/), and go when the process exits
+            obs_read.remove_job_dirs()
     # -- outside the window, the chip free again ---------------------------
     t0 = time.monotonic()
     fsck = subprocess.run(
@@ -201,8 +206,10 @@ def run(cell: dict, args, t_start: float) -> dict:
                  f"{fsck.stdout.strip().splitlines()[-1:]}")
     out["checks"]["fsck rc 0 on the persisted step"] = fsck.returncode == 0
     leftovers = _arenas(job) + [
-        p.pid for p in _marked_pids(mark)]
-    out["checks"]["no arena or process outlives the run"] = not leftovers
+        p.pid for p in _marked_pids(mark)] + (
+        [] if args.trace else obs_read.job_dirs())
+    out["checks"]["no arena, process or journal outlives the run"] = (
+        not leftovers)
     for what, ok in out["checks"].items():
         notes.append(f"CHECK {'ok  ' if ok else 'FAIL'} {what}")
     out["correct"] = all(out.pop("checks").values())

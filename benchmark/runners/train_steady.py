@@ -83,6 +83,9 @@ def run(cell: dict, args, t_start: float) -> dict:
         "memory_peak_bytes": device["memory_peak_bytes"],
         "compiles_in_window": compiles.count,
         "compiled_memory": sess.job.memory,
+        # what the last step returned beside its loss, for a reader of a
+        # counter the jitted step computes itself
+        "step_metrics": sess.step_metrics(),
     }
     notes = [
         f"DEVICE {summary}",

@@ -46,6 +46,17 @@ def test_records_come_from_this_runs_directory_only(journal):
     assert obs_read.records({}) == []  # no run, nothing read
 
 
+def test_an_untraced_run_removes_its_directory_itself(journal):
+    """What the elastic runner's ``finally`` calls, and what its check
+    "no arena, process or journal outlives the run" then looks for."""
+    assert obs_read.job_dirs() == [str(journal)]
+    obs_read.remove_job_dirs()
+    assert obs_read.job_dirs() == []
+    # another run's directory, whose pid only starts like ours, stays
+    assert os.listdir(journal.parent) == [
+        f"bench-{os.getpid()}7-ffff0000"]
+
+
 def test_a_line_cut_by_the_kill_is_skipped(journal):
     with open(journal / "flight-worker-r0-i0-101.jsonl", "a") as f:
         f.write('{"k": "span", "name": "ckpt.save", "ts": 1, "du')

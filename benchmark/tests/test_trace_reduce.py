@@ -192,3 +192,49 @@ def test_recorded_v5e_trace_of_one_sharded_step():
     assert r["kernel_s"]["flash_fwd"] == pytest.approx(0.071204469)
     assert sum(r["idle_gaps"].values()) == pytest.approx(
         r["window_s"] - r["busy_s"])
+
+
+def test_a_gap_goes_to_the_innermost_span_that_covers_most_of_it():
+    outer, inner = ["ckpt_save", 0.0, 100.0, ""], ["ckpt.save", 1.0, 98.0, ""]
+    d2h, write = ["ckpt.save.d2h", 2.0, 80.0, ""], [
+        "ckpt.save.arena_write", 82.0, 16.0, ""]
+    spans = [outer, inner, d2h, write, ["loss_sync", 100.0, 50.0, ""]]
+    assert tr.span_of_gap((0.0, 100.0), spans) == "ckpt.save.d2h"
+    assert tr.span_of_gap((80.0, 100.0), spans) == "ckpt.save.arena_write"
+    # nothing nested holds more than half of it: the innermost that does
+    assert tr.span_of_gap((68.0, 96.0), spans) == "ckpt.save"
+    # no span holds more than half: the one that holds most, as before
+    assert tr.span_of_gap((90.0, 300.0), spans) == "loss_sync"
+    assert tr.span_of_gap((500.0, 600.0), spans) == "no_span"
+    # the window is the loop's own spans': a program span outside them
+    # (the launcher's per-step report) does not stretch it
+    t = _trace([["fusion.1", 0.0, 50.0, ""]],
+               [["dispatch", 0.0, 100.0, ""],
+                ["trainer.report_step", 100.0, 900.0, ""]])
+    assert tr.window_of(t) == (0.0, 100.0)
+
+
+def test_recorded_v5e_trace_of_one_elastic_period():
+    """One period of ``mistral7b-l1.elastic`` on one v5e, four steps and a
+    memory save (my chip run, PR 25, ``--dump-trace``): the program's own
+    spans lie inside the loop's ``ckpt_save``, the idle gap of the save goes
+    to the device-to-host copy, and no other number knows of them."""
+    trace = _recorded("l1_elastic_1period.json.gz")
+    names = {ev[0] for ev in tr.host_spans(trace, program=True)}
+    assert {"ckpt_save", "ckpt.save", "ckpt.save.d2h",
+            "ckpt.save.arena_write", "trainer.report_step"} <= names
+    r = tr.reduce_trace(trace)
+    assert r["window_s"] == pytest.approx(7.403581124)
+    assert r["busy_s"] == pytest.approx(1.427133909)
+    assert tr.breakdown(r)["idle_gaps"][0] == [
+        "ckpt.save.d2h", pytest.approx(5.903127071)]
+    assert sum(r["idle_gaps"].values()) == pytest.approx(
+        r["window_s"] - r["busy_s"])
+    loop_only = {"planes": [
+        dict(p, lines=[dict(ln, events=[
+            ev for ev in ln["events"] if not tr.is_program_span(ev[0])])
+            for ln in p["lines"]]) for p in trace["planes"]]}
+    before = tr.reduce_trace(loop_only)
+    assert before["idle_gaps"]["ckpt_save"] == pytest.approx(5.903127071)
+    assert {k: v for k, v in before.items() if k != "idle_gaps"} == {
+        k: v for k, v in r.items() if k != "idle_gaps"}
