@@ -28,6 +28,7 @@ from dlrover_tpu.ops.cross_entropy import (
     softmax_cross_entropy,
 )
 from dlrover_tpu.ops.flash_attention import flash_attention
+from dlrover_tpu.ops.grouped_matmul import grouped_matmul_ragged
 from dlrover_tpu.ops.rmsnorm import rmsnorm
 
 
@@ -47,7 +48,19 @@ class LlamaConfig:
     num_experts: int = 0
     top_k: int = 2
     moe_every: int = 2
-    capacity_factor: float = 1.25
+    # None: dropless (every chosen pair is computed).  A number caps each
+    # expert at round(factor * tokens * top_k / experts) pairs; the pairs
+    # past it keep their rows in the grouped matmuls and lose their weight.
+    capacity_factor: Optional[float] = None
+    # The router as published models state it: top-k weights renormalised
+    # to sum to one or left as the softmax gave them (OLMoE: left); the
+    # load-balance term counting each token's first choice or all top_k
+    # (the HF ``load_balancing_loss_func``).
+    norm_topk_prob: bool = True
+    balance_all_k: bool = False
+    # RMSNorm (gains ``q_norm``/``k_norm``) over the WHOLE q and k
+    # projections, before the split into heads and before RoPE (OLMoE).
+    qk_norm: bool = False
     # Sliding-window attention (>0: each position attends the last
     # `sliding_window` positions only — Mistral-style long-context;
     # flash path only, kernels skip out-of-window blocks).
@@ -123,6 +136,9 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
             "wo": _dense(k[3], cfg.n_head * hd, cfg.d_model),
             "ln2": jnp.ones((cfg.d_model,), jnp.float32),
         }
+        if cfg.qk_norm:
+            layer["q_norm"] = jnp.ones((cfg.n_head * hd,), jnp.float32)
+            layer["k_norm"] = jnp.ones((cfg.n_kv_head * hd,), jnp.float32)
         if cfg.is_moe_layer(i):
             layer["moe"] = {
                 "router": _dense(k[4], cfg.d_model, cfg.num_experts),
@@ -159,6 +175,9 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
             "wo": ("heads", "embed"),
             "ln2": (None,),
         }
+        if cfg.qk_norm:
+            ax["q_norm"] = (None,)
+            ax["k_norm"] = (None,)
         if has_moe:
             ax["moe"] = {
                 "router": (None, None),
@@ -202,6 +221,18 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     ).astype(x.dtype)
 
 
+def qk_normed(q, k, layer, cfg: "LlamaConfig"):
+    """q [..., H*D], k [..., KV*D] as projected -> the same, RMS-normalised
+    over the whole projection width where ``cfg.qk_norm`` (the kernel
+    computes in float32 and, under a mesh, takes the normalised dim whole
+    in every shard, so the mean is over all heads under ``tp`` too).  The
+    training block and the KV-cache decoder both call it."""
+    if not cfg.qk_norm:
+        return q, k
+    return (rmsnorm(q, layer["q_norm"], eps=cfg.rms_eps),
+            rmsnorm(k, layer["k_norm"], eps=cfg.rms_eps))
+
+
 def _fp8_proj(x, w, st, dt):
     """[..., K] @ [K, N] through ops.fp8.fp8_dot (delayed scaling).
     Returns (out [..., N] in compute dtype, new Fp8State)."""
@@ -232,15 +263,14 @@ def _attention(
         q, new_fp8["wq"] = _fp8_proj(x, layer["wq"], fp8_layer["wq"], dt)
         k, new_fp8["wk"] = _fp8_proj(x, layer["wk"], fp8_layer["wk"], dt)
         v, new_fp8["wv"] = _fp8_proj(x, layer["wv"], fp8_layer["wv"], dt)
-        q = q.reshape(B, S, H, D)
-        k = k.reshape(B, S, KV, D)
-        v = v.reshape(B, S, KV, D)
     else:
-        q = (x @ layer["wq"].astype(dt)).reshape(B, S, H, D)
-        k = (x @ layer["wk"].astype(dt)).reshape(B, S, KV, D)
-        v = (x @ layer["wv"].astype(dt)).reshape(B, S, KV, D)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+        q = x @ layer["wq"].astype(dt)
+        k = x @ layer["wk"].astype(dt)
+        v = x @ layer["wv"].astype(dt)
+    q, k = qk_normed(q, k, layer, cfg)
+    q = _rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
+    k = _rope(k.reshape(B, S, KV, D), positions, cfg.rope_theta)
+    v = v.reshape(B, S, KV, D)
     if KV != H and attn_impl in ("ring", "ulysses") and mesh is not None:
         # Ring/Ulysses shard over heads and need the full head count; the
         # flash path handles GQA in-kernel (no materialized repeat).
@@ -308,103 +338,181 @@ def _swiglu(x, mlp, dt, fp8_mlp=None):
     return (jax.nn.silu(g) * u) @ mlp["w_down"].astype(dt), None
 
 
+@jax.custom_vjp
+def _dispatch_rows(tokens, order, inverse):
+    """``tokens`` [N, C] -> the rows of the N*K (token, k) pairs in sorted
+    order, [N*K, C]: pair ``p = n*K + k`` sits at ``inverse[p]``, position
+    ``i`` holds pair ``order[i]``.  The pairs are a permutation, so the
+    transpose is a gather by ``inverse`` and a sum over k, never a
+    scatter-add."""
+    return tokens[order // (order.shape[0] // tokens.shape[0])]
+
+
+def _dispatch_rows_fwd(tokens, order, inverse):
+    return _dispatch_rows(tokens, order, inverse), (inverse, tokens.shape[0])
+
+
+def _dispatch_rows_bwd(res, g):
+    inverse, n = res
+    per_pair = g[inverse].reshape(n, -1, g.shape[-1])
+    return (jnp.sum(per_pair, axis=1, dtype=jnp.float32).astype(g.dtype),
+            None, None)
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(rows, perm, inverse):
+    """``rows[perm]`` for a permutation ``perm`` with inverse ``inverse``:
+    the transpose is ``g[inverse]``, a gather again."""
+    return rows[perm]
+
+
+_permute_rows.defvjp(
+    lambda rows, perm, inverse: (rows[perm], inverse),
+    lambda inverse, g: (g[inverse], None, None))
+
+
+@functools.partial(
+    jax.checkpoint, static_argnums=(5,),
+    policy=jax.checkpoint_policies.save_only_these_names("moe_gate_up"))
+def _expert_ffn(rows, wg, wi, wo, group_sizes, dt):
+    """The three grouped matmuls over the sorted pair rows.  Kept for the
+    backward pass: the rows and the two ``[N*K, F]`` products; recomputed
+    there: the bf16 casts of the weights and ``silu(g) * u`` (elementwise
+    passes, in place of 0.8 GB of bf16 weights and a third ``[N*K, F]``
+    buffer at OLMoE's widths)."""
+    g = checkpoint_name(
+        grouped_matmul_ragged(rows, wg.astype(dt), group_sizes),
+        "moe_gate_up")
+    u = checkpoint_name(
+        grouped_matmul_ragged(rows, wi.astype(dt), group_sizes),
+        "moe_gate_up")
+    return grouped_matmul_ragged(
+        jax.nn.silu(g) * u, wo.astype(dt), group_sizes)
+
+
 def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
                 valid=None, fp8_moe=None):
-    """Expert-parallel SwiGLU MoE (dense capacity dispatch, see
-    ``parallel.moe`` for the mechanism).  ``capacity`` overrides the
-    config-derived expert capacity — decode passes a no-drop value,
-    since at T=1 the rounded capacity is so coarse that two batch rows
-    landing on one expert would silently drop the second.
+    """Routed SwiGLU block, sorted and ragged: the ``N*K`` (token, expert)
+    pairs are sorted by expert (stable, so a pair's rank inside its
+    expert's group follows the token order), the token rows gathered in
+    that order, ``wg``/``wi``/``wo`` applied as grouped matmuls over the
+    ragged groups (``ops.grouped_matmul``), the rows brought back by the
+    inverse permutation and summed over k with the router's weights.
+    Nothing of size ``N x E x capacity`` or ``N*K x E`` is built, forward
+    or backward, and no pair is dropped unless a capacity is asked for.
+
+    Returns ``(out [B, S, C], stats)`` with ``stats`` the layer's
+    ``moe_aux`` (load-balance term ``E * sum_e f_e * P_e``: ``f_e`` the
+    share of tokens whose first choice is ``e``, or with
+    ``cfg.balance_all_k`` the mean over tokens and the k picks; ``P_e`` the
+    mean router probability), ``moe_z`` (router z-loss, the mean over
+    tokens of ``logsumexp(logits)**2``), ``experts`` (int32 ``[B, S, K]``,
+    what the one ``top_k`` took) and ``tokens_per_expert`` (int32 ``[E]``,
+    pairs routed to each expert before any capacity).
+
+    ``capacity`` (default: from ``cfg.capacity_factor``, None = dropless)
+    survives only as a mask: a pair whose rank in its group is
+    ``>= capacity`` keeps its row and loses its weight.  Decode passes a
+    no-drop value, which overrides a configured factor.
 
     ``valid`` [B, S] bool marks real tokens in packed-sequence training:
-    pad positions are excluded from expert routing — they take no
-    capacity slots (the position-ordered cumsum would otherwise let a
-    pad displace a real token that follows it in the flattened order)
-    and contribute nothing to the load-balance aux statistics.
+    pads sort behind every expert's group, so they take no rank and no
+    capacity, their weight is zero and they count in no statistic (their
+    rows ride at the end of the last group, computed and unused).
 
-    ``fp8_moe`` (a dict of ``ops.fp8.Fp8State`` for wg/wi/wo) routes the
-    expert projections — the bulk of a MoE model's FLOPs — through the
-    batched e4m3/e5m2 path (``ops.fp8.fp8_batched_dot``); the router and
-    the dispatch/combine einsums stay in fp32/compute dtype (they are
-    permutation-weighted sums, not GEMM hot spots).  Returns a third
-    element (the new fp8 dict) when set — the reference rewrites every
-    eligible expert linear the same way
-    (``atorch/auto/opt_lib/amp_optimization.py:396``)."""
+    ``fp8_moe`` (``ops.fp8.Fp8State`` for wg/wi/wo) routes the three
+    grouped matmuls through ``ops.fp8.fp8_ragged_dot``; the router, the
+    permutation and the combine stay in fp32/compute dtype.  The new
+    states come back as ``stats["fp8"]``."""
     B, S, C = x.shape
     E, K = cfg.num_experts, cfg.top_k
     N = B * S
     dt = cfg.dtype
+    f32 = jnp.float32
     tokens = x.reshape(N, C)
-    logits = tokens.astype(jnp.float32) @ moe["router"]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, -1, keepdims=True), 1e-9
-    )
     valid_n = None if valid is None else valid.reshape(N)
-    if capacity is None:
+    if capacity is None and cfg.capacity_factor is not None:
         capacity = int(max(1, round(cfg.capacity_factor * N * K / E)))
-    onehot_e = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)
-    if valid_n is not None:
-        # Pads claim no expert slot: drop them before the capacity
-        # cumsum so they can't displace later real tokens.
-        onehot_e = onehot_e * valid_n[:, None, None].astype(jnp.int32)
-    # Rank within the expert: the -1 must come AFTER the sum over E —
-    # inside it, every non-selected expert column contributes a spurious
-    # -1 (pos = rank - (E-1)), and rank-0 assignments land on pos -1
-    # where one_hot() is all-zero: each expert's first token silently
-    # vanished from the dispatch.
-    pos = (jnp.cumsum(onehot_e.reshape(N * K, E), axis=0)
-           * onehot_e.reshape(N * K, E)).reshape(N, K, E).sum(-1) - 1
-    keep = pos < capacity
-    if valid_n is not None:
-        keep = keep & valid_n[:, None]
-    dispatch = (
-        jax.nn.one_hot(gate_idx, E, dtype=dt)[..., None]
-        * jax.nn.one_hot(pos, capacity, dtype=dt)[..., None, :]
-        * keep[..., None, None].astype(dt)
-    )  # [N, K, E, C]
-    xin = jnp.einsum("nd,nkec->ecd", tokens.astype(dt), dispatch)
-    if fp8_moe is not None:
-        from dlrover_tpu.ops.fp8 import fp8_batched_dot
-
-        new_fp8 = {}
-        g, new_fp8["wg"] = fp8_batched_dot(
-            xin, moe["wg"].astype(dt), fp8_moe["wg"]
-        )
-        u, new_fp8["wi"] = fp8_batched_dot(
-            xin, moe["wi"].astype(dt), fp8_moe["wi"]
-        )
-        h = jax.nn.silu(g) * u
-        xout, new_fp8["wo"] = fp8_batched_dot(
-            h, moe["wo"].astype(dt), fp8_moe["wo"]
-        )
-    else:
+    with jax.named_scope("moe_router"):
+        logits = tokens.astype(f32) @ moe["router"]
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, K)
+        if cfg.norm_topk_prob:
+            gate_vals = gate_vals / jnp.maximum(
+                jnp.sum(gate_vals, -1, keepdims=True), 1e-9
+            )
+    with jax.named_scope("moe_permute"):
+        pair_expert = gate_idx.reshape(N * K)
+        if valid_n is not None:
+            # pads sort behind the last expert's group
+            pair_expert = jnp.where(
+                jnp.repeat(valid_n, K), pair_expert, E)
+        pairs = jnp.arange(N * K, dtype=jnp.int32)
+        sorted_expert, order = jax.lax.sort(
+            (pair_expert, pairs), num_keys=1, is_stable=True)
+        _, inverse = jax.lax.sort((order, pairs), num_keys=1)
+        ends = jnp.searchsorted(
+            sorted_expert, jnp.arange(E, dtype=jnp.int32), side="right"
+        ).astype(jnp.int32)
+        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+        counts = ends - starts  # valid pairs per expert
+        # the groups the matmuls run over cover every row: pads ride at
+        # the end of the last one
+        group_sizes = counts.at[E - 1].add(N * K - ends[E - 1])
+        if capacity is not None or valid_n is not None:
+            keep_sorted = sorted_expert < E
+            if capacity is not None:
+                rank = pairs - starts[jnp.minimum(sorted_expert, E - 1)]
+                keep_sorted = keep_sorted & (rank < capacity)
+            gate_vals = jnp.where(
+                keep_sorted[inverse].reshape(N, K), gate_vals, 0.0)
+        rows = _dispatch_rows(tokens.astype(dt), order, inverse)
+    with jax.named_scope("moe_experts"):
         new_fp8 = None
-        g = jnp.einsum("ecd,edf->ecf", xin, moe["wg"].astype(dt))
-        u = jnp.einsum("ecd,edf->ecf", xin, moe["wi"].astype(dt))
-        h = jax.nn.silu(g) * u
-        xout = jnp.einsum("ecf,efd->ecd", h, moe["wo"].astype(dt))
-    combine = dispatch * gate_vals[..., None, None].astype(dt)
-    out = jnp.einsum("ecd,nkec->nd", xout, combine)
-    # Aux load-balance loss, returned via a side dict by forward().
-    if valid_n is None:
-        me = jnp.mean(probs, axis=0)
-        ce = jnp.mean(
-            jax.nn.one_hot(gate_idx[:, 0], E, dtype=jnp.float32), axis=0
-        )
-    else:
-        w = valid_n.astype(jnp.float32)
+        if fp8_moe is not None:
+            from dlrover_tpu.ops.fp8 import fp8_ragged_dot
+
+            new_fp8 = {}
+            g, new_fp8["wg"] = fp8_ragged_dot(
+                rows, moe["wg"].astype(dt), group_sizes, fp8_moe["wg"])
+            u, new_fp8["wi"] = fp8_ragged_dot(
+                rows, moe["wi"].astype(dt), group_sizes, fp8_moe["wi"])
+            y, new_fp8["wo"] = fp8_ragged_dot(
+                jax.nn.silu(g) * u, moe["wo"].astype(dt), group_sizes,
+                fp8_moe["wo"])
+        else:
+            y = _expert_ffn(rows, moe["wg"], moe["wi"], moe["wo"],
+                            group_sizes, dt)
+    with jax.named_scope("moe_permute"):
+        per_pair = _permute_rows(y, inverse, order).reshape(N, K, C)
+    with jax.named_scope("moe_combine"):
+        out = jnp.einsum(
+            "nkc,nk->nc", per_pair, gate_vals.astype(dt),
+            preferred_element_type=f32).astype(dt)
+    with jax.named_scope("moe_router"):
+        # the two loss terms, over real tokens only
+        w = jnp.ones((N,), f32) if valid_n is None else valid_n.astype(f32)
         denom = jnp.maximum(jnp.sum(w), 1.0)
         me = jnp.sum(probs * w[:, None], axis=0) / denom
-        ce = jnp.sum(
-            jax.nn.one_hot(gate_idx[:, 0], E, dtype=jnp.float32)
-            * w[:, None], axis=0,
-        ) / denom
-    aux = E * jnp.sum(me * ce)
-    if fp8_moe is not None:
-        return out.reshape(B, S, C), aux, new_fp8
-    return out.reshape(B, S, C), aux
+        if cfg.balance_all_k:
+            ce = counts.astype(f32) / (denom * K)
+        else:
+            ce = jnp.sum(
+                jax.nn.one_hot(gate_idx[:, 0], E, dtype=f32) * w[:, None],
+                axis=0) / denom
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        stats = {
+            "moe_aux": E * jnp.sum(me * ce),
+            "moe_z": jnp.sum(jnp.square(lse) * w) / denom,
+            "experts": gate_idx.reshape(B, S, K),
+            "tokens_per_expert": counts,
+        }
+    if new_fp8 is not None:
+        stats["fp8"] = new_fp8
+    return out.reshape(B, S, C), stats
 
 
 def block_apply(
@@ -420,21 +528,25 @@ def block_apply(
     moe_capacity: Optional[int] = None,
     fp8_layer=None,
 ) -> tuple:
-    """One transformer block: (x, layer) -> (x, moe_aux scalar).  The unit
-    the pipeline stage partitioner groups (``models.llama_pp``).
+    """One transformer block: (x, layer) -> (x, stats).  ``stats`` is what
+    a routed layer's :func:`_moe_swiglu` reports (``moe_aux``, ``moe_z``,
+    ``experts``, ``tokens_per_expert``) and empty for a dense layer.  The
+    unit the pipeline stage partitioner groups (``models.llama_pp``).
     ``attn_fn`` swaps the attention implementation (the KV-cache decoder
     plugs in here, so train and decode share one block wiring).
 
     With ``fp8_layer`` (per-layer Fp8State dict from
     :func:`init_fp8_states`) the attention/MLP projections run through
     fp8_dot and the return becomes a 3-tuple
-    ``(x, moe_aux, new_fp8_layer)``; on MoE layers the expert
-    projections (the bulk of the layer's FLOPs) go through the batched
-    fp8 grouped dot as well — only the router and dispatch/combine stay
-    in the compute dtype."""
-    # The scopes (``attention``, ``mlp``/``moe``; each with its norm and
-    # its residual add) go into every instruction's ``op_name`` of the
-    # compiled step: ``accelerate.program_summary`` reads them back.
+    ``(x, stats, new_fp8_layer)``; on MoE layers the expert
+    projections (the bulk of the layer's FLOPs) go through the fp8
+    ragged dot as well — only the router, the permutation and the
+    combine stay in the compute dtype."""
+    # The scopes (``attention``, ``mlp``, and the routed block's four:
+    # ``moe_router`` with its norm, ``moe_permute``, ``moe_experts``,
+    # ``moe_combine`` with the residual add) go into every instruction's
+    # ``op_name`` of the compiled step: ``accelerate.program_summary``
+    # reads them back, outermost scope only, hence siblings.
     with jax.named_scope("attention"):
         h = rmsnorm(x, layer["ln1"], eps=cfg.rms_eps)
         if attn_fn is not None:
@@ -452,18 +564,19 @@ def block_apply(
             )
         x = x + attn
     if "moe" in layer:
-        with jax.named_scope("moe"):
+        with jax.named_scope("moe_router"):
             h = rmsnorm(x, layer["ln2"], eps=cfg.rms_eps)
-            res = _moe_swiglu(
-                h, layer["moe"], cfg, capacity=moe_capacity,
-                valid=None if segment_ids is None else segment_ids >= 0,
-                fp8_moe=None if fp8_layer is None else fp8_layer["moe"],
-            )
-            if fp8_layer is not None:
-                delta, aux, new_fp8_attn["moe"] = res
-                return x + delta, aux, new_fp8_attn
-            delta, aux = res
-            return x + delta, aux
+        delta, stats = _moe_swiglu(
+            h, layer["moe"], cfg, capacity=moe_capacity,
+            valid=None if segment_ids is None else segment_ids >= 0,
+            fp8_moe=None if fp8_layer is None else fp8_layer["moe"],
+        )
+        with jax.named_scope("moe_combine"):
+            x = x + delta
+        if fp8_layer is not None:
+            new_fp8_attn["moe"] = stats.pop("fp8")
+            return x, stats, new_fp8_attn
+        return x, stats
     with jax.named_scope("mlp"):
         h = rmsnorm(x, layer["ln2"], eps=cfg.rms_eps)
         out_m, new_fp8_mlp = _swiglu(
@@ -473,8 +586,8 @@ def block_apply(
         x = x + out_m
     if fp8_layer is not None:
         new_fp8_attn["mlp"] = new_fp8_mlp
-        return x, jnp.zeros((), jnp.float32), new_fp8_attn
-    return x, jnp.zeros((), jnp.float32)
+        return x, {}, new_fp8_attn
+    return x, {}
 
 
 def segment_positions(segment_ids: jax.Array) -> jax.Array:
@@ -533,6 +646,13 @@ def forward_hidden(
 ) -> tuple:
     """tokens [B, S] -> (final-norm hidden [B, S, D], aux dict).
 
+    The aux dict always holds ``moe_aux`` (the load-balance terms summed
+    over the routed layers; 0 for a dense model).  A routed model adds
+    ``moe_z`` (the z-loss terms, summed), ``moe_experts`` (``{layer
+    index: int32 [B, S, K]}``, the experts each routed layer's router
+    took — under block remat too) and ``moe_tokens_per_expert`` (int32
+    ``[routed layers, E]``).
+
     ``segment_ids`` [B, S] enables packed-sequence training: attention is
     restricted to same-segment pairs (flash-kernel mask) and rope
     positions reset at each segment boundary.  ``fp8_states`` (from
@@ -547,6 +667,8 @@ def forward_hidden(
     else:
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     moe_aux = jnp.zeros((), jnp.float32)
+    moe_z = jnp.zeros((), jnp.float32)
+    experts, per_expert = {}, []
     apply = functools.partial(
         block_apply, attn_impl=attn_impl, mesh=mesh,
         segment_ids=segment_ids,
@@ -556,9 +678,9 @@ def forward_hidden(
     new_fp8 = [] if fp8_states is not None else None
     for i, layer in enumerate(params["layers"]):
         if fp8_states is None:
-            x, aux = apply(layer, x, cfg, positions)
+            x, stats = apply(layer, x, cfg, positions)
         else:
-            x, aux, nf = apply(
+            x, stats, nf = apply(
                 layer, x, cfg, positions, fp8_layer=fp8_states[i]
             )
             new_fp8.append(nf)
@@ -568,10 +690,17 @@ def forward_hidden(
         # selective_offloading_checkpoint.py:252) while everything
         # inside the block rematerializes.
         x = checkpoint_name(x, "block_out")
-        moe_aux = moe_aux + aux
+        if stats:
+            moe_aux = moe_aux + stats["moe_aux"]
+            moe_z = moe_z + stats["moe_z"]
+            experts[i] = stats["experts"]
+            per_expert.append(stats["tokens_per_expert"])
     with jax.named_scope("final_norm"):
         x = rmsnorm(x, params["ln_f"], eps=cfg.rms_eps)
     out_aux = {"moe_aux": moe_aux}
+    if per_expert:
+        out_aux.update(moe_z=moe_z, moe_experts=experts,
+                       moe_tokens_per_expert=jnp.stack(per_expert))
     if new_fp8 is not None:
         out_aux["fp8_states"] = new_fp8
     return x, out_aux
@@ -622,10 +751,19 @@ def loss_fn(
     attn_impl: str = "auto",
     mesh=None,
     moe_aux_weight: float = 1e-2,
+    moe_z_weight: float = 0.0,
     fused_lm_head: Optional[bool] = None,
     fp8_states=None,
+    metrics: bool = False,
 ) -> jax.Array:
-    """Next-token loss.  ``fused_lm_head`` (default: auto — on for large
+    """Next-token loss, plus ``moe_aux_weight`` x the routed layers'
+    load-balance terms and ``moe_z_weight`` x their router z-losses.
+    With ``metrics`` a routed model returns ``(loss, counters)`` with the
+    counters of its routed blocks (``moe_tokens_per_expert`` int32
+    ``[routed layers, E]``, ``moe_aux``, ``moe_z``): ``accelerate()``'s
+    step hands them out beside ``loss`` and ``grad_norm``.  A dense model
+    returns the scalar alone either way.
+    ``fused_lm_head`` (default: auto — on for large
     vocabs) routes the projection through the chunked fused lm-head
     cross-entropy so the [B, S, vocab] logits never hit HBM.  A
     ``batch["segment_ids"]`` entry ([B, S] or [B, S+1] matching tokens)
@@ -687,10 +825,15 @@ def loss_fn(
         else:
             ce = jnp.mean(per_tok)
     loss = ce + moe_aux_weight * aux["moe_aux"]
+    if "moe_z" in aux:
+        loss = loss + moe_z_weight * aux["moe_z"]
     if fp8_states is not None:
         # (loss, new_fp8_states): use under value_and_grad(has_aux=True)
         # and feed the states back in next step (delayed scaling).
         return loss, aux["fp8_states"]
+    if metrics and "moe_z" in aux:
+        return loss, {k: aux[k] for k in (
+            "moe_tokens_per_expert", "moe_aux", "moe_z")}
     return loss
 
 

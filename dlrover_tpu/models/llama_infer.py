@@ -116,11 +116,11 @@ def _cached_attention(x, layer, cfg, cache_layer, offset, positions,
     B, T, C = x.shape
     H, KV, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     dt = cfg.dtype
-    q = (x @ layer["wq"].astype(dt)).reshape(B, T, H, D)
-    k = (x @ layer["wk"].astype(dt)).reshape(B, T, KV, D)
+    q, k = llama.qk_normed(
+        x @ layer["wq"].astype(dt), x @ layer["wk"].astype(dt), layer, cfg)
     v = (x @ layer["wv"].astype(dt)).reshape(B, T, KV, D)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    q = _rope(q.reshape(B, T, H, D), positions, cfg.rope_theta)
+    k = _rope(k.reshape(B, T, KV, D), positions, cfg.rope_theta)
 
     quant = "ks" in cache_layer
 

@@ -72,64 +72,69 @@ def _cast_fp8(x: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     ).astype(dtype)
 
 
-def _build_fp8_dot(fwd_dn, dx_dn, dw_dn):
-    """One delayed-scaling fp8 dot with custom VJP, parameterized by
-    ``dot_general`` dimension numbers: forward ``x @ w`` (e4m3 x e4m3),
-    backward ``dX = g ·dx_dn w`` and ``dW = x ·dw_dn g`` with the
-    incoming grad in e5m2.  The plain-linear and batched-expert variants
-    below differ ONLY in these dimension numbers — everything else
-    (cast recipe, descaling, VJP scaffolding) is this one definition."""
+def _build_fp8_dot(fwd_dot, dx_dot, dw_dot):
+    """One delayed-scaling fp8 dot with custom VJP, parameterized by its
+    three products, each ``(a, b, *extra) -> float32``: forward ``x @ w``
+    (e4m3 x e4m3), backward ``dX = dx_dot(g, w)`` and ``dW = dw_dot(x, g)``
+    with the incoming grad in e5m2.  The plain-linear and ragged-expert
+    variants below differ ONLY in these products (``extra`` carries the
+    ragged one's group sizes) — everything else (cast recipe, descaling,
+    VJP scaffolding) is this one definition."""
 
     @jax.custom_vjp
-    def dot(x, w, x_scale, w_scale, g_scale):
+    def dot(x, w, x_scale, w_scale, g_scale, *extra):
         xq = _cast_fp8(x, x_scale, E4M3)
         wq = _cast_fp8(w, w_scale, E4M3)
-        out = jax.lax.dot_general(
-            xq, wq, fwd_dn, preferred_element_type=jnp.float32
-        )
+        out = fwd_dot(xq, wq, *extra)
         return (out * (x_scale * w_scale)).astype(x.dtype)
 
-    def fwd(x, w, x_scale, w_scale, g_scale):
-        return dot(x, w, x_scale, w_scale, g_scale), (
-            x, w, x_scale, w_scale, g_scale,
+    def fwd(x, w, x_scale, w_scale, g_scale, *extra):
+        return dot(x, w, x_scale, w_scale, g_scale, *extra), (
+            x, w, x_scale, w_scale, g_scale, extra,
         )
 
     def bwd(res, g):
-        x, w, x_scale, w_scale, g_scale = res
+        x, w, x_scale, w_scale, g_scale, extra = res
         gq = _cast_fp8(g, g_scale, E5M2)
         wq = _cast_fp8(w, w_scale, E4M3)
         xq = _cast_fp8(x, x_scale, E4M3)
-        dx = jax.lax.dot_general(
-            gq, wq, dx_dn, preferred_element_type=jnp.float32
-        )
-        dx = (dx * (g_scale * w_scale)).astype(x.dtype)
-        dw = jax.lax.dot_general(
-            xq, gq, dw_dn, preferred_element_type=jnp.float32
-        )
-        dw = (dw * (x_scale * g_scale)).astype(w.dtype)
-        return dx, dw, None, None, None
+        dx = (dx_dot(gq, wq, *extra) * (g_scale * w_scale)).astype(x.dtype)
+        dw = (dw_dot(xq, gq, *extra) * (x_scale * g_scale)).astype(w.dtype)
+        return (dx, dw, None, None, None) + (None,) * len(extra)
 
     dot.defvjp(fwd, bwd)
     return dot
 
 
+def _dot(dimension_numbers):
+    return lambda a, b: jax.lax.dot_general(
+        a, b, dimension_numbers, preferred_element_type=jnp.float32)
+
+
 # x [M, K] @ w [K, N]: dX = g @ W^T, dW = X^T @ g.
 _fp8_dot = _build_fp8_dot(
-    (((1,), (0,)), ((), ())),
-    (((1,), (1,)), ((), ())),
-    (((0,), (0,)), ((), ())),
+    _dot((((1,), (0,)), ((), ()))),
+    _dot((((1,), (1,)), ((), ()))),
+    _dot((((0,), (0,)), ((), ()))),
 )
 
-# x [E, C, D] @ w [E, D, F], batched over the expert dim: dX contracts
-# F, dW contracts C, both carrying E as the batch dim.
-_fp8_bdot = _build_fp8_dot(
-    (((2,), (1,)), ((0,), (0,))),
-    (((2,), (2,)), ((0,), (0,))),
-    (((1,), (1,)), ((0,), (0,))),
+# x [T, D] rows sorted by expert @ w [E, D, F] over ragged groups: dX is
+# the same ragged product with w transposed, dW contracts the ragged row
+# dim group by group (what ``lax.ragged_dot``'s own transposes compute).
+_DW_RAGGED = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+_fp8_rdot = _build_fp8_dot(
+    lambda xq, wq, gs: jax.lax.ragged_dot(
+        xq, wq, gs, preferred_element_type=jnp.float32),
+    lambda gq, wq, gs: jax.lax.ragged_dot(
+        gq, wq.swapaxes(1, 2), gs, preferred_element_type=jnp.float32),
+    lambda xq, gq, gs: jax.lax.ragged_dot_general(
+        xq, gq, gs, _DW_RAGGED, preferred_element_type=jnp.float32),
 )
 
 
-def _delayed_scaling_dot(dot, x, w, state: Fp8State):
+def _delayed_scaling_dot(dot, x, w, state: Fp8State, *extra):
     """The ONE delayed-scaling recipe both public entry points share:
     scales applied come from the PREVIOUS amax history while the CURRENT
     tensors' amax are pushed in — keeping the cast free of a same-step
@@ -139,7 +144,7 @@ def _delayed_scaling_dot(dot, x, w, state: Fp8State):
     x_scale = _scale_from_hist(state.x_hist, E4M3_MAX)
     w_scale = _scale_from_hist(state.w_hist, E4M3_MAX)
     g_scale = _scale_from_hist(state.g_hist, E5M2_MAX)
-    out = dot(x, w, x_scale, w_scale, g_scale)
+    out = dot(x, w, x_scale, w_scale, g_scale, *extra)
     new_state = Fp8State(
         x_hist=_push(
             state.x_hist, jnp.max(jnp.abs(x)).astype(jnp.float32)
@@ -162,17 +167,19 @@ def fp8_dot(
     return _delayed_scaling_dot(_fp8_dot, x, w, state)
 
 
-def fp8_batched_dot(
-    x: jax.Array, w: jax.Array, state: Fp8State
+def fp8_ragged_dot(
+    x: jax.Array, w: jax.Array, group_sizes: jax.Array, state: Fp8State
 ) -> Tuple[jax.Array, Fp8State]:
-    """Per-expert batched ``x[e] @ w[e]`` — the MoE grouped-matmul
-    analogue of :func:`fp8_dot`.
+    """Grouped ``x[rows of e] @ w[e]`` over ragged groups — the MoE
+    grouped-matmul analogue of :func:`fp8_dot` (the fp8 counterpart of
+    ``ops.grouped_matmul.grouped_matmul_ragged``).
 
     Scales are per-STACKED-tensor (one amax over all experts), the
     "shared" variant: a per-expert scale would need a gather per token
     block and buys little when experts share an init distribution.
-    Shapes: x [E, C, D], w [E, D, F] -> [E, C, F]."""
-    return _delayed_scaling_dot(_fp8_bdot, x, w, state)
+    Shapes: x [T, D] sorted by expert, w [E, D, F], group_sizes [E]
+    (sum T) -> [T, F]."""
+    return _delayed_scaling_dot(_fp8_rdot, x, w, state, group_sizes)
 
 
 def fp8_supported() -> bool:

@@ -279,8 +279,9 @@ def _run_quant() -> Dict:
 
 
 def _run_grouped_matmul() -> Dict:
-    """lax.ragged_dot (the MoE grouped GEMM) — XLA-native, but on the MoE
-    hot path; confirm it lowers and matches on this backend."""
+    """The MoE grouped GEMM (megablox gmm on the TPU at this shape,
+    ``lax.ragged_dot`` elsewhere) — on the MoE hot path; confirm it lowers
+    and matches on this backend."""
     import jax
     import jax.numpy as jnp
 

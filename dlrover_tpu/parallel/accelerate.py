@@ -437,6 +437,15 @@ def _build_train_step(
 
         A = strategy.grad_accum
 
+        def scalar_loss(params, mb, **kw):
+            out = lfn(params, mb, **kw)
+            if isinstance(out, tuple):
+                raise ValueError(
+                    "Strategy(quant_grads=True): the loss function "
+                    "returned (loss, metrics); the int8-compressed dp "
+                    "reduction hands out no metrics")
+            return out
+
         def local(params, b_local, frozen):
             kw_l = {"frozen": frozen} if has_frozen else {}
             # pcast to varying: custom-VJP rules (rmsnorm, flash
@@ -459,7 +468,7 @@ def _build_train_step(
 
                 def acc_fn(carry, mb):
                     loss_sum, grads_sum = carry
-                    loss, grads = jax.value_and_grad(lfn)(
+                    loss, grads = jax.value_and_grad(scalar_loss)(
                         params, mb, **kw_l
                     )
                     return (
@@ -483,7 +492,7 @@ def _build_train_step(
                     lambda g: g / A, grads
                 )
             else:
-                loss, grads = jax.value_and_grad(lfn)(
+                loss, grads = jax.value_and_grad(scalar_loss)(
                     params, b_local, **kw_l
                 )
             # ONE compressed reduction per step, after accumulation —
@@ -520,17 +529,24 @@ def _build_train_step(
             out_specs=(P(), P()),
         )(params, batch, frozen_arg)
 
+    def _loss_and_aux(params, mb, **kw):
+        """A loss function returns its scalar, or ``(scalar, aux)``: the
+        new fp8 states under the fp8 strategy, else a dict of metrics the
+        step hands out beside ``loss`` and ``grad_norm``."""
+        out = lfn(params, mb, **kw)
+        return out if isinstance(out, tuple) else (out, {})
+
     def _value_and_grad(params, mb, fp8, frozen):
-        """(loss, grads, new_fp8) for one microbatch; new_fp8 is None
-        when the fp8 strategy is off."""
+        """(loss, grads, new_fp8, metrics) for one microbatch; new_fp8 is
+        None when the fp8 strategy is off, metrics empty unless the loss
+        function returned some."""
         kw = {"frozen": frozen} if has_frozen else {}
         if fp8_on:
-            (loss, new_fp8), grads = jax.value_and_grad(
-                lfn, has_aux=True
-            )(params, mb, fp8_states=fp8, **kw)
-            return loss, grads, new_fp8
-        loss, grads = jax.value_and_grad(lfn)(params, mb, **kw)
-        return loss, grads, None
+            kw["fp8_states"] = fp8
+        (loss, aux), grads = jax.value_and_grad(
+            _loss_and_aux, has_aux=True)(params, mb, **kw)
+        new_fp8, metrics = (aux, {}) if fp8_on else (None, aux)
+        return loss, grads, new_fp8, metrics
 
     def train_step(state, batch, frozen=None):
         params = state["params"]
@@ -542,7 +558,7 @@ def _build_train_step(
             # Accumulation happens INSIDE the sharded local step; one
             # compressed reduction per optimizer step.
             loss, grads = _quant_loss_and_grads(params, batch, frozen)
-            new_fp8 = None
+            new_fp8, metrics = None, {}
         elif strategy.grad_accum > 1:
             micro = jax.tree_util.tree_map(
                 lambda x: x.reshape(
@@ -553,7 +569,7 @@ def _build_train_step(
 
             def acc_fn(carry, mb):
                 loss_sum, grads_sum, fp8_c = carry
-                loss, grads, new_fp8 = _value_and_grad(
+                loss, grads, new_fp8, metrics = _value_and_grad(
                     params, mb, fp8_c, frozen
                 )
                 carry = (
@@ -561,7 +577,7 @@ def _build_train_step(
                     jax.tree_util.tree_map(jnp.add, grads_sum, grads),
                     new_fp8 if fp8_on else fp8_c,
                 )
-                return carry, None
+                return carry, metrics
 
             zero = (
                 jnp.zeros((), jnp.float32),
@@ -570,16 +586,22 @@ def _build_train_step(
                 ),
                 fp8,
             )
-            (loss_sum, grad_sum, new_fp8), _ = jax.lax.scan(
+            (loss_sum, grad_sum, new_fp8), per_micro = jax.lax.scan(
                 acc_fn, zero, micro
             )
+            # counts (integers) add up over the microbatches, the rest
+            # is averaged like the loss
+            metrics = jax.tree_util.tree_map(
+                lambda m: jnp.sum(m, axis=0)
+                if jnp.issubdtype(m.dtype, jnp.integer)
+                else jnp.mean(m, axis=0), per_micro)
             loss = loss_sum / strategy.grad_accum
             grads = jax.tree_util.tree_map(
                 lambda g: g / strategy.grad_accum, grad_sum
             )
         else:
-            loss, grads, new_fp8 = _value_and_grad(params, batch, fp8,
-                                                   frozen)
+            loss, grads, new_fp8, metrics = _value_and_grad(
+                params, batch, fp8, frozen)
 
         import optax
 
@@ -597,7 +619,7 @@ def _build_train_step(
             new_state["fp8"] = new_fp8
         with jax.named_scope("grad_norm"):
             gnorm = optax.global_norm(grads)
-        return new_state, {"loss": loss, "grad_norm": gnorm}
+        return new_state, dict(metrics, loss=loss, grad_norm=gnorm)
 
     return train_step
 
@@ -651,7 +673,9 @@ def _build_span(fn: Callable) -> Callable:
 @_build_span
 def accelerate(
     *,
-    loss_fn: Callable,  # (params, batch) -> scalar loss
+    # (params, batch) -> scalar loss, or (loss, {name: metric}): the step
+    # then returns those metrics beside "loss" and "grad_norm"
+    loss_fn: Callable,
     init_fn: Callable,  # (rng) -> params pytree
     optimizer,  # optax GradientTransformation
     sample_batch: Any,  # pytree of np arrays w/ GLOBAL batch dim

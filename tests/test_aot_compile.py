@@ -147,11 +147,17 @@ def _quant():
             [((4 << 20,), f32)], {"quantize_blockwise": 1})
 
 
-def _grouped_matmul():
+def _grouped_matmul(backend):
+    """OLMoE's expert shapes, forward and both gradients: the megablox
+    kernel at the tiling ``ops/grouped_matmul.py`` fixes, and its
+    reference ``lax.ragged_dot`` (XLA's own kernels over the groups)."""
     from dlrover_tpu.ops.grouped_matmul import grouped_matmul_ragged
 
-    return (grouped_matmul_ragged,
-            [((1024, 512), bf16), ((8, 512, 1024), bf16), ((8,), i32)], {})
+    def fn(x, w, sizes):
+        return jax.grad(lambda x, w: _sq(grouped_matmul_ragged(
+            x, w, sizes, backend=backend)), argnums=(0, 1))(x, w)
+    return (fn, [((8192, 2048), bf16), ((64, 2048, 1024), bf16),
+                 ((64,), i32)], {})
 
 
 def _bwd_block_q_128():
@@ -177,7 +183,8 @@ KERNEL_CASES = {
     "cross_entropy-fwd": _xent,
     "fused_lm_head_ce-grad": _fused_lm_head,
     "quantize_blockwise-fwd": _quant,
-    "grouped_matmul-fwd": _grouped_matmul,
+    "grouped_matmul-grad": lambda: _grouped_matmul("pallas"),
+    "grouped_matmul_reference-grad": lambda: _grouped_matmul("reference"),
     "flash_causal-bwd_block_q128": _bwd_block_q_128,
 }
 
@@ -189,6 +196,58 @@ def test_kernel_compiles_for_v5e(topo, case):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes]
     assert _our_kernels(jax.jit(fn).lower(*args).compile()) == kernels
+
+
+#: (tokens, d_model, d_ff) of the routed block below, 64 experts top 8:
+#: toy widths (every honest buffer is under tokens x 8 x 64 elements; the
+#: grouped matmuls go to ``lax.ragged_dot``) and OLMoE's (the kernel)
+ROUTED_CASES = {"mid_size": (2048, 32, 16), "olmoe_widths": (512, 2048, 1024)}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTED_CASES))
+def test_routed_block_holds_no_dispatch_sized_buffer(topo, monkeypatch, case):
+    """Forward and backward of ``_moe_swiglu`` as the chip's compiler leaves
+    it: nothing shaped tokens*k x experts or tokens x k x experts (the
+    one-hot dispatch this block replaced, 86 GB at OLMoE's widths; a dense
+    fallback of a ragged product's transpose would bring it back), and at
+    toy widths no buffer of tokens*k*experts elements at all."""
+    from dlrover_tpu.models import llama
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d, f = ROUTED_CASES[case]
+    e, k = 64, 8
+    cfg = llama.LlamaConfig.tiny(
+        d_model=d, d_ff=f, num_experts=e, top_k=k, moe_every=1,
+        norm_topk_prob=False, balance_all_k=True, dtype=bf16)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    moe = {"router": arg((d, e), f32), "wg": arg((e, d, f), f32),
+           "wi": arg((e, d, f), f32), "wo": arg((e, f, d), f32)}
+
+    def loss(x, moe):
+        out, stats = llama._moe_swiglu(x, moe, cfg)
+        return _sq(out) + stats["moe_aux"] + stats["moe_z"]
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        arg((1, n, d), bf16), moe).compile()
+    text = compiled.as_text()
+    kernels = acc.program_summary(text)["kernels"]
+    if case == "olmoe_widths":
+        assert (kernels.get("gmm"), kernels.get("tgmm")) == (6, 3), kernels
+    else:
+        assert "gmm" not in kernels, kernels
+    bad, seen = set(), set()
+    for dims in re.findall(r"\b(?:pred|[a-z]+\d+)\[([\d,]+)\]", text):
+        seen.add(dims)
+        shape = [int(v) for v in dims.split(",")]
+        tokens_by_experts = e in shape and (
+            n * k in shape or (n in shape and k in shape))
+        if tokens_by_experts or (
+                case == "mid_size" and np.prod(shape) >= n * k * e):
+            bad.add(dims)
+    assert f"{n * k},{d}" in seen  # the sorted pair rows are there
+    assert not bad, bad
 
 
 def _mesh(topo):

@@ -19,7 +19,7 @@ from dlrover_tpu.ops.fp8 import (
     E4M3,
     E5M2,
     Fp8State,
-    fp8_batched_dot,
+    fp8_ragged_dot,
     fp8_dot,
 )
 from dlrover_tpu.ops.quant import (
@@ -88,41 +88,46 @@ class TestFp8Dot:
         assert np.isfinite(np.asarray(sums)).all()
 
 
-class TestFp8BatchedDot:
-    """The MoE expert path: per-expert batched matmul in e4m3/e5m2
-    (VERDICT r3 missing #4 — the reference rewrites every eligible
-    expert linear, amp_optimization.py:396)."""
+class TestFp8RaggedDot:
+    """The MoE expert path: the grouped matmul over ragged groups in
+    e4m3/e5m2 (VERDICT r3 missing #4 — the reference rewrites every
+    eligible expert linear, amp_optimization.py:396).  Rows sorted by
+    expert, here in groups of unequal size."""
 
     def test_forward_close_to_fp32(self):
         rs = np.random.RandomState(0)
-        x = jnp.asarray(rs.randn(4, 16, 32), jnp.float32)
+        x = jnp.asarray(rs.randn(64, 32), jnp.float32)
         w = jnp.asarray(rs.randn(4, 32, 8), jnp.float32) * 0.1
+        sizes = jnp.asarray([16, 5, 0, 43], jnp.int32)
         state = Fp8State.init()
-        _, state = fp8_batched_dot(x, w, state)  # warm scales
-        out, state = fp8_batched_dot(x, w, state)
-        ref = jnp.einsum("ecd,edf->ecf", x, w)
+        _, state = fp8_ragged_dot(x, w, sizes, state)  # warm scales
+        out, state = fp8_ragged_dot(x, w, sizes, state)
+        ref = jax.lax.ragged_dot(x, w, sizes)
         err = jnp.linalg.norm(out - ref) / jnp.linalg.norm(ref)
         assert float(err) < 0.06, float(err)
 
     def test_gradients_match_fp32_direction(self):
         rs = np.random.RandomState(1)
-        x = jnp.asarray(rs.randn(3, 8, 16), jnp.float32)
+        x = jnp.asarray(rs.randn(24, 16), jnp.float32)
         w = jnp.asarray(rs.randn(3, 16, 4), jnp.float32) * 0.2
+        sizes = jnp.asarray([8, 3, 13], jnp.int32)
         state = Fp8State.init()
-        _, state = fp8_batched_dot(x, w, state)
+        _, state = fp8_ragged_dot(x, w, sizes, state)
 
-        def loss(w_):
-            out, _ = fp8_batched_dot(x, w_, state)
+        def loss(x_, w_):
+            out, _ = fp8_ragged_dot(x_, w_, sizes, state)
             return jnp.sum(out**2)
 
-        g = jax.grad(loss)(w)
-        g_ref = jax.grad(
-            lambda w_: jnp.sum(jnp.einsum("ecd,edf->ecf", x, w_) ** 2)
-        )(w)
-        cos = jnp.sum(g * g_ref) / (
-            jnp.linalg.norm(g) * jnp.linalg.norm(g_ref)
-        )
-        assert float(cos) > 0.97, float(cos)
+        grads = jax.grad(loss, argnums=(0, 1))(x, w)
+        refs = jax.grad(
+            lambda x_, w_: jnp.sum(
+                jax.lax.ragged_dot(x_, w_, sizes) ** 2), argnums=(0, 1)
+        )(x, w)
+        for g, g_ref in zip(grads, refs):
+            cos = jnp.sum(g * g_ref) / (
+                jnp.linalg.norm(g) * jnp.linalg.norm(g_ref)
+            )
+            assert float(cos) > 0.97, float(cos)
 
 
 class TestFp8Moe:

@@ -1,0 +1,368 @@
+"""The routed block of ``models/llama.py``: sorted by expert, dropless,
+ragged.  Against a per-token loop over the chosen experts (forward and
+``jax.grad``), with and without renormalisation, pads, a capacity that
+drops; the published router's loss terms against closed forms; q/k RMSNorm;
+and ``accelerate()``'s step handing out the block's counters."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from dlrover_tpu.models import llama, llama_infer
+
+E, K, D, F = 8, 2, 16, 8
+
+
+def _cfg(**over):
+    base = dict(n_layer=1, d_model=D, d_ff=F, n_head=4, n_kv_head=2,
+                num_experts=E, top_k=K, moe_every=1, dtype=jnp.float32)
+    base.update(over)
+    return llama.LlamaConfig.tiny(**base)
+
+
+def _moe(seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return {"router": jax.random.normal(k[0], (D, E)) * 0.5,
+            "wg": jax.random.normal(k[1], (E, D, F)) * 0.3,
+            "wi": jax.random.normal(k[2], (E, D, F)) * 0.3,
+            "wo": jax.random.normal(k[3], (E, F, D)) * 0.3}
+
+
+def _x(b=2, s=12, seed=1):
+    return jax.random.normal(jax.random.PRNGKey(seed), (b, s, D))
+
+
+def _per_token(x, moe, cfg, keep=None):
+    """sum_k weight_k * expert_k(token), each token by itself: the weights
+    of its chosen experts gathered per (token, k)."""
+    toks = x.reshape(-1, D)
+    probs = jax.nn.softmax(toks @ moe["router"], -1)
+    w, idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        w = w / w.sum(-1, keepdims=True)
+    if keep is not None:
+        w = w * keep
+    g = jnp.einsum("nd,nkdf->nkf", toks, moe["wg"][idx])
+    u = jnp.einsum("nd,nkdf->nkf", toks, moe["wi"][idx])
+    y = jnp.einsum("nkf,nkfd->nkd", jax.nn.silu(g) * u, moe["wo"][idx])
+    return jnp.einsum("nkd,nk->nd", y, w).reshape(x.shape)
+
+
+def _rank_in_expert(idx, valid=None):
+    """numpy: each (token, k) pair's rank among the pairs of its expert,
+    in token order, pads taking none."""
+    idx = np.asarray(idx)
+    seen = np.zeros(E, int)
+    rank = np.zeros(idx.shape, int)
+    for n in range(idx.shape[0]):
+        for k in range(idx.shape[1]):
+            if valid is not None and not valid[n]:
+                rank[n, k] = 10**9
+                continue
+            rank[n, k] = seen[idx[n, k]]
+            seen[idx[n, k]] += 1
+    return rank
+
+
+@pytest.mark.parametrize("renorm", [True, False], ids=["renorm", "raw"])
+def test_block_matches_per_token_loop_forward_and_grad(renorm):
+    cfg, moe, x = _cfg(norm_topk_prob=renorm), _moe(), _x()
+    out, stats = llama._moe_swiglu(x, moe, cfg)
+    np.testing.assert_allclose(out, _per_token(x, moe, cfg), atol=1e-5)
+    assert stats["experts"].shape == (2, 12, K)
+    assert stats["experts"].dtype == jnp.int32
+    r = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    got = jax.grad(lambda x, m: jnp.sum(
+        llama._moe_swiglu(x, m, cfg)[0] * r), argnums=(0, 1))(x, moe)
+    ref = jax.grad(lambda x, m: jnp.sum(
+        _per_token(x, m, cfg) * r), argnums=(0, 1))(x, moe)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(ref)):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_renormalised_and_raw_weights_differ():
+    moe, x = _moe(), _x()
+    a, _ = llama._moe_swiglu(x, moe, _cfg(norm_topk_prob=True))
+    b, _ = llama._moe_swiglu(x, moe, _cfg(norm_topk_prob=False))
+    assert float(jnp.linalg.norm(a - b) / jnp.linalg.norm(a)) > 0.1
+
+
+def test_group_sizes_sum_to_the_valid_pairs():
+    cfg, moe, x = _cfg(), _moe(), _x(b=1, s=16)
+    _, stats = llama._moe_swiglu(x, moe, cfg)
+    counts = np.asarray(stats["tokens_per_expert"])
+    assert counts.dtype == np.int32 and counts.sum() == 16 * K
+    np.testing.assert_array_equal(
+        counts, np.bincount(np.asarray(stats["experts"]).ravel(),
+                            minlength=E))
+    valid = jnp.arange(16)[None, :] % 3 != 0  # 10 real tokens
+    _, stats = llama._moe_swiglu(x, moe, cfg, valid=valid)
+    counts = np.asarray(stats["tokens_per_expert"])
+    assert counts.sum() == 10 * K
+    np.testing.assert_array_equal(counts, np.bincount(
+        np.asarray(stats["experts"])[0][np.asarray(valid[0])].ravel(),
+        minlength=E))
+
+
+def test_pads_get_nothing_and_count_in_no_statistic():
+    cfg, moe = _cfg(norm_topk_prob=False, balance_all_k=True), _moe()
+    real = _x(b=1, s=6)
+    out_ref, stats_ref = llama._moe_swiglu(real, moe, cfg)
+    pads = 5.0 * _x(b=1, s=4, seed=3)
+    x = jnp.concatenate([pads[:, :2], real[:, :3], pads[:, 2:], real[:, 3:]],
+                        axis=1)
+    valid = jnp.asarray([[False] * 2 + [True] * 3 + [False] * 2 + [True] * 3])
+    out, stats = llama._moe_swiglu(x, moe, cfg, valid=valid)
+    np.testing.assert_allclose(out[0][np.asarray(valid[0])], out_ref[0],
+                               atol=1e-5)
+    np.testing.assert_array_equal(out[0][~np.asarray(valid[0])], 0.0)
+    for key in ("moe_aux", "moe_z"):
+        assert float(stats[key]) == pytest.approx(float(stats_ref[key]),
+                                                  rel=1e-5)
+    np.testing.assert_array_equal(stats["tokens_per_expert"],
+                                  stats_ref["tokens_per_expert"])
+    # and no gradient reaches a pad
+    g = jax.grad(lambda x: jnp.sum(
+        llama._moe_swiglu(x, moe, cfg, valid=valid)[0] ** 2))(x)
+    np.testing.assert_array_equal(g[0][~np.asarray(valid[0])], 0.0)
+
+
+@pytest.mark.parametrize("capacity", [1, 3, None])
+def test_a_capacity_drops_by_rank_and_none_drops_nothing(capacity):
+    cfg, moe, x = _cfg(), _moe(), _x(b=1, s=24)
+    out, stats = llama._moe_swiglu(x, moe, cfg, capacity=capacity)
+    keep = None
+    if capacity is not None:
+        keep = jnp.asarray(
+            _rank_in_expert(stats["experts"][0]) < capacity, jnp.float32)
+        assert float(keep.mean()) < 1.0 or capacity == 3
+    np.testing.assert_allclose(out, _per_token(x, moe, cfg, keep),
+                               atol=1e-5)
+    # the counter is what was routed, before any capacity
+    assert int(stats["tokens_per_expert"].sum()) == 24 * K
+
+
+def test_capacity_factor_of_the_config_and_the_decode_override():
+    moe, x = _moe(), _x(b=1, s=24)
+    tight = _cfg(capacity_factor=0.25)  # round(0.25 * 24 * 2 / 8) = 2
+    out, stats = llama._moe_swiglu(x, moe, tight)
+    keep = jnp.asarray(_rank_in_expert(stats["experts"][0]) < 2, jnp.float32)
+    assert 0.0 < float(keep.mean()) < 1.0
+    np.testing.assert_allclose(out, _per_token(x, moe, tight, keep),
+                               atol=1e-5)
+    free, _ = llama._moe_swiglu(x, moe, tight, capacity=24 * K)
+    np.testing.assert_allclose(free, _per_token(x, moe, tight), atol=1e-5)
+    assert _cfg().capacity_factor is None  # dropless unless asked
+
+
+def test_pads_take_no_rank_under_a_capacity():
+    cfg, moe = _cfg(top_k=1), _moe()
+    x = _x(b=1, s=16)
+    valid = jnp.arange(16)[None, :] >= 8
+    out, stats = llama._moe_swiglu(x, moe, cfg, capacity=2, valid=valid)
+    keep = jnp.asarray(_rank_in_expert(
+        stats["experts"][0], np.asarray(valid[0])) < 2, jnp.float32)
+    np.testing.assert_allclose(out, _per_token(x, moe, cfg, keep), atol=1e-5)
+
+
+@pytest.mark.parametrize("all_k", [False, True], ids=["first", "all_k"])
+def test_balance_and_z_terms_against_closed_forms(all_k):
+    cfg, moe, x = _cfg(balance_all_k=all_k), _moe(), _x(b=2, s=32)
+    _, stats = llama._moe_swiglu(x, moe, cfg)
+    logits = np.asarray(x.reshape(-1, D) @ moe["router"], np.float64)
+    probs = np.exp(logits) / np.exp(logits).sum(-1, keepdims=True)
+    picks = np.argsort(-probs, -1)[:, :K]
+    taken = picks if all_k else picks[:, :1]
+    f = np.bincount(taken.ravel(), minlength=E) / taken.size
+    assert float(stats["moe_aux"]) == pytest.approx(
+        E * float(np.sum(f * probs.mean(0))), rel=1e-5)
+    lse = np.log(np.exp(logits).sum(-1))
+    assert float(stats["moe_z"]) == pytest.approx(
+        float(np.mean(lse ** 2)), rel=1e-5)
+
+
+def test_an_even_router_reads_one_and_log_e_squared():
+    cfg = _cfg(balance_all_k=True)
+    moe = dict(_moe(), router=jnp.zeros((D, E)))
+    _, stats = llama._moe_swiglu(_x(), moe, cfg)
+    assert float(stats["moe_aux"]) == pytest.approx(1.0, rel=1e-6)
+    assert float(stats["moe_z"]) == pytest.approx(np.log(E) ** 2, rel=1e-5)
+
+
+def test_loss_fn_weighs_both_terms_and_hands_out_the_counters():
+    cfg = _cfg(n_layer=2, norm_topk_prob=False, balance_all_k=True)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jnp.asarray(
+        np.random.RandomState(0).randint(0, 250, (2, 17)))}
+    plain = llama.loss_fn(params, batch, cfg, moe_aux_weight=0.0)
+    loss, m = llama.loss_fn(params, batch, cfg, moe_aux_weight=0.5,
+                            moe_z_weight=0.25, metrics=True)
+    assert float(loss) == pytest.approx(
+        float(plain) + 0.5 * float(m["moe_aux"]) + 0.25 * float(m["moe_z"]),
+        rel=1e-6)
+    assert m["moe_tokens_per_expert"].shape == (2, E)
+    assert np.asarray(m["moe_tokens_per_expert"]).sum(1).tolist() == [
+        2 * 16 * K] * 2
+    # without the option a routed model's loss is the scalar it always was
+    assert jnp.ndim(llama.loss_fn(params, batch, cfg)) == 0
+    # and a dense model has no counters to hand out
+    dense = llama.LlamaConfig.tiny(n_layer=1, dtype=jnp.float32)
+    dense_loss = llama.loss_fn(
+        llama.init_params(jax.random.PRNGKey(0), dense), batch, dense,
+        metrics=True)
+    assert jnp.ndim(dense_loss) == 0
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_forward_hidden_hands_out_the_experts_it_took(remat):
+    cfg = _cfg(n_layer=2, moe_every=2, remat_block=remat)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    toks = jnp.asarray(np.random.RandomState(1).randint(0, 250, (2, 16)))
+    hidden, aux = jax.jit(
+        lambda p, t: llama.forward_hidden(p, t, cfg))(params, toks)
+    assert sorted(aux["moe_experts"]) == [1]  # layer 0 is dense
+    taken = aux["moe_experts"][1]
+    assert taken.shape == (2, 16, K) and taken.dtype == jnp.int32
+    assert aux["moe_tokens_per_expert"].shape == (1, E)
+    np.testing.assert_array_equal(
+        aux["moe_tokens_per_expert"][0],
+        np.bincount(np.asarray(taken).ravel(), minlength=E))
+    plain, plain_aux = llama.forward_hidden(
+        params, toks, dataclasses.replace(cfg, remat_block=False))
+    np.testing.assert_allclose(hidden, plain, atol=1e-5)
+    np.testing.assert_array_equal(taken, plain_aux["moe_experts"][1])
+    # a dense model's aux dict is what it always was
+    dense = llama.LlamaConfig.tiny(n_layer=1, dtype=jnp.float32)
+    _, dense_aux = llama.forward_hidden(
+        llama.init_params(jax.random.PRNGKey(0), dense), toks, dense)
+    assert sorted(dense_aux) == ["moe_aux"]
+
+
+def _plain_attention(x, layer, cfg, qk_norm):
+    """Causal softmax attention written out, RoPE on halves, with RMSNorm
+    over the whole q and k projections where asked."""
+    b, s, _ = x.shape
+    h, kv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+
+    def rms(v, w):
+        return v * jax.lax.rsqrt(
+            jnp.mean(v * v, -1, keepdims=True) + cfg.rms_eps) * w
+
+    q, k, v = x @ layer["wq"], x @ layer["wk"], x @ layer["wv"]
+    if qk_norm:
+        q, k = rms(q, layer["q_norm"]), rms(k, layer["k_norm"])
+    pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+    q = llama._rope(q.reshape(b, s, h, hd), pos, cfg.rope_theta)
+    k = llama._rope(k.reshape(b, s, kv, hd), pos, cfg.rope_theta)
+    k = jnp.repeat(k, h // kv, 2)
+    v = jnp.repeat(v.reshape(b, s, kv, hd), h // kv, 2)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
+    mask = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    p = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(b, s, h * hd) @ (
+        layer["wo"])
+
+
+@pytest.mark.parametrize("qk_norm", [False, True], ids=["off", "on"])
+def test_qk_norm_over_the_whole_projection(qk_norm):
+    cfg = _cfg(qk_norm=qk_norm)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    layer = params["layers"][0]
+    assert ("q_norm" in layer) == qk_norm
+    if qk_norm:
+        assert layer["q_norm"].shape == (cfg.n_head * cfg.head_dim,)
+        assert layer["k_norm"].shape == (cfg.n_kv_head * cfg.head_dim,)
+        axes = llama.param_logical_axes(cfg)["layers"][0]
+        assert axes["q_norm"] == axes["k_norm"] == (None,)
+        # gains that are not one, so that a norm per head would differ
+        layer = dict(layer,
+                     q_norm=1.0 + 0.3 * jnp.sin(jnp.arange(16.0)),
+                     k_norm=1.0 + 0.3 * jnp.cos(jnp.arange(8.0)))
+    layer = dict(layer, wq=layer["wq"] * 20, wk=layer["wk"] * 20)
+    x = _x(b=2, s=10)
+    pos = jnp.broadcast_to(jnp.arange(10), (2, 10))
+    got, _ = llama._attention(x, layer, cfg, pos, "auto", None)
+    np.testing.assert_allclose(
+        got, _plain_attention(x, layer, cfg, qk_norm), atol=2e-5)
+    if qk_norm:
+        other = _plain_attention(x, layer, cfg, False)
+        assert float(jnp.linalg.norm(got - other)
+                     / jnp.linalg.norm(other)) > 0.05
+
+
+def test_the_kv_cache_decoder_applies_the_qk_norms():
+    cfg = _cfg(qk_norm=True, norm_topk_prob=False)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    params["layers"][0]["q_norm"] = 1.0 + 0.3 * jnp.sin(jnp.arange(16.0))
+    toks = jnp.asarray(np.random.RandomState(2).randint(0, 250, (2, 9)))
+    logits, _ = llama.forward(params, toks, cfg)
+    cache = llama_infer.init_cache(cfg, 2, 16)
+    cached, cache = llama_infer.forward_step(params, toks[:, :6], cfg, cache)
+    np.testing.assert_allclose(cached, logits[:, :6], atol=2e-4)
+    for t in range(6, 9):  # one token at a time through the cache
+        step, cache = llama_infer.forward_step(
+            params, toks[:, t:t + 1], cfg, cache)
+        np.testing.assert_allclose(step[:, 0], logits[:, t], atol=2e-4)
+
+
+def _job(cfg, loss, grad_accum=None):
+    from dlrover_tpu.parallel.accelerate import Strategy, accelerate
+    from dlrover_tpu.parallel.mesh import MeshSpec
+
+    return accelerate(
+        loss_fn=loss, init_fn=lambda r: llama.init_params(r, cfg),
+        optimizer=optax.adamw(1e-3),
+        sample_batch={"tokens": np.zeros((4, 17), np.int32)},
+        strategy=Strategy(mesh=MeshSpec()), grad_accum=grad_accum,
+        devices=jax.devices()[:1])
+
+
+def _tokens():
+    return {"tokens": np.random.RandomState(0).randint(
+        0, 250, (4, 17)).astype(np.int32)}
+
+
+def test_train_step_returns_the_counters_of_a_routed_model():
+    cfg = _cfg(norm_topk_prob=False, balance_all_k=True)
+    job = _job(cfg, lambda p, b: llama.loss_fn(
+        p, b, cfg, moe_z_weight=1e-3, metrics=True))
+    state = job.create_state(jax.random.PRNGKey(0))
+    params = jax.device_get(state["params"])
+    state, metrics = job.train_step(state, _tokens())
+    assert sorted(metrics) == ["grad_norm", "loss", "moe_aux",
+                               "moe_tokens_per_expert", "moe_z"]
+    loss, want = llama.loss_fn(params, _tokens(), cfg, moe_z_weight=1e-3,
+                               metrics=True)
+    assert float(metrics["loss"]) == pytest.approx(float(loss), rel=1e-5)
+    np.testing.assert_array_equal(metrics["moe_tokens_per_expert"],
+                                  want["moe_tokens_per_expert"])
+    assert int(metrics["moe_tokens_per_expert"].sum()) == 4 * 16 * K
+
+
+def test_train_step_of_a_dense_model_returns_what_it_returned():
+    cfg = llama.LlamaConfig.tiny(n_layer=1, dtype=jnp.float32)
+    job = _job(cfg, lambda p, b: llama.loss_fn(p, b, cfg, metrics=True))
+    state = job.create_state(jax.random.PRNGKey(0))
+    _, metrics = job.train_step(state, _tokens())
+    assert sorted(metrics) == ["grad_norm", "loss"]
+
+
+def test_counters_add_up_over_grad_accum_microbatches():
+    cfg = _cfg(norm_topk_prob=False, balance_all_k=True)
+    loss = lambda p, b: llama.loss_fn(p, b, cfg, metrics=True)  # noqa: E731
+    whole, split = _job(cfg, loss), _job(cfg, loss, grad_accum=2)
+    state = whole.create_state(jax.random.PRNGKey(0))
+    _, one = whole.train_step(state, _tokens())
+    state = split.create_state(jax.random.PRNGKey(0))
+    _, two = split.train_step(state, _tokens())
+    # counts add up, the rest is averaged like the loss
+    np.testing.assert_array_equal(two["moe_tokens_per_expert"],
+                                  one["moe_tokens_per_expert"])
+    assert float(two["moe_z"]) == pytest.approx(float(one["moe_z"]),
+                                                rel=1e-4)
+    assert float(two["loss"]) == pytest.approx(float(one["loss"]), rel=1e-4)
