@@ -301,11 +301,13 @@ def mesh4_phase(cfg=None, *, batch=8, seq=2048, steps=3, seed=0,
 def restore_phase(*, leaves=4, leaf_mib=64, seed=0) -> dict:
     """Save state A to shared memory, ``load()`` it onto the device, save
     a different state B into the same arena, and compare what was
-    restored with A bit for bit.  On a TPU every piece must have gone to
-    ``device_put`` as a view of the arena (``copied_bytes`` 0), so this
-    is the proof that the restored arrays keep no alias of the mapping
-    once ``block_until_ready`` has returned; on the CPU backend, which
-    may alias a numpy buffer, the pieces are copied first."""
+    restored with A bit for bit.  On a TPU every piece must have gone
+    from the arena's file through a reused staging buffer to
+    ``device_put`` (``staged_bytes`` = the state, ``copied_bytes`` 0),
+    so this is the proof that the restored arrays keep no alias of the
+    arena or of a staging buffer once ``block_until_ready`` has
+    returned; on the CPU backend, which may alias a numpy buffer, each
+    piece is read into an array of its own."""
     import jax
     import numpy as np
 
@@ -355,7 +357,7 @@ def restore_phase(*, leaves=4, leaf_mib=64, seed=0) -> dict:
     want_copied = nbytes if on_cpu else 0
     counted = (read.get("copy") is False
                and put.get("copied_bytes") == want_copied
-               and put.get("in_place_bytes") == nbytes - want_copied)
+               and put.get("staged_bytes") == nbytes - want_copied)
     say(f"restore: {nbytes} bytes in {len(host_a)} leaves on "
         f"{dev.platform}; load {load_s:.3f}s; shm_read {json.dumps(read)}; "
         f"device_put {json.dumps(put)}")

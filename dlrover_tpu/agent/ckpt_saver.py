@@ -64,8 +64,9 @@ class AsyncCheckpointSaver:
         self._stat = SharedDict(ckpt_stat_name(job_name), create=True)
         # In-process mutex per rank: the replica thread, the save-event
         # thread and breakpoint saves share one cached arena object, and
-        # reopen() munmaps the mapping — concurrent reopen()/read_state()
-        # on the same instance is a use-after-munmap.  Always taken
+        # reopen() munmaps the mapping and closes the descriptor tensor
+        # reads go through — concurrent reopen()/read_state() on the
+        # same instance is a use-after-munmap.  Always taken
         # *inside* the cross-process fencing lock (never around it).
         # Pre-populated for every rank so lazy init can't race either.
         self._arenas: Dict[int, SharedMemoryArena] = {
@@ -279,9 +280,10 @@ class AsyncCheckpointSaver:
         if not locked:
             logger.warning("saver: lock for rank %d busy; skipping", lr)
             return
-        # Zero-copy fast path: stream the arena's mapped bytes straight to
-        # storage, holding the fencing lock + arena mutex for the whole
-        # persist (the views' lifetime contract — see
+        # Fast path: stream the arena's bytes to storage — read() chunk
+        # by chunk into one reused buffer, CRC'd and written from there —
+        # holding the fencing lock + arena mutex for the whole persist
+        # (the handles' lifetime contract — see
         # SharedMemoryArena.read_state).  A worker staging its next step
         # waits on the lock for the persist duration, exactly like the
         # reference saver; the bench measures that stall.  Copy mode —
@@ -401,6 +403,7 @@ class AsyncCheckpointSaver:
             stats = self._write_shard(
                 ckpt_dir, step, pid, tensors, extra, lr, sliced, world)
             sp.set(bytes=int(stats["total_bytes"]),
+                   read_bytes=int(stats["read_bytes"]),
                    mbps=round(stats["mbps"], 1),
                    skipped=int(stats["skipped"]))
         return stats

@@ -193,8 +193,8 @@ class CheckpointEngine:
     def _fence_arena(self) -> None:
         """Take the rank's cross-process fencing lock (agent mode) and,
         inside it, the in-process arena mutex: whoever writes the arena,
-        reads views of it or remaps it holds both for as long as that
-        lasts.  A zero-copy persist (agent saver on the fencing lock, or
+        reads its tensors or remaps it holds both for as long as that
+        lasts.  A streamed persist (agent saver on the fencing lock, or
         the standalone persist thread on the arena mutex) legitimately
         holds its lock for a WHOLE streamed storage write, which can
         exceed a minute on slow storage — waiting is correct; crashing
@@ -333,10 +333,10 @@ class CheckpointEngine:
         buffers, and an async stream from them races the next train step
         into a torn shard whose CRC (computed in the same pass over the
         same torn bytes) would still validate.  The arena holds a stable
-        staged copy; ``_arena_mu`` fences it against concurrent
-        re-staging for the duration of the zero-copy stream (the
-        ``ckpt_zero_copy=False`` knob trades that hold for one copy,
-        exactly like the agent saver)."""
+        staged copy, which the writer ``read()``s chunk by chunk;
+        ``_arena_mu`` fences it against concurrent re-staging for the
+        duration of the stream (the ``ckpt_zero_copy=False`` knob trades
+        that hold for one copy, exactly like the agent saver)."""
         with span("ckpt.persist", "ckpt", step=step, reason="save",
                   rank=self.process_id):
             self._persist_staged(step)
@@ -420,6 +420,7 @@ class CheckpointEngine:
         )
         write_span.set(rank=self.process_id, mbps=round(mbps, 1),
                        bytes=int(stats["total_bytes"]),
+                       read_bytes=int(stats["read_bytes"]),
                        skipped=int(plan.skipped))
         perf_stats.set("ckpt_persist_mbps", mbps)
         # Standalone = one rank per process: its own persist rate IS its
@@ -584,23 +585,27 @@ class CheckpointEngine:
         """Warm restore from this rank's arena, or ``None`` for "go to
         storage" (the same answer on every rank).
 
-        The arena is read as VIEWS into the mapping and each view goes
-        straight to ``jax.device_put``: no host copy of the state is
-        made in between.  What makes that safe is the hold, not a copy:
-        the rank's fencing lock keeps every other writer out (the agent
-        saver's ``seed_from_replicas`` may ``write_state`` this arena
-        after a re-rendezvous) and the arena mutex keeps the mapping
-        where it is (``reopen()`` is inside the hold, and the standalone
-        persist thread streams from the same mapping), from before the
-        views are taken until ``block_until_ready`` of the restored
+        The arena hands out one ``ArenaTensor`` a piece (dtype, shape,
+        place) and ``restore_to_target`` moves each one by ``read()`` on
+        the arena's file: into a reused staging buffer and from there to
+        ``jax.device_put`` for an accelerator, straight into an array
+        the tree owns for a host leaf or the CPU backend.  No host copy
+        of the state is made, and no page of the fresh mapping is
+        touched for a tensor byte.  What makes the reads safe is the
+        hold: the rank's fencing lock keeps every other writer out (the
+        agent saver's ``seed_from_replicas`` may ``write_state`` this
+        arena after a re-rendezvous) and the arena mutex keeps the
+        segment open (``reopen()`` is inside the hold, and the
+        standalone persist thread reads the same segment), from before
+        the header is read until ``block_until_ready`` of the restored
         state has returned.  After that no piece of the state refers to
-        the arena: a device that could alias host memory was given
-        copies (``tree_utils.restore_to_target``).
+        the arena or to a staging buffer.
 
         Without a target the ``ShardSource`` escapes to the caller with
-        unbounded lifetime, so it holds copies, made under the same
-        hold.  The saver never waits on a collective, so holding a
-        per-rank lock across the ranks' agreement cannot cycle."""
+        unbounded lifetime, so it holds arrays of its own, ``read()``
+        under the same hold.  The saver never waits on a collective, so
+        holding a per-rank lock across the ranks' agreement cannot
+        cycle."""
         self._fence_arena()
         try:
             got = self._load_from_shm(copy=target is None)
@@ -773,9 +778,11 @@ class CheckpointEngine:
         return None
 
     def _load_from_shm(self, copy: bool):
-        """Read the staged state; the caller holds :meth:`_fence_arena`
-        (``reopen()`` munmaps, and with ``copy=False`` the tensors are
-        views that are valid only inside that hold)."""
+        """Read the staged state's header and meta; the caller holds
+        :meth:`_fence_arena` (``reopen()`` closes the segment, and with
+        ``copy=False`` the tensors are ``ArenaTensor`` handles whose
+        bytes are read later, inside that hold: under
+        ``ckpt.load.device_put``)."""
         with span("ckpt.load.shm_read", "ckpt", copy=copy) as sp:
             try:
                 self._arena.reopen()

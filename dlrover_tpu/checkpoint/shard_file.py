@@ -28,7 +28,8 @@ excluded from :func:`list_steps`, restore candidates, and rotation.
 Two writers produce the same bytes: :func:`pack_shard` (reference
 implementation, materializes the blob) and :class:`ShardStreamWriter` /
 :func:`write_shard_from_views` (the hot path: streams tensor bytes
-straight from the caller's views — typically the shm arena mapping — in
+straight from the caller's arrays, or ``read()`` off the shm arena's
+file into one reused chunk buffer a worker for ``ArenaTensor`` handles, in
 bounded chunks, CRC folded into the same single pass, zero intermediate
 full-state buffers, optional parallel range workers).
 :func:`verify_shard_file` is the bounded-memory counterpart of
@@ -52,6 +53,7 @@ from dlrover_tpu.common.byte_audit import audit
 from dlrover_tpu.common.constants import CheckpointConstant as CC
 from dlrover_tpu.common.log import logger
 from dlrover_tpu.common.native import shm_lib
+from dlrover_tpu.common.shm import ArenaTensor
 from dlrover_tpu.common.storage import CheckpointStorage, drain_ranges
 
 FORMAT_VERSION = 2
@@ -173,6 +175,18 @@ def crc32_update(buf, crc: int = 0) -> int:
 def crc32_bytes(buf) -> int:
     """CRC-32 (zlib polynomial) of a whole bytes-like buffer."""
     return crc32_update(buf, 0)
+
+
+def crc32_staged(src) -> int:
+    """CRC-32 of staged tensor bytes (the dirty probe of an incremental
+    save): a bytes-like buffer, or a tensor still in the shm arena,
+    which is ``read()`` chunk by chunk into one buffer for it."""
+    if not isinstance(src, ArenaTensor):
+        return crc32_bytes(src)
+    crc = 0
+    for chunk in src.chunks(np.empty(STREAM_CHUNK_BYTES, np.uint8)):
+        crc = crc32_update(chunk, crc)
+    return crc
 
 
 def step_dir(ckpt_dir: str, step: int) -> str:
@@ -545,16 +559,22 @@ def write_shard(
 
 
 class ShardStreamWriter:
-    """Single-pass, zero-copy v2 shard writer.
+    """Single-pass v2 shard writer with no full-state buffer.
 
     Where :func:`pack_shard` materializes three full copies of the state
     (arena read copy, per-tensor ``tobytes``, blob join) before the bytes
-    ever reach storage, this writer streams tensor bytes **directly from
-    the caller's memoryviews** (typically the shm arena mapping) to the
-    storage sink in ``chunk_bytes`` chunks, folding each tensor's CRC-32
-    incrementally during that same pass.  The header+meta region — whose
-    byte length depends on those CRCs (see ``_CRC_PLACEHOLDER``) — is
-    patched in place afterwards.  Output is **byte-identical** to
+    ever reach storage, this writer streams tensor bytes to the storage
+    sink in ``chunk_bytes`` chunks, folding each tensor's CRC-32
+    incrementally during that same pass.  A tensor that is an array is
+    streamed **directly from its own memory**; one still in the shm
+    arena (:class:`ArenaTensor`, what ``read_state(copy=False)`` hands
+    out) is ``read()`` chunk by chunk into ONE buffer per range worker,
+    reused: the CRC is folded over that buffer and the buffer is what
+    the sink gets, which has written it before the next chunk is asked
+    for (``drain_ranges``: one thread a range, synchronous
+    ``write_at``).  The header+meta region — whose byte length depends
+    on the CRCs (see ``_CRC_PLACEHOLDER``) — is patched in place
+    afterwards.  Output is **byte-identical** to
     ``pack_shard(tensors, extra)`` for the same inputs.
 
     ``workers > 1`` splits the tensors into contiguous byte-balanced
@@ -562,17 +582,17 @@ class ShardStreamWriter:
     preallocated file (``CheckpointStorage.write_shard_ranges``; POSIX
     pwrite fast path, sequential on object stores).
 
-    Lifetime contract: the caller must keep the views' backing memory
-    mapped and fenced against writers for the duration of
-    :meth:`write` — the agent saver holds the per-rank fencing lock and
-    arena mutex across this call.
+    Lifetime contract: the caller must keep the tensors' backing memory
+    (or the arena) in place and fenced against writers for the duration
+    of :meth:`write` — the agent saver holds the per-rank fencing lock
+    and arena mutex across this call.
     """
 
     def __init__(
         self,
         storage: CheckpointStorage,
         path: str,
-        tensors: Dict[str, np.ndarray],
+        tensors: Dict[str, "np.ndarray | ArenaTensor"],
         extra: dict,
         *,
         workers: int = 1,
@@ -589,45 +609,49 @@ class ShardStreamWriter:
         self._damage_ctx = damage_ctx
         self._meta_extra = meta_extra or {}
         self._crcs: Dict[str, int] = {}
+        self._arena_reads: list = []  # bytes read() per tensor and pass
         self._stats: dict = {}
 
     # -- layout --------------------------------------------------------------
     def _layout(self):
-        """(placeholder metas, [(key, byte_view, rel_offset)], data_bytes) —
-        identical field order and offsets to :func:`pack_shard`."""
+        """(placeholder metas, [(key, bytes source, rel_offset, nbytes)],
+        data_bytes) — identical field order and offsets to
+        :func:`pack_shard`.  A bytes source is a memoryview of an
+        array's data, or the :class:`ArenaTensor` itself."""
         metas: Dict[str, dict] = {}
         views = []
         offset = 0
         for key, arr in self._tensors.items():
-            arr = np.asarray(arr)
-            shape = list(np.shape(arr))
-            view = _byte_view(arr)
+            if not isinstance(arr, ArenaTensor):
+                arr = np.asarray(arr)
+            nbytes = int(arr.nbytes)
             metas[key] = {
                 "dtype": _dtype_key(arr.dtype),
-                "shape": shape,
+                "shape": list(arr.shape),
                 "offset": offset,
-                "nbytes": int(arr.nbytes),
+                "nbytes": nbytes,
                 # An empty blob's CRC is exactly 0 — pin it now so a 0-d
                 # optimizer scalar or empty buffer never forces the
                 # relayout pass just to shrink a placeholder.
-                "crc32": _CRC_PLACEHOLDER if arr.nbytes else 0,
+                "crc32": _CRC_PLACEHOLDER if nbytes else 0,
             }
             if key in self._meta_extra:
                 metas[key].update(self._meta_extra[key])
-            views.append((key, view, offset))
-            offset += int(arr.nbytes)
+            src = arr if isinstance(arr, ArenaTensor) else _byte_view(arr)
+            views.append((key, src, offset, nbytes))
+            offset += nbytes
         return metas, views, offset
 
     def _partition(self, views, n: int):
         """Contiguous byte-balanced groups, one per range worker."""
         if n <= 1 or len(views) <= 1:
             return [views] if views else []
-        total = sum(len(v) for _, v, _ in views)
+        total = sum(item[3] for item in views)
         target = max(1, total // n)
         groups, cur, cur_bytes = [], [], 0
         for item in views:
             cur.append(item)
-            cur_bytes += len(item[1])
+            cur_bytes += item[3]
             if cur_bytes >= target and len(groups) < n - 1:
                 groups.append(cur)
                 cur, cur_bytes = [], 0
@@ -637,11 +661,23 @@ class ShardStreamWriter:
 
     def _gen(self, group):
         """Yield one group's tensor bytes in bounded chunks, folding each
-        tensor's CRC-32 as a side effect of the same traversal."""
-        for key, view, _rel in group:
+        tensor's CRC-32 as a side effect of the same traversal.  A chunk
+        of an arena tensor is this generator's one scratch buffer: gone
+        when the next chunk is asked for."""
+        scratch = None
+        for key, src, _rel, nbytes in group:
+            if isinstance(src, ArenaTensor):
+                if scratch is None:
+                    scratch = np.empty(self._chunk, np.uint8)
+                chunks = src.chunks(scratch)
+                self._arena_reads.append(nbytes)
+            else:
+                chunks = (
+                    src[lo : lo + self._chunk]
+                    for lo in range(0, nbytes, self._chunk)
+                )
             crc = 0
-            for lo in range(0, len(view), self._chunk):
-                chunk = view[lo : lo + self._chunk]
+            for chunk in chunks:
                 crc = crc32_update(chunk, crc)
                 audit.record_write(len(chunk))
                 yield chunk
@@ -714,6 +750,7 @@ class ShardStreamWriter:
             finalize=_finalize,
         )
         self._stats["crcs"] = dict(self._crcs)
+        self._stats["read_bytes"] = sum(self._arena_reads)
         return dict(self._stats)
 
     def _apply_chaos(self, sink, total: int) -> None:
@@ -752,11 +789,12 @@ def write_shard_from_views(
     chunk_bytes: int = STREAM_CHUNK_BYTES,
     meta_extra: Optional[Dict[str, dict]] = None,
 ) -> dict:
-    """Streamed, zero-copy counterpart of :func:`write_shard`: same file
-    bytes, same done-file vote, no intermediate full-state buffers.
-    ``tensors`` may be live shm-arena views — see
-    :class:`ShardStreamWriter` for the lifetime contract.  Returns the
-    writer's stats dict (bytes, passes, workers, per-tensor crcs)."""
+    """Streamed counterpart of :func:`write_shard`: same file bytes,
+    same done-file vote, no intermediate full-state buffers.
+    ``tensors`` may be arrays or the shm arena's :class:`ArenaTensor`
+    handles — see :class:`ShardStreamWriter` for the lifetime contract.
+    Returns the writer's stats dict (bytes, passes, workers, per-tensor
+    crcs, ``read_bytes`` fetched from the arena by ``read()``)."""
     storage.safe_makedirs(step_dir(ckpt_dir, step))
     writer = ShardStreamWriter(
         storage,

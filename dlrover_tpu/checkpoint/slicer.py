@@ -15,8 +15,9 @@ The planning layer between the checkpoint engines and the shard writer:
 
 - **Dirty fences** (:class:`DirtyTracker`): a save skips tensors whose
   staged bytes carry the same CRC fingerprint the rank persisted at its
-  *holder* step (the probe CRCs the staged views in place — for the
-  zero-copy paths these ARE the shm arena's mapped bytes — and runs on
+  *holder* step (the probe CRCs the staged bytes where they are — on
+  the streamed paths they are ``read()`` off the shm arena chunk by
+  chunk for it — and runs on
   the async persist path, never the synchronous train stall), writing
   a meta ``ref`` to the holder's bytes instead.  Chains are flattened —
   every ref targets the step physically holding the bytes — rotation
@@ -42,6 +43,7 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from dlrover_tpu.common.log import logger
+from dlrover_tpu.common.shm import ArenaTensor
 
 #: Below this size a tensor is not shredded across owners: it goes whole
 #: to one deterministically-chosen owner (hash-balanced across keys).
@@ -87,13 +89,16 @@ def _effective_owners(meta: Optional[dict], world: int) -> Optional[list]:
     return None
 
 
-def _byte_view(arr: np.ndarray) -> np.ndarray:
-    """Flat uint8 view of an array's bytes (zero-copy for the contiguous
-    staged-arena case)."""
+def _byte_range(arr, lo: int, hi: int):
+    """Bytes ``[lo, hi)`` of a staged tensor's C-order buffer, flat
+    uint8: a view of an array, a narrower handle of an
+    :class:`ArenaTensor` (nothing is read here)."""
+    if isinstance(arr, ArenaTensor):
+        return arr.byte_range(lo, hi)
     contig = np.ascontiguousarray(arr)
     if contig.nbytes == 0:
         return np.empty(0, dtype=np.uint8)
-    return contig.reshape(-1).view(np.uint8)
+    return contig.reshape(-1).view(np.uint8)[lo:hi]
 
 
 @dataclasses.dataclass
@@ -143,7 +148,7 @@ class DirtyTracker:
 class PersistPlan:
     """What one rank actually streams for one save."""
 
-    tensors: Dict[str, np.ndarray]  # payloads to write (views)
+    tensors: Dict[str, np.ndarray]  # payloads to write (or ArenaTensors)
     meta_extra: Dict[str, dict]  # per-key shard-meta overlays
     extra: dict  # shard extra (copy; ref_steps/sliced markers added)
     layout: Dict[str, Tuple[int, int, int]]  # key -> (lo, hi, full_nbytes)
@@ -169,14 +174,15 @@ def plan_persist(
     holder step's shard file is still on storage before a ref may target
     it — a holder lost to GC/quarantine forces a rewrite, never a
     dangling reference.  The dirty probe CRCs the staged slice bytes
-    in-process (memory speed); the writes it avoids run at storage-link
+    in-process (memory speed; a tensor still in the arena is ``read()``
+    chunk by chunk for it); the writes it avoids run at storage-link
     speed, which is the asymmetry incremental saves monetize.
 
     Registered as a sim-bound pure policy (graftcheck DET70x): slice
     assignment is a function of (tensors, process_id, num_processes)
     only — no ambient effects, so every rank computes the identical
     partition without coordination."""
-    from dlrover_tpu.checkpoint.shard_file import crc32_bytes, _dtype_key
+    from dlrover_tpu.checkpoint.shard_file import _dtype_key, crc32_staged
 
     info = extra.get("tensors_info") or {}
     out: Dict[str, np.ndarray] = {}
@@ -193,7 +199,8 @@ def plan_persist(
     # destroy the only copy of the bytes.
     cur_step = extra.get("step")
     for key, arr in tensors.items():
-        arr = np.asarray(arr)
+        if not isinstance(arr, ArenaTensor):
+            arr = np.asarray(arr)
         n = int(arr.nbytes)
         logical += n
         owners = _effective_owners(info.get(key), num_processes)
@@ -216,13 +223,13 @@ def plan_persist(
         part = (lo, hi) != (0, n)
         base_meta = {
             "dtype": _dtype_key(arr.dtype),
-            "shape": list(np.shape(arr)),
+            "shape": list(arr.shape),
         }
         if part:
             base_meta["slice"] = [lo, hi]
             base_meta["full_nbytes"] = n
         layout[key] = (lo, hi, n)
-        view = _byte_view(arr)[lo:hi] if part else None
+        view = _byte_range(arr, lo, hi) if part else None
         h = tracker.holder(key) if tracker is not None else None
         if (
             h is not None
@@ -234,8 +241,8 @@ def plan_persist(
             if alive is None:
                 alive = bool(holder_exists(h.step)) if holder_exists else False
                 holder_alive[h.step] = alive
-            probe = view if view is not None else _byte_view(arr)
-            if alive and crc32_bytes(probe) == h.crc32:
+            probe = view if view is not None else _byte_range(arr, 0, n)
+            if alive and crc32_staged(probe) == h.crc32:
                 # Fence untripped: reference the holder's bytes.  The
                 # payload written is EMPTY, so full_nbytes must ride the
                 # meta even for unsliced entries — the coverage proof

@@ -22,6 +22,7 @@ import jax
 from jax.tree_util import keystr, tree_flatten_with_path, tree_unflatten
 
 from dlrover_tpu.common.byte_audit import audit
+from dlrover_tpu.common.shm import ArenaTensor
 
 
 def _norm_index(index, shape) -> Tuple[Tuple[int, int], ...]:
@@ -115,8 +116,9 @@ class ShardSource:
     region uncovered and the restore ladder falls back)."""
 
     def __init__(self):
-        # path -> list of (index, np.ndarray)
-        self.pieces: Dict[str, List[Tuple[Tuple[Tuple[int, int], ...], np.ndarray]]] = {}
+        # path -> list of (index, piece); a piece is an array, or — on
+        # the warm shm restore — an ArenaTensor still to be read
+        self.pieces: Dict[str, List[Tuple[Tuple[Tuple[int, int], ...], Any]]] = {}
         # (path, index) -> {"full", "dtype", "shape", "parts": {(lo,hi): bytes}}
         self._partial: Dict[Tuple[str, tuple], dict] = {}
 
@@ -178,8 +180,10 @@ class ShardSource:
         self, path: str, index: Tuple[Tuple[int, int], ...], dtype=None
     ) -> Optional[np.ndarray]:
         """Build the sub-array of leaf ``path`` covering ``index`` from the
-        available pieces.  Exact-match fast path; otherwise overlap-copy
-        (resharding).  Returns None if any region is uncovered."""
+        available pieces.  Exact-match fast path (the piece as it is: an
+        :class:`ArenaTensor` stays unread); otherwise overlap-copy
+        (resharding; an arena piece is read for it, one at a time).
+        Returns None if any region is uncovered."""
         pieces = self.pieces.get(path)
         if not pieces:
             return None
@@ -202,6 +206,8 @@ class ShardSource:
                 src_sl.append(slice(lo - ps, hi - ps))
             if not ok:
                 continue
+            if isinstance(arr, ArenaTensor):
+                arr = arr.read()
             out[tuple(dst_sl)] = arr[tuple(src_sl)]
             if covered is not None:
                 covered[tuple(dst_sl)] = True
@@ -213,12 +219,10 @@ class ShardSource:
 def _owned(piece: np.ndarray) -> np.ndarray:
     """Ensure a restored piece owns its bytes.
 
-    ``assemble()``'s exact-match fast path returns the source array
-    itself, which on the warm shm restore is a VIEW into the live arena.
-    Such a view is valid only while ``CheckpointEngine`` holds the rank's
-    fencing lock and the arena mutex, so it must not reach the restored
-    tree.  ``base is not None`` is exactly "this array borrows someone
-    else's buffer"; storage-restored pieces (``unpack_shard`` copies) and
+    ``base is not None`` is exactly "this array borrows someone else's
+    buffer" (a caller's live host shard, a slice of a larger read); such
+    a view must not reach a restored tree that could go on referring to
+    it.  Storage-restored pieces (``unpack_shard`` copies) and
     overlap-assembled pieces (fresh ``np.empty``) pass through
     untouched."""
     piece = np.asarray(piece)
@@ -234,15 +238,39 @@ def _may_alias_host(device) -> bool:
     return device.platform == "cpu"
 
 
-def _hand_over(piece, keep: bool, tally: Dict[str, int]) -> np.ndarray:
-    """One piece on its way into the restored tree: made to own its
-    bytes when the destination would ``keep`` referring to them, as it
-    is otherwise.  ``tally`` counts both kinds."""
-    piece = np.asarray(piece)
-    out = _owned(piece) if keep else piece
-    kind = "in_place_bytes" if out is piece else "copied_bytes"
-    tally[kind] += int(out.nbytes)
-    return out
+class _Staging:
+    """The host side of arena -> accelerator: two reused buffers, each
+    as large as the largest arena piece of the restore.  A piece is
+    ``read()`` into a buffer and ``device_put`` from there; the buffer
+    is refilled only after the put that read it is ready
+    (``device_put`` returns before the bytes have left the host).  Two
+    buffers let the read of one piece run beside the transfer of the
+    one before; their pages are touched once, by the first pieces, and
+    host memory stays a constant however large the state is."""
+
+    def __init__(self, capacity: int):
+        self._capacity = int(capacity)
+        self._bufs: List[Optional[np.ndarray]] = [None, None]
+        self._in_flight: List[Any] = [None, None]
+        self._turn = 0
+
+    def put(self, piece: ArenaTensor, device):
+        i = self._turn
+        self._turn = (i + 1) % len(self._bufs)
+        if self._in_flight[i] is not None:
+            jax.block_until_ready(self._in_flight[i])
+            self._in_flight[i] = None
+        if self._bufs[i] is None:
+            self._bufs[i] = np.empty(self._capacity, dtype=np.uint8)
+        arr = jax.device_put(piece.read(out=self._bufs[i]), device)
+        self._in_flight[i] = arr
+        return arr
+
+    def drain(self) -> None:
+        """Wait for every put still reading a buffer: after this the
+        buffers may go."""
+        jax.block_until_ready([a for a in self._in_flight if a is not None])
+        self._in_flight = [None] * len(self._bufs)
 
 
 def _leaf_placements(leaf):
@@ -272,49 +300,75 @@ def restore_to_target(
     mesh the saving world never had) are rebuilt shard-by-shard on their
     devices; others become full np arrays.
 
-    A piece that borrows its bytes (a view into the shm arena) is copied
-    only where the restored tree would otherwise keep referring to them:
-    bound for a host leaf, or for a device that may alias host memory
-    (:func:`_may_alias_host`).  Every other piece goes to ``device_put``
-    as it is, so the caller keeps borrowed bytes valid and unwritten
-    until ``jax.block_until_ready`` of the result has returned.
-    ``tally``, when given, receives ``in_place_bytes`` and
-    ``copied_bytes``: what was handed on as it was, and what was copied
-    on the host first."""
-    count = {"in_place_bytes": 0, "copied_bytes": 0}
+    A piece still in the shm arena (:class:`ArenaTensor`) bound for an
+    accelerator is ``read()`` into a reused staging buffer and
+    ``device_put`` from there (:class:`_Staging`); bound for a host leaf,
+    or for a device that may alias host memory
+    (:func:`_may_alias_host`), it is ``read()`` straight into an array
+    the restored tree owns.  The caller keeps the arena readable and
+    unwritten for the length of this call.  A piece that is an array
+    goes to ``device_put`` as it is, copied first only if it borrows its
+    bytes and the destination could keep referring to them.  ``tally``,
+    when given, receives ``staged_bytes`` (reached a device through a
+    staging buffer) and ``copied_bytes`` (read or copied into an array
+    of their own on the host)."""
+    count = {"staged_bytes": 0, "copied_bytes": 0}
+    staging = _Staging(max(
+        (p.nbytes for ps in source.pieces.values() for _, p in ps
+         if isinstance(p, ArenaTensor)),
+        default=0,
+    ))
+
+    def place(piece, device=None):
+        """One piece on its way into the restored tree; ``device`` None
+        is a host leaf."""
+        keep = device is None or _may_alias_host(device)
+        if isinstance(piece, ArenaTensor):
+            if not keep:
+                count["staged_bytes"] += piece.nbytes
+                return staging.put(piece, device)
+            out = piece.read()
+            count["copied_bytes"] += piece.nbytes
+        else:
+            piece = np.asarray(piece)
+            out = _owned(piece) if keep else piece
+            if out is not piece:
+                count["copied_bytes"] += int(out.nbytes)
+        return out if device is None else jax.device_put(out, device)
+
     flat, treedef = jax.tree_util.tree_flatten(target)
     paths_leaves = tree_flatten_with_path(target)[0]
     out_leaves = []
-    for (path, leaf) in paths_leaves:
-        name = keystr(path)
-        placed = _leaf_placements(leaf)
-        if placed is not None:
-            sharding, gshape, placements = placed
-            arrays = []
-            for device, index in placements:
-                idx = _norm_index(index, gshape)
-                piece = source.assemble(name, idx, dtype=leaf.dtype)
+    try:
+        for (path, leaf) in paths_leaves:
+            name = keystr(path)
+            placed = _leaf_placements(leaf)
+            if placed is not None:
+                sharding, gshape, placements = placed
+                arrays = []
+                for device, index in placements:
+                    idx = _norm_index(index, gshape)
+                    piece = source.assemble(name, idx, dtype=leaf.dtype)
+                    if piece is None:
+                        raise KeyError(
+                            f"checkpoint missing data for {name} index {idx}"
+                        )
+                    arrays.append(place(piece, device))
+                restored = jax.make_array_from_single_device_arrays(
+                    gshape, sharding, arrays
+                )
+                out_leaves.append(restored)
+            else:
+                shape = tuple(getattr(leaf, "shape", np.shape(leaf)))
+                full_idx = tuple((0, d) for d in shape)
+                piece = source.assemble(
+                    name, full_idx, dtype=getattr(leaf, "dtype", None)
+                )
                 if piece is None:
-                    raise KeyError(
-                        f"checkpoint missing data for {name} index {idx}"
-                    )
-                arrays.append(jax.device_put(
-                    _hand_over(piece, _may_alias_host(device), count),
-                    device,
-                ))
-            restored = jax.make_array_from_single_device_arrays(
-                gshape, sharding, arrays
-            )
-            out_leaves.append(restored)
-        else:
-            shape = tuple(getattr(leaf, "shape", np.shape(leaf)))
-            full_idx = tuple((0, d) for d in shape)
-            piece = source.assemble(
-                name, full_idx, dtype=getattr(leaf, "dtype", None)
-            )
-            if piece is None:
-                raise KeyError(f"checkpoint missing data for {name}")
-            out_leaves.append(_hand_over(piece, True, count))
+                    raise KeyError(f"checkpoint missing data for {name}")
+                out_leaves.append(place(piece))
+    finally:
+        staging.drain()
     if count["copied_bytes"]:
         audit.record_copy(count["copied_bytes"], "restore_owned_copy")
     if tally is not None:

@@ -87,13 +87,6 @@ def shm_lib() -> Optional[ctypes.CDLL]:
         lib.shm_arena_unlink.argtypes = [ctypes.c_char_p]
         lib.shm_arena_close.restype = ctypes.c_int
         lib.shm_arena_close.argtypes = [ctypes.c_int]
-        lib.shm_parallel_memcpy.restype = None
-        lib.shm_parallel_memcpy.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_void_p,
-            ctypes.c_uint64,
-            ctypes.c_int,
-        ]
         lib.shm_crc32.restype = ctypes.c_uint32
         lib.shm_crc32.argtypes = [ctypes.c_void_p, ctypes.c_uint64, ctypes.c_uint32]
         lib._sigs_set = True

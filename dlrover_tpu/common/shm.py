@@ -16,8 +16,21 @@ tensor bytes first, then meta, then the header's ``meta_len``/``commit_count``
 — a reader that sees a consistent header+crc sees consistent data.
 
 Two backends: the C++ native one (``native/shm_arena.cc`` via ctypes —
-shm_open/mmap with multi-threaded memcpy, no Python resource-tracker
-interference) and a ``multiprocessing.shared_memory`` fallback.
+shm_open/mmap, no Python resource-tracker interference) and a
+``multiprocessing.shared_memory`` fallback.
+
+The mapping serves the header and the meta blob (a few KB), on both
+sides.  Tensor bytes enter and leave the arena by positional
+``write()``/``read()`` on the segment's file (:func:`_pwrite_full`,
+:class:`ArenaTensor`), from and into memory the caller owns: each
+process has just made its mapping, and where a fault on a fresh
+shared-memory page is dear (gVisor: ~35 us a 4 KiB page, once a mapping,
+no fault-around) touching the 1.4 M pages of a 5.76 GB state costs most
+of a minute — for the first save, for the agent's persist, for the
+restore and (once the restore no longer faulted the pages in as a side
+effect) for the restarted worker's first save — while ``read()`` and
+``write()`` of the same file pay none of it and are one memcpy anywhere
+else.
 """
 
 from __future__ import annotations
@@ -60,6 +73,117 @@ class TensorMeta:
     nbytes: int
 
 
+def _pwrite_full(fd: int, buf, offset: int, what: str) -> None:
+    """Write ``buf`` (C-contiguous bytes) to ``fd`` at ``offset``; the
+    positional twin of :func:`_pread_full`."""
+    if fd < 0:
+        raise ValueError(f"shm segment {what} is closed")
+    mv = memoryview(buf).cast("B")
+    done = 0
+    while done < len(mv):
+        done += os.pwrite(fd, mv[done:], offset + done)
+
+
+def _pread_full(fd: int, buf, offset: int, what: str) -> None:
+    """Fill ``buf`` (writable, C-contiguous bytes) from ``fd`` starting
+    at ``offset``.  Positional, so threads share the descriptor freely;
+    one call moves at most ~2 GiB on Linux, hence the loop."""
+    if fd < 0:
+        raise ValueError(f"shm segment {what} is closed")
+    mv = memoryview(buf).cast("B")
+    done = 0
+    while done < len(mv):
+        got = os.preadv(fd, [mv[done:]], offset + done)
+        if got <= 0:
+            raise OSError(
+                errno.EIO,
+                f"shm segment {what}: short read at {offset + done} "
+                f"({len(mv) - done} bytes missing)",
+            )
+        done += got
+
+
+class ArenaTensor:
+    """One staged tensor as :meth:`SharedMemoryArena.read_state` hands
+    it to a bulk consumer: what it is (``dtype``, ``shape``, ``nbytes``)
+    and where its bytes lie in the segment — never the bytes, and never
+    a view of the mapping.  The bytes are fetched by ``read()`` on the
+    segment's file into memory the consumer owns: a fresh array
+    (:meth:`read`), a reused buffer (:meth:`read`'s ``out``,
+    :meth:`chunks`), any window (:meth:`read_into`).
+
+    Valid under the hold :meth:`SharedMemoryArena.read_state` describes;
+    after the arena was closed or re-opened a read raises."""
+
+    __slots__ = ("_seg", "dtype", "shape", "offset", "nbytes")
+
+    def __init__(self, seg, dtype, shape, offset: int, nbytes: int):
+        self._seg = seg
+        self.dtype = np.dtype(dtype)
+        self.shape = tuple(int(d) for d in shape)
+        self.offset = int(offset)
+        self.nbytes = int(nbytes)
+
+    def __repr__(self) -> str:
+        return (f"ArenaTensor({self.dtype.name}{list(self.shape)} "
+                f"@{self.offset}+{self.nbytes})")
+
+    def __array__(self, *_args, **_kwargs):
+        # np.asarray() of a handle would otherwise make a 0-d object
+        # array and every consumer downstream would write garbage
+        raise TypeError(
+            "ArenaTensor holds no bytes: call read(), read_into() or "
+            "chunks()"
+        )
+
+    def byte_range(self, lo: int, hi: int) -> "ArenaTensor":
+        """Bytes ``[lo, hi)`` of this tensor's C-order buffer, as a
+        flat uint8 handle (a slice of a sliced persist)."""
+        lo, hi = int(lo), int(hi)
+        if not 0 <= lo <= hi <= self.nbytes:
+            raise ValueError(f"byte range [{lo}, {hi}) outside {self!r}")
+        return ArenaTensor(
+            self._seg, np.uint8, (hi - lo,), self.offset + lo, hi - lo
+        )
+
+    def read_into(self, buf, lo: int = 0) -> int:
+        """Fill ``buf`` (uint8 array, bytearray or writable memoryview)
+        with this tensor's bytes from ``lo`` on: ``len(buf)`` of them,
+        or what is left.  Returns the count."""
+        mv = memoryview(buf).cast("B")
+        n = min(len(mv), self.nbytes - lo)
+        if n > 0:
+            _pread_full(
+                self._seg._fd, mv[:n], self.offset + lo, self._seg.name
+            )
+        return max(n, 0)
+
+    def read(self, out: Optional[np.ndarray] = None) -> np.ndarray:
+        """The tensor, typed and shaped, in memory the caller owns: a
+        fresh array, or the head of ``out`` (a flat uint8 buffer of at
+        least ``nbytes``, whose earlier contents are gone)."""
+        if out is None:
+            arr = np.empty(self.shape, dtype=self.dtype)
+            self.read_into(arr.reshape(-1).view(np.uint8))
+            return arr
+        if out.dtype != np.uint8 or out.ndim != 1 or out.size < self.nbytes:
+            raise ValueError(
+                f"staging buffer {out.dtype}{list(out.shape)} cannot "
+                f"take {self!r}"
+            )
+        head = out[: self.nbytes]
+        self.read_into(head)
+        return head.view(self.dtype).reshape(self.shape)
+
+    def chunks(self, buf: np.ndarray):
+        """The tensor's bytes in order, ``len(buf)`` at a time, each
+        yielded as a memoryview of ``buf`` refilled: a chunk is gone
+        once the next one is asked for."""
+        mv = memoryview(buf).cast("B")
+        for lo in range(0, self.nbytes, len(mv)):
+            yield mv[: self.read_into(mv, lo)]
+
+
 def _required_size(flat: Dict[str, np.ndarray], meta_capacity: int) -> int:
     data = sum(int(a.nbytes) for a in flat.values())
     # Round each tensor start to 128B for aligned copies.
@@ -97,23 +221,16 @@ class _NativeSegment:
             ctypes.cast(ptr, ctypes.POINTER(ctypes.c_ubyte)), shape=(self.size,)
         )
 
-    def memcpy_in(self, offset: int, src: np.ndarray) -> None:
-        src = np.ascontiguousarray(src)
-        n = src.nbytes
-        if n >= (1 << 22):
-            self._lib.shm_parallel_memcpy(
-                self._ptr + offset, src.ctypes.data, n, 0
-            )
-        else:
-            self.buf[offset : offset + n] = src.reshape(-1).view(np.uint8)
-
     def crc32(self, offset: int, n: int) -> int:
         return int(self._lib.shm_crc32(self._ptr + offset, n, 0))
 
     def close(self, unlink: bool = False) -> None:
+        # a handle that outlives the segment must fail, not read
+        # whichever file the descriptor's number names next
+        fd, self._fd = self._fd, -1
         try:
             self._lib.shm_arena_unmap(self._ptr, self.size)
-            self._lib.shm_arena_close(self._fd)
+            self._lib.shm_arena_close(fd)
             if unlink:
                 self._lib.shm_arena_unlink(("/" + self.name.lstrip("/")).encode())
         # graftcheck: disable=CC104 -- teardown path: the peer may have
@@ -159,11 +276,13 @@ class _PySegment:
             pass
         self.size = self._shm.size
         self.buf = np.frombuffer(self._shm.buf, dtype=np.uint8)
-
-    def memcpy_in(self, offset: int, src: np.ndarray) -> None:
-        src = np.ascontiguousarray(src)
-        n = src.nbytes
-        self.buf[offset : offset + n] = src.reshape(-1).view(np.uint8)
+        try:
+            self._fd = os.open(
+                f"/dev/shm/{name.lstrip('/')}", os.O_RDWR | os.O_CLOEXEC
+            )
+        except OSError:
+            self._shm.close()
+            raise
 
     def crc32(self, offset: int, n: int) -> int:
         import zlib
@@ -173,7 +292,10 @@ class _PySegment:
         return zlib.crc32(self.buf[offset : offset + n]) & 0xFFFFFFFF
 
     def close(self, unlink: bool = False) -> None:
+        fd, self._fd = self._fd, -1
         try:
+            if fd >= 0:
+                os.close(fd)
             self.buf = None
             self._shm.close()
             if unlink:
@@ -303,7 +425,12 @@ class SharedMemoryArena:
         for path, arr in flat.items():
             arr = np.asarray(arr)
             offset = (offset + 127) & ~127  # 128B alignment
-            seg.memcpy_in(offset, arr)
+            if arr.nbytes:
+                _pwrite_full(
+                    seg._fd,
+                    np.ascontiguousarray(arr).reshape(-1).view(np.uint8),
+                    offset, seg.name,
+                )
             # dtype.name round-trips extended types (bfloat16/fp8 via
             # ml_dtypes) where dtype.str degrades to raw void ('<V2').
             try:
@@ -371,7 +498,9 @@ class SharedMemoryArena:
         return vals
 
     def reopen(self) -> None:
-        """Re-map the segment (it may have been re-created bigger)."""
+        """Re-map the segment (it may have been re-created bigger); its
+        descriptor, which the tensor reads go through, is re-opened with
+        it."""
         if self._seg is not None:
             self._seg.close()
             self._seg = None
@@ -422,39 +551,45 @@ class SharedMemoryArena:
 
     def read_state(
         self, copy: bool = True
-    ) -> Optional[Tuple[Dict[str, np.ndarray], dict]]:
-        """Read the staged state.
+    ) -> Optional[Tuple[Dict[str, "np.ndarray | ArenaTensor"], dict]]:
+        """Read the staged state: header and meta through the mapping,
+        tensor bytes by ``read()`` on the segment's file — no consumer
+        walks the mapping page by page.
 
-        ``copy=False`` returns **views into the live shm mapping** — the
-        flash-checkpoint zero-copy fast path (the saver's streamed
-        persist, the reshard mover, and the warm restore, which hands
-        each view to ``jax.device_put``).  Lifetime contract: the views
-        are valid only while (a) this arena object stays mapped (no
-        concurrent :meth:`reopen`/:meth:`close` — callers hold their
-        arena mutex) and (b) every writer is fenced out (the per-rank
-        SharedLock), since a concurrent :meth:`write_state` would rewrite
-        the bytes under them.  The caller takes both BEFORE this call
-        and keeps them until the last consumer is done with the views
-        (for a ``device_put``: until ``block_until_ready`` has returned).
+        ``copy=False`` returns an :class:`ArenaTensor` per tensor: dtype,
+        shape and place, and the means to fill a buffer from it.  It is
+        for a consumer that moves the bytes on while it holds the arena
+        (the saver's streamed persist, chunk by chunk into one reused
+        buffer; the warm restore, piece by piece into a staging buffer
+        and from there to ``jax.device_put``).  Lifetime contract: a
+        handle reads the live segment, so it is valid only while (a)
+        this arena object stays open (no concurrent
+        :meth:`reopen`/:meth:`close` — callers hold their arena mutex)
+        and (b) every writer is fenced out (the per-rank SharedLock),
+        since a concurrent :meth:`write_state` would rewrite the bytes
+        under it.  The caller takes both BEFORE this call and keeps them
+        until the last byte has been read (for a restore: until
+        ``block_until_ready`` has returned).
 
-        ``copy=True`` is for a consumer that outlives that hold (the
-        replica push, whose payload is shipped after the lock is
-        released; a ``ShardSource`` returned to the caller of
-        ``load()``).  The copy is itself a read of the live bytes: it
-        needs the same hold for as long as it runs — ``dirty`` is looked
-        at once, before the first tensor."""
+        ``copy=True`` reads every tensor into an array of its own, for
+        a consumer that outlives that hold (the replica push, whose
+        payload is shipped after the lock is released; a
+        ``ShardSource`` returned to the caller of ``load()``; the
+        reshard mover's source).  It needs the same hold for as long as
+        it runs — ``dirty`` is looked at once, before the first
+        tensor."""
         meta = self.metadata()
         if meta is None:
             return None
-        out: Dict[str, np.ndarray] = {}
+        out: Dict[str, "np.ndarray | ArenaTensor"] = {}
         nbytes_total = 0
         for path, tm in meta["tensors"].items():
-            dtype = np.dtype(tm["dtype"])
-            n = tm["nbytes"]
-            view = self._seg.buf[tm["offset"] : tm["offset"] + n]
-            arr = view.view(dtype).reshape(tuple(tm["shape"]))
-            out[path] = arr.copy() if copy else arr
-            nbytes_total += n
+            handle = ArenaTensor(
+                self._seg, tm["dtype"], tm["shape"], tm["offset"],
+                tm["nbytes"],
+            )
+            out[path] = handle.read() if copy else handle
+            nbytes_total += handle.nbytes
         if copy:
             audit.record_copy(nbytes_total, "arena_read_copy")
         return out, meta["extra"]

@@ -10,8 +10,9 @@ that to the JAX/pjit stack:
   segments, with a validator proving the segments tile every target shard
   exactly once.  Zero processes needed; the same plans drive the
   checkpoint engine's restore-to-any-mesh.
-- :mod:`mover` — segment execution: intra-host segments stream zero-copy
-  from the shm arena's mapped views, cross-host segments ride a
+- :mod:`mover` — segment execution: intra-host segments are copied out
+  of the rank's staged shards (the shm arena's tensors ``read()`` into
+  arrays, or views of live host shards), cross-host segments ride a
   replica-ring-style RPC with CRC-32-verified payloads.
 - :mod:`coordinator` — orchestration: quiesce at a step boundary, execute
   the plan, rebuild the mesh and re-jit on the new world without process

@@ -2,10 +2,10 @@
 
 Two substrates, chosen per segment by the plan's rank topology:
 
-- **intra-host** segments are numpy-level copies out of zero-copy views —
-  the shm arena's ``read_state(copy=False)`` mapping (PR 4's lifetime
-  contract: views stay valid while the arena stays mapped and the writer
-  is fenced out) or the live state's host shards;
+- **intra-host** segments are numpy-level copies out of the rank's
+  staged shards — the shm arena's tensors, ``read()`` into arrays of
+  their own (:meth:`LocalShardSource.from_arena`), or zero-copy views of
+  the live state's host shards;
 - **cross-host** segments ride a replica-ring-style RPC
   (:class:`ReshardPeer`): the destination pulls each segment from the
   source rank's published shard table, and every payload carries a CRC-32
@@ -53,9 +53,9 @@ def _local_slices(box: Box, src_box: Box) -> Tuple[slice, ...]:
 
 class LocalShardSource:
     """One rank's staged shards: ``{key: array}`` plus each key's global
-    box.  Arrays may be zero-copy views (arena mapping, live host
-    shards); :meth:`segment_view` never copies — the caller does, into
-    the destination buffer."""
+    box.  Arrays may be zero-copy views (live host shards);
+    :meth:`segment_view` never copies — the caller does, into the
+    destination buffer."""
 
     def __init__(
         self,
@@ -70,15 +70,18 @@ class LocalShardSource:
 
     @classmethod
     def from_arena(cls, arena) -> "LocalShardSource":
-        """Zero-copy source over a staged shm arena: the tensors are
-        ``read_state(copy=False)`` VIEWS into the live mapping, so the
-        caller owns PR 4's lifetime contract — keep the arena mapped (no
-        reopen/close) and the writer fenced (the per-rank SharedLock /
-        arena mutex) for as long as this source — or anything published
-        from it — is readable.  Raises when the arena holds no valid
-        staged state (a torn/mid-write arena must fail the move, which
-        lands the resize on the restart ladder, not on torn bytes)."""
-        read = arena.read_state(copy=False)
+        """Source over a staged shm arena.  The tensors are
+        ``read_state(copy=True)`` arrays — ``read()`` off the arena's
+        file like every other bulk consumer's bytes, into memory the
+        source owns — so the caller must keep the arena open and the
+        writer fenced (the per-rank SharedLock / arena mutex) only for
+        the length of this call; the source, and anything published
+        from it, stays readable afterwards.  The price is one host copy
+        of the rank's staged state for the length of the move.  Raises
+        when the arena holds no valid staged state (a torn/mid-write
+        arena must fail the move, which lands the resize on the restart
+        ladder, not on torn bytes)."""
+        read = arena.read_state(copy=True)
         if read is None:
             raise ReshardMoveError(
                 f"arena {arena.name} holds no staged state"
@@ -173,9 +176,10 @@ class ReshardPeer:
     """Agent-side segment server + puller for one rank.
 
     ``publish`` exposes this rank's staged shards for the duration of a
-    resize epoch (views are NOT copied — same lifetime contract as
-    ``read_state(copy=False)``: keep the arena mapped and the writer
-    fenced until :meth:`unpublish`); peers discover each other through
+    resize epoch (the arrays are NOT copied: where they are views of
+    live host shards, keep those alive and unwritten until
+    :meth:`unpublish`; a :meth:`LocalShardSource.from_arena` source
+    owns its bytes); peers discover each other through
     the master KV store under ``reshard/addr/{rank}``, exactly like the
     replica ring."""
 

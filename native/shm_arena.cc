@@ -2,23 +2,20 @@
 //
 // TPU-native analogue of the reference's pure-Python shm path
 // (dlrover/python/elastic_agent/torch/ckpt_saver.py:148 _create_shared_memory
-// + SharedMemoryHandler memcpy) — the copy path is the latency-critical part
-// of flash checkpointing (device -> host DRAM -> shm), so it lives in C++:
-// POSIX shm_open/mmap lifecycle, multi-threaded memcpy, and crc32c-style
-// checksums for shard integrity on restore.
+// + SharedMemoryHandler): POSIX shm_open/mmap lifecycle without Python's
+// resource tracker, and crc32c-style checksums for shard integrity on
+// restore.  Tensor bytes move by pwrite()/pread() on the segment's
+// descriptor from Python (common/shm.py): no copy loop lives here.
 //
 // Exposed as a plain C ABI consumed from Python via ctypes (no pybind11 in
 // this image).
 
 #include <cerrno>
 #include <cstdint>
-#include <cstring>
 #include <fcntl.h>
 #include <sys/mman.h>
 #include <sys/stat.h>
-#include <thread>
 #include <unistd.h>
-#include <vector>
 
 extern "C" {
 
@@ -70,32 +67,6 @@ int shm_arena_unlink(const char* name) {
 }
 
 int shm_arena_close(int fd) { return close(fd) == 0 ? 0 : -errno; }
-
-// Multi-threaded memcpy: the host DRAM -> shm staging copy.  With pinned
-// host buffers this saturates memory bandwidth well before thread count
-// matters; nthreads<=0 picks hardware_concurrency.
-void shm_parallel_memcpy(void* dst, const void* src, uint64_t n,
-                         int nthreads) {
-  if (nthreads <= 0) {
-    nthreads = (int)std::thread::hardware_concurrency();
-    if (nthreads <= 0) nthreads = 1;
-  }
-  if (n < (uint64_t)(1 << 22) || nthreads == 1) {  // <4MB: single memcpy
-    memcpy(dst, src, n);
-    return;
-  }
-  std::vector<std::thread> ts;
-  uint64_t chunk = (n + nthreads - 1) / nthreads;
-  for (int i = 0; i < nthreads; ++i) {
-    uint64_t off = (uint64_t)i * chunk;
-    if (off >= n) break;
-    uint64_t len = (off + chunk > n) ? (n - off) : chunk;
-    ts.emplace_back([=] {
-      memcpy((char*)dst + off, (const char*)src + off, len);
-    });
-  }
-  for (auto& t : ts) t.join();
-}
 
 // CRC-32 (zlib polynomial, table-driven) for shard integrity checks.
 static uint32_t kCrcTable[256];

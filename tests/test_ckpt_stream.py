@@ -7,17 +7,22 @@ criterion — exactly one pass over the state bytes with zero intermediate
 full-state copies, counted by the byte-audit test hook.
 """
 
+import contextlib
 import io
 import os
 
 import numpy as np
 import pytest
 
-from dlrover_tpu import chaos
+from dlrover_tpu import chaos, obs
 from dlrover_tpu.checkpoint import fsck, shard_file
 from dlrover_tpu.common.byte_audit import audit
 from dlrover_tpu.common.shm import SharedMemoryArena
-from dlrover_tpu.common.storage import CheckpointStorage, PosixDiskStorage
+from dlrover_tpu.common.storage import (
+    CheckpointStorage,
+    PosixDiskStorage,
+    _BufferShardSink as _BufferShardSinkBase,
+)
 
 
 def _mixed_tensors():
@@ -167,6 +172,175 @@ class TestSinglePassZeroCopy:
             assert snap["copied_bytes"] == 0
         finally:
             arena.close(unlink=True)
+
+
+class _CheckingSink(_BufferShardSinkBase):
+    """A sink that looks at every chunk AT ``write_at`` time: the bytes
+    it is handed must be the file's bytes at that offset right now —
+    which a reused buffer refilled before it was written would not be.
+    Concurrent ``write_at`` is allowed, so range workers really fan
+    out.  Also notes which buffers the chunks came from."""
+
+    parallel_safe = True
+
+    def __init__(self, want: bytes, seen_buffers: set):
+        super().__init__()
+        self._want = want
+        self._seen = seen_buffers
+        self.bad = []
+
+    def write_at(self, data, offset):
+        view = memoryview(data)
+        if bytes(view) != self._want[offset : offset + len(view)]:
+            self.bad.append(offset)
+        if len(view) and isinstance(view.obj, np.ndarray):
+            root = view.obj
+            while root.base is not None and isinstance(root.base, np.ndarray):
+                root = root.base
+            self._seen.add((root.ctypes.data, root.nbytes))
+        return super().write_at(data, offset)
+
+
+class _CheckingStorage(PosixDiskStorage):
+    def __init__(self, want: bytes):
+        self.want = want
+        self.buffers = set()
+        self.sinks = []
+        self.out = None
+
+    @contextlib.contextmanager
+    def stream_writer(self, path):
+        sink = _CheckingSink(self.want, self.buffers)
+        self.sinks.append(sink)
+        yield sink
+        self.out = sink.getvalue()
+
+
+class TestArenaFedPersist:
+    """ISSUE 28: tensors still in the shm arena reach the shard by
+    ``read()`` into one reused chunk buffer a range worker — same file
+    bytes as ``pack_shard``, same CRCs, one data pass, no state-sized
+    buffer."""
+
+    CHUNK = 1 << 16  # the writer's floor: the big tensors take 19 chunks
+
+    @pytest.fixture
+    def staged(self):
+        tensors = {k: np.ascontiguousarray(v)
+                   for k, v in _mixed_tensors().items()}
+        rng = np.random.RandomState(28)
+        for i in range(5):
+            tensors[f"big{i}|0"] = rng.standard_normal(
+                300_001 + i).astype(np.float32)
+        info = {k: {"path": k.split("|")[0],
+                    "global_shape": list(np.shape(v)),
+                    "index": [[0, d] for d in np.shape(v)],
+                    "owners": [0, 1]} for k, v in tensors.items()}
+        extra = dict(_extra(), tensors_info=info, num_processes=2,
+                     process_id=1)
+        arena = SharedMemoryArena(f"tckpt-arenafed-{os.getpid()}")
+        reader = SharedMemoryArena(arena.name)
+        try:
+            arena.write_state(tensors, extra=extra)
+            copies, _ = reader.read_state(copy=True)
+            handles, extra2 = reader.read_state(copy=False)
+            assert extra2 == extra
+            yield copies, handles, extra
+        finally:
+            reader.close()
+            arena.close(unlink=True)
+
+    @pytest.mark.parametrize("workers", [1, 4])
+    @pytest.mark.parametrize("variant", ["whole", "sliced", "relayout"])
+    def test_identical_to_pack_shard(self, tmp_path, monkeypatch, staged,
+                                     variant, workers):
+        from dlrover_tpu.checkpoint import slicer
+
+        copies, handles, extra = staged
+        meta_extra = None
+        if variant == "sliced":
+            plan = slicer.plan_persist(
+                handles, extra, process_id=1, num_processes=2)
+            ref = slicer.plan_persist(
+                copies, extra, process_id=1, num_processes=2)
+            assert plan.meta_extra == ref.meta_extra
+            assert plan.layout == ref.layout and plan.extra == ref.extra
+            assert 0 < plan.written_bytes == ref.written_bytes < (
+                plan.logical_bytes)
+            handles, copies, extra = plan.tensors, ref.tensors, plan.extra
+            meta_extra = plan.meta_extra
+        if variant == "relayout":
+            monkeypatch.setattr(shard_file, "_CRC_PLACEHOLDER", 1)
+        nbytes = sum(int(v.nbytes) for v in copies.values())
+        st = PosixDiskStorage()
+        path = str(tmp_path / "s.ckpt")
+        audit.enable()
+        try:
+            stats = shard_file.ShardStreamWriter(
+                st, path, handles, extra, workers=workers,
+                chunk_bytes=self.CHUNK, meta_extra=meta_extra).write()
+            snap = audit.snapshot()
+        finally:
+            audit.disable()
+        assert open(path, "rb").read() == shard_file.pack_shard(
+            copies, extra, meta_extra)
+        assert stats["crcs"] == {
+            k: shard_file.crc32_bytes(
+                np.ascontiguousarray(v).reshape(-1).view(np.uint8))
+            for k, v in copies.items()}
+        passes = 2 if variant == "relayout" else 1
+        assert stats["passes"] == passes
+        assert stats["read_bytes"] == passes * nbytes
+        # one data pass (two through the rare relayout), and no buffer
+        # the size of the state anywhere
+        assert snap["copied_bytes"] == 0
+        assert snap["written_bytes"] == passes * nbytes
+        assert snap["passes"] == dict(
+            stream_data=1, **({"stream_relayout": 1} if passes == 2 else {}))
+
+    @pytest.mark.parametrize("workers", [1, 4])
+    def test_a_chunk_is_written_before_its_buffer_is_refilled(
+            self, staged, workers):
+        copies, handles, extra = staged
+        st = _CheckingStorage(shard_file.pack_shard(copies, extra))
+        stats = shard_file.ShardStreamWriter(
+            st, "/ck/s.ckpt", handles, extra, workers=workers,
+            chunk_bytes=self.CHUNK).write()
+        assert st.out == st.want
+        assert [s.bad for s in st.sinks] == [[]]
+        # every tensor byte came through a range worker's one buffer,
+        # none of them larger than a chunk
+        assert (stats["workers"] > 1) == (workers > 1)
+        assert 1 <= len(st.buffers) <= stats["workers"]
+        assert all(n <= self.CHUNK for _addr, n in st.buffers)
+
+    def test_dirty_probe_reads_through_the_primitive(self, staged):
+        """An incremental save's fence probe CRCs a tensor that is still
+        in the arena: same verdict as on arrays, for the whole tensor
+        and for this rank's slice of it."""
+        from dlrover_tpu.checkpoint import slicer
+
+        copies, handles, extra = staged
+        for sliced in (False, True):
+            tracker = slicer.DirtyTracker()
+            first = slicer.plan_persist(
+                copies, extra, process_id=1, num_processes=2, sliced=sliced)
+            crcs = {k: shard_file.crc32_bytes(
+                np.ascontiguousarray(v).reshape(-1).view(np.uint8))
+                for k, v in first.tensors.items()}
+            tracker.note_plan(first, 3, crcs)
+            later = dict(extra, step=4)
+            plans = [
+                slicer.plan_persist(
+                    src, later, process_id=1, num_processes=2,
+                    sliced=sliced, tracker=tracker,
+                    holder_exists=lambda step: True)
+                for src in (copies, handles)]
+            assert plans[0].refs == plans[1].refs
+            assert plans[1].skipped == len(
+                [k for k, (lo, hi, _n) in first.layout.items() if hi > lo])
+            assert plans[0].meta_extra == plans[1].meta_extra
+            assert plans[1].written_bytes == 0
 
 
 class TestChaosSitesOnStreamedPath:
@@ -443,6 +617,14 @@ class TestEngineAndSaverFastPath:
                 shard_file.shard_path(str(tmp_path), 4, 0), "rb"
             ).read()
             assert on_disk == shard_file.pack_shard(tensors, extra)
+            # the write span says how many bytes the writer read() off
+            # the arena: every tensor byte, once
+            evs, _, _ = obs.get_recorder().snapshot()
+            writes = [e["args"] for e in evs if e["k"] == "span"
+                      and e["name"] == "ckpt.persist.write"
+                      and e["args"].get("step") == 4]
+            assert writes and writes[-1]["read_bytes"] == sum(
+                int(v.nbytes) for v in tensors.values())
             # Observability: persist throughput + the worker's stall
             # reached the agent-side surfaces.
             assert perf_stats.get("ckpt_persist_mbps") > 0
@@ -557,6 +739,17 @@ class TestEngineAndSaverFastPath:
             np.testing.assert_array_equal(
                 np.asarray(state["w"]), np.full(256, 1.0, np.float32)
             )
+            # the spans say how: header and meta under shm_read, the
+            # tensor read() into an array of its own under device_put
+            evs, _, _ = obs.get_recorder().snapshot()
+            args = {e["name"]: e.get("args", {}) for e in evs
+                    if e["k"] == "span"
+                    and e["name"].startswith("ckpt.load.")}
+            assert args["ckpt.load.shm_read"]["copy"] is False
+            assert args["ckpt.load.shm_read"]["bytes"] == 1024
+            put = args["ckpt.load.device_put"]
+            assert (put["staged_bytes"], put["copied_bytes"]) == (0, 1024)
+            assert "in_place_bytes" not in put
         finally:
             eng.close()
 
@@ -606,22 +799,58 @@ class _StandInLeaf:
         self.sharding = _StandInSharding(device)
 
 
+@pytest.fixture
+def arena_pieces():
+    """Stage arrays into a real shm arena and hand back their
+    ``ArenaTensor`` handles, as a warm restore gets them."""
+    arenas = []
+
+    def stage(flat):
+        w = SharedMemoryArena(
+            f"tckpt-pieces-{os.getpid()}-{len(arenas)}")
+        r = SharedMemoryArena(w.name)
+        arenas.append((w, r))
+        w.write_state(flat, extra={"step": 1})
+        handles, _ = r.read_state(copy=False)
+        return handles, w
+
+    yield stage
+    for w, r in arenas:
+        r.close()
+        w.close(unlink=True)
+
+
+def _root_buffer(arr):
+    while isinstance(arr.base, np.ndarray):
+        arr = arr.base
+    return arr
+
+
 class TestRestoreCopiesOnlyWhereItProtects:
-    """ISSUE 24: a borrowed piece (a view into the shm arena) is copied
-    on the host only where the restored tree would keep referring to it —
-    a host leaf, or a device that may alias host memory (the CPU
-    backend).  The decision is read off the destination, by no flag."""
+    """ISSUE 24, ISSUE 28: how a piece reaches the restored tree is read
+    off the piece and its destination, by no flag.  A piece still in the
+    shm arena is ``read()`` — through a reused staging buffer on its way
+    to an accelerator, into an array the tree owns for a host leaf or a
+    device that may alias host memory (the CPU backend).  A piece that
+    is an array goes on as it is, copied only where it borrows its bytes
+    and the restored tree would keep referring to them."""
 
     @pytest.mark.parametrize("leaf_kind", ["device", "host"])
-    @pytest.mark.parametrize("piece_kind", ["borrowed", "owned"])
+    @pytest.mark.parametrize("piece_kind", ["arena", "borrowed", "owned"])
     @pytest.mark.parametrize("platform", ["cpu", "tpu"])
-    def test_copy_or_not(self, monkeypatch, platform, piece_kind, leaf_kind):
+    def test_copy_or_not(self, monkeypatch, arena_pieces, platform,
+                         piece_kind, leaf_kind):
         from dlrover_tpu.checkpoint import tree_utils
 
+        want = np.arange(16, 80, dtype=np.float32)
         backing = np.arange(96, dtype=np.float32)
-        piece = backing[16:80] if piece_kind == "borrowed" else (
-            np.arange(16, 80, dtype=np.float32))
-        assert (piece.base is not None) == (piece_kind == "borrowed")
+        if piece_kind == "arena":
+            piece = arena_pieces({"w": want})[0]["w"]
+        elif piece_kind == "borrowed":
+            piece = backing[16:80]
+            assert piece.base is not None
+        else:
+            piece = want.copy()
         put_on = []
 
         def device_put(x, device):
@@ -633,7 +862,7 @@ class TestRestoreCopiesOnlyWhereItProtects:
             tree_utils.jax, "make_array_from_single_device_arrays",
             lambda shape, sharding, arrays: arrays[0])
         device = _StandInDevice(platform)
-        target = {"w": _StandInLeaf(piece, device)
+        target = {"w": _StandInLeaf(want, device)
                   if leaf_kind == "device" else np.zeros(64, np.float32)}
         source = tree_utils.ShardSource()
         source.add({"['w']|0": piece},
@@ -645,20 +874,190 @@ class TestRestoreCopiesOnlyWhereItProtects:
             snap = audit.snapshot()
         finally:
             audit.disable()
-        copied = piece_kind == "borrowed" and (
-            leaf_kind == "host" or platform == "cpu")
-        np.testing.assert_array_equal(out, np.arange(16, 80, dtype=np.float32))
-        assert np.shares_memory(out, piece) == (not copied)
-        if piece_kind == "borrowed":
-            assert np.shares_memory(out, backing) == (not copied)
+        keeps = leaf_kind == "host" or platform == "cpu"
+        staged = piece_kind == "arena" and not keeps
+        copied = keeps and piece_kind != "owned"
+        np.testing.assert_array_equal(out, want)
+        assert out.dtype == want.dtype and out.shape == want.shape
         assert put_on == ([device] if leaf_kind == "device" else [])
         assert tally == {
-            "in_place_bytes": 0 if copied else piece.nbytes,
-            "copied_bytes": piece.nbytes if copied else 0,
+            "staged_bytes": want.nbytes if staged else 0,
+            "copied_bytes": want.nbytes if copied else 0,
         }
-        assert tally["in_place_bytes"] + tally["copied_bytes"] == piece.nbytes
+        if piece_kind == "arena":
+            # every arena byte is counted once, on one side or the other
+            assert tally["staged_bytes"] + tally["copied_bytes"] == (
+                want.nbytes)
+            # a staged piece is a view of a staging buffer; a kept one
+            # owns its bytes
+            assert (_root_buffer(out) is out) == (not staged)
+        else:
+            assert np.shares_memory(out, piece) == (not copied)
+        if piece_kind == "borrowed":
+            assert np.shares_memory(out, backing) == (not copied)
         assert snap["copied_by_site"] == (
-            {"restore_owned_copy": piece.nbytes} if copied else {})
+            {"restore_owned_copy": want.nbytes} if copied else {})
+
+
+class _LatePut:
+    """What a stand-in ``device_put`` returns: like the real one it has
+    NOT read its host buffer when it returns; ``block_until_ready`` is
+    when the bytes leave the host."""
+
+    def __init__(self, host, awaited):
+        self.host = host
+        self.value = None
+        self._awaited = awaited
+
+    def block_until_ready(self):
+        if self.value is None:
+            self.value = np.array(self.host)
+            self._awaited.append(self)
+        return self
+
+
+class TestRestoreStagesThroughReusedBuffers:
+    """ISSUE 28: arena -> accelerator goes through two reused staging
+    buffers; a buffer is refilled only after the put that read it is
+    ready, and host memory is a constant, not the state."""
+
+    SIZES = [4096, 17, 70_000, 1, 33_333, 70_000, 5, 12_345, 64, 50_000, 3]
+
+    def _state(self):
+        rng = np.random.RandomState(7)
+        flat = {f"t{i:02d}": rng.standard_normal(n).astype(np.float32)
+                for i, n in enumerate(self.SIZES)}
+        flat["count"] = np.asarray(np.int32(9))
+        flat["none"] = np.zeros((0, 4), np.float32)
+        return flat
+
+    def _restore(self, monkeypatch, arena_pieces, platform):
+        from dlrover_tpu.checkpoint import tree_utils
+
+        flat = self._state()
+        handles, writer = arena_pieces(flat)
+        puts, awaited = [], []
+
+        def device_put(x, device):
+            puts.append(_LatePut(x, awaited))
+            return puts[-1]
+
+        monkeypatch.setattr(tree_utils.jax, "device_put", device_put)
+        monkeypatch.setattr(
+            tree_utils.jax, "make_array_from_single_device_arrays",
+            lambda shape, sharding, arrays: arrays[0])
+        device = _StandInDevice(platform)
+        target = {k: _StandInLeaf(v, device) for k, v in flat.items()}
+        source = tree_utils.ShardSource()
+        source.add(
+            {f"['{k}']|0": h for k, h in handles.items()},
+            {f"['{k}']|0": {"path": f"['{k}']",
+                            "index": [[0, d] for d in flat[k].shape]}
+             for k in flat})
+        tally = {}
+        out = tree_utils.restore_to_target(target, source, tally)
+        return flat, writer, out, puts, awaited, tally
+
+    def test_buffer_not_refilled_before_its_put_was_awaited(
+            self, monkeypatch, arena_pieces):
+        flat, writer, out, puts, awaited, tally = self._restore(
+            monkeypatch, arena_pieces, "tpu")
+        nbytes = sum(v.nbytes for v in flat.values())
+        assert tally == {"staged_bytes": nbytes, "copied_bytes": 0}
+        assert len(puts) == len(flat)
+        # restore_to_target has awaited every put before it returned
+        # (its buffers die with it), each exactly once
+        assert sorted(map(id, awaited)) == sorted(map(id, puts))
+        # and what each put read, late, is its own piece: nothing was
+        # refilled under it
+        for key, want in flat.items():
+            got = out[key].value
+            assert got.dtype == want.dtype and got.shape == want.shape
+            np.testing.assert_array_equal(got, want)
+        # two buffers, each as large as the largest piece: a constant
+        # for a state of any number of pieces
+        roots = {(_root_buffer(p.host).ctypes.data,
+                  _root_buffer(p.host).nbytes)
+                 for p in puts if p.host.nbytes}
+        assert len(roots) == 2
+        assert {n for _addr, n in roots} == {
+            max(v.nbytes for v in flat.values())}
+        # overwrite the arena and every staging buffer: the restored
+        # tree does not change
+        writer.write_state({k: np.zeros_like(v) for k, v in flat.items()},
+                           extra={"step": 2})
+        for p in puts:
+            _root_buffer(p.host)[...] = 0xFF
+        for key, want in flat.items():
+            np.testing.assert_array_equal(out[key].value, want)
+
+    def test_a_device_that_may_alias_gets_arrays_of_its_own(
+            self, monkeypatch, arena_pieces):
+        flat, writer, out, puts, awaited, tally = self._restore(
+            monkeypatch, arena_pieces, "cpu")
+        nbytes = sum(v.nbytes for v in flat.values())
+        assert tally == {"staged_bytes": 0, "copied_bytes": nbytes}
+        # the CPU backend may adopt the buffer it is given: each piece
+        # is read into an array nobody else refers to, no staging
+        assert all(p.host.flags.owndata for p in puts)
+        assert len({p.host.ctypes.data for p in puts if p.host.nbytes}) == (
+            len([v for v in flat.values() if v.nbytes]))
+        writer.write_state({k: np.zeros_like(v) for k, v in flat.items()},
+                           extra={"step": 2})
+        for key, want in flat.items():
+            np.testing.assert_array_equal(out[key].host, want)
+
+    def test_put_failure_still_awaits_what_is_in_flight(
+            self, monkeypatch, arena_pieces):
+        from dlrover_tpu.checkpoint import tree_utils
+
+        flat = {f"t{i}": np.full(100, i, np.float32) for i in range(4)}
+        handles, _w = arena_pieces(flat)
+        awaited, puts = [], []
+
+        def device_put(x, device):
+            if len(puts) == 3:
+                raise RuntimeError("device lost")
+            puts.append(_LatePut(x, awaited))
+            return puts[-1]
+
+        monkeypatch.setattr(tree_utils.jax, "device_put", device_put)
+        monkeypatch.setattr(
+            tree_utils.jax, "make_array_from_single_device_arrays",
+            lambda shape, sharding, arrays: arrays[0])
+        device = _StandInDevice("tpu")
+        source = tree_utils.ShardSource()
+        source.add({f"['{k}']|0": h for k, h in handles.items()},
+                   {f"['{k}']|0": {"path": f"['{k}']", "index": [[0, 100]]}
+                    for k in flat})
+        with pytest.raises(RuntimeError, match="device lost"):
+            tree_utils.restore_to_target(
+                {k: _StandInLeaf(v, device) for k, v in flat.items()},
+                source)
+        # nothing is left reading a buffer that is about to be freed
+        assert sorted(map(id, awaited)) == sorted(map(id, puts))
+        assert len(puts) == 3
+
+    def test_resharded_target_reads_arena_pieces_for_the_overlap(
+            self, arena_pieces):
+        """A target box that matches no staged piece (another sharding)
+        is assembled by overlap: the arena pieces it needs are read."""
+        from dlrover_tpu.checkpoint import tree_utils
+
+        full = np.arange(64, dtype=np.float32).reshape(8, 8)
+        handles, _w = arena_pieces({"lo": full[:4], "hi": full[4:]})
+        source = tree_utils.ShardSource()
+        source.add(
+            {"['w']|0": handles["lo"], "['w']|1": handles["hi"]},
+            {"['w']|0": {"path": "['w']", "index": [[0, 4], [0, 8]]},
+             "['w']|1": {"path": "['w']", "index": [[4, 8], [0, 8]]}})
+        tally = {}
+        out = tree_utils.restore_to_target(
+            {"w": np.zeros((8, 8), np.float32)}, source, tally)
+        np.testing.assert_array_equal(out["w"], full)
+        assert out["w"].flags.owndata
+        # assembled into a fresh array the tree owns: nothing to count
+        assert tally == {"staged_bytes": 0, "copied_bytes": 0}
 
 
 class _OtherHolder:

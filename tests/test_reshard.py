@@ -1256,11 +1256,11 @@ class TestRestoreToAnyMesh:
 
 
 class TestArenaSource:
-    """The intra-host substrate: mover segments stream ZERO-COPY from the
-    shm arena's ``read_state(copy=False)`` views (PR 4's lifetime
-    contract), exactly as the agent saver's persist path does."""
+    """The intra-host substrate: the mover's source over a staged shm
+    arena holds the tensors ``read()`` off the arena's file into arrays
+    of its own (ISSUE 28: no bulk consumer walks the mapping)."""
 
-    def test_from_arena_views_feed_the_mover(self):
+    def test_from_arena_feeds_the_mover(self):
         from dlrover_tpu.common.shm import SharedMemoryArena
 
         W = np.arange(64, dtype=np.float32).reshape(16, 4)
@@ -1275,8 +1275,9 @@ class TestArenaSource:
             arena.write_state({"w|0": W}, extra={"tensors_info": infos,
                                                  "step": 2})
             src = LocalShardSource.from_arena(arena)
-            # views, not copies: the arrays borrow the mapping's buffer
-            assert src.tensors["w|0"].base is not None
+            # arrays of its own: the source outlives the arena's hold
+            assert isinstance(src.tensors["w|0"], np.ndarray)
+            assert src.tensors["w|0"].flags.owndata
             dst = rp.build_layout(
                 MeshSpec(dp=2), {"w": ("dp",)}, {"w": (16, 4)},
                 {"w": "float32"}, ranks=[0],
@@ -1295,6 +1296,7 @@ class TestArenaSource:
                 extra={"tensors_info": infos, "step": 3},
             )
             np.testing.assert_array_equal(tensors["w|0"], W[:8])
+            np.testing.assert_array_equal(src.tensors["w|0"], W)
         finally:
             arena.close(unlink=True)
 
