@@ -130,8 +130,8 @@ def deserialize(data: bytes) -> Message:
 
 def _encode_generic(obj: Any) -> Any:
     """The pre-fast-path encoder (reflection + per-element recursion
-    everywhere) — kept as the measured baseline for ``bench.py
-    --load_bench``'s serialization profile; not used on any wire path."""
+    everywhere) — kept as the reference the tests hold ``serialize``
+    to, byte for byte (``serialize_baseline``); not used on any wire path."""
     if isinstance(obj, Message):
         optional = type(obj)._WIRE_OPTIONAL
         return {

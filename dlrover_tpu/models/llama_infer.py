@@ -1265,7 +1265,7 @@ def _spec_k_request(ewma: float, draft_k: int, break_even: float) -> int:
     measurement yet: start at full width and let the first rounds
     decide).  Below ``break_even`` — the measured round-cost ratio
     ``(t_draft_roll + t_verify) / t_plain_step`` from
-    ``SPEC_DECODE_CPU.json``'s components row — drafting costs more
+    one CPU run of the three components — drafting costs more
     target-equivalent time than it saves, so the stream decodes PLAIN
     (k = 0): a bad draft can never make a request slower than a
     spec-less replica serves it.  Above break-even the stream keeps a
@@ -1656,7 +1656,7 @@ class DecodeServer:
         # global ``adapt_k`` window policy.
         adapt_k_per_request: bool = False,
         spec_break_even: float = 0.0,  # 0 = 1 + 0.6*draft_k (measured
-        # shape of SPEC_DECODE_CPU.json's break-even at k=4)
+        # shape of a CPU run's break-even at k=4; the chip's: not measured)
         spec_probe_every: int = 32,
         spec_ewma_alpha: float = 0.25,
         # Remote-draft speculation (ISSUE 11): the server may be handed

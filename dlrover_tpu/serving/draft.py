@@ -3,7 +3,7 @@
 The seed rounds proved batched speculative decoding inside one process
 (``models/llama_infer.py``: draft-roll / chunked verify / rejection-
 sampling acceptance, break-even ~3.35 tokens/round on the committed
-``SPEC_DECODE_CPU.json``).  This module makes the DRAFT half a fleet
+CPU record, since deleted).  This module makes the DRAFT half a fleet
 citizen: a small draft model runs on its own replica (its own chip)
 and ships per-round proposals to target replicas over the PR-9
 segment-path idiom — a tiny RPC server per publisher, CRC-wrapped

@@ -227,7 +227,7 @@ class ReplicaRunner:
         self.replay_limit = replay_limit
         #: Optional per-round latency floor: models the device-bound
         #: regime on hosts where decode compute shares the CPU with the
-        #: control plane (see bench.py --serve_bench).  The sleep sits
+        #: control plane (a CPU stand-in for device time).  The sleep sits
         #: in tick — between dispatch rounds — exactly where a blocking
         #: device future would.
         self.round_floor_s = round_floor_s

@@ -6,12 +6,12 @@ borrows, federation placement and cross-cell moves — over synthetic
 fleets and traces in virtual time.  Three rigs, one law:
 
 * :class:`~dlrover_tpu.sim.serve.GlobalServeSim` — the micro rig: an
-  event-by-event replay of ``bench.py --global_bench`` (real
-  ``GatewayCore`` + ``CellSpillRouter`` per cell), fidelity-checked
-  against the committed ``GLOBAL_BENCH_CPU.json`` rows.
+  event-by-event run of the global-serve scenario (real
+  ``GatewayCore`` + ``CellSpillRouter`` per cell): blackout of the
+  hot cell under a Zipf-over-cells trace, static against spillover.
 * :class:`~dlrover_tpu.sim.cellsim.CellPlaneSim` — the control-plane
-  rig: the cell bench's shard physics over the real consistent hash,
-  fidelity-checked against ``CELL_BENCH_CPU.json``.
+  rig: journaled-mutation shard physics over the real consistent
+  hash; its floored row is analytic (``tests/test_sim.py``).
 * :class:`~dlrover_tpu.sim.storm.FleetStormSim` — the macro rig:
   10,000 nodes, 24 cells, a day-long diurnal trace and chaos storms
   (correlated blackouts, gray networks, churn waves) no real bench

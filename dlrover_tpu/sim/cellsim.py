@@ -12,9 +12,9 @@ PR-13 append lock): an op holds its worker from pull to completion
 and holds the owning cell for ``floor_ms + overhead_ms`` — the
 modeled durable-log floor plus one calibrated constant for the
 request path around it (gRPC hop, handler, commit bookkeeping).  The
-calibration point is the committed 1-cell floored row of
-``CELL_BENCH_CPU.json``; every other row is a prediction.  The convoy
-effect the real bench measures — workers FIFO-blocked behind the hot
+calibration point was one 1-cell floored row of a CPU run of the
+process tree; every other row is a prediction.  The convoy
+effect such a run shows — workers FIFO-blocked behind the hot
 cell starve the cold cells — emerges from the same structure here, it
 is not programmed in.
 

@@ -7,12 +7,12 @@ real :class:`~dlrover_tpu.serving.gateway.GatewayCore` admission, real
 ``merge_global_snapshots`` accounting — with every thread, socket and
 sleep of the bench replaced by scheduler events over one
 :class:`VirtualClock`.  The arrival trace is an *input* (the caller
-replays the bench's own seeded ``zipf_cell_trace``, or synthesizes one
-from :mod:`sim.rand`), so the fidelity comparison against the
-committed ``GLOBAL_BENCH_CPU.json`` is apples to apples: identical
+replays a recorded seeded trace, or synthesizes one
+from :mod:`sim.rand`), so a comparison against a run of the real
+process tree is apples to apples: identical
 arrivals, identical policy code, only the transport physics modeled.
 
-The physics model, calibrated once (see ``SIM_BENCH.json``):
+The physics model, calibrated once against such a run on a CPU:
 
 * each cell's gateway is a serialized server with a per-message floor
   (``gw_service_us``, the bench's ``_PacedPipeline`` budget) — submits

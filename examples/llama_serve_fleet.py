@@ -6,8 +6,7 @@ Single-process demo (gateway + replicas as threads, loopback driver)::
 
     python examples/llama_serve_fleet.py --replicas 2 --requests 12
 
-Process-per-role (what the chaos e2e and ``bench.py --serve_bench``
-compose; each role is also how a supervised deployment runs under the
+Process-per-role (what the chaos e2e composes; each role is also how a supervised deployment runs under the
 elastic agent)::
 
     python examples/llama_serve_fleet.py --role gateway --port 8710
@@ -115,7 +114,7 @@ def parse_args(argv=None):
     p.add_argument("--spec_break_even", type=float, default=0.0,
                    help="(replica) accepted-tokens/round below which "
                         "a stream rides plain (0 = 1 + 0.6*draft_k, "
-                        "the SPEC_DECODE_CPU.json break-even shape)")
+                        "the default shape)")
     p.add_argument("--spec_min_tokens", type=int, default=0,
                    help="(gateway) max_new_tokens at which the grant "
                         "scan prefers spec-capable replicas (0 = off)")
@@ -442,8 +441,8 @@ def main() -> int:
 
         if args.draft_seed < 0:
             # Ceiling draft: the target itself (stands in for a
-            # trained draft — acceptance ~k+1; the committed
-            # SPEC_DECODE_CPU.json bounds the realistic range).
+            # trained draft — acceptance ~k+1, the ceiling of what a
+            # real draft can reach).
             dparams, dcfg = serve_common.tiny_llama(
                 seed=args.seed, dtype=jnp.float32,
                 n_layer=args.n_layer, d_model=args.d_model,

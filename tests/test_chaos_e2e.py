@@ -1632,8 +1632,7 @@ class TestCellMasterKillFailover:
       announced pre-kill are visible post-takeover;
     - cell1 NEVER blacks out: its probe stream of short-budget RPCs
       shows no gap above one probe budget while cell0 fails over (the
-      per-cell blackout metric extending HA_BENCH_CPU.json's
-      fleet-wide one);
+      per-cell blackout metric beside the fleet-wide one);
     - the shared cell registry re-learns cell0 from the promoted
       standby, so the ring covers both cells again;
     - ``statecheck`` exits 0 on cell0's surviving journal.

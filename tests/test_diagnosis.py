@@ -190,17 +190,37 @@ class TestHangingDetector:
         det.record_step(2)
         assert not det.is_hanging()
 
-    def test_callback_fires_once_per_stall(self):
+    def test_callback_fires_once_per_stall(self, monkeypatch):
+        """Eight checks 4 s apart against a 10 s timeout, on a stand-in
+        clock: an alarm at 12 s and, the clock reset by it, at 24 s —
+        one a stall, not one a check (six checks lie past the
+        timeout)."""
+        import types
+
+        from dlrover_tpu.diagnosis import agent
+
+        now = [1000.0]
+        monkeypatch.setattr(
+            agent, "time", types.SimpleNamespace(time=lambda: now[0]))
+
+        class _EightChecks:
+            left = 8
+
+            def wait(self, dt):
+                now[0] += dt
+                self.left -= 1
+                return self.left < 0
+
         fired = []
         det = HangingDetector(
-            hang_timeout_s=0.1, compile_grace_s=0.0,
-            on_hang=lambda: fired.append(1), check_interval_s=0.05,
+            hang_timeout_s=10.0, compile_grace_s=0.0,
+            on_hang=lambda: fired.append(now[0] - 1000.0),
+            check_interval_s=4.0,
         )
         det.record_step(1)
-        det.start()
-        time.sleep(0.4)
-        det.stop()
-        assert 1 <= len(fired) <= 3  # reset after each alarm
+        det._stop = _EightChecks()
+        det._loop()
+        assert fired == [12.0, 24.0]
 
     def test_heartbeat_file(self, tmp_path):
         hb = tmp_path / "hb"

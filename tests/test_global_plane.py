@@ -35,6 +35,7 @@ from dlrover_tpu.serving import (
     merge_snapshots,
 )
 from dlrover_tpu.serving.tier import GatewayTierNode
+from dlrover_tpu.sim import run_global_rows
 
 from test_serving import (  # noqa: I100 - shared fleet fixtures
     FakeClock,
@@ -549,3 +550,63 @@ class TestCellBlackoutE2E:
         assert "gw.request" in failed_over
         b.core.drain("rB")
         th_b.join(timeout=5)
+
+
+# ---------------------------------------------------------------------------
+# Conservation across the hop, over a whole saturating trace
+# ---------------------------------------------------------------------------
+
+#: Two cells of one 4-slot replica behind a 6-deep queue, 500 arrivals
+#: in a second, three quarters of them homed at cell 0: the hot cell
+#: is over its cap from the first tenth of a second, so the spillover
+#: router forwards; ``blackout`` kills cell 0 halfway.  The rig is the
+#: wind tunnel's micro rig: the REAL GatewayCore / CellSpillRouter /
+#: merge_global_snapshots on a virtual clock, no thread and no sleep.
+_HOP_OPTS = {
+    "cells": 2, "replicas": 1, "slots": 4, "queue_cap": 6,
+    "deadline_s": 5.0, "slo_ms": 500.0, "service_ms": 10.0,
+    "gw_service_us": 200.0, "duration_s": 1.0, "blackout_frac": 0.5,
+    "move_delay_s": 0.25, "prompt_tokens": 4, "mnt": 4,
+    "poll_interval": 0.005,
+}
+_HOP_TIMES = [round(i * 0.002, 3) for i in range(500)]
+_HOP_HOMES = [0 if i % 4 else 1 for i in range(500)]
+
+
+class TestHopConservation:
+    @pytest.fixture(scope="class")
+    def rows(self):
+        rows = run_global_rows(_HOP_OPTS, _HOP_TIMES, _HOP_HOMES,
+                               overhead_ms=0.0, shapes=[False, True])
+        return {(r["mode"], r["blackout"]): r for r in rows}
+
+    @pytest.mark.parametrize("blackout", [False, True])
+    @pytest.mark.parametrize("mode", ["static", "spillover"])
+    def test_every_arrival_and_every_hop_is_accounted(
+        self, rows, mode, blackout
+    ):
+        row = rows[(mode, blackout)]
+        assert row["arrivals"] == len(_HOP_TIMES)
+        # an arrival is submitted once (the hop deduped), shed on the
+        # wire, or lost to the dead cell
+        assert row["arrivals"] == row["submitted_unique"] \
+            + row["wire_dropped"] + row["blackout_lost"] \
+            + row["blackout_dropped"]
+        assert row["submitted_unique"] == row["accepted"] \
+            + row["rejected"]
+        # an accepted request ends, or is counted stranded in the dead
+        # cell's core
+        assert row["accepted"] == row["completed"] + row["timeout"] \
+            + row["failed"] + row["stranded"]
+        # a forward lands at a sibling or is rebuffed by its hop budget
+        assert row["spill_forwarded"] == row["spill_ingress"] \
+            + row["spill_rebuffed"]
+        assert row["rejected"] > 0  # the trace IS over the hot cell's cap
+        if mode == "static":
+            assert row["spill_forwarded"] == 0
+            assert (row["blackout_lost"] > 0) == blackout
+        else:
+            assert row["spill_forwarded"] > 0
+            assert row["blackout_lost"] == 0
+            assert row["moved_replicas"] == (
+                _HOP_OPTS["replicas"] if blackout else 0)

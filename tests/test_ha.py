@@ -424,6 +424,48 @@ class TestStandbyTakeover:
             client.close()
             master.stop()
 
+    @pytest.mark.parametrize("state_dir", ["same", "blank"])
+    def test_cold_relaunch_recovers_what_its_state_dir_holds(
+        self, tmp_path, state_dir
+    ):
+        """What a relaunch knows is what its state directory holds: on
+        the dead primary's directory it reads the acknowledged key back
+        and its queue goes on after the tasks already granted; on a
+        blank one (the relaunch the journal replaced) the key is gone
+        and the same dataset starts over at task 0."""
+        first = tmp_path / "first"
+        first.mkdir()
+        master = _mk_primary(first)
+        client = MasterClient(master.addr, 0)
+        try:
+            client.kv_store_set("boot/k", b"v")
+            client.report_dataset_shard_params(
+                dataset_name="ds", dataset_size=50, shard_size=10)
+            done = client.get_task("ds")
+            client.report_task_result("ds", done.task_id, True)
+        finally:
+            client.close()
+            _silence(master)
+        where = first
+        if state_dir == "blank":
+            where = tmp_path / "blank"
+            where.mkdir()
+        relaunched = _mk_primary(where)
+        c2 = MasterClient(relaunched.addr, 0)
+        try:
+            if state_dir == "same":
+                assert c2.kv_store_get("boot/k") == b"v"
+                assert c2.get_task("ds").task_id == done.task_id + 1
+            else:
+                assert c2.kv_store_get("boot/k") is None
+                c2.report_dataset_shard_params(
+                    dataset_name="ds", dataset_size=50, shard_size=10)
+                assert c2.get_task("ds").task_id == done.task_id == 0
+        finally:
+            c2.close()
+            relaunched.stop()
+        assert check_state_dir(str(where))["clean"]
+
     def test_standby_holds_while_primary_leases(self, tmp_path):
         master = _mk_primary(tmp_path)
         try:
@@ -778,10 +820,10 @@ class TestRestoreRearm:
         params = dict(dataset_name="d", dataset_size=20, shard_size=10)
         tm.new_dataset(new_dataset_splitter(**params), params=params)
         got = tm.get_task("d", 1, token="t")
+        granted_at = tm._datasets["d"]._doing[got[0]].start_time
         tm._datasets["d"]._doing[got[0]].start_time -= 1e6
         tm.rearm_doing()
-        assert time.monotonic() - \
-            tm._datasets["d"]._doing[got[0]].start_time < 5.0
+        assert tm._datasets["d"]._doing[got[0]].start_time >= granted_at
 
 
 class _FakeProc:

@@ -366,8 +366,8 @@ class TestDecodeThroughput:
         """One batched decode produces row-for-row the same tokens as
         sequential single-row calls.  (The THROUGHPUT win of batching
         is an accelerator property — B=1 decode is HBM-bandwidth-bound
-        there — measured by bench.py's decode_tokens_per_sec on real
-        hardware; on a single CPU core compute scales linearly with B
+        there — not measured on the chip yet: no serving cell, PERF.md
+        section 7; on a single CPU core compute scales linearly with B
         and a wall-clock assertion would test the backend, not us.)"""
         cfg = llama.LlamaConfig.tiny(n_layer=2, dtype=jnp.float32)
         params = llama.init_params(jax.random.PRNGKey(0), cfg)
@@ -2238,6 +2238,49 @@ class TestPagedKv:
             slotted, paged, self._prompts(cfg, [6, 9, 5]), 8,
             all_free=False, shared_prefix=prefix,
         )
+
+    @pytest.mark.parametrize("workload,lens", [
+        ("uniform", [20, 24, 22, 26, 21, 25, 23, 20]),
+        ("longtail", [4, 5, 4, 40, 6, 4, 30, 5]),
+    ])
+    def test_matched_memory_buys_seats_not_different_outputs(
+        self, workload, lens
+    ):
+        """At the KV memory of two slab seats (2 x 64 tokens) the block
+        pool holds 16 blocks of 8 and seats six: the same bytes, more
+        requests in flight when they are short, the same greedy
+        outputs on uniform and on long-tail lengths."""
+        cfg, params = self._models()
+        kw = dict(max_len=64, prompt_buckets=(8, 32, 48), seed=0)
+        slab = llama_infer.DecodeServer(params, cfg, slots=2, **kw)
+        pool = llama_infer.DecodeServer(
+            params, cfg, slots=6, paged=True, block_size=self.BS,
+            pool_blocks=2 * (64 // self.BS), **kw)
+        assert pool.pool_blocks * self.BS == slab.slots * 64
+        assert pool.slots > slab.slots
+        prompts = self._prompts(cfg, lens, seed=11)
+        ref = slab.serve(prompts, max_new_tokens=6)
+        seated = []
+        outs = {}
+        for i, p in enumerate(prompts):
+            pool.submit(i, p, 6)
+
+        def tick():
+            seated.append(len(pool.active_rids()))
+            return False  # drain: finish everything, then return
+
+        pool.serve_incremental(
+            tick=tick, on_finish=lambda rid, toks: outs.__setitem__(
+                rid, np.asarray(toks)))
+        for i, r in enumerate(ref):
+            np.testing.assert_array_equal(outs[i], np.asarray(r))
+        arena = pool.kv_arena
+        assert arena.conserved()
+        assert arena.free_blocks == arena.n_blocks
+        if workload == "longtail":
+            # short requests take 2 blocks each: more of them are in
+            # flight at once than the slab has seats
+            assert max(seated) > slab.slots
 
     def test_cow_divergence_keeps_sharer_byte_identical(self):
         """Two requests share a prefix template's blocks; each

@@ -393,6 +393,30 @@ class TestReshardByteIdentity:
                     )
 
 
+    @pytest.mark.parametrize("src_dp,dst_dp", [(2, 4), (4, 2)])
+    def test_a_resize_moves_each_byte_of_the_state_once(
+        self, cpu_mesh_devices, src_dp, dst_dp
+    ):
+        """2->4 and 4->2: the plan tiles every distinct target box
+        exactly once, so what the move reports moved is the byte size
+        of the state, a replicated leaf once; nothing crosses a process
+        here; and the state is equal after the move."""
+        from dlrover_tpu.parallel.mesh import build_mesh
+        from dlrover_tpu.reshard.coordinator import reshard_state
+
+        src_spec, dst_spec = MeshSpec(dp=src_dp), MeshSpec(dp=dst_dp)
+        src_mesh = build_mesh(src_spec, cpu_mesh_devices[:src_dp])
+        dst_mesh = build_mesh(dst_spec, cpu_mesh_devices[:dst_dp])
+        host, _, state = self._state(src_mesh, src_spec)
+        new_state, outcome = reshard_state(state, dst_mesh)
+        assert outcome.ok and outcome.segments > 0
+        state_bytes = sum(a.nbytes for a in host.values())
+        assert round(outcome.moved_mb * (1 << 20)) == state_bytes > 0
+        assert outcome.moved_cross_mb == 0  # one process holds it all
+        for k, arr in new_state.items():
+            np.testing.assert_array_equal(np.asarray(arr), host[k])
+
+
 def spec_size(spec, axis):
     return getattr(spec, axis, 1)
 
@@ -552,8 +576,6 @@ class TestReshardChaos:
             puller.stop()
 
     def test_stall_peer_delays_but_completes(self):
-        import time
-
         from dlrover_tpu import chaos
 
         W = np.arange(16, dtype=np.float32).reshape(4, 4)
@@ -578,9 +600,9 @@ class TestReshardChaos:
                     seg, epoch=1, addr=server.addr
                 ),
             )
-            t0 = time.perf_counter()
             tensors, _i, _s = mover.execute(plan)
-            assert time.perf_counter() - t0 >= 0.3
+            assert chaos.active_plan().stats() == {
+                "reshard.stall_peer": 1}
             np.testing.assert_array_equal(tensors["w|0"], W)
         finally:
             server.stop()

@@ -4,7 +4,7 @@ Everything here runs WITHOUT jax or sockets: the gateway core takes an
 injectable clock, the replica runner takes a fake decode server with
 the real incremental-admission surface, and transports are loopback.
 The real-model integration rides the ``serving+slow`` e2e lane
-(``test_chaos_e2e.py``) and ``bench.py --serve_bench``.
+(``test_chaos_e2e.py``).
 """
 
 import collections
@@ -1050,6 +1050,79 @@ class TestReplicaRunner:
 # ---------------------------------------------------------------------------
 # Wire round-trip of the new messages
 # ---------------------------------------------------------------------------
+
+
+class TestFleetAccounting:
+    def test_healthy_fleet_accounts_every_request_and_token(self, tmp_path):
+        """5 requests x 6 tokens through a healthy replica: every
+        request admitted once and finished once, every token of the
+        budget streamed and returned, nothing rejected, redispatched,
+        failed or timed out."""
+        core = GatewayCore(GatewayConfig())
+        (runner,) = make_loopback_fleet(core, 1, tmp=str(tmp_path))
+        th = threading.Thread(target=runner.run, daemon=True)
+        th.start()
+        prompts = {f"q{i}": [i + 1, i + 2, i + 3] for i in range(5)}
+        for rid, p in prompts.items():
+            assert core.submit(rid, p, 6).status == "accepted"
+        assert wait_for(lambda: core.counters["completed"] == 5)
+        core.drain("r0")
+        th.join(timeout=10)
+        c = core.counters
+        assert c["submitted"] == c["accepted"] == c["completed"] == 5
+        for key in ("rejected", "redispatched", "failed", "timeout",
+                    "duplicate_completions", "late_completions",
+                    "replicas_lost"):
+            assert c[key] == 0, key
+        for rid, p in prompts.items():
+            assert core.status(rid).tokens == expected_tokens(p, 6)
+        new = sum(len(core.status(rid).tokens) for rid in prompts)
+        assert new == 5 * 6
+        assert c["streamed_tokens"] == new
+        assert runner.served == 5
+
+    @pytest.mark.parametrize("fingerprinted", [True, False])
+    def test_every_prefixed_grant_is_a_hit_a_miss_or_a_steal(
+        self, fingerprinted
+    ):
+        """12 requests over two prefix templates, one warm at a
+        one-slot replica and one nobody holds, against a cold replica
+        with room: each fingerprinted grant is counted exactly once as
+        hit, miss or steal; with the fingerprints withheld the router
+        has nothing to route on and counts none."""
+        core, _ = make_core()
+        core.register("warm", 1)
+        core.register("cold", 2)
+        core.poll("warm", 1, [], warm_prefixes=["fpA"])
+        for i in range(12):
+            fp = ("fpA", "fpB")[i % 2] if fingerprinted else ""
+            core.submit(f"q{i}", [7, 8, i], 4,
+                        prefix_len=2 if fingerprinted else 0,
+                        prefix_fp=fp)
+        held = {"warm": [], "cold": []}
+        slots = {"warm": 1, "cold": 2}
+        granted = 0
+        for _ in range(40):
+            for rid in ("cold", "warm"):
+                # finish what the replica holds, then ask for more
+                for req in held[rid]:
+                    core.complete(rid, req.req_id, [1], True, "", False)
+                held[rid] = list(core.poll(
+                    rid, slots[rid], [],
+                    warm_prefixes=["fpA"] if rid == "warm" else [],
+                ).requests)
+                granted += len(held[rid])
+            if granted == 12:
+                break
+        assert granted == 12
+        c = core.counters
+        outcomes = c["prefix_hits"] + c["prefix_misses"] \
+            + c["prefix_steals"]
+        if fingerprinted:
+            assert outcomes == 12
+            assert c["prefix_hits"] > 0 and c["prefix_misses"] == 6
+        else:
+            assert outcomes == 0
 
 
 def test_serving_messages_roundtrip():

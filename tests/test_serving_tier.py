@@ -6,9 +6,10 @@ machines behind loopback transports, the registry is a ``LocalKv``,
 segment servers are stores behind ``kvseg.handle_fetch`` loopbacks.
 The real-socket tier (RegistryServer + RpcKv + gateway subprocesses +
 ``serving.gateway_kill``) rides the ``serving+chaos+slow`` e2e lane in
-``test_chaos_e2e.py`` and ``bench.py --load_bench``.
+``test_chaos_e2e.py``.
 """
 
+import collections
 import threading
 import time
 
@@ -688,6 +689,68 @@ class TestTierClientFailover:
 # ---------------------------------------------------------------------------
 # P2P KV handoff: store, pulls, ticket path, fallback ladder
 # ---------------------------------------------------------------------------
+
+
+class TestTierAdmissionConservation:
+    """Every request offered to the tier is accounted at exactly one
+    gateway, under its admission cap and over it; what a tier admits
+    grows with its gateways.  Driven by hand on the bare cores: no
+    thread, no clock."""
+
+    CAP = 4
+
+    def _offer(self, tier, n):
+        acks = collections.Counter()
+        for i in range(n):
+            rid = f"q{i}"
+            gid = tier.ring.owner(rid)
+            ack = tier.addr_map[f"addr-{gid}"].call(wire.ServeSubmit(
+                req_id=rid, prompt=[i + 1, i + 2], max_new_tokens=3))
+            acks[ack.status] += 1
+        return acks
+
+    def _serve_everything_admitted(self, tier):
+        for core in tier.cores.values():
+            core.register("r0", 2)
+            while True:
+                grants = core.poll("r0", 2, []).requests
+                if not grants:
+                    break
+                for g in grants:
+                    core.complete(
+                        "r0", g.req_id,
+                        expected_tokens(g.prompt, g.max_new_tokens),
+                        True, "", False)
+
+    @pytest.mark.parametrize("offered", [3, 40])
+    @pytest.mark.parametrize("gateways", [1, 2])
+    def test_every_offered_request_is_accounted(self, gateways, offered):
+        tier = _Tier(gateways, queue_cap=self.CAP)
+        acks = self._offer(tier, offered)
+        self._serve_everything_admitted(tier)
+        total = collections.Counter()
+        for core in tier.cores.values():
+            c = core.counters
+            assert c["submitted"] == c["accepted"] + c["rejected"]
+            assert c["accepted"] == c["completed"] + c["timeout"] \
+                + c["failed"]
+            assert c["accepted"] <= self.CAP
+            total.update({k: c[k] for k in (
+                "submitted", "accepted", "rejected", "completed")})
+        assert total["submitted"] == offered
+        assert total["accepted"] == acks["accepted"]
+        assert total["rejected"] == acks["rejected"]
+        merged = merge_snapshots(
+            [c.stats_snapshot() for c in tier.cores.values()])
+        assert merged["counters"]["completed"] == total["completed"]
+        if offered <= self.CAP:
+            assert total["rejected"] == 0
+            assert total["completed"] == offered
+        else:
+            # over the cap something is rejected, and every gateway of
+            # the tier admits a queue of its own
+            assert total["rejected"] > 0
+            assert total["accepted"] == gateways * self.CAP
 
 
 class _FakeKvServer:

@@ -184,6 +184,22 @@ class TestCellPlaneSim:
         assert once() == once()
 
 
+    @pytest.mark.parametrize("cells", [1, 2, 4])
+    def test_every_op_is_counted_at_its_owning_cell(self, cells):
+        """Offered past the one-cell ceiling: every offered op
+        completes (the rig drains), none errs, and each is counted at
+        exactly one cell, every cell of the ring owning some."""
+        row = CellPlaneSim(
+            n_cells=cells, floor_ms=3.0, offered_rps=1200.0, clients=8,
+            duration_s=1.0, warmup_s=0.25, overhead_ms=1.0,
+        ).run()
+        assert row["errors"] == 0
+        assert row["completed"] == 1200 * 1.25 + 1  # t = 0 .. 1.25 s
+        assert len(row["per_cell"]) == cells
+        assert sum(row["per_cell"].values()) == row["completed"]
+        assert all(v > 0 for v in row["per_cell"].values())
+
+
 # ---------------------------------------------------------------------------
 # micro rig: the fidelity smoke
 # ---------------------------------------------------------------------------

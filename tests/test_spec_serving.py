@@ -247,6 +247,52 @@ class TestSpecRouting:
         assert [r.req_id for r in g.requests] == ["r1"]
 
 
+    @pytest.mark.parametrize("fleet,grants,bypass", [
+        ("spec_ample", 6, 0),      # every long decode reaches a spec seat
+        ("plain_only", 0, 6),      # no spec replica: all given up to plain
+        ("spec_saturated", None, None),  # one spec seat against six
+    ])
+    def test_every_long_decode_is_a_grant_or_a_bypass(
+        self, fleet, grants, bypass
+    ):
+        """Six long decodes among four short ones: each long request
+        is counted exactly once, as a grant to a spec replica or as a
+        bypass to a plain one; a short one counts as neither."""
+        core = _mk_core()
+        slots = {"plain": 2}
+        if fleet != "plain_only":
+            slots["fast"] = 6 if fleet == "spec_ample" else 1
+        for rid, n in slots.items():
+            core.register(rid, n, spec=(rid == "fast"))
+        for i in range(10):
+            core.submit(f"q{i}", [i + 1], 32 if i < 6 else 4)
+        held = {rid: [] for rid in slots}
+        granted = 0
+        order = ["fast", "plain"] if "fast" in slots else ["plain"]
+        for _ in range(40):
+            for rid in order:
+                held[rid] = list(core.poll(
+                    rid, slots[rid] - len(held[rid]),
+                    [r.req_id for r in held[rid]]).requests) + held[rid]
+            for rid in order:
+                # the spec replica is slow to finish: while it is full
+                # the plain one is what is left for a long decode
+                if rid == "plain" or fleet == "spec_ample":
+                    for req in held[rid]:
+                        core.complete(rid, req.req_id, [1], True, "",
+                                      False)
+                        granted += 1
+                    held[rid] = []
+            if granted + sum(len(v) for v in held.values()) == 10:
+                break
+        c = core.counters
+        assert c["spec_grants"] + c["spec_bypass"] == 6
+        if grants is None:
+            assert c["spec_grants"] >= 1 and c["spec_bypass"] >= 1
+        else:
+            assert (c["spec_grants"], c["spec_bypass"]) == (grants, bypass)
+
+
 class TestDraftControlPlane:
     def test_poll_reply_carries_least_loaded_draft_addr(self):
         core = _mk_core()

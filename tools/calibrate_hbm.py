@@ -141,7 +141,7 @@ def main() -> int:
             file=sys.stderr,
         )
         # Flush partials as points complete (a wedged run still leaves
-        # data, same pattern as bench.py's BENCH_PARTIAL).
+        # data).
         import os as _os
 
         _out = _os.path.join(

@@ -9,7 +9,7 @@ prints the observed fractions plus the top op names by self time, so
 wrong prefixes are immediately visible (and fixable).
 
 Run on the chip:  python tools/validate_op_metrics.py
-Writes OP_METRICS_TPU.json next to bench.py.
+Writes OP_METRICS_TPU.json at the repo root.
 """
 
 from __future__ import annotations
