@@ -24,7 +24,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.ops.cross_entropy import (
-    linear_softmax_cross_entropy,
+    linear_softmax_cross_entropy_sum,
     softmax_cross_entropy,
 )
 from dlrover_tpu.ops.flash_attention import flash_attention
@@ -808,8 +808,12 @@ def loss_fn(
             segment_ids=seg, fp8_states=fp8_states,
         )
         with jax.named_scope("lm_head_loss"):
-            per_tok = linear_softmax_cross_entropy(
-                x, params["lm_head"].astype(cfg.dtype), targets
+            # The row weights are known here, so the reduced op forms
+            # the head's gradients in its forward scan.
+            ce = linear_softmax_cross_entropy_sum(
+                x, params["lm_head"].astype(cfg.dtype), targets,
+                None if valid is None
+                else valid / jnp.maximum(jnp.sum(valid), 1.0),
             )
     else:
         logits, aux = forward(
@@ -818,12 +822,11 @@ def loss_fn(
         )
         with jax.named_scope("lm_head_loss"):
             per_tok = softmax_cross_entropy(logits, targets)
-    with jax.named_scope("lm_head_loss"):
-        if valid is not None:
-            ce = jnp.sum(per_tok * valid) / jnp.maximum(
-                jnp.sum(valid), 1.0)
-        else:
-            ce = jnp.mean(per_tok)
+            if valid is not None:
+                ce = jnp.sum(per_tok * valid) / jnp.maximum(
+                    jnp.sum(valid), 1.0)
+            else:
+                ce = jnp.mean(per_tok)
     loss = ce + moe_aux_weight * aux["moe_aux"]
     if "moe_z" in aux:
         loss = loss + moe_z_weight * aux["moe_z"]

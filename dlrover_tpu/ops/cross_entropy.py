@@ -232,3 +232,126 @@ def linear_softmax_cross_entropy(
         x.reshape(-1, x.shape[-1]), w, labels.reshape(-1), chunk_rows
     )
     return out.reshape(shape)
+
+
+# ---------------------------------------------------------------------------
+# The reduced form: sum_r weights[r] * loss[r].  A loss is the last
+# operation of the graph, so its cotangent is a scalar and the row weights
+# are known in the forward pass: the forward rule forms dx and dw in the
+# one scan that computes the loss, the one time the chunk logits exist,
+# and the backward rule only scales them.  Three [chunk, D] x [D, V]-sized
+# matmuls a chunk where the per-token op above needs four.
+# ---------------------------------------------------------------------------
+
+
+def _chunk_weights(weights, pad, shape):
+    return (jnp.pad(weights, (0, pad)) if pad else weights).reshape(shape)
+
+
+def _carry_init(shape, *operands):
+    """fp32 zeros that vary over the manual mesh axes the operands vary
+    over: inside a ``shard_map`` a scan's carry must enter with the type
+    it leaves with."""
+    zeros = jnp.zeros(shape, jnp.float32)
+    vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
+    return jax.lax.pcast(zeros, tuple(vma), to="varying") if vma else zeros
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _linear_xent_sum(x2, w, labels, weights, chunk_rows):
+    xs, ls, pad = _chunk(x2, labels, chunk_rows)
+
+    def body(total, xlg):
+        x_c, l_c, g_c = xlg
+        return total + jnp.sum(_chunk_loss(x_c, w, l_c) * g_c), None
+
+    total, _ = jax.lax.scan(
+        body, _carry_init((), x2, w, weights),
+        (xs, ls, _chunk_weights(weights, pad, ls.shape)),
+    )
+    return total
+
+
+def _linear_xent_sum_fwd(x2, w, labels, weights, chunk_rows):
+    R = x2.shape[0]
+    xs, ls, pad = _chunk(x2, labels, chunk_rows)
+
+    def body(carry, xlg):
+        total, dw = carry
+        x_c, l_c, g_c = xlg
+        # _chunk_loss's arithmetic, written out because the gradients
+        # take its intermediates: exp(logits - m) / sum is the softmax
+        # of _linear_xent_bwd without a second max / sum pass.
+        logits = jnp.dot(x_c, w, preferred_element_type=jnp.float32)
+        m = jnp.max(logits, axis=-1)
+        e = jnp.exp(logits - m[:, None])
+        s = jnp.sum(e, axis=-1)
+        onehot = (
+            jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+            == l_c[:, None]
+        )
+        target = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
+        total = total + jnp.sum((m + jnp.log(s) - target) * g_c)
+        # From here down _linear_xent_bwd's body, dtype for dtype.
+        dlogits = (e / s[:, None] - onehot.astype(jnp.float32)) * g_c[:, None]
+        dx_c = jnp.dot(
+            dlogits.astype(w.dtype), w.T, preferred_element_type=jnp.float32
+        )
+        dw = dw + jnp.dot(
+            x_c.T.astype(jnp.float32), dlogits,
+            preferred_element_type=jnp.float32,
+        )
+        return (total, dw), dx_c.astype(x2.dtype)
+
+    operands = (x2, w, weights)
+    (total, dw), dx = jax.lax.scan(
+        body,
+        (_carry_init((), *operands), _carry_init(w.shape, *operands)),
+        (xs, ls, _chunk_weights(weights, pad, ls.shape)),
+    )
+    return total, (dx.reshape(-1, x2.shape[1])[:R], dw.astype(w.dtype))
+
+
+def _linear_xent_sum_bwd(chunk_rows, res, g):
+    dx, dw = res
+    return g.astype(dx.dtype) * dx, g.astype(dw.dtype) * dw, None, None
+
+
+_linear_xent_sum.defvjp(_linear_xent_sum_fwd, _linear_xent_sum_bwd)
+
+
+def linear_softmax_cross_entropy_sum(
+    x: jax.Array,
+    w: jax.Array,
+    labels: jax.Array,
+    weights: Optional[jax.Array] = None,
+    *,
+    chunk_rows: int = _DEFAULT_CHUNK_ROWS,
+) -> jax.Array:
+    """Fused ``sum(weights * softmax_cross_entropy(x @ w, labels))``.
+
+    x: [..., D], w: [D, V], labels: [...] int, weights: [...] float row
+    weights or None for the mean (every row 1/N) — returns the fp32
+    scalar, logits never materialized beyond one [chunk_rows, V] block.
+
+    Contract: the weights are known in the forward pass and are constants
+    of the loss (no gradient flows to them).  That is what lets the
+    forward rule under differentiation compute ``dx`` and ``dw`` in the
+    same scan as the loss — its residuals are exactly those two, in
+    ``x``'s and ``w``'s dtype, and the backward rule multiplies them by
+    the scalar cotangent.  Without a gradient the scan is forward only
+    (one matmul a chunk, no ``dw`` accumulator).  Same dtypes at the
+    same places as the per-token op's backward, so the gradients are
+    those of ``sum(weights * linear_softmax_cross_entropy(...))``.
+
+    The per-token op stays for callers that need per-token losses: a
+    [...] result can meet any cotangent, so its backward has to
+    recompute each chunk's logits.
+    """
+    rows = labels.size
+    if weights is None:
+        weights = jnp.full((rows,), 1.0 / rows, jnp.float32)
+    return _linear_xent_sum(
+        x.reshape(-1, x.shape[-1]), w, labels.reshape(-1),
+        weights.reshape(-1).astype(jnp.float32), chunk_rows,
+    )

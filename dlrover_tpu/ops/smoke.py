@@ -368,3 +368,65 @@ def run_kernel_smoke(
     )
     flush()
     return results
+
+
+#: (rows, D, V) of the lm head in the benchmark's steady cells
+#: (mistral7b at 2 x 8,192 tokens, OLMoE at 8 x 4,096)
+HEAD_SHAPES = ((16384, 4096, 32000), (32768, 2048, 50304))
+
+
+def _head_gradient_case(rows: int, D: int, V: int) -> Dict:
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.ops.cross_entropy import (
+        linear_softmax_cross_entropy,
+        linear_softmax_cross_entropy_sum,
+    )
+
+    def rel_l2(a, b):
+        a, b = (np.asarray(t, np.float32).ravel() for t in (a, b))
+        return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+    k = jax.random.split(jax.random.PRNGKey(rows), 3)
+    x = jax.random.normal(k[0], (rows, D), jnp.bfloat16)
+    w = (jax.random.normal(k[1], (D, V)) * 0.02).astype(jnp.bfloat16)
+    y = jax.random.randint(k[2], (rows,), 0, V)
+    reduced = jax.jit(jax.value_and_grad(
+        lambda x, w: linear_softmax_cross_entropy_sum(x, w, y),
+        argnums=(0, 1)))
+    per_token = jax.jit(jax.value_and_grad(
+        lambda x, w: jnp.mean(linear_softmax_cross_entropy(x, w, y)),
+        argnums=(0, 1)))
+    (l_s, g_s), (l_t, g_t) = reduced(x, w), per_token(x, w)
+    res = {
+        "shape": [rows, D, V], "backend": jax.default_backend(),
+        "loss": float(l_s),
+        "loss_rel": abs(float(l_s) - float(l_t)) / abs(float(l_t)),
+        "dx_rel_l2": rel_l2(g_s[0], g_t[0]),
+        "dw_rel_l2": rel_l2(g_s[1], g_t[1]),
+    }
+    res["ok"] = bool(max(
+        res["loss_rel"], res["dx_rel_l2"], res["dw_rel_l2"]) < 1e-2)
+    return res
+
+
+def run_head_gradient_check(shapes=HEAD_SHAPES) -> List[Dict]:
+    """The reduced lm-head loss (gradients formed in its forward scan: what
+    a training step runs) against the per-token op's recompute backward
+    (what the benchmark's gradient comparison differentiates), in bf16 at
+    the cells' shapes: relative L2 of loss, dx and dw between the two.
+    Not one of ``run_kernel_smoke``'s cases: ``python -m
+    dlrover_tpu.ops.smoke`` runs it alone and prints one line a shape."""
+    out = []
+    for rows, D, V in shapes:
+        res = _head_gradient_case(rows, D, V)
+        print("HEAD_GRADIENT_CHECK " + json.dumps(res), flush=True)
+        out.append(res)
+    return out
+
+
+if __name__ == "__main__":
+    import sys
+
+    sys.exit(0 if all(r["ok"] for r in run_head_gradient_check()) else 1)

@@ -140,6 +140,21 @@ def _fused_lm_head():
                 ((2048,), i32)], {}
 
 
+def _fused_lm_head_sum():
+    """The reduced form ``llama.loss_fn`` calls (gradients formed in its
+    forward scan), at the per-token case's shapes."""
+    from dlrover_tpu.ops.cross_entropy import (
+        linear_softmax_cross_entropy_sum,
+    )
+
+    def fn(x, w, y):
+        return jax.value_and_grad(
+            lambda x, w: linear_softmax_cross_entropy_sum(x, w, y),
+            argnums=(0, 1))(x, w)
+    return fn, [((2048, 1024), bf16), ((1024, 32000), bf16),
+                ((2048,), i32)], {}
+
+
 def _quant():
     from dlrover_tpu.ops.quant import quantize_blockwise
 
@@ -182,6 +197,7 @@ KERNEL_CASES = {
     "rmsnorm-grad": lambda: _rmsnorm(True),
     "cross_entropy-fwd": _xent,
     "fused_lm_head_ce-grad": _fused_lm_head,
+    "fused_lm_head_ce_sum-grad": _fused_lm_head_sum,
     "quantize_blockwise-fwd": _quant,
     "grouped_matmul-grad": lambda: _grouped_matmul("pallas"),
     "grouped_matmul_reference-grad": lambda: _grouped_matmul("reference"),

@@ -389,8 +389,12 @@ class TestBuildSpansAndScopes:
         job, _ = built
         table = job.program["scopes"]
         verdicts = {tuple(v) for v in table.values()}
+        # No ("backward", "lm_head_loss"): the reduced lm-head loss forms
+        # dx and dw in its forward scan, and what its backward rule adds
+        # (a scaling by the cotangent, 1.0 here) XLA folds away — the
+        # head's gradient matmuls read phase "forward".
         for want in (("forward", "lm_head_loss"),
-                     ("backward", "lm_head_loss"),
+                     ("backward", "embed"),
                      ("optimizer", "optimizer"),
                      ("forward", "attention"), ("backward", "attention"),
                      ("recompute", "attention"), ("backward", "mlp"),

@@ -277,6 +277,193 @@ class TestCrossEntropy:
                                    atol=1e-5)
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (scan, jit, custom_vjp)
+    included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def head_matmuls_and_scans(fn, *args, vocab):
+    """(dot_generals with a vocab-sized operand or result dim inside a
+    scan, scans holding one, whether such a scan carries a [D, V] array) —
+    the structure the reduced lm-head loss is pinned to."""
+    closed = jax.make_jaxpr(fn)(*args)
+    dots, scans, carries = 0, 0, False
+    for eqn in _eqns(closed.jaxpr):
+        if eqn.primitive.name != "scan":
+            continue
+        body = eqn.params["jaxpr"].jaxpr
+        n = sum(
+            1 for e in _eqns(body)
+            if e.primitive.name == "dot_general" and any(
+                vocab in v.aval.shape for v in (*e.invars, *e.outvars))
+        )
+        if not n:
+            continue
+        dots, scans = dots + n, scans + 1
+        nc, k = eqn.params["num_consts"], eqn.params["num_carry"]
+        carries |= any(
+            v.aval.ndim == 2 and v.aval.shape[-1] == vocab
+            for v in eqn.invars[nc:nc + k])
+    return dots, scans, carries
+
+
+class TestLinearXentSum:
+    """The reduced form of the fused lm-head loss: its forward rule forms
+    dx and dw in the scan that computes the loss."""
+
+    R, D, V, CHUNK = 26, 12, 32, 8  # 4 chunks, 6 rows of padding
+
+    def _data(self, kind, dtype):
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        x = jax.random.normal(ks[0], (self.R, self.D)).astype(dtype)
+        w = (jax.random.normal(ks[1], (self.D, self.V)) * 0.2).astype(dtype)
+        labels = jax.random.randint(ks[2], (self.R,), 0, self.V)
+        if kind == "none":
+            weights = None
+        elif kind == "mask":  # 0/1, the whole second chunk masked
+            weights = jnp.ones((self.R,)).at[8:16].set(0.0).at[3].set(0.0)
+        else:
+            weights = jax.random.uniform(ks[3], (self.R,)) / self.R
+        return x, w, labels, weights
+
+    @staticmethod
+    def _reduce(per_tok, weights):
+        if weights is None:
+            return jnp.mean(per_tok)
+        return jnp.sum(per_tok * weights)
+
+    def _losses(self, labels, weights):
+        from dlrover_tpu.ops.cross_entropy import (
+            linear_softmax_cross_entropy,
+            linear_softmax_cross_entropy_sum,
+        )
+
+        def reduced(x, w, labels=labels, weights=weights):
+            return linear_softmax_cross_entropy_sum(
+                x, w, labels, weights, chunk_rows=self.CHUNK)
+
+        def per_token(x, w, labels=labels, weights=weights):
+            return self._reduce(linear_softmax_cross_entropy(
+                x, w, labels, chunk_rows=self.CHUNK), weights)
+
+        def unfused(x, w, labels=labels, weights=weights):
+            logits = x.astype(jnp.float32) @ w.astype(jnp.float32)
+            return self._reduce(softmax_cross_entropy(
+                logits, labels, backend="reference"), weights)
+
+        return reduced, per_token, unfused
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("kind", ["none", "mask", "fractional"])
+    def test_value_is_the_weighted_sum_of_the_per_token_op(
+            self, kind, dtype):
+        x, w, labels, weights = self._data(kind, dtype)
+        reduced, per_token, unfused = self._losses(labels, weights)
+        got = reduced(x, w)
+        assert got.shape == () and got.dtype == jnp.float32
+        np.testing.assert_allclose(got, per_token(x, w), rtol=1e-5)
+        np.testing.assert_allclose(
+            got, unfused(x, w),
+            rtol=1e-5 if dtype == jnp.float32 else 2e-2)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("kind", ["none", "mask", "fractional"])
+    @pytest.mark.parametrize(
+        "wrap", ["plain", "checkpoint", "microbatch_scan", "mesh_2x2"])
+    def test_grads_match_the_per_token_path(self, wrap, kind, dtype):
+        """(dx, dw) under an upstream scalar that is not 1 (3 * loss +
+        another term), alone, under jax.checkpoint, inside a grad-accum
+        style scan over microbatches, and on a 2 x 2 (fsdp, tp) mesh."""
+        x, w, labels, weights = self._data(kind, dtype)
+        reduced, per_token, unfused = self._losses(labels, weights)
+
+        def total(head):
+            def f(x, w, *lw):
+                return 3.0 * head(x, w, *lw) + 0.1 * jnp.sum(
+                    x.astype(jnp.float32) ** 2) + jnp.sum(
+                    w.astype(jnp.float32))
+            return f
+
+        def grads(head):
+            f = total(head)
+            if wrap == "plain":
+                return jax.grad(f, (0, 1))(x, w)
+            if wrap == "checkpoint":
+                return jax.grad(jax.checkpoint(f), (0, 1))(x, w)
+            if wrap == "mesh_2x2":
+                from jax.sharding import Mesh, NamedSharding
+                from jax.sharding import PartitionSpec as P
+                mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                            ("fsdp", "tp"))
+                xs = jax.device_put(
+                    jnp.pad(x, ((0, 2), (0, 0))),  # 28 rows over fsdp
+                    NamedSharding(mesh, P("fsdp", None)))
+                ws = jax.device_put(w, NamedSharding(mesh, P(None, "tp")))
+                gx, gw = jax.jit(jax.grad(
+                    lambda xp, w: f(xp[:self.R], w), (0, 1)))(xs, ws)
+                return gx[:self.R], gw
+            # two microbatches of 13 rows, gradients summed by the scan
+            half = self.R // 2
+            lw = (labels.reshape(2, half),
+                  (jnp.full((self.R,), 1.0 / self.R) if weights is None
+                   else weights).reshape(2, half))
+
+            def body(acc, mb):
+                x_mb, l_mb, w_mb = mb
+                gx, gw = jax.grad(f, (0, 1))(x_mb, w, l_mb, w_mb)
+                return acc + gw.astype(jnp.float32), gx
+            gw, gx = jax.lax.scan(
+                body, jnp.zeros(w.shape, jnp.float32),
+                (x.reshape(2, half, self.D), *lw))
+            return gx.reshape(self.R, self.D), gw
+
+        got, same_dtypes, ref = grads(reduced), grads(per_token), grads(
+            unfused)
+        tol = dict(atol=1e-5) if dtype == jnp.float32 else dict(
+            atol=2e-2, rtol=2e-2)
+        for g, a, b in zip(got, same_dtypes, ref):
+            assert g.dtype == a.dtype and g.shape == a.shape
+            g, a, b = (np.asarray(t, np.float32) for t in (g, a, b))
+            np.testing.assert_allclose(g, a, **tol)
+            np.testing.assert_allclose(g, b, **tol)
+
+    def test_no_gradient_reaches_the_weights(self):
+        x, w, labels, weights = self._data("fractional", jnp.float32)
+        reduced, _, _ = self._losses(labels, weights)
+        g = jax.grad(lambda wt: reduced(x, w, weights=wt))(weights)
+        np.testing.assert_array_equal(np.asarray(g), 0.0)
+
+    def test_the_smoke_check_compares_both_ops(self, capsys):
+        """``python -m dlrover_tpu.ops.smoke`` at a toy shape: several
+        chunks, the two ops a rounding apart."""
+        from dlrover_tpu.ops.smoke import run_head_gradient_check
+
+        (res,) = run_head_gradient_check(((2500, 64, 512),))
+        assert res["ok"] and res["shape"] == [2500, 64, 512]
+        assert max(res["loss_rel"], res["dx_rel_l2"],
+                   res["dw_rel_l2"]) < 1e-3
+        assert "HEAD_GRADIENT_CHECK" in capsys.readouterr().out
+
+    @pytest.mark.parametrize("grad", [True, False])
+    def test_three_matmuls_and_one_scan_with_a_gradient_one_without(
+            self, grad):
+        x, w, labels, weights = self._data("mask", jnp.bfloat16)
+        reduced, per_token, _ = self._losses(labels, weights)
+        wrap = (lambda f: jax.value_and_grad(f, (0, 1))) if grad else (
+            lambda f: f)
+        assert head_matmuls_and_scans(
+            wrap(reduced), x, w, vocab=self.V
+        ) == ((3, 1, True) if grad else (1, 1, False))
+        # the per-token op: recompute backward, a scan each way
+        assert head_matmuls_and_scans(
+            wrap(per_token), x, w, vocab=self.V
+        ) == ((4, 2, True) if grad else (1, 1, False))
+
+
 class TestQuant:
     def test_quant_roundtrip_error_bounded(self):
         x = jax.random.normal(jax.random.PRNGKey(0), (1000,)) * 3.0
