@@ -23,11 +23,17 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from dlrover_tpu.models.llama import LlamaConfig
+from dlrover_tpu.models.llama import LlamaConfig, refuse_looped
 
 
 def config_from_hf(hf_config: Any) -> LlamaConfig:
     """transformers ``LlamaConfig`` -> :class:`LlamaConfig`."""
+    passes = int(getattr(hf_config, "total_ut_steps", 1) or 1)
+    if passes > 1:
+        raise ValueError(
+            f"HF config has total_ut_steps={passes}: a looped model's "
+            "checkpoint (four norms a layer, an exit gate) has no layout "
+            "in this converter")
     derived_hd = int(hf_config.hidden_size) // int(
         hf_config.num_attention_heads
     )
@@ -166,6 +172,7 @@ def _build_params(
     """The single HF-Llama -> params layout table, shared by the
     in-memory and streaming importers (key names, transposes,
     tied-embedding fallback, bias rejection live HERE only)."""
+    refuse_looped(cfg, "the HF Llama layout table (models.hf_convert)")
     bias_keys = [k for k in all_keys() if k.endswith(".bias")]
     if bias_keys:
         raise ValueError(

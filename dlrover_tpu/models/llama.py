@@ -71,10 +71,42 @@ class LlamaConfig:
     # save every dot output (``dots_saveable``) or re-run a forward whose
     # own intermediates still peak the same (``nothing_saveable``).
     remat_block: bool = False
+    # A looped model (Ouro, arXiv:2510.25741): the whole layer stack runs
+    # ``loop_passes`` times on the SAME weights; every pass ends in the
+    # final norm, whose output is that pass's result and the next pass's
+    # input.
+    loop_passes: int = 1
+    # Sandwich norm: an RMSNorm on each branch's OUTPUT too (gains
+    # ``ln1_out`` / ``ln2_out``), before the residual add.
+    branch_norm: bool = False
+    # The exit gate of a looped model: ``sigmoid(z_t @ w + b)`` per token
+    # and pass (``params["exit_gate"]``) makes a distribution over the
+    # pass a token leaves at, and the loss is the expectation of the
+    # passes' cross-entropies under it minus this weight times its
+    # entropy (:func:`exit_distribution`, :func:`loss_fn`).  None: no gate.
+    exit_gate_beta: Optional[float] = None
+
+    def __post_init__(self):
+        if (self.loop_passes > 1) != (self.exit_gate_beta is not None):
+            raise ValueError(
+                f"LlamaConfig: loop_passes={self.loop_passes} with "
+                f"exit_gate_beta={self.exit_gate_beta}: a looped model's "
+                "loss is the expectation over its exit gate, and a gate "
+                "needs more than one pass to choose from")
+        if self.loop_passes > 1 and self.num_experts > 0:
+            raise ValueError(
+                f"LlamaConfig: loop_passes={self.loop_passes} with "
+                f"num_experts={self.num_experts}: the routed block's "
+                "counters are kept per layer, not per (pass, layer)")
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_head
+
+    @property
+    def block_applications(self) -> int:
+        """Blocks a token passes through: layers x passes."""
+        return self.n_layer * self.loop_passes
 
     def is_moe_layer(self, i: int) -> bool:
         """Single source of truth for MoE placement (init_params,
@@ -125,6 +157,12 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
         "ln_f": jnp.ones((cfg.d_model,), jnp.float32),
         "layers": [],
     }
+    if cfg.exit_gate_beta is not None:
+        params["exit_gate"] = {
+            "w": jax.random.normal(
+                keys[cfg.n_layer + 2], (cfg.d_model,), jnp.float32) * 0.02,
+            "b": jnp.zeros((), jnp.float32),
+        }
     hd = cfg.head_dim
     for i in range(cfg.n_layer):
         k = jax.random.split(keys[2 + i], 8)
@@ -139,6 +177,9 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
         if cfg.qk_norm:
             layer["q_norm"] = jnp.ones((cfg.n_head * hd,), jnp.float32)
             layer["k_norm"] = jnp.ones((cfg.n_kv_head * hd,), jnp.float32)
+        if cfg.branch_norm:
+            layer["ln1_out"] = jnp.ones((cfg.d_model,), jnp.float32)
+            layer["ln2_out"] = jnp.ones((cfg.d_model,), jnp.float32)
         if cfg.is_moe_layer(i):
             layer["moe"] = {
                 "router": _dense(k[4], cfg.d_model, cfg.num_experts),
@@ -178,6 +219,9 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
         if cfg.qk_norm:
             ax["q_norm"] = (None,)
             ax["k_norm"] = (None,)
+        if cfg.branch_norm:
+            ax["ln1_out"] = (None,)
+            ax["ln2_out"] = (None,)
         if has_moe:
             ax["moe"] = {
                 "router": (None, None),
@@ -196,12 +240,15 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
     layers = []
     for i in range(cfg.n_layer):
         layers.append(layer_axes(cfg.is_moe_layer(i)))
-    return {
+    axes = {
         "embed": ("vocab", "embed"),
         "lm_head": ("embed", "vocab"),
         "ln_f": (None,),
         "layers": layers,
     }
+    if cfg.exit_gate_beta is not None:
+        axes["exit_gate"] = {"w": (None,), "b": ()}
+    return axes
 
 
 def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -546,7 +593,8 @@ def block_apply(
     # ``moe_router`` with its norm, ``moe_permute``, ``moe_experts``,
     # ``moe_combine`` with the residual add) go into every instruction's
     # ``op_name`` of the compiled step: ``accelerate.program_summary``
-    # reads them back, outermost scope only, hence siblings.
+    # reads them back, outermost scope only, hence siblings.  A branch's
+    # output norm (``cfg.branch_norm``) sits in its branch's scope.
     with jax.named_scope("attention"):
         h = rmsnorm(x, layer["ln1"], eps=cfg.rms_eps)
         if attn_fn is not None:
@@ -562,6 +610,8 @@ def block_apply(
                 h, layer, cfg, positions, attn_impl, mesh, segment_ids,
                 fp8_layer=fp8_layer,
             )
+        if cfg.branch_norm:
+            attn = rmsnorm(attn, layer["ln1_out"], eps=cfg.rms_eps)
         x = x + attn
     if "moe" in layer:
         with jax.named_scope("moe_router"):
@@ -572,6 +622,8 @@ def block_apply(
             fp8_moe=None if fp8_layer is None else fp8_layer["moe"],
         )
         with jax.named_scope("moe_combine"):
+            if cfg.branch_norm:
+                delta = rmsnorm(delta, layer["ln2_out"], eps=cfg.rms_eps)
             x = x + delta
         if fp8_layer is not None:
             new_fp8_attn["moe"] = stats.pop("fp8")
@@ -583,6 +635,8 @@ def block_apply(
             h, layer["mlp"], cfg.dtype,
             fp8_mlp=None if fp8_layer is None else fp8_layer["mlp"],
         )
+        if cfg.branch_norm:
+            out_m = rmsnorm(out_m, layer["ln2_out"], eps=cfg.rms_eps)
         x = x + out_m
     if fp8_layer is not None:
         new_fp8_attn["mlp"] = new_fp8_mlp
@@ -657,9 +711,21 @@ def forward_hidden(
     restricted to same-segment pairs (flash-kernel mask) and rope
     positions reset at each segment boundary.  ``fp8_states`` (from
     :func:`init_fp8_states`) routes the block linears through fp8 and
-    adds the updated states to the aux dict as ``aux["fp8_states"]``."""
+    adds the updated states to the aux dict as ``aux["fp8_states"]``.
+
+    A looped model (``cfg.loop_passes`` = T > 1) runs the layers T times
+    on the same weights, each pass ended by the final norm, and returns
+    the T normed streams stacked, ``[T, B, S, D]`` (pass t's is pass
+    t+1's input), with ``aux["exit_logits"]`` (float32 ``[T, B, S]``, the
+    exit gate's logit on each).  Each block APPLICATION is rematerialised
+    and named ``block_out`` on its own."""
     B, S = tokens.shape
     dt = cfg.dtype
+    if cfg.loop_passes > 1 and fp8_states is not None:
+        raise ValueError(
+            f"forward_hidden: loop_passes={cfg.loop_passes} with "
+            "fp8_states: a delayed-scaling state belongs to one "
+            "application of a linear, a looped layer has several")
     with jax.named_scope("embed"):
         x = params["embed"].astype(dt)[tokens]
     if segment_ids is not None:
@@ -676,28 +742,42 @@ def forward_hidden(
     if cfg.remat_block:
         apply = jax.checkpoint(apply, static_argnums=(2,))
     new_fp8 = [] if fp8_states is not None else None
-    for i, layer in enumerate(params["layers"]):
-        if fp8_states is None:
-            x, stats = apply(layer, x, cfg, positions)
-        else:
-            x, stats, nf = apply(
-                layer, x, cfg, positions, fp8_layer=fp8_states[i]
-            )
-            new_fp8.append(nf)
-        # Identity unless a remat policy references the name: lets
-        # Strategy(remat="offload") park the inter-block residual
-        # stream in host DRAM (reference
-        # selective_offloading_checkpoint.py:252) while everything
-        # inside the block rematerializes.
-        x = checkpoint_name(x, "block_out")
-        if stats:
-            moe_aux = moe_aux + stats["moe_aux"]
-            moe_z = moe_z + stats["moe_z"]
-            experts[i] = stats["experts"]
-            per_expert.append(stats["tokens_per_expert"])
-    with jax.named_scope("final_norm"):
-        x = rmsnorm(x, params["ln_f"], eps=cfg.rms_eps)
+    streams, exit_logits = [], []
+    for _ in range(cfg.loop_passes):
+        for i, layer in enumerate(params["layers"]):
+            if fp8_states is None:
+                x, stats = apply(layer, x, cfg, positions)
+            else:
+                x, stats, nf = apply(
+                    layer, x, cfg, positions, fp8_layer=fp8_states[i]
+                )
+                new_fp8.append(nf)
+            # Identity unless a remat policy references the name: lets
+            # Strategy(remat="offload") park the inter-block residual
+            # stream in host DRAM (reference
+            # selective_offloading_checkpoint.py:252) while everything
+            # inside the block rematerializes.
+            x = checkpoint_name(x, "block_out")
+            if stats:
+                moe_aux = moe_aux + stats["moe_aux"]
+                moe_z = moe_z + stats["moe_z"]
+                experts[i] = stats["experts"]
+                per_expert.append(stats["tokens_per_expert"])
+        with jax.named_scope("final_norm"):
+            x = rmsnorm(x, params["ln_f"], eps=cfg.rms_eps)
+        if cfg.loop_passes > 1:
+            streams.append(x)
+            with jax.named_scope("exit_gate"):
+                gate = params["exit_gate"]
+                # a [.., D] x [D] product in float32, exactly: 2 D
+                # operations a token, and the loss's weights hang on it
+                exit_logits.append(jnp.einsum(
+                    "bsd,d->bs", x.astype(jnp.float32), gate["w"],
+                    precision="highest") + gate["b"])
     out_aux = {"moe_aux": moe_aux}
+    if streams:
+        x = jnp.stack(streams)
+        out_aux["exit_logits"] = jnp.stack(exit_logits)
     if per_expert:
         out_aux.update(moe_z=moe_z, moe_experts=experts,
                        moe_tokens_per_expert=jnp.stack(per_expert))
@@ -716,7 +796,8 @@ def forward(
     segment_ids=None,
     fp8_states=None,
 ) -> tuple:
-    """tokens [B, S] -> (logits [B, S, vocab] fp32, aux dict)."""
+    """tokens [B, S] -> (logits [B, S, vocab] fp32, aux dict); of a
+    looped model every pass's logits, ``[T, B, S, vocab]``."""
     x, aux = forward_hidden(
         params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
         segment_ids=segment_ids, fp8_states=fp8_states,
@@ -763,6 +844,15 @@ def loss_fn(
     ``[routed layers, E]``, ``moe_aux``, ``moe_z``): ``accelerate()``'s
     step hands them out beside ``loss`` and ``grad_norm``.  A dense model
     returns the scalar alone either way.
+
+    A looped model's loss is the expectation over the pass a token exits
+    at: ``mean_r [sum_t p_t[r] * ce_t[r] - beta * H(p[r])]`` with ``p``
+    from :func:`exit_distribution` of the gate's logits (float32),
+    ``ce_t`` pass t's cross-entropy and ``beta = cfg.exit_gate_beta``.
+    The T x N rows go through the head ONCE, with row weights
+    ``p_t[r] / N`` that the gate's gradient flows through.  With
+    ``metrics`` it returns ``(loss, {"loop_ce": [T], "loop_exit_prob":
+    [T], "loop_exit_entropy": scalar})``, means over the real tokens.
     ``fused_lm_head`` (default: auto — on for large
     vocabs) routes the projection through the chunked fused lm-head
     cross-entropy so the [B, S, vocab] logits never hit HBM.  A
@@ -802,6 +892,15 @@ def loss_fn(
             )
     if fused_lm_head is None:
         fused_lm_head = uses_fused_lm_head(cfg)
+    if cfg.loop_passes > 1:
+        x, aux = forward_hidden(
+            params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
+            segment_ids=seg, fp8_states=fp8_states,  # refused there
+        )
+        loss, counters = exit_expectation_loss(
+            x, aux["exit_logits"], params["lm_head"], targets, cfg,
+            valid=valid, fused_lm_head=fused_lm_head)
+        return (loss, counters) if metrics else loss
     if fused_lm_head:
         x, aux = forward_hidden(
             params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
@@ -840,19 +939,90 @@ def loss_fn(
     return loss
 
 
+def exit_distribution(exit_logits: jax.Array) -> jax.Array:
+    """Gate logits ``[T, ...]`` -> the distribution ``p [T, ...]`` over the
+    pass a token exits at, float32: with ``lam_t = sigmoid(logit_t)``,
+    ``p_t = lam_t * prod_{j<t} (1 - lam_j)`` for ``t < T`` and ``p_T`` the
+    remainder ``prod_{j<T} (1 - lam_j)`` (the last pass's own logit is not
+    used), so that ``p`` sums to one whatever the logits."""
+    lam = jax.nn.sigmoid(exit_logits[:-1].astype(jnp.float32))
+    stay = jnp.cumprod(1.0 - lam, axis=0)  # still running after pass t
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate([lam * before, stay[-1:]])
+
+
+def exit_expectation_loss(x, exit_logits, lm_head, targets,
+                          cfg: LlamaConfig, *, valid=None,
+                          fused_lm_head: bool = True) -> tuple:
+    """A looped model's loss from what :func:`forward_hidden` returned
+    (``x [T, B, S, D]``, ``exit_logits [T, B, S]``; :func:`loss_fn` has
+    the formula) -> ``(loss, counters)``.  ``valid`` [B, S] weights the
+    real tokens.  Scopes stay siblings: ``exit_gate`` holds the
+    distribution, the row weights and the entropy term, ``lm_head_loss``
+    the head."""
+    with jax.named_scope("exit_gate"):
+        p = exit_distribution(exit_logits)
+        # each real token's share of the mean
+        share = (jnp.full(targets.shape, 1.0 / targets.size, jnp.float32)
+                 if valid is None
+                 else valid / jnp.maximum(jnp.sum(valid), 1.0))
+        # p log p -> 0 as p -> 0, with a finite gradient
+        entropy = -jnp.sum(p * jnp.log(jnp.maximum(p, 1e-30)), axis=0)
+        mean_entropy = jnp.sum(entropy * share)
+    labels = jnp.broadcast_to(targets, p.shape)
+    with jax.named_scope("lm_head_loss"):
+        if fused_lm_head:
+            ce, rows = linear_softmax_cross_entropy_sum(
+                x, lm_head.astype(cfg.dtype), labels, p * share,
+                with_row_losses=True)
+            per_tok = rows.reshape(labels.shape)
+        else:
+            logits = (x @ lm_head.astype(cfg.dtype)).astype(jnp.float32)
+            per_tok = softmax_cross_entropy(logits, labels)
+            ce = jnp.sum(per_tok * p * share)
+    loss = ce - cfg.exit_gate_beta * mean_entropy
+    per_tok = jax.lax.stop_gradient(per_tok)
+    return loss, {
+        "loop_ce": jnp.sum(per_tok * share, axis=(1, 2)),
+        "loop_exit_prob": jnp.sum(p * share, axis=(1, 2)),
+        "loop_exit_entropy": mean_entropy,
+    }
+
+
+def refuse_looped(cfg: LlamaConfig, where: str) -> None:
+    """``ValueError`` naming the setting, for code that applies each layer
+    once and no branch-output norm: run on a looped or sandwich-norm
+    config it would compute another model without a word."""
+    for name, default in (("loop_passes", 1), ("branch_norm", False),
+                          ("exit_gate_beta", None)):
+        value = getattr(cfg, name)
+        if value != default:
+            raise ValueError(
+                f"{where} does not compute {name}={value!r}: it applies "
+                "every layer once, with no branch-output norm and no exit "
+                "gate (only llama.forward_hidden / loss_fn do)")
+
+
 def num_params(params: Dict) -> int:
     return sum(int(np.prod(x.shape))
                for x in jax.tree_util.tree_leaves(params))
 
 
 def flops_per_token(cfg: LlamaConfig) -> float:
-    """~6 * non-embedding params + attention FLOPs (for MFU accounting)."""
+    """~6 * non-embedding params + attention FLOPs (for MFU accounting).
+    A looped model runs every layer and the head ``loop_passes`` times a
+    token, and its exit gate (``2 * d_model`` a pass) with them."""
     p_layer = (
         cfg.d_model * cfg.n_head * cfg.head_dim  # wq
         + 2 * cfg.d_model * cfg.n_kv_head * cfg.head_dim  # wk, wv
         + cfg.n_head * cfg.head_dim * cfg.d_model  # wo
         + 3 * cfg.d_model * cfg.d_ff  # swiglu
     )
-    dense = cfg.n_layer * p_layer + 2 * cfg.vocab_size * cfg.d_model
-    attn = 2 * cfg.n_layer * cfg.max_seq_len * cfg.n_head * cfg.head_dim
+    head = cfg.vocab_size * cfg.d_model
+    if cfg.exit_gate_beta is not None:
+        head += cfg.d_model
+    dense = (cfg.block_applications * p_layer + cfg.loop_passes * head
+             + cfg.vocab_size * cfg.d_model)
+    attn = (2 * cfg.block_applications * cfg.max_seq_len
+            * cfg.n_head * cfg.head_dim)
     return 6.0 * dense + 6.0 * attn

@@ -87,6 +87,8 @@ def merge_stage_grads(
 
 
 def _stage_fn(cfg: LlamaConfig):
+    llama.refuse_looped(cfg, "the pipeline split (models.llama_pp)")
+
     def fn(stage_blocks, x):
         B = x.shape[0]
         pos = jnp.broadcast_to(jnp.arange(x.shape[1]), (B, x.shape[1]))

@@ -240,7 +240,8 @@ def linear_softmax_cross_entropy(
 # are known in the forward pass: the forward rule forms dx and dw in the
 # one scan that computes the loss, the one time the chunk logits exist,
 # and the backward rule only scales them.  Three [chunk, D] x [D, V]-sized
-# matmuls a chunk where the per-token op above needs four.
+# matmuls a chunk where the per-token op above needs four.  The same scan
+# has every row's loss in hand, and that is the weights' own gradient.
 # ---------------------------------------------------------------------------
 
 
@@ -248,28 +249,35 @@ def _chunk_weights(weights, pad, shape):
     return (jnp.pad(weights, (0, pad)) if pad else weights).reshape(shape)
 
 
-def _carry_init(shape, *operands):
-    """fp32 zeros that vary over the manual mesh axes the operands vary
-    over: inside a ``shard_map`` a scan's carry must enter with the type
-    it leaves with."""
-    zeros = jnp.zeros(shape, jnp.float32)
+def _vary_like(value, *operands):
+    """``value``, varying over the manual mesh axes the operands vary over
+    (inside a ``shard_map``; elsewhere unchanged)."""
     vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
-    return jax.lax.pcast(zeros, tuple(vma), to="varying") if vma else zeros
+    missing = tuple(vma - jax.typeof(value).vma)
+    return jax.lax.pcast(value, missing, to="varying") if missing else value
+
+
+def _carry_init(shape, *operands):
+    """fp32 zeros typed like the operands: inside a ``shard_map`` a scan's
+    carry must enter with the type it leaves with."""
+    return _vary_like(jnp.zeros(shape, jnp.float32), *operands)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _linear_xent_sum(x2, w, labels, weights, chunk_rows):
+    """-> (the weighted sum, the fp32 row losses ``[rows]``)."""
     xs, ls, pad = _chunk(x2, labels, chunk_rows)
 
     def body(total, xlg):
         x_c, l_c, g_c = xlg
-        return total + jnp.sum(_chunk_loss(x_c, w, l_c) * g_c), None
+        loss_c = _chunk_loss(x_c, w, l_c)
+        return total + jnp.sum(loss_c * g_c), loss_c
 
-    total, _ = jax.lax.scan(
+    total, loss = jax.lax.scan(
         body, _carry_init((), x2, w, weights),
         (xs, ls, _chunk_weights(weights, pad, ls.shape)),
     )
-    return total
+    return total, loss.reshape(-1)[: x2.shape[0]]
 
 
 def _linear_xent_sum_fwd(x2, w, labels, weights, chunk_rows):
@@ -291,7 +299,8 @@ def _linear_xent_sum_fwd(x2, w, labels, weights, chunk_rows):
             == l_c[:, None]
         )
         target = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
-        total = total + jnp.sum((m + jnp.log(s) - target) * g_c)
+        loss_c = m + jnp.log(s) - target
+        total = total + jnp.sum(loss_c * g_c)
         # From here down _linear_xent_bwd's body, dtype for dtype.
         dlogits = (e / s[:, None] - onehot.astype(jnp.float32)) * g_c[:, None]
         dx_c = jnp.dot(
@@ -301,20 +310,25 @@ def _linear_xent_sum_fwd(x2, w, labels, weights, chunk_rows):
             x_c.T.astype(jnp.float32), dlogits,
             preferred_element_type=jnp.float32,
         )
-        return (total, dw), dx_c.astype(x2.dtype)
+        return (total, dw), (dx_c.astype(x2.dtype), loss_c)
 
     operands = (x2, w, weights)
-    (total, dw), dx = jax.lax.scan(
+    (total, dw), (dx, loss) = jax.lax.scan(
         body,
         (_carry_init((), *operands), _carry_init(w.shape, *operands)),
         (xs, ls, _chunk_weights(weights, pad, ls.shape)),
     )
-    return total, (dx.reshape(-1, x2.shape[1])[:R], dw.astype(w.dtype))
+    loss = loss.reshape(-1)[:R]
+    return (total, loss), (
+        dx.reshape(-1, x2.shape[1])[:R], dw.astype(w.dtype), loss)
 
 
 def _linear_xent_sum_bwd(chunk_rows, res, g):
-    dx, dw = res
-    return g.astype(dx.dtype) * dx, g.astype(dw.dtype) * dw, None, None
+    # the row losses are handed out for reading (stop_gradient in the
+    # caller below): their cotangent is not propagated
+    g, _ = g
+    dx, dw, loss = res
+    return g.astype(dx.dtype) * dx, g.astype(dw.dtype) * dw, None, g * loss
 
 
 _linear_xent_sum.defvjp(_linear_xent_sum_fwd, _linear_xent_sum_bwd)
@@ -327,22 +341,31 @@ def linear_softmax_cross_entropy_sum(
     weights: Optional[jax.Array] = None,
     *,
     chunk_rows: int = _DEFAULT_CHUNK_ROWS,
-) -> jax.Array:
+    with_row_losses: bool = False,
+):
     """Fused ``sum(weights * softmax_cross_entropy(x @ w, labels))``.
 
     x: [..., D], w: [D, V], labels: [...] int, weights: [...] float row
     weights or None for the mean (every row 1/N) — returns the fp32
     scalar, logits never materialized beyond one [chunk_rows, V] block.
+    ``with_row_losses`` returns ``(scalar, row losses fp32 [rows])``: what
+    the scan computed on the way, for a caller's metrics, carrying no
+    gradient.
 
-    Contract: the weights are known in the forward pass and are constants
-    of the loss (no gradient flows to them).  That is what lets the
-    forward rule under differentiation compute ``dx`` and ``dw`` in the
-    same scan as the loss — its residuals are exactly those two, in
-    ``x``'s and ``w``'s dtype, and the backward rule multiplies them by
-    the scalar cotangent.  Without a gradient the scan is forward only
-    (one matmul a chunk, no ``dw`` accumulator).  Same dtypes at the
-    same places as the per-token op's backward, so the gradients are
-    those of ``sum(weights * linear_softmax_cross_entropy(...))``.
+    Contract: the weights are known in the forward pass (they do not
+    depend on this op's result).  That is what lets the forward rule
+    under differentiation compute ``dx`` and ``dw`` in the same scan as
+    the loss — its residuals are those two, in ``x``'s and ``w``'s dtype,
+    and the fp32 row losses the scan computes anyway; the backward rule
+    multiplies each by the scalar cotangent ``g``.  The weights may
+    depend on parameters (a looped model's exit probabilities): their
+    cotangent is ``g * loss[r]``, the row's own loss, with no further
+    matmul; for weights that no parameter reaches (``None``, a validity
+    mask) it is computed and dropped.  Without a gradient the scan is
+    forward only (one matmul a chunk, no ``dw`` accumulator).  Same
+    dtypes at the same places as the per-token op's backward, so the
+    gradients are those of
+    ``sum(weights * linear_softmax_cross_entropy(...))``.
 
     The per-token op stays for callers that need per-token losses: a
     [...] result can meet any cotangent, so its backward has to
@@ -351,7 +374,12 @@ def linear_softmax_cross_entropy_sum(
     rows = labels.size
     if weights is None:
         weights = jnp.full((rows,), 1.0 / rows, jnp.float32)
-    return _linear_xent_sum(
-        x.reshape(-1, x.shape[-1]), w, labels.reshape(-1),
-        weights.reshape(-1).astype(jnp.float32), chunk_rows,
+    # the weights' cotangent is a row loss, typed like x and w
+    weights = _vary_like(weights.reshape(-1).astype(jnp.float32), x, w)
+    total, row_losses = _linear_xent_sum(
+        x.reshape(-1, x.shape[-1]), w, labels.reshape(-1), weights,
+        chunk_rows,
     )
+    if with_row_losses:
+        return total, jax.lax.stop_gradient(row_losses)
+    return total

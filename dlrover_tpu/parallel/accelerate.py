@@ -231,6 +231,9 @@ _COLLECTIVES = (
 #: the scope ``train_step`` puts around ``tx.update`` + ``apply_updates``;
 #: :func:`scope_table` makes it the phase of the same name
 OPTIMIZER_SCOPE = "optimizer"
+#: the attention kernel (``ops.flash_attention``): a transformer block
+#: calls it once per forward application
+BLOCK_KERNEL = "flash_fwd"
 #: path components of an ``op_name`` that JAX's transforms and control
 #: flow add and that are no scope of the program
 _NOT_A_SCOPE = frozenset((
@@ -355,26 +358,36 @@ def scope_table(hlo_text: str) -> dict:
 
 
 def program_summary(hlo_text: str) -> dict:
-    """``{"kernels": {name: n}, "collectives": {kind: n}, "scopes":
-    {instruction: [phase, scope]}}`` of a compiled
+    """``{"kernels": {name: n}, "block_applications": n, "collectives":
+    {kind: n}, "scopes": {instruction: [phase, scope]}}`` of a compiled
     program's text: every Mosaic kernel is a ``tpu_custom_call`` whose
     ``op_name`` ends in ``<pallas_call name>/pallas_call`` (wrapped as
-    ``jvp(<name>)`` under differentiation); collectives
-    are counted by opcode (async ``-start`` forms included once);
-    ``scopes`` is :func:`scope_table`."""
+    ``jvp(<name>)`` under differentiation); ``block_applications`` is how
+    often a token meets a transformer block on the way forward — the
+    :data:`BLOCK_KERNEL` calls that are neither a backward pass's nor a
+    remat's recomputation, so layers x passes of a looped model, whose
+    ``kernels`` alone cannot tell 8 layers run four times from 32 (0
+    where the step runs no such kernel: the CPU, ring attention);
+    collectives are counted by opcode (async ``-start`` forms included
+    once); ``scopes`` is :func:`scope_table`."""
     kernels: dict = {}
+    applications = 0
     for line in hlo_text.splitlines():
         if 'custom_call_target="tpu_custom_call"' not in line:
             continue
-        m = re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line)
-        name = m.group(1) if m else "unnamed"
+        m = re.search(r'op_name="([^"]*?(\w+)\)*/pallas_call)', line)
+        name = m.group(2) if m else "unnamed"
         kernels[name] = kernels.get(name, 0) + 1
+        if name == BLOCK_KERNEL:
+            verdict = phase_and_scope(m.group(1))
+            applications += verdict is None or verdict[0] not in (
+                "backward", "recompute")
     collectives = {
         kind: len(re.findall(rf"\s{kind}(?:-start)?\(", hlo_text))
         for kind in _COLLECTIVES
     }
-    return {"kernels": kernels, "collectives": collectives,
-            "scopes": scope_table(hlo_text)}
+    return {"kernels": kernels, "block_applications": applications,
+            "collectives": collectives, "scopes": scope_table(hlo_text)}
 
 
 def _build_train_step(
@@ -655,8 +668,8 @@ class _CacheWatch:
 def _build_span(fn: Callable) -> Callable:
     """``accelerate.build`` around the whole of :func:`accelerate`, and
     the compiled step's summary journalled once (``accelerate.program``:
-    kernels, collectives and the scope table that names a device
-    trace's instructions)."""
+    kernels, block applications, collectives and the scope table that
+    names a device trace's instructions)."""
 
     @functools.wraps(fn)
     def build(*args, **kwargs) -> "AcceleratedJob":

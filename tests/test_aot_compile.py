@@ -388,3 +388,35 @@ def test_small_300m_loss_grad_on_fsdp2_tp2(topo, monkeypatch):
         if m:
             gathered |= set(re.findall(r"\[[\d,]+\]", m.group(1)))
     assert gathered and not (gathered & full), gathered & full
+
+
+def test_looped_step_tells_block_applications_from_layers(topo, monkeypatch):
+    """One layer run twice with block remat: the kernel counts alone (four
+    ``flash_fwd``: two forward, two recomputed) cannot say how often a
+    token meets a block; ``block_applications`` does, and every scope of
+    the looped loss is in the compiled step's table."""
+    import optax
+
+    from dlrover_tpu.models import llama
+    from dlrover_tpu.parallel.mesh import MeshSpec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.small_300m(), n_layer=1, loop_passes=2,
+        branch_norm=True, exit_gate_beta=0.1, remat_block=True)
+    job = acc.aot_analyze(
+        loss_fn=lambda p, b: llama.loss_fn(p, b, cfg, metrics=True),
+        init_fn=lambda r: llama.init_params(r, cfg),
+        optimizer=optax.adamw(3e-4),
+        sample_batch={"tokens": np.zeros((4, 1025), np.int32)},
+        strategy=acc.Strategy(mesh=MeshSpec()), param_specs="planner",
+        devices=topo.devices[:1],
+    )
+    kernels = job.program["kernels"]
+    assert (kernels["flash_fwd"], kernels["flash_bwd_dq"],
+            kernels["flash_bwd_dkv"]) == (4, 2, 2)
+    assert job.program["block_applications"] == cfg.block_applications == 2
+    found = {tuple(v) for v in job.program["scopes"].values()}
+    assert {("forward", "exit_gate"), ("backward", "exit_gate"),
+            ("recompute", "attention"), ("recompute", "mlp"),
+            ("forward", "lm_head_loss"), ("forward", "final_norm")} <= found

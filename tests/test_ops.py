@@ -431,11 +431,16 @@ class TestLinearXentSum:
             np.testing.assert_allclose(g, a, **tol)
             np.testing.assert_allclose(g, b, **tol)
 
-    def test_no_gradient_reaches_the_weights(self):
+    def test_the_weights_gradient_is_each_rows_own_loss(self):
+        """Until PR 33 the weights were constants of the loss; a looped
+        model's are its exit probabilities, and their cotangent is the
+        row loss the forward scan has in hand."""
         x, w, labels, weights = self._data("fractional", jnp.float32)
-        reduced, _, _ = self._losses(labels, weights)
+        reduced, per_token, _ = self._losses(labels, weights)
         g = jax.grad(lambda wt: reduced(x, w, weights=wt))(weights)
-        np.testing.assert_array_equal(np.asarray(g), 0.0)
+        want = jax.grad(lambda wt: per_token(x, w, weights=wt))(weights)
+        np.testing.assert_allclose(g, want, rtol=1e-6)
+        assert float(jnp.min(g)) > 0.0  # a cross-entropy each
 
     def test_the_smoke_check_compares_both_ops(self, capsys):
         """``python -m dlrover_tpu.ops.smoke`` at a toy shape: several
