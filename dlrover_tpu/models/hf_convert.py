@@ -23,7 +23,11 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from dlrover_tpu.models.llama import LlamaConfig, refuse_looped
+from dlrover_tpu.models.llama import (
+    LlamaConfig,
+    refuse_latent,
+    refuse_looped,
+)
 
 
 def config_from_hf(hf_config: Any) -> LlamaConfig:
@@ -173,6 +177,7 @@ def _build_params(
     in-memory and streaming importers (key names, transposes,
     tied-embedding fallback, bias rejection live HERE only)."""
     refuse_looped(cfg, "the HF Llama layout table (models.hf_convert)")
+    refuse_latent(cfg, "the HF Llama layout table (models.hf_convert)")
     bias_keys = [k for k in all_keys() if k.endswith(".bias")]
     if bias_keys:
         raise ValueError(

@@ -14,6 +14,7 @@ not model changes.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, Optional
@@ -85,6 +86,60 @@ class LlamaConfig:
     # passes' cross-entropies under it minus this weight times its
     # entropy (:func:`exit_distribution`, :func:`loss_fn`).  None: no gate.
     exit_gate_beta: Optional[float] = None
+    # Latent attention (DeepSeek-V2/V3, GLM-4.7-Flash; ``kv_lora_rank`` > 0
+    # turns it on): queries through a ``q_lora_rank``-wide normed latent,
+    # keys and values out of ONE ``kv_lora_rank``-
+    # wide normed latent a token, each head ``qk_nope_head_dim`` dims
+    # without position + ``qk_rope_head_dim`` rotary dims whose key part is
+    # one vector a token under every head.  The head size is their sum,
+    # whatever ``d_model / n_head`` is; training expands keys and values per
+    # head, so the flash kernels see plain MHA and ``v_head_dim`` must equal
+    # that sum (:func:`_mla_qkv`).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The routed layout past the stride: the first ``first_k_dense`` layers
+    # stay dense (width ``d_ff``) whatever ``moe_every`` says; an expert is
+    # ``d_ff_expert`` wide (0: ``d_ff``); ``n_shared_experts`` > 0 adds one
+    # SwiGLU of ``n_shared_experts * d_ff_expert`` that every token runs
+    # (``moe["shared"]``).
+    first_k_dense: int = 0
+    d_ff_expert: int = 0
+    n_shared_experts: int = 0
+    # The router's scores: "softmax" over the experts, or "sigmoid" of each
+    # logit (DeepSeek-V3 ``noaux_tc``: the top-k weights are the chosen
+    # scores over their sum + 1e-20 where ``norm_topk_prob``).  Either way
+    # times ``routed_scaling``.
+    router_score: str = "softmax"
+    routed_scaling: float = 1.0
+    # A selection bias per expert (``moe["router_bias"]``, float32 [E]): it
+    # is added to the scores for the top-k CHOICE and never to a weight,
+    # takes no gradient, and is moved by a rule after every step,
+    # ``b_e += rate * sign(mean(c) - c_e)`` with ``c_e`` the pairs the step
+    # routed to expert e (:func:`loss_fn` hands the new values out under
+    # :data:`RULE_UPDATES`; :func:`rule_leaves` names the leaves).  None:
+    # no bias.
+    router_bias_rate: Optional[float] = None
+    # The load-balance term per SEQUENCE (DeepSeek-V3 eq. 17-20) in place of
+    # the batch-wide one: ``mean_b sum_e f_be * P_be``, ``f_be = E / (K S)
+    # * #{t: e in T_t}``, ``P_be = mean_t s_te / sum_e' s_te'``.
+    balance_per_sequence: bool = False
+    # The chip's SHARE of the experts (expert parallelism cut to one chip):
+    # the router knows ``num_experts``, this layer holds the
+    # ``experts_held`` of them that start at ``experts_held_first`` (0: all)
+    # and computes the pairs routed to those; what the absent experts would
+    # add is left out of the layer's result.  No pair routed to a held
+    # expert is dropped, whatever the imbalance.
+    experts_held: int = 0
+    experts_held_first: int = 0
+    # Multi-token prediction (DeepSeek-V3 section 2.2; 0 or 1): one further
+    # block with weights of its own (``params["mtp"]``) over
+    # ``w_eh [norm(embed(t_{i+1})); norm(z_i)]``, ``z`` the last layer's
+    # output, predicts ``t_{i+2}`` through the shared head
+    # (:func:`forward_hidden`, :func:`loss_fn`).
+    mtp_layers: int = 0
 
     def __post_init__(self):
         if (self.loop_passes > 1) != (self.exit_gate_beta is not None):
@@ -98,20 +153,68 @@ class LlamaConfig:
                 f"LlamaConfig: loop_passes={self.loop_passes} with "
                 f"num_experts={self.num_experts}: the routed block's "
                 "counters are kept per layer, not per (pass, layer)")
+        if self.kv_lora_rank > 0:
+            if min(self.q_lora_rank, self.qk_nope_head_dim,
+                   self.qk_rope_head_dim) <= 0 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    f"LlamaConfig: kv_lora_rank={self.kv_lora_rank} needs "
+                    "q_lora_rank > 0, qk_nope_head_dim > 0 and an even "
+                    f"qk_rope_head_dim > 0, not {self.q_lora_rank}, "
+                    f"{self.qk_nope_head_dim} and {self.qk_rope_head_dim}")
+            if self.v_head_dim != self.head_dim:
+                raise ValueError(
+                    f"LlamaConfig: v_head_dim={self.v_head_dim} with "
+                    f"qk_nope_head_dim + qk_rope_head_dim={self.head_dim}: "
+                    "the flash kernels take one head size for q, k and v")
+            if self.n_kv_head != self.n_head or self.qk_norm:
+                raise ValueError(
+                    f"LlamaConfig: kv_lora_rank={self.kv_lora_rank} with "
+                    f"n_kv_head={self.n_kv_head} (of {self.n_head}) or "
+                    f"qk_norm={self.qk_norm}: latent attention expands one "
+                    "key and one value per query head and norms its latents")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"LlamaConfig: router_score={self.router_score!r} is "
+                "neither 'softmax' nor 'sigmoid'")
+        if not (0 <= self.experts_held_first
+                and self.experts_held_first + self.experts_held
+                <= max(self.num_experts, 0)) or self.experts_held < 0:
+            raise ValueError(
+                f"LlamaConfig: experts_held={self.experts_held} from "
+                f"experts_held_first={self.experts_held_first} is no slice "
+                f"of num_experts={self.num_experts}")
+        if self.mtp_layers not in (0, 1) or (
+                self.mtp_layers and self.loop_passes > 1):
+            raise ValueError(
+                f"LlamaConfig: mtp_layers={self.mtp_layers} with "
+                f"loop_passes={self.loop_passes}: one prediction block "
+                "after a stack that runs once is what is built")
 
     @property
     def head_dim(self) -> int:
+        if self.kv_lora_rank > 0:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.d_model // self.n_head
 
     @property
     def block_applications(self) -> int:
-        """Blocks a token passes through: layers x passes."""
-        return self.n_layer * self.loop_passes
+        """Blocks a token passes through: layers x passes, and the
+        multi-token-prediction block."""
+        return self.n_layer * self.loop_passes + self.mtp_layers
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def experts_here(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.experts_held or self.num_experts
 
     def is_moe_layer(self, i: int) -> bool:
         """Single source of truth for MoE placement (init_params,
         param_logical_axes and init_fp8_states must agree)."""
-        return self.num_experts > 0 and (
+        return self.num_experts > 0 and i >= self.first_k_dense and (
             i % self.moe_every == self.moe_every - 1
         )
 
@@ -149,13 +252,75 @@ def _dense(key, fan_in, fan_out, std=0.02):
     return jax.random.normal(key, (fan_in, fan_out), jnp.float32) * std
 
 
+def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool) -> Dict:
+    """One block's parameters.  The leaves every earlier configuration has
+    draw from the same eight keys as ever; what latent attention and the
+    shared expert add draws from keys folded out of the layer's."""
+    k = jax.random.split(key, 8)
+    more = jax.random.split(jax.random.fold_in(key, 1), 5)
+    hd = cfg.head_dim
+    layer = {"ln1": jnp.ones((cfg.d_model,), jnp.float32)}
+    if cfg.kv_lora_rank > 0:
+        layer["wq_a"] = _dense(k[0], cfg.d_model, cfg.q_lora_rank)
+        layer["q_a_norm"] = jnp.ones((cfg.q_lora_rank,), jnp.float32)
+        layer["wq_b"] = _dense(more[0], cfg.q_lora_rank, cfg.n_head * hd)
+        layer["wkv_a"] = _dense(
+            k[1], cfg.d_model, cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+        layer["kv_a_norm"] = jnp.ones((cfg.kv_lora_rank,), jnp.float32)
+        layer["wkv_b"] = _dense(
+            more[1], cfg.kv_lora_rank,
+            cfg.n_head * (cfg.qk_nope_head_dim + cfg.v_head_dim))
+    else:
+        layer["wq"] = _dense(k[0], cfg.d_model, cfg.n_head * hd)
+        layer["wk"] = _dense(k[1], cfg.d_model, cfg.n_kv_head * hd)
+        layer["wv"] = _dense(k[2], cfg.d_model, cfg.n_kv_head * hd)
+    layer["wo"] = _dense(k[3], cfg.n_head * hd, cfg.d_model)
+    layer["ln2"] = jnp.ones((cfg.d_model,), jnp.float32)
+    if cfg.qk_norm:
+        layer["q_norm"] = jnp.ones((cfg.n_head * hd,), jnp.float32)
+        layer["k_norm"] = jnp.ones((cfg.n_kv_head * hd,), jnp.float32)
+    if cfg.branch_norm:
+        layer["ln1_out"] = jnp.ones((cfg.d_model,), jnp.float32)
+        layer["ln2_out"] = jnp.ones((cfg.d_model,), jnp.float32)
+    if routed:
+        held, width = cfg.experts_here, cfg.expert_width
+        layer["moe"] = {
+            "router": _dense(k[4], cfg.d_model, cfg.num_experts),
+            "wi": jax.random.normal(
+                k[5], (held, cfg.d_model, width), jnp.float32) * 0.02,
+            "wg": jax.random.normal(
+                k[6], (held, cfg.d_model, width), jnp.float32) * 0.02,
+            "wo": jax.random.normal(
+                k[7], (held, width, cfg.d_model), jnp.float32) * 0.02,
+        }
+        if cfg.router_bias_rate is not None:
+            layer["moe"]["router_bias"] = jnp.zeros(
+                (cfg.num_experts,), jnp.float32)
+        if cfg.n_shared_experts > 0:
+            shared = cfg.n_shared_experts * width
+            layer["moe"]["shared"] = {
+                "w_gate": _dense(more[2], cfg.d_model, shared),
+                "w_up": _dense(more[3], cfg.d_model, shared),
+                "w_down": _dense(more[4], shared, cfg.d_model),
+            }
+    else:
+        layer["mlp"] = {
+            "w_gate": _dense(k[4], cfg.d_model, cfg.d_ff),
+            "w_up": _dense(k[5], cfg.d_model, cfg.d_ff),
+            "w_down": _dense(k[6], cfg.d_ff, cfg.d_model),
+        }
+    return layer
+
+
 def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
     keys = jax.random.split(rng, cfg.n_layer + 3)
     params: Dict = {
         "embed": _dense(keys[0], cfg.vocab_size, cfg.d_model),
         "lm_head": _dense(keys[1], cfg.d_model, cfg.vocab_size),
         "ln_f": jnp.ones((cfg.d_model,), jnp.float32),
-        "layers": [],
+        "layers": [
+            _init_layer(keys[2 + i], cfg, cfg.is_moe_layer(i))
+            for i in range(cfg.n_layer)],
     }
     if cfg.exit_gate_beta is not None:
         params["exit_gate"] = {
@@ -163,43 +328,16 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
                 keys[cfg.n_layer + 2], (cfg.d_model,), jnp.float32) * 0.02,
             "b": jnp.zeros((), jnp.float32),
         }
-    hd = cfg.head_dim
-    for i in range(cfg.n_layer):
-        k = jax.random.split(keys[2 + i], 8)
-        layer = {
-            "ln1": jnp.ones((cfg.d_model,), jnp.float32),
-            "wq": _dense(k[0], cfg.d_model, cfg.n_head * hd),
-            "wk": _dense(k[1], cfg.d_model, cfg.n_kv_head * hd),
-            "wv": _dense(k[2], cfg.d_model, cfg.n_kv_head * hd),
-            "wo": _dense(k[3], cfg.n_head * hd, cfg.d_model),
-            "ln2": jnp.ones((cfg.d_model,), jnp.float32),
+    if cfg.mtp_layers:
+        k_eh, k_block = jax.random.split(jax.random.fold_in(rng, 1))
+        params["mtp"] = {
+            "ln_e": jnp.ones((cfg.d_model,), jnp.float32),
+            "ln_h": jnp.ones((cfg.d_model,), jnp.float32),
+            "w_eh": _dense(k_eh, 2 * cfg.d_model, cfg.d_model),
+            # of the routed kind where the model has routed layers at all
+            "block": _init_layer(k_block, cfg, cfg.num_experts > 0),
+            "ln_f": jnp.ones((cfg.d_model,), jnp.float32),
         }
-        if cfg.qk_norm:
-            layer["q_norm"] = jnp.ones((cfg.n_head * hd,), jnp.float32)
-            layer["k_norm"] = jnp.ones((cfg.n_kv_head * hd,), jnp.float32)
-        if cfg.branch_norm:
-            layer["ln1_out"] = jnp.ones((cfg.d_model,), jnp.float32)
-            layer["ln2_out"] = jnp.ones((cfg.d_model,), jnp.float32)
-        if cfg.is_moe_layer(i):
-            layer["moe"] = {
-                "router": _dense(k[4], cfg.d_model, cfg.num_experts),
-                "wi": jax.random.normal(
-                    k[5], (cfg.num_experts, cfg.d_model, cfg.d_ff),
-                    jnp.float32) * 0.02,
-                "wg": jax.random.normal(
-                    k[6], (cfg.num_experts, cfg.d_model, cfg.d_ff),
-                    jnp.float32) * 0.02,
-                "wo": jax.random.normal(
-                    k[7], (cfg.num_experts, cfg.d_ff, cfg.d_model),
-                    jnp.float32) * 0.02,
-            }
-        else:
-            layer["mlp"] = {
-                "w_gate": _dense(k[4], cfg.d_model, cfg.d_ff),
-                "w_up": _dense(k[5], cfg.d_model, cfg.d_ff),
-                "w_down": _dense(k[6], cfg.d_ff, cfg.d_model),
-            }
-        params["layers"].append(layer)
     return params
 
 
@@ -208,14 +346,14 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
     ``parallel.sharding.tree_logical_to_specs``)."""
 
     def layer_axes(has_moe: bool) -> Dict:
-        ax = {
-            "ln1": (None,),
-            "wq": ("embed", "heads"),
-            "wk": ("embed", "heads"),
-            "wv": ("embed", "heads"),
-            "wo": ("heads", "embed"),
-            "ln2": (None,),
-        }
+        ax = {"ln1": (None,), "wo": ("heads", "embed"), "ln2": (None,)}
+        if cfg.kv_lora_rank > 0:
+            ax.update(wq_a=("embed", None), q_a_norm=(None,),
+                      wq_b=(None, "heads"), wkv_a=("embed", None),
+                      kv_a_norm=(None,), wkv_b=(None, "heads"))
+        else:
+            ax.update(wq=("embed", "heads"), wk=("embed", "heads"),
+                      wv=("embed", "heads"))
         if cfg.qk_norm:
             ax["q_norm"] = (None,)
             ax["k_norm"] = (None,)
@@ -229,6 +367,14 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
                 "wg": ("expert", "embed", "expert_mlp"),
                 "wo": ("expert", "expert_mlp", "embed"),
             }
+            if cfg.router_bias_rate is not None:
+                ax["moe"]["router_bias"] = (None,)
+            if cfg.n_shared_experts > 0:
+                ax["moe"]["shared"] = {
+                    "w_gate": ("embed", "mlp"),
+                    "w_up": ("embed", "mlp"),
+                    "w_down": ("mlp", "embed"),
+                }
         else:
             ax["mlp"] = {
                 "w_gate": ("embed", "mlp"),
@@ -248,6 +394,11 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
     }
     if cfg.exit_gate_beta is not None:
         axes["exit_gate"] = {"w": (None,), "b": ()}
+    if cfg.mtp_layers:
+        axes["mtp"] = {
+            "ln_e": (None,), "ln_h": (None,), "w_eh": (None, "embed"),
+            "block": layer_axes(cfg.num_experts > 0), "ln_f": (None,),
+        }
     return axes
 
 
@@ -291,6 +442,42 @@ def _fp8_proj(x, w, st, dt):
     return out.reshape(x.shape[:-1] + (w.shape[-1],)), new
 
 
+def _mla_qkv(x, layer, cfg: LlamaConfig, positions) -> tuple:
+    """Latent attention's projections: normed ``x [B, S, C]`` -> ``(q, k,
+    v)``, each ``[B, S, H, head_dim]``, plain multi-head operands for any
+    attention backend.  Per token: ``c_q = rms(x wq_a)``, ``[q_nope_i;
+    q_rope_i] = c_q wq_b`` per head i; ``[c_kv; k_rope] = x wkv_a``,
+    ``c_kv = rms(c_kv)``, ``[k_nope_i; v_i] = c_kv wkv_b``; ``q_i =
+    [q_nope_i; rope(q_rope_i)]``, ``k_i = [k_nope_i; rope(k_rope)]`` with
+    the token's one ``k_rope`` under every head.  RoPE turns the pairs
+    ``(j, j + qk_rope_head_dim / 2)`` of the rotary dims, as :func:`_rope`
+    does everywhere (HF's ``rotate_half``).  Scopes ``mla_q`` and
+    ``mla_kv`` sit inside the block's ``attention``."""
+    B, S, _ = x.shape
+    H, dt = cfg.n_head, cfg.dtype
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    with jax.named_scope("mla_q"):
+        c_q = rmsnorm(x @ layer["wq_a"].astype(dt), layer["q_a_norm"],
+                      eps=cfg.rms_eps)
+        q = (c_q @ layer["wq_b"].astype(dt)).reshape(B, S, H, nope + rope)
+        q = jnp.concatenate(
+            [q[..., :nope], _rope(q[..., nope:], positions, cfg.rope_theta)],
+            axis=-1)
+    with jax.named_scope("mla_kv"):
+        down = x @ layer["wkv_a"].astype(dt)
+        c_kv = rmsnorm(down[..., :cfg.kv_lora_rank], layer["kv_a_norm"],
+                       eps=cfg.rms_eps)
+        k_rope = _rope(down[..., None, cfg.kv_lora_rank:], positions,
+                       cfg.rope_theta)  # [B, S, 1, rope]
+        kv = (c_kv @ layer["wkv_b"].astype(dt)).reshape(
+            B, S, H, nope + cfg.v_head_dim)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, H, rope))],
+            axis=-1)
+        v = kv[..., nope:]
+    return q, k, v
+
+
 def _attention(
     x, layer, cfg: LlamaConfig, positions, attn_impl: str, mesh,
     segment_ids=None, fp8_layer=None,
@@ -305,7 +492,14 @@ def _attention(
     H, KV, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     dt = cfg.dtype
     new_fp8 = None
-    if fp8_layer is not None:
+    latent = cfg.kv_lora_rank > 0
+    if latent and fp8_layer is not None:
+        raise ValueError(
+            f"_attention: kv_lora_rank={cfg.kv_lora_rank} with fp8 states: "
+            "the fp8 rewrite knows the wq/wk/wv/wo linears only")
+    if latent:
+        q, k, v = _mla_qkv(x, layer, cfg, positions)
+    elif fp8_layer is not None:
         new_fp8 = {}
         q, new_fp8["wq"] = _fp8_proj(x, layer["wq"], fp8_layer["wq"], dt)
         k, new_fp8["wk"] = _fp8_proj(x, layer["wk"], fp8_layer["wk"], dt)
@@ -314,10 +508,11 @@ def _attention(
         q = x @ layer["wq"].astype(dt)
         k = x @ layer["wk"].astype(dt)
         v = x @ layer["wv"].astype(dt)
-    q, k = qk_normed(q, k, layer, cfg)
-    q = _rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
-    k = _rope(k.reshape(B, S, KV, D), positions, cfg.rope_theta)
-    v = v.reshape(B, S, KV, D)
+    if not latent:
+        q, k = qk_normed(q, k, layer, cfg)
+        q = _rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
+        k = _rope(k.reshape(B, S, KV, D), positions, cfg.rope_theta)
+        v = v.reshape(B, S, KV, D)
     if KV != H and attn_impl in ("ring", "ulysses") and mesh is not None:
         # Ring/Ulysses shard over heads and need the full head count; the
         # flash path handles GQA in-kernel (no materialized repeat).
@@ -364,7 +559,9 @@ def _attention(
         out, new_fp8["wo"] = _fp8_proj(out, layer["wo"],
                                        fp8_layer["wo"], dt)
         return out, new_fp8
-    return out @ layer["wo"].astype(dt), None
+    with (jax.named_scope("mla_out") if latent
+          else contextlib.nullcontext()):
+        return out @ layer["wo"].astype(dt), None
 
 
 def _swiglu(x, mlp, dt, fp8_mlp=None):
@@ -473,7 +670,20 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
     ``fp8_moe`` (``ops.fp8.Fp8State`` for wg/wi/wo) routes the three
     grouped matmuls through ``ops.fp8.fp8_ragged_dot``; the router, the
     permutation and the combine stay in fp32/compute dtype.  The new
-    states come back as ``stats["fp8"]``."""
+    states come back as ``stats["fp8"]``.
+
+    The router's variants (``cfg.router_score``, ``moe["router_bias"]``,
+    ``cfg.routed_scaling``, ``cfg.balance_per_sequence``) and the shared
+    expert (``moe["shared"]``, scope ``moe_shared``, added for every
+    token) are described at :class:`LlamaConfig`.  With a SHARE of the
+    experts (``cfg.experts_held`` < E) the router still scores, chooses
+    and normalises over all E; the pairs sort with the held experts
+    first, the grouped matmuls' groups end with the last held expert's
+    pairs, and the rows behind them are neither computed nor read (their
+    weight is zero, and the two masks keep what the kernels leave
+    unwritten there out of every sum).  ``stats["held_pairs"]`` counts
+    the pairs computed here; ``tokens_per_expert`` stays ``[E]``, in the
+    router's numbering."""
     B, S, C = x.shape
     E, K = cfg.num_experts, cfg.top_k
     N = B * S
@@ -483,16 +693,37 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
     valid_n = None if valid is None else valid.reshape(N)
     if capacity is None and cfg.capacity_factor is not None:
         capacity = int(max(1, round(cfg.capacity_factor * N * K / E)))
+    held, first = cfg.experts_here, cfg.experts_held_first
+    share = held < E  # the pairs of absent experts are not computed here
     with jax.named_scope("moe_router"):
         logits = tokens.astype(f32) @ moe["router"]
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, gate_idx = jax.lax.top_k(probs, K)
+        if cfg.router_score == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            probs = scores / jnp.sum(scores, -1, keepdims=True)
+        else:
+            scores = probs = jax.nn.softmax(logits, axis=-1)
+        if "router_bias" in moe:
+            # the bias chooses and never weighs
+            _, gate_idx = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(moe["router_bias"]), K)
+            gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+        else:
+            gate_vals, gate_idx = jax.lax.top_k(scores, K)
         if cfg.norm_topk_prob:
-            gate_vals = gate_vals / jnp.maximum(
-                jnp.sum(gate_vals, -1, keepdims=True), 1e-9
-            )
+            if cfg.router_score == "sigmoid":
+                gate_vals = gate_vals / (
+                    jnp.sum(gate_vals, -1, keepdims=True) + 1e-20)
+            else:
+                gate_vals = gate_vals / jnp.maximum(
+                    jnp.sum(gate_vals, -1, keepdims=True), 1e-9
+                )
+        if cfg.routed_scaling != 1.0:
+            gate_vals = gate_vals * cfg.routed_scaling
     with jax.named_scope("moe_permute"):
         pair_expert = gate_idx.reshape(N * K)
+        if share:
+            # the held experts sort first, in their own order
+            pair_expert = (pair_expert - first) % E
         if valid_n is not None:
             # pads sort behind the last expert's group
             pair_expert = jnp.where(
@@ -506,20 +737,35 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
         ).astype(jnp.int32)
         starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
         counts = ends - starts  # valid pairs per expert
-        # the groups the matmuls run over cover every row: pads ride at
-        # the end of the last one
-        group_sizes = counts.at[E - 1].add(N * K - ends[E - 1])
-        if capacity is not None or valid_n is not None:
-            keep_sorted = sorted_expert < E
+        if share:
+            # the groups end with the last held expert's pairs; the rows
+            # behind them (absent experts' pairs, pads) are not computed
+            group_sizes = counts[:held]
+            live = (pairs < ends[held - 1])[:, None]
+        else:
+            # the groups the matmuls run over cover every row: pads ride
+            # at the end of the last one
+            group_sizes = counts.at[E - 1].add(N * K - ends[E - 1])
+        if capacity is not None or valid_n is not None or share:
+            keep_sorted = sorted_expert < held
             if capacity is not None:
                 rank = pairs - starts[jnp.minimum(sorted_expert, E - 1)]
                 keep_sorted = keep_sorted & (rank < capacity)
             gate_vals = jnp.where(
                 keep_sorted[inverse].reshape(N, K), gate_vals, 0.0)
         rows = _dispatch_rows(tokens.astype(dt), order, inverse)
+        if share:
+            # a grouped matmul leaves the rows past its groups unwritten,
+            # forward and backward: nothing of them may reach a sum
+            rows = jnp.where(live, rows, 0)
     with jax.named_scope("moe_experts"):
         new_fp8 = None
         if fp8_moe is not None:
+            if share:
+                raise ValueError(
+                    f"_moe_swiglu: experts_held={held} of {E} with fp8 "
+                    "states: the fp8 ragged dot takes groups that cover "
+                    "every row")
             from dlrover_tpu.ops.fp8 import fp8_ragged_dot
 
             new_fp8 = {}
@@ -534,29 +780,52 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
             y = _expert_ffn(rows, moe["wg"], moe["wi"], moe["wo"],
                             group_sizes, dt)
     with jax.named_scope("moe_permute"):
+        if share:
+            y = jnp.where(live, y, 0)
         per_pair = _permute_rows(y, inverse, order).reshape(N, K, C)
     with jax.named_scope("moe_combine"):
         out = jnp.einsum(
             "nkc,nk->nc", per_pair, gate_vals.astype(dt),
             preferred_element_type=f32).astype(dt)
+    if "shared" in moe:
+        with jax.named_scope("moe_shared"):
+            out = out + _swiglu(tokens.astype(dt), moe["shared"], dt)[0]
     with jax.named_scope("moe_router"):
         # the two loss terms, over real tokens only
         w = jnp.ones((N,), f32) if valid_n is None else valid_n.astype(f32)
         denom = jnp.maximum(jnp.sum(w), 1.0)
         me = jnp.sum(probs * w[:, None], axis=0) / denom
+        # pairs per expert in the router's own numbering, held or not
+        per_expert = jnp.roll(counts, first) if share else counts
         if cfg.balance_all_k:
-            ce = counts.astype(f32) / (denom * K)
+            ce = per_expert.astype(f32) / (denom * K)
         else:
             ce = jnp.sum(
                 jax.nn.one_hot(gate_idx[:, 0], E, dtype=f32) * w[:, None],
                 axis=0) / denom
         lse = jax.nn.logsumexp(logits, axis=-1)
+        if cfg.balance_per_sequence:
+            # per row of the batch: f over the K picks, P the mean share
+            # of the score, their product summed over experts
+            w_bs = w.reshape(B, S)
+            rows_b = jnp.maximum(jnp.sum(w_bs, axis=1), 1.0)  # [B]
+            taken = jnp.sum(
+                jax.nn.one_hot(gate_idx.reshape(B, S, K), E, dtype=f32),
+                axis=2) * w_bs[..., None]  # [B, S, E]
+            f_be = jnp.sum(taken, axis=1) * (E / K) / rows_b[:, None]
+            p_be = jnp.sum(probs.reshape(B, S, E) * w_bs[..., None],
+                           axis=1) / rows_b[:, None]
+            balance = jnp.mean(jnp.sum(f_be * p_be, axis=-1))
+        else:
+            balance = E * jnp.sum(me * ce)
         stats = {
-            "moe_aux": E * jnp.sum(me * ce),
+            "moe_aux": balance,
             "moe_z": jnp.sum(jnp.square(lse) * w) / denom,
             "experts": gate_idx.reshape(B, S, K),
-            "tokens_per_expert": counts,
+            "tokens_per_expert": per_expert,
         }
+        if share:
+            stats["held_pairs"] = ends[held - 1]
     if new_fp8 is not None:
         stats["fp8"] = new_fp8
     return out.reshape(B, S, C), stats
@@ -697,6 +966,7 @@ def forward_hidden(
     mesh=None,
     segment_ids=None,
     fp8_states=None,
+    next_tokens=None,
 ) -> tuple:
     """tokens [B, S] -> (final-norm hidden [B, S, D], aux dict).
 
@@ -718,7 +988,18 @@ def forward_hidden(
     the T normed streams stacked, ``[T, B, S, D]`` (pass t's is pass
     t+1's input), with ``aux["exit_logits"]`` (float32 ``[T, B, S]``, the
     exit gate's logit on each).  Each block APPLICATION is rematerialised
-    and named ``block_out`` on its own."""
+    and named ``block_out`` on its own.
+
+    With ``cfg.mtp_layers`` and ``next_tokens`` ([B, S], token i+1 under
+    position i: the targets) the multi-token-prediction block runs too
+    (scope ``mtp``): ``u_i = [rms(embed(t_{i+1}), ln_e); rms(z_i, ln_h)]
+    @ w_eh`` with ``z`` the last layer's output BEFORE the final norm, one
+    block of its own, its own final norm; the result is the two normed
+    streams stacked, ``[2, B, S, D]`` (main, then the block's, which
+    predicts token i+2).  Its routed block's statistics come last in every
+    per-block entry of the aux dict, and its experts under the key
+    ``"mtp"``.  A model with a share of the experts adds
+    ``moe_held_pairs`` (int32 ``[routed blocks]``)."""
     B, S = tokens.shape
     dt = cfg.dtype
     if cfg.loop_passes > 1 and fp8_states is not None:
@@ -734,7 +1015,18 @@ def forward_hidden(
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     moe_aux = jnp.zeros((), jnp.float32)
     moe_z = jnp.zeros((), jnp.float32)
-    experts, per_expert = {}, []
+    experts, per_expert, held_pairs = {}, [], []
+
+    def collect(block, stats):
+        """A routed block's statistics into the aux dict's entries."""
+        nonlocal moe_aux, moe_z
+        moe_aux = moe_aux + stats["moe_aux"]
+        moe_z = moe_z + stats["moe_z"]
+        experts[block] = stats["experts"]
+        per_expert.append(stats["tokens_per_expert"])
+        if "held_pairs" in stats:
+            held_pairs.append(stats["held_pairs"])
+
     apply = functools.partial(
         block_apply, attn_impl=attn_impl, mesh=mesh,
         segment_ids=segment_ids,
@@ -759,10 +1051,8 @@ def forward_hidden(
             # inside the block rematerializes.
             x = checkpoint_name(x, "block_out")
             if stats:
-                moe_aux = moe_aux + stats["moe_aux"]
-                moe_z = moe_z + stats["moe_z"]
-                experts[i] = stats["experts"]
-                per_expert.append(stats["tokens_per_expert"])
+                collect(i, stats)
+        z = x  # the last layer's output, what the prediction block reads
         with jax.named_scope("final_norm"):
             x = rmsnorm(x, params["ln_f"], eps=cfg.rms_eps)
         if cfg.loop_passes > 1:
@@ -774,6 +1064,24 @@ def forward_hidden(
                 exit_logits.append(jnp.einsum(
                     "bsd,d->bs", x.astype(jnp.float32), gate["w"],
                     precision="highest") + gate["b"])
+    if cfg.mtp_layers and next_tokens is not None:
+        if fp8_states is not None:
+            raise ValueError(
+                f"forward_hidden: mtp_layers={cfg.mtp_layers} with "
+                "fp8_states: the prediction block has no fp8 state")
+        with jax.named_scope("mtp"):
+            mtp = params["mtp"]
+            u = jnp.concatenate([
+                rmsnorm(params["embed"].astype(dt)[next_tokens],
+                        mtp["ln_e"], eps=cfg.rms_eps),
+                rmsnorm(z, mtp["ln_h"], eps=cfg.rms_eps),
+            ], axis=-1) @ mtp["w_eh"].astype(dt)
+            u, stats = apply(mtp["block"], u, cfg, positions)
+            u = checkpoint_name(u, "block_out")
+            if stats:
+                collect("mtp", stats)
+            u = rmsnorm(u, mtp["ln_f"], eps=cfg.rms_eps)
+        x = jnp.stack([x, u])
     out_aux = {"moe_aux": moe_aux}
     if streams:
         x = jnp.stack(streams)
@@ -781,6 +1089,8 @@ def forward_hidden(
     if per_expert:
         out_aux.update(moe_z=moe_z, moe_experts=experts,
                        moe_tokens_per_expert=jnp.stack(per_expert))
+    if held_pairs:
+        out_aux["moe_held_pairs"] = jnp.stack(held_pairs)
     if new_fp8 is not None:
         out_aux["fp8_states"] = new_fp8
     return x, out_aux
@@ -795,12 +1105,16 @@ def forward(
     mesh=None,
     segment_ids=None,
     fp8_states=None,
+    next_tokens=None,
 ) -> tuple:
     """tokens [B, S] -> (logits [B, S, vocab] fp32, aux dict); of a
-    looped model every pass's logits, ``[T, B, S, vocab]``."""
+    looped model every pass's logits, ``[T, B, S, vocab]``; with
+    ``next_tokens`` of a model with a prediction block the main logits
+    and the block's, ``[2, B, S, vocab]``."""
     x, aux = forward_hidden(
         params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
         segment_ids=segment_ids, fp8_states=fp8_states,
+        next_tokens=next_tokens,
     )
     with jax.named_scope("lm_head_loss"):  # the head's matmul is the
         # unfused loss's larger half
@@ -836,6 +1150,7 @@ def loss_fn(
     fused_lm_head: Optional[bool] = None,
     fp8_states=None,
     metrics: bool = False,
+    mtp_weight: float = 0.3,
 ) -> jax.Array:
     """Next-token loss, plus ``moe_aux_weight`` x the routed layers'
     load-balance terms and ``moe_z_weight`` x their router z-losses.
@@ -853,6 +1168,18 @@ def loss_fn(
     ``p_t[r] / N`` that the gate's gradient flows through.  With
     ``metrics`` it returns ``(loss, {"loop_ce": [T], "loop_exit_prob":
     [T], "loop_exit_entropy": scalar})``, means over the real tokens.
+
+    A model with a prediction block (``cfg.mtp_layers``) adds
+    ``mtp_weight`` x the mean cross-entropy of the block's stream against
+    the token TWO ahead, over the positions that have one
+    (:func:`mtp_loss`; counters ``main_ce``, ``mtp_ce``).  Further
+    counters of a routed model, where the setting is on: ``moe_seq_aux``
+    in place of ``moe_aux`` (``cfg.balance_per_sequence``),
+    ``moe_held_pairs`` (a share of the experts),
+    ``moe_router_bias_abs_max`` and, under :data:`RULE_UPDATES`, the
+    selection biases' next values (``cfg.router_bias_rate``; scope
+    ``router_bias``), which ``accelerate()``'s step writes into the
+    leaves :func:`rule_leaves` names.
     ``fused_lm_head`` (default: auto — on for large
     vocabs) routes the projection through the chunked fused lm-head
     cross-entropy so the [B, S, vocab] logits never hit HBM.  A
@@ -901,7 +1228,17 @@ def loss_fn(
             x, aux["exit_logits"], params["lm_head"], targets, cfg,
             valid=valid, fused_lm_head=fused_lm_head)
         return (loss, counters) if metrics else loss
-    if fused_lm_head:
+    counters = {}
+    if cfg.mtp_layers:
+        x, aux = forward_hidden(
+            params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
+            segment_ids=seg, fp8_states=fp8_states,  # refused there
+            next_tokens=targets,
+        )
+        ce, counters = mtp_loss(
+            x, params["lm_head"], targets, cfg, valid=valid,
+            fused_lm_head=fused_lm_head, mtp_weight=mtp_weight)
+    elif fused_lm_head:
         x, aux = forward_hidden(
             params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
             segment_ids=seg, fp8_states=fp8_states,
@@ -933,10 +1270,105 @@ def loss_fn(
         # (loss, new_fp8_states): use under value_and_grad(has_aux=True)
         # and feed the states back in next step (delayed scaling).
         return loss, aux["fp8_states"]
-    if metrics and "moe_z" in aux:
-        return loss, {k: aux[k] for k in (
-            "moe_tokens_per_expert", "moe_aux", "moe_z")}
-    return loss
+    if not metrics:
+        return loss
+    if "moe_z" in aux:
+        counters.update(
+            moe_tokens_per_expert=aux["moe_tokens_per_expert"],
+            moe_z=aux["moe_z"])
+        counters["moe_seq_aux" if cfg.balance_per_sequence
+                 else "moe_aux"] = aux["moe_aux"]
+        if "moe_held_pairs" in aux:
+            counters["moe_held_pairs"] = aux["moe_held_pairs"]
+        if cfg.router_bias_rate is not None:
+            with jax.named_scope("router_bias"):
+                updates = router_bias_rule(
+                    params, aux["moe_tokens_per_expert"], cfg)
+                counters[RULE_UPDATES] = updates
+                counters["moe_router_bias_abs_max"] = jnp.max(jnp.stack(
+                    [jnp.max(jnp.abs(b)) for b in updates.values()]))
+    return (loss, counters) if counters else loss
+
+
+#: the key of a loss function's metrics under which ``accelerate()``'s step
+#: looks for the next values of the leaves a rule moves
+#: (``parallel.accelerate.RULE_UPDATES``; the same string)
+RULE_UPDATES = "rule_updates"
+
+
+def _router_bias_paths(cfg: LlamaConfig) -> list:
+    """``(key path string, path into params)`` of every selection bias, in
+    the order of ``aux["moe_tokens_per_expert"]``'s rows."""
+    if cfg.router_bias_rate is None or cfg.num_experts <= 0:
+        return []
+    paths = [("layers", i, "moe", "router_bias")
+             for i in range(cfg.n_layer) if cfg.is_moe_layer(i)]
+    if cfg.mtp_layers:
+        paths.append(("mtp", "block", "moe", "router_bias"))
+    keys = jax.tree_util
+    return [(keys.keystr(tuple(
+        keys.SequenceKey(k) if isinstance(k, int) else keys.DictKey(k)
+        for k in path)), path) for path in paths]
+
+
+def rule_leaves(cfg: LlamaConfig) -> tuple:
+    """The leaves of ``params`` that a rule moves and no gradient does, as
+    ``jax.tree_util.keystr`` writes their paths: the routers' selection
+    biases.  A loss function handed to ``accelerate()`` carries them as its
+    ``rule_leaves`` attribute; the optimizer then holds no moment for them
+    and decays nothing of them."""
+    return tuple(name for name, _ in _router_bias_paths(cfg))
+
+
+def router_bias_rule(params: Dict, tokens_per_expert: jax.Array,
+                     cfg: LlamaConfig) -> Dict:
+    """``{leaf path: b + rate * sign(mean(c) - c)}`` for every routed
+    block's selection bias ``b`` and the pairs ``c [E]`` the step routed
+    to each expert (``tokens_per_expert [routed blocks, E]``): an expert
+    under the mean load becomes likelier to be chosen, one over it less
+    (DeepSeek-V3's auxiliary-loss-free balancing)."""
+    out = {}
+    for row, (name, path) in enumerate(_router_bias_paths(cfg)):
+        bias = params
+        for key in path:
+            bias = bias[key]
+        load = tokens_per_expert[row].astype(jnp.float32)
+        out[name] = jax.lax.stop_gradient(
+            bias + cfg.router_bias_rate * jnp.sign(jnp.mean(load) - load))
+    return out
+
+
+def mtp_loss(x, lm_head, targets, cfg: LlamaConfig, *, valid=None,
+             fused_lm_head: bool = True, mtp_weight: float = 0.3) -> tuple:
+    """The cross-entropy of a model with a prediction block from what
+    :func:`forward_hidden` returned (``x [2, B, S, D]``: the main stream,
+    the block's) -> ``(L_main + mtp_weight * L_mtp, counters)``.  Position
+    i of the block's stream predicts token i+2, the NEXT position's
+    target; the last position has none and weighs nothing, nor does a
+    position whose next is no real token (``valid`` [B, S]).  Both sets of
+    rows go through the head in ONE call, with row weights."""
+    with jax.named_scope("lm_head_loss"):
+        real = (jnp.ones(targets.shape, jnp.float32) if valid is None
+                else valid)
+        two_ahead = real * jnp.concatenate(
+            [real[:, 1:], jnp.zeros_like(real[:, :1])], axis=1)
+        share = jnp.stack([
+            real / jnp.maximum(jnp.sum(real), 1.0),
+            two_ahead / jnp.maximum(jnp.sum(two_ahead), 1.0)])
+        labels = jnp.stack([targets, jnp.roll(targets, -1, axis=1)])
+        weights = share * jnp.array([1.0, mtp_weight], jnp.float32)[
+            :, None, None]
+        if fused_lm_head:
+            ce, rows = linear_softmax_cross_entropy_sum(
+                x, lm_head.astype(cfg.dtype), labels, weights,
+                with_row_losses=True)
+            per_tok = rows.reshape(labels.shape)
+        else:
+            logits = (x @ lm_head.astype(cfg.dtype)).astype(jnp.float32)
+            per_tok = softmax_cross_entropy(logits, labels)
+            ce = jnp.sum(per_tok * weights)
+        each = jnp.sum(jax.lax.stop_gradient(per_tok) * share, axis=(1, 2))
+    return ce, {"main_ce": each[0], "mtp_ce": each[1]}
 
 
 def exit_distribution(exit_logits: jax.Array) -> jax.Array:
@@ -1003,6 +1435,22 @@ def refuse_looped(cfg: LlamaConfig, where: str) -> None:
                 "gate (only llama.forward_hidden / loss_fn do)")
 
 
+def refuse_latent(cfg: LlamaConfig, where: str) -> None:
+    """``ValueError`` naming the setting, for code that projects q, k and
+    v from ``wq``/``wk``/``wv``, holds every expert and knows no second
+    prediction head: latent attention, a share of the experts and the
+    multi-token-prediction block are computed by ``llama.forward_hidden``
+    / ``loss_fn`` alone."""
+    for name in ("kv_lora_rank", "experts_held", "mtp_layers"):
+        value = getattr(cfg, name)
+        if value:
+            raise ValueError(
+                f"{where} does not compute {name}={value!r}: latent "
+                "attention, a share of the experts and the "
+                "multi-token-prediction block exist on the training path "
+                "only (llama.forward_hidden / loss_fn)")
+
+
 def num_params(params: Dict) -> int:
     return sum(int(np.prod(x.shape))
                for x in jax.tree_util.tree_leaves(params))
@@ -1012,9 +1460,19 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     """~6 * non-embedding params + attention FLOPs (for MFU accounting).
     A looped model runs every layer and the head ``loop_passes`` times a
     token, and its exit gate (``2 * d_model`` a pass) with them."""
+    if cfg.kv_lora_rank > 0:  # latent attention's five projections
+        qkv = (
+            cfg.d_model * cfg.q_lora_rank
+            + cfg.q_lora_rank * cfg.n_head * cfg.head_dim
+            + cfg.d_model * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+            + cfg.kv_lora_rank * cfg.n_head
+            * (cfg.qk_nope_head_dim + cfg.v_head_dim))
+    else:
+        qkv = (
+            cfg.d_model * cfg.n_head * cfg.head_dim  # wq
+            + 2 * cfg.d_model * cfg.n_kv_head * cfg.head_dim)  # wk, wv
     p_layer = (
-        cfg.d_model * cfg.n_head * cfg.head_dim  # wq
-        + 2 * cfg.d_model * cfg.n_kv_head * cfg.head_dim  # wk, wv
+        qkv
         + cfg.n_head * cfg.head_dim * cfg.d_model  # wo
         + 3 * cfg.d_model * cfg.d_ff  # swiglu
     )

@@ -224,6 +224,26 @@ def _kv_row_map(H: int, KV: int):
     return index_map
 
 
+#: the compiler's own limit on a kernel's scoped VMEM; the chip has 128 MiB
+_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+
+
+def _vmem_params(resident_bytes: int) -> dict:
+    """``pallas_call`` keywords for a kernel whose whole-sequence operands
+    (K and V forward and for dq, Q and dO for dkv: ``[1, S, D]`` each,
+    double-buffered) hold ``resident_bytes`` at once.  Under the compiler's
+    default limit nothing is passed and the kernel compiles as it always
+    has (S 8,192 at D 128: 8 MiB); past it (S 8,192 at D 256: 16 MiB
+    before any block) the limit is raised to what the kernel holds plus
+    room for its blocks and temporaries."""
+    if resident_bytes + 4 * 2 ** 20 <= _DEFAULT_SCOPED_VMEM:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=int(resident_bytes * 1.25) + 12 * 2 ** 20)}
+
+
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
                segment_ids=None, window=0):
     from jax.experimental import pallas as pl
@@ -275,6 +295,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
         ],
         interpret=interpret,
         name="flash_fwd",
+        **_vmem_params(2 * 2 * S_pad * D * k.dtype.itemsize),
     )(*inputs)
     return (
         out.reshape(B, H, S_pad, D)[:, :, :S],
@@ -518,6 +539,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((B * H, S_pad, D), q.dtype),
         interpret=interpret,
         name="flash_bwd_dq",
+        **_vmem_params(2 * 2 * S_pad * D * k.dtype.itemsize),
     )(*common)
 
     # dkv: grid (B*KV, k_blocks, rep) — the innermost axis streams the
@@ -560,6 +582,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
+        **_vmem_params(2 * 2 * S_pad * D * q.dtype.itemsize),
     )(*dkv_in)
 
     return (

@@ -43,13 +43,21 @@ def _kernel_fits(tokens: jax.Array, weights: jax.Array) -> bool:
 def grouped_matmul_ragged(
     tokens: jax.Array,  # [T, K] sorted by group
     weights: jax.Array,  # [E, K, N]
-    group_sizes: jax.Array,  # [E] int32, sum == T
+    group_sizes: jax.Array,  # [E] int32, sum <= T
     *,
     backend: Optional[str] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Ragged grouped GEMM: rows [offset_e : offset_e + size_e] x weights[e].
-    """
+
+    The sizes may sum to fewer rows than ``tokens`` has (a layer that
+    holds a share of the experts computes the pairs routed to those): the
+    kernel's grid then ends with the last group's tile, forward and in both
+    transposes, and no time is spent on the rest.  The rows of the result
+    past the sum are UNSPECIFIED (the kernel never writes them; the
+    reference writes zeros): a caller keeps them out of every sum, as
+    ``_moe_swiglu`` does with a mask on the rows going in and on the
+    result coming out."""
     if backend is None:
         backend = "pallas" if (jax.default_backend() == "tpu"
                                and _kernel_fits(tokens, weights)) else (

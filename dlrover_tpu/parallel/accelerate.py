@@ -228,6 +228,38 @@ _COLLECTIVES = (
 )
 
 
+#: Under this key of its metrics a loss function hands out the next values
+#: of the leaves of ``params`` that a RULE moves and no gradient does (a
+#: router's selection bias, a running statistic): ``{leaf path as
+#: jax.tree_util.keystr writes it: new value}``.  The same function names
+#: those leaves in its ``rule_leaves`` attribute (a tuple of the paths), so
+#: that the step builder knows them before it traces anything: the
+#: optimizer then holds no moment for them and decays nothing of them, the
+#: step writes the rule's values over them, and they are saved and restored
+#: with ``state["params"]`` like any other leaf.
+RULE_UPDATES = "rule_updates"
+
+
+def _outside_the_rule(optimizer, rule_leaves: tuple, params_shape):
+    """``optimizer`` over every leaf of ``params`` but the rule's."""
+    import optax
+
+    known = {jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_flatten_with_path(params_shape)[0]}
+    unknown = sorted(set(rule_leaves) - known)
+    if unknown:
+        raise ValueError(
+            f"the loss function's rule_leaves {unknown} name no leaf of "
+            "the parameters")
+
+    def trained(tree):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: jax.tree_util.keystr(path) not in rule_leaves,
+            tree)
+
+    return optax.masked(optimizer, trained)
+
+
 #: the scope ``train_step`` puts around ``tx.update`` + ``apply_updates``;
 #: :func:`scope_table` makes it the phase of the same name
 OPTIMIZER_SCOPE = "optimizer"
@@ -246,6 +278,28 @@ _NO_DEVICE_OP = re.compile(
     r" (?:parameter|constant|get-tuple-element|tuple|bitcast)\(")
 
 
+def _program_scopes(parts):
+    """The components of an ``op_name`` path that are scopes of the
+    program, outermost first."""
+    for part in parts:
+        inner = part.replace("transpose(", "").replace(
+            "jvp(", "").rstrip(")")
+        if (inner and "(" not in inner and "," not in inner
+                and inner not in _NOT_A_SCOPE):
+            yield inner
+
+
+def inner_scope(op_name: str) -> str:
+    """The innermost scope on an ``op_name``'s path where the program
+    nests them (``jit(train_step)/jvp(attention)/mla_q/dot_general``:
+    ``mla_q``; ``mtp/attention/mla_q/..``: ``mla_q`` too), "" where the
+    outermost is the only one.  A Pallas kernel's name sits there as well
+    (``attention/flash_fwd/pallas_call``)."""
+    scopes = list(_program_scopes(op_name.split("/")[:-1]))
+    # a scope entered again inside itself (a scan's body) nests nothing
+    return scopes[-1] if scopes and scopes[-1] != scopes[0] else ""
+
+
 def phase_and_scope(op_name: str) -> Optional[list]:
     """``[phase, scope]`` of one instruction's ``op_name``
     (``jit(train_step)/transpose(jvp(attention))/dot_general``): the
@@ -255,14 +309,7 @@ def phase_and_scope(op_name: str) -> Optional[list]:
     recompute — or ``optimizer`` under :data:`OPTIMIZER_SCOPE`, else
     ``other``.  None where the path names no scope."""
     parts = op_name.split("/")[:-1]  # the last one is the primitive
-    scope = ""
-    for part in parts:
-        inner = part.replace("transpose(", "").replace(
-            "jvp(", "").rstrip(")")
-        if (inner and "(" not in inner and "," not in inner
-                and inner not in _NOT_A_SCOPE):
-            scope = inner
-            break
+    scope = next(_program_scopes(parts), "")
     if not scope:
         return None
     if scope == OPTIMIZER_SCOPE:
@@ -279,8 +326,15 @@ def phase_and_scope(op_name: str) -> Optional[list]:
 
 
 def scope_table(hlo_text: str) -> dict:
-    """``{instruction name: [phase, scope]}`` for every instruction of
-    the compiled text that runs as a device op of its own (the entry
+    """:func:`scope_tables`' first table alone."""
+    return scope_tables(hlo_text)[0]
+
+
+def scope_tables(hlo_text: str) -> tuple:
+    """``({instruction name: [phase, scope]}, {instruction name: inner
+    scope})``; the second names the innermost scope (:func:`inner_scope`)
+    of the instructions under nested scopes, found by the same rules.
+    The first: ``[phase, scope]`` for every instruction of the compiled text that runs as a device op of its own (the entry
     computation's, a loop body's; not those inside a fusion): what joins
     a device trace's names (``fusion.129``) to the program's.  A fusion
     carries one ``op_name`` — its root's; where XLA fused a weight
@@ -300,9 +354,12 @@ def scope_table(hlo_text: str) -> dict:
         if m and current is not None:
             current.append((m.group(1), m.group(2)))
 
-    def verdict(rest: str) -> Optional[list]:
+    def named(rest: str) -> tuple:
+        """(``[phase, scope]`` or None, innermost scope or "")."""
         m = re.search(r'op_name="([^"]*)"', rest)
-        return phase_and_scope(m.group(1)) if m else None
+        if m is None:
+            return None, ""
+        return phase_and_scope(m.group(1)), inner_scope(m.group(1))
 
     fused = {
         m.group(1)
@@ -311,31 +368,42 @@ def scope_table(hlo_text: str) -> dict:
         for m in [re.search(r"calls=%?([\w.\-]+)", rest)] if m
     }
     table: dict = {}
+    inners: Dict[str, str] = {}
     for comp, body in computations.items():
         if comp in fused:
             continue
         for name, rest in body:
             if _NO_DEVICE_OP.search(rest):
                 continue
-            found = verdict(rest)
+            found, within = named(rest)
             if found is None and " fusion(" in rest:
                 m = re.search(r"calls=%?([\w.\-]+)", rest)
                 votes: Dict[tuple, int] = {}
+                inner_votes: Dict[tuple, Dict[str, int]] = {}
                 for _, inner in computations.get(m.group(1), []) if m else []:
-                    v = verdict(inner)
+                    v, v_inner = named(inner)
                     if v is not None:
                         votes[tuple(v)] = votes.get(tuple(v), 0) + 1
+                        tally = inner_votes.setdefault(tuple(v), {})
+                        tally[v_inner] = tally.get(v_inner, 0) + 1
                 if votes:
-                    found = list(max(votes, key=votes.get))
+                    winner = max(votes, key=votes.get)
+                    found = list(winner)
+                    within = max(inner_votes[winner],
+                                 key=inner_votes[winner].get)
             if found is None:
                 # a copy or a convert XLA added names nothing: it goes
                 # with the first operand that does (text is in def order)
-                found = next(
-                    (table[o] for o in re.findall(
+                source = next(
+                    (o for o in re.findall(
                         r"%([\w.\-]+)", rest.split("(", 1)[-1])
                      if o in table), None)
+                if source is not None:
+                    found, within = table[source], inners.get(source, "")
             if found is not None:
                 table[name] = found
+                if within:
+                    inners[name] = within
         # What the compiler adds in front of an instruction (a prefetch
         # of its operand: copy-start/-done, slice-start/-done) names
         # nothing and reads only parameters: it goes with its first
@@ -353,8 +421,10 @@ def scope_table(hlo_text: str) -> dict:
                 for o in ops:
                     if o in operands and o not in table:
                         table[o] = table[user]
+                        if user in inners:
+                            inners[o] = inners[user]
                         changed = True
-    return table
+    return table, inners
 
 
 def program_summary(hlo_text: str) -> dict:
@@ -369,7 +439,9 @@ def program_summary(hlo_text: str) -> dict:
     ``kernels`` alone cannot tell 8 layers run four times from 32 (0
     where the step runs no such kernel: the CPU, ring attention);
     collectives are counted by opcode (async ``-start`` forms included
-    once); ``scopes`` is :func:`scope_table`."""
+    once); ``scopes`` is :func:`scope_table`, and ``subscopes`` (only
+    where the program nests scopes of its own) :func:`scope_tables`'
+    second table."""
     kernels: dict = {}
     applications = 0
     for line in hlo_text.splitlines():
@@ -386,8 +458,15 @@ def program_summary(hlo_text: str) -> dict:
         kind: len(re.findall(rf"\s{kind}(?:-start)?\(", hlo_text))
         for kind in _COLLECTIVES
     }
-    return {"kernels": kernels, "block_applications": applications,
-            "collectives": collectives, "scopes": scope_table(hlo_text)}
+    scopes, inners = scope_tables(hlo_text)
+    summary = {"kernels": kernels, "block_applications": applications,
+               "collectives": collectives, "scopes": scopes}
+    # only a program that nests scopes of its own says so: the kernels'
+    # names alone (every ``flash_fwd`` sits inside ``attention``) are in
+    # ``kernels`` already
+    if set(inners.values()) - set(kernels):
+        summary["subscopes"] = inners
+    return summary
 
 
 def _build_train_step(
@@ -397,6 +476,7 @@ def _build_train_step(
     has_frozen: bool = False,
     mesh: Optional[Mesh] = None,
     batch_axes: Any = None,  # resolved PartitionSpec tree (quant path)
+    rule_leaves: tuple = (),  # the loss function's (see RULE_UPDATES)
 ):
     """state={'params','opt_state','step'}; batch pytree; returns jittable
     step with optional remat and grad accumulation (grad-accum preserves
@@ -422,6 +502,12 @@ def _build_train_step(
     quant_on = (
         strategy.quant_grads and quant_grads_incompat(strategy) is None
     )
+    if rule_leaves and (quant_on or strategy.grad_accum > 1):
+        raise ValueError(
+            f"rule_leaves={rule_leaves} with grad_accum="
+            f"{strategy.grad_accum} or quant_grads={strategy.quant_grads}: "
+            "a rule reads one step's counters, not a microbatch's, and "
+            "the compressed reduction hands out no metrics")
 
     def _quant_loss_and_grads(params, batch, frozen):
         """Full-step (loss, grads) with int8-compressed dp reduction.
@@ -623,6 +709,18 @@ def _build_train_step(
             updates, opt_state = tx.update(
                 grads, state["opt_state"], params)
             params = optax.apply_updates(params, updates)
+        metrics = dict(metrics)
+        ruled = metrics.pop(RULE_UPDATES, {})
+        if set(ruled) != set(rule_leaves):
+            raise ValueError(
+                f"the loss function's metrics[{RULE_UPDATES!r}] name "
+                f"{sorted(ruled)}, its rule_leaves {sorted(rule_leaves)}: "
+                "the two must be the same leaves")
+        if ruled:
+            params = jax.tree_util.tree_map_with_path(
+                lambda path, p: jnp.asarray(
+                    ruled.get(jax.tree_util.keystr(path), p),
+                    p.dtype).reshape(p.shape), params)
         new_state = {
             "params": params,
             "opt_state": opt_state,
@@ -968,6 +1066,9 @@ def _compile_candidate(
     mesh = build_mesh(mesh_spec, devs)
 
     params_shape = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    rule_leaves = tuple(getattr(loss_fn, "rule_leaves", ()))
+    if rule_leaves:
+        optimizer = _outside_the_rule(optimizer, rule_leaves, params_shape)
     if callable(param_specs):
         p_specs = param_specs(strategy)
     elif isinstance(param_specs, str) and param_specs == "planner":
@@ -1062,7 +1163,7 @@ def _compile_candidate(
             raise ValueError(reason)
     step_fn = _build_train_step(
         loss_fn, optimizer, strategy, has_frozen=frozen is not None,
-        mesh=mesh, batch_axes=batch_axes,
+        mesh=mesh, batch_axes=batch_axes, rule_leaves=rule_leaves,
     )
     # The frozen tree is a separate, never-donated jit argument (see
     # _build_train_step); the public train_step keeps the state-dict API.

@@ -420,3 +420,58 @@ def test_looped_step_tells_block_applications_from_layers(topo, monkeypatch):
     assert {("forward", "exit_gate"), ("backward", "exit_gate"),
             ("recompute", "attention"), ("recompute", "mlp"),
             ("forward", "lm_head_loss"), ("forward", "final_norm")} <= found
+
+
+def test_latent_share_mtp_step_compiles_at_published_widths(topo, monkeypatch):
+    """The GLM-4.7-Flash cell's step from shapes, depth cut to the dense
+    layer, one routed layer and the prediction block (three block
+    applications), widths and sequence length whole: the flash kernels at a
+    head size of 256 hold K and V of 8,192 rows (4 MB each, double
+    buffered: the compiler's default scoped VMEM to the byte, so they ask
+    for more), the grouped matmuls run over 8 held experts' groups that end
+    before the buffer does, and the compiled step's tables name the nested
+    scopes."""
+    import optax
+
+    from dlrover_tpu.models import llama
+    from dlrover_tpu.parallel.mesh import MeshSpec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = llama.LlamaConfig(
+        vocab_size=19360, n_layer=2, n_head=20, n_kv_head=20, d_model=2048,
+        d_ff=10240, max_seq_len=8192, rope_theta=1e6, remat_block=True,
+        num_experts=64, top_k=4, moe_every=1, first_k_dense=1,
+        d_ff_expert=1536, n_shared_experts=1, router_score="sigmoid",
+        routed_scaling=1.8, router_bias_rate=1e-3,
+        balance_per_sequence=True, experts_held=8, mtp_layers=1,
+        q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256)
+
+    def loss(params, batch):
+        return llama.loss_fn(params, batch, cfg, moe_aux_weight=1e-4,
+                             metrics=True)
+
+    loss.rule_leaves = llama.rule_leaves(cfg)
+    job = acc.aot_analyze(
+        loss_fn=loss, init_fn=lambda r: llama.init_params(r, cfg),
+        optimizer=optax.adamw(3e-4),
+        sample_batch={"tokens": np.zeros((1, 8193), np.int32)},
+        strategy=acc.Strategy(mesh=MeshSpec()), param_specs="planner",
+        devices=topo.devices[:1],
+    )
+    kernels = job.program["kernels"]
+    assert (kernels["flash_fwd"], kernels["flash_bwd_dq"],
+            kernels["flash_bwd_dkv"]) == (6, 3, 3)
+    assert job.program["block_applications"] == cfg.block_applications == 3
+    # two routed blocks: three grouped matmuls forward, recomputed and for
+    # the row gradients, three weight gradients
+    assert kernels["gmm"] == 2 * 9 and kernels["tgmm"] == 2 * 3
+    found = {tuple(v) for v in job.program["scopes"].values()}
+    assert {("forward", "mtp"), ("backward", "mtp"), ("recompute", "mtp"),
+            ("forward", "moe_shared"), ("backward", "moe_experts"),
+            ("forward", "router_bias"), ("forward", "lm_head_loss"),
+            ("recompute", "attention")} <= found
+    inner = set(job.program["subscopes"].values())
+    assert {"mla_q", "mla_kv", "mla_out", "attention", "moe_shared"} <= inner
+    # and the cut fits the chip
+    assert job.memory["peak_bytes"] < 16_909_336_064
