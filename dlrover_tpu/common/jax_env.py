@@ -12,6 +12,9 @@ from __future__ import annotations
 import glob
 import os
 import sys
+import threading
+import time
+from typing import Optional
 
 #: Where compiled programs are kept when ``JAX_COMPILATION_CACHE_DIR`` does
 #: not place them: one fixed, git-ignored path inside the checkout.  The
@@ -52,6 +55,7 @@ def enable_compilation_cache() -> bool:
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
+    install_compile_listener()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         os.makedirs(DEFAULT_COMPILE_CACHE_DIR, exist_ok=True)
         jax.config.update(
@@ -76,6 +80,7 @@ def device_summary() -> dict:
 
     from dlrover_tpu.obs import span
 
+    install_compile_listener()
     # the first jax.devices() of a process initialises the backend and
     # takes the chip; a later call is a lookup
     with span("bootstrap.backend_init", "bootstrap",
@@ -88,6 +93,120 @@ def device_summary() -> dict:
         }
         sp.set(**summary)
     return summary
+
+
+# ---------------------------------------------------------------------------
+# JAX's own compile events as flight-recorder spans
+# ---------------------------------------------------------------------------
+
+#: JAX's duration events of the three stages every program passes through
+#: on its way to the device, and the span each becomes (cat ``jax``)
+_STAGE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+#: the persistent cache's two durations of a hit, by ``jax.compile`` arg
+_CACHE_ARGS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s",
+}
+
+#: A ``jax.trace`` shorter than this is not recorded.  JAX reports one for
+#: every jitted function and primitive a trace passes through: thousands a
+#: step build, nine in ten under a millisecond and nearly all inside the
+#: trace of the function that called them, which is recorded — while the
+#: ring they would fill holds 4,096 records.
+MIN_TRACE_SPAN_S = 1e-3
+
+_listener_mu = threading.Lock()
+_listener_installed = False
+
+
+class _ThreadCompiles(threading.local):
+    """Per thread: what the cache has said since the thread's last
+    ``jax.compile`` span (its events come before the duration of the
+    compile that asked), and how many compiles it has answered either
+    way."""
+
+    def __init__(self):
+        self.pending: dict = {}
+        self.hits = self.misses = 0
+
+
+_compiles = _ThreadCompiles()
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT:
+        _compiles.pending["cache_hit"] = True
+    elif event == _CACHE_MISS:
+        _compiles.pending["cache_hit"] = False
+
+
+def _on_stage_duration(event: str, secs: float, **kw) -> None:
+    arg = _CACHE_ARGS.get(event)
+    if arg is not None:
+        _compiles.pending[arg] = round(secs, 6)
+        return
+    name = _STAGE_SPANS.get(event)
+    if name is None or (name == "jax.trace" and secs < MIN_TRACE_SPAN_S):
+        return
+    from dlrover_tpu.obs import current_span_id, record_span
+
+    args = {"fun_name": str(kw.get("fun_name", ""))}
+    if name == "jax.compile":
+        args.update({"cache_hit": None, **_compiles.pending})
+        _compiles.pending = {}
+        _compiles.hits += args["cache_hit"] is True
+        _compiles.misses += args["cache_hit"] is False
+    # JAX hands over a duration and no instants: the stage ended just
+    # now, on the recorder's clock
+    end = time.monotonic()
+    record_span(name, "jax", end - secs, end, parent=current_span_id(),
+                args=args, durable=True)
+
+
+def install_compile_listener() -> None:
+    """Every program this process traces, lowers and compiles (or reads
+    from the persistent cache) from now on leaves ``jax.trace``,
+    ``jax.lower`` and ``jax.compile`` spans (a trace only from
+    :data:`MIN_TRACE_SPAN_S` up), each with ``fun_name`` and the span
+    open on its thread as parent; ``jax.compile`` also says
+    what the cache said (``cache_hit`` True, False, or None where it was
+    not asked; on a hit ``retrieval_s`` and ``saved_s``).  One listener
+    pair per process however often this is called; a warmed-up step
+    fires neither."""
+    global _listener_installed
+    with _listener_mu:
+        if _listener_installed:
+            return
+        _listener_installed = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_listener(_on_cache_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_stage_duration)
+
+
+class CompileWatch:
+    """The cache's verdict over the ``jax.compile`` spans this thread
+    records from now on: ``cache_hit`` is True when every executable
+    asked for came from the cache, False when one was compiled, None
+    when the cache was not asked (disabled, or nothing compiled)."""
+
+    def __init__(self):
+        install_compile_listener()
+        self._hits, self._misses = _compiles.hits, _compiles.misses
+
+    @property
+    def cache_hit(self) -> Optional[bool]:
+        hits = _compiles.hits - self._hits
+        misses = _compiles.misses - self._misses
+        if not hits and not misses:
+            return None
+        return misses == 0
 
 
 def host_chip_count() -> int:

@@ -40,6 +40,7 @@ from dlrover_tpu.obs.recorder import (  # noqa: F401
     ENV_PROCESS,
     FlightRecorder,
     configure,
+    current_span_id,
     gc_job_dirs,
     get_recorder,
     job_dir,

@@ -461,6 +461,13 @@ def _open_spans() -> List[str]:
     return stack
 
 
+def current_span_id() -> str:
+    """The id of the innermost span open on this thread, "" when none:
+    the parent of a span recorded after the fact (:func:`record_span`)."""
+    stack = _open_spans()
+    return stack[-1] if stack else ""
+
+
 def span(name: str, cat: str, ring_only: bool = False, parent: str = "",
          **args: Any) -> Span:
     """The span primitive (see :class:`Span`).  Low-rate spans (save,
@@ -472,9 +479,10 @@ def span(name: str, cat: str, ring_only: bool = False, parent: str = "",
 
 def record_span(name: str, cat: str, start_s: float, end_s: float,
                 trace_id: str = "", span_id: Optional[str] = None,
-                parent: str = "", args: Optional[dict] = None) -> str:
+                parent: str = "", args: Optional[dict] = None,
+                durable: bool = False) -> str:
     """Record one span on the process recorder (hot-path one-liner)."""
     return get_recorder().span(
         name, cat, start_s, end_s, trace_id=trace_id,
-        span_id=span_id, parent=parent, args=args,
+        span_id=span_id, parent=parent, args=args, durable=durable,
     )

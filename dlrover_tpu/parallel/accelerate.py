@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dlrover_tpu.common.jax_env import CompileWatch
 from dlrover_tpu.common.log import logger
 from dlrover_tpu.obs import journal, span
 from dlrover_tpu.parallel.mesh import MeshSpec, build_mesh, candidate_specs
@@ -735,34 +736,6 @@ def _build_train_step(
     return train_step
 
 
-class _CacheWatch:
-    """JAX's own persistent-cache events while a compile runs:
-    ``cache_hit`` is True when every executable asked for came from the
-    cache, False when one was compiled, None when the cache was not
-    asked (disabled, or nothing compiled)."""
-
-    HIT = "/jax/compilation_cache/cache_hits"
-    MISS = "/jax/compilation_cache/cache_misses"
-
-    def __enter__(self) -> "_CacheWatch":
-        self.hits = self.misses = 0
-        jax.monitoring.register_event_listener(self._on)
-        return self
-
-    def _on(self, event: str, **_kw) -> None:
-        self.hits += event == self.HIT
-        self.misses += event == self.MISS
-
-    def __exit__(self, *exc) -> None:
-        jax.monitoring.unregister_event_listener(self._on)
-
-    @property
-    def cache_hit(self) -> Optional[bool]:
-        if not self.hits and not self.misses:
-            return None
-        return self.misses == 0
-
-
 def _build_span(fn: Callable) -> Callable:
     """``accelerate.build`` around the whole of :func:`accelerate`, and
     the compiled step's summary journalled once (``accelerate.program``:
@@ -1210,14 +1183,17 @@ def _compile_candidate(
     def public_step(state, batch):
         if called:
             return run_step(state, batch)
-        # The first call traces, lowers and compiles once more (the AOT
-        # executable below serves the analysis only): a cache read where
-        # the cache is on.  The span ends when the call returns, not
-        # when the step has run.
+        # The first call goes through jit's own dispatch (the AOT
+        # executable below serves the analysis only).  Whatever it has
+        # to trace, lower or compile again shows as ``jax.*`` spans under
+        # this one; where JAX's in-memory caches still hold the AOT
+        # lowering's work (jax 0.9: milliseconds, no stage) there are
+        # none and ``cache_hit`` is None.  The span ends when the call
+        # returns, not when the step has run.
         called.append(True)
         with span("accelerate.first_call", "accelerate",
-                  strategy=strategy.describe()) as sp, \
-                _CacheWatch() as watch:
+                  strategy=strategy.describe()) as sp:
+            watch = CompileWatch()
             out = run_step(state, batch)
             sp.set(cache_hit=watch.cache_hit)
         return out
@@ -1228,6 +1204,16 @@ def _compile_candidate(
         the tree given to accelerate() when that was concrete; "zeros"
         builds sharded zeros (strategy scoring — same FLOPs, no
         multi-GB transfer per candidate)."""
+        # Ends when the call returns (the init traced, lowered, compiled
+        # or read from the cache, and dispatched), not when the state is
+        # on the device; ``bytes`` from the shapes, no device read.
+        with span("accelerate.create_state", "accelerate",
+                  bytes=state_bytes,
+                  frozen="none" if frozen is None else
+                  "zeros" if isinstance(frozen_values, str) else "values"):
+            return _create_state(rng, frozen_values)
+
+    def _create_state(rng, frozen_values):
         with mesh:
             def mk(r):
                 st = {
@@ -1302,6 +1288,9 @@ def _compile_candidate(
     abstract_inner = {
         k: v for k, v in abstract_state.items() if k != "frozen"
     }
+    state_bytes = sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize
+        for x in jax.tree_util.tree_leaves(abstract_state))
     lower_args = (abstract_inner, abstract_batch)
     if frozen is not None:
         lower_args += (abstract_state["frozen"],)
@@ -1310,7 +1299,8 @@ def _compile_candidate(
         with span("accelerate.lower", "accelerate", strategy=described):
             lowered = jitted.lower(*lower_args)
         with span("accelerate.compile", "accelerate",
-                  strategy=described) as sp, _CacheWatch() as watch:
+                  strategy=described) as sp:
+            watch = CompileWatch()
             compiled = lowered.compile()
             sp.set(cache_hit=watch.cache_hit)
     with span("accelerate.analyze", "accelerate", strategy=described):

@@ -484,3 +484,123 @@ class TestCompilationCache:
             if "jax_compilation_cache_dir" in line
         ]
         assert [f for f, _ in hits] == ["dlrover_tpu/common/jax_env.py"]
+
+
+class TestCompileListener:
+    """JAX's compile events reach the flight recorder through one
+    listener pair (``jax_env.install_compile_listener``), and whoever
+    asks what the cache said asks that listener."""
+
+    TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    HIT = "/jax/compilation_cache/cache_hits"
+    MISS = "/jax/compilation_cache/cache_misses"
+    RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+    @pytest.fixture
+    def recorder(self):
+        from dlrover_tpu import obs
+
+        rec = obs.configure()
+        yield rec
+        obs.reset()
+
+    @staticmethod
+    def _compiles(rec):
+        return [e for e in rec.snapshot()[0]
+                if e.get("name") == "jax.compile"]
+
+    def test_one_pair_however_often_it_is_installed(self):
+        from jax._src import monitoring
+
+        from dlrover_tpu.common import jax_env
+
+        jax_env.install_compile_listener()
+        before = (len(monitoring._event_listeners),
+                  len(monitoring._event_duration_secs_listeners))
+        jax_env.install_compile_listener()
+        jax_env.device_summary()
+        jax_env.CompileWatch()
+        assert (len(monitoring._event_listeners),
+                len(monitoring._event_duration_secs_listeners)) == before
+        assert monitoring._event_listeners.count(
+            jax_env._on_cache_event) == 1
+        assert monitoring._event_duration_secs_listeners.count(
+            jax_env._on_stage_duration) == 1
+
+    def test_exactly_one_site_registers_listeners(self):
+        """``grep -rn 'register_event.*listener' dlrover_tpu``: the two
+        calls of ``install_compile_listener``."""
+        import glob
+
+        from conftest import REPO_ROOT
+
+        hits = [
+            os.path.relpath(f, REPO_ROOT)
+            for f in glob.glob(os.path.join(
+                REPO_ROOT, "dlrover_tpu", "**", "*.py"), recursive=True)
+            for line in open(f, encoding="utf-8")
+            if "monitoring.register_" in line
+        ]
+        assert hits == ["dlrover_tpu/common/jax_env.py"] * 2
+
+    def test_the_verdict_rides_on_the_compile_that_asked(self, recorder):
+        from dlrover_tpu.common import jax_env
+
+        watch = jax_env.CompileWatch()
+        assert watch.cache_hit is None  # nothing compiled
+        jax_env._on_stage_duration(self.COMPILE, 0.25, fun_name="jit(a)")
+        assert watch.cache_hit is None  # compiled, the cache not asked
+        jax_env._on_cache_event(self.HIT)
+        jax_env._on_stage_duration(self.RETRIEVAL, 0.125)
+        jax_env._on_stage_duration(self.COMPILE, 0.5, fun_name="jit(b)")
+        assert watch.cache_hit is True
+        jax_env._on_cache_event(self.MISS)
+        jax_env._on_stage_duration(self.COMPILE, 2.0, fun_name="jit(c)")
+        assert watch.cache_hit is False  # one was compiled
+        assert jax_env.CompileWatch().cache_hit is None  # a later watch
+        a, b, c = self._compiles(recorder)
+        assert a["args"] == {"fun_name": "jit(a)", "cache_hit": None}
+        assert b["args"] == {"fun_name": "jit(b)", "cache_hit": True,
+                             "retrieval_s": 0.125}
+        # the hit's retrieval time does not leak into the next compile
+        assert c["args"] == {"fun_name": "jit(c)", "cache_hit": False}
+        assert c["dur"] == pytest.approx(2.0e6)
+
+    def test_a_verdict_stays_on_its_thread(self, recorder):
+        import threading
+
+        from dlrover_tpu.common import jax_env
+
+        watch = jax_env.CompileWatch()
+        t = threading.Thread(target=lambda: (
+            jax_env._on_cache_event(self.MISS),
+            jax_env._on_stage_duration(self.COMPILE, 1.0, fun_name="x")))
+        t.start()
+        t.join()
+        assert watch.cache_hit is None
+        jax_env._on_stage_duration(self.COMPILE, 1.0, fun_name="y")
+        by = {e["args"]["fun_name"]: e["args"]["cache_hit"]
+              for e in self._compiles(recorder)}
+        assert by == {"x": False, "y": None}
+
+    def test_a_stage_span_ends_now_and_names_the_open_span(self, recorder):
+        import time
+
+        from dlrover_tpu import obs
+        from dlrover_tpu.common import jax_env
+        from dlrover_tpu.obs.span import anchored_us
+
+        with obs.span("outer", "ut") as outer:
+            jax_env._on_stage_duration(self.TRACE, 0.5, fun_name="f")
+            # under the threshold: thousands of these a build, unrecorded
+            jax_env._on_stage_duration(
+                self.TRACE, jax_env.MIN_TRACE_SPAN_S / 2, fun_name="g")
+            jax_env._on_stage_duration("/jax/other/duration", 9.0)
+        now = anchored_us(time.monotonic())
+        (traced,) = [e for e in recorder.snapshot()[0]
+                     if e.get("cat") == "jax"]
+        assert traced["name"] == "jax.trace"
+        assert traced["psid"] == outer.sid
+        assert traced["dur"] == pytest.approx(0.5e6)
+        assert now - 1e6 < traced["ts"] + traced["dur"] <= now

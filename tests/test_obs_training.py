@@ -378,6 +378,38 @@ class TestBuildSpansAndScopes:
         assert len(firsts) == 1  # the second train_step call records none
         assert "psid" not in firsts[0]
 
+    def test_create_state_has_its_span_and_its_init_is_its_child(
+            self, built):
+        _, evs = built
+        (made,) = [e for e in evs
+                   if e.get("name") == "accelerate.create_state"]
+        # fp32 params + two AdamW moments, from the shapes
+        assert made["args"]["bytes"] > 3 * 4 * 4096 * 64
+        assert made["args"]["frozen"] == "none"
+        assert "psid" not in made
+        kids = [e for e in evs if e.get("psid") == made["sid"]]
+        compiled = [k for k in kids if k["name"] == "jax.compile"]
+        assert [k["args"]["fun_name"] for k in compiled] == ["jit(mk)"]
+        assert {"jax.trace", "jax.lower"} <= {k["name"] for k in kids}
+        assert all(_inside(k, made) for k in kids)
+
+    def test_compile_and_first_call_say_what_their_compiles_said(
+            self, built):
+        """``cache_hit`` of ``accelerate.compile`` and ``.first_call``
+        comes from the ``jax.compile`` spans under them: one listener."""
+        _, evs = built
+        for name in ("accelerate.compile", "accelerate.first_call"):
+            (sp,) = [e for e in evs if e.get("name") == name]
+            verdicts = [e["args"]["cache_hit"] for e in evs
+                        if e.get("name") == "jax.compile"
+                        and e.get("psid") == sp["sid"]]
+            # the first call's executable may come from JAX's in-memory
+            # cache of compilations: no compile, no verdict, None
+            assert verdicts or name == "accelerate.first_call"
+            asked = [v for v in verdicts if v is not None]
+            assert sp["args"]["cache_hit"] == (
+                all(asked) if asked else None), name
+
     def test_program_event_carries_the_scope_table(self, built):
         job, evs = built
         (ev,) = [e for e in evs if e.get("kind") == "accelerate.program"]
@@ -454,6 +486,123 @@ ENTRY %main.9 (a: f32[4]) -> f32[4] {
             "dot.7": ["backward", "lm_head_loss"],
             "while.4": ["backward", "lm_head_loss"],
         }
+
+
+class TestJaxStageSpans:
+    """JAX's own trace, lower and compile events as spans
+    (``common/jax_env.py::install_compile_listener``), in a process of
+    its own with a fresh persistent cache."""
+
+    CODE = (
+        "import json, jax, jax.numpy as jnp\n"
+        "from dlrover_tpu import obs\n"
+        "from dlrover_tpu.common import jax_env\n"
+        "assert jax_env.enable_compilation_cache()\n"
+        "jax_env.device_summary()\n"
+        "jax_env.install_compile_listener()\n"  # a third time
+        "@jax.jit\n"
+        "def stepfn(x):\n"
+        "    for i in range(60):\n"
+        "        x = jnp.tanh(x * (i + 1.5)) + jnp.cumsum(x)\n"
+        "    return x\n"
+        "x = jnp.ones(8)\n"
+        "jax.block_until_ready(x)\n"
+        "with obs.span('outer', 'ut'):\n"
+        "    stepfn(x)\n"
+        "jax.clear_caches()\n"
+        "stepfn(x)\n"
+        "before = obs.get_recorder().stats()['spans']\n"
+        "for _ in range(10):\n"
+        "    x = stepfn(x)\n"
+        "jax.block_until_ready(x)\n"
+        "fired = obs.get_recorder().stats()['spans'] - before\n"
+        "evs, dropped, _ = obs.get_recorder().snapshot()\n"
+        "print(json.dumps({'events': evs, 'dropped': dropped, "
+        "'fired_warm': fired}))\n"
+    )
+
+    @pytest.fixture(scope="class")
+    def ran(self, tmp_path_factory):
+        tmp = tmp_path_factory.mktemp("stages")
+        res = _run_python(
+            self.CODE, JAX_COMPILATION_CACHE_DIR=str(tmp / "cache"),
+            DLROVER_TPU_COMPILE_CACHE="1",
+            DLROVER_TPU_OBS_DIR=str(tmp / "obs"),
+            DLROVER_TPU_OBS_PROCESS="ut")
+        assert res.returncode == 0, res.stderr[-2000:]
+        out = json.loads(res.stdout.strip().splitlines()[-1])
+        (dump,) = load_dir(str(tmp / "obs"))
+        return out, dump
+
+    @staticmethod
+    def _of(evs, stage, fun):
+        return [e for e in evs if e.get("name") == stage
+                and e["args"]["fun_name"] in (fun, f"jit({fun})")]
+
+    def test_first_call_records_three_stages_and_a_miss(self, ran):
+        evs = ran[0]["events"]
+        traced, again = self._of(evs, "jax.trace", "stepfn")
+        lowered, _ = self._of(evs, "jax.lower", "stepfn")
+        compiled, _ = self._of(evs, "jax.compile", "stepfn")
+        assert traced["cat"] == lowered["cat"] == compiled["cat"] == "jax"
+        assert compiled["args"]["cache_hit"] is False
+        assert "retrieval_s" not in compiled["args"]
+        # in the order JAX runs them, none inside another
+        assert (traced["ts"] + traced["dur"] <= lowered["ts"] + 0.2
+                and lowered["ts"] + lowered["dur"] <= compiled["ts"] + 0.2)
+
+    def test_after_clear_caches_the_compile_is_a_cache_read(self, ran):
+        _, hit = self._of(ran[0]["events"], "jax.compile", "stepfn")
+        assert hit["args"]["cache_hit"] is True
+        assert hit["args"]["retrieval_s"] > 0
+        assert "saved_s" in hit["args"]
+
+    def test_a_compile_inside_an_open_span_is_its_child(self, ran):
+        evs = ran[0]["events"]
+        (outer,) = [e for e in evs if e.get("name") == "outer"]
+        for stage in ("jax.trace", "jax.lower", "jax.compile"):
+            first, second = self._of(evs, stage, "stepfn")
+            assert first["psid"] == outer["sid"], stage
+            assert "psid" not in second, stage
+
+    def test_installing_again_records_each_event_once(self, ran):
+        """``enable_compilation_cache``, ``device_summary`` and a direct
+        call all install: still one span a stage and call."""
+        evs = ran[0]["events"]
+        for stage in ("jax.trace", "jax.lower", "jax.compile"):
+            assert len(self._of(evs, stage, "stepfn")) == 2, stage
+
+    def test_ten_warm_calls_fire_the_listener_zero_times(self, ran):
+        assert ran[0]["fired_warm"] == 0
+        assert ran[0]["dropped"] == 0
+
+    def test_durations_are_spans_on_the_monotonic_clock(self, ran):
+        evs = ran[0]["events"]
+        (outer,) = [e for e in evs if e.get("name") == "outer"]
+        stages = [e for e in evs if e.get("cat") == "jax"]
+        assert stages and all(e["dur"] > 0 for e in stages)
+        for e in stages:
+            if e.get("psid") == outer["sid"]:
+                assert _inside(e, outer), e
+        # a trace holds the traces of what it called: they nest
+        outer_trace = self._of(evs, "jax.trace", "stepfn")[0]
+        nested = [e for e in stages if e["name"] == "jax.trace"
+                  and e is not outer_trace and _inside(e, outer_trace)]
+        assert all(e["dur"] < outer_trace["dur"] for e in nested)
+
+    def test_short_traces_are_not_recorded(self, ran):
+        from dlrover_tpu.common.jax_env import MIN_TRACE_SPAN_S
+
+        traces = [e for e in ran[0]["events"]
+                  if e.get("name") == "jax.trace"]
+        assert all(e["dur"] >= MIN_TRACE_SPAN_S * 1e6 - 1 for e in traces)
+
+    def test_stage_spans_are_journalled_as_they_end(self, ran):
+        out, dump = ran
+        on_disk = [e["sid"] for e in dump["events"]
+                   if e.get("cat") == "jax"]
+        assert on_disk == [e["sid"] for e in out["events"]
+                           if e.get("cat") == "jax"]
 
 
 class TestBootstrapSpans:
