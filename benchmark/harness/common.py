@@ -127,6 +127,21 @@ def memory_peak_bytes() -> int:
         for d in jax.local_devices())
 
 
+def less_parts(name: str, total: float, **parts: float):
+    """``setup_s`` and ``resume_s`` judge the tree's own seconds: the host
+    clock's total less the parts no tree can move (the backend's start-up)
+    or that are the benchmark's own (its comparison with the reference).
+    Returns the metric and the note line that shows the whole account —
+    ``SETUP_S 9.930412 total=22.970113 backend_open_s=7.860001
+    check_s=5.179700`` — so that the old series (the total) stays readable
+    in every run's output and the metric is the printed total less the
+    printed parts."""
+    value = total - sum(parts.values())
+    return value, " ".join(
+        [f"{name} {value:.6f}", f"total={total:.6f}"]
+        + [f"{k}={v:.6f}" for k, v in parts.items()])
+
+
 def print_result(result: dict) -> None:
     """The last line of standard output: one JSON object."""
     sys.stdout.flush()

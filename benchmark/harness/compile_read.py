@@ -4,9 +4,12 @@ function it traces, lowers and compiles or reads from the persistent cache
 (``dlrover_tpu/common/jax_env.py::install_compile_listener``), each with
 ``fun_name``, ``jax.compile`` with the cache's verdict (``cache_hit``).
 
-All of them are summed over the whole process (in the elastic cell: the
-resumed incarnation): a correct run has no compile inside its window, so
-what is there is set-up.  ``jax.trace`` nests (a traced function's inner
+The four ``compile.*`` metrics move ``setup_s`` and so read only the stages
+with an ``accelerate.*`` span among their ancestors (the program's build,
+state and first step; in the elastic cell of the resumed incarnation): the
+comparison's programs are the harness's and lie outside ``setup_s``.  A
+correct run has no compile inside its window, so what is there is set-up.
+``jax.trace`` nests (a traced function's inner
 jits and primitives report their own), so seconds are the union of the
 intervals, never the sum of the durations.
 
@@ -32,12 +35,6 @@ def _stages(recs: Iterable[dict], names=STAGES) -> List[dict]:
             if r.get("k") == "span" and r.get("name") in names]
 
 
-def stage_spans(spans: dict, *names: str) -> List[dict]:
-    """The newest incarnation's spans of these stages (all three where
-    none is named)."""
-    return _stages(_records(spans), names or STAGES)
-
-
 def covered_s(spans_: Iterable[dict]) -> Optional[float]:
     """Seconds that at least one of the spans covers; None of no span."""
     spans_ = sorted(spans_, key=lambda s: s["ts"])
@@ -58,10 +55,14 @@ def missed(spans_: Iterable[dict]) -> List[dict]:
             and (s.get("args") or {}).get("cache_hit") is False]
 
 
-def outside_build(spans: dict) -> Optional[List[dict]]:
-    """The newest incarnation's stage spans none of whose ancestors is an
-    ``accelerate.*`` span: what code other than the program's own build,
-    state and first step caused.  None where it recorded no stage."""
+def by_cause(spans: dict) -> tuple:
+    """The newest incarnation's stage spans as ``(inside, outside)``: those
+    with an ``accelerate.*`` span among their ancestors — the program's own
+    build, state and first step, which ``setup_s`` holds and the four
+    ``compile.*`` metrics read — and those with none: the harness's
+    comparison against the reference (taken out of ``setup_s`` as
+    ``check_s``) or a user's own jits, which only the ``COMPILES`` line
+    shows."""
     recs = _records(spans)
     by_sid = {r["sid"]: r for r in recs if r.get("k") == "span"}
 
@@ -74,20 +75,34 @@ def outside_build(spans: dict) -> Optional[List[dict]]:
                 return True
         return False
 
-    stages = _stages(recs)
-    if not stages:
+    inside, outside = [], []
+    for s in _stages(recs):
+        (inside if caused_by_the_build(s) else outside).append(s)
+    return inside, outside
+
+
+def build_stages(spans: dict, *names: str) -> Optional[List[dict]]:
+    """The spans of these stages that the program's own build caused; None
+    where the program recorded no span of these stages at all."""
+    inside, outside = by_cause(spans)
+    if not _stages(inside + outside, names):
         return None
-    return [s for s in stages if not caused_by_the_build(s)]
+    return _stages(inside, names)
 
 
-def print_compiles(spans_: List[dict]) -> None:
-    """One line: how many stage spans, how many misses, then the five
-    longest and every miss as ``fun_name:stage=seconds[:miss]``."""
+def print_compiles(inside: List[dict], outside: List[dict]) -> None:
+    """One line over EVERY stage span, whatever caused it: how many, how
+    many misses, the seconds of those no ``accelerate.*`` span encloses
+    (``outside_build_s``, the union of their intervals: the comparison's
+    programs traced, lowered, compiled or read), then the five longest and
+    every miss as ``fun_name:stage=seconds[:miss]``."""
+    spans_ = inside + outside
     misses = {s["sid"] for s in missed(spans_)}
     by_length = sorted(spans_, key=lambda s: -s["dur"])
     shown = [s for i, s in enumerate(by_length)
              if i < 5 or s["sid"] in misses]
-    print(f"COMPILES n={len(spans_)} misses={len(misses)} " + " ".join(
+    print(f"COMPILES n={len(spans_)} misses={len(misses)} "
+          f"outside_build_s={covered_s(outside) or 0.0:.3f} " + " ".join(
         "{}:{}={:.3f}{}".format(
             str((s.get("args") or {}).get("fun_name", "")).replace(" ", "_"),
             s["name"].split(".", 1)[1], s["dur"] * 1e-6,

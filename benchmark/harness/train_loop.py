@@ -34,15 +34,22 @@ class TrainSession:
 
     # -- set-up -------------------------------------------------------------
     def open_device(self) -> dict:
-        """First touch of JAX's backend: the process takes the chip."""
+        """First touch of JAX's backend: the process takes the chip.
+        ``backend_open_s`` is that call alone, the runtime's own start-up,
+        which no tree can move: the runners take it out of ``setup_s`` and
+        ``resume_s`` and print it beside them.  What precedes it in
+        ``device_open_s`` (imports, the compile cache's set-up) stays in."""
         from dlrover_tpu.common.jax_env import (
             device_summary,
             enable_compilation_cache,
         )
 
         enable_compilation_cache()
+        t0 = time.monotonic()
         summary = device_summary()
-        self.spans["device_open_s"] = time.monotonic() - self.t_proc_start
+        t1 = time.monotonic()
+        self.spans["backend_open_s"] = t1 - t0
+        self.spans["device_open_s"] = t1 - self.t_proc_start
         return summary
 
     def build(self) -> None:

@@ -1,7 +1,9 @@
-"""Step builder: seconds the process spent tracing functions to jaxprs and
-lowering them to MLIR — the union of its ``jax.trace`` and ``jax.lower``
-spans (JAX's ``jaxpr_trace_duration`` and ``jaxpr_to_mlir_module_duration``,
-recorded by ``common/jax_env.py``'s listener); in the elastic cell of the
+"""Step builder: seconds the program's build spent tracing functions to
+jaxprs and lowering them to MLIR — the union of the ``jax.trace`` and
+``jax.lower`` spans (JAX's ``jaxpr_trace_duration`` and
+``jaxpr_to_mlir_module_duration``, recorded by ``common/jax_env.py``'s
+listener) under an ``accelerate.*`` span; the harness's comparison programs
+are left out, as they are out of ``setup_s``; in the elastic cell of the
 resumed incarnation.  Host work that no cache saves."""
 from benchmark.harness import compile_read
 
@@ -11,4 +13,4 @@ SOURCE = "program_span"
 
 def read(spans, trace, counters):
     return compile_read.covered_s(
-        compile_read.stage_spans(spans, "jax.trace", "jax.lower"))
+        compile_read.build_stages(spans, "jax.trace", "jax.lower") or [])

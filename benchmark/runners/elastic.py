@@ -11,6 +11,13 @@ goodput: both bounded metrics of this cell are over before it opens), then
 ends the whole process tree, checks the persisted checkpoint with
 ``checkpoint.fsck`` and removes what the run left in ``/dev/shm`` and on disk
 (the job's journal directory too; a traced run's after its readers).
+
+Both metrics leave out the runtime's own start-up, which no tree can move:
+each worker stamps its first ``jax.devices()`` as ``backend_open_s`` on its
+``device`` line, ``resume_s`` is the parent's clock from the kill to that
+step LESS the restarted worker's, ``setup_s`` the parent's clock from its
+own start to the window's opening LESS both workers'.  The ``RESUME`` and
+``SETUP_S`` lines print each total and what was taken out.
 """
 
 from __future__ import annotations
@@ -42,6 +49,16 @@ WAIT_S = {"start": 120, "device": 180, "step": 900, "restored": 900,
 #: the v5e (PR 22); 1e-6 relative allows nothing but a changed last digit.
 REPLAY_REL_TOL = 1e-6
 MARK = "DLROVER_BENCH_RUN"
+#: the agent's log lines between the kill and the new worker's first line,
+#: by the parent's clock as they arrive; printed on the ``RESTART`` line as
+#: seconds after the kill (a reworded line prints None), read by no metric
+#: but ``ckpt.persist_s`` (persisting -> stopped)
+RESTART_MARKS = {
+    "failure_seen": r"worker failure\(s\)",
+    "persisting": r"breakpoint save \(.*persisting",
+    "stopped": r"stopped workers \(",
+    "started": r"started \d+ worker\(s\)",
+}
 
 
 class Lines:
@@ -152,6 +169,29 @@ def _arenas(job: str) -> list:
     return glob.glob(f"/dev/shm/dlrtpu_{job}[-_]*")
 
 
+def _watch_exit(pid: int, t_kill: float, seen: dict) -> None:
+    """Stamps ``worker_exit``: the parent's clock from its SIGKILL until
+    /proc shows the worker dead, and how (``Z``: a zombie for the agent to
+    reap; ``gone``: reaped already).  Beside the agent's ``failure_seen``
+    on the ``RESTART`` line it says whether the seconds before the agent
+    sees an exit code are the kernel's or the agent's; taken out of
+    nothing."""
+    def poll():
+        while time.monotonic() - t_kill < WAIT_S["restored"]:
+            try:
+                with open(f"/proc/{pid}/stat") as f:
+                    state = f.read().rsplit(")", 1)[1].split()[0]
+            except (OSError, IndexError):
+                state = "gone"
+            if state in ("Z", "X", "gone"):
+                seen["worker_exit"] = (
+                    f"{time.monotonic() - t_kill:.3f}({state})")
+                return
+            time.sleep(0.02)
+
+    threading.Thread(target=poll, daemon=True).start()
+
+
 def run(cell: dict, args, t_start: float) -> dict:
     traffic = cell["traffic_data"]
     work = os.path.join(common.WORK_DIR, cell["name"])
@@ -244,6 +284,8 @@ def _drive(cell, args, t_start, lines, mark) -> dict:
             break
     t_kill = time.monotonic()
     os.kill(first["pid"], signal.SIGKILL)
+    marks = {}
+    _watch_exit(first["pid"], t_kill, marks)
     setup_save = [e for e in lines.seen if e["kind"] == "save"]
 
     # -- incarnation 1: restore, first step, replay, window -----------------
@@ -253,25 +295,31 @@ def _drive(cell, args, t_start, lines, mark) -> dict:
     restored = lines.expect("restored", WAIT_S["restored"])
     losses1 = {}
     ev = lines.expect("step", WAIT_S["step"], first=True)
-    resume_s = ev["t"] - t_kill
+    resume_s, resume_note = common.less_parts(
+        "RESUME", ev["t"] - t_kill, backend_open_s=dev1["backend_open_s"])
     losses1[ev["n"]] = ev["loss"]
     while ev["n"] < kill_at:
         ev = lines.expect("step", WAIT_S["step"])
         losses1[ev["n"]] = ev["loss"]
     opened = lines.expect("window_open", WAIT_S["window_open"])
-    setup_s = opened["t"] - t_start
+    setup_s, setup_note = common.less_parts(
+        "SETUP_S", opened["t"] - t_start,
+        backend_open_s_0=dev0["backend_open_s"],
+        backend_open_s_1=dev1["backend_open_s"])
     # while the window runs: nobody but the worker may hold the chip
     others = [p.pid for p in _marked_pids(mark) if p.pid != second["pid"]]
     holders = [pid for pid in others if _holds_device(pid)]
     worker_holds = _holds_device(second["pid"]) or args.rehearse
     res = lines.expect("result", args.seconds + 240)
 
-    t_bp = lines.first_time(r"breakpoint save \(.*persisting", after=t_kill)
-    t_stopped = lines.first_time(r"stopped workers \(", after=t_kill)
-    if t_bp is not None and t_stopped is not None:
-        spans["persist_s"] = t_stopped - t_bp
+    for name, pattern in RESTART_MARKS.items():
+        t = lines.first_time(pattern, after=t_kill)
+        marks[name] = None if t is None else t - t_kill
+    if marks["persisting"] is not None and marks["stopped"] is not None:
+        spans["persist_s"] = marks["stopped"] - marks["persisting"]
     spans.update(res["spans"])
     spans["device_open_s"] = dev1["device_open_s"]
+    spans["backend_open_s"] = dev1["backend_open_s"]
     if setup_save:
         spans["first_save_s"] = setup_save[0]["stall_s"]
 
@@ -309,12 +357,16 @@ def _drive(cell, args, t_start, lines, mark) -> dict:
         f"DEVICE {dev1['summary']}",
         f"PROGRAM {res['program']} memory {res['memory']}",
         f"SETUP first save (first touch) {setup_save[:1]}",
-        f"SETUP_S {setup_s:.3f}",
-        f"RESUME resume_s={resume_s:.3f} agent_restart_s="
+        setup_note,
+        f"{resume_note} agent_restart_s="
         f"{spans['agent_restart_s']:.3f} persist_s="
         f"{spans.get('persist_s')} device_open_s="
         f"{spans['device_open_s']:.3f} build_s={spans['build_s']:.3f} "
         f"restore_s={spans['restore_s']:.3f}",
+        "RESTART seconds after the kill: " + " ".join(
+            f"{k}={round(v, 3) if isinstance(v, float) else v}"
+            for k, v in list(marks.items()))
+        + f" first_line={spans['agent_restart_s']:.3f}",
         f"REPLAY steps {replayed} worst relative loss difference "
         f"{replay_rel:.3g}: first {[losses0[n] for n in replayed]} "
         f"second {[losses1[n] for n in replayed]}",

@@ -70,7 +70,6 @@ def test_a_line_cut_by_the_kill_is_skipped(journal):
     ("ckpt.restore_put_s", 35.0),
     ("ckpt.persist_write_s", 50.0),  # the commit ran beside the restart
     ("agent.restart_overhead_s", 8.0),  # restart 60 - persist 52
-    ("bootstrap.backend_init_s", 6.0),  # incarnation 1's, not 0's 9
     ("accelerate.compile_s", 3.5),  # compile 1.5 + first call 2, inc. 1
 ])
 def test_span_metrics_on_the_recorded_journal(journal, metric, want):
@@ -80,9 +79,8 @@ def test_span_metrics_on_the_recorded_journal(journal, metric, want):
 @pytest.mark.parametrize("metric", [
     "ckpt.first_save_d2h_s", "ckpt.first_save_write_s",
     "ckpt.restore_read_s", "ckpt.restore_put_s", "ckpt.persist_write_s",
-    "agent.restart_overhead_s", "bootstrap.backend_init_s",
-    "accelerate.compile_s", "step.lm_head_share_pct",
-    "step.optimizer_share_pct"])
+    "agent.restart_overhead_s", "accelerate.compile_s",
+    "step.lm_head_share_pct", "step.optimizer_share_pct"])
 def test_nothing_recorded_reads_as_nothing(tmp_path, monkeypatch, metric):
     """The parent of the PR that added the spans: no journal directory,
     nothing in the ring."""
@@ -100,17 +98,28 @@ def test_one_process_run_reads_the_ring(tmp_path, monkeypatch):
     monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
     obs.configure()
     try:
-        obs.get_recorder().span("bootstrap.backend_init", "bootstrap",
-                                10.0, 14.0, args={"first": True})
-        obs.get_recorder().span("bootstrap.backend_init", "bootstrap",
-                                20.0, 20.5, args={"first": False})
+        obs.get_recorder().span("accelerate.create_state", "accelerate",
+                                10.0, 14.0)
         obs.get_recorder().span("accelerate.compile", "accelerate",
                                 30.0, 31.0)
-        assert _read("bootstrap.backend_init_s") == pytest.approx(4.0)
+        assert _read("state.create_s") == pytest.approx(4.0)
         assert _read("accelerate.compile_s") == pytest.approx(1.0)
         assert _read("ckpt.restore_read_s") is None
     finally:
         obs.reset()
+
+
+def test_device_open_is_what_precedes_the_backend():
+    """``bootstrap.device_open_s`` moves ``setup_s`` and ``resume_s``, which
+    leave the first ``jax.devices()`` out: it reads the stamp less that
+    call (imports, the compile cache's set-up), and nothing where a runner
+    handed over only one of the two."""
+    assert _read("bootstrap.device_open_s", spans={
+        "device_open_s": 10.865, "backend_open_s": 7.857}) == (
+        pytest.approx(3.008))
+    assert _read("bootstrap.device_open_s", spans=RAN) is None
+    assert _read("bootstrap.device_open_s",
+                 spans={"backend_open_s": 7.857}) is None
 
 
 TRACE = {"busy_s": 10.0, "op_self_s": {

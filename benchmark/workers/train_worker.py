@@ -66,7 +66,8 @@ def main() -> int:
         emit("refused", why=str(e))
         return 3
     emit("device", summary=summary,
-         device_open_s=sess.spans["device_open_s"])
+         device_open_s=sess.spans["device_open_s"],
+         backend_open_s=sess.spans["backend_open_s"])
     compiles = common.CompileCounter()
     sess.build()
     sess.create_state()
