@@ -28,7 +28,10 @@ from dlrover_tpu.ops.cross_entropy import (
     linear_softmax_cross_entropy_sum,
     softmax_cross_entropy,
 )
-from dlrover_tpu.ops.flash_attention import flash_attention
+from dlrover_tpu.ops.flash_attention import (
+    SAVED_NAMES as FLASH_SAVED_NAMES,
+    flash_attention,
+)
 from dlrover_tpu.ops.grouped_matmul import grouped_matmul_ragged
 from dlrover_tpu.ops.rmsnorm import rmsnorm
 
@@ -66,11 +69,16 @@ class LlamaConfig:
     # `sliding_window` positions only — Mistral-style long-context;
     # flash path only, kernels skip out-of-window blocks).
     sliding_window: int = 0
-    # Per-block rematerialization: save only the residual stream at layer
-    # boundaries, recompute attention/MLP internals in the backward pass.
-    # Far better peak-HBM than whole-loss remat policies, which either
-    # save every dot output (``dots_saveable``) or re-run a forward whose
-    # own intermediates still peak the same (``nothing_saveable``).
+    # Per-block rematerialization: save the residual stream at layer
+    # boundaries and the flash kernel's output and log-sum-exp, recompute
+    # the projections' and the MLP's internals in the backward pass.  The
+    # kernel's two outputs cost as much to recompute as they cost to
+    # compute and are small to keep: per block application
+    # ``B*S*(D + H*Dv)`` bf16 and ``4*B*H*S`` bytes stay, twice what the
+    # stream alone takes where ``H*Dv == D``.  Far better peak-HBM than
+    # whole-loss remat policies, which either save every dot output
+    # (``dots_saveable``) or re-run a forward whose own intermediates
+    # still peak the same (``nothing_saveable``).
     remat_block: bool = False
     # A looped model (Ouro, arXiv:2510.25741): the whole layer stack runs
     # ``loop_passes`` times on the SAME weights; every pass ends in the
@@ -988,7 +996,8 @@ def forward_hidden(
     the T normed streams stacked, ``[T, B, S, D]`` (pass t's is pass
     t+1's input), with ``aux["exit_logits"]`` (float32 ``[T, B, S]``, the
     exit gate's logit on each).  Each block APPLICATION is rematerialised
-    and named ``block_out`` on its own.
+    (keeping its flash kernel's output and log-sum-exp) and named
+    ``block_out`` on its own.
 
     With ``cfg.mtp_layers`` and ``next_tokens`` ([B, S], token i+1 under
     position i: the targets) the multi-token-prediction block runs too
@@ -1032,7 +1041,10 @@ def forward_hidden(
         segment_ids=segment_ids,
     )
     if cfg.remat_block:
-        apply = jax.checkpoint(apply, static_argnums=(2,))
+        apply = jax.checkpoint(
+            apply, static_argnums=(2,),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *FLASH_SAVED_NAMES))
     new_fp8 = [] if fp8_states is not None else None
     streams, exit_logits = [], []
     for _ in range(cfg.loop_passes):

@@ -203,7 +203,9 @@ def strategy_loss_builder(cfg: LlamaConfig, *, devices=None,
     the MODEL the way the reference's opt_lib transforms do.
 
     - ``remat == "block"`` -> ``cfg.remat_block=True`` (per-block
-      checkpointing inside the model);
+      checkpointing inside the model: the stream at block boundaries
+      and the flash kernel's output and log-sum-exp are kept, the rest
+      of a block is recomputed);
     - ``mesh.pp > 1`` -> the GPipe pipelined loss over the candidate's
       own mesh (so the BO search can genuinely score pipeline points
       instead of treating the pp axis as replication);

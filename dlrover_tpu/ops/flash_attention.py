@@ -19,6 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.ops.per_shard import P, per_shard, shard_axes
 
@@ -45,6 +46,11 @@ DEFAULT_BLOCK_K = _env_block("DLROVER_TPU_FLASH_BLOCK_K", 512)
 DEFAULT_BWD_BLOCK_Q = _env_block("DLROVER_TPU_FLASH_BWD_BLOCK_Q", 256)
 DEFAULT_BWD_BLOCK_K = _env_block("DLROVER_TPU_FLASH_BWD_BLOCK_K", 512)
 NEG_INF = -1e30
+# The forward kernel's two outputs as the backward rule receives them,
+# under names a remat policy can keep (``models/llama.py``'s block remat
+# does: the kernel then runs once per block application, not again in
+# front of the block's backward).  Identities under any other policy.
+SAVED_NAMES = ("flash_out", "flash_lse")
 
 
 # ---------------------------------------------------------------------------
@@ -636,10 +642,15 @@ def _flash_attention(q, k, v, causal, block_q, block_k, bwd_block_q,
     return out
 
 
+def _name_saved(out, lse):
+    return (checkpoint_name(out, SAVED_NAMES[0]),
+            checkpoint_name(lse, SAVED_NAMES[1]))
+
+
 def _fwd_rule(q, k, v, causal, block_q, block_k, bwd_block_q, bwd_block_k,
               interpret, window):
-    out, lse = _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
-                          window=window)
+    out, lse = _name_saved(*_flash_fwd(
+        q, k, v, causal, block_q, block_k, interpret, window=window))
     return out, (q, k, v, out, lse)
 
 
@@ -673,10 +684,10 @@ def _flash_attention_seg(q, k, v, seg, causal, block_q, block_k,
 
 def _seg_fwd_rule(q, k, v, seg, causal, block_q, block_k, bwd_block_q,
                   bwd_block_k, interpret, window):
-    out, lse = _flash_fwd(
+    out, lse = _name_saved(*_flash_fwd(
         q, k, v, causal, block_q, block_k, interpret, segment_ids=seg,
         window=window,
-    )
+    ))
     return out, (q, k, v, seg, out, lse)
 
 

@@ -391,10 +391,11 @@ def test_small_300m_loss_grad_on_fsdp2_tp2(topo, monkeypatch):
 
 
 def test_looped_step_tells_block_applications_from_layers(topo, monkeypatch):
-    """One layer run twice with block remat: the kernel counts alone (four
-    ``flash_fwd``: two forward, two recomputed) cannot say how often a
-    token meets a block; ``block_applications`` does, and every scope of
-    the looped loss is in the compiled step's table."""
+    """One layer run twice with block remat: ``block_applications`` says
+    how often a token meets a block, the remat keeps each application's
+    flash output and log-sum-exp, so the kernel runs as often and no more
+    (the projections and the MLP are what is recomputed), and every scope
+    of the looped loss is in the compiled step's table."""
     import optax
 
     from dlrover_tpu.models import llama
@@ -414,7 +415,7 @@ def test_looped_step_tells_block_applications_from_layers(topo, monkeypatch):
     )
     kernels = job.program["kernels"]
     assert (kernels["flash_fwd"], kernels["flash_bwd_dq"],
-            kernels["flash_bwd_dkv"]) == (4, 2, 2)
+            kernels["flash_bwd_dkv"]) == (2, 2, 2)
     assert job.program["block_applications"] == cfg.block_applications == 2
     found = {tuple(v) for v in job.program["scopes"].values()}
     assert {("forward", "exit_gate"), ("backward", "exit_gate"),
@@ -461,7 +462,7 @@ def test_latent_share_mtp_step_compiles_at_published_widths(topo, monkeypatch):
     )
     kernels = job.program["kernels"]
     assert (kernels["flash_fwd"], kernels["flash_bwd_dq"],
-            kernels["flash_bwd_dkv"]) == (6, 3, 3)
+            kernels["flash_bwd_dkv"]) == (3, 3, 3)
     assert job.program["block_applications"] == cfg.block_applications == 3
     # two routed blocks: three grouped matmuls forward, recomputed and for
     # the row gradients, three weight gradients

@@ -208,10 +208,14 @@ class TestChipSmokeContract:
         assert res["ok"] is True
         assert res["device"]["platform"] == "cpu"
 
-    def test_mesh4_phase_tiny_with_kernels_per_shard(self, monkeypatch):
+    @pytest.mark.parametrize("remat_block", [False, True])
+    def test_mesh4_phase_tiny_with_kernels_per_shard(self, monkeypatch,
+                                                     remat_block):
         """fsdp2 x tp2 against one device, the model's kernels steered to
         Pallas in interpret mode (here, in the test: on the CPU the
-        dispatchers would pick the references and never meet the mesh)."""
+        dispatchers would pick the references and never meet the mesh);
+        with block remat the policy that keeps the flash kernel's two
+        outputs reaches them inside the kernels' ``shard_map``."""
         monkeypatch.setattr(
             llama, "flash_attention",
             lambda q, k, v, backend=None, **kw: fa.flash_attention(
@@ -224,7 +228,8 @@ class TestChipSmokeContract:
             llama, "softmax_cross_entropy",
             lambda lg, y, **kw: ce.softmax_cross_entropy(
                 lg, y, backend="pallas", interpret=True, **kw))
-        cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)  # GQA: 4 q, 2 kv
+        cfg = llama.LlamaConfig.tiny(  # GQA: 4 q, 2 kv
+            dtype=jnp.float32, remat_block=remat_block)
         res = chip_smoke.mesh4_phase(
             cfg, batch=4, seq=32, steps=3, rel_tol=1e-4)
         assert res["ok"] is True

@@ -487,6 +487,34 @@ ENTRY %main.9 (a: f32[4]) -> f32[4] {
             "while.4": ["backward", "lm_head_loss"],
         }
 
+    def test_program_summary_of_a_remat_that_keeps_the_kernels_outputs(self):
+        """The op_names of two block applications as the chip's compiler
+        writes them (``tests/test_aot_compile.py`` compiles the real
+        thing) where block remat keeps the flash kernel's output and
+        log-sum-exp: no ``flash_fwd`` sits in ``rematted_computation``, and
+        ``kernels.flash_fwd == block_applications`` — the condition under
+        which ``step.recompute_share_pct``'s reader adds nothing for the
+        kernel (``tests/test_llama_looped.py`` has the remat that runs it
+        twice)."""
+        from dlrover_tpu.parallel.accelerate import (
+            phase_and_scope,
+            program_summary,
+        )
+
+        call = ('  %k.{n} = bf16[8] custom-call(%a), custom_call_target='
+                '"tpu_custom_call", metadata={{op_name="jit(train_step)/'
+                '{path}/pallas_call"}}')
+        paths = ["jvp(attention)/flash_fwd"] * 2 + [
+            "transpose(jvp(jvp()))/checkpoint/attention/" + bwd
+            for bwd in ("flash_bwd_dq", "flash_bwd_dkv") * 2]
+        got = program_summary("\n".join(
+            call.format(n=n, path=path) for n, path in enumerate(paths)))
+        assert got["kernels"] == {
+            "flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
+        assert got["block_applications"] == got["kernels"]["flash_fwd"]
+        assert [phase_and_scope(f"jit(train_step)/{path}/pallas_call")[0]
+                for path in paths] == ["forward"] * 2 + ["backward"] * 4
+
 
 class TestJaxStageSpans:
     """JAX's own trace, lower and compile events as spans
