@@ -27,6 +27,7 @@ from dlrover_tpu.models.llama import (
     LlamaConfig,
     refuse_latent,
     refuse_looped,
+    refuse_ssm,
 )
 
 
@@ -178,6 +179,7 @@ def _build_params(
     tied-embedding fallback, bias rejection live HERE only)."""
     refuse_looped(cfg, "the HF Llama layout table (models.hf_convert)")
     refuse_latent(cfg, "the HF Llama layout table (models.hf_convert)")
+    refuse_ssm(cfg, "the HF Llama layout table (models.hf_convert)")
     bias_keys = [k for k in all_keys() if k.endswith(".bias")]
     if bias_keys:
         raise ValueError(

@@ -34,6 +34,7 @@ from dlrover_tpu.ops.flash_attention import (
 )
 from dlrover_tpu.ops.grouped_matmul import grouped_matmul_ragged
 from dlrover_tpu.ops.rmsnorm import rmsnorm
+from dlrover_tpu.ops.ssd import causal_conv1d, ssd_chunked
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,6 +149,42 @@ class LlamaConfig:
     # output, predicts ``t_{i+2}`` through the shared head
     # (:func:`forward_hidden`, :func:`loss_fn`).
     mtp_layers: int = 0
+    # The kind of each layer, "attention" or "mamba" (a tuple of
+    # ``n_layer`` names; empty: every layer is an attention layer).  A
+    # "mamba" layer's mixer is the Mamba-2 one (:func:`_ssm_mixer`,
+    # ``ops.ssd``) in place of attention, under the same pre-norm, residual
+    # add and dense SwiGLU: ``mamba_n_heads`` heads of ``mamba_d_head``
+    # (together ``mamba_expand * d_model`` wide), a state of
+    # ``mamba_d_state`` per head dim, B and C in ``mamba_n_groups`` groups,
+    # a causal depthwise convolution ``mamba_d_conv`` wide (with a bias
+    # where ``mamba_conv_bias``) and chunks of ``mamba_chunk_size``
+    # positions.  ``mamba_proj_bias`` must stay False: the two projections
+    # have no bias here.
+    layer_types: tuple = ()
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    # False: attention without rotary position (``position_embedding_type:
+    # "nope"``).
+    rope: bool = True
+    # The softmax scale of attention; None: ``1 / sqrt(head_dim)``.
+    attention_multiplier: Optional[float] = None
+    # Scalars on the stream (Granite): the embedding's rows times
+    # ``embedding_multiplier``, every branch added as ``x +
+    # residual_multiplier * branch(norm(x))``, the logits divided by
+    # ``logits_scaling``.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # The head reads ``embed`` transposed: ONE leaf, no ``lm_head``, and
+    # the gradient of ``embed`` is the sum of both uses.
+    tie_word_embeddings: bool = False
 
     def __post_init__(self):
         if (self.loop_passes > 1) != (self.exit_gate_beta is not None):
@@ -198,6 +235,59 @@ class LlamaConfig:
                 f"loop_passes={self.loop_passes}: one prediction block "
                 "after a stack that runs once is what is built")
 
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        kinds = self.layer_types
+        if kinds and (len(kinds) != self.n_layer
+                      or set(kinds) - {"attention", "mamba"}):
+            raise ValueError(
+                f"LlamaConfig: layer_types={kinds} is not n_layer="
+                f"{self.n_layer} names out of 'attention' and 'mamba'")
+        if self.ssm_layers:
+            if (self.mamba_n_heads * self.mamba_d_head
+                    != self.mamba_expand * self.d_model
+                    or min(self.mamba_d_state, self.mamba_d_conv,
+                           self.mamba_chunk_size, self.mamba_n_groups) <= 0
+                    or self.mamba_n_heads % self.mamba_n_groups
+                    or self.mamba_proj_bias):
+                raise ValueError(
+                    f"LlamaConfig: a 'mamba' layer needs mamba_n_heads x "
+                    f"mamba_d_head ({self.mamba_n_heads} x "
+                    f"{self.mamba_d_head}) == mamba_expand x d_model "
+                    f"({self.mamba_expand} x {self.d_model}), heads that "
+                    f"mamba_n_groups={self.mamba_n_groups} divides, "
+                    "positive mamba_d_state, mamba_d_conv and "
+                    "mamba_chunk_size, and mamba_proj_bias False")
+            if self.num_experts > 0 or self.loop_passes > 1 or (
+                    self.mtp_layers):
+                raise ValueError(
+                    f"LlamaConfig: 'mamba' layers with num_experts="
+                    f"{self.num_experts}, loop_passes={self.loop_passes} or "
+                    f"mtp_layers={self.mtp_layers}: a state-space layer's "
+                    "MLP is dense and the stack runs once, with no "
+                    "prediction block")
+
+    @property
+    def ssm_layers(self) -> int:
+        """Layers whose mixer is the state-space one."""
+        return sum(kind == "mamba" for kind in self.layer_types)
+
+    @property
+    def attention_layers(self) -> int:
+        return self.n_layer - self.ssm_layers
+
+    def is_ssm_layer(self, i: int) -> bool:
+        return bool(self.layer_types) and self.layer_types[i] == "mamba"
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the convolution: x, B and C side by side."""
+        return (self.mamba_d_inner
+                + 2 * self.mamba_n_groups * self.mamba_d_state)
+
     @property
     def head_dim(self) -> int:
         if self.kv_lora_rank > 0:
@@ -206,9 +296,9 @@ class LlamaConfig:
 
     @property
     def block_applications(self) -> int:
-        """Blocks a token passes through: layers x passes, and the
-        multi-token-prediction block."""
-        return self.n_layer * self.loop_passes + self.mtp_layers
+        """Attention blocks a token passes through: the attention layers x
+        passes, and the multi-token-prediction block."""
+        return self.attention_layers * self.loop_passes + self.mtp_layers
 
     @property
     def expert_width(self) -> int:
@@ -260,15 +350,47 @@ def _dense(key, fan_in, fan_out, std=0.02):
     return jax.random.normal(key, (fan_in, fan_out), jnp.float32) * std
 
 
-def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool) -> Dict:
+def _init_ssm(key: jax.Array, cfg: LlamaConfig) -> Dict:
+    """A Mamba-2 mixer's parameters as the reference implementation draws
+    them: projections N(0, 0.02); the convolution PyTorch's ``Conv1d``
+    default, uniform in +-1/sqrt(``mamba_d_conv``), stored ``[taps,
+    channels]``; ``A_log = log(1..H)``; ``D`` and the gated norm's gain 1;
+    ``dt_bias`` the inverse softplus of a log-uniform draw in [1e-3,
+    1e-1]."""
+    k = jax.random.split(key, 5)
+    H, inner, conv = cfg.mamba_n_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
+    bound = cfg.mamba_d_conv ** -0.5
+    dt = jnp.exp(jax.random.uniform(
+        k[3], (H,), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    ssm = {
+        "in_proj": _dense(k[0], cfg.d_model, inner + conv + H),
+        "conv_w": jax.random.uniform(
+            k[1], (cfg.mamba_d_conv, conv), jnp.float32, -bound, bound),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jnp.arange(1, H + 1, dtype=jnp.float32)),
+        "D": jnp.ones((H,), jnp.float32),
+        "norm": jnp.ones((inner,), jnp.float32),
+        "out_proj": _dense(k[4], inner, cfg.d_model),
+    }
+    if cfg.mamba_conv_bias:
+        ssm["conv_b"] = jax.random.uniform(
+            k[2], (conv,), jnp.float32, -bound, bound)
+    return ssm
+
+
+def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool,
+                ssm: bool = False) -> Dict:
     """One block's parameters.  The leaves every earlier configuration has
-    draw from the same eight keys as ever; what latent attention and the
-    shared expert add draws from keys folded out of the layer's."""
+    draw from the same eight keys as ever; what latent attention, the
+    shared expert and a state-space mixer (``layer["ssm"]``, in place of
+    the attention leaves) add draws from keys folded out of the layer's."""
     k = jax.random.split(key, 8)
     more = jax.random.split(jax.random.fold_in(key, 1), 5)
     hd = cfg.head_dim
     layer = {"ln1": jnp.ones((cfg.d_model,), jnp.float32)}
-    if cfg.kv_lora_rank > 0:
+    if ssm:
+        layer["ssm"] = _init_ssm(jax.random.fold_in(key, 2), cfg)
+    elif cfg.kv_lora_rank > 0:
         layer["wq_a"] = _dense(k[0], cfg.d_model, cfg.q_lora_rank)
         layer["q_a_norm"] = jnp.ones((cfg.q_lora_rank,), jnp.float32)
         layer["wq_b"] = _dense(more[0], cfg.q_lora_rank, cfg.n_head * hd)
@@ -282,9 +404,10 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool) -> Dict:
         layer["wq"] = _dense(k[0], cfg.d_model, cfg.n_head * hd)
         layer["wk"] = _dense(k[1], cfg.d_model, cfg.n_kv_head * hd)
         layer["wv"] = _dense(k[2], cfg.d_model, cfg.n_kv_head * hd)
-    layer["wo"] = _dense(k[3], cfg.n_head * hd, cfg.d_model)
+    if not ssm:
+        layer["wo"] = _dense(k[3], cfg.n_head * hd, cfg.d_model)
     layer["ln2"] = jnp.ones((cfg.d_model,), jnp.float32)
-    if cfg.qk_norm:
+    if cfg.qk_norm and not ssm:
         layer["q_norm"] = jnp.ones((cfg.n_head * hd,), jnp.float32)
         layer["k_norm"] = jnp.ones((cfg.n_kv_head * hd,), jnp.float32)
     if cfg.branch_norm:
@@ -327,9 +450,12 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
         "lm_head": _dense(keys[1], cfg.d_model, cfg.vocab_size),
         "ln_f": jnp.ones((cfg.d_model,), jnp.float32),
         "layers": [
-            _init_layer(keys[2 + i], cfg, cfg.is_moe_layer(i))
+            _init_layer(keys[2 + i], cfg, cfg.is_moe_layer(i),
+                        ssm=cfg.is_ssm_layer(i))
             for i in range(cfg.n_layer)],
     }
+    if cfg.tie_word_embeddings:
+        del params["lm_head"]
     if cfg.exit_gate_beta is not None:
         params["exit_gate"] = {
             "w": jax.random.normal(
@@ -353,16 +479,26 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
     """Logical-axis names per parameter (consumed by
     ``parallel.sharding.tree_logical_to_specs``)."""
 
-    def layer_axes(has_moe: bool) -> Dict:
+    def layer_axes(has_moe: bool, ssm: bool = False) -> Dict:
         ax = {"ln1": (None,), "wo": ("heads", "embed"), "ln2": (None,)}
-        if cfg.kv_lora_rank > 0:
+        if ssm:
+            # the large dimensions over ``fsdp``, the rest replicated: the
+            # mixer has no ``tp`` rule yet
+            del ax["wo"]
+            ax["ssm"] = {
+                "in_proj": ("embed", None), "conv_w": (None, None),
+                "dt_bias": (None,), "A_log": (None,), "D": (None,),
+                "norm": (None,), "out_proj": (None, "embed")}
+            if cfg.mamba_conv_bias:
+                ax["ssm"]["conv_b"] = (None,)
+        elif cfg.kv_lora_rank > 0:
             ax.update(wq_a=("embed", None), q_a_norm=(None,),
                       wq_b=(None, "heads"), wkv_a=("embed", None),
                       kv_a_norm=(None,), wkv_b=(None, "heads"))
         else:
             ax.update(wq=("embed", "heads"), wk=("embed", "heads"),
                       wv=("embed", "heads"))
-        if cfg.qk_norm:
+        if cfg.qk_norm and not ssm:
             ax["q_norm"] = (None,)
             ax["k_norm"] = (None,)
         if cfg.branch_norm:
@@ -393,13 +529,15 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
 
     layers = []
     for i in range(cfg.n_layer):
-        layers.append(layer_axes(cfg.is_moe_layer(i)))
+        layers.append(layer_axes(cfg.is_moe_layer(i), cfg.is_ssm_layer(i)))
     axes = {
         "embed": ("vocab", "embed"),
         "lm_head": ("embed", "vocab"),
         "ln_f": (None,),
         "layers": layers,
     }
+    if cfg.tie_word_embeddings:
+        del axes["lm_head"]
     if cfg.exit_gate_beta is not None:
         axes["exit_gate"] = {"w": (None,), "b": ()}
     if cfg.mtp_layers:
@@ -518,9 +656,15 @@ def _attention(
         v = x @ layer["wv"].astype(dt)
     if not latent:
         q, k = qk_normed(q, k, layer, cfg)
-        q = _rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
-        k = _rope(k.reshape(B, S, KV, D), positions, cfg.rope_theta)
+        q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
+        if cfg.rope:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
         v = v.reshape(B, S, KV, D)
+    if cfg.attention_multiplier is not None:
+        # every backend scales the scores by 1 / sqrt(D): the rest of the
+        # stated scale goes onto q (Granite: 1/64 at D = 64, so 1/8, exact)
+        q = q * (cfg.attention_multiplier * D ** 0.5)
     if KV != H and attn_impl in ("ring", "ulysses") and mesh is not None:
         # Ring/Ulysses shard over heads and need the full head count; the
         # flash path handles GQA in-kernel (no materialized repeat).
@@ -570,6 +714,48 @@ def _attention(
     with (jax.named_scope("mla_out") if latent
           else contextlib.nullcontext()):
         return out @ layer["wo"].astype(dt), None
+
+
+def _ssm_mixer(u, ssm, cfg: LlamaConfig) -> tuple:
+    """The Mamba-2 mixer on the normed stream ``u [B, S, C]`` -> ``(out [B,
+    S, C], stats)``.  ``[z | xBC | dt] = u in_proj``; ``xBC = silu(conv(xBC)
+    + b)``, causal, depthwise; ``[x | B | C] = xBC``; ``dt = softplus(dt +
+    dt_bias)`` in float32, unclamped; ``A = -exp(A_log)``; the scan
+    (``ops.ssd.ssd_chunked`` at ``cfg.mamba_chunk_size``) gives ``y_t = h_t
+    C_t + D x_t``; ``y = rms(y * silu(z)) * norm`` — the gate BEFORE the
+    norm, one group over the whole width; ``out = y out_proj``.  Scopes
+    ``ssm_in``, ``ssm_conv``, ``ssm_scan``, ``ssm_gate`` and ``ssm_out`` sit
+    inside the block's ``ssm``.  ``stats``: ``ssm_state_rms`` (of the state
+    the sequence leaves) and ``ssm_decay_min`` (the least ``exp(sum dt A)``
+    over a chunk: 0 says a chunk's decay underflowed float32)."""
+    B, S, _ = u.shape
+    H, P, G, N = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_n_groups,
+                  cfg.mamba_d_state)
+    inner, conv, dt = cfg.mamba_d_inner, cfg.mamba_conv_dim, cfg.dtype
+    with jax.named_scope("ssm_in"):
+        zxbcdt = u @ ssm["in_proj"].astype(dt)
+        z = zxbcdt[..., :inner]
+        xbc = zxbcdt[..., inner:inner + conv]
+        step = zxbcdt[..., inner + conv:]
+    with jax.named_scope("ssm_conv"):
+        xbc = jax.nn.silu(causal_conv1d(
+            xbc, ssm["conv_w"], ssm.get("conv_b"))).astype(dt)
+    with jax.named_scope("ssm_scan"):
+        step = jax.nn.softplus(step.astype(jnp.float32) + ssm["dt_bias"])
+        y, state, decay_min = ssd_chunked(
+            xbc[..., :inner].reshape(B, S, H, P), step,
+            -jnp.exp(ssm["A_log"]),
+            xbc[..., inner:inner + G * N].reshape(B, S, G, N),
+            xbc[..., inner + G * N:].reshape(B, S, G, N),
+            cfg.mamba_chunk_size, D=ssm["D"])
+        stats = jax.lax.stop_gradient({
+            "ssm_state_rms": jnp.sqrt(jnp.mean(jnp.square(state))),
+            "ssm_decay_min": decay_min})
+    with jax.named_scope("ssm_gate"):
+        y = y.reshape(B, S, inner) * jax.nn.silu(z.astype(jnp.float32))
+        y = rmsnorm(y.astype(dt), ssm["norm"], eps=cfg.rms_eps)
+    with jax.named_scope("ssm_out"):
+        return y @ ssm["out_proj"].astype(dt), stats
 
 
 def _swiglu(x, mlp, dt, fp8_mlp=None):
@@ -854,7 +1040,10 @@ def block_apply(
 ) -> tuple:
     """One transformer block: (x, layer) -> (x, stats).  ``stats`` is what
     a routed layer's :func:`_moe_swiglu` reports (``moe_aux``, ``moe_z``,
-    ``experts``, ``tokens_per_expert``) and empty for a dense layer.  The
+    ``experts``, ``tokens_per_expert``), what a state-space layer's
+    :func:`_ssm_mixer` reports (a layer dict with ``"ssm"`` in place of the
+    attention leaves: ``ssm_state_rms``, ``ssm_decay_min``; scope ``ssm``)
+    and empty for a dense attention layer.  The
     unit the pipeline stage partitioner groups (``models.llama_pp``).
     ``attn_fn`` swaps the attention implementation (the KV-cache decoder
     plugs in here, so train and decode share one block wiring).
@@ -872,24 +1061,47 @@ def block_apply(
     # ``op_name`` of the compiled step: ``accelerate.program_summary``
     # reads them back, outermost scope only, hence siblings.  A branch's
     # output norm (``cfg.branch_norm``) sits in its branch's scope.
-    with jax.named_scope("attention"):
-        h = rmsnorm(x, layer["ln1"], eps=cfg.rms_eps)
-        if attn_fn is not None:
-            if fp8_layer is not None:
-                raise ValueError(
-                    "block_apply: fp8_layer is not supported with a "
-                    "custom attn_fn (fp8 is a training-path strategy; "
-                    "the KV-cache decode path stays in the compute dtype)"
+    def add(x, branch):
+        """``x + residual_multiplier * branch``; at 1.0 the add alone."""
+        if cfg.residual_multiplier != 1.0:
+            branch = branch * cfg.residual_multiplier
+        return x + branch
+
+    ssm_stats = {}
+    if "ssm" in layer:
+        if (segment_ids is not None or fp8_layer is not None
+                or attn_fn is not None):
+            raise NotImplementedError(
+                "block_apply: a 'mamba' layer with segment_ids, fp8 states "
+                "or a custom attn_fn: the scan and the convolution know no "
+                "document boundary, no fp8 linear and no cache")
+        # outermost ``ssm`` as ``attention`` is for the other kind; the
+        # mixer's own five scopes nest inside it (``subscopes``)
+        with jax.named_scope("ssm"):
+            h = rmsnorm(x, layer["ln1"], eps=cfg.rms_eps)
+            mixed, ssm_stats = _ssm_mixer(h, layer["ssm"], cfg)
+            if cfg.branch_norm:
+                mixed = rmsnorm(mixed, layer["ln1_out"], eps=cfg.rms_eps)
+            x = add(x, mixed)
+    else:
+        with jax.named_scope("attention"):
+            h = rmsnorm(x, layer["ln1"], eps=cfg.rms_eps)
+            if attn_fn is not None:
+                if fp8_layer is not None:
+                    raise ValueError(
+                        "block_apply: fp8_layer is not supported with a "
+                        "custom attn_fn (fp8 is a training-path strategy; "
+                        "the KV-cache decode path stays in the compute dtype)"
+                    )
+                attn, new_fp8_attn = attn_fn(h, layer, cfg, positions), None
+            else:
+                attn, new_fp8_attn = _attention(
+                    h, layer, cfg, positions, attn_impl, mesh, segment_ids,
+                    fp8_layer=fp8_layer,
                 )
-            attn, new_fp8_attn = attn_fn(h, layer, cfg, positions), None
-        else:
-            attn, new_fp8_attn = _attention(
-                h, layer, cfg, positions, attn_impl, mesh, segment_ids,
-                fp8_layer=fp8_layer,
-            )
-        if cfg.branch_norm:
-            attn = rmsnorm(attn, layer["ln1_out"], eps=cfg.rms_eps)
-        x = x + attn
+            if cfg.branch_norm:
+                attn = rmsnorm(attn, layer["ln1_out"], eps=cfg.rms_eps)
+            x = add(x, attn)
     if "moe" in layer:
         with jax.named_scope("moe_router"):
             h = rmsnorm(x, layer["ln2"], eps=cfg.rms_eps)
@@ -901,7 +1113,7 @@ def block_apply(
         with jax.named_scope("moe_combine"):
             if cfg.branch_norm:
                 delta = rmsnorm(delta, layer["ln2_out"], eps=cfg.rms_eps)
-            x = x + delta
+            x = add(x, delta)
         if fp8_layer is not None:
             new_fp8_attn["moe"] = stats.pop("fp8")
             return x, stats, new_fp8_attn
@@ -914,11 +1126,11 @@ def block_apply(
         )
         if cfg.branch_norm:
             out_m = rmsnorm(out_m, layer["ln2_out"], eps=cfg.rms_eps)
-        x = x + out_m
+        x = add(x, out_m)
     if fp8_layer is not None:
         new_fp8_attn["mlp"] = new_fp8_mlp
         return x, {}, new_fp8_attn
-    return x, {}
+    return x, ssm_stats
 
 
 def segment_positions(segment_ids: jax.Array) -> jax.Array:
@@ -1008,7 +1220,14 @@ def forward_hidden(
     predicts token i+2).  Its routed block's statistics come last in every
     per-block entry of the aux dict, and its experts under the key
     ``"mtp"``.  A model with a share of the experts adds
-    ``moe_held_pairs`` (int32 ``[routed blocks]``)."""
+    ``moe_held_pairs`` (int32 ``[routed blocks]``).
+
+    A model with state-space layers (``cfg.layer_types``) adds
+    ``ssm_state_rms`` (float32 ``[mamba layers]``: the RMS of the state each
+    layer's scan leaves) and ``ssm_decay_min`` (the least decay over a
+    chunk, any layer and head).  The embedding's rows are scaled by
+    ``cfg.embedding_multiplier`` here; the head's side of a tied or scaled
+    head is :func:`head_operands`'."""
     B, S = tokens.shape
     dt = cfg.dtype
     if cfg.loop_passes > 1 and fp8_states is not None:
@@ -1018,6 +1237,8 @@ def forward_hidden(
             "application of a linear, a looped layer has several")
     with jax.named_scope("embed"):
         x = params["embed"].astype(dt)[tokens]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
     if segment_ids is not None:
         positions = segment_positions(segment_ids)
     else:
@@ -1025,6 +1246,7 @@ def forward_hidden(
     moe_aux = jnp.zeros((), jnp.float32)
     moe_z = jnp.zeros((), jnp.float32)
     experts, per_expert, held_pairs = {}, [], []
+    state_rms, decay_min = [], []
 
     def collect(block, stats):
         """A routed block's statistics into the aux dict's entries."""
@@ -1062,8 +1284,11 @@ def forward_hidden(
             # selective_offloading_checkpoint.py:252) while everything
             # inside the block rematerializes.
             x = checkpoint_name(x, "block_out")
-            if stats:
+            if "moe_aux" in stats:
                 collect(i, stats)
+            if "ssm_state_rms" in stats:
+                state_rms.append(stats["ssm_state_rms"])
+                decay_min.append(stats["ssm_decay_min"])
         z = x  # the last layer's output, what the prediction block reads
         with jax.named_scope("final_norm"):
             x = rmsnorm(x, params["ln_f"], eps=cfg.rms_eps)
@@ -1103,6 +1328,9 @@ def forward_hidden(
                        moe_tokens_per_expert=jnp.stack(per_expert))
     if held_pairs:
         out_aux["moe_held_pairs"] = jnp.stack(held_pairs)
+    if state_rms:
+        out_aux.update(ssm_state_rms=jnp.stack(state_rms),
+                       ssm_decay_min=jnp.min(jnp.stack(decay_min)))
     if new_fp8 is not None:
         out_aux["fp8_states"] = new_fp8
     return x, out_aux
@@ -1130,10 +1358,22 @@ def forward(
     )
     with jax.named_scope("lm_head_loss"):  # the head's matmul is the
         # unfused loss's larger half
-        logits = (
-            x @ params["lm_head"].astype(cfg.dtype)
-        ).astype(jnp.float32)
+        x, head = head_operands(params, x, cfg)
+        logits = (x @ head.astype(cfg.dtype)).astype(jnp.float32)
     return logits, aux
+
+
+def head_operands(params: Dict, x: jax.Array, cfg: LlamaConfig) -> tuple:
+    """``(x, head [D, V])`` as the head's matmul takes them: the float32
+    head is ``lm_head``, or ``embed`` transposed where
+    ``cfg.tie_word_embeddings`` (the one leaf's second use); ``logits /
+    cfg.logits_scaling`` is folded into the normed stream, so that the
+    fused loss never sees the logits (Granite: 1/8, exact)."""
+    head = (params["embed"].T if cfg.tie_word_embeddings
+            else params["lm_head"])
+    if cfg.logits_scaling != 1.0:
+        x = x * (1.0 / cfg.logits_scaling)
+    return x, head
 
 
 def uses_fused_lm_head(cfg: LlamaConfig) -> bool:
@@ -1170,7 +1410,11 @@ def loss_fn(
     counters of its routed blocks (``moe_tokens_per_expert`` int32
     ``[routed layers, E]``, ``moe_aux``, ``moe_z``): ``accelerate()``'s
     step hands them out beside ``loss`` and ``grad_norm``.  A dense model
-    returns the scalar alone either way.
+    returns the scalar alone either way; one with state-space layers
+    returns ``ssm_state_rms`` ``[mamba layers]`` and ``ssm_decay_min``.
+    The head is ``lm_head``, or ``embed`` transposed where
+    ``cfg.tie_word_embeddings``, behind ``1 / cfg.logits_scaling``
+    (:func:`head_operands`).
 
     A looped model's loss is the expectation over the pass a token exits
     at: ``mean_r [sum_t p_t[r] * ce_t[r] - beta * H(p[r])]`` with ``p``
@@ -1236,8 +1480,9 @@ def loss_fn(
             params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
             segment_ids=seg, fp8_states=fp8_states,  # refused there
         )
+        x, head = head_operands(params, x, cfg)
         loss, counters = exit_expectation_loss(
-            x, aux["exit_logits"], params["lm_head"], targets, cfg,
+            x, aux["exit_logits"], head, targets, cfg,
             valid=valid, fused_lm_head=fused_lm_head)
         return (loss, counters) if metrics else loss
     counters = {}
@@ -1247,8 +1492,9 @@ def loss_fn(
             segment_ids=seg, fp8_states=fp8_states,  # refused there
             next_tokens=targets,
         )
+        x, head = head_operands(params, x, cfg)
         ce, counters = mtp_loss(
-            x, params["lm_head"], targets, cfg, valid=valid,
+            x, head, targets, cfg, valid=valid,
             fused_lm_head=fused_lm_head, mtp_weight=mtp_weight)
     elif fused_lm_head:
         x, aux = forward_hidden(
@@ -1258,8 +1504,9 @@ def loss_fn(
         with jax.named_scope("lm_head_loss"):
             # The row weights are known here, so the reduced op forms
             # the head's gradients in its forward scan.
+            x, head = head_operands(params, x, cfg)
             ce = linear_softmax_cross_entropy_sum(
-                x, params["lm_head"].astype(cfg.dtype), targets,
+                x, head.astype(cfg.dtype), targets,
                 None if valid is None
                 else valid / jnp.maximum(jnp.sum(valid), 1.0),
             )
@@ -1284,6 +1531,9 @@ def loss_fn(
         return loss, aux["fp8_states"]
     if not metrics:
         return loss
+    if "ssm_state_rms" in aux:
+        counters.update(ssm_state_rms=aux["ssm_state_rms"],
+                        ssm_decay_min=aux["ssm_decay_min"])
     if "moe_z" in aux:
         counters.update(
             moe_tokens_per_expert=aux["moe_tokens_per_expert"],
@@ -1463,6 +1713,46 @@ def refuse_latent(cfg: LlamaConfig, where: str) -> None:
                 "only (llama.forward_hidden / loss_fn)")
 
 
+def refuse_ssm(cfg: LlamaConfig, where: str) -> None:
+    """``ValueError`` naming the setting, for code that knows one kind of
+    layer, rotary attention at ``1 / sqrt(head_dim)``, a head of its own
+    and no scalar on the stream: a state-space layer (whose decode needs
+    recurrent state beside keys and values), attention without position,
+    the stream's multipliers and a tied head are computed by
+    ``llama.forward_hidden`` / ``loss_fn`` alone."""
+    if cfg.ssm_layers:
+        raise ValueError(
+            f"{where} does not compute layer_types with a 'mamba' entry "
+            f"({cfg.ssm_layers} of {cfg.n_layer} layers): a state-space "
+            "layer exists on the training path only "
+            "(llama.forward_hidden / loss_fn)")
+    for name, default in (("rope", True), ("attention_multiplier", None),
+                          ("embedding_multiplier", 1.0),
+                          ("residual_multiplier", 1.0),
+                          ("logits_scaling", 1.0),
+                          ("tie_word_embeddings", False)):
+        value = getattr(cfg, name)
+        if value != default:
+            raise ValueError(
+                f"{where} does not compute {name}={value!r}: attention "
+                "without rotary position or at a stated scale, the "
+                "stream's multipliers and a tied head exist on the "
+                "training path only (llama.forward_hidden / loss_fn)")
+
+
+def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:
+    """What the compiled step's text cannot say of a model with
+    state-space layers, for the ``accelerate.program`` event (a loss
+    function carries it as its ``program_facts`` attribute): how many
+    layers are of each kind, and the chunks the scan carries a state over
+    in a sequence of ``seq_len``.  Empty for every other model."""
+    if not cfg.ssm_layers:
+        return {}
+    return {"ssm_layers": cfg.ssm_layers,
+            "attention_layers": cfg.attention_layers,
+            "ssm_chunks_per_sequence": -(-seq_len // cfg.mamba_chunk_size)}
+
+
 def num_params(params: Dict) -> int:
     return sum(int(np.prod(x.shape))
                for x in jax.tree_util.tree_leaves(params))
@@ -1471,7 +1761,10 @@ def num_params(params: Dict) -> int:
 def flops_per_token(cfg: LlamaConfig) -> float:
     """~6 * non-embedding params + attention FLOPs (for MFU accounting).
     A looped model runs every layer and the head ``loop_passes`` times a
-    token, and its exit gate (``2 * d_model`` a pass) with them."""
+    token, and its exit gate (``2 * d_model`` a pass) with them.  A
+    state-space layer counts its two projections and its MLP, and per token
+    the recurrence's update and read (``4 * H * P * N``) and the
+    convolution's taps."""
     if cfg.kv_lora_rank > 0:  # latent attention's five projections
         qkv = (
             cfg.d_model * cfg.q_lora_rank
@@ -1495,4 +1788,12 @@ def flops_per_token(cfg: LlamaConfig) -> float:
              + cfg.vocab_size * cfg.d_model)
     attn = (2 * cfg.block_applications * cfg.max_seq_len
             * cfg.n_head * cfg.head_dim)
-    return 6.0 * dense + 6.0 * attn
+    if not cfg.ssm_layers:
+        return 6.0 * dense + 6.0 * attn
+    inner, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    p_ssm = (cfg.d_model * (inner + conv + cfg.mamba_n_heads)  # in_proj
+             + inner * cfg.d_model  # out_proj
+             + 3 * cfg.d_model * cfg.d_ff)
+    scan = 4 * inner * cfg.mamba_d_state + 2 * cfg.mamba_d_conv * conv
+    return (6.0 * (dense + cfg.ssm_layers * p_ssm) + 6.0 * attn
+            + 3.0 * cfg.ssm_layers * scan)

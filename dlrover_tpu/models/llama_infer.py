@@ -65,6 +65,7 @@ def init_cache(
     decoding need.  Memory cost: the full max_len rows."""
     llama.refuse_looped(cfg, "the KV cache (models.llama_infer)")
     llama.refuse_latent(cfg, "the KV cache (models.llama_infer)")
+    llama.refuse_ssm(cfg, "the KV cache (models.llama_infer)")
     KV, D = cfg.n_kv_head, cfg.head_dim
     L = max_len
     if cfg.sliding_window > 0 and ring and ring_len is not None:
@@ -252,6 +253,7 @@ def forward_step(
     rows colliding on an expert would be silently dropped."""
     llama.refuse_looped(cfg, "the cached decoder (models.llama_infer)")
     llama.refuse_latent(cfg, "the cached decoder (models.llama_infer)")
+    llama.refuse_ssm(cfg, "the cached decoder (models.llama_infer)")
     B, T = tokens.shape
     dt = cfg.dtype
     offset = cache["offset"]
@@ -1452,6 +1454,7 @@ def init_paged_pool(cfg: LlamaConfig, n_blocks: int, block_size: int,
     writes land somewhere harmless."""
     llama.refuse_looped(cfg, "the paged KV pool (models.llama_infer)")
     llama.refuse_latent(cfg, "the paged KV pool (models.llama_infer)")
+    llama.refuse_ssm(cfg, "the paged KV pool (models.llama_infer)")
     KV, D = cfg.n_kv_head, cfg.head_dim
     NB = n_blocks + 1
 

@@ -442,7 +442,10 @@ def program_summary(hlo_text: str) -> dict:
     collectives are counted by opcode (async ``-start`` forms included
     once); ``scopes`` is :func:`scope_table`, and ``subscopes`` (only
     where the program nests scopes of its own) :func:`scope_tables`'
-    second table."""
+    second table.  ``accelerate()`` adds the loss function's
+    ``program_facts`` attribute (a dict; ``models.llama.program_facts``:
+    ``ssm_layers``, ``attention_layers``, ``ssm_chunks_per_sequence`` of a
+    model with state-space layers), where it carries one."""
     kernels: dict = {}
     applications = 0
     for line in hlo_text.splitlines():
@@ -1306,6 +1309,9 @@ def _compile_candidate(
     with span("accelerate.analyze", "accelerate", strategy=described):
         cost, memory = _cost_and_memory(compiled)
         program = program_summary(compiled.as_text())
+        # what the text cannot say and the model can (the counts of each
+        # kind of layer): the loss function's ``program_facts``, if any
+        program.update(getattr(loss_fn, "program_facts", {}))
 
     return AcceleratedJob(
         mesh=mesh,

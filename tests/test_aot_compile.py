@@ -476,3 +476,56 @@ def test_latent_share_mtp_step_compiles_at_published_widths(topo, monkeypatch):
     assert {"mla_q", "mla_kv", "mla_out", "attention", "moe_shared"} <= inner
     # and the cut fits the chip
     assert job.memory["peak_bytes"] < 16_909_336_064
+
+
+def test_hybrid_step_compiles_at_published_widths(topo, monkeypatch):
+    """The Granite hybrid cell's step from shapes, depth cut to one
+    state-space layer and the attention layer, widths, batch and sequence
+    length whole: the chunked scan's ``[Q, Q]`` decay masks are built eight
+    heads at a time (all 64 at once are 1.07 GB of float32, forward alone),
+    the flash kernels run at 32/8 heads of 64 without rotary position, the
+    head is the embedding transposed, and the compiled step's tables name
+    the mixer's nested scopes in every phase."""
+    import optax
+
+    from dlrover_tpu.models import llama
+    from dlrover_tpu.parallel.mesh import MeshSpec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = llama.LlamaConfig(
+        vocab_size=12544, n_layer=2, n_head=32, n_kv_head=8, d_model=2048,
+        d_ff=8192, max_seq_len=8192, remat_block=True,
+        layer_types=("mamba", "attention"), mamba_n_heads=64,
+        mamba_d_head=64, mamba_d_state=128, rope=False,
+        attention_multiplier=1 / 64, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=8.0,
+        tie_word_embeddings=True)
+
+    def loss(params, batch):
+        return llama.loss_fn(params, batch, cfg, metrics=True)
+
+    loss.program_facts = llama.program_facts(cfg, 8192)
+    job = acc.aot_analyze(
+        loss_fn=loss, init_fn=lambda r: llama.init_params(r, cfg),
+        optimizer=optax.adamw(3e-4),
+        sample_batch={"tokens": np.zeros((2, 8193), np.int32)},
+        strategy=acc.Strategy(mesh=MeshSpec()), param_specs="planner",
+        devices=topo.devices[:1],
+    )
+    kernels = job.program["kernels"]
+    assert (kernels["flash_fwd"], kernels["flash_bwd_dq"],
+            kernels["flash_bwd_dkv"]) == (1, 1, 1)
+    assert job.program["block_applications"] == cfg.block_applications == 1
+    assert (job.program["ssm_layers"], job.program["attention_layers"],
+            job.program["ssm_chunks_per_sequence"]) == (1, 1, 32)
+    found = {tuple(v) for v in job.program["scopes"].values()}
+    assert {("forward", "ssm"), ("backward", "ssm"), ("recompute", "ssm"),
+            ("forward", "attention"), ("forward", "lm_head_loss")} <= found
+    by_inner = {}
+    for name, inner in job.program["subscopes"].items():
+        by_inner.setdefault(inner, set()).add(job.program["scopes"][name][0])
+    for inner in ("ssm_in", "ssm_conv", "ssm_scan", "ssm_gate", "ssm_out"):
+        assert {"forward", "backward", "recompute"} <= by_inner[inner], inner
+    # 137 M parameters of state and two sequences of 8,192: the step's
+    # temporaries stay under 4 GB, which all heads' masks at once would not
+    assert job.memory["temp_bytes"] < 4 * 1024 ** 3
