@@ -478,20 +478,16 @@ def test_latent_share_mtp_step_compiles_at_published_widths(topo, monkeypatch):
     assert job.memory["peak_bytes"] < 16_909_336_064
 
 
-def test_hybrid_step_compiles_at_published_widths(topo, monkeypatch):
-    """The Granite hybrid cell's step from shapes, depth cut to one
-    state-space layer and the attention layer, widths, batch and sequence
-    length whole: the chunked scan's ``[Q, Q]`` decay masks are built eight
-    heads at a time (all 64 at once are 1.07 GB of float32, forward alone),
-    the flash kernels run at 32/8 heads of 64 without rotary position, the
-    head is the embedding transposed, and the compiled step's tables name
-    the mixer's nested scopes in every phase."""
+@pytest.fixture(scope="module")
+def hybrid_step(topo):
+    """``(job, compiled text, cfg)`` of the Granite hybrid cell's step from
+    shapes, depth cut to one state-space layer and the attention layer,
+    widths, batch and sequence length whole."""
     import optax
 
     from dlrover_tpu.models import llama
     from dlrover_tpu.parallel.mesh import MeshSpec
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = llama.LlamaConfig(
         vocab_size=12544, n_layer=2, n_head=32, n_kv_head=8, d_model=2048,
         d_ff=8192, max_seq_len=8192, remat_block=True,
@@ -505,13 +501,28 @@ def test_hybrid_step_compiles_at_published_widths(topo, monkeypatch):
         return llama.loss_fn(params, batch, cfg, metrics=True)
 
     loss.program_facts = llama.program_facts(cfg, 8192)
-    job = acc.aot_analyze(
-        loss_fn=loss, init_fn=lambda r: llama.init_params(r, cfg),
-        optimizer=optax.adamw(3e-4),
-        sample_batch={"tokens": np.zeros((2, 8193), np.int32)},
-        strategy=acc.Strategy(mesh=MeshSpec()), param_specs="planner",
-        devices=topo.devices[:1],
-    )
+    texts = []
+    with pytest.MonkeyPatch.context() as patch:
+        # the kernel dispatchers ask the backend and would see the CPU
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        summary = acc.program_summary
+        patch.setattr(acc, "program_summary",
+                      lambda text: texts.append(text) or summary(text))
+        job = acc.aot_analyze(
+            loss_fn=loss, init_fn=lambda r: llama.init_params(r, cfg),
+            optimizer=optax.adamw(3e-4),
+            sample_batch={"tokens": np.zeros((2, 8193), np.int32)},
+            strategy=acc.Strategy(mesh=MeshSpec()), param_specs="planner",
+            devices=topo.devices[:1],
+        )
+    return job, texts[-1], cfg
+
+
+def test_hybrid_step_compiles_at_published_widths(hybrid_step):
+    """The flash kernels run at 32/8 heads of 64 without rotary position,
+    the head is the embedding transposed, and the compiled step's tables
+    name the mixer's nested scopes in every phase."""
+    job, _, cfg = hybrid_step
     kernels = job.program["kernels"]
     assert (kernels["flash_fwd"], kernels["flash_bwd_dq"],
             kernels["flash_bwd_dkv"]) == (1, 1, 1)
@@ -529,3 +540,29 @@ def test_hybrid_step_compiles_at_published_widths(topo, monkeypatch):
     # 137 M parameters of state and two sequences of 8,192: the step's
     # temporaries stay under 4 GB, which all heads' masks at once would not
     assert job.memory["temp_bytes"] < 4 * 1024 ** 3
+
+
+def test_hybrid_step_forms_no_decay_mask_in_hbm(hybrid_step):
+    """The chunked scan's ``[Q, Q]`` part is the kernel pair: under block
+    remat the step journals ``ssd_chunk_fwd`` twice a state-space layer
+    (forward, recomputation) and ``ssd_chunk_bwd`` once, and no instruction
+    under ``ssm_scan`` — a fusion's inner ones included — has a result or an
+    operand with two chunk-length dimensions larger than one ``C B^T`` a group
+    (2 x 32 chunks x 256 x 256): a mask of every head would be 64 times
+    that."""
+    job, text, cfg = hybrid_step
+    kernels, layers = job.program["kernels"], job.program["ssm_layers"]
+    assert kernels["ssd_chunk_fwd"] == 2 * layers
+    assert kernels["ssd_chunk_bwd"] == layers
+    q, per_group = cfg.mamba_chunk_size, 2 * 32 * cfg.mamba_n_groups
+    seen = 0
+    for line in text.splitlines():
+        if "ssm_scan" not in line or " = " not in line:
+            continue
+        seen += 1
+        shapes = line.split(" = ", 1)[1].split("metadata=", 1)[0]
+        for dims in re.findall(r"\[([0-9,]+)\]", shapes):
+            dims = [int(d) for d in dims.split(",")]
+            assert not (dims.count(q) >= 2
+                        and np.prod(dims) > per_group * q * q), line[:200]
+    assert seen > 100  # the scope's instructions were there to be read

@@ -13,6 +13,7 @@ cannot compute the new settings refuses them by name.
 """
 
 import dataclasses
+import functools
 import importlib
 
 import jax
@@ -88,26 +89,12 @@ def _mixer_core(scan):
     return run
 
 
-#: (chunk, one head a block): chunks that divide S = 40 and that do not,
-#: one longer than the sequence; all heads of a group at once, as the
-#: shapes here allow, and — with no room for a second head's masks — in
-#: blocks of one, the path the published shapes take eight heads at a time
-CHUNKS = [(8, False), (16, False), (7, False), (64, False), (10, True),
-          (16, True)]
+#: chunks that divide S = 40 and that do not, one longer than the sequence
+CHUNKS = [8, 16, 7, 64, 10, 5]
 
 
-@pytest.fixture
-def room(monkeypatch):
-    def set_room(one_head_a_block):
-        if one_head_a_block:
-            monkeypatch.setattr(ssd, "_L_BYTES_AT_ONCE", 0)
-    return set_room
-
-
-@pytest.mark.parametrize("chunk,one_head", CHUNKS)
-def test_chunked_scan_equals_the_sequential_recurrence(chunk, one_head,
-                                                       room):
-    room(one_head)
+@pytest.mark.parametrize("chunk", CHUNKS)
+def test_chunked_scan_equals_the_sequential_recurrence(chunk):
     ops = _operands()
     chunked = _mixer_core(lambda x, dt, a, b, c, d: ssd.ssd_chunked(
         x, dt, a, b, c, chunk, D=d))
@@ -121,11 +108,10 @@ def test_chunked_scan_equals_the_sequential_recurrence(chunk, one_head,
     assert 0.0 < float(decay_min) <= 1.0
 
 
-@pytest.mark.parametrize("chunk,one_head", CHUNKS[1:5])
-def test_chunked_scan_has_the_sequential_gradients(chunk, one_head, room):
+@pytest.mark.parametrize("chunk", CHUNKS[1:5])
+def test_chunked_scan_has_the_sequential_gradients(chunk):
     """All eight: x, B, C, dt, A_log, D, dt_bias and the convolution's
     weights, through a loss that reads the outputs AND the last state."""
-    room(one_head)
     ops = _operands(1)
     weights = jax.random.normal(jax.random.PRNGKey(9), (B, S, H, P))
 
@@ -145,21 +131,159 @@ def test_chunked_scan_has_the_sequential_gradients(chunk, one_head, room):
         assert _rel(g, w) < 2e-5, name
 
 
-def test_a_chunk_whose_decay_underflows_stays_finite():
+#: how a test reaches the intra-chunk kernels on the CPU: the dispatcher's
+#: own keywords, Pallas in interpret mode
+KERNELS = dict(backend="pallas", interpret=True)
+
+
+def _scan_operands(chunk, groups, head, dtype, seed=0, heads=2, chunks=2.5):
+    """``x, dt, A, B, C, D`` at shapes the kernels tile (N 128, two heads a
+    group), the sequence two and a half chunks long."""
+    s, n, h = int(chunk * chunks), 128, groups * heads
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return (jax.random.normal(k[0], (B, s, h, head)).astype(dtype),
+            jax.nn.softplus(jax.random.normal(k[1], (B, s, h)) - 2.0),
+            -jnp.exp(0.3 * jax.random.normal(k[2], (h,))),
+            (jax.random.normal(k[3], (B, s, groups, n)) * n ** -.5).astype(
+                dtype),
+            jax.random.normal(k[4], (B, s, groups, n)).astype(dtype),
+            jax.random.normal(k[5], (h,)))
+
+
+SHAPES = [(chunk, groups, head) for chunk in (128, 256)
+          for groups in (1, 2) for head in (64, 128)]
+
+
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("chunk,groups,head", SHAPES)
+def test_the_kernel_pair_equals_the_numpy_form(chunk, groups, head, dtype):
+    """``ssd_chunk_fwd`` and ``ssd_chunk_bwd`` against ``_chunk_outputs``
+    (``_intra_chunk`` plus the entering state's term and ``D x``) and JAX's
+    own derivative of it: the value and the cotangents of ``x``, ``dt``,
+    the cumulative sums, ``B``, ``C``, the entering states and ``D``."""
+    x, dt, a, bm, cm, d = _scan_operands(chunk, groups, head, dtype, chunks=2)
+    c, r, n = 2, x.shape[2] // groups, bm.shape[-1]
+    assert ssd._kernel_heads(chunk, r, head, n) == r
+    dtc = dt.reshape(B, c, chunk, groups, r)
+    ops = (x.reshape(B, c, chunk, groups, r, head), dtc,
+           jnp.cumsum(dtc * a.reshape(groups, r), axis=2),
+           bm.reshape(B, c, chunk, groups, n),
+           cm.reshape(B, c, chunk, groups, n),
+           (jax.random.normal(jax.random.PRNGKey(5),
+                              (c, B, groups, r, head, n)) * .1).astype(dtype),
+           d.reshape(groups, r))
+    names = ("x", "dt", "cumulative sums", "B", "C", "entering", "D")
+    weights = jax.random.normal(jax.random.PRNGKey(9), ops[0].shape)
+
+    def value_and_grads(f):
+        return jax.value_and_grad(
+            lambda *o: jnp.sum(f(*o) * weights), argnums=tuple(range(7)))(
+                *ops)
+
+    y = ssd.chunk_outputs(*ops, **KERNELS)
+    assert y.dtype == F32 and _rel(y, ssd._chunk_outputs(*ops)) < 1e-6
+    # the [Q, Q] part alone: no state enters, D is 0
+    bare = ops[:5] + (jnp.zeros_like(ops[5]), jnp.zeros_like(ops[6]))
+    xdt = (ops[0].astype(F32) * dtc[..., None]).astype(dtype)
+    assert _rel(ssd.chunk_outputs(*bare, **KERNELS),
+                ssd._intra_chunk(xdt, *ops[2:5])) < 1e-6
+    (_, got), (_, want) = (
+        value_and_grads(lambda *o: ssd.chunk_outputs(*o, **KERNELS)),
+        value_and_grads(ssd._chunk_outputs))
+    # float32: two orders of summation.  bf16: the kernel keeps ``dy
+    # xdt^T`` in float32 where JAX's transpose rounds it to bf16
+    tol = 1e-5 if dtype == F32 else 1e-2
+    for name, g, w in zip(names, got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert _rel(g.astype(F32), w.astype(F32)) < tol, name
+
+
+def _scan_loss(scan, weights):
+    def loss(*ops):
+        y, state = scan(*ops)[:2]
+        return jnp.sum(y * weights) + jnp.sum(jnp.square(state))
+    return loss
+
+
+@pytest.mark.parametrize("chunk,groups,head", SHAPES)
+def test_the_scan_through_the_kernels_equals_the_recurrence(chunk, groups,
+                                                            head):
+    """Values and every gradient (``x``, ``dt``, ``A``, ``B``, ``C``,
+    ``D``) of ``ssd_chunked`` through the kernel pair against
+    ``ssd_sequential``, float32, at the tolerances of the ``jax.numpy``
+    form, on a sequence the chunk does not divide."""
+    ops = _scan_operands(chunk, groups, head, F32)
+    weights = jax.random.normal(jax.random.PRNGKey(9), ops[0].shape)
+    chunked = lambda x, dt, a, b, c, d: ssd.ssd_chunked(  # noqa: E731
+        x, dt, a, b, c, chunk, D=d, **KERNELS)
+    sequential = lambda x, dt, a, b, c, d: ssd.ssd_sequential(  # noqa: E731
+        x, dt, a, b, c, D=d)
+    y, state, _ = chunked(*ops)
+    y_seq, state_seq = sequential(*ops)
+    assert _rel(y, y_seq) < 1e-5 and _rel(state, state_seq) < 1e-5
+    got, want = (jax.grad(_scan_loss(f, weights), argnums=tuple(range(6)))(
+        *ops) for f in (chunked, sequential))
+    for name, g, w in zip(("x", "dt", "A", "B", "C", "D"), got, want):
+        # A's gradient sums, over the whole sequence, row sums less column
+        # sums of one [Q, Q] product, which cancel: the kernel forms the
+        # two as separate sums over P, so its float32 rounding does not
+        # cancel entry by entry as the jax.numpy form's does (6e-6 here)
+        assert _rel(g, w) < (5e-5 if name == "A" else 2e-5), name
+
+
+@pytest.mark.parametrize("chunk,groups,head", SHAPES[2:6])
+def test_the_kernels_take_bf16_operands_and_accumulate_in_float32(
+        chunk, groups, head):
+    """bf16 ``x``, ``B`` and ``C`` through the kernel pair: values and
+    gradients within bf16's 8 bits of the float32 recurrence, as the
+    ``jax.numpy`` form's are, and the two forms as near each other."""
+    ops32 = _scan_operands(chunk, groups, head, F32, seed=3)
+    ops = _scan_operands(chunk, groups, head, jnp.bfloat16, seed=3)
+    weights = jax.random.normal(jax.random.PRNGKey(9), ops[0].shape)
+
+    def grads(scan, operands):
+        return jax.grad(_scan_loss(scan, weights), argnums=tuple(range(6)))(
+            *operands)
+
+    kernels = lambda x, dt, a, b, c, d: ssd.ssd_chunked(  # noqa: E731
+        x, dt, a, b, c, chunk, D=d, **KERNELS)
+    y, state, _ = kernels(*ops)
+    assert y.dtype == F32 and state.dtype == F32
+    y_seq, _ = ssd.ssd_sequential(*ops32[:5], D=ops32[5])
+    assert _rel(y, y_seq) < 2e-2
+    got = grads(kernels, ops)
+    numpy_form = grads(lambda x, dt, a, b, c, d: ssd.ssd_chunked(
+        x, dt, a, b, c, chunk, D=d), ops)
+    want = grads(lambda x, dt, a, b, c, d: ssd.ssd_sequential(
+        x, dt, a, b, c, D=d), ops32)
+    for name, g, n, w in zip(("x", "dt", "A", "B", "C", "D"), got,
+                             numpy_form, want):
+        assert g.dtype == n.dtype, name
+        assert _rel(g.astype(F32), w) < 3e-2, name
+        assert _rel(g.astype(F32), n.astype(F32)) < 2e-2, name
+
+
+@pytest.mark.parametrize("via", ["numpy", "kernels"])
+def test_a_chunk_whose_decay_underflows_stays_finite(via):
     """dt A of -200 a position: ``exp`` of a chunk's sum is 0 in float32;
     the masked differences keep every entry finite, values and gradients,
     and ``ssm_decay_min`` says so."""
-    x, b, c, dt, a_log, d, dt_bias, conv_w = _operands(2)
-    step = jnp.full((B, S, H), 2.0)
-    a = jnp.full((H,), -100.0)
-    bm, cm = b.reshape(B, S, G, N), c.reshape(B, S, G, N)
+    if via == "kernels":
+        xs, _, _, bm, cm, _ = _scan_operands(128, 1, 64, F32, seed=2)
+        chunk, how = 128, KERNELS
+    else:
+        x, b, c = _operands(2)[:3]
+        xs, bm, cm = (x.reshape(B, S, H, P), b.reshape(B, S, G, N),
+                      c.reshape(B, S, G, N))
+        chunk, how = 16, {}
+    step = jnp.full(xs.shape[:3], 2.0)
+    a = jnp.full(xs.shape[2:3], -100.0)
 
     def loss(xs):
-        y, state, _ = ssd.ssd_chunked(xs, step, a, bm, cm, 16)
+        y, state, _ = ssd.ssd_chunked(xs, step, a, bm, cm, chunk, **how)
         return jnp.sum(y) + jnp.sum(state)
 
-    xs = x.reshape(B, S, H, P)
-    y, _, decay_min = ssd.ssd_chunked(xs, step, a, bm, cm, 16)
+    y, _, decay_min = ssd.ssd_chunked(xs, step, a, bm, cm, chunk, **how)
     y_seq, _ = ssd.ssd_sequential(xs, step, a, bm, cm)
     assert float(decay_min) == 0.0
     assert bool(jnp.isfinite(y).all()) and _rel(y, y_seq) < 1e-5
@@ -200,12 +324,88 @@ def test_the_convolution_is_causal_and_is_four_shifted_adds():
     assert float(jnp.abs(moved[:, t + K:] - out[:, t + K:]).max()) == 0.0
 
 
-def test_head_blocks_follow_from_the_shapes():
-    # 2 x 8,192 tokens, 64 heads, Q 256: 1.07 GB of L at once is too much
-    assert ssd._head_block(2, 32, 64, 1, 256) == 8
-    assert ssd._head_block(1, 32, 64, 1, 256) == 16
-    assert ssd._head_block(2, 3, 4, 2, 16) == 4  # a toy: all at once
-    assert ssd._head_block(64, 32, 3, 1, 256) == 1  # never under one head
+def test_the_kernels_run_once_per_batch_shard_of_the_mesh_in_scope():
+    """Under a ``dp x fsdp`` mesh GSPMD cannot partition a Mosaic call: the
+    pair runs in a ``shard_map`` over the batch dim (``ops/per_shard.py``),
+    values and gradients those of the ``jax.numpy`` form, outputs still
+    sharded."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from dlrover_tpu.parallel.mesh import build_mesh
+
+    mesh = build_mesh(MeshSpec(dp=2), jax.devices()[:2])
+    ops = _scan_operands(128, 1, 64, F32, chunks=2)
+    weights = jax.random.normal(jax.random.PRNGKey(9), ops[0].shape)
+
+    def loss(how):
+        return _scan_loss(lambda x, dt, a, b, c, d: ssd.ssd_chunked(
+            x, dt, a, b, c, 128, D=d, **how), weights)
+
+    want = jax.value_and_grad(loss({}), argnums=(0, 1, 3))(*ops)
+    rows = NamedSharding(mesh, PartitionSpec(("dp", "fsdp")))
+    with jax.set_mesh(mesh):
+        got = jax.jit(jax.value_and_grad(loss(KERNELS), argnums=(0, 1, 3)))(
+            *(jax.device_put(o, rows) if o.ndim > 1 else o for o in ops))
+    assert got[1][0].sharding.spec[0] == ("dp", "fsdp")
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, w in zip(got[1], want[1]):
+        assert _rel(g, w) < 1e-5
+
+
+def test_kernel_heads_follow_from_the_shapes():
+    # the cell: 64 heads of 64 in one group, N 128, chunks of 256 -> eight
+    # heads a grid step, 512 lanes
+    assert ssd._kernel_heads(256, 64, 64, 128) == 8
+    assert ssd._kernel_heads(128, 32, 128, 128) == 4
+    assert ssd._kernel_heads(256, 6, 64, 128) == 6  # a divisor of R
+    assert ssd._kernel_heads(256, 2, 1024, 128) == 1  # one head is wider
+    # refused: a chunk or a state that is no multiple of 128 lanes, one
+    # head of 64 alone, a head size that neither divides nor is divided
+    for shape in ((64, 64, 64, 128), (256, 64, 64, 16), (256, 1, 64, 128),
+                  (256, 3, 64, 128), (256, 64, 96, 128)):
+        assert ssd._kernel_heads(*shape) == 0, shape
+
+
+def _calls_a_kernel(f, *args):
+    return "pallas_call" in str(jax.make_jaxpr(f)(*args))
+
+
+def test_a_shape_the_rule_refuses_takes_the_numpy_form():
+    """Asked for the kernels by name, a chunk of 16 still runs (and equals)
+    ``_intra_chunk``; the shapes the rule admits do call a kernel."""
+    x, b, c, dt = _operands(4)[:4]
+    args = (x.reshape(B, S, H, P), jax.nn.softplus(dt),
+            -jnp.arange(1.0, H + 1), b.reshape(B, S, G, N),
+            c.reshape(B, S, G, N))
+    asked = lambda *a: ssd.ssd_chunked(*a, 16, **KERNELS)  # noqa: E731
+    assert not _calls_a_kernel(asked, *args)
+    np.testing.assert_array_equal(
+        np.asarray(asked(*args)[0]),
+        np.asarray(ssd.ssd_chunked(*args, 16)[0]))
+    tiled = _scan_operands(128, 1, 64, F32)[:5]
+    assert _calls_a_kernel(lambda *a: ssd.ssd_chunked(*a, 128, **KERNELS),
+                           *tiled)
+    # and on the CPU nobody is asked: the jax.numpy form
+    assert not _calls_a_kernel(lambda *a: ssd.ssd_chunked(*a, 128), *tiled)
+
+
+def test_the_choice_reads_the_backend_and_the_shapes_not_the_environment(
+        monkeypatch):
+    import os
+
+    class Closed(dict):
+        def _refuse(self, *a, **k):
+            raise AssertionError("the choice read the environment")
+        __getitem__ = get = __contains__ = _refuse
+
+    tiled = _scan_operands(128, 1, 64, F32)[:5]
+    monkeypatch.setattr(os, "environ", Closed())
+    monkeypatch.setattr(os, "getenv", Closed()._refuse)
+    # a new function each time: JAX caches a function's trace
+    for backend, kernel in (("tpu", True), ("cpu", False), ("gpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert _calls_a_kernel(
+            lambda *a: ssd.ssd_chunked(*a, 128), *tiled) is kernel, backend
 
 
 # -- the mixer and the block against the equations ---------------------------
@@ -318,14 +518,28 @@ def test_the_mixer_reports_the_state_the_sequence_leaves():
     assert 0.0 < float(stats["ssm_decay_min"]) < 1.0
 
 
-def test_block_remat_of_a_mixed_stack_equals_no_remat():
-    cfg = _hybrid()
-    params, toks = _params(cfg), _tokens(1)
+@pytest.mark.parametrize("via", ["numpy", "kernels"])
+def test_block_remat_of_a_mixed_stack_equals_no_remat(via, monkeypatch):
+    cfg, toks = _hybrid(), _tokens(1)
+    if via == "kernels":
+        # two heads of 64, N 128 and chunks of 128: shapes the kernels
+        # tile, two and a half chunks a sequence
+        cfg = _hybrid(d_model=64, mamba_n_heads=2, mamba_d_head=64,
+                      mamba_d_state=128, mamba_chunk_size=128,
+                      max_seq_len=320)
+        toks = _tokens(1, s=320)
+        monkeypatch.setattr(llama, "ssd_chunked", functools.partial(
+            ssd.ssd_chunked, **KERNELS))
+    params = _params(cfg)
     plain = jax.value_and_grad(lambda p: llama.loss_fn(
         p, {"tokens": toks}, cfg))(params)
     remat = jax.value_and_grad(lambda p: llama.loss_fn(
         p, {"tokens": toks}, dataclasses.replace(cfg, remat_block=True)))(
             params)
+    if via == "kernels":
+        text = str(jax.make_jaxpr(jax.grad(lambda p: llama.loss_fn(
+            p, {"tokens": toks}, cfg)))(params))
+        assert "ssd_chunk_fwd" in text and "ssd_chunk_bwd" in text
     for a, b in zip(jax.tree_util.tree_leaves(plain),
                     jax.tree_util.tree_leaves(remat)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
