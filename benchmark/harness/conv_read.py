@@ -1,0 +1,51 @@
+"""What the readers of the gated short-convolution layers share: device
+seconds of the traced window under the block's ``conv`` scope and under each
+of the mixer's three nested scopes (``conv_in``, ``conv_gate``,
+``conv_out``; ``dlrover_tpu/models/llama.py::_conv_mixer``), every phase —
+forward, backward and block remat's recomputation alike.  The trace's
+instruction names are joined to the two tables of the ``accelerate.program``
+event, ``scopes`` (outermost scope) and ``subscopes`` (innermost), as
+``harness/ssm_read.py`` does for the state-space layers.
+
+The RMSNorm kernel (the block's ``ln1``) is a Mosaic call whose label in the
+trace is the kernel's name, the same under every scope: the join cannot
+place it and it is left out.
+
+A program that journals no ``conv`` scope (every configuration without such
+layers, and the parent of the PR that brought them) yields None, and every
+reader built on this returns None.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+from benchmark.harness import obs_read
+
+INNER = ("conv_in", "conv_gate", "conv_out")
+
+
+def seconds(spans: dict, trace: dict) -> Optional[dict]:
+    """``{"conv", "conv_in", "conv_gate", "conv_out", "busy_s",
+    "conv_layers"}``: seconds of the instructions whose outermost scope is
+    ``conv``, of those under each nested scope, the device's busy seconds,
+    and the program's own count of its convolution layers."""
+    programs = [r for r in obs_read.last_incarnation(obs_read.records(spans))
+                if r.get("kind") == "accelerate.program"
+                and r.get("scopes") and r.get("subscopes")]
+    ops = trace.get("op_self_s") if trace else None
+    if not programs or not ops or not trace.get("busy_s"):
+        return None
+    scopes, inner = programs[-1]["scopes"], programs[-1]["subscopes"]
+    out = dict.fromkeys(("conv",) + INNER, 0.0)
+    for label, secs in ops.items():
+        name = label.split(" ", 1)[0]
+        if name not in scopes or scopes[name][1] != "conv":
+            continue  # a kernel's label, another scope's, or nobody's
+        out["conv"] += secs
+        if inner.get(name) in INNER:
+            out[inner[name]] += secs
+    if not out["conv"]:
+        return None
+    return dict(out, busy_s=trace["busy_s"],
+                conv_layers=programs[-1].get("conv_layers"))

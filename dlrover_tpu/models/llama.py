@@ -37,6 +37,10 @@ from dlrover_tpu.ops.rmsnorm import rmsnorm
 from dlrover_tpu.ops.ssd import causal_conv1d, ssd_chunked
 
 
+#: the kinds of mixer a layer may have (``LlamaConfig.layer_types``)
+MIXER_KINDS = ("attention", "mamba", "conv")
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
@@ -66,6 +70,11 @@ class LlamaConfig:
     # RMSNorm (gains ``q_norm``/``k_norm``) over the WHOLE q and k
     # projections, before the split into heads and before RoPE (OLMoE).
     qk_norm: bool = False
+    # With ``qk_norm``: each head's q and each head's k normalised over its
+    # OWN ``head_dim`` dims, one gain of ``head_dim`` for all query heads
+    # and one for all key heads (LFM2's ``q_layernorm``/``k_layernorm``),
+    # before RoPE as well.
+    qk_norm_per_head: bool = False
     # Sliding-window attention (>0: each position attends the last
     # `sliding_window` positions only — Mistral-style long-context;
     # flash path only, kernels skip out-of-window blocks).
@@ -119,10 +128,11 @@ class LlamaConfig:
     n_shared_experts: int = 0
     # The router's scores: "softmax" over the experts, or "sigmoid" of each
     # logit (DeepSeek-V3 ``noaux_tc``: the top-k weights are the chosen
-    # scores over their sum + 1e-20 where ``norm_topk_prob``).  Either way
-    # times ``routed_scaling``.
+    # scores over their sum + ``router_norm_eps`` where ``norm_topk_prob``;
+    # LFM2 states 1e-6).  Either way times ``routed_scaling``.
     router_score: str = "softmax"
     routed_scaling: float = 1.0
+    router_norm_eps: float = 1e-20
     # A selection bias per expert (``moe["router_bias"]``, float32 [E]): it
     # is added to the scores for the top-k CHOICE and never to a weight,
     # takes no gradient, and is moved by a rule after every step,
@@ -149,11 +159,16 @@ class LlamaConfig:
     # output, predicts ``t_{i+2}`` through the shared head
     # (:func:`forward_hidden`, :func:`loss_fn`).
     mtp_layers: int = 0
-    # The kind of each layer, "attention" or "mamba" (a tuple of
-    # ``n_layer`` names; empty: every layer is an attention layer).  A
+    # The kind of each layer's MIXER, "attention", "mamba" or "conv" (a
+    # tuple of ``n_layer`` names; empty: every layer is an attention
+    # layer), under the same pre-norm and residual add; which MLP follows
+    # (dense or routed) is :meth:`is_moe_layer`'s, whatever the mixer — but
+    # for "mamba", whose MLP is dense.  A "conv" layer's mixer is LFM2's
+    # double-gated short convolution (:func:`_conv_mixer`): ``[B | C | X]
+    # = u in_proj``, a causal depthwise convolution of ``conv_taps`` taps
+    # over ``B * X``, times ``C``, ``out_proj``; no bias, no activation.  A
     # "mamba" layer's mixer is the Mamba-2 one (:func:`_ssm_mixer`,
-    # ``ops.ssd``) in place of attention, under the same pre-norm, residual
-    # add and dense SwiGLU: ``mamba_n_heads`` heads of ``mamba_d_head``
+    # ``ops.ssd``): ``mamba_n_heads`` heads of ``mamba_d_head``
     # (together ``mamba_expand * d_model`` wide), a state of
     # ``mamba_d_state`` per head dim, B and C in ``mamba_n_groups`` groups,
     # a causal depthwise convolution ``mamba_d_conv`` wide (with a bias
@@ -161,6 +176,7 @@ class LlamaConfig:
     # positions.  ``mamba_proj_bias`` must stay False: the two projections
     # have no bias here.
     layer_types: tuple = ()
+    conv_taps: int = 3
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
     mamba_d_state: int = 0
@@ -235,13 +251,30 @@ class LlamaConfig:
                 f"loop_passes={self.loop_passes}: one prediction block "
                 "after a stack that runs once is what is built")
 
+        if self.qk_norm_per_head and (
+                not self.qk_norm or self.kv_lora_rank > 0):
+            raise ValueError(
+                f"LlamaConfig: qk_norm_per_head={self.qk_norm_per_head} "
+                f"with qk_norm={self.qk_norm} and kv_lora_rank="
+                f"{self.kv_lora_rank}: it is the form of the q/k norm of "
+                "plain q and k projections, and says nothing without one")
+
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         kinds = self.layer_types
         if kinds and (len(kinds) != self.n_layer
-                      or set(kinds) - {"attention", "mamba"}):
+                      or set(kinds) - set(MIXER_KINDS)):
             raise ValueError(
                 f"LlamaConfig: layer_types={kinds} is not n_layer="
-                f"{self.n_layer} names out of 'attention' and 'mamba'")
+                f"{self.n_layer} names out of {MIXER_KINDS}")
+        if self.conv_layers and (
+                self.conv_taps <= 0 or self.loop_passes > 1
+                or self.mtp_layers):
+            raise ValueError(
+                f"LlamaConfig: 'conv' layers with conv_taps="
+                f"{self.conv_taps}, loop_passes={self.loop_passes} or "
+                f"mtp_layers={self.mtp_layers}: the convolution has at "
+                "least one tap and the stack runs once, with no prediction "
+                "block")
         if self.ssm_layers:
             if (self.mamba_n_heads * self.mamba_d_head
                     != self.mamba_expand * self.d_model
@@ -263,8 +296,14 @@ class LlamaConfig:
                     f"LlamaConfig: 'mamba' layers with num_experts="
                     f"{self.num_experts}, loop_passes={self.loop_passes} or "
                     f"mtp_layers={self.mtp_layers}: a state-space layer's "
-                    "MLP is dense and the stack runs once, with no "
-                    "prediction block")
+                    "MLP is dense (experts beside a 'conv' or an "
+                    "'attention' mixer are built, beside a 'mamba' one not "
+                    "yet) and the stack runs once, with no prediction "
+                    "block")
+
+    def mixer_kind(self, i: int) -> str:
+        """Layer ``i``'s mixer: one of :data:`MIXER_KINDS`."""
+        return self.layer_types[i] if self.layer_types else "attention"
 
     @property
     def ssm_layers(self) -> int:
@@ -272,11 +311,13 @@ class LlamaConfig:
         return sum(kind == "mamba" for kind in self.layer_types)
 
     @property
-    def attention_layers(self) -> int:
-        return self.n_layer - self.ssm_layers
+    def conv_layers(self) -> int:
+        """Layers whose mixer is the gated short convolution."""
+        return sum(kind == "conv" for kind in self.layer_types)
 
-    def is_ssm_layer(self, i: int) -> bool:
-        return bool(self.layer_types) and self.layer_types[i] == "mamba"
+    @property
+    def attention_layers(self) -> int:
+        return self.n_layer - self.ssm_layers - self.conv_layers
 
     @property
     def mamba_d_inner(self) -> int:
@@ -378,18 +419,38 @@ def _init_ssm(key: jax.Array, cfg: LlamaConfig) -> Dict:
     return ssm
 
 
+def _init_conv(key: jax.Array, cfg: LlamaConfig) -> Dict:
+    """A gated short convolution's parameters: projections N(0, 0.02), the
+    taps PyTorch's ``Conv1d`` default as :func:`_init_ssm` draws them,
+    stored ``[taps, channels]``."""
+    k = jax.random.split(key, 3)
+    bound = cfg.conv_taps ** -0.5
+    return {
+        "in_proj": _dense(k[0], cfg.d_model, 3 * cfg.d_model),
+        "conv_w": jax.random.uniform(
+            k[1], (cfg.conv_taps, cfg.d_model), jnp.float32, -bound, bound),
+        "out_proj": _dense(k[2], cfg.d_model, cfg.d_model),
+    }
+
+
 def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool,
-                ssm: bool = False) -> Dict:
-    """One block's parameters.  The leaves every earlier configuration has
-    draw from the same eight keys as ever; what latent attention, the
-    shared expert and a state-space mixer (``layer["ssm"]``, in place of
-    the attention leaves) add draws from keys folded out of the layer's."""
+                mixer: str = "attention") -> Dict:
+    """One block's parameters: the mixer's (``mixer``, one of
+    :data:`MIXER_KINDS`) and the MLP's (``routed`` or dense), chosen apart.
+    The leaves every earlier configuration has draw from the same eight
+    keys as ever; what latent attention, the shared expert, a state-space
+    mixer (``layer["ssm"]``) and a convolution mixer (``layer["conv"]``,
+    either in place of the attention leaves) add draws from keys folded out
+    of the layer's."""
     k = jax.random.split(key, 8)
     more = jax.random.split(jax.random.fold_in(key, 1), 5)
     hd = cfg.head_dim
+    attention = mixer == "attention"
     layer = {"ln1": jnp.ones((cfg.d_model,), jnp.float32)}
-    if ssm:
+    if mixer == "mamba":
         layer["ssm"] = _init_ssm(jax.random.fold_in(key, 2), cfg)
+    elif mixer == "conv":
+        layer["conv"] = _init_conv(jax.random.fold_in(key, 3), cfg)
     elif cfg.kv_lora_rank > 0:
         layer["wq_a"] = _dense(k[0], cfg.d_model, cfg.q_lora_rank)
         layer["q_a_norm"] = jnp.ones((cfg.q_lora_rank,), jnp.float32)
@@ -404,12 +465,15 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool,
         layer["wq"] = _dense(k[0], cfg.d_model, cfg.n_head * hd)
         layer["wk"] = _dense(k[1], cfg.d_model, cfg.n_kv_head * hd)
         layer["wv"] = _dense(k[2], cfg.d_model, cfg.n_kv_head * hd)
-    if not ssm:
+    if attention:
         layer["wo"] = _dense(k[3], cfg.n_head * hd, cfg.d_model)
     layer["ln2"] = jnp.ones((cfg.d_model,), jnp.float32)
-    if cfg.qk_norm and not ssm:
-        layer["q_norm"] = jnp.ones((cfg.n_head * hd,), jnp.float32)
-        layer["k_norm"] = jnp.ones((cfg.n_kv_head * hd,), jnp.float32)
+    if cfg.qk_norm and attention:
+        per_head = cfg.qk_norm_per_head
+        layer["q_norm"] = jnp.ones(
+            (hd if per_head else cfg.n_head * hd,), jnp.float32)
+        layer["k_norm"] = jnp.ones(
+            (hd if per_head else cfg.n_kv_head * hd,), jnp.float32)
     if cfg.branch_norm:
         layer["ln1_out"] = jnp.ones((cfg.d_model,), jnp.float32)
         layer["ln2_out"] = jnp.ones((cfg.d_model,), jnp.float32)
@@ -451,7 +515,7 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
         "ln_f": jnp.ones((cfg.d_model,), jnp.float32),
         "layers": [
             _init_layer(keys[2 + i], cfg, cfg.is_moe_layer(i),
-                        ssm=cfg.is_ssm_layer(i))
+                        mixer=cfg.mixer_kind(i))
             for i in range(cfg.n_layer)],
     }
     if cfg.tie_word_embeddings:
@@ -479,9 +543,18 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
     """Logical-axis names per parameter (consumed by
     ``parallel.sharding.tree_logical_to_specs``)."""
 
-    def layer_axes(has_moe: bool, ssm: bool = False) -> Dict:
+    def layer_axes(has_moe: bool, mixer: str = "attention") -> Dict:
         ax = {"ln1": (None,), "wo": ("heads", "embed"), "ln2": (None,)}
-        if ssm:
+        attention = mixer == "attention"
+        if mixer == "conv":
+            # ``in_proj`` by columns, ``out_proj`` by rows, the taps along
+            # their channels: a depthwise convolution mixes no channels, so
+            # ``tp`` needs no exchange between the taps and ``out_proj``
+            del ax["wo"]
+            ax["conv"] = {"in_proj": ("embed", "mlp"),
+                          "conv_w": (None, "mlp"),
+                          "out_proj": ("mlp", "embed")}
+        elif mixer == "mamba":
             # the large dimensions over ``fsdp``, the rest replicated: the
             # mixer has no ``tp`` rule yet
             del ax["wo"]
@@ -498,7 +571,7 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
         else:
             ax.update(wq=("embed", "heads"), wk=("embed", "heads"),
                       wv=("embed", "heads"))
-        if cfg.qk_norm and not ssm:
+        if cfg.qk_norm and attention:
             ax["q_norm"] = (None,)
             ax["k_norm"] = (None,)
         if cfg.branch_norm:
@@ -529,7 +602,7 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
 
     layers = []
     for i in range(cfg.n_layer):
-        layers.append(layer_axes(cfg.is_moe_layer(i), cfg.is_ssm_layer(i)))
+        layers.append(layer_axes(cfg.is_moe_layer(i), cfg.mixer_kind(i)))
     axes = {
         "embed": ("vocab", "embed"),
         "lm_head": ("embed", "vocab"),
@@ -569,12 +642,28 @@ def qk_normed(q, k, layer, cfg: "LlamaConfig"):
     """q [..., H*D], k [..., KV*D] as projected -> the same, RMS-normalised
     over the whole projection width where ``cfg.qk_norm`` (the kernel
     computes in float32 and, under a mesh, takes the normalised dim whole
-    in every shard, so the mean is over all heads under ``tp`` too).  The
-    training block and the KV-cache decoder both call it."""
+    in every shard, so the mean is over all heads under ``tp`` too), or
+    head by head where ``cfg.qk_norm_per_head`` too.  The training block
+    and the KV-cache decoder both call it."""
     if not cfg.qk_norm:
         return q, k
+    if cfg.qk_norm_per_head:
+        return (_rms_per_head(q, layer["q_norm"], cfg),
+                _rms_per_head(k, layer["k_norm"], cfg))
     return (rmsnorm(q, layer["q_norm"], eps=cfg.rms_eps),
             rmsnorm(k, layer["k_norm"], eps=cfg.rms_eps))
+
+
+def _rms_per_head(x, gain, cfg: "LlamaConfig"):
+    """``x [..., heads * head_dim]`` -> the same, each head RMS-normalised
+    over its own ``head_dim`` dims in float32 with the one ``gain
+    [head_dim]``: elementwise work on 64-wide rows that XLA fuses with the
+    rotary pass behind it (the RMSNorm kernel takes rows of the stream's
+    width), and local to a head, so to a ``tp`` shard."""
+    heads = x.reshape(x.shape[:-1] + (-1, cfg.head_dim)).astype(jnp.float32)
+    inv = jax.lax.rsqrt(
+        jnp.mean(jnp.square(heads), axis=-1, keepdims=True) + cfg.rms_eps)
+    return (heads * inv * gain).astype(x.dtype).reshape(x.shape)
 
 
 def _fp8_proj(x, w, st, dt):
@@ -758,6 +847,27 @@ def _ssm_mixer(u, ssm, cfg: LlamaConfig) -> tuple:
         return y @ ssm["out_proj"].astype(dt), stats
 
 
+def _conv_mixer(u, conv, cfg: LlamaConfig):
+    """LFM2's double-gated short convolution on the normed stream ``u [B,
+    S, C]`` -> ``[B, S, C]``.  ``[B | C | X] = u in_proj`` (thirds of
+    ``3 C`` columns, in that order); ``c_t = sum_k w_k * (B * X)_{t - (K -
+    1) + k}``, causal and depthwise over ``K = cfg.conv_taps`` taps with
+    zeros before the sequence (``ops.ssd.causal_conv1d``); ``out = (C * c)
+    out_proj``.  No bias and no activation.  The two gates and the taps are
+    one elementwise chain in float32, rounded once.  Scopes ``conv_in``,
+    ``conv_gate`` and ``conv_out`` sit inside the block's ``conv``."""
+    d, dt = cfg.d_model, cfg.dtype
+    f32 = jnp.float32
+    with jax.named_scope("conv_in"):
+        bcx = u @ conv["in_proj"].astype(dt)
+    with jax.named_scope("conv_gate"):
+        gate_b, gate_c, x = bcx[..., :d], bcx[..., d:2 * d], bcx[..., 2 * d:]
+        y = gate_c.astype(f32) * causal_conv1d(
+            gate_b.astype(f32) * x.astype(f32), conv["conv_w"])
+    with jax.named_scope("conv_out"):
+        return y.astype(dt) @ conv["out_proj"].astype(dt)
+
+
 def _swiglu(x, mlp, dt, fp8_mlp=None):
     """Returns ``(out, new_fp8_mlp)``; fp8 routing as in
     :func:`_attention` when ``fp8_mlp`` carries Fp8States for
@@ -906,7 +1016,8 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
         if cfg.norm_topk_prob:
             if cfg.router_score == "sigmoid":
                 gate_vals = gate_vals / (
-                    jnp.sum(gate_vals, -1, keepdims=True) + 1e-20)
+                    jnp.sum(gate_vals, -1, keepdims=True)
+                    + cfg.router_norm_eps)
             else:
                 gate_vals = gate_vals / jnp.maximum(
                     jnp.sum(gate_vals, -1, keepdims=True), 1e-9
@@ -1038,13 +1149,16 @@ def block_apply(
     moe_capacity: Optional[int] = None,
     fp8_layer=None,
 ) -> tuple:
-    """One transformer block: (x, layer) -> (x, stats).  ``stats`` is what
-    a routed layer's :func:`_moe_swiglu` reports (``moe_aux``, ``moe_z``,
-    ``experts``, ``tokens_per_expert``), what a state-space layer's
-    :func:`_ssm_mixer` reports (a layer dict with ``"ssm"`` in place of the
-    attention leaves: ``ssm_state_rms``, ``ssm_decay_min``; scope ``ssm``)
-    and empty for a dense attention layer.  The
-    unit the pipeline stage partitioner groups (``models.llama_pp``).
+    """One transformer block: (x, layer) -> (x, stats).  The mixer is the
+    one the layer dict holds — a state-space one (``"ssm"``, scope ``ssm``),
+    a gated short convolution (``"conv"``, scope ``conv``) or attention (the
+    attention leaves, scope ``attention``) — and the MLP the one it holds,
+    routed (``"moe"``) or dense (``"mlp"``), each chosen apart from the
+    other.  ``stats`` is what the mixer reports (:func:`_ssm_mixer`:
+    ``ssm_state_rms``, ``ssm_decay_min``; the other two nothing) with what
+    a routed MLP's :func:`_moe_swiglu` reports (``moe_aux``, ``moe_z``,
+    ``experts``, ``tokens_per_expert``); empty for a dense attention layer.
+    The unit the pipeline stage partitioner groups (``models.llama_pp``).
     ``attn_fn`` swaps the attention implementation (the KV-cache decoder
     plugs in here, so train and decode share one block wiring).
 
@@ -1067,56 +1181,56 @@ def block_apply(
             branch = branch * cfg.residual_multiplier
         return x + branch
 
-    ssm_stats = {}
-    if "ssm" in layer:
-        if (segment_ids is not None or fp8_layer is not None
-                or attn_fn is not None):
-            raise NotImplementedError(
-                "block_apply: a 'mamba' layer with segment_ids, fp8 states "
-                "or a custom attn_fn: the scan and the convolution know no "
-                "document boundary, no fp8 linear and no cache")
-        # outermost ``ssm`` as ``attention`` is for the other kind; the
-        # mixer's own five scopes nest inside it (``subscopes``)
-        with jax.named_scope("ssm"):
-            h = rmsnorm(x, layer["ln1"], eps=cfg.rms_eps)
-            mixed, ssm_stats = _ssm_mixer(h, layer["ssm"], cfg)
-            if cfg.branch_norm:
-                mixed = rmsnorm(mixed, layer["ln1_out"], eps=cfg.rms_eps)
-            x = add(x, mixed)
-    else:
-        with jax.named_scope("attention"):
-            h = rmsnorm(x, layer["ln1"], eps=cfg.rms_eps)
-            if attn_fn is not None:
-                if fp8_layer is not None:
-                    raise ValueError(
-                        "block_apply: fp8_layer is not supported with a "
-                        "custom attn_fn (fp8 is a training-path strategy; "
-                        "the KV-cache decode path stays in the compute dtype)"
-                    )
-                attn, new_fp8_attn = attn_fn(h, layer, cfg, positions), None
-            else:
-                attn, new_fp8_attn = _attention(
-                    h, layer, cfg, positions, attn_impl, mesh, segment_ids,
-                    fp8_layer=fp8_layer,
+    stats, new_fp8 = {}, None
+    kind = next((k for k in ("ssm", "conv") if k in layer), "attention")
+    if kind != "attention" and (
+            segment_ids is not None or fp8_layer is not None
+            or attn_fn is not None):
+        named = {"ssm": "mamba", "conv": "conv"}[kind]
+        raise NotImplementedError(
+            f"block_apply: a {named!r} layer with segment_ids, fp8 states "
+            "or a custom attn_fn: the scan and the convolution know no "
+            "document boundary, no fp8 linear and no cache")
+    # outermost ``ssm`` / ``conv`` as ``attention`` is for the other kind;
+    # the mixer's own scopes nest inside it (``subscopes``)
+    with jax.named_scope(kind):
+        h = rmsnorm(x, layer["ln1"], eps=cfg.rms_eps)
+        if kind == "ssm":
+            mixed, stats = _ssm_mixer(h, layer["ssm"], cfg)
+        elif kind == "conv":
+            mixed = _conv_mixer(h, layer["conv"], cfg)
+        elif attn_fn is not None:
+            if fp8_layer is not None:
+                raise ValueError(
+                    "block_apply: fp8_layer is not supported with a "
+                    "custom attn_fn (fp8 is a training-path strategy; "
+                    "the KV-cache decode path stays in the compute dtype)"
                 )
-            if cfg.branch_norm:
-                attn = rmsnorm(attn, layer["ln1_out"], eps=cfg.rms_eps)
-            x = add(x, attn)
+            mixed = attn_fn(h, layer, cfg, positions)
+        else:
+            mixed, new_fp8 = _attention(
+                h, layer, cfg, positions, attn_impl, mesh, segment_ids,
+                fp8_layer=fp8_layer,
+            )
+        if cfg.branch_norm:
+            mixed = rmsnorm(mixed, layer["ln1_out"], eps=cfg.rms_eps)
+        x = add(x, mixed)
     if "moe" in layer:
         with jax.named_scope("moe_router"):
             h = rmsnorm(x, layer["ln2"], eps=cfg.rms_eps)
-        delta, stats = _moe_swiglu(
+        delta, routed = _moe_swiglu(
             h, layer["moe"], cfg, capacity=moe_capacity,
             valid=None if segment_ids is None else segment_ids >= 0,
             fp8_moe=None if fp8_layer is None else fp8_layer["moe"],
         )
+        stats = dict(stats, **routed)
         with jax.named_scope("moe_combine"):
             if cfg.branch_norm:
                 delta = rmsnorm(delta, layer["ln2_out"], eps=cfg.rms_eps)
             x = add(x, delta)
         if fp8_layer is not None:
-            new_fp8_attn["moe"] = stats.pop("fp8")
-            return x, stats, new_fp8_attn
+            new_fp8["moe"] = stats.pop("fp8")
+            return x, stats, new_fp8
         return x, stats
     with jax.named_scope("mlp"):
         h = rmsnorm(x, layer["ln2"], eps=cfg.rms_eps)
@@ -1128,9 +1242,9 @@ def block_apply(
             out_m = rmsnorm(out_m, layer["ln2_out"], eps=cfg.rms_eps)
         x = add(x, out_m)
     if fp8_layer is not None:
-        new_fp8_attn["mlp"] = new_fp8_mlp
-        return x, {}, new_fp8_attn
-    return x, ssm_stats
+        new_fp8["mlp"] = new_fp8_mlp
+        return x, stats, new_fp8
+    return x, stats
 
 
 def segment_positions(segment_ids: jax.Array) -> jax.Array:
@@ -1716,16 +1830,19 @@ def refuse_latent(cfg: LlamaConfig, where: str) -> None:
 def refuse_ssm(cfg: LlamaConfig, where: str) -> None:
     """``ValueError`` naming the setting, for code that knows one kind of
     layer, rotary attention at ``1 / sqrt(head_dim)``, a head of its own
-    and no scalar on the stream: a state-space layer (whose decode needs
-    recurrent state beside keys and values), attention without position,
-    the stream's multipliers and a tied head are computed by
-    ``llama.forward_hidden`` / ``loss_fn`` alone."""
-    if cfg.ssm_layers:
-        raise ValueError(
-            f"{where} does not compute layer_types with a 'mamba' entry "
-            f"({cfg.ssm_layers} of {cfg.n_layer} layers): a state-space "
-            "layer exists on the training path only "
-            "(llama.forward_hidden / loss_fn)")
+    and no scalar on the stream: a layer of any kind but attention (a
+    state-space or a convolution mixer, whose decode needs recurrent state
+    beside keys and values), attention without position, the stream's
+    multipliers and a tied head are computed by ``llama.forward_hidden`` /
+    ``loss_fn`` alone."""
+    for kind in MIXER_KINDS[1:]:
+        met = sum(k == kind for k in cfg.layer_types)
+        if met:
+            raise ValueError(
+                f"{where} does not compute layer_types with a {kind!r} "
+                f"entry ({met} of {cfg.n_layer} layers): a layer whose "
+                "mixer is not attention exists on the training path only "
+                "(llama.forward_hidden / loss_fn)")
     for name, default in (("rope", True), ("attention_multiplier", None),
                           ("embedding_multiplier", 1.0),
                           ("residual_multiplier", 1.0),
@@ -1741,16 +1858,21 @@ def refuse_ssm(cfg: LlamaConfig, where: str) -> None:
 
 
 def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:
-    """What the compiled step's text cannot say of a model with
-    state-space layers, for the ``accelerate.program`` event (a loss
-    function carries it as its ``program_facts`` attribute): how many
+    """What the compiled step's text cannot say of a model whose layers
+    are not all attention layers, for the ``accelerate.program`` event (a
+    loss function carries it as its ``program_facts`` attribute): how many
     layers are of each kind, and the chunks the scan carries a state over
     in a sequence of ``seq_len``.  Empty for every other model."""
-    if not cfg.ssm_layers:
-        return {}
-    return {"ssm_layers": cfg.ssm_layers,
-            "attention_layers": cfg.attention_layers,
-            "ssm_chunks_per_sequence": -(-seq_len // cfg.mamba_chunk_size)}
+    facts = {}
+    if cfg.ssm_layers:
+        facts.update(
+            ssm_layers=cfg.ssm_layers,
+            ssm_chunks_per_sequence=-(-seq_len // cfg.mamba_chunk_size))
+    if cfg.conv_layers:
+        facts["conv_layers"] = cfg.conv_layers
+    if facts:
+        facts["attention_layers"] = cfg.attention_layers
+    return facts
 
 
 def num_params(params: Dict) -> int:
@@ -1764,7 +1886,8 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     token, and its exit gate (``2 * d_model`` a pass) with them.  A
     state-space layer counts its two projections and its MLP, and per token
     the recurrence's update and read (``4 * H * P * N``) and the
-    convolution's taps."""
+    convolution's taps; a convolution layer its two projections, its MLP
+    and its taps."""
     if cfg.kv_lora_rank > 0:  # latent attention's five projections
         qkv = (
             cfg.d_model * cfg.q_lora_rank
@@ -1788,12 +1911,14 @@ def flops_per_token(cfg: LlamaConfig) -> float:
              + cfg.vocab_size * cfg.d_model)
     attn = (2 * cfg.block_applications * cfg.max_seq_len
             * cfg.n_head * cfg.head_dim)
-    if not cfg.ssm_layers:
-        return 6.0 * dense + 6.0 * attn
+    mlp = 3 * cfg.d_model * cfg.d_ff
     inner, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
     p_ssm = (cfg.d_model * (inner + conv + cfg.mamba_n_heads)  # in_proj
              + inner * cfg.d_model  # out_proj
-             + 3 * cfg.d_model * cfg.d_ff)
+             + mlp)
     scan = 4 * inner * cfg.mamba_d_state + 2 * cfg.mamba_d_conv * conv
-    return (6.0 * (dense + cfg.ssm_layers * p_ssm) + 6.0 * attn
-            + 3.0 * cfg.ssm_layers * scan)
+    p_conv = 4 * cfg.d_model * cfg.d_model + mlp  # in_proj, out_proj
+    taps = 2 * cfg.conv_taps * cfg.d_model
+    return (6.0 * (dense + cfg.ssm_layers * p_ssm + cfg.conv_layers * p_conv)
+            + 6.0 * attn
+            + 3.0 * (cfg.ssm_layers * scan + cfg.conv_layers * taps))

@@ -444,8 +444,9 @@ def program_summary(hlo_text: str) -> dict:
     where the program nests scopes of its own) :func:`scope_tables`'
     second table.  ``accelerate()`` adds the loss function's
     ``program_facts`` attribute (a dict; ``models.llama.program_facts``:
-    ``ssm_layers``, ``attention_layers``, ``ssm_chunks_per_sequence`` of a
-    model with state-space layers), where it carries one."""
+    ``ssm_layers``, ``conv_layers``, ``attention_layers``,
+    ``ssm_chunks_per_sequence`` of a model whose layers are not all
+    attention layers), where it carries one."""
     kernels: dict = {}
     applications = 0
     for line in hlo_text.splitlines():

@@ -566,3 +566,70 @@ def test_hybrid_step_forms_no_decay_mask_in_hbm(hybrid_step):
             assert not (dims.count(q) >= 2
                         and np.prod(dims) > per_group * q * q), line[:200]
     assert seen > 100  # the scope's instructions were there to be read
+
+
+@pytest.fixture(scope="module")
+def conv_step(topo):
+    """``(job, cfg)`` of the LFM2 cell's step from shapes, depth cut to the
+    dense convolution layer, the routed attention layer and ONE routed
+    convolution layer, widths, held experts, batch and sequence length
+    whole."""
+    import optax
+
+    from dlrover_tpu.models import llama
+    from dlrover_tpu.parallel.mesh import MeshSpec
+
+    cfg = llama.LlamaConfig(
+        vocab_size=16384, n_layer=3, n_head=32, n_kv_head=8, d_model=2048,
+        d_ff=7168, max_seq_len=8192, rope_theta=1e6, remat_block=True,
+        layer_types=("conv", "attention", "conv"), qk_norm=True,
+        qk_norm_per_head=True, num_experts=32, top_k=4, moe_every=1,
+        first_k_dense=1, d_ff_expert=1792, router_score="sigmoid",
+        router_norm_eps=1e-6, router_bias_rate=1e-3, experts_held=8,
+        tie_word_embeddings=True)
+
+    def loss(params, batch):
+        return llama.loss_fn(params, batch, cfg, moe_aux_weight=0.0,
+                             metrics=True)
+
+    loss.rule_leaves = llama.rule_leaves(cfg)
+    loss.program_facts = llama.program_facts(cfg, 8192)
+    with pytest.MonkeyPatch.context() as patch:
+        # the kernel dispatchers ask the backend and would see the CPU
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        job = acc.aot_analyze(
+            loss_fn=loss, init_fn=lambda r: llama.init_params(r, cfg),
+            optimizer=optax.adamw(1e-5),
+            sample_batch={"tokens": np.zeros((4, 8193), np.int32)},
+            strategy=acc.Strategy(mesh=MeshSpec()), param_specs="planner",
+            devices=topo.devices[:1],
+        )
+    return job, cfg
+
+
+def test_conv_step_compiles_at_published_widths(conv_step):
+    """The flash kernels run in the ONE attention layer (32/8 heads of 64
+    behind the per-head q/k norm), a convolution layer's routed MLP runs
+    the grouped matmuls over its share of the experts, and the compiled
+    step's tables name the mixer's nested scopes in every phase."""
+    job, cfg = conv_step
+    kernels = job.program["kernels"]
+    assert (kernels["flash_fwd"], kernels["flash_bwd_dq"],
+            kernels["flash_bwd_dkv"]) == (1, 1, 1)
+    assert job.program["block_applications"] == cfg.block_applications == 1
+    assert (job.program["conv_layers"],
+            job.program["attention_layers"]) == (2, 1)
+    # two routed blocks: three grouped matmuls forward, again recomputed
+    # (less the kept pair), and their two transposes backward
+    assert kernels["gmm"] > 0 and kernels["tgmm"] == 3 * 2
+    found = {tuple(v) for v in job.program["scopes"].values()}
+    assert {("forward", "conv"), ("backward", "conv"), ("recompute", "conv"),
+            ("forward", "attention"), ("backward", "moe_experts"),
+            ("forward", "router_bias"), ("forward", "lm_head_loss")} <= found
+    by_inner = {}
+    for name, inner in job.program["subscopes"].items():
+        by_inner.setdefault(inner, set()).add(job.program["scopes"][name][0])
+    for inner in ("conv_in", "conv_gate", "conv_out"):
+        assert {"forward", "backward", "recompute"} <= by_inner[inner], inner
+    # 311 M parameters of state and four sequences of 8,192 fit the chip
+    assert job.memory["peak_bytes"] < 16_909_336_064

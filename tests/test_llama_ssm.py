@@ -613,7 +613,8 @@ def test_defaults_are_todays_and_name_no_state_space_layer():
             cfg.mamba_chunk_size, cfg.mamba_conv_bias,
             cfg.mamba_proj_bias) == (0, 0, 0, 1, 4, 2, 256, True, False)
     assert llama.program_facts(cfg, 4096) == {}
-    assert not any(cfg.is_ssm_layer(i) for i in range(cfg.n_layer))
+    assert not any(cfg.mixer_kind(i) == "mamba"
+                   for i in range(cfg.n_layer))
     tiny = llama.LlamaConfig.tiny()
     params = llama.init_params(jax.random.PRNGKey(0), tiny)
     assert "lm_head" in params and "ssm" not in params["layers"][0]
