@@ -32,7 +32,7 @@ from dlrover_tpu.ops.flash_attention import (
     SAVED_NAMES as FLASH_SAVED_NAMES,
     flash_attention,
 )
-from dlrover_tpu.ops.grouped_matmul import grouped_matmul_ragged
+from dlrover_tpu.ops.grouped_matmul import TILING, grouped_matmul_ragged
 from dlrover_tpu.ops.rmsnorm import rmsnorm
 from dlrover_tpu.ops.ssd import causal_conv1d, ssd_chunked
 
@@ -888,12 +888,13 @@ def _swiglu(x, mlp, dt, fp8_mlp=None):
 
 @jax.custom_vjp
 def _dispatch_rows(tokens, order, inverse):
-    """``tokens`` [N, C] -> the rows of the N*K (token, k) pairs in sorted
-    order, [N*K, C]: pair ``p = n*K + k`` sits at ``inverse[p]``, position
-    ``i`` holds pair ``order[i]``.  The pairs are a permutation, so the
-    transpose is a gather by ``inverse`` and a sum over k, never a
+    """``tokens`` [N, C] -> the rows of the (token, k) pairs at the first
+    ``R = len(order)`` sorted positions, [R, C]: pair ``p = n*K + k`` sits
+    at ``inverse[p]``, position ``i`` holds pair ``order[i]``.  The pairs
+    are a permutation, so the transpose is a gather by ``inverse`` ([N*K],
+    below ``R`` everywhere: the caller clamps it) and a sum over k, never a
     scatter-add."""
-    return tokens[order // (order.shape[0] // tokens.shape[0])]
+    return tokens[order // (inverse.shape[0] // tokens.shape[0])]
 
 
 def _dispatch_rows_fwd(tokens, order, inverse):
@@ -912,8 +913,10 @@ _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 @jax.custom_vjp
 def _permute_rows(rows, perm, inverse):
-    """``rows[perm]`` for a permutation ``perm`` with inverse ``inverse``:
-    the transpose is ``g[inverse]``, a gather again."""
+    """``rows[perm]`` where the transpose is ``g[inverse]``, a gather
+    again: ``perm`` a permutation with inverse ``inverse``, or one clamped
+    into the ``len(inverse)`` rows a buffer keeps of it, whose transpose
+    then holds for the rows that ``g`` is zero outside of."""
     return rows[perm]
 
 
@@ -923,22 +926,134 @@ _permute_rows.defvjp(
 
 
 @functools.partial(
-    jax.checkpoint, static_argnums=(5,),
+    jax.checkpoint, static_argnums=(5, 6),
     policy=jax.checkpoint_policies.save_only_these_names("moe_gate_up"))
-def _expert_ffn(rows, wg, wi, wo, group_sizes, dt):
-    """The three grouped matmuls over the sorted pair rows.  Kept for the
+def _expert_ffn(rows, wg, wi, wo, group_sizes, dt, backend):
+    """The three grouped matmuls over the sorted pair rows (``backend``:
+    ``ops.grouped_matmul``'s, None for its own choice).  Kept for the
     backward pass: the rows and the two ``[N*K, F]`` products; recomputed
     there: the bf16 casts of the weights and ``silu(g) * u`` (elementwise
     passes, in place of 0.8 GB of bf16 weights and a third ``[N*K, F]``
     buffer at OLMoE's widths)."""
     g = checkpoint_name(
-        grouped_matmul_ragged(rows, wg.astype(dt), group_sizes),
+        grouped_matmul_ragged(rows, wg.astype(dt), group_sizes,
+                              backend=backend),
         "moe_gate_up")
     u = checkpoint_name(
-        grouped_matmul_ragged(rows, wi.astype(dt), group_sizes),
+        grouped_matmul_ragged(rows, wi.astype(dt), group_sizes,
+                              backend=backend),
         "moe_gate_up")
     return grouped_matmul_ragged(
-        jax.nn.silu(g) * u, wo.astype(dt), group_sizes)
+        jax.nn.silu(g) * u, wo.astype(dt), group_sizes, backend=backend)
+
+
+def _moe_buffer_bounds(n: int, k: int, e: int, held: int) -> tuple:
+    """The row counts a routed block's sorted buffer may take, ascending,
+    ``n * k`` (every pick) last: a pure function of the block's shapes.
+    A chip that holds ``held < e`` experts computes about ``n * k * held /
+    e`` rows, so the first size is that with a quarter of slack, a multiple
+    of the grouped matmul's row tile (40,960 of 131,072 at 8 of 32 experts,
+    10,240 of 65,536 at 8 of 64).  Where it is not under half of ``n * k``
+    — every expert held, a toy shape, decode's few rows — the one size is
+    ``n * k`` and :func:`_moe_swiglu` chooses nothing.  Two sizes and no
+    third between them: each size is a program of its own in every pass
+    of every routed block, and a warm start reads and loads them all."""
+    total, tile = n * k, TILING[0]
+    if held >= e:
+        return (total,)
+    first = -(-5 * total * held // (4 * e * tile)) * tile
+    return (first, total) if 2 * first <= total else (total,)
+
+
+def _routed_sum(ffn, rows: int, tokens, gate_vals, order, inverse,
+                live_rows):
+    """A routed block's sorted side in a buffer of ``rows`` rows, and the
+    sum over k: the token rows of the first ``rows`` sorted pairs gathered
+    (scope ``moe_permute``), ``ffn`` over them (``moe_experts``), its
+    result brought back to the pairs' own order (``moe_permute``) and
+    summed with the router's weights, ``[N, C]`` (``moe_combine``).
+    ``live_rows`` (None: every row is computed) is how many rows ``ffn``'s
+    groups cover, and ``rows`` must be ABOVE it unless it is ``N*K``: the
+    rows from there on are zero going in and coming out, forward and
+    backward, and a pair past the buffer reads the last of them."""
+    n, c = tokens.shape
+    if rows < inverse.shape[0]:
+        order, inverse = order[:rows], jnp.minimum(inverse, rows - 1)
+    with jax.named_scope("moe_permute"):
+        x = _dispatch_rows(tokens, order, inverse)
+        if live_rows is not None:
+            # a grouped matmul leaves the rows past its groups unwritten,
+            # forward and backward: nothing of them may reach a sum
+            live = (jnp.arange(rows, dtype=jnp.int32) < live_rows)[:, None]
+            x = jnp.where(live, x, 0)
+    with jax.named_scope("moe_experts"):
+        y = ffn(x)
+    with jax.named_scope("moe_permute"):
+        if live_rows is not None:
+            y = jnp.where(live, y, 0)
+        per_pair = _permute_rows(y, inverse, order).reshape(n, -1, c)
+    with jax.named_scope("moe_combine"):
+        return jnp.einsum(
+            "nkc,nk->nc", per_pair, gate_vals.astype(tokens.dtype),
+            preferred_element_type=jnp.float32).astype(tokens.dtype)
+
+
+def _routed_sum_at(rows: int, dt, tokens, gate_vals, weights, indices):
+    order, inverse, group_sizes, live_rows = indices
+    # The buffer of every pick is where a skewed step falls back to, and a
+    # balanced router never takes it: its products are XLA's own ragged
+    # dot, a fifth of the executable that the kernels' twelve programs a
+    # block are (8.7 MB against 1.8 serialized at 8 of 32 experts; AOT),
+    # which a warm start would read and load for nothing.
+    backend = "reference" if rows == inverse.shape[0] else None
+    return _routed_sum(
+        lambda x: _expert_ffn(x, *weights, group_sizes, dt, backend), rows,
+        tokens, gate_vals, order, inverse, live_rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed_sum_sized(bounds, dt, size, tokens, gate_vals, weights, indices):
+    """:func:`_routed_sum` over the plain experts (``weights``: wg, wi,
+    wo) in a buffer of ``bounds[size]`` rows, ``size`` an int32 of the
+    step's own; ``indices`` is ``(order, inverse, group_sizes,
+    live_rows)``.
+
+    Differentiated as ONE conditional a pass: what is kept for the
+    backward pass is the arguments, and the backward rule chooses again
+    and runs the chosen size's forward and transpose together.  A plain
+    ``lax.switch`` under ``grad`` hands every size's residuals out of
+    every branch (the others' as zeros: 2 GB of peak memory and as many
+    bytes of writes a block at 4 x 8,192 tokens, 8 of 32 experts).
+    The routed forward then runs twice a step with block remat or
+    without: once forward and once inside the backward rule (the block's
+    recomputation needs nothing of this function's result, and XLA drops
+    its copy)."""
+    return jax.lax.switch(
+        size, [functools.partial(_routed_sum_at, rows, dt)
+               for rows in bounds], tokens, gate_vals, weights, indices)
+
+
+def _routed_sum_sized_fwd(bounds, dt, size, *operands):
+    return _routed_sum_sized(bounds, dt, size, *operands), (size, operands)
+
+
+def _routed_sum_sized_bwd(bounds, dt, res, g):
+    size, operands = res
+
+    def pull(rows):
+        def run(g, tokens, gate_vals, weights, indices):
+            # a checkpoint, so that JAX names the two halves what they are
+            at = jax.checkpoint(functools.partial(
+                _routed_sum_at, rows, dt, indices=indices))
+            return jax.vjp(at, tokens, gate_vals, weights)[1](g)
+        return run
+
+    grads = jax.lax.switch(
+        size, [pull(rows) for rows in bounds], g, *operands)
+    return (None, *grads, None)
+
+
+_routed_sum_sized.defvjp(_routed_sum_sized_fwd, _routed_sum_sized_bwd)
 
 
 def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
@@ -982,11 +1097,20 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
     token) are described at :class:`LlamaConfig`.  With a SHARE of the
     experts (``cfg.experts_held`` < E) the router still scores, chooses
     and normalises over all E; the pairs sort with the held experts
-    first, the grouped matmuls' groups end with the last held expert's
-    pairs, and the rows behind them are neither computed nor read (their
-    weight is zero, and the two masks keep what the kernels leave
-    unwritten there out of every sum).  ``stats["held_pairs"]`` counts
-    the pairs computed here; ``tokens_per_expert`` stays ``[E]``, in the
+    first and the grouped matmuls' groups end with the last held expert's
+    pairs.  The sorted side — the dispatch gather, the three grouped
+    matmuls with ``silu(g) * u``, the two masks, the gather back — runs in
+    a buffer of the first of :func:`_moe_buffer_bounds`' sizes that is
+    above the held pairs (``jax.lax.switch``; ``N*K`` when a skewed step
+    outgrows the others), so it is sized by the rows computed and not by
+    every pick: no pair is dropped and every computed row's arithmetic is
+    the full buffer's.  The rows between the held pairs and the buffer's
+    end are gathered and masked to zero going in and coming out (a kernel
+    leaves them unwritten); a pick past the buffer has weight zero and
+    reads the buffer's last row, a zero one.  ``stats["held_pairs"]``
+    counts the pairs computed here and ``stats["buffer_rows"]`` the size
+    taken (absent where the shapes leave one size: the program is then the
+    one without a choice); ``tokens_per_expert`` stays ``[E]``, in the
     router's numbering."""
     B, S, C = x.shape
     E, K = cfg.num_experts, cfg.top_k
@@ -1045,12 +1169,12 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
         if share:
             # the groups end with the last held expert's pairs; the rows
             # behind them (absent experts' pairs, pads) are not computed
-            group_sizes = counts[:held]
-            live = (pairs < ends[held - 1])[:, None]
+            group_sizes, held_pairs = counts[:held], ends[held - 1]
         else:
             # the groups the matmuls run over cover every row: pads ride
             # at the end of the last one
             group_sizes = counts.at[E - 1].add(N * K - ends[E - 1])
+            held_pairs = None
         if capacity is not None or valid_n is not None or share:
             keep_sorted = sorted_expert < held
             if capacity is not None:
@@ -1058,22 +1182,19 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
                 keep_sorted = keep_sorted & (rank < capacity)
             gate_vals = jnp.where(
                 keep_sorted[inverse].reshape(N, K), gate_vals, 0.0)
-        rows = _dispatch_rows(tokens.astype(dt), order, inverse)
+        tokens = tokens.astype(dt)
+    new_fp8 = None
+    if fp8_moe is not None:
         if share:
-            # a grouped matmul leaves the rows past its groups unwritten,
-            # forward and backward: nothing of them may reach a sum
-            rows = jnp.where(live, rows, 0)
-    with jax.named_scope("moe_experts"):
-        new_fp8 = None
-        if fp8_moe is not None:
-            if share:
-                raise ValueError(
-                    f"_moe_swiglu: experts_held={held} of {E} with fp8 "
-                    "states: the fp8 ragged dot takes groups that cover "
-                    "every row")
-            from dlrover_tpu.ops.fp8 import fp8_ragged_dot
+            raise ValueError(
+                f"_moe_swiglu: experts_held={held} of {E} with fp8 "
+                "states: the fp8 ragged dot takes groups that cover "
+                "every row")
+        from dlrover_tpu.ops.fp8 import fp8_ragged_dot
 
-            new_fp8 = {}
+        new_fp8 = {}
+
+        def ffn(rows):
             g, new_fp8["wg"] = fp8_ragged_dot(
                 rows, moe["wg"].astype(dt), group_sizes, fp8_moe["wg"])
             u, new_fp8["wi"] = fp8_ragged_dot(
@@ -1081,17 +1202,25 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
             y, new_fp8["wo"] = fp8_ragged_dot(
                 jax.nn.silu(g) * u, moe["wo"].astype(dt), group_sizes,
                 fp8_moe["wo"])
-        else:
-            y = _expert_ffn(rows, moe["wg"], moe["wi"], moe["wo"],
-                            group_sizes, dt)
-    with jax.named_scope("moe_permute"):
-        if share:
-            y = jnp.where(live, y, 0)
-        per_pair = _permute_rows(y, inverse, order).reshape(N, K, C)
-    with jax.named_scope("moe_combine"):
-        out = jnp.einsum(
-            "nkc,nk->nc", per_pair, gate_vals.astype(dt),
-            preferred_element_type=f32).astype(dt)
+            return y
+    else:
+        def ffn(rows):
+            return _expert_ffn(rows, moe["wg"], moe["wi"], moe["wo"],
+                               group_sizes, dt, None)
+    bounds = _moe_buffer_bounds(N, K, E, held)
+    if len(bounds) == 1:
+        out = _routed_sum(ffn, bounds[0], tokens, gate_vals, order,
+                          inverse, held_pairs)
+    else:
+        with jax.named_scope("moe_permute"):
+            # the first size ABOVE the held pairs, so that the buffer's
+            # last row is a dead one; a step too skewed takes every pick
+            size = sum(held_pairs >= rows for rows in bounds[:-1])
+        # outside every scope: a branch's instructions name their own
+        out = _routed_sum_sized(
+            bounds, dt, size, tokens, gate_vals,
+            (moe["wg"], moe["wi"], moe["wo"]),
+            (order, inverse, group_sizes, held_pairs))
     if "shared" in moe:
         with jax.named_scope("moe_shared"):
             out = out + _swiglu(tokens.astype(dt), moe["shared"], dt)[0]
@@ -1130,7 +1259,9 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
             "tokens_per_expert": per_expert,
         }
         if share:
-            stats["held_pairs"] = ends[held - 1]
+            stats["held_pairs"] = held_pairs
+        if len(bounds) > 1:
+            stats["buffer_rows"] = jnp.asarray(bounds, jnp.int32)[size]
     if new_fp8 is not None:
         stats["fp8"] = new_fp8
     return out.reshape(B, S, C), stats
@@ -1334,7 +1465,9 @@ def forward_hidden(
     predicts token i+2).  Its routed block's statistics come last in every
     per-block entry of the aux dict, and its experts under the key
     ``"mtp"``.  A model with a share of the experts adds
-    ``moe_held_pairs`` (int32 ``[routed blocks]``).
+    ``moe_held_pairs`` (int32 ``[routed blocks]``) and, where its blocks
+    choose their buffer (:func:`_moe_buffer_bounds`), ``moe_buffer_rows``
+    (the same shape: the rows each block's sorted buffer took).
 
     A model with state-space layers (``cfg.layer_types``) adds
     ``ssm_state_rms`` (float32 ``[mamba layers]``: the RMS of the state each
@@ -1359,7 +1492,7 @@ def forward_hidden(
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     moe_aux = jnp.zeros((), jnp.float32)
     moe_z = jnp.zeros((), jnp.float32)
-    experts, per_expert, held_pairs = {}, [], []
+    experts, per_expert, held_pairs, buffer_rows = {}, [], [], []
     state_rms, decay_min = [], []
 
     def collect(block, stats):
@@ -1371,6 +1504,8 @@ def forward_hidden(
         per_expert.append(stats["tokens_per_expert"])
         if "held_pairs" in stats:
             held_pairs.append(stats["held_pairs"])
+        if "buffer_rows" in stats:
+            buffer_rows.append(stats["buffer_rows"])
 
     apply = functools.partial(
         block_apply, attn_impl=attn_impl, mesh=mesh,
@@ -1442,6 +1577,8 @@ def forward_hidden(
                        moe_tokens_per_expert=jnp.stack(per_expert))
     if held_pairs:
         out_aux["moe_held_pairs"] = jnp.stack(held_pairs)
+    if buffer_rows:
+        out_aux["moe_buffer_rows"] = jnp.stack(buffer_rows)
     if state_rms:
         out_aux.update(ssm_state_rms=jnp.stack(state_rms),
                        ssm_decay_min=jnp.min(jnp.stack(decay_min)))
@@ -1545,7 +1682,8 @@ def loss_fn(
     (:func:`mtp_loss`; counters ``main_ce``, ``mtp_ce``).  Further
     counters of a routed model, where the setting is on: ``moe_seq_aux``
     in place of ``moe_aux`` (``cfg.balance_per_sequence``),
-    ``moe_held_pairs`` (a share of the experts),
+    ``moe_held_pairs`` (a share of the experts), ``moe_buffer_rows``
+    (a share whose blocks choose their buffer's size),
     ``moe_router_bias_abs_max`` and, under :data:`RULE_UPDATES`, the
     selection biases' next values (``cfg.router_bias_rate``; scope
     ``router_bias``), which ``accelerate()``'s step writes into the
@@ -1654,8 +1792,9 @@ def loss_fn(
             moe_z=aux["moe_z"])
         counters["moe_seq_aux" if cfg.balance_per_sequence
                  else "moe_aux"] = aux["moe_aux"]
-        if "moe_held_pairs" in aux:
-            counters["moe_held_pairs"] = aux["moe_held_pairs"]
+        for name in ("moe_held_pairs", "moe_buffer_rows"):
+            if name in aux:
+                counters[name] = aux[name]
         if cfg.router_bias_rate is not None:
             with jax.named_scope("router_bias"):
                 updates = router_bias_rule(

@@ -274,6 +274,8 @@ _NOT_A_SCOPE = frozenset((
     "branch", "closed_call", "custom_vjp_call", "custom_jvp_call",
     "shard_map", "pallas_call", "scan",
 ))
+#: what ``lax.cond`` / ``lax.switch`` name a branch's instructions after
+_BRANCH = re.compile(r"branch_\d+_fun")
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
 _NO_DEVICE_OP = re.compile(
     r" (?:parameter|constant|get-tuple-element|tuple|bitcast)\(")
@@ -286,7 +288,8 @@ def _program_scopes(parts):
         inner = part.replace("transpose(", "").replace(
             "jvp(", "").rstrip(")")
         if (inner and "(" not in inner and "," not in inner
-                and inner not in _NOT_A_SCOPE):
+                and inner not in _NOT_A_SCOPE
+                and not _BRANCH.fullmatch(inner)):
             yield inner
 
 

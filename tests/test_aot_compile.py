@@ -633,3 +633,24 @@ def test_conv_step_compiles_at_published_widths(conv_step):
         assert {"forward", "backward", "recompute"} <= by_inner[inner], inner
     # 311 M parameters of state and four sequences of 8,192 fit the chip
     assert job.memory["peak_bytes"] < 16_909_336_064
+
+
+def test_conv_step_sizes_its_sorted_buffer(conv_step):
+    """Four sequences of 8,192 tokens take 4 of 32 experts and the chip
+    holds 8: the routed blocks choose between 40,960 rows and all 131,072.
+    The kernels are ONE size's (nine ``gmm`` and three ``tgmm`` a block:
+    forward, and recomputed with the transposes inside the backward rule —
+    the block's own recomputation drops its copy); the buffer of every
+    pick multiplies with XLA's ragged dot.  A branch's instructions carry
+    the program's scopes and phases, and no scope is a branch's name."""
+    from dlrover_tpu.models import llama
+
+    job, _ = conv_step
+    assert llama._moe_buffer_bounds(4 * 8192, 4, 32, 8) == (40960, 131072)
+    kernels = job.program["kernels"]
+    assert (kernels["gmm"], kernels["tgmm"]) == (2 * 9, 2 * 3)
+    assert kernels["unnamed"] > 0  # lax.ragged_dot's own Mosaic kernels
+    found = {tuple(v) for v in job.program["scopes"].values()}
+    assert {(phase, scope) for phase in ("forward", "recompute", "backward")
+            for scope in ("moe_permute", "moe_experts")} <= found
+    assert not [scope for _, scope in found if scope.startswith("branch_")]

@@ -447,6 +447,147 @@ def test_no_pick_on_a_held_expert_leaves_the_shared_expert_alone():
         llama._moe_swiglu(x, part, cfg)[0] ** 2))(x)).all())
 
 
+# -- the share's buffer ------------------------------------------------------
+
+#: a block of 2 x 1024 tokens taking 2 of 8 experts, experts 2 and 3 held:
+#: 4,096 picks, 1,024 on the held ones under an even router
+BUF_N, BUF_E, BUF_K, BUF_FIRST = 2048, 8, 2, 2
+BUF_BOUNDS = (1536, 4096)
+
+
+def _buffer_cfg():
+    return _glm(num_experts=BUF_E, top_k=BUF_K, experts_held=2,
+                experts_held_first=BUF_FIRST, n_shared_experts=0)
+
+
+def _picks_with(held_pairs):
+    """``(x [2, 1024, 64], moe)`` whose router sends exactly ``held_pairs``
+    of the 4,096 picks to experts 2 and 3: the router reads the stream's
+    first eight dims, and every token carries its two picks there."""
+    rng = np.random.RandomState(held_pairs)
+    both = max(0, held_pairs - BUF_N)  # tokens with two held picks
+    one = held_pairs - 2 * both
+    absent = [e for e in range(BUF_E) if e not in (2, 3)]
+    picks = np.empty((BUF_N, 2), np.int64)
+    for n in range(BUF_N):
+        if n < both:
+            picks[n] = (2, 3) if n % 2 else (3, 2)
+        elif n < both + one:
+            picks[n] = (2 + n % 2, rng.choice(absent))
+        else:
+            picks[n] = rng.choice(absent, 2, replace=False)
+    x = 0.1 * rng.standard_normal((BUF_N, 64)).astype(np.float32)
+    x[:, :BUF_E] -= 4.0
+    x[np.arange(BUF_N), picks[:, 0]] += 8.0
+    x[np.arange(BUF_N), picks[:, 1]] += 7.0
+    x = x[rng.permutation(BUF_N)]  # the held picks anywhere in the block
+    moe = _share_of(_moe_of(_glm(
+        num_experts=BUF_E, top_k=BUF_K, experts_held=0,
+        n_shared_experts=0)), BUF_FIRST, 2)
+    moe["router"] = jnp.eye(64, BUF_E, dtype=F32)
+    return jnp.asarray(x).reshape(2, BUF_N // 2, 64), moe
+
+
+def test_buffer_bounds_follow_from_the_shapes_alone():
+    bounds = llama._moe_buffer_bounds
+    assert bounds(BUF_N, BUF_K, BUF_E, 2) == BUF_BOUNDS
+    # the two cells with a share: 31.25 % and 15.6 % of every pick first
+    assert bounds(4 * 8192, 4, 32, 8) == (40960, 131072)
+    assert bounds(2 * 8192, 4, 64, 8) == (10240, 65536)
+    for sizes in (bounds(4 * 8192, 4, 32, 8), bounds(2 * 8192, 4, 64, 8)):
+        assert all(r % 512 == 0 for r in sizes)
+    # a first size of half the buffer is the largest that counts as one
+    assert bounds(1024, 2, 8, 2) == (1024, 2048)
+    assert bounds(1024, 2, 8, 4) == (2048,)
+    # one size, the parent's program: every expert held (OLMoE's cell), a
+    # toy block, decode's few rows
+    assert bounds(8 * 4096, 8, 64, 64) == (262144,)
+    assert bounds(B * S, K, E, HELD) == (B * S * K,)
+    assert bounds(8, 4, 64, 8) == (32,)
+    assert bounds(1, 4, 32, 8) == (4,)
+
+
+@pytest.mark.parametrize("held_pairs,rows", [
+    (0, 1536), (1, 1536), (1535, 1536), (1536, 4096), (1537, 4096),
+    (3072, 4096), (4096, 4096)])
+def test_a_sized_buffer_computes_what_the_full_one_does(
+        monkeypatch, held_pairs, rows):
+    """The first size ABOVE the held pairs is taken (a buffer exactly full
+    has no dead last row for the picks past it to read), and at every size
+    the block's output, its statistics and every gradient are those of the
+    buffer that holds all 4,096 picks."""
+    cfg = _buffer_cfg()
+    x, moe = _picks_with(held_pairs)
+    cot = jax.random.normal(jax.random.PRNGKey(3), x.shape, F32)
+
+    def run(x, moe):  # jitted anew a call: the sizes are read at the trace
+        def scalar(x, moe):
+            out, stats = llama._moe_swiglu(x, moe, cfg)
+            return jnp.sum(out * cot) + 0.1 * stats["moe_z"], (out, stats)
+        return jax.jit(jax.grad(scalar, argnums=(0, 1), has_aux=True))(
+            x, moe)
+
+    (g_x, g_moe), (out, stats) = run(x, moe)
+    assert int(stats["held_pairs"]) == held_pairs
+    assert int(stats.pop("buffer_rows")) == rows
+    monkeypatch.setattr(llama, "_moe_buffer_bounds",
+                        lambda n, k, e, held: (n * k,))
+    (g_x_full, g_moe_full), (out_full, stats_full) = run(x, moe)
+    assert "buffer_rows" not in stats_full
+    _close((out, stats, g_x, g_moe),
+           (out_full, stats_full, g_x_full, g_moe_full), tol=1e-6)
+    assert float(jnp.abs(g_moe["router"]).max()) > 0
+    if held_pairs:
+        for leaf in ("wg", "wi", "wo"):
+            assert float(jnp.abs(g_moe[leaf]).max()) > 0
+    else:
+        assert float(jnp.abs(out).max()) == 0.0
+
+
+def test_buffer_rows_come_back_one_a_routed_block():
+    """``moe_buffer_rows`` beside ``moe_held_pairs``, through the aux dict
+    and the loss's counters, under block remat too; absent where every
+    expert is held and where the shapes leave one size."""
+    cfg = dataclasses.replace(
+        _glm(num_experts=BUF_E, top_k=BUF_K, experts_held=2),
+        max_seq_len=1024, remat_block=True)
+    assert llama._moe_buffer_bounds(2 * 1024, BUF_K, BUF_E, 2) == (
+        1536, 4096)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": _tokens(s=1024)}
+    (_, m), grads = jax.jit(jax.value_and_grad(
+        lambda p: llama.loss_fn(p, batch, cfg, metrics=True),
+        has_aux=True))(params)
+    assert m["moe_buffer_rows"].shape == m["moe_held_pairs"].shape == (3,)
+    assert m["moe_buffer_rows"].dtype == jnp.int32
+    for held_pairs, rows in zip(m["moe_held_pairs"], m["moe_buffer_rows"]):
+        assert int(rows) == (1536 if held_pairs < 1536 else 4096)
+    assert all(bool(jnp.isfinite(g).all())
+               for g in jax.tree_util.tree_leaves(grads))
+    # one size: the toy block of every other test here
+    _, m = llama.loss_fn(params, {"tokens": _tokens()}, cfg, metrics=True)
+    assert "moe_buffer_rows" not in m and "moe_held_pairs" in m
+    whole = dataclasses.replace(cfg, experts_held=0)
+    _, m = llama.loss_fn(llama.init_params(jax.random.PRNGKey(0), whole),
+                         batch, whole, metrics=True)
+    assert "moe_buffer_rows" not in m and "moe_held_pairs" not in m
+
+
+def test_a_branch_of_the_choice_is_no_scope_of_the_program():
+    """``lax.switch`` names a branch's instructions ``branch_<i>_fun``: the
+    scope is the program's own inside it."""
+    assert acc.phase_and_scope(
+        "jit(train_step)/transpose(jvp(mtp))/checkpoint/"
+        "rematted_computation/cond/branch_0_fun/moe_experts/mul") == [
+            "recompute", "mtp"]
+    assert acc.phase_and_scope(
+        "jit(train_step)/jvp(cond)/branch_1_fun/moe_permute/gather") == [
+            "forward", "moe_permute"]
+    assert acc.inner_scope(
+        "jit(train_step)/jvp(mtp)/cond/branch_2_fun/moe_experts/mul") == (
+            "moe_experts")
+
+
 @pytest.mark.parametrize("backend", ["reference", "pallas"])
 def test_grouped_matmul_with_sizes_that_sum_to_fewer_rows(backend):
     """The groups end before the buffer does: the rows inside them are the
