@@ -196,7 +196,7 @@ def kernels_phase() -> dict:
             {k: v for k, v in r.items() if k != "traceback"}))
     say(f"kernels: {res['n_ok']} of {res['n_total']} ok (interpret=False)")
     return {
-        "ok": bool(res["all_ok"] and res["n_total"] == 13),
+        "ok": bool(res["all_ok"] and res["n_total"] == 14),
         "device": device_summary(),
         "peak_bytes_in_use": _peak_bytes(),
     }

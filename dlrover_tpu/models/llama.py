@@ -32,6 +32,7 @@ from dlrover_tpu.ops.flash_attention import (
     SAVED_NAMES as FLASH_SAVED_NAMES,
     flash_attention,
 )
+from dlrover_tpu.ops.gather_sum import gather_sum, weighted_sum
 from dlrover_tpu.ops.grouped_matmul import TILING, grouped_matmul_ragged
 from dlrover_tpu.ops.rmsnorm import rmsnorm
 from dlrover_tpu.ops.ssd import causal_conv1d, ssd_chunked
@@ -886,43 +887,108 @@ def _swiglu(x, mlp, dt, fp8_mlp=None):
     return (jax.nn.silu(g) * u) @ mlp["w_down"].astype(dt), None
 
 
+def _live_mask(rows: int, live_rows):
+    return (jnp.arange(rows, dtype=jnp.int32) < live_rows)[:, None]
+
+
 @jax.custom_vjp
-def _dispatch_rows(tokens, order, inverse):
+def _dispatch_rows(tokens, order, inverse, live_rows):
     """``tokens`` [N, C] -> the rows of the (token, k) pairs at the first
     ``R = len(order)`` sorted positions, [R, C]: pair ``p = n*K + k`` sits
-    at ``inverse[p]``, position ``i`` holds pair ``order[i]``.  The pairs
-    are a permutation, so the transpose is a gather by ``inverse`` ([N*K],
-    below ``R`` everywhere: the caller clamps it) and a sum over k, never a
-    scatter-add."""
-    return tokens[order // (inverse.shape[0] // tokens.shape[0])]
+    at ``inverse[p]``, position ``i`` holds pair ``order[i]``; the rows
+    from ``live_rows`` on (None: there are none) are zero.  The pairs are a
+    permutation, so the transpose is :func:`gather_sum` by ``inverse``
+    ([N*K], below ``R`` everywhere: the caller clamps it) with weights of
+    one — K rows a token gathered and summed, never a scatter-add — and a
+    pair at or past ``live_rows`` is no term of it: the cotangent's rows
+    there are never read."""
+    x = tokens[order // (inverse.shape[0] // tokens.shape[0])]
+    if live_rows is not None:
+        # a grouped matmul leaves the rows past its groups unwritten,
+        # forward and backward: nothing of them may reach a sum
+        x = jnp.where(_live_mask(order.shape[0], live_rows), x, 0)
+    return x
 
 
-def _dispatch_rows_fwd(tokens, order, inverse):
-    return _dispatch_rows(tokens, order, inverse), (inverse, tokens.shape[0])
+def _dispatch_rows_fwd(tokens, order, inverse, live_rows):
+    return (_dispatch_rows(tokens, order, inverse, live_rows),
+            (inverse, live_rows, tokens.shape[0]))
+
+
+def _gather_k(rows, index, weights, live_rows):
+    """``(gather_sum(rows, index, weights), the gathered rows or None)``, by
+    the arrangement that moves fewer rows.  Under a share of the experts
+    (``live_rows`` given) most picks are dead: their rows are never read
+    and nothing gathered is kept.  With every expert held there is no row
+    to skip: all are gathered, ``[N*K, C]``, and handed back for a
+    backward pass that wants them."""
+    if live_rows is not None:
+        return gather_sum(rows, index, weights), None
+    picked = rows[index.reshape(-1)]
+    return weighted_sum(picked.reshape(*index.shape, -1), weights), picked
 
 
 def _dispatch_rows_bwd(res, g):
-    inverse, n = res
-    per_pair = g[inverse].reshape(n, -1, g.shape[-1])
-    return (jnp.sum(per_pair, axis=1, dtype=jnp.float32).astype(g.dtype),
-            None, None)
+    inverse, live_rows, n = res
+    index = inverse.reshape(n, -1)
+    here = None if live_rows is None else (index < live_rows).astype(g.dtype)
+    return _gather_k(g, index, here, live_rows)[0], None, None, None
 
 
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
 @jax.custom_vjp
-def _permute_rows(rows, perm, inverse):
-    """``rows[perm]`` where the transpose is ``g[inverse]``, a gather
-    again: ``perm`` a permutation with inverse ``inverse``, or one clamped
-    into the ``len(inverse)`` rows a buffer keeps of it, whose transpose
-    then holds for the rows that ``g`` is zero outside of."""
-    return rows[perm]
+def _combine_rows(rows, weights, order, inverse, live_rows):
+    """The transpose of :func:`_dispatch_rows` with weights: ``out[n] =
+    sum_k weights[n, k] * rows[inverse[n*K + k]]``, [R, C] -> [N, C]
+    (:func:`gather_sum`: float32 products and sum from operands in
+    ``rows``' dtype, one rounding; a pick of weight zero contributes a
+    selected zero and its row is never read).
+
+    Under a share of the experts (``live_rows`` given) nothing of ``N*K``
+    rows is kept for the backward pass, which runs on the sorted side:
+    with ``gs`` the dispatch gather of the cotangent (R rows), ``d rows =
+    w_sorted * gs`` and ``d weights`` is the R row dots ``sum_c rows *
+    gs`` in float32, gathered by ``inverse`` (``N*K`` scalars); the dots
+    of the rows from ``live_rows`` on, which may hold anything, are
+    selected zeros.  With every expert held (``R = N*K``, every pick
+    live) the sorted side is no smaller than the token side: the gathered
+    rows are kept (they are the forward's own), ``d weights`` is their dot
+    with the cotangent and ``d rows`` one gather of the weighted cotangent
+    by ``order`` — 4 ms a step less than the sorted side's pass and its
+    two gathers of ``N*K`` scalars at 8 x 4,096 tokens, 8 picks (PERF.md
+    section 6, PR 48)."""
+    return _combine_rows_fwd(rows, weights, order, inverse, live_rows)[0]
 
 
-_permute_rows.defvjp(
-    lambda rows, perm, inverse: (rows[perm], inverse),
-    lambda inverse, g: (g[inverse], None, None))
+def _combine_rows_fwd(rows, weights, order, inverse, live_rows):
+    out, picked = _gather_k(rows, inverse.reshape(weights.shape), weights,
+                            live_rows)
+    kept = rows if picked is None else picked
+    return out, (kept, weights, order, inverse, live_rows)
+
+
+def _combine_rows_bwd(res, g):
+    kept, weights, order, inverse, live_rows = res
+    (n, k), f32 = weights.shape, jnp.float32
+    if live_rows is None:  # every pick's row, gathered going forward
+        # the weighted sum's own transposes, as JAX derives them
+        weighted, dots = jax.vjp(
+            lambda picked, w: weighted_sum(picked.reshape(n, k, -1), w),
+            kept, weights)[1](g)
+        d_rows = weighted[order]
+    else:
+        gs = g[order // k].astype(f32)
+        w_sorted = weights.reshape(-1)[order].astype(f32)
+        dots = jnp.sum(kept.astype(f32) * gs, axis=-1, keepdims=True)
+        dots = jnp.where(_live_mask(kept.shape[0], live_rows), dots, 0.0)
+        dots = dots[inverse, 0].reshape(n, k)
+        d_rows = (w_sorted[:, None] * gs).astype(kept.dtype)
+    return d_rows, dots.astype(weights.dtype), None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 @functools.partial(
@@ -969,33 +1035,26 @@ def _routed_sum(ffn, rows: int, tokens, gate_vals, order, inverse,
                 live_rows):
     """A routed block's sorted side in a buffer of ``rows`` rows, and the
     sum over k: the token rows of the first ``rows`` sorted pairs gathered
-    (scope ``moe_permute``), ``ffn`` over them (``moe_experts``), its
-    result brought back to the pairs' own order (``moe_permute``) and
-    summed with the router's weights, ``[N, C]`` (``moe_combine``).
+    (:func:`_dispatch_rows`, scope ``moe_permute``), ``ffn`` over them
+    (``moe_experts``), and each token's K rows of the result gathered,
+    weighed by the router and summed, ``[N, C]`` (:func:`_combine_rows`,
+    ``moe_combine``): the token side is that one operation and its
+    transpose, and under a share of the experts no array of ``N*K`` rows
+    by ``C`` is built for it.
     ``live_rows`` (None: every row is computed) is how many rows ``ffn``'s
     groups cover, and ``rows`` must be ABOVE it unless it is ``N*K``: the
-    rows from there on are zero going in and coming out, forward and
-    backward, and a pair past the buffer reads the last of them."""
-    n, c = tokens.shape
+    rows from there on are zero going in, what ``ffn`` leaves of them is
+    never read (their pairs' weights are zero), and a pair past the buffer
+    is given the last of them."""
     if rows < inverse.shape[0]:
         order, inverse = order[:rows], jnp.minimum(inverse, rows - 1)
     with jax.named_scope("moe_permute"):
-        x = _dispatch_rows(tokens, order, inverse)
-        if live_rows is not None:
-            # a grouped matmul leaves the rows past its groups unwritten,
-            # forward and backward: nothing of them may reach a sum
-            live = (jnp.arange(rows, dtype=jnp.int32) < live_rows)[:, None]
-            x = jnp.where(live, x, 0)
+        x = _dispatch_rows(tokens, order, inverse, live_rows)
     with jax.named_scope("moe_experts"):
         y = ffn(x)
-    with jax.named_scope("moe_permute"):
-        if live_rows is not None:
-            y = jnp.where(live, y, 0)
-        per_pair = _permute_rows(y, inverse, order).reshape(n, -1, c)
     with jax.named_scope("moe_combine"):
-        return jnp.einsum(
-            "nkc,nk->nc", per_pair, gate_vals.astype(tokens.dtype),
-            preferred_element_type=jnp.float32).astype(tokens.dtype)
+        return _combine_rows(y, gate_vals.astype(tokens.dtype), order,
+                             inverse, live_rows)
 
 
 def _routed_sum_at(rows: int, dt, tokens, gate_vals, weights, indices):
@@ -1027,7 +1086,8 @@ def _routed_sum_sized(bounds, dt, size, tokens, gate_vals, weights, indices):
     The routed forward then runs twice a step with block remat or
     without: once forward and once inside the backward rule (the block's
     recomputation needs nothing of this function's result, and XLA drops
-    its copy)."""
+    its copy) — less its last step there: the combine's backward needs
+    the experts' rows and nothing of the combine's result."""
     return jax.lax.switch(
         size, [functools.partial(_routed_sum_at, rows, dt)
                for rows in bounds], tokens, gate_vals, weights, indices)
@@ -1062,8 +1122,9 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
     pairs are sorted by expert (stable, so a pair's rank inside its
     expert's group follows the token order), the token rows gathered in
     that order, ``wg``/``wi``/``wo`` applied as grouped matmuls over the
-    ragged groups (``ops.grouped_matmul``), the rows brought back by the
-    inverse permutation and summed over k with the router's weights.
+    ragged groups (``ops.grouped_matmul``), and each token's K rows of the
+    result gathered where the sort put them, weighed by the router and
+    summed (``ops.gather_sum``; its backward runs on the sorted side).
     Nothing of size ``N x E x capacity`` or ``N*K x E`` is built, forward
     or backward, and no pair is dropped unless a capacity is asked for.
 
@@ -1098,16 +1159,17 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
     experts (``cfg.experts_held`` < E) the router still scores, chooses
     and normalises over all E; the pairs sort with the held experts
     first and the grouped matmuls' groups end with the last held expert's
-    pairs.  The sorted side — the dispatch gather, the three grouped
-    matmuls with ``silu(g) * u``, the two masks, the gather back — runs in
-    a buffer of the first of :func:`_moe_buffer_bounds`' sizes that is
-    above the held pairs (``jax.lax.switch``; ``N*K`` when a skewed step
-    outgrows the others), so it is sized by the rows computed and not by
-    every pick: no pair is dropped and every computed row's arithmetic is
-    the full buffer's.  The rows between the held pairs and the buffer's
-    end are gathered and masked to zero going in and coming out (a kernel
-    leaves them unwritten); a pick past the buffer has weight zero and
-    reads the buffer's last row, a zero one.  ``stats["held_pairs"]``
+    pairs.  The sorted side — the dispatch gather and its mask, the three
+    grouped matmuls with ``silu(g) * u`` — runs in a buffer of the first
+    of :func:`_moe_buffer_bounds`' sizes that is above the held pairs
+    (``jax.lax.switch``; ``N*K`` when a skewed step outgrows the others),
+    so it is sized by the rows computed and not by every pick: no pair is
+    dropped and every computed row's arithmetic is the full buffer's.  The
+    rows between the held pairs and the buffer's end are gathered and
+    masked to zero going in (a kernel leaves them unwritten coming out,
+    forward and backward); a pick there or past the buffer has weight zero,
+    so the token side never reads its row: it contributes a selected
+    zero.  ``stats["held_pairs"]``
     counts the pairs computed here and ``stats["buffer_rows"]`` the size
     taken (absent where the shapes leave one size: the program is then the
     one without a choice); ``tokens_per_expert`` stays ``[E]``, in the

@@ -310,6 +310,33 @@ def _run_grouped_matmul() -> Dict:
             "fwd_us": round(us, 1)}
 
 
+def _run_gather_sum() -> Dict:
+    """The routed block's token side (``ops.gather_sum``: K rows a token
+    gathered out of HBM by row DMA, weighed and summed) at a routed cell's
+    width, a quarter of the picks live and the rows past them poisoned:
+    the kernel against its ``jax.numpy`` reference (the same float32
+    terms; a bf16 rounding apart at most, where the sums' orders differ)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.ops.gather_sum import gather_sum
+
+    rng = np.random.RandomState(6)
+    N, K, C, R, live = 4096, 4, 2048, 5120, 4000
+    rows = jnp.asarray(rng.randn(R, C), jnp.bfloat16).at[live:].set(jnp.nan)
+    dead = rng.rand(N, K) < 0.75
+    index = jnp.asarray(
+        np.where(dead, R - 1, rng.randint(0, live, size=(N, K))), jnp.int32)
+    weights = jnp.asarray(np.where(dead, 0.0, rng.rand(N, K)), jnp.bfloat16)
+    fwd = jax.jit(lambda r, i, w: gather_sum(r, i, w, backend="pallas"))
+    ref = jax.jit(lambda r, i, w: gather_sum(r, i, w, backend="reference"))
+    out = fwd(rows, index, weights)
+    err = _rel_err(out, ref(rows, index, weights))
+    us = _time_fn(fwd, rows, index, weights)
+    return {"ok": bool(err < 1e-2), "fwd_rel_err": round(err, 5),
+            "fwd_us": round(us, 1)}
+
+
 def run_kernel_smoke(
     out_path: Optional[str] = None,
     only: Optional[str] = None,
@@ -330,6 +357,7 @@ def run_kernel_smoke(
         ("fused_lm_head_ce", _run_fused_lm_head),
         ("quantize_blockwise", _run_quant),
         ("grouped_matmul", _run_grouped_matmul),
+        ("gather_sum", _run_gather_sum),
     ]
     if only:
         cases = [c for c in cases if only in c[0]]

@@ -568,16 +568,57 @@ def test_hybrid_step_forms_no_decay_mask_in_hbm(hybrid_step):
     assert seen > 100  # the scope's instructions were there to be read
 
 
-@pytest.fixture(scope="module")
-def conv_step(topo):
-    """``(job, cfg)`` of the LFM2 cell's step from shapes, depth cut to the
-    dense convolution layer, the routed attention layer and ONE routed
-    convolution layer, widths, held experts, batch and sequence length
-    whole."""
+def _step_and_text(topo, loss, cfg, sequences, seq_len):
+    """``(job, compiled text)`` of ``loss``'s training step on one described
+    chip, from shapes."""
     import optax
 
     from dlrover_tpu.models import llama
     from dlrover_tpu.parallel.mesh import MeshSpec
+
+    texts = []
+    with pytest.MonkeyPatch.context() as patch:
+        # the kernel dispatchers ask the backend and would see the CPU
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        summary = acc.program_summary
+        patch.setattr(acc, "program_summary",
+                      lambda text: texts.append(text) or summary(text))
+        job = acc.aot_analyze(
+            loss_fn=loss, init_fn=lambda r: llama.init_params(r, cfg),
+            optimizer=optax.adamw(1e-5),
+            sample_batch={
+                "tokens": np.zeros((sequences, seq_len + 1), np.int32)},
+            strategy=acc.Strategy(mesh=MeshSpec()), param_specs="planner",
+            devices=topo.devices[:1],
+        )
+    return job, texts[-1]
+
+
+def _row_gathers(text, width, only=""):
+    """``{(phase, scope, rows): n}`` of the compiled step's XLA row
+    gathers with ``width`` columns (a gather is a fusion of its own that
+    writes every row it reads), those whose ``op_name`` holds ``only``."""
+    found = {}
+    for line in text.splitlines():
+        m = re.match(rf"\s*%?[\w.\-]+ = bf16\[(\d+),{width}\]\S* fusion\(",
+                     line)
+        op = re.search(r'op_name="([^"]*/gather)"', line)
+        if not (m and op and "kind=kCustom" in line and only in op.group(1)):
+            continue
+        verdict = acc.phase_and_scope(op.group(1))
+        if verdict is not None:
+            key = (*verdict, int(m.group(1)))
+            found[key] = found.get(key, 0) + 1
+    return found
+
+
+@pytest.fixture(scope="module")
+def conv_step(topo):
+    """``(job, compiled text, cfg)`` of the LFM2 cell's step from shapes,
+    depth cut to the dense convolution layer, the routed attention layer
+    and ONE routed convolution layer, widths, held experts, batch and
+    sequence length whole."""
+    from dlrover_tpu.models import llama
 
     cfg = llama.LlamaConfig(
         vocab_size=16384, n_layer=3, n_head=32, n_kv_head=8, d_model=2048,
@@ -594,17 +635,7 @@ def conv_step(topo):
 
     loss.rule_leaves = llama.rule_leaves(cfg)
     loss.program_facts = llama.program_facts(cfg, 8192)
-    with pytest.MonkeyPatch.context() as patch:
-        # the kernel dispatchers ask the backend and would see the CPU
-        patch.setattr(jax, "default_backend", lambda: "tpu")
-        job = acc.aot_analyze(
-            loss_fn=loss, init_fn=lambda r: llama.init_params(r, cfg),
-            optimizer=optax.adamw(1e-5),
-            sample_batch={"tokens": np.zeros((4, 8193), np.int32)},
-            strategy=acc.Strategy(mesh=MeshSpec()), param_specs="planner",
-            devices=topo.devices[:1],
-        )
-    return job, cfg
+    return (*_step_and_text(topo, loss, cfg, 4, 8192), cfg)
 
 
 def test_conv_step_compiles_at_published_widths(conv_step):
@@ -612,7 +643,7 @@ def test_conv_step_compiles_at_published_widths(conv_step):
     behind the per-head q/k norm), a convolution layer's routed MLP runs
     the grouped matmuls over its share of the experts, and the compiled
     step's tables name the mixer's nested scopes in every phase."""
-    job, cfg = conv_step
+    job, _, cfg = conv_step
     kernels = job.program["kernels"]
     assert (kernels["flash_fwd"], kernels["flash_bwd_dq"],
             kernels["flash_bwd_dkv"]) == (1, 1, 1)
@@ -645,7 +676,7 @@ def test_conv_step_sizes_its_sorted_buffer(conv_step):
     the program's scopes and phases, and no scope is a branch's name."""
     from dlrover_tpu.models import llama
 
-    job, _ = conv_step
+    job, _, _ = conv_step
     assert llama._moe_buffer_bounds(4 * 8192, 4, 32, 8) == (40960, 131072)
     kernels = job.program["kernels"]
     assert (kernels["gmm"], kernels["tgmm"]) == (2 * 9, 2 * 3)
@@ -654,3 +685,84 @@ def test_conv_step_sizes_its_sorted_buffer(conv_step):
     assert {(phase, scope) for phase in ("forward", "recompute", "backward")
             for scope in ("moe_permute", "moe_experts")} <= found
     assert not [scope for _, scope in found if scope.startswith("branch_")]
+
+
+def test_conv_step_token_side_builds_no_pick_sized_array(conv_step):
+    """The token side of a routed block is ``gather_sum`` and its transpose:
+    the kernel runs twice a block in each size's branch (the combine
+    forward, the dispatch's transpose backward — the combine recomputed
+    inside the backward rule has no reader and is dropped), the grouped
+    matmuls are the parent's, and no instruction of the sized buffer's
+    branch has 131,072 rows by 2,048 columns in any arrangement, forward,
+    recomputed or backward: the three XLA row gathers left a block are the
+    40,960-row dispatch (forward and recomputed) and the dispatch of the
+    cotangent that the combine's backward runs on the sorted side."""
+    job, text, cfg = conv_step
+    kernels = job.program["kernels"]
+    assert (kernels["gmm"], kernels["tgmm"]) == (2 * 9, 2 * 3)
+    assert kernels["gather_sum"] == 2 * 2 * 2
+    n, k, c = 4 * 8192, cfg.top_k, cfg.d_model
+    picks = {f"[{n * k},{c}]", f"[{n},{k},{c}]", f"[{k},{n},{c}]"}
+    seen = 0
+    for line in text.splitlines():
+        if "branch_0_fun" not in line or " = " not in line:
+            continue
+        seen += 1
+        shapes = line.split(" = ", 1)[1].split("metadata=", 1)[0]
+        assert not picks & set(re.findall(r"\[[\d,]+\]", shapes)), line[:200]
+    assert seen > 100  # the branch's instructions were there to be read
+    gathers = {("forward", "moe_permute"): 2, ("recompute", "moe_permute"): 2,
+               ("backward", "moe_combine"): 2}
+    for branch, rows in (("branch_0_fun", 40960), ("branch_1_fun", n * k)):
+        assert _row_gathers(text, c, only=branch) == {
+            (*where, rows): count for where, count in gathers.items()}
+    # what is left under each scope: the recomputed forward holds nothing
+    # of the combine (its residuals are the experts' rows, the weights and
+    # the two index vectors; the parent's second gather sat in moe_permute)
+    found = {tuple(v) for v in job.program["scopes"].values()}
+    assert {(phase, "moe_permute")
+            for phase in ("forward", "recompute", "backward")} <= found
+    assert {("forward", "moe_combine"), ("backward", "moe_combine")} <= found
+    assert job.memory["peak_bytes"] < 16_909_336_064
+
+
+@pytest.fixture(scope="module")
+def olmoe_step(topo):
+    """``(job, compiled text, cfg)`` of the OLMoE cell's step from shapes:
+    its one layer at published widths, eight sequences of 4,096, every
+    expert held, no block remat."""
+    from dlrover_tpu.models import llama
+
+    cfg = llama.LlamaConfig(
+        vocab_size=50304, n_layer=1, n_head=16, n_kv_head=16, d_model=2048,
+        d_ff=1024, max_seq_len=4096, rms_eps=1e-5, remat_block=False,
+        num_experts=64, top_k=8, moe_every=1, norm_topk_prob=False,
+        balance_all_k=True, qk_norm=True)
+
+    def loss(params, batch):
+        return llama.loss_fn(params, batch, cfg, metrics=True)
+
+    return (*_step_and_text(topo, loss, cfg, 8, 4096), cfg)
+
+
+def test_olmoe_step_keeps_the_gathered_rows(olmoe_step):
+    """Every expert is held, so every pick is live and has its row (``R =
+    N*K``): the kernel would have no row to skip and the sorted side is no
+    smaller than the token side, so the step stays what it was — XLA's row
+    gathers, two forward (the dispatch, the combine) and two backward
+    (the weighted cotangent by ``order``, the dispatch's transpose), the
+    gathered rows kept for the router weights' gradient — at the same
+    compiled peak (14.149 GB; the sorted-side rule read 14.245 and 1.1 %
+    fewer tokens a second on the chip)."""
+    job, text, cfg = olmoe_step
+    kernels = job.program["kernels"]
+    assert (kernels["gmm"], kernels["tgmm"]) == (6, 3)
+    assert "gather_sum" not in kernels
+    rows = 8 * 4096 * cfg.top_k
+    assert _row_gathers(text, cfg.d_model) == {
+        ("forward", "embed", 8 * 4096): 1,
+        ("forward", "moe_permute", rows): 1,
+        ("forward", "moe_combine", rows): 1,
+        ("backward", "moe_combine", rows): 1,
+        ("backward", "moe_permute", rows): 1}
+    assert job.memory["peak_bytes"] < 14.16e9

@@ -2,9 +2,13 @@
 ragged.  Against a per-token loop over the chosen experts (forward and
 ``jax.grad``), with and without renormalisation, pads, a capacity that
 drops; the published router's loss terms against closed forms; q/k RMSNorm;
-and ``accelerate()``'s step handing out the block's counters."""
+``accelerate()``'s step handing out the block's counters; and the token
+side — K rows a token gathered, weighed and summed, its backward on the
+sorted side — against the gather to ``[N*K, C]`` and the einsum it
+replaced, the kernel in interpret mode."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +17,7 @@ import optax
 import pytest
 
 from dlrover_tpu.models import llama, llama_infer
+from dlrover_tpu.ops import gather_sum as gather_sum_op
 
 E, K, D, F = 8, 2, 16, 8
 
@@ -366,3 +371,207 @@ def test_counters_add_up_over_grad_accum_microbatches():
     assert float(two["moe_z"]) == pytest.approx(float(one["moe_z"]),
                                                 rel=1e-4)
     assert float(two["loss"]) == pytest.approx(float(one["loss"]), rel=1e-4)
+
+
+# -- the token side: gather K rows, weigh, sum ------------------------------
+
+
+def _token_side(k, share, dtype, n=64, c=32, seed=3):
+    """A routed block's indices as ``_moe_swiglu`` makes them, over random
+    picks of 8 experts: ``share`` holds 2 of them in a buffer that ends
+    before every pick (a clamped ``inverse``, zero weights for the absent
+    experts' picks, ``live_rows`` under the buffer's rows) or, as
+    ``"every_pick"``, in the buffer a skewed step falls back to; else
+    every expert is held.  Returns ``(rows, weights, order, inverse, live_rows)``
+    with the rows from ``live_rows`` on poisoned with NaN."""
+    rng = np.random.RandomState(seed + k)
+    e, held = 8, (2 if share else 8)
+    expert = rng.randint(0, e, size=(n, k))
+    flat = expert.reshape(-1)
+    order = np.argsort(flat, kind="stable").astype(np.int32)
+    inverse = np.argsort(order).astype(np.int32)
+    weights = np.where(expert < held, rng.rand(n, k) + 0.1, 0.0)
+    total = n * k
+    live = int((flat < held).sum())
+    r = min(total, live + 5) if share is True else total
+    rows = rng.randn(r, c)
+    if share:
+        rows[live:] = np.nan
+    return (jnp.asarray(rows, dtype), jnp.asarray(weights, dtype),
+            jnp.asarray(order[:r]), jnp.asarray(np.minimum(inverse, r - 1)),
+            jnp.int32(live) if share else None)
+
+
+def _gather_einsum(rows, weights, order, inverse, live_rows):
+    """What the block ran before: the mask over the result, every pair's
+    row gathered to ``[N*K, C]`` (a pair past the buffer reads its last,
+    zero row), ``einsum nkc,nk->nc`` in float32."""
+    n, k = weights.shape
+    if live_rows is not None:
+        rows = jnp.where(llama._live_mask(rows.shape[0], live_rows), rows, 0)
+    per_pair = rows[inverse].reshape(n, k, -1)
+    return jnp.einsum("nkc,nk->nc", per_pair, weights,
+                      preferred_element_type=jnp.float32).astype(rows.dtype)
+
+
+def _value_and_grads(fn, rows, weights, *indices):
+    cot = jax.random.normal(jax.random.PRNGKey(9),
+                            (weights.shape[0], rows.shape[1]), rows.dtype)
+
+    def loss(rows, weights):
+        out = fn(rows, weights, *indices)
+        return jnp.sum(out.astype(jnp.float32) * cot.astype(jnp.float32)), out
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+        rows, weights)
+    return (out, *grads)
+
+
+def _assert_close(got, want, dtype):
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("share", [False, True, "every_pick"],
+                         ids=["all_held", "share", "share_every_pick"])
+@pytest.mark.parametrize("k", [1, 4, 8])
+def test_combine_rows_against_the_gather_and_einsum(k, share, dtype):
+    """Value, ``d rows`` and ``d weights``, by the rule on the sorted side
+    (a buffer that ends before every pick) and by the one that keeps the
+    gathered rows (a row for every pick); with a share the rows from
+    ``live_rows`` on hold NaN and nothing of it leaks, forward or back."""
+    args = _token_side(k, share, dtype)
+    got = _value_and_grads(llama._combine_rows, *args)
+    want = _value_and_grads(_gather_einsum, *args)
+    if share:
+        # the old form's d rows is masked where ours is 0 * finite
+        assert not np.asarray(got[1], np.float32)[int(args[4]):].any()
+    _assert_close(got, want, dtype)
+
+
+@pytest.mark.parametrize("share", [False, True], ids=["all_held", "share"])
+@pytest.mark.parametrize("k", [1, 4, 8])
+def test_dispatch_transpose_is_the_unweighted_gather_sum(k, share):
+    """``_dispatch_rows``' backward against its rule as it stood — the
+    mask's transpose over the cotangent, ``g[inverse]`` to ``[N*K, C]``,
+    the sum over k in float32 — with the cotangent's rows from
+    ``live_rows`` on poisoned."""
+    rows, _, order, inverse, live_rows = _token_side(k, share, jnp.bfloat16)
+    n = inverse.shape[0] // k
+    tokens = jax.random.normal(jax.random.PRNGKey(2), (n, rows.shape[1]),
+                               jnp.bfloat16)
+    x, pull = jax.vjp(
+        lambda t: llama._dispatch_rows(t, order, inverse, live_rows), tokens)
+    want_x = tokens[order // k]
+    g = rows  # finite below live_rows, NaN from there on
+    if share:
+        live = llama._live_mask(rows.shape[0], live_rows)
+        want_x, g_masked = jnp.where(live, want_x, 0), jnp.where(live, g, 0)
+    else:
+        g_masked = g
+    assert (x == want_x).all()
+    want = jnp.sum(g_masked[inverse].reshape(n, k, -1), axis=1,
+                   dtype=jnp.float32).astype(g.dtype)
+    (got,) = pull(g)
+    assert (got == want).all()
+
+
+def test_combine_rows_check_grads():
+    rows, weights, order, inverse, live_rows = _token_side(
+        4, True, jnp.float32, n=12, c=8)
+    rows = jnp.nan_to_num(rows)  # finite differences read every row
+    jax.test_util.check_grads(
+        lambda r, w: llama._combine_rows(r, w, order, inverse, live_rows),
+        (rows, weights), order=1, modes=("rev",), atol=1e-2, rtol=1e-2)
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """32 picks a grid step: several steps at a toy token count."""
+    monkeypatch.setattr(gather_sum_op, "_TILE_PICKS", 32)
+
+
+@pytest.mark.parametrize("share", [False, True], ids=["all_held", "share"])
+@pytest.mark.parametrize("k", [1, 4, 8])
+def test_gather_sum_kernel_is_its_reference_bit_for_bit(small_tiles, k, share):
+    """The Pallas kernel in interpret mode: the dead picks start no DMA
+    and contribute a selected zero (their rows hold NaN, and the buffer
+    they would have landed in is unwritten)."""
+    rows, weights, _, inverse, _ = _token_side(
+        k, share, jnp.bfloat16, n=64, c=1024)
+    index = inverse.reshape(weights.shape)
+    assert gather_sum_op._kernel_fits(rows, index)
+    want = gather_sum_op.gather_sum(rows, index, weights, backend="reference")
+    got = gather_sum_op.gather_sum(rows, index, weights, backend="pallas",
+                                   interpret=True)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    assert (got == want).all()
+
+
+@pytest.mark.parametrize("case", ["float32", "narrow", "ragged_tile", "mesh"])
+def test_gather_sum_kernel_chooses_itself_by_shape(small_tiles, case):
+    """bfloat16 rows of whole 1,024-column tiles, whole tiles of tokens, no
+    free mesh axis: anything else is the ``jax.numpy`` form."""
+    dtype = jnp.float32 if case == "float32" else jnp.bfloat16
+    rows = jnp.zeros((16, 256 if case == "narrow" else 1024), dtype)
+    index = jnp.zeros((60 if case == "ragged_tile" else 64, 4), jnp.int32)
+    if case == "mesh":
+        from dlrover_tpu.parallel.mesh import MeshSpec, build_mesh
+
+        with jax.set_mesh(build_mesh(MeshSpec(fsdp=2), jax.devices()[:2])):
+            assert not gather_sum_op._kernel_fits(rows, index)
+        assert gather_sum_op._kernel_fits(rows, index)
+    else:
+        assert not gather_sum_op._kernel_fits(rows, index)
+
+
+@pytest.mark.parametrize("held", [8, 2], ids=["all_held", "share"])
+def test_block_with_the_kernel_is_the_block_without(small_tiles, monkeypatch,
+                                                    held):
+    """``_moe_swiglu`` forward and gradients with ``gather_sum`` steered to
+    the kernel (interpret mode) in the combine and in the dispatch's
+    transpose, against the ``jax.numpy`` form."""
+    d = 1024
+    cfg = llama.LlamaConfig.tiny(
+        n_layer=1, d_model=d, d_ff=F, n_head=4, n_kv_head=2, num_experts=E,
+        top_k=K, moe_every=1, experts_held=held, dtype=jnp.bfloat16)
+    k = jax.random.split(jax.random.PRNGKey(0), 5)
+    moe = {"router": jax.random.normal(k[0], (d, E)) * 0.05,
+           "wg": jax.random.normal(k[1], (held, d, F)) * 0.03,
+           "wi": jax.random.normal(k[2], (held, d, F)) * 0.03,
+           "wo": jax.random.normal(k[3], (held, F, d)) * 0.3}
+    # a share: 1,024 pairs, a quarter held, in the first size of 512 rows
+    x = jax.random.normal(k[4], (2, 64 if held == E else 256, d),
+                          jnp.bfloat16)
+
+    def run():
+        def loss(x, moe):
+            out, _ = llama._moe_swiglu(x, moe, cfg)
+            return jnp.sum(jnp.square(out.astype(jnp.float32))), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(x, moe)
+        return jax.tree.leaves((out, grads))
+
+    want = run()
+    calls = []
+
+    def steered(rows, index, weights):
+        calls.append(rows.shape)
+        return gather_sum_op.gather_sum(rows, index, weights,
+                                        backend="pallas", interpret=True)
+    monkeypatch.setattr(llama, "gather_sum", steered)
+    got = run()
+    if held == E:
+        # every pick live: gathered whole, both ways, as before
+        assert not calls
+    else:
+        assert llama._moe_buffer_bounds(512, K, E, held) == (512, 1024)
+        # the combine and the dispatch's transpose, in each size's branch
+        assert [rows for rows, _ in calls].count(512) >= 2
+        assert [rows for rows, _ in calls].count(1024) >= 2
+    for a, b in zip(got, want):
+        assert (a == b).all()
