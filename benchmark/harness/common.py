@@ -128,7 +128,7 @@ def memory_peak_bytes() -> int:
 
 
 def less_parts(name: str, total: float, **parts: float):
-    """``setup_s`` and ``resume_s`` judge the tree's own seconds: the host
+    """``setup_s`` and the kill-to-step seconds judge the tree's own: the host
     clock's total less the parts no tree can move (the backend's start-up)
     or that are the benchmark's own (its comparison with the reference).
     Returns the metric and the note line that shows the whole account —

@@ -30,17 +30,17 @@ def seconds(spans: dict, trace: dict) -> Optional[dict]:
     "conv_layers"}``: seconds of the instructions whose outermost scope is
     ``conv``, of those under each nested scope, the device's busy seconds,
     and the program's own count of its convolution layers."""
-    programs = [r for r in obs_read.last_incarnation(obs_read.records(spans))
-                if r.get("kind") == "accelerate.program"
-                and r.get("scopes") and r.get("subscopes")]
-    ops = trace.get("op_self_s") if trace else None
-    if not programs or not ops or not trace.get("busy_s"):
+    program = obs_read.program_tables(obs_read.records(spans), trace,
+                                      nested=True)
+    if program is None:
         return None
-    scopes, inner = programs[-1]["scopes"], programs[-1]["subscopes"]
+    scopes, inner = program["scopes"], program["subscopes"]
     out = dict.fromkeys(("conv",) + INNER, 0.0)
-    for label, secs in ops.items():
+    kernels = trace.get("kernel_s") or {}
+    for label, secs in trace["op_self_s"].items():
         name = label.split(" ", 1)[0]
-        if name not in scopes or scopes[name][1] != "conv":
+        if label in kernels or name not in scopes or (
+                scopes[name][1] != "conv"):
             continue  # a kernel's label, another scope's, or nobody's
         out["conv"] += secs
         if inner.get(name) in INNER:
@@ -48,4 +48,4 @@ def seconds(spans: dict, trace: dict) -> Optional[dict]:
     if not out["conv"]:
         return None
     return dict(out, busy_s=trace["busy_s"],
-                conv_layers=programs[-1].get("conv_layers"))
+                conv_layers=program.get("conv_layers"))

@@ -2,8 +2,14 @@
 """What the router of a cell with a SHARE of the experts does over the first
 steps of training: per step the loss, the share of the router's picks that
 land on the held experts, the fullest expert over the mean, and the largest
-selection bias — the counters the jitted step returns, fetched every step.
-Not a cell and not a measurement of speed:
+selection bias — the counters the jitted step returns, fetched every step —
+and, where the block chooses its sorted buffer's size at run time, the rows
+each block's buffer took.  It replays the traffic of the cell it is given
+(its workload file's ``traffic``: the learning rate, the sequences), so the
+same call shows ``train-steady``'s collapse within ten steps on a workload
+file that names it and ``train-decayed``'s routing holding for a window
+(``glm4_7_flash-l5.train-decayed``, ``lfm2_8b_a1b-l5.train-decayed``;
+PERF.md section 5).  Not a cell and not a measurement of speed:
 
     python3 benchmark/harness/glm_trajectory.py <cell> <seed> [steps]
 """
@@ -48,6 +54,8 @@ def main(argv) -> int:
                                         per_expert)],
             "load_max_over_mean": [round(row.max() * row.size / row.sum(), 2)
                                    for row in per_expert],
+            "buffer_rows": np.asarray(
+                m.get("moe_buffer_rows", [])).tolist(),
             "router_bias_abs_max": float(m["moe_router_bias_abs_max"]),
             "main_ce": round(float(m["main_ce"]), 4),
             "mtp_ce": round(float(m["mtp_ce"]), 4),

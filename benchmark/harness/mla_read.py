@@ -33,19 +33,20 @@ def seconds(spans: dict, trace: dict) -> Optional[dict]:
     the ``attention`` scope of every block application (the prediction
     block's too), of those under each latent sub-scope, of the three flash
     kernels, and of everything under ``mtp`` that is no Mosaic kernel."""
-    programs = [r for r in obs_read.last_incarnation(obs_read.records(spans))
-                if r.get("kind") == "accelerate.program"
-                and r.get("scopes") and r.get("subscopes")]
-    ops = trace.get("op_self_s") if trace else None
-    if not programs or not ops or not trace.get("busy_s"):
+    program = obs_read.program_tables(obs_read.records(spans), trace,
+                                      nested=True)
+    if program is None:
         return None
-    scopes, inner = programs[-1]["scopes"], programs[-1]["subscopes"]
+    scopes, inner = program["scopes"], program["subscopes"]
     out = {"attention_ops": 0.0, "mla_q": 0.0, "mla_kv": 0.0, "mla_out": 0.0,
            "mtp_ops": 0.0}
-    for label, secs in ops.items():
+    kernels = trace.get("kernel_s") or {}
+    for label, secs in trace["op_self_s"].items():
         name = label.split(" ", 1)[0]
-        if name not in scopes:
-            continue  # a kernel's label, or nothing the program names
+        if label in kernels or name not in scopes:
+            # a kernel's label (its first call's instruction may bear the
+            # same name: ``gmm``), or nothing the program names
+            continue
         scope, within = scopes[name][1], inner.get(name, "")
         if scope == "mtp":
             out["mtp_ops"] += secs
@@ -57,4 +58,4 @@ def seconds(spans: dict, trace: dict) -> Optional[dict]:
     out["flash"] = sum(trace.get("kernel_s", {}).get(k, 0.0)
                        for k in trace_reduce.FLASH_KERNELS)
     return dict(out, busy_s=trace["busy_s"],
-                block_applications=programs[-1].get("block_applications"))
+                block_applications=program.get("block_applications"))

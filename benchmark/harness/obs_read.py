@@ -99,8 +99,8 @@ def incarnation(rec: dict) -> Optional[int]:
 
 def last_incarnation(recs: Iterable[dict]) -> List[dict]:
     """The records of the newest worker incarnation where the job ran
-    under the launcher (the resumed worker: the one ``resume_s`` waits
-    for), else all of them (one process, no launcher)."""
+    under the launcher (the resumed worker: the one the kill-to-step clock
+    waits for), else all of them (one process, no launcher)."""
     recs = list(recs)
     newest = max((i for i in map(incarnation, recs) if i is not None),
                  default=None)
@@ -148,40 +148,67 @@ def child_seconds(recs: List[dict], parents: List[dict],
 # -- the compiled step's scope table against a device trace -----------------
 
 
+def program_tables(recs: List[dict], trace: dict,
+                   nested: bool = False) -> Optional[dict]:
+    """The newest ``accelerate.program`` event that journals a scope table
+    (with ``nested``: and ``subscopes``), where the reduced trace has
+    operations to join to it; None otherwise."""
+    if not trace or not trace.get("op_self_s") or not trace.get("busy_s"):
+        return None
+    programs = [r for r in last_incarnation(recs)
+                if r.get("kind") == "accelerate.program" and r.get("scopes")
+                and (r.get("subscopes") or not nested)]
+    return programs[-1] if programs else None
+
+
+def placed_ops(trace: dict):
+    """``(instruction, label, seconds)`` of every device operation of a
+    reduced trace, by the name the program's scope table knows it by: an
+    XLA instruction's own, and for a Mosaic kernel each CALLING instruction
+    (``trace["kernel_call_s"]``: ``gather_sum.16`` runs under
+    ``moe_combine``, ``gather_sum.24`` under ``moe_permute``), never the
+    kernel's name, which is the same under every scope.  ``label`` is the
+    key of ``op_self_s``: the kernel's name for a kernel."""
+    calls = trace.get("kernel_call_s") or {}
+    for label, secs in trace["op_self_s"].items():
+        if label in calls:
+            for name, call_secs in calls[label].items():
+                yield name, label, call_secs
+        else:
+            yield label.split(" ", 1)[0], label, secs
+
+
 def scope_shares(recs: List[dict], trace: dict) -> Optional[dict]:
     """Device self time by ``(phase, scope)`` as shares of busy time: the
-    trace's ``op_self_s`` (keys ``<instruction name> <result shape>``, or a
-    Pallas kernel's name) joined to the ``scopes`` table the program
-    journals once with its ``accelerate.program`` event.  ``unphased`` is
-    what the table does not name."""
-    tables = [r["scopes"] for r in last_incarnation(recs)
-              if r.get("kind") == "accelerate.program" and r.get("scopes")]
-    ops = trace.get("op_self_s") if trace else None
-    if not tables or not ops or not trace.get("busy_s"):
+    trace's operations (:func:`placed_ops`) joined to the ``scopes`` table
+    the program journals once with its ``accelerate.program`` event.
+    ``unphased`` is what the table does not name; ``unplaced_kernel_s``
+    (``{kernel: seconds}``) is the part of it that is Mosaic kernels, by
+    kernel name, for a reader that knows whose kernel it is."""
+    program = program_tables(recs, trace)
+    if program is None:
         return None
-    table = tables[-1]
+    table = program["scopes"]
+    kernels = trace.get("kernel_s") or {}
     by: Dict[tuple, float] = {}
-    for label, secs in ops.items():
-        name = label.split(" ", 1)[0]
+    unplaced: Dict[str, float] = {}
+    for name, label, secs in placed_ops(trace):
         verdict = table.get(name)
-        if verdict is None:
-            # a kernel's label is its pallas_call name, which its
-            # instructions' names carry (jvp_flash_fwd_.2)
-            hits = {tuple(v) for k, v in table.items() if name in k}
-            if hits:
-                verdict = tuple(
-                    vals.pop() if len(vals) == 1 else "mixed"
-                    for vals in ({h[0] for h in hits},
-                                 {h[1] for h in hits}))
         key = tuple(verdict) if verdict else ("", "unphased")
         by[key] = by.get(key, 0.0) + secs
+        if verdict is None and label in kernels:
+            unplaced[label] = unplaced.get(label, 0.0) + secs
     busy = trace["busy_s"]
     return {"by": {k: 100.0 * v / busy for k, v in by.items()},
-            "unphased_pct": 100.0 * by.get(("", "unphased"), 0.0) / busy}
+            "unphased_pct": 100.0 * by.get(("", "unphased"), 0.0) / busy,
+            "unplaced_kernel_s": unplaced}
 
 
 def print_scope_shares(shares: dict) -> None:
     rows = sorted(shares["by"].items(), key=lambda kv: -kv[1])
     print("SCOPES pct_of_busy " + " ".join(
         f"{phase or '-'}/{scope}={pct:.2f}" for (phase, scope), pct in rows)
-        + f" unphased_pct={shares['unphased_pct']:.3f}", flush=True)
+        + f" unphased_pct={shares['unphased_pct']:.3f}"
+        + " unplaced_kernels=" + (",".join(
+            f"{k}:{v:.4f}s" for k, v in sorted(
+                shares["unplaced_kernel_s"].items())) or "none"), flush=True)

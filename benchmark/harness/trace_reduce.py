@@ -34,10 +34,14 @@ DEVICE_PLANE_PREFIX = "/device:TPU:"
 #: and collectives in flight beside it, exists on device 0 only and is not
 #: read: a collective that overlaps compute is by definition not exposed.)
 OP_LINE = "XLA Ops"
-#: the repo's pallas_call names (ops/), as PR 21 fixed them
+#: the program's pallas_call names (ops/; ``gmm`` and ``tgmm`` are the
+#: megablox grouped matmuls ``ops/grouped_matmul.py`` calls).  A Mosaic
+#: kernel of any other name is ``pallas_other``: a later PR that brings a
+#: kernel adds its name here in a ``benchmark`` PR, or reads it there.
 PALLAS_KERNELS = (
     "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rmsnorm_fwd",
     "softmax_xent_fwd", "quantize_blockwise",
+    "gmm", "tgmm", "gather_sum", "ssd_chunk_fwd", "ssd_chunk_bwd",
 )
 FLASH_KERNELS = PALLAS_KERNELS[:3]
 COLLECTIVE_PREFIXES = (
@@ -202,7 +206,7 @@ def kernel_of(ev: list) -> Optional[str]:
     (``jvp_flash_fwd_.2``, ``transpose_jvp_flash_bwd_dkv__.3``)."""
     if not ev[3].startswith("pallas"):
         return None
-    # longest first: flash_bwd_dkv before flash_bwd_dq before flash_fwd
+    # longest first: flash_bwd_dkv before flash_bwd_dq, tgmm before gmm
     for k in sorted(PALLAS_KERNELS, key=len, reverse=True):
         if k in ev[0]:
             return k
@@ -251,7 +255,13 @@ def reduce_trace(trace: dict) -> dict:
 
         window_s, busy_s, idle_share, kernel_s {name: s}, collective_s,
         exposed_collective_s, op_self_s {name: s}, idle_gaps {label: s},
-        n_devices
+        kernel_call_s {name: {instruction: s}}, n_devices
+
+    ``op_self_s`` files every call of a Mosaic kernel under the kernel's
+    name; ``kernel_call_s`` keeps the same seconds by the calling
+    instruction (``gather_sum.16``), which is what the program's scope
+    table names: a kernel called under two scopes is placed call by call
+    (``obs_read.scope_shares``).
     """
     planes = device_planes(trace)
     if not planes:
@@ -263,6 +273,7 @@ def reduce_trace(trace: dict) -> dict:
     spans = host_spans(trace, program=True)
     busy_s = coll_s = exposed_s = 0.0
     kernel_s: Dict[str, float] = {}
+    kernel_calls: Dict[str, Dict[str, float]] = {}
     op_self: Dict[str, float] = {}
     gap_s: Dict[str, float] = {}
     for plane in planes:
@@ -285,6 +296,8 @@ def reduce_trace(trace: dict) -> dict:
             k = kernel_of(ev)
             if k is not None:
                 kernel_s[k] = kernel_s.get(k, 0.0) + ev[2] * 1e-9
+                calls = kernel_calls.setdefault(k, {})
+                calls[ev[0]] = calls.get(ev[0], 0.0) + self_s
             label = k or f"{ev[0]} {ev[3]}".strip()
             op_self[label] = op_self.get(label, 0.0) + self_s
         for gap in subtract([win], busy):
@@ -298,6 +311,7 @@ def reduce_trace(trace: dict) -> dict:
         "busy_s": busy_s / n,
         "idle_share": 1.0 - busy_s / n / window_s,
         "kernel_s": avg(kernel_s),
+        "kernel_call_s": {k: avg(v) for k, v in kernel_calls.items()},
         "collective_s": coll_s / n,
         "exposed_collective_s": exposed_s / n,
         "op_self_s": avg(op_self),

@@ -37,7 +37,7 @@ class TrainSession:
         """First touch of JAX's backend: the process takes the chip.
         ``backend_open_s`` is that call alone, the runtime's own start-up,
         which no tree can move: the runners take it out of ``setup_s`` and
-        ``resume_s`` and print it beside them.  What precedes it in
+        the kill-to-step seconds and print it beside them.  What precedes it in
         ``device_open_s`` (imports, the compile cache's set-up) stays in."""
         from dlrover_tpu.common.jax_env import (
             device_summary,

@@ -1,5 +1,6 @@
-"""Kernels: device time in the repo's six named Pallas kernels over device
-busy time, from the trace."""
+"""Kernels: device time in every Mosaic kernel of the step (the program's
+named ones, ``trace_reduce.PALLAS_KERNELS``, and ``pallas_other``) over
+device busy time, from the trace."""
 LAYER = "kernels"
 SOURCE = "device_trace"
 

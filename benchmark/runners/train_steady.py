@@ -110,8 +110,14 @@ def run(cell: dict, args, t_start: float) -> dict:
         setup_note,
         f"WINDOW steps={n} tokens={tokens} seconds={t_end - t_open:.3f} "
         f"median_step_s={statistics.median(sess.spans['step_s']):.4f} "
+        # a host stall shows as one long step; the rate counts it
+        f"max_step_s={max(sess.spans['step_s']):.4f} "
+        f"at_step={sess.spans['step_s'].index(max(sess.spans['step_s']))} "
         f"compiles_in_window={compiles.count} "
         f"loss_first={sess.losses[0]:.4f} loss_last={sess.losses[-1]:.4f}",
+        # what the window's last step returned beside its loss: the routed
+        # cells' loads, held pairs and buffer sizes, in untraced runs too
+        f"STEP_METRICS {json.dumps(counters['step_metrics'])}",
     ]
     return {
         "correct": check["ok"] and bad == 0 and compiles.count == 0,

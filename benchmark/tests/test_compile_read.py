@@ -224,12 +224,13 @@ GONE = {"bootstrap.backend_init_s", "compile.outside_build_s"}
 def test_a_rehearsal_finds_what_moves_the_set_up(cell):
     """End to end at toy widths on the CPU, every cell: the program
     records, the readers read (the elastic cell from the resumed worker's
-    journal) every per-layer metric that moves ``setup_s`` or ``resume_s``
+    journal) every per-layer metric that moves ``setup_s`` (the kill, the
+    restart and the restore lie inside it; ``resume_s`` until PR 50)
     — host stamps and spans, which the CPU has too — and neither of the two
     that read what the metrics no longer hold."""
     spec = common.load_spec()
     wanted = {m["name"] for m in common.metrics_for(spec, "per_layer", cell)
-              if m["moves"] in ("setup_s", "resume_s")}
+              if m["moves"] == "setup_s"}
     assert set(WANT) <= wanted and not GONE & wanted
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")

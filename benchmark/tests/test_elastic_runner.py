@@ -1,6 +1,7 @@
 """The elastic runner: its clean-up finds this run's arenas and no other
-run's, and its two metrics are the parent's clock less each worker's own
-stamp of the backend's start-up."""
+run's, its two clocks of set-up are the parent's less each worker's own
+stamp of the backend's start-up, and its rate is the window's tokens over the
+window's seconds."""
 
 import os
 import re
@@ -46,13 +47,14 @@ WORKER_LINES = [
     {"kind": "step", "n": 4, "loss": 6.5, "t": 1132.0},
     {"kind": "window_open", "t": 1133.0},
     {"kind": "result", "t": 1160.0,
-     "spans": {"save_stall_s": [5.0, 5.5], "build_s": 3.7, "restore_s": 1.5,
-               "device_open_s": 13.0, "backend_open_s": 10.5},
-     "losses": [6.0, 5.5, 5.0, 4.5], "tokens_per_period": 65536,
-     "periods": [{"loop_s": 2.0, "stall_s": 5.5, "traced": False}],
+     "spans": {"save_stall_s": [5.5], "build_s": 3.7, "restore_s": 1.5,
+               "device_open_s": 13.0, "backend_open_s": 10.5,
+               "step_s": [0.5, 0.75, 0.5, 0.5]},
+     "losses": [6.0, 5.5, 5.0, 4.5], "steps": 4, "tokens_per_step": 16384,
+     "window_s": 2.5, "traced_steps": [1], "save_stall_s": 5.5,
      "compiles_in_window": 0, "memory_peak_bytes": 0,
      "engine_stall_ms_last": 5500.0, "engine_staged_mbps_last": None,
-     "program": {}, "memory": {}, "median_step_s": 0.5},
+     "program": {}, "memory": {}},
 ]
 
 
@@ -82,7 +84,7 @@ class RecordedLines:
                      if t >= after and re.search(pattern, line)), None)
 
 
-def test_both_metrics_leave_out_each_workers_backend(tmp_path, monkeypatch):
+def test_set_up_leaves_out_each_workers_backend_and_the_rate_is_the_windows(tmp_path, monkeypatch):
     elastic = common.load_module("runners", "elastic")
     monkeypatch.setattr(common, "WORK_DIR", str(tmp_path))
     monkeypatch.setattr(elastic.os, "kill", lambda pid, sig: None)
@@ -102,8 +104,14 @@ def test_both_metrics_leave_out_each_workers_backend(tmp_path, monkeypatch):
     assert all(out["checks"].values()), out["checks"]
     # kill -> first step 31.5 s, of which the restarted worker's backend
     # 10.5; start -> window 133 s, of which the two backends 9 + 10.5
-    assert out["end_to_end"]["resume_s"] == pytest.approx(21.0)
-    assert out["end_to_end"]["setup_s"] == pytest.approx(113.5)
+    assert out["spans"]["kill_to_step_s"] == pytest.approx(21.0)
+    # the window: four steps of 16,384 tokens in 2.5 s, all of them counted;
+    # what a traced run's readers get leaves the traced step out
+    assert out["end_to_end"] == {
+        "train_tokens_per_s": pytest.approx(26214.4),
+        "setup_s": pytest.approx(113.5)}
+    assert out["counters"]["tokens_per_s"] == pytest.approx(32768.0)
+    assert out["attempted"] == 6 and out["failed"] == 0
     notes = "\n".join(out["notes"])
     assert ("SETUP_S 113.500000 total=133.000000 backend_open_s_0=9.000000"
             " backend_open_s_1=10.500000\n") in notes
@@ -119,3 +127,7 @@ def test_both_metrics_leave_out_each_workers_backend(tmp_path, monkeypatch):
     # the resumed worker's two stamps reach the reader of what is left
     reader = common.load_module("layer_metrics", "bootstrap.device_open_s")
     assert reader.read(out["spans"], {}, {}) == pytest.approx(2.5)
+    # what was ``resume_s`` end to end until PR 50 reaches its reader
+    reader = common.load_module("layer_metrics", "agent.kill_to_step_s")
+    assert reader.read(out["spans"], {}, {}) == pytest.approx(21.0)
+    assert reader.read({}, {}, {}) is None

@@ -286,6 +286,44 @@ def test_the_readers_on_a_traced_step(monkeypatch):
         100 * 9000 / 65536)
 
 
+def test_the_prediction_blocks_routed_branch_is_a_routed_block(monkeypatch):
+    """Under ``mtp`` the four ``moe_*`` scopes are nested: an instruction
+    counts by its innermost scope, the block's kernels by their name and
+    ``gather_sum`` by its call's phase — and ``step.mtp_share_pct`` takes no
+    kernel for the instruction that bears its name (``gmm``)."""
+    from benchmark.harness import moe_read
+
+    scopes = {
+        "f.1": ["forward", "moe_experts"], "f.2": ["backward", "mtp"],
+        "f.3": ["forward", "mtp"], "f.4": ["forward", "mtp"],
+        "gmm": ["forward", "mtp"], "gmm.1": ["forward", "moe_experts"],
+        "gather_sum.1": ["forward", "mtp"],
+        "gather_sum.2": ["backward", "mtp"],
+        "gather_sum.3": ["recompute", "moe_combine"],
+        "rmsnorm_fwd.1": ["forward", "mtp"]}
+    subscopes = {"f.2": "moe_router", "f.3": "moe_experts", "f.4": "mla_q",
+                 "gather_sum.1": "gather_sum", "gather_sum.2": "gather_sum",
+                 "gather_sum.3": "gather_sum", "rmsnorm_fwd.1": "rmsnorm_fwd"}
+    _program(monkeypatch, scopes, subscopes)
+    calls = {"gmm": {"gmm": 0.2, "gmm.1": 0.8},
+             "gather_sum": {"gather_sum.1": 0.1, "gather_sum.2": 0.3,
+                            "gather_sum.3": 0.05},
+             "rmsnorm_fwd": {"rmsnorm_fwd.1": 0.4}}
+    kernel_s = {k: sum(v.values()) for k, v in calls.items()}
+    trace = {"busy_s": 10.0, "kernel_s": kernel_s, "kernel_call_s": calls,
+             "op_self_s": dict(kernel_s, **{
+                 "f.1 bf16[8]": 1.0, "f.2": 0.6, "f.3": 0.5, "f.4": 0.7})}
+    secs = moe_read.scope_seconds({"x": 1}, trace)
+    assert secs["moe_experts"] == pytest.approx(1.0 + 0.5 + 0.2 + 0.8)
+    assert secs["moe_router"] == pytest.approx(0.6)  # no norm under mtp
+    assert secs["moe_combine"] == pytest.approx(0.1 + 0.05)
+    assert secs["moe_permute"] == pytest.approx(0.3)
+    assert (secs["unplaced"], secs["whole"]) == (0.0, pytest.approx(3.55))
+    mtp = common.load_module("layer_metrics", "step.mtp_share_pct").read(
+        {"x": 1}, trace, {})
+    assert mtp == pytest.approx(100 * (0.6 + 0.5 + 0.7) / 10)
+
+
 @pytest.mark.parametrize("name", [
     "step.attention_share_pct", "mla.latent_share_pct",
     "step.mtp_share_pct", "moe.held_pair_share_pct"])

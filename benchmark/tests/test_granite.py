@@ -292,6 +292,47 @@ def test_the_readers_on_a_traced_step(monkeypatch):
         100 * least * 9 * 5 / 1.25)
 
 
+def test_the_scan_kernels_seconds_reach_the_scan(monkeypatch):
+    """The same step with what a chunk puts out as Mosaic kernels: their
+    calls under ``ssm`` count there and under ``ssm_scan``, the recomputed
+    forward call as recomputation; a norm's call stays out."""
+    scopes = {
+        "f.1": ["forward", "ssm"], "f.2": ["backward", "ssm"],
+        "f.8": ["forward", "mlp"],
+        "ssd_chunk_fwd.1": ["forward", "ssm"],
+        "ssd_chunk_fwd.2": ["recompute", "ssm"],
+        "ssd_chunk_bwd.1": ["backward", "ssm"],
+        "rmsnorm_fwd.1": ["forward", "ssm"]}
+    subscopes = {"f.1": "ssm_in", "f.2": "ssm_scan",
+                 "ssd_chunk_fwd.1": "ssd_chunk_fwd",
+                 "ssd_chunk_fwd.2": "ssd_chunk_fwd",
+                 "ssd_chunk_bwd.1": "ssd_chunk_bwd",
+                 "rmsnorm_fwd.1": "rmsnorm_fwd"}
+    _program(monkeypatch, scopes, subscopes, ssm_layers=9)
+    calls = {"ssd_chunk_fwd": {"ssd_chunk_fwd.1": 0.2,
+                               "ssd_chunk_fwd.2": 0.1},
+             "ssd_chunk_bwd": {"ssd_chunk_bwd.1": 0.45},
+             "rmsnorm_fwd": {"rmsnorm_fwd.1": 0.3}}
+    kernel_s = {k: sum(v.values()) for k, v in calls.items()}
+    trace = {"busy_s": 10.0, "kernel_s": kernel_s, "kernel_call_s": calls,
+             "op_self_s": dict(kernel_s, **{
+                 "f.1 bf16[8]": 1.0, "f.2 f32[8]": 0.25, "f.8": 2.0})}
+    secs = ssm_read.seconds({"x": 1}, trace)
+    assert secs["ssm"] == pytest.approx(1.0 + 0.25 + 0.75)
+    assert secs["ssm_scan"] == pytest.approx(0.25 + 0.75)
+    peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+    counters = {"traced_steps": 5, "peaks": peaks, "chips": 1,
+                "cell": common.load_cell(CELL_NAME)}
+    read = lambda name: common.load_module(  # noqa: E731
+        "layer_metrics", name).read({"x": 1}, trace, counters)
+    assert read("step.ssm_share_pct") == pytest.approx(20.0)
+    assert read("ssm.scan_share_pct") == pytest.approx(50.0)
+    least = granite.ssd_least_seconds(FULL, 2, 8192, peaks)["seconds"]
+    assert read("ssm.scan_roofline") == pytest.approx(
+        100 * least * 9 * 5 / 1.0)
+    assert read("step.recompute_share_pct") == pytest.approx(1.0)
+
+
 @pytest.mark.parametrize("name", [
     "step.ssm_share_pct", "ssm.scan_share_pct", "ssm.scan_roofline"])
 def test_a_program_without_the_scopes_reads_nothing(monkeypatch, name):

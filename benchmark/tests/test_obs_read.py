@@ -110,10 +110,10 @@ def test_one_process_run_reads_the_ring(tmp_path, monkeypatch):
 
 
 def test_device_open_is_what_precedes_the_backend():
-    """``bootstrap.device_open_s`` moves ``setup_s`` and ``resume_s``, which
-    leave the first ``jax.devices()`` out: it reads the stamp less that
-    call (imports, the compile cache's set-up), and nothing where a runner
-    handed over only one of the two."""
+    """``bootstrap.device_open_s`` moves ``setup_s``, which like the elastic
+    cell's kill-to-step seconds leaves the first ``jax.devices()`` out: it
+    reads the stamp less that call (imports, the compile cache's set-up),
+    and nothing where a runner handed over only one of the two."""
     assert _read("bootstrap.device_open_s", spans={
         "device_open_s": 10.865, "backend_open_s": 7.857}) == (
         pytest.approx(3.008))
@@ -127,16 +127,22 @@ TRACE = {"busy_s": 10.0, "op_self_s": {
     "all-reduce.3 f32[1024,16000]": 1.0,   # backward lm_head_loss
     "fusion.2 (f32[4096,14336], f32[])": 3.0,  # optimizer
     "flash_fwd": 1.5,                      # kernel: forward + recompute
-    "rmsnorm_fwd": 0.5,                    # kernel: three scopes
-    "fusion.9 f32[8]": 1.9,                # backward mlp
+    "rmsnorm_fwd": 0.5,                    # kernel: two scopes
+    "pallas_other": 0.2,                   # a kernel nobody can place
+    "fusion.9 f32[8]": 1.7,                # backward mlp
     "copy.77 f32[2]": 0.1,                 # in no scope
-}}
+}, "kernel_s": {"flash_fwd": 1.5, "rmsnorm_fwd": 0.5, "pallas_other": 0.2},
+    # a kernel's seconds by the calling instruction: what the table names
+    "kernel_call_s": {
+        "flash_fwd": {"flash_fwd.2": 1.0, "flash_fwd.5": 0.5},
+        "rmsnorm_fwd": {"rmsnorm_fwd.1": 0.3, "rmsnorm_fwd.2": 0.2},
+        "pallas_other": {"custom-call.9": 0.2}}}
 PROGRAM = {"k": "ev", "kind": "accelerate.program", "ts": 1.0, "scopes": {
     "fusion.1": ["forward", "lm_head_loss"],
     "all-reduce.3": ["backward", "lm_head_loss"],
     "fusion.2": ["optimizer", "optimizer"],
-    "jvp_flash_fwd_.2": ["forward", "attention"],
-    "checkpoint_jvp_flash_fwd_.5": ["recompute", "attention"],
+    "flash_fwd.2": ["forward", "attention"],
+    "flash_fwd.5": ["recompute", "attention"],
     "rmsnorm_fwd.1": ["forward", "attention"],
     "rmsnorm_fwd.2": ["forward", "mlp"],
     "fusion.9": ["backward", "mlp"],
@@ -145,17 +151,22 @@ PROGRAM = {"k": "ev", "kind": "accelerate.program", "ts": 1.0, "scopes": {
 
 def test_scope_join_against_a_device_trace(capsys):
     shares = obs_read.scope_shares([dict(PROGRAM, _proc="")], TRACE)
-    assert shares["unphased_pct"] == pytest.approx(1.0)
+    # the copy, and the kernel no row of the table names: reported
+    assert shares["unphased_pct"] == pytest.approx(3.0)
+    assert shares["unplaced_kernel_s"] == {"pallas_other": pytest.approx(0.2)}
     by = shares["by"]
     assert by[("forward", "lm_head_loss")] == pytest.approx(20.0)
     assert by[("optimizer", "optimizer")] == pytest.approx(30.0)
-    assert by[("mixed", "attention")] == pytest.approx(15.0)
-    assert by[("forward", "mixed")] == pytest.approx(5.0)
+    # a kernel is placed call by call: the same name, two phases, two scopes
+    assert by[("forward", "attention")] == pytest.approx(10.0 + 3.0)
+    assert by[("recompute", "attention")] == pytest.approx(5.0)
+    assert by[("forward", "mlp")] == pytest.approx(2.0)
     assert sum(by.values()) == pytest.approx(100.0)
     obs_read.print_scope_shares(shares)
     line = capsys.readouterr().out
     assert line.startswith("SCOPES pct_of_busy optimizer/optimizer=30.00")
-    assert line.rstrip().endswith("unphased_pct=1.000")
+    assert line.rstrip().endswith(
+        "unphased_pct=3.000 unplaced_kernels=pallas_other:0.2000s")
 
 
 def test_share_metrics_read_the_ring_and_the_trace(tmp_path, monkeypatch,

@@ -173,35 +173,68 @@ def test_flop_and_byte_counts():
 # -- the routed block's per-layer readers -----------------------------------
 
 
-def _fake_scope_shares(monkeypatch, by):
-    monkeypatch.setattr(moe_read.obs_read, "records", lambda spans: [])
-    monkeypatch.setattr(
-        moe_read.obs_read, "scope_shares",
-        lambda recs, trace: {"by": by, "unphased_pct": 0.0} if by else None)
+def _program(monkeypatch, scopes):
+    monkeypatch.setattr(moe_read.obs_read, "records", lambda spans: [
+        {"kind": "accelerate.program", "scopes": scopes, "_proc": ""}])
 
 
-def test_the_readers_on_a_traced_routed_step(monkeypatch):
-    by = {("forward", "moe_router"): 1.0, ("backward", "moe_router"): 2.0,
-          ("forward", "moe_permute"): 3.0, ("backward", "moe_permute"): 4.0,
-          ("forward", "moe_experts"): 1.0, ("recompute", "moe_experts"): 1.0,
-          ("forward", "moe_combine"): 2.0, ("backward", "lm_head_loss"): 50.0}
-    _fake_scope_shares(monkeypatch, by)
-    # 2 s busy; the grouped-matmul kernels' 0.5 s carry no scope of their own
-    trace = {"busy_s": 2.0, "kernel_s": {"pallas_other": 0.5,
-                                         "flash_fwd": 0.1}}
+ROUTED_SCOPES = {
+    "f.1": ["forward", "moe_router"], "f.2": ["backward", "moe_router"],
+    "f.3": ["forward", "moe_permute"], "f.4": ["backward", "moe_permute"],
+    "f.5": ["forward", "moe_experts"], "f.6": ["forward", "moe_combine"],
+    "f.7": ["backward", "lm_head_loss"],
+    "gmm.1": ["forward", "moe_experts"], "gmm.2": ["recompute", "moe_experts"],
+    "tgmm.1": ["backward", "moe_experts"],
+    "gather_sum.1": ["forward", "moe_combine"],
+    "gather_sum.2": ["backward", "moe_permute"],
+    "rmsnorm_fwd.1": ["forward", "moe_router"]}
+
+
+def _routed_trace(gmm_calls):
+    """2 s busy.  ``gmm_calls``: the ``gmm`` kernel's seconds by calling
+    instruction, 0.3 s in all."""
+    kernel_call_s = {
+        "gmm": gmm_calls, "tgmm": {"tgmm.1": 0.2},
+        "gather_sum": {"gather_sum.1": 0.06, "gather_sum.2": 0.10},
+        "rmsnorm_fwd": {"rmsnorm_fwd.1": 0.01},
+        "flash_fwd": {"flash_fwd.1": 0.1}}
+    kernel_s = {k: sum(v.values()) for k, v in kernel_call_s.items()}
+    return {"busy_s": 2.0, "kernel_s": kernel_s,
+            "kernel_call_s": kernel_call_s,
+            "op_self_s": dict(kernel_s, **{
+                "f.1 f32[8]": 0.02, "f.2": 0.04, "f.3": 0.06, "f.4": 0.08,
+                "f.5 bf16[8]": 0.04, "f.6": 0.04, "f.7": 1.0})}
+
+
+def _counters(cell):
+    return {"cell": cell, "chips": 1, "traced_steps": 5,
+            "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+            "step_metrics": {"moe_tokens_per_expert": [
+                [10, 30, 20, 20], [25, 25, 25, 5]]}}
+
+
+def test_the_readers_on_a_traced_routed_step(monkeypatch, capsys):
+    _program(monkeypatch, ROUTED_SCOPES)
+    trace = _routed_trace({"gmm.1": 0.2, "gmm.2": 0.1})
     secs = moe_read.scope_seconds({"x": 1}, trace)
-    assert secs["moe_experts"] == pytest.approx(0.02 * 2 + 0.5)
+    # the grouped matmuls' calls are the experts'; gather_sum's forward call
+    # is the combine's and its backward call the permute's, not the experts'
+    assert secs["moe_experts"] == pytest.approx(0.04 + 0.3 + 0.2)
+    assert secs["moe_combine"] == pytest.approx(0.04 + 0.06)
+    assert secs["moe_permute"] == pytest.approx(0.06 + 0.08 + 0.10)
+    assert secs["moe_router"] == pytest.approx(0.02 + 0.04 + 0.01)
+    assert secs["unplaced"] == 0.0
+    whole = 0.54 + 0.10 + 0.24 + 0.07
+    assert secs["whole"] == pytest.approx(whole)
     cell = common.load_cell("olmoe-l1.train-4k")
-    counters = {"cell": cell, "chips": 1, "traced_steps": 5,
-                "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
-                "step_metrics": {"moe_tokens_per_expert": [
-                    [10, 30, 20, 20], [25, 25, 25, 5]]}}
+    counters = _counters(cell)
     read = lambda name: common.load_module(  # noqa: E731
         "layer_metrics", name).read({"x": 1}, trace, counters)
-    whole = 0.14 * 2 + 0.5
     assert read("step.moe_share_pct") == pytest.approx(100 * whole / 2.0)
+    assert "MOE_KERNELS gmm=0.3000s tgmm=0.2000s gather_sum=0.1600s " \
+        "unplaced=0.0000s" in capsys.readouterr().out
     assert read("moe.permute_share_pct") == pytest.approx(
-        100 * (0.12 * 2) / whole)
+        100 * (whole - 0.54) / whole)
     least = olmoe.grouped_matmul_least_seconds(
         cell["config_data"], cell["batch_sequences"], 4096,
         counters["peaks"])["seconds"]
@@ -210,9 +243,36 @@ def test_the_readers_on_a_traced_routed_step(monkeypatch):
     assert read("moe.load_max_over_mean") == pytest.approx(30 * 4 / 80)
 
 
+def test_a_kernel_call_nobody_can_place_is_reported(monkeypatch, capsys):
+    """A grouped matmul called by an instruction the program's table does
+    not name: in the block's total, in no scope, said in the note line —
+    and the roofline's share reads lower for it, never higher."""
+    _program(monkeypatch, ROUTED_SCOPES)
+    trace = _routed_trace({"gmm.1": 0.2, "custom-call.7": 0.1})
+    secs = moe_read.scope_seconds({"x": 1}, trace)
+    assert secs["moe_experts"] == pytest.approx(0.04 + 0.2 + 0.2)
+    assert secs["unplaced"] == pytest.approx(0.1)
+    assert secs["whole"] == pytest.approx(0.95)
+    cell = common.load_cell("olmoe-l1.train-4k")
+    counters = _counters(cell)
+    read = lambda name: common.load_module(  # noqa: E731
+        "layer_metrics", name).read({"x": 1}, trace, counters)
+    assert read("step.moe_share_pct") == pytest.approx(100 * 0.95 / 2.0)
+    assert "unplaced=0.1000s (in the block's total, in no scope)" in (
+        capsys.readouterr().out)
+    assert read("moe.permute_share_pct") == pytest.approx(
+        100 * (0.95 - 0.44 - 0.1) / 0.95)
+    least = olmoe.grouped_matmul_least_seconds(
+        cell["config_data"], cell["batch_sequences"], 4096,
+        counters["peaks"])["seconds"]
+    assert read("moe.grouped_matmul_roofline") == pytest.approx(
+        100 * least * 5 / 0.54)
+
+
 def test_the_readers_find_nothing_in_a_dense_step(monkeypatch):
-    _fake_scope_shares(monkeypatch, {("forward", "mlp"): 40.0})
-    trace = {"busy_s": 2.0, "kernel_s": {"flash_fwd": 0.1}}
+    _program(monkeypatch, {"f.1": ["forward", "mlp"]})
+    trace = {"busy_s": 2.0, "kernel_s": {"flash_fwd": 0.1},
+             "op_self_s": {"f.1": 0.8, "flash_fwd": 0.1}}
     counters = {"cell": common.load_cell("mistral7b-l2.train-steady"),
                 "chips": 1, "traced_steps": 5, "peaks": {},
                 "step_metrics": {"grad_norm": 1.0}}

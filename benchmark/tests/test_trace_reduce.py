@@ -72,6 +72,35 @@ def test_kernels_are_found_by_their_pallas_call_name():
     assert not tr.is_collective("fusion.2")
 
 
+@pytest.mark.parametrize("instruction,kernel", [
+    ("gmm.14", "gmm"), ("tgmm.2", "tgmm"), ("gather_sum.24", "gather_sum"),
+    ("ssd_chunk_fwd.3", "ssd_chunk_fwd"),
+    ("ssd_chunk_bwd.1", "ssd_chunk_bwd"),
+])
+def test_the_routed_blocks_and_the_scans_kernels_have_names(instruction,
+                                                            kernel):
+    """Filed under its own name, not ``pallas_other`` (and ``tgmm`` not
+    under ``gmm``, which its name holds)."""
+    assert tr.kernel_of([instruction, 0, 1, "pallas bf16[8]"]) == kernel
+    assert tr.kernel_of([instruction, 0, 1, "bf16[8]"]) is None
+
+
+def test_a_kernels_seconds_are_kept_by_the_calling_instruction():
+    dev = [["gather_sum.16", 0.0, 100.0, "pallas bf16[8]"],
+           ["gather_sum.24", 100.0, 300.0, "pallas bf16[8]"],
+           ["gather_sum.16", 400.0, 100.0, "pallas bf16[8]"],
+           ["custom-call.9", 500.0, 50.0, "pallas f32[2]"],
+           ["fusion.1", 550.0, 50.0, "f32[2]"]]
+    r = tr.reduce_trace(_trace(dev, [["dispatch", 0.0, 600.0, ""]]))
+    assert r["kernel_s"] == {"gather_sum": pytest.approx(500e-9),
+                             "pallas_other": pytest.approx(50e-9)}
+    assert r["op_self_s"]["gather_sum"] == pytest.approx(500e-9)
+    assert r["kernel_call_s"] == {
+        "gather_sum": {"gather_sum.16": pytest.approx(200e-9),
+                       "gather_sum.24": pytest.approx(300e-9)},
+        "pallas_other": {"custom-call.9": pytest.approx(50e-9)}}
+
+
 def test_reduce_on_a_trace_worked_out_by_hand():
     # window 0..1000 ns (host spans).  Device: busy 100..400 and 600..900.
     dev = [

@@ -6,9 +6,12 @@ Incarnation 0 (``restart_count`` 0) builds the step, trains, saves to shared
 memory at ``setup_save_step`` and trains on until the benchmark's parent
 kills it.  Every later incarnation restores, replays the steps the first
 had computed past the save (their losses must agree), then measures the
-window: after a first save, whole periods of ``save_every_steps`` optimizer
-steps (``loop_s``) and one memory save (``stall_s``), timed apart.  It reports on standard output in lines
-``BENCH {json}``; the parent stamps each with its own clock as it arrives.
+window as the steady cells do: optimizer steps until its seconds are up,
+each ended by the loss reaching the host and by the step's report to the
+agent.  Once the window has closed it saves to shared memory once more (the
+resumed worker can; the stall is printed and is no part of any metric).  It
+reports on standard output in lines ``BENCH {json}``; the parent stamps each
+with its own clock as it arrives.
 """
 
 from __future__ import annotations
@@ -20,7 +23,6 @@ T_START = time.monotonic()
 import argparse  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
-import statistics  # noqa: E402
 import sys  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -108,51 +110,51 @@ def main() -> int:
         ctx.report_step(sess.step_no)
         emit("step", n=sess.step_no, loss=loss)
 
-    # -- the window: whole save periods ------------------------------------
-    every = traffic["save_every_steps"]
+    # -- the window: optimizer steps, as ``runners/train_steady.py`` --------
     trace_dir = os.path.join(args.work, "trace")
+
+    def end_trace():
+        with open(os.path.join(args.work, "trace.json"), "w") as f:
+            json.dump(stop_trace(trace_dir), f)
+
     emit("window_open")
     compiles.armed = True
     t_open = time.monotonic()
-    while sess.step_no % every:
+    traced_steps, tracing = [], False
+    first_traced = traffic["trace_skip_steps"] if args.trace else -1
+    n = 0
+    while True:
+        if n == first_traced:
+            start_trace(trace_dir)
+            tracing = True
         sess.step()
         ctx.report_step(sess.step_no)
-    sess.save(ckpt)
-    periods = []  # one entry per whole period: its loop, its save
-    period_s = 0.0
-    while (time.monotonic() - t_open) + period_s <= args.seconds:
-        tracing = bool(args.trace) and sum(
-            p["traced"] for p in periods) < traffic["trace_periods"]
         if tracing:
-            start_trace(trace_dir)
-        t_loop = time.monotonic()
-        for _ in range(every):
-            sess.step()
-            ctx.report_step(sess.step_no)
-        loop_s = time.monotonic() - t_loop
-        stall_s = sess.save(ckpt)
-        if tracing:
-            with open(os.path.join(args.work, "trace.json"), "w") as f:
-                json.dump(stop_trace(trace_dir), f)
-        else:
-            # a traced period is slower (the profiler): it predicts
-            # nothing about the next one
-            period_s = time.monotonic() - t_loop
-        periods.append(
-            {"loop_s": loop_s, "stall_s": stall_s, "traced": tracing})
+            traced_steps.append(n)
+            if len(traced_steps) == traffic["trace_steps"]:
+                end_trace()
+                tracing = False
+        n += 1
+        t_end = time.monotonic()
+        if t_end - t_open >= args.seconds:
+            break
     compiles.armed = False
+    if tracing:
+        end_trace()
+    # the window has closed: training went on after the resume; saving does
+    stall_s = sess.save(ckpt)
 
     from dlrover_tpu.agent.metrics import perf_stats
 
     emit("result",
-         spans=sess.spans, losses=sess.losses, periods=periods,
-         tokens_per_period=sess.tokens_per_step * every,
+         spans=sess.spans, losses=sess.losses, steps=n,
+         window_s=t_end - t_open, traced_steps=traced_steps,
+         tokens_per_step=sess.tokens_per_step, save_stall_s=stall_s,
          compiles_in_window=compiles.count,
          memory_peak_bytes=common.memory_peak_bytes(),
          engine_stall_ms_last=ckpt.engine.last_stall_ms,
          engine_staged_mbps_last=perf_stats.get("ckpt_staged_mbps"),
-         program=sess.job.program, memory=sess.job.memory,
-         median_step_s=statistics.median(sess.spans["step_s"]))
+         program=sess.job.program, memory=sess.job.memory)
     # Exiting would make the agent persist the staged step once more
     # (tens of seconds nobody measures): wait for the parent to end the run.
     time.sleep(3600)
