@@ -229,9 +229,12 @@ class CkptReplicaManager:
         if self.world_size <= 1:
             return False
         now = time.monotonic()
-        if not force and now - self._last_push.get(process_id, 0.0) < (
-            self.push_interval
-        ):
+        # no entry = never pushed: the monotonic clock starts near the
+        # host's boot, so "0.0" would throttle the first push of a host
+        # that has been up for less than the interval
+        last = self._last_push.get(process_id)
+        if not force and last is not None and (
+                now - last < self.push_interval):
             return False
         peer = self._peer(self.backup_rank)
         if peer is None:

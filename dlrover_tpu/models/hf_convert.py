@@ -25,9 +25,7 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models.llama import (
     LlamaConfig,
-    refuse_latent,
-    refuse_looped,
-    refuse_ssm,
+    refuse_training_path_only,
 )
 
 
@@ -177,9 +175,8 @@ def _build_params(
     """The single HF-Llama -> params layout table, shared by the
     in-memory and streaming importers (key names, transposes,
     tied-embedding fallback, bias rejection live HERE only)."""
-    refuse_looped(cfg, "the HF Llama layout table (models.hf_convert)")
-    refuse_latent(cfg, "the HF Llama layout table (models.hf_convert)")
-    refuse_ssm(cfg, "the HF Llama layout table (models.hf_convert)")
+    refuse_training_path_only(
+        cfg, "the HF Llama layout table (models.hf_convert)")
     bias_keys = [k for k in all_keys() if k.endswith(".bias")]
     if bias_keys:
         raise ValueError(

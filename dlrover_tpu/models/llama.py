@@ -353,7 +353,7 @@ class LlamaConfig:
 
     def is_moe_layer(self, i: int) -> bool:
         """Single source of truth for MoE placement (init_params,
-        param_logical_axes and init_fp8_states must agree)."""
+        param_logical_axes must agree)."""
         return self.num_experts > 0 and i >= self.first_k_dense and (
             i % self.moe_every == self.moe_every - 1
         )
@@ -667,17 +667,6 @@ def _rms_per_head(x, gain, cfg: "LlamaConfig"):
     return (heads * inv * gain).astype(x.dtype).reshape(x.shape)
 
 
-def _fp8_proj(x, w, st, dt):
-    """[..., K] @ [K, N] through ops.fp8.fp8_dot (delayed scaling).
-    Returns (out [..., N] in compute dtype, new Fp8State)."""
-    from dlrover_tpu.ops.fp8 import fp8_dot
-
-    out, new = fp8_dot(
-        x.reshape(-1, x.shape[-1]), w.astype(dt), st
-    )
-    return out.reshape(x.shape[:-1] + (w.shape[-1],)), new
-
-
 def _mla_qkv(x, layer, cfg: LlamaConfig, positions) -> tuple:
     """Latent attention's projections: normed ``x [B, S, C]`` -> ``(q, k,
     v)``, each ``[B, S, H, head_dim]``, plain multi-head operands for any
@@ -716,30 +705,14 @@ def _mla_qkv(x, layer, cfg: LlamaConfig, positions) -> tuple:
 
 def _attention(
     x, layer, cfg: LlamaConfig, positions, attn_impl: str, mesh,
-    segment_ids=None, fp8_layer=None,
+    segment_ids=None,
 ):
-    """Returns ``(out, new_fp8_layer)``; ``new_fp8_layer`` is None unless
-    ``fp8_layer`` (a dict of ``ops.fp8.Fp8State`` for wq/wk/wv/wo) routes
-    the projections through e4m3/e5m2 fp8_dot — the reference's
-    ``Fp8Optimization`` rewrite of eligible linears
-    (``atorch/auto/opt_lib/amp_optimization.py:396``) as a functional
-    strategy knob."""
     B, S, C = x.shape
     H, KV, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     dt = cfg.dtype
-    new_fp8 = None
     latent = cfg.kv_lora_rank > 0
-    if latent and fp8_layer is not None:
-        raise ValueError(
-            f"_attention: kv_lora_rank={cfg.kv_lora_rank} with fp8 states: "
-            "the fp8 rewrite knows the wq/wk/wv/wo linears only")
     if latent:
         q, k, v = _mla_qkv(x, layer, cfg, positions)
-    elif fp8_layer is not None:
-        new_fp8 = {}
-        q, new_fp8["wq"] = _fp8_proj(x, layer["wq"], fp8_layer["wq"], dt)
-        k, new_fp8["wk"] = _fp8_proj(x, layer["wk"], fp8_layer["wk"], dt)
-        v, new_fp8["wv"] = _fp8_proj(x, layer["wv"], fp8_layer["wv"], dt)
     else:
         q = x @ layer["wq"].astype(dt)
         k = x @ layer["wk"].astype(dt)
@@ -797,13 +770,9 @@ def _attention(
         )
         out = o.transpose(0, 2, 1, 3)
     out = out.reshape(B, S, H * D)
-    if fp8_layer is not None:
-        out, new_fp8["wo"] = _fp8_proj(out, layer["wo"],
-                                       fp8_layer["wo"], dt)
-        return out, new_fp8
     with (jax.named_scope("mla_out") if latent
           else contextlib.nullcontext()):
-        return out @ layer["wo"].astype(dt), None
+        return out @ layer["wo"].astype(dt)
 
 
 def _ssm_mixer(u, ssm, cfg: LlamaConfig) -> tuple:
@@ -869,22 +838,10 @@ def _conv_mixer(u, conv, cfg: LlamaConfig):
         return y.astype(dt) @ conv["out_proj"].astype(dt)
 
 
-def _swiglu(x, mlp, dt, fp8_mlp=None):
-    """Returns ``(out, new_fp8_mlp)``; fp8 routing as in
-    :func:`_attention` when ``fp8_mlp`` carries Fp8States for
-    w_gate/w_up/w_down."""
-    if fp8_mlp is not None:
-        new = {}
-        g, new["w_gate"] = _fp8_proj(x, mlp["w_gate"],
-                                     fp8_mlp["w_gate"], dt)
-        u, new["w_up"] = _fp8_proj(x, mlp["w_up"], fp8_mlp["w_up"], dt)
-        out, new["w_down"] = _fp8_proj(
-            jax.nn.silu(g) * u, mlp["w_down"], fp8_mlp["w_down"], dt
-        )
-        return out, new
+def _swiglu(x, mlp, dt):
     g = x @ mlp["w_gate"].astype(dt)
     u = x @ mlp["w_up"].astype(dt)
-    return (jax.nn.silu(g) * u) @ mlp["w_down"].astype(dt), None
+    return (jax.nn.silu(g) * u) @ mlp["w_down"].astype(dt)
 
 
 def _live_mask(rows: int, live_rows):
@@ -1117,7 +1074,7 @@ _routed_sum_sized.defvjp(_routed_sum_sized_fwd, _routed_sum_sized_bwd)
 
 
 def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
-                valid=None, fp8_moe=None):
+                valid=None):
     """Routed SwiGLU block, sorted and ragged: the ``N*K`` (token, expert)
     pairs are sorted by expert (stable, so a pair's rank inside its
     expert's group follows the token order), the token rows gathered in
@@ -1146,11 +1103,6 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
     pads sort behind every expert's group, so they take no rank and no
     capacity, their weight is zero and they count in no statistic (their
     rows ride at the end of the last group, computed and unused).
-
-    ``fp8_moe`` (``ops.fp8.Fp8State`` for wg/wi/wo) routes the three
-    grouped matmuls through ``ops.fp8.fp8_ragged_dot``; the router, the
-    permutation and the combine stay in fp32/compute dtype.  The new
-    states come back as ``stats["fp8"]``.
 
     The router's variants (``cfg.router_score``, ``moe["router_bias"]``,
     ``cfg.routed_scaling``, ``cfg.balance_per_sequence``) and the shared
@@ -1245,30 +1197,11 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
             gate_vals = jnp.where(
                 keep_sorted[inverse].reshape(N, K), gate_vals, 0.0)
         tokens = tokens.astype(dt)
-    new_fp8 = None
-    if fp8_moe is not None:
-        if share:
-            raise ValueError(
-                f"_moe_swiglu: experts_held={held} of {E} with fp8 "
-                "states: the fp8 ragged dot takes groups that cover "
-                "every row")
-        from dlrover_tpu.ops.fp8 import fp8_ragged_dot
 
-        new_fp8 = {}
+    def ffn(rows):
+        return _expert_ffn(rows, moe["wg"], moe["wi"], moe["wo"],
+                           group_sizes, dt, None)
 
-        def ffn(rows):
-            g, new_fp8["wg"] = fp8_ragged_dot(
-                rows, moe["wg"].astype(dt), group_sizes, fp8_moe["wg"])
-            u, new_fp8["wi"] = fp8_ragged_dot(
-                rows, moe["wi"].astype(dt), group_sizes, fp8_moe["wi"])
-            y, new_fp8["wo"] = fp8_ragged_dot(
-                jax.nn.silu(g) * u, moe["wo"].astype(dt), group_sizes,
-                fp8_moe["wo"])
-            return y
-    else:
-        def ffn(rows):
-            return _expert_ffn(rows, moe["wg"], moe["wi"], moe["wo"],
-                               group_sizes, dt, None)
     bounds = _moe_buffer_bounds(N, K, E, held)
     if len(bounds) == 1:
         out = _routed_sum(ffn, bounds[0], tokens, gate_vals, order,
@@ -1285,7 +1218,7 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
             (order, inverse, group_sizes, held_pairs))
     if "shared" in moe:
         with jax.named_scope("moe_shared"):
-            out = out + _swiglu(tokens.astype(dt), moe["shared"], dt)[0]
+            out = out + _swiglu(tokens.astype(dt), moe["shared"], dt)
     with jax.named_scope("moe_router"):
         # the two loss terms, over real tokens only
         w = jnp.ones((N,), f32) if valid_n is None else valid_n.astype(f32)
@@ -1324,8 +1257,6 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
             stats["held_pairs"] = held_pairs
         if len(bounds) > 1:
             stats["buffer_rows"] = jnp.asarray(bounds, jnp.int32)[size]
-    if new_fp8 is not None:
-        stats["fp8"] = new_fp8
     return out.reshape(B, S, C), stats
 
 
@@ -1340,7 +1271,6 @@ def block_apply(
     segment_ids=None,
     attn_fn=None,  # (h, layer, cfg, positions) -> attn out; overrides
     moe_capacity: Optional[int] = None,
-    fp8_layer=None,
 ) -> tuple:
     """One transformer block: (x, layer) -> (x, stats).  The mixer is the
     one the layer dict holds — a state-space one (``"ssm"``, scope ``ssm``),
@@ -1353,15 +1283,7 @@ def block_apply(
     ``experts``, ``tokens_per_expert``); empty for a dense attention layer.
     The unit the pipeline stage partitioner groups (``models.llama_pp``).
     ``attn_fn`` swaps the attention implementation (the KV-cache decoder
-    plugs in here, so train and decode share one block wiring).
-
-    With ``fp8_layer`` (per-layer Fp8State dict from
-    :func:`init_fp8_states`) the attention/MLP projections run through
-    fp8_dot and the return becomes a 3-tuple
-    ``(x, stats, new_fp8_layer)``; on MoE layers the expert
-    projections (the bulk of the layer's FLOPs) go through the fp8
-    ragged dot as well — only the router, the permutation and the
-    combine stay in the compute dtype."""
+    plugs in here, so train and decode share one block wiring)."""
     # The scopes (``attention``, ``mlp``, and the routed block's four:
     # ``moe_router`` with its norm, ``moe_permute``, ``moe_experts``,
     # ``moe_combine`` with the residual add) go into every instruction's
@@ -1374,16 +1296,15 @@ def block_apply(
             branch = branch * cfg.residual_multiplier
         return x + branch
 
-    stats, new_fp8 = {}, None
+    stats = {}
     kind = next((k for k in ("ssm", "conv") if k in layer), "attention")
     if kind != "attention" and (
-            segment_ids is not None or fp8_layer is not None
-            or attn_fn is not None):
+            segment_ids is not None or attn_fn is not None):
         named = {"ssm": "mamba", "conv": "conv"}[kind]
         raise NotImplementedError(
-            f"block_apply: a {named!r} layer with segment_ids, fp8 states "
-            "or a custom attn_fn: the scan and the convolution know no "
-            "document boundary, no fp8 linear and no cache")
+            f"block_apply: a {named!r} layer with segment_ids or a custom "
+            "attn_fn: the scan and the convolution know no document "
+            "boundary and no cache")
     # outermost ``ssm`` / ``conv`` as ``attention`` is for the other kind;
     # the mixer's own scopes nest inside it (``subscopes``)
     with jax.named_scope(kind):
@@ -1393,18 +1314,10 @@ def block_apply(
         elif kind == "conv":
             mixed = _conv_mixer(h, layer["conv"], cfg)
         elif attn_fn is not None:
-            if fp8_layer is not None:
-                raise ValueError(
-                    "block_apply: fp8_layer is not supported with a "
-                    "custom attn_fn (fp8 is a training-path strategy; "
-                    "the KV-cache decode path stays in the compute dtype)"
-                )
             mixed = attn_fn(h, layer, cfg, positions)
         else:
-            mixed, new_fp8 = _attention(
-                h, layer, cfg, positions, attn_impl, mesh, segment_ids,
-                fp8_layer=fp8_layer,
-            )
+            mixed = _attention(
+                h, layer, cfg, positions, attn_impl, mesh, segment_ids)
         if cfg.branch_norm:
             mixed = rmsnorm(mixed, layer["ln1_out"], eps=cfg.rms_eps)
         x = add(x, mixed)
@@ -1414,29 +1327,19 @@ def block_apply(
         delta, routed = _moe_swiglu(
             h, layer["moe"], cfg, capacity=moe_capacity,
             valid=None if segment_ids is None else segment_ids >= 0,
-            fp8_moe=None if fp8_layer is None else fp8_layer["moe"],
         )
         stats = dict(stats, **routed)
         with jax.named_scope("moe_combine"):
             if cfg.branch_norm:
                 delta = rmsnorm(delta, layer["ln2_out"], eps=cfg.rms_eps)
             x = add(x, delta)
-        if fp8_layer is not None:
-            new_fp8["moe"] = stats.pop("fp8")
-            return x, stats, new_fp8
         return x, stats
     with jax.named_scope("mlp"):
         h = rmsnorm(x, layer["ln2"], eps=cfg.rms_eps)
-        out_m, new_fp8_mlp = _swiglu(
-            h, layer["mlp"], cfg.dtype,
-            fp8_mlp=None if fp8_layer is None else fp8_layer["mlp"],
-        )
+        out_m = _swiglu(h, layer["mlp"], cfg.dtype)
         if cfg.branch_norm:
             out_m = rmsnorm(out_m, layer["ln2_out"], eps=cfg.rms_eps)
         x = add(x, out_m)
-    if fp8_layer is not None:
-        new_fp8["mlp"] = new_fp8_mlp
-        return x, stats, new_fp8
     return x, stats
 
 
@@ -1458,32 +1361,6 @@ def segment_positions(segment_ids: jax.Array) -> jax.Array:
     return idx - start
 
 
-def init_fp8_states(cfg: LlamaConfig):
-    """Per-layer delayed-scaling Fp8State pytree for :func:`loss_fn`'s
-    ``fp8_states`` (one state per rewritten linear: wq/wk/wv/wo, plus
-    w_gate/w_up/w_down on dense-MLP layers and the stacked wg/wi/wo
-    expert tensors on MoE layers).  Thread through the train
-    state and feed each step's output back in — the functional analogue
-    of the reference's TE amax history
-    (``atorch/auto/opt_lib/amp_optimization.py:396``)."""
-    from dlrover_tpu.ops.fp8 import Fp8State
-
-    states = []
-    for i in range(cfg.n_layer):
-        st = {k: Fp8State.init() for k in ("wq", "wk", "wv", "wo")}
-        if cfg.is_moe_layer(i):
-            st["moe"] = {
-                k: Fp8State.init() for k in ("wg", "wi", "wo")
-            }
-        else:
-            st["mlp"] = {
-                k: Fp8State.init()
-                for k in ("w_gate", "w_up", "w_down")
-            }
-        states.append(st)
-    return states
-
-
 def forward_hidden(
     params: Dict,
     tokens: jax.Array,
@@ -1492,7 +1369,6 @@ def forward_hidden(
     attn_impl: str = "auto",
     mesh=None,
     segment_ids=None,
-    fp8_states=None,
     next_tokens=None,
 ) -> tuple:
     """tokens [B, S] -> (final-norm hidden [B, S, D], aux dict).
@@ -1506,9 +1382,7 @@ def forward_hidden(
 
     ``segment_ids`` [B, S] enables packed-sequence training: attention is
     restricted to same-segment pairs (flash-kernel mask) and rope
-    positions reset at each segment boundary.  ``fp8_states`` (from
-    :func:`init_fp8_states`) routes the block linears through fp8 and
-    adds the updated states to the aux dict as ``aux["fp8_states"]``.
+    positions reset at each segment boundary.
 
     A looped model (``cfg.loop_passes`` = T > 1) runs the layers T times
     on the same weights, each pass ended by the final norm, and returns
@@ -1539,11 +1413,6 @@ def forward_hidden(
     head is :func:`head_operands`'."""
     B, S = tokens.shape
     dt = cfg.dtype
-    if cfg.loop_passes > 1 and fp8_states is not None:
-        raise ValueError(
-            f"forward_hidden: loop_passes={cfg.loop_passes} with "
-            "fp8_states: a delayed-scaling state belongs to one "
-            "application of a linear, a looped layer has several")
     with jax.named_scope("embed"):
         x = params["embed"].astype(dt)[tokens]
         if cfg.embedding_multiplier != 1.0:
@@ -1578,17 +1447,10 @@ def forward_hidden(
             apply, static_argnums=(2,),
             policy=jax.checkpoint_policies.save_only_these_names(
                 *FLASH_SAVED_NAMES))
-    new_fp8 = [] if fp8_states is not None else None
     streams, exit_logits = [], []
     for _ in range(cfg.loop_passes):
         for i, layer in enumerate(params["layers"]):
-            if fp8_states is None:
-                x, stats = apply(layer, x, cfg, positions)
-            else:
-                x, stats, nf = apply(
-                    layer, x, cfg, positions, fp8_layer=fp8_states[i]
-                )
-                new_fp8.append(nf)
+            x, stats = apply(layer, x, cfg, positions)
             # Identity unless a remat policy references the name: lets
             # Strategy(remat="offload") park the inter-block residual
             # stream in host DRAM (reference
@@ -1613,10 +1475,6 @@ def forward_hidden(
                     "bsd,d->bs", x.astype(jnp.float32), gate["w"],
                     precision="highest") + gate["b"])
     if cfg.mtp_layers and next_tokens is not None:
-        if fp8_states is not None:
-            raise ValueError(
-                f"forward_hidden: mtp_layers={cfg.mtp_layers} with "
-                "fp8_states: the prediction block has no fp8 state")
         with jax.named_scope("mtp"):
             mtp = params["mtp"]
             u = jnp.concatenate([
@@ -1644,8 +1502,6 @@ def forward_hidden(
     if state_rms:
         out_aux.update(ssm_state_rms=jnp.stack(state_rms),
                        ssm_decay_min=jnp.min(jnp.stack(decay_min)))
-    if new_fp8 is not None:
-        out_aux["fp8_states"] = new_fp8
     return x, out_aux
 
 
@@ -1657,7 +1513,6 @@ def forward(
     attn_impl: str = "auto",
     mesh=None,
     segment_ids=None,
-    fp8_states=None,
     next_tokens=None,
 ) -> tuple:
     """tokens [B, S] -> (logits [B, S, vocab] fp32, aux dict); of a
@@ -1666,8 +1521,7 @@ def forward(
     and the block's, ``[2, B, S, vocab]``."""
     x, aux = forward_hidden(
         params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
-        segment_ids=segment_ids, fp8_states=fp8_states,
-        next_tokens=next_tokens,
+        segment_ids=segment_ids, next_tokens=next_tokens,
     )
     with jax.named_scope("lm_head_loss"):  # the head's matmul is the
         # unfused loss's larger half
@@ -1713,7 +1567,6 @@ def loss_fn(
     moe_aux_weight: float = 1e-2,
     moe_z_weight: float = 0.0,
     fused_lm_head: Optional[bool] = None,
-    fp8_states=None,
     metrics: bool = False,
     mtp_weight: float = 0.3,
 ) -> jax.Array:
@@ -1792,7 +1645,7 @@ def loss_fn(
     if cfg.loop_passes > 1:
         x, aux = forward_hidden(
             params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
-            segment_ids=seg, fp8_states=fp8_states,  # refused there
+            segment_ids=seg,
         )
         x, head = head_operands(params, x, cfg)
         loss, counters = exit_expectation_loss(
@@ -1803,8 +1656,7 @@ def loss_fn(
     if cfg.mtp_layers:
         x, aux = forward_hidden(
             params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
-            segment_ids=seg, fp8_states=fp8_states,  # refused there
-            next_tokens=targets,
+            segment_ids=seg, next_tokens=targets,
         )
         x, head = head_operands(params, x, cfg)
         ce, counters = mtp_loss(
@@ -1813,7 +1665,7 @@ def loss_fn(
     elif fused_lm_head:
         x, aux = forward_hidden(
             params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
-            segment_ids=seg, fp8_states=fp8_states,
+            segment_ids=seg,
         )
         with jax.named_scope("lm_head_loss"):
             # The row weights are known here, so the reduced op forms
@@ -1827,7 +1679,7 @@ def loss_fn(
     else:
         logits, aux = forward(
             params, tokens, cfg, attn_impl=attn_impl, mesh=mesh,
-            segment_ids=seg, fp8_states=fp8_states,
+            segment_ids=seg,
         )
         with jax.named_scope("lm_head_loss"):
             per_tok = softmax_cross_entropy(logits, targets)
@@ -1839,10 +1691,6 @@ def loss_fn(
     loss = ce + moe_aux_weight * aux["moe_aux"]
     if "moe_z" in aux:
         loss = loss + moe_z_weight * aux["moe_z"]
-    if fp8_states is not None:
-        # (loss, new_fp8_states): use under value_and_grad(has_aux=True)
-        # and feed the states back in next step (delayed scaling).
-        return loss, aux["fp8_states"]
     if not metrics:
         return loss
     if "ssm_state_rms" in aux:
@@ -1998,64 +1846,53 @@ def exit_expectation_loss(x, exit_logits, lm_head, targets,
     }
 
 
-def refuse_looped(cfg: LlamaConfig, where: str) -> None:
-    """``ValueError`` naming the setting, for code that applies each layer
-    once and no branch-output norm: run on a looped or sandwich-norm
-    config it would compute another model without a word."""
-    for name, default in (("loop_passes", 1), ("branch_norm", False),
-                          ("exit_gate_beta", None)):
+#: What ``forward_hidden`` / ``loss_fn`` alone compute: ``(setting, the
+#: value every other path computes, what it is)``, in the order refused.
+#: The pipeline split, the KV caches and their decoder and the HF layout
+#: table apply each attention layer once, project q, k and v from
+#: ``wq``/``wk``/``wv`` under rotary position at ``1 / sqrt(head_dim)``,
+#: hold every expert, and know one head of their own and no scalar on the
+#: stream.  For ``layer_types`` the value is the one kind of layer they
+#: know (a state-space or a convolution mixer's decode needs recurrent
+#: state beside keys and values).  A new architecture adds a row.
+TRAINING_PATH_ONLY = (
+    ("loop_passes", 1, "layers applied more than once"),
+    ("branch_norm", False, "a norm on each branch's output"),
+    ("exit_gate_beta", None, "the exit gate"),
+    ("kv_lora_rank", 0, "latent attention"),
+    ("experts_held", 0, "a share of the experts"),
+    ("mtp_layers", 0, "the multi-token-prediction block"),
+    ("layer_types", MIXER_KINDS[0], "a layer whose mixer is not attention"),
+    ("rope", True, "attention without rotary position"),
+    ("attention_multiplier", None, "attention at a stated scale"),
+    ("embedding_multiplier", 1.0, "a scalar on the embedding"),
+    ("residual_multiplier", 1.0, "a scalar on each branch"),
+    ("logits_scaling", 1.0, "a scalar on the logits"),
+    ("tie_word_embeddings", False, "a head tied to the embedding"),
+)
+
+
+def refuse_training_path_only(cfg: LlamaConfig, where: str) -> None:
+    """``ValueError`` naming the first setting of
+    :data:`TRAINING_PATH_ONLY` that ``where`` does not compute: run on
+    such a config it would compute another model without a word."""
+    for name, computed, what in TRAINING_PATH_ONLY:
         value = getattr(cfg, name)
-        if value != default:
-            raise ValueError(
-                f"{where} does not compute {name}={value!r}: it applies "
-                "every layer once, with no branch-output norm and no exit "
-                "gate (only llama.forward_hidden / loss_fn do)")
-
-
-def refuse_latent(cfg: LlamaConfig, where: str) -> None:
-    """``ValueError`` naming the setting, for code that projects q, k and
-    v from ``wq``/``wk``/``wv``, holds every expert and knows no second
-    prediction head: latent attention, a share of the experts and the
-    multi-token-prediction block are computed by ``llama.forward_hidden``
-    / ``loss_fn`` alone."""
-    for name in ("kv_lora_rank", "experts_held", "mtp_layers"):
-        value = getattr(cfg, name)
-        if value:
-            raise ValueError(
-                f"{where} does not compute {name}={value!r}: latent "
-                "attention, a share of the experts and the "
-                "multi-token-prediction block exist on the training path "
-                "only (llama.forward_hidden / loss_fn)")
-
-
-def refuse_ssm(cfg: LlamaConfig, where: str) -> None:
-    """``ValueError`` naming the setting, for code that knows one kind of
-    layer, rotary attention at ``1 / sqrt(head_dim)``, a head of its own
-    and no scalar on the stream: a layer of any kind but attention (a
-    state-space or a convolution mixer, whose decode needs recurrent state
-    beside keys and values), attention without position, the stream's
-    multipliers and a tied head are computed by ``llama.forward_hidden`` /
-    ``loss_fn`` alone."""
-    for kind in MIXER_KINDS[1:]:
-        met = sum(k == kind for k in cfg.layer_types)
-        if met:
-            raise ValueError(
-                f"{where} does not compute layer_types with a {kind!r} "
-                f"entry ({met} of {cfg.n_layer} layers): a layer whose "
-                "mixer is not attention exists on the training path only "
-                "(llama.forward_hidden / loss_fn)")
-    for name, default in (("rope", True), ("attention_multiplier", None),
-                          ("embedding_multiplier", 1.0),
-                          ("residual_multiplier", 1.0),
-                          ("logits_scaling", 1.0),
-                          ("tie_word_embeddings", False)):
-        value = getattr(cfg, name)
-        if value != default:
-            raise ValueError(
-                f"{where} does not compute {name}={value!r}: attention "
-                "without rotary position or at a stated scale, the "
-                "stream's multipliers and a tied head exist on the "
-                "training path only (llama.forward_hidden / loss_fn)")
+        if name == "layer_types":
+            kind = next((k for k in MIXER_KINDS
+                         if k != computed and k in value), None)
+            if kind is None:
+                continue
+            met = sum(k == kind for k in value)
+            said = (f"layer_types with a {kind!r} entry ({met} of "
+                    f"{cfg.n_layer} layers)")
+        elif value == computed:
+            continue
+        else:
+            said = f"{name}={value!r}"
+        raise ValueError(
+            f"{where} does not compute {said}: {what}, training path only "
+            "(llama.forward_hidden / loss_fn)")
 
 
 def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:

@@ -63,9 +63,7 @@ def init_cache(
     dense layout supports ragged per-row offsets and rewind-by-offset,
     which is what the continuous-batching server and speculative
     decoding need.  Memory cost: the full max_len rows."""
-    llama.refuse_looped(cfg, "the KV cache (models.llama_infer)")
-    llama.refuse_latent(cfg, "the KV cache (models.llama_infer)")
-    llama.refuse_ssm(cfg, "the KV cache (models.llama_infer)")
+    llama.refuse_training_path_only(cfg, "the KV cache (models.llama_infer)")
     KV, D = cfg.n_kv_head, cfg.head_dim
     L = max_len
     if cfg.sliding_window > 0 and ring and ring_len is not None:
@@ -251,9 +249,8 @@ def forward_step(
     from the training forward.  MoE layers run with a no-drop capacity:
     at T=1 the config-derived capacity rounds so coarsely that batch
     rows colliding on an expert would be silently dropped."""
-    llama.refuse_looped(cfg, "the cached decoder (models.llama_infer)")
-    llama.refuse_latent(cfg, "the cached decoder (models.llama_infer)")
-    llama.refuse_ssm(cfg, "the cached decoder (models.llama_infer)")
+    llama.refuse_training_path_only(
+        cfg, "the cached decoder (models.llama_infer)")
     B, T = tokens.shape
     dt = cfg.dtype
     offset = cache["offset"]
@@ -1452,9 +1449,8 @@ def init_paged_pool(cfg: LlamaConfig, n_blocks: int, block_size: int,
     arrays, the vllm layout).  Row ``n_blocks`` is the scratch block —
     never allocated; unassigned table entries point here so stray
     writes land somewhere harmless."""
-    llama.refuse_looped(cfg, "the paged KV pool (models.llama_infer)")
-    llama.refuse_latent(cfg, "the paged KV pool (models.llama_infer)")
-    llama.refuse_ssm(cfg, "the paged KV pool (models.llama_infer)")
+    llama.refuse_training_path_only(
+        cfg, "the paged KV pool (models.llama_infer)")
     KV, D = cfg.n_kv_head, cfg.head_dim
     NB = n_blocks + 1
 

@@ -87,9 +87,8 @@ def merge_stage_grads(
 
 
 def _stage_fn(cfg: LlamaConfig):
-    llama.refuse_looped(cfg, "the pipeline split (models.llama_pp)")
-    llama.refuse_latent(cfg, "the pipeline split (models.llama_pp)")
-    llama.refuse_ssm(cfg, "the pipeline split (models.llama_pp)")
+    llama.refuse_training_path_only(
+        cfg, "the pipeline split (models.llama_pp)")
 
     def fn(stage_blocks, x):
         B = x.shape[0]

@@ -6,7 +6,7 @@ quoted "no LoRA" because LoRA is the default cheap mode).  TPU-first
 shape: no module wrapping — LoRA is a PYTREE of (A, B) factors plus a
 pure ``merge`` that computes ``W_eff = W + scale * (A @ B)`` for the
 targeted projection leaves.  The merged tree feeds the UNCHANGED llama
-loss/decode machinery, so every path (flash attention, fp8, remat,
+loss/decode machinery, so every path (flash attention, remat,
 pipeline, KV cache) works under LoRA for free; only the factors are
 trainable (``optax.masked`` via :func:`trainable_mask`).
 

@@ -14,8 +14,3 @@ quantization CUDA ops): each op ships
 from dlrover_tpu.ops.flash_attention import flash_attention  # noqa: F401
 from dlrover_tpu.ops.rmsnorm import rmsnorm  # noqa: F401
 from dlrover_tpu.ops.cross_entropy import softmax_cross_entropy  # noqa: F401
-from dlrover_tpu.ops.fp8 import Fp8State, fp8_dot  # noqa: F401
-from dlrover_tpu.ops.amp import (  # noqa: F401
-    dynamic_loss_scaling,
-    scaled_value_and_grad,
-)

@@ -121,13 +121,6 @@ class Strategy:
     # Park optimizer state in host DRAM (ZeRO-Offload analogue,
     # optim/offload.py): XLA streams it through HBM during the update.
     offload_opt: bool = False
-    # Route eligible linears through e4m3/e5m2 fp8_dot with delayed
-    # scaling (the reference's Fp8Optimization,
-    # ``atorch/auto/opt_lib/amp_optimization.py:396``).  Requires
-    # accelerate(fp8_init=...) and a loss_fn taking ``fp8_states=`` and
-    # returning ``(loss, new_states)`` — e.g. ``models.llama.loss_fn``
-    # with ``init_fp8_states``.
-    fp8: bool = False
     # Compress the dp-axis gradient reduction to int8 (blockwise
     # quantize -> all_to_all of int8 shard-partials -> local dequant
     # reduce -> one-hot int8 psum to re-replicate; all_gather is
@@ -135,7 +128,7 @@ class Strategy:
     # which breaks check_vma), the reference's quant_reduce.cu
     # capability (``atorch/ops/csrc/quantization/quant_reduce.cu``).
     # The win is bandwidth on a DCN-crossing dp axis (multislice hybrid
-    # mesh); needs mesh.dp > 1 and is incompatible with fp8 for now.
+    # mesh); needs mesh.dp > 1.
     quant_grads: bool = False
 
     def describe(self) -> str:
@@ -143,7 +136,6 @@ class Strategy:
             f"mesh={self.mesh.describe()} remat={self.remat} "
             f"accum={self.grad_accum}"
             + (" offload_opt" if self.offload_opt else "")
-            + (" fp8" if self.fp8 else "")
             + (" quant_grads" if self.quant_grads else "")
         )
 
@@ -155,11 +147,6 @@ def quant_grads_incompat(strategy: "Strategy") -> Optional[str]:
     with compressed gradient reduction, else None."""
     if not strategy.quant_grads:
         return None
-    if strategy.fp8:
-        return (
-            "Strategy(quant_grads=True) is incompatible with fp8 for "
-            "now (fp8 state reduction across dp is undefined)"
-        )
     m = strategy.mesh
     if any(getattr(m, a) > 1 for a in ("pp", "fsdp", "ep", "tp")):
         return (
@@ -506,7 +493,6 @@ def _build_train_step(
     if strategy.remat not in ("none", "block"):
         lfn = jax.checkpoint(loss_fn, policy=remat_policy)
 
-    fp8_on = strategy.fp8
     quant_on = (
         strategy.quant_grads and quant_grads_incompat(strategy) is None
     )
@@ -636,36 +622,28 @@ def _build_train_step(
             out_specs=(P(), P()),
         )(params, batch, frozen_arg)
 
-    def _loss_and_aux(params, mb, **kw):
-        """A loss function returns its scalar, or ``(scalar, aux)``: the
-        new fp8 states under the fp8 strategy, else a dict of metrics the
-        step hands out beside ``loss`` and ``grad_norm``."""
-        out = lfn(params, mb, **kw)
-        return out if isinstance(out, tuple) else (out, {})
-
-    def _value_and_grad(params, mb, fp8, frozen):
-        """(loss, grads, new_fp8, metrics) for one microbatch; new_fp8 is
-        None when the fp8 strategy is off, metrics empty unless the loss
-        function returned some."""
+    def _value_and_grad(params, mb, frozen):
+        """(loss, grads, metrics) for one microbatch.  A loss function
+        returns its scalar, or ``(scalar, metrics)``: a dict the step
+        hands out beside ``loss`` and ``grad_norm``."""
         kw = {"frozen": frozen} if has_frozen else {}
-        if fp8_on:
-            kw["fp8_states"] = fp8
-        (loss, aux), grads = jax.value_and_grad(
-            _loss_and_aux, has_aux=True)(params, mb, **kw)
-        new_fp8, metrics = (aux, {}) if fp8_on else (None, aux)
-        return loss, grads, new_fp8, metrics
+
+        def loss_and_metrics(params, mb, **kw):
+            out = lfn(params, mb, **kw)
+            return out if isinstance(out, tuple) else (out, {})
+
+        (loss, metrics), grads = jax.value_and_grad(
+            loss_and_metrics, has_aux=True)(params, mb, **kw)
+        return loss, grads, metrics
 
     def train_step(state, batch, frozen=None):
         params = state["params"]
-        # Indexing (not .get): a state restored from a pre-fp8 checkpoint
-        # must fail fast here, not as an opaque has_aux tracing error.
-        fp8 = state["fp8"] if fp8_on else None
 
         if quant_on:
             # Accumulation happens INSIDE the sharded local step; one
             # compressed reduction per optimizer step.
             loss, grads = _quant_loss_and_grads(params, batch, frozen)
-            new_fp8, metrics = None, {}
+            metrics = {}
         elif strategy.grad_accum > 1:
             micro = jax.tree_util.tree_map(
                 lambda x: x.reshape(
@@ -675,14 +653,11 @@ def _build_train_step(
             )
 
             def acc_fn(carry, mb):
-                loss_sum, grads_sum, fp8_c = carry
-                loss, grads, new_fp8, metrics = _value_and_grad(
-                    params, mb, fp8_c, frozen
-                )
+                loss_sum, grads_sum = carry
+                loss, grads, metrics = _value_and_grad(params, mb, frozen)
                 carry = (
                     loss_sum + loss,
                     jax.tree_util.tree_map(jnp.add, grads_sum, grads),
-                    new_fp8 if fp8_on else fp8_c,
                 )
                 return carry, metrics
 
@@ -691,9 +666,8 @@ def _build_train_step(
                 jax.tree_util.tree_map(
                     lambda p: jnp.zeros(p.shape, jnp.float32), params
                 ),
-                fp8,
             )
-            (loss_sum, grad_sum, new_fp8), per_micro = jax.lax.scan(
+            (loss_sum, grad_sum), per_micro = jax.lax.scan(
                 acc_fn, zero, micro
             )
             # counts (integers) add up over the microbatches, the rest
@@ -707,8 +681,7 @@ def _build_train_step(
                 lambda g: g / strategy.grad_accum, grad_sum
             )
         else:
-            loss, grads, new_fp8, metrics = _value_and_grad(
-                params, batch, fp8, frozen)
+            loss, grads, metrics = _value_and_grad(params, batch, frozen)
 
         import optax
 
@@ -734,8 +707,6 @@ def _build_train_step(
             "opt_state": opt_state,
             "step": state["step"] + 1,
         }
-        if fp8_on:
-            new_state["fp8"] = new_fp8
         with jax.named_scope("grad_norm"):
             gnorm = optax.global_norm(grads)
         return new_state, dict(metrics, loss=loss, grad_norm=gnorm)
@@ -778,7 +749,6 @@ def accelerate(
     grad_accum: Optional[int] = None,  # force on every candidate
     search_evals: int = 10,  # strategy="bo": timed-dry-run budget
     cache: Union[None, str, Any] = None,  # StrategyCache or its path
-    fp8_init: Optional[Callable] = None,  # () -> fp8-state pytree
     # (strategy) -> loss_fn: lets a candidate rewrite the MODEL (e.g.
     # remat="block" -> cfg.remat_block=True), the reference opt_lib
     # transform shape.  Overrides loss_fn per candidate when given.
@@ -812,8 +782,7 @@ def accelerate(
             batch_axes=batch_axes, devices=devs,
             profile_steps=max(2, profile_steps), max_evals=search_evals,
             grad_accum=grad_accum, cache=cache, job_out=job_out,
-            fp8_init=fp8_init, loss_fn_builder=loss_fn_builder,
-            frozen=frozen,
+            loss_fn_builder=loss_fn_builder, frozen=frozen,
         )
         if job_out.get("job") is not None:
             # The search already compiled (and timed) the winner — don't
@@ -849,18 +818,10 @@ def accelerate(
     qg_reasons = [_qg_reason(c) for c in candidates]
     if qg_reasons and all(qg_reasons):
         # Every candidate is an incompatible quant_grads combination
-        # (fp8, hybrid mesh, or dp<=1): fail fast with the real cause —
+        # (hybrid mesh, or dp<=1): fail fast with the real cause —
         # an explicit-Strategy caller would otherwise only see the
         # generic "no viable strategy found".
         raise ValueError(qg_reasons[0])
-    if fp8_init is None and any(c.fp8 for c in candidates):
-        # Fail fast with the real cause: inside the candidate loop this
-        # ValueError would be swallowed and resurface only as the generic
-        # "no viable strategy found".
-        raise ValueError(
-            "Strategy.fp8 requires accelerate(fp8_init=...) — e.g. "
-            "lambda: llama.init_fp8_states(cfg)"
-        )
     if loss_fn_builder is None and any(
         c.remat == "block" for c in candidates
     ):
@@ -949,8 +910,7 @@ def accelerate(
             lf = loss_fn_builder(cand) if loss_fn_builder else loss_fn
             job = _compile_candidate(
                 cand, lf, init_fn, optimizer, sample_batch,
-                param_specs, batch_axes, devs, fp8_init=fp8_init,
-                frozen=frozen,
+                param_specs, batch_axes, devs, frozen=frozen,
             )
         except Exception as e:  # noqa: BLE001
             logger.info("strategy %s rejected: %s", cand.describe(), e)
@@ -1016,7 +976,6 @@ def aot_analyze(
     param_specs: Union[None, Any, Callable[[Strategy], Any]] = None,
     batch_axes: Optional[Any] = None,
     devices: Optional[Sequence] = None,
-    fp8_init: Optional[Callable] = None,
     loss_fn_builder: Optional[Callable] = None,
     frozen: Any = None,
 ) -> AcceleratedJob:
@@ -1033,13 +992,13 @@ def aot_analyze(
     lf = loss_fn_builder(strategy) if loss_fn_builder else loss_fn
     return _compile_candidate(
         strategy, lf, init_fn, optimizer, sample_batch,
-        param_specs, batch_axes, devs, fp8_init=fp8_init, frozen=frozen,
+        param_specs, batch_axes, devs, frozen=frozen,
     )
 
 
 def _compile_candidate(
     strategy, loss_fn, init_fn, optimizer, sample_batch,
-    param_specs, batch_axes, devs, fp8_init=None, frozen=None,
+    param_specs, batch_axes, devs, frozen=None,
 ) -> AcceleratedJob:
     mesh_spec = strategy.mesh.normalized(len(devs))
     strategy = dataclasses.replace(strategy, mesh=mesh_spec)
@@ -1109,18 +1068,6 @@ def _compile_candidate(
         else:
             f_specs = infer_param_specs(frozen_shape, mesh_spec)
         state_specs["frozen"] = f_specs
-    fp8_shape = None
-    if strategy.fp8:
-        if fp8_init is None:
-            raise ValueError(
-                "Strategy.fp8 requires accelerate(fp8_init=...) — e.g. "
-                "lambda: llama.init_fp8_states(cfg)"
-            )
-        fp8_shape = jax.eval_shape(fp8_init)
-        # Delayed-scaling histories are tiny scalar-ish arrays: replicate.
-        state_specs["fp8"] = jax.tree_util.tree_map(
-            lambda _: P(), fp8_shape
-        )
     state_sharding = named_sharding_tree(state_specs, mesh)
     if strategy.offload_opt:
         from dlrover_tpu.optim.offload import host_shardings_for
@@ -1223,14 +1170,11 @@ def _compile_candidate(
     def _create_state(rng, frozen_values):
         with mesh:
             def mk(r):
-                st = {
+                return {
                     "params": init_fn(r),
                     "opt_state": optimizer.init(init_fn(r)),
                     "step": jnp.zeros((), jnp.int32),
                 }
-                if strategy.fp8:
-                    st["fp8"] = fp8_init()
-                return st
 
             init_jit = jax.jit(mk, out_shardings=step_state_sharding)
             st = init_jit(rng)
@@ -1278,8 +1222,6 @@ def _compile_candidate(
     }
     if frozen is not None:
         abstract_parts["frozen"] = frozen_shape
-    if strategy.fp8:
-        abstract_parts["fp8"] = fp8_shape
     abstract_state = jax.tree_util.tree_map(
         lambda x, s: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=s),
         abstract_parts,
@@ -1371,7 +1313,6 @@ def search(
     warm_start: Sequence[Strategy] = (),
     cache: Union[None, str, Any] = None,
     job_out: Optional[dict] = None,
-    fp8_init: Optional[Callable] = None,
     loss_fn_builder: Optional[Callable] = None,
     frozen: Any = None,
 ) -> Strategy:
@@ -1449,8 +1390,7 @@ def search(
             lf = loss_fn_builder(s) if loss_fn_builder else loss_fn
             job = _compile_candidate(
                 s, lf, init_fn, optimizer, sample_batch,
-                param_specs, batch_axes, devs, fp8_init=fp8_init,
-                frozen=frozen,
+                param_specs, batch_axes, devs, frozen=frozen,
             )
         except Exception as e:  # noqa: BLE001
             err = e
@@ -1491,8 +1431,6 @@ def search(
     space_kw: dict = {}
     if grad_accum is not None:
         space_kw["accum"] = (grad_accum,)
-    if fp8_init is not None:
-        space_kw["fp8"] = (False, True)
     if loss_fn_builder is None:
         # Without a model-rewriting builder, remat="block" is
         # indistinguishable from "none" and a pp>1 mesh is pure

@@ -53,7 +53,6 @@ def strategy_to_dict(strategy) -> dict:
         "grad_accum": strategy.grad_accum,
         "donate": strategy.donate,
         "offload_opt": strategy.offload_opt,
-        "fp8": strategy.fp8,
         "quant_grads": strategy.quant_grads,
     }
 
@@ -63,6 +62,13 @@ def strategy_from_dict(d: dict):
 
     from dlrover_tpu.parallel.accelerate import Strategy
 
+    if d.get("fp8"):
+        # Stored strategies come from outside the process (the master's
+        # cache): one found under the fp8 option this tree no longer has
+        # is refused, not run in bf16 under its old score.
+        raise ValueError(
+            "stored strategy has 'fp8': true; Strategy has no fp8 option "
+            "(the step computes in compute_dtype): search again")
     return Strategy(
         mesh=MeshSpec(**d["mesh"]),
         remat=d["remat"],
@@ -70,7 +76,6 @@ def strategy_from_dict(d: dict):
         grad_accum=int(d["grad_accum"]),
         donate=bool(d.get("donate", True)),
         offload_opt=bool(d.get("offload_opt", False)),
-        fp8=bool(d.get("fp8", False)),
         quant_grads=bool(d.get("quant_grads", False)),
     )
 
@@ -88,7 +93,6 @@ def default_space(
     allow_ep: bool = False,
     allow_pp: bool = True,
     offload_opt: Sequence[bool] = (False, True),
-    fp8: Sequence[bool] = (False,),
     quant_grads: Sequence[bool] = (False,),
     base=None,
 ) -> List[Any]:
@@ -97,8 +101,7 @@ def default_space(
 
     Covers every lever the bench sweeps by hand (r2 NOTES "next perf
     wins"): pp factorizations, per-block/offload remat, host-offloaded
-    optimizer state, grad-accum up to 8, and (opt-in, needs
-    ``accelerate(fp8_init=...)``) fp8 linears."""
+    optimizer state and grad-accum up to 8."""
     from dlrover_tpu.parallel.accelerate import Strategy
 
     base = base or Strategy()
@@ -109,28 +112,22 @@ def default_space(
         for r in remat:
             for a in accum:
                 for oo in offload_opt:
-                    for f8 in fp8:
-                        if f8 and spec.pp > 1:
-                            # The pipelined loss path takes no
-                            # fp8_states; such a point would burn a
-                            # compile and die as an opaque TypeError.
-                            continue
-                        for qg in quant_grads:
-                            cand = dataclasses.replace(
-                                base, mesh=spec, remat=r,
-                                grad_accum=a, offload_opt=oo,
-                                fp8=f8, quant_grads=qg,
-                            )
-                            if qg:
-                                from dlrover_tpu.parallel.accelerate \
-                                    import quant_grads_incompat
+                    for qg in quant_grads:
+                        cand = dataclasses.replace(
+                            base, mesh=spec, remat=r,
+                            grad_accum=a, offload_opt=oo,
+                            quant_grads=qg,
+                        )
+                        if qg:
+                            from dlrover_tpu.parallel.accelerate \
+                                import quant_grads_incompat
 
-                                # Incompatible combination (no dp axis
-                                # to compress, hybrid mesh, fp8): skip
-                                # rather than burn a compile.
-                                if quant_grads_incompat(cand):
-                                    continue
-                            out.append(cand)
+                            # Incompatible combination (no dp axis
+                            # to compress, hybrid mesh): skip rather
+                            # than burn a compile.
+                            if quant_grads_incompat(cand):
+                                continue
+                        out.append(cand)
     return out
 
 
@@ -283,7 +280,6 @@ def _features(strategy) -> np.ndarray:
             else 1.0,
             np.log2(max(1, strategy.grad_accum)),
             float(strategy.offload_opt),
-            float(strategy.fp8),
             float(strategy.quant_grads),
         ],
         dtype=np.float64,

@@ -42,9 +42,6 @@ def parse_args():
     p.add_argument("--strategy", default="dp",
                    choices=["dp", "auto"])
     p.add_argument("--remat_block", action="store_true")
-    p.add_argument("--fp8", action="store_true",
-                   help="route attention/MLP linears through e4m3/e5m2 "
-                        "fp8_dot with delayed scaling")
     p.add_argument("--quant_grads", action="store_true",
                    help="int8-compress the dp gradient reduction "
                         "(pure-dp mesh; the DCN-bandwidth lever)")
@@ -117,7 +114,7 @@ def main() -> int:
     strategy = (
         "auto" if args.strategy == "auto"
         else Strategy(
-            mesh=MeshSpec(dp=len(jax.devices())), fp8=args.fp8,
+            mesh=MeshSpec(dp=len(jax.devices())),
             quant_grads=args.quant_grads,
         )
     )
@@ -153,11 +150,8 @@ def main() -> int:
             t.strip() for t in args.lora_targets.split(",") if t.strip()
         )
 
-        def loss_fn(factors, b, frozen, fp8_states=None):
-            return llama.loss_fn(
-                lora.merge(frozen, factors), b, cfg,
-                fp8_states=fp8_states,
-            )
+        def loss_fn(factors, b, frozen):
+            return llama.loss_fn(lora.merge(frozen, factors), b, cfg)
 
         base_for_shapes = frozen
 
@@ -169,13 +163,7 @@ def main() -> int:
             optax.adamw(args.lr), lora.trainable_mask
         )
     else:
-        # One signature for both modes (fp8_states defaults to None in
-        # llama.loss_fn): under --strategy auto the sweep mixes fp8 and
-        # non-fp8 candidates, and a required fp8_states would silently
-        # reject every non-fp8 point.
-        loss_fn = lambda p, b, fp8_states=None: llama.loss_fn(  # noqa: E731
-            p, b, cfg, fp8_states=fp8_states
-        )
+        loss_fn = lambda p, b: llama.loss_fn(p, b, cfg)  # noqa: E731
         init_fn = lambda r: llama.init_params(r, cfg)  # noqa: E731
         optimizer = optax.adamw(args.lr)
         frozen = None
@@ -187,8 +175,6 @@ def main() -> int:
         sample_batch={"tokens": sample},
         strategy=strategy,
         param_specs="planner",
-        fp8_init=(lambda: llama.init_fp8_states(cfg))
-        if args.fp8 else None,
         frozen=frozen,
     )
     print(f"{tag} PROGRAM {json.dumps(job.program)}", flush=True)

@@ -77,6 +77,36 @@ def run_cpu_mesh_subprocess(
     return proc
 
 
+#: how each of :func:`refusing_calls`' paths names itself in its refusal
+REFUSING_PATH_NAMES = {
+    "pipeline stage": "the pipeline split", "kv cache": "the KV cache",
+    "paged pool": "the paged KV pool",
+    "cached decoder": "the cached decoder",
+    "hf layout": "the HF Llama layout table",
+}
+
+
+def refusing_calls(cfg):
+    """The five paths beside ``llama.forward_hidden`` / ``loss_fn``, each
+    as a call that must refuse ``cfg`` (``llama.TRAINING_PATH_ONLY``)
+    before it touches a parameter.  A function and not a fixture: its keys
+    parametrise tests at collection time."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dlrover_tpu.models import hf_convert, llama_infer, llama_pp
+
+    return {
+        "pipeline stage": lambda: llama_pp._stage_fn(cfg),
+        "kv cache": lambda: llama_infer.init_cache(cfg, 1, 8),
+        "paged pool": lambda: llama_infer.init_paged_pool(cfg, 4, 4),
+        "cached decoder": lambda: llama_infer.forward_step(
+            None, jnp.zeros((1, 1), jnp.int32), cfg, {"offset": 0}),
+        "hf layout": lambda: hf_convert._build_params(
+            lambda name: np.zeros(()), lambda: [], cfg, jnp.float32),
+    }
+
+
 @pytest.fixture(scope="session")
 def cpu_mesh_subprocess():
     """Session fixture handle on :func:`run_cpu_mesh_subprocess`."""

@@ -1,6 +1,7 @@
 """Flash-checkpoint tests: flatten/assemble (resharding), engine save/load,
 shard-file commit protocol, agent saver breakpoint save."""
 
+import dataclasses
 import os
 import time
 
@@ -185,8 +186,27 @@ class TestShardFile:
         assert len(remaining) == 2
 
 
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class _History:
+    """A registered pytree class, as a train state may hold one beside its
+    dicts (an optimizer's own state, a caller's counters)."""
+
+    seen: jax.Array
+
+    def tree_flatten(self):
+        return (self.seen,), None
+
+    @classmethod
+    def tree_unflatten(cls, _, leaves):
+        return cls(*leaves)
+
+
 class TestEngineStandalone:
-    def test_save_load_memory_and_storage(self, tmp_path, monkeypatch):
+    @pytest.mark.parametrize("holder", [dict, _History],
+                             ids=["dict", "pytree class"])
+    def test_save_load_memory_and_storage(
+            self, tmp_path, monkeypatch, holder):
         monkeypatch.setenv("DLROVER_TPU_JOB_NAME", "ckpt-ut")
         monkeypatch.setenv("DLROVER_TPU_PROCESS_ID", "0")
         monkeypatch.setenv("DLROVER_TPU_NUM_PROCESSES", "1")
@@ -194,13 +214,21 @@ class TestEngineStandalone:
         state = {
             "params": {"w": jnp.arange(16.0).reshape(4, 4)},
             "count": jnp.array(3),
+            "history": holder(seen=jnp.arange(4.0)),
         }
         ckpt.save(state, meta={"step": 5})  # memory only
-        restored, meta = ckpt.load(target=state)
+        # load(target=) rebuilds the target's own containers around the
+        # saved leaves
+        restored, meta = ckpt.load(
+            target=jax.tree_util.tree_map(jnp.zeros_like, state))
         assert meta["step"] == 5
         np.testing.assert_array_equal(
             np.asarray(restored["params"]["w"]), np.arange(16.0).reshape(4, 4)
         )
+        assert type(restored["history"]) is holder
+        np.testing.assert_array_equal(
+            np.asarray(jax.tree_util.tree_leaves(restored["history"])[0]),
+            np.arange(4.0))
         # Storage save + wait -> tracker advanced.
         ckpt.save(state, meta={"step": 6}, storage=True)
         assert ckpt.wait(timeout=60)

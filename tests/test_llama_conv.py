@@ -20,8 +20,9 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import REFUSING_PATH_NAMES, refusing_calls
 
-from dlrover_tpu.models import hf_convert, llama, llama_infer, llama_pp
+from dlrover_tpu.models import llama, llama_infer
 from dlrover_tpu.parallel.mesh import MeshSpec
 
 acc = importlib.import_module("dlrover_tpu.parallel.accelerate")
@@ -280,7 +281,7 @@ def test_attention_norms_q_and_k_as_the_setting_says(per_head):
     layer = _attention_layer(cfg)
     u = jax.random.normal(jax.random.PRNGKey(3), (B, S, D))
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    got, _ = llama._attention(u, layer, cfg, positions, "auto", None)
+    got = llama._attention(u, layer, cfg, positions, "auto", None)
     want = _attention_by_head(u, layer, cfg, per_head=per_head)
     if per_head:
         assert _rel(got, want) < 1e-5
@@ -601,39 +602,24 @@ def test_fsdp2_tp2_gives_the_one_device_loss_and_gradients():
 # -- what cannot compute it says so -------------------------------------------
 
 
-def _refusing_calls(cfg):
-    return {
-        "pipeline stage": lambda: llama_pp._stage_fn(cfg),
-        "kv cache": lambda: llama_infer.init_cache(cfg, 1, 8),
-        "paged pool": lambda: llama_infer.init_paged_pool(cfg, 4, 4),
-        "cached decoder": lambda: llama_infer.forward_step(
-            None, jnp.zeros((1, 1), jnp.int32), cfg, {"offset": 0}),
-        "hf layout": lambda: hf_convert._build_params(
-            lambda name: np.zeros(()), lambda: [], cfg, jnp.float32),
-    }
-
-
-@pytest.mark.parametrize("where,path", [
-    ("pipeline stage", "the pipeline split"), ("kv cache", "the KV cache"),
-    ("paged pool", "the paged KV pool"),
-    ("cached decoder", "the cached decoder"),
-    ("hf layout", "the HF Llama layout table")])
+@pytest.mark.parametrize("where,path", sorted(REFUSING_PATH_NAMES.items()))
 def test_the_refusal_names_the_conv_layers_and_the_path(where, path):
     # a dense stack, so that the layer kind is the first thing refused
     cfg = _lfm(num_experts=0, router_bias_rate=None,
                tie_word_embeddings=False)
     with pytest.raises(ValueError) as e:
-        _refusing_calls(cfg)[where]()
+        refusing_calls(cfg)[where]()
     assert "'conv' entry (2 of 3 layers)" in str(e.value)
     assert path in str(e.value) and "training path only" in str(e.value)
 
 
-def test_a_conv_layer_refuses_what_its_taps_do_not_know():
+@pytest.mark.parametrize("kw", [
+    dict(segment_ids=np.zeros((B, S), np.int32)),
+    dict(attn_fn=lambda *a: None)], ids=["segment_ids", "attn_fn"])
+def test_a_conv_layer_refuses_what_its_taps_do_not_know(kw):
     cfg = _lfm()
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     x = jnp.zeros((B, S, cfg.d_model))
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    for kw in (dict(segment_ids=jnp.zeros((B, S), jnp.int32)),
-               dict(fp8_layer={}), dict(attn_fn=lambda *a: None)):
-        with pytest.raises(NotImplementedError, match="'conv' layer"):
-            llama.block_apply(params["layers"][0], x, cfg, positions, **kw)
+    with pytest.raises(NotImplementedError, match="'conv' layer"):
+        llama.block_apply(params["layers"][0], x, cfg, positions, **kw)

@@ -18,11 +18,12 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import REFUSING_PATH_NAMES, refusing_calls
 from test_lm_head_loss import (  # noqa: I100 - shared
     _assert_trees_close as _tree_close,
 )
 
-from dlrover_tpu.models import hf_convert, llama, llama_infer, llama_pp
+from dlrover_tpu.models import hf_convert, llama
 from dlrover_tpu.ops.cross_entropy import (
     linear_softmax_cross_entropy,
     linear_softmax_cross_entropy_sum,
@@ -435,25 +436,14 @@ SETTINGS = {
 }
 
 
-def _refusing_calls(cfg):
-    params = None  # refused before any parameter is touched
-    return {
-        "pipeline stage": lambda: llama_pp._stage_fn(cfg),
-        "kv cache": lambda: llama_infer.init_cache(cfg, 1, 8),
-        "paged pool": lambda: llama_infer.init_paged_pool(cfg, 4, 4),
-        "cached decoder": lambda: llama_infer.forward_step(
-            params, jnp.zeros((1, 1), jnp.int32), cfg, {"offset": 0}),
-        "hf layout": lambda: hf_convert._build_params(
-            lambda name: np.zeros(()), lambda: [], cfg, jnp.float32),
-    }
-
-
-@pytest.mark.parametrize("where", sorted(_refusing_calls(None)))
+@pytest.mark.parametrize("where", sorted(refusing_calls(None)))
 @pytest.mark.parametrize("setting", sorted(SETTINGS))
 def test_paths_that_apply_each_layer_once_refuse_by_name(setting, where):
     cfg = _cfg(**SETTINGS[setting])
-    with pytest.raises(ValueError, match=setting):
-        _refusing_calls(cfg)[where]()
+    with pytest.raises(ValueError, match=setting) as e:
+        refusing_calls(cfg)[where]()
+    assert REFUSING_PATH_NAMES[where] in str(e.value)
+    assert "training path only" in str(e.value)
 
 
 def test_hf_config_of_a_looped_model_is_refused_by_name():
@@ -475,14 +465,6 @@ def test_hf_config_of_a_looped_model_is_refused_by_name():
 def test_config_refuses_half_a_looped_model(over, match):
     with pytest.raises(ValueError, match=match):
         _cfg(**over)
-
-
-def test_fp8_states_are_refused_for_a_looped_model():
-    cfg = _looped()
-    params = llama.init_params(jax.random.PRNGKey(0), cfg)
-    with pytest.raises(ValueError, match="loop_passes=3"):
-        llama.loss_fn(params, {"tokens": _tokens()}, cfg,
-                      fp8_states=llama.init_fp8_states(cfg))
 
 
 # -- the count the trainer's MFU print uses -----------------------------------

@@ -19,8 +19,9 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import REFUSING_PATH_NAMES, refusing_calls
 
-from dlrover_tpu.models import hf_convert, llama, llama_infer, llama_pp
+from dlrover_tpu.models import llama
 from dlrover_tpu.ops.grouped_matmul import grouped_matmul_ragged
 from dlrover_tpu.parallel.mesh import MeshSpec
 
@@ -241,7 +242,7 @@ def _attention_case(cfg, s):
     cot = jax.random.normal(jax.random.PRNGKey(5), y.shape, F32)
 
     def system(y, leaves):
-        out, _ = llama._attention(
+        out = llama._attention(
             y, dict(layer, **leaves), cfg, positions, "auto", None)
         return jnp.sum(out * cot), out
 
@@ -889,32 +890,10 @@ SETTINGS = {
 }
 
 
-def _refusing_calls(cfg):
-    return {
-        "pipeline stage": lambda: llama_pp._stage_fn(cfg),
-        "kv cache": lambda: llama_infer.init_cache(cfg, 1, 8),
-        "paged pool": lambda: llama_infer.init_paged_pool(cfg, 4, 4),
-        "cached decoder": lambda: llama_infer.forward_step(
-            None, jnp.zeros((1, 1), jnp.int32), cfg, {"offset": 0}),
-        "hf layout": lambda: hf_convert._build_params(
-            lambda name: np.zeros(()), lambda: [], cfg, jnp.float32),
-    }
-
-
-@pytest.mark.parametrize("where", sorted(_refusing_calls(None)))
+@pytest.mark.parametrize("where", sorted(refusing_calls(None)))
 @pytest.mark.parametrize("setting", sorted(SETTINGS))
 def test_paths_without_the_latent_block_refuse_by_name(setting, where):
-    with pytest.raises(ValueError, match=setting):
-        _refusing_calls(SETTINGS[setting])[where]()
-
-
-def test_fp8_states_are_refused_for_what_they_do_not_know():
-    cfg = _glm()
-    params = llama.init_params(jax.random.PRNGKey(0), cfg)
-    x = _x()
-    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    with pytest.raises(ValueError, match="kv_lora_rank=16"):
-        llama._attention(x, params["layers"][0], cfg, positions, "auto",
-                         None, fp8_layer={})
-    with pytest.raises(ValueError, match="experts_held=4"):
-        llama._moe_swiglu(x, params["layers"][1]["moe"], cfg, fp8_moe={})
+    with pytest.raises(ValueError, match=setting) as e:
+        refusing_calls(SETTINGS[setting])[where]()
+    assert REFUSING_PATH_NAMES[where] in str(e.value)
+    assert "training path only" in str(e.value)

@@ -21,8 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import refusing_calls
 
-from dlrover_tpu.models import hf_convert, llama, llama_infer, llama_pp
+from dlrover_tpu.models import llama
 from dlrover_tpu.ops import ssd
 from dlrover_tpu.parallel.mesh import MeshSpec
 
@@ -760,23 +761,11 @@ SETTINGS = {
 }
 
 
-def _refusing_calls(cfg):
-    return {
-        "pipeline stage": lambda: llama_pp._stage_fn(cfg),
-        "kv cache": lambda: llama_infer.init_cache(cfg, 1, 8),
-        "paged pool": lambda: llama_infer.init_paged_pool(cfg, 4, 4),
-        "cached decoder": lambda: llama_infer.forward_step(
-            None, jnp.zeros((1, 1), jnp.int32), cfg, {"offset": 0}),
-        "hf layout": lambda: hf_convert._build_params(
-            lambda name: np.zeros(()), lambda: [], cfg, jnp.float32),
-    }
-
-
-@pytest.mark.parametrize("where", sorted(_refusing_calls(None)))
+@pytest.mark.parametrize("where", sorted(refusing_calls(None)))
 @pytest.mark.parametrize("setting", sorted(SETTINGS))
 def test_paths_without_the_state_space_layer_refuse_by_name(setting, where):
     with pytest.raises(ValueError, match=setting):
-        _refusing_calls(SETTINGS[setting])[where]()
+        refusing_calls(SETTINGS[setting])[where]()
 
 
 @pytest.mark.parametrize("where,path", [
@@ -784,17 +773,18 @@ def test_paths_without_the_state_space_layer_refuse_by_name(setting, where):
     ("hf layout", "the HF Llama layout table")])
 def test_the_refusal_names_the_mamba_layers_and_the_path(where, path):
     with pytest.raises(ValueError) as e:
-        _refusing_calls(_hybrid())[where]()
+        refusing_calls(_hybrid())[where]()
     assert "'mamba' entry (2 of 3 layers)" in str(e.value)
     assert path in str(e.value) and "training path only" in str(e.value)
 
 
-def test_a_mamba_layer_refuses_what_its_scan_does_not_know():
+@pytest.mark.parametrize("kw", [
+    dict(segment_ids=np.zeros((B, S), np.int32)),
+    dict(attn_fn=lambda *a: None)], ids=["segment_ids", "attn_fn"])
+def test_a_mamba_layer_refuses_what_its_scan_does_not_know(kw):
     cfg = _hybrid()
     params = _params(cfg)
     x = jnp.zeros((B, S, cfg.d_model))
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    for kw in (dict(segment_ids=jnp.zeros((B, S), jnp.int32)),
-               dict(fp8_layer={}), dict(attn_fn=lambda *a: None)):
-        with pytest.raises(NotImplementedError, match="'mamba' layer"):
-            llama.block_apply(params["layers"][0], x, cfg, positions, **kw)
+    with pytest.raises(NotImplementedError, match="'mamba' layer"):
+        llama.block_apply(params["layers"][0], x, cfg, positions, **kw)

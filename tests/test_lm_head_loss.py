@@ -193,22 +193,3 @@ def test_one_step_of_every_accelerate_path_matches_the_per_token_loss(path):
     np.testing.assert_allclose(
         got_m["grad_norm"], want_m["grad_norm"], rtol=max(tol, 1e-4))
     _assert_trees_close(got_p, want_p, atol=tol)
-
-
-def test_fp8_states_ride_beside_the_reduced_loss():
-    """``fp8_states`` makes ``loss_fn`` return (loss, new states) under
-    ``has_aux``: the reduced op is the differentiated output's only
-    head term, and both forms of the head agree."""
-    cfg = _cfg()
-    params = llama.init_params(jax.random.PRNGKey(0), cfg)
-    batch = _batch("none")
-    states = llama.init_fp8_states(cfg)
-    out = [
-        jax.value_and_grad(
-            lambda p: llama.loss_fn(
-                p, batch, cfg, fp8_states=states, fused_lm_head=fused),
-            has_aux=True)(params)
-        for fused in (True, False)]
-    ((got, _), got_g), ((want, _), want_g) = out
-    np.testing.assert_allclose(got, want, rtol=1e-5)
-    _assert_trees_close(got_g, want_g, atol=1e-5)

@@ -88,7 +88,7 @@ def _lora_problem(n_layer=2, seq=16, batch=8, **cfg_over):
 
 
 class TestLoraCompose:
-    """LoRA x {fsdp, fp8, pp, checkpoint-resume} through the PRODUCT
+    """LoRA x {fsdp, pp, checkpoint-resume} through the PRODUCT
     path (accelerate's ``frozen`` state) — the claims lora.py used to
     make without tests (round-3 review Weak #5)."""
 
@@ -132,41 +132,6 @@ class TestLoraCompose:
         assert float(
             jnp.abs(state["params"]["layers"][0]["wq"]["b"]).max()
         ) > 0
-
-    def test_lora_fp8(self, cpu_mesh_devices):
-        from dlrover_tpu.parallel.accelerate import Strategy, accelerate
-        from dlrover_tpu.parallel.mesh import MeshSpec
-
-        cfg, base, toks = _lora_problem()
-
-        def loss_fn(factors, batch, fp8_states=None, frozen=None):
-            return llama.loss_fn(
-                lora.merge(frozen, factors), batch, cfg,
-                fp8_states=fp8_states,
-            )
-
-        job = accelerate(
-            loss_fn=loss_fn,
-            init_fn=lambda r: lora.init_lora(r, base, rank=4),
-            optimizer=optax.masked(optax.adamw(1e-2),
-                                   lora.trainable_mask),
-            sample_batch={"tokens": toks},
-            strategy=Strategy(mesh=MeshSpec(dp=2, fsdp=2), fp8=True),
-            devices=cpu_mesh_devices[:4],
-            fp8_init=lambda: llama.init_fp8_states(cfg),
-            frozen=base,
-        )
-        state = job.create_state(jax.random.PRNGKey(2))
-        batch = {"tokens": jnp.asarray(toks)}
-        losses = []
-        for _ in range(6):
-            state, m = job.train_step(state, batch)
-            losses.append(float(m["loss"]))
-        assert np.isfinite(losses).all()
-        assert losses[-1] < losses[0], losses
-        # fp8 amax histories actually advanced (the states are live).
-        leaves = jax.tree_util.tree_leaves(state["fp8"])
-        assert any(float(jnp.abs(x).max()) > 0 for x in leaves)
 
     def test_lora_pp_grads_match_dense_merge(self, cpu_mesh_devices):
         """Pipelined loss over the merged tree: grads wrt the FACTORS

@@ -291,7 +291,7 @@ def test_qk_norm_over_the_whole_projection(qk_norm):
     layer = dict(layer, wq=layer["wq"] * 20, wk=layer["wk"] * 20)
     x = _x(b=2, s=10)
     pos = jnp.broadcast_to(jnp.arange(10), (2, 10))
-    got, _ = llama._attention(x, layer, cfg, pos, "auto", None)
+    got = llama._attention(x, layer, cfg, pos, "auto", None)
     np.testing.assert_allclose(
         got, _plain_attention(x, layer, cfg, qk_norm), atol=2e-5)
     if qk_norm:

@@ -11,12 +11,29 @@ import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.models import llama
+from dlrover_tpu.ops.quant import dequantize_blockwise, quantize_blockwise
 from dlrover_tpu.ops.quant_collectives import (
     quantized_pmean,
     quantized_psum,
 )
 from dlrover_tpu.parallel.accelerate import Strategy, accelerate
 from dlrover_tpu.parallel.mesh import MeshSpec
+
+
+class TestPallasQuant:
+    def test_pallas_matches_jnp_path(self):
+        rs = np.random.RandomState(3)
+        x = jnp.asarray(rs.randn(1000) * 10, jnp.float32)
+        cj, sj = quantize_blockwise(x, backend="jnp")
+        cp, sp = quantize_blockwise(x, backend="pallas", interpret=True)
+        np.testing.assert_array_equal(np.asarray(cj), np.asarray(cp))
+        np.testing.assert_allclose(
+            np.asarray(sj), np.asarray(sp), rtol=1e-6
+        )
+        back = dequantize_blockwise(cp, sp, x.shape)
+        assert float(jnp.max(jnp.abs(back - x))) <= float(
+            jnp.max(sp)
+        )  # within one quantization step
 
 
 class TestQuantizedCollective:
@@ -178,39 +195,47 @@ class TestQuantGradsStrategy:
         assert abs(quant[5] - exact[5]) < 0.1, (exact[5], quant[5])
         assert abs(quant[-1] - exact[-1]) < 0.5, (exact[-1], quant[-1])
 
-    def test_rejected_with_fp8_or_sharded_mesh(self, cpu_mesh_devices):
+    @pytest.mark.parametrize("mesh,match", [
+        # fsdp x quant_grads: fail fast with the real cause.
+        (dict(dp=2, fsdp=2), "pure-dp mesh"),
+        # dp=1 x quant_grads: nothing to compress — fail fast, not a
+        # silent no-op.
+        (dict(), "dp > 1"),
+    ], ids=["sharded mesh", "dp=1"])
+    def test_rejected_with_a_sharded_or_single_mesh(
+            self, cpu_mesh_devices, mesh, match):
         cfg = llama.LlamaConfig.tiny(n_layer=1, max_seq_len=16)
         toks = np.random.RandomState(0).randint(
             0, cfg.vocab_size, (8, 17)
         ).astype("int32")
-        kw = dict(
-            loss_fn=lambda p, b: llama.loss_fn(p, b, cfg),
-            init_fn=lambda r: llama.init_params(r, cfg),
-            optimizer=optax.adamw(1e-2),
-            sample_batch={"tokens": toks},
-        )
-        with pytest.raises(ValueError, match="pure-dp mesh"):
-            # fsdp x quant_grads: fail fast with the real cause.
+        spec = MeshSpec(**mesh)
+        with pytest.raises(ValueError, match=match):
             accelerate(
-                strategy=Strategy(
-                    mesh=MeshSpec(dp=2, fsdp=2), quant_grads=True
-                ),
-                devices=cpu_mesh_devices[:4], **kw,
+                loss_fn=lambda p, b: llama.loss_fn(p, b, cfg),
+                init_fn=lambda r: llama.init_params(r, cfg),
+                optimizer=optax.adamw(1e-2),
+                sample_batch={"tokens": toks},
+                strategy=Strategy(mesh=spec, quant_grads=True),
+                devices=cpu_mesh_devices[:spec.dp * spec.fsdp],
             )
-        with pytest.raises(ValueError, match="dp > 1"):
-            # dp=1 x quant_grads: nothing to compress — fail fast, not
-            # a silent no-op.
+
+    def test_a_loss_function_with_metrics_is_refused_by_name(
+            self, cpu_mesh_devices):
+        """The step's contract under the compressed reduction: the loss
+        function returns its scalar.  ``(loss, metrics)`` is refused at
+        compile time (the candidate's rejection carries the words), not
+        dropped."""
+        cfg = llama.LlamaConfig.tiny(n_layer=1, max_seq_len=16)
+        toks = np.zeros((8, 17), np.int32)
+        with pytest.raises(RuntimeError, match="hands out no metrics"):
             accelerate(
-                strategy=Strategy(quant_grads=True),
-                devices=cpu_mesh_devices[:1], **kw,
-            )
-        with pytest.raises(ValueError, match="incompatible with fp8"):
-            accelerate(
-                strategy=Strategy(
-                    mesh=MeshSpec(dp=4), quant_grads=True, fp8=True
-                ),
-                devices=cpu_mesh_devices[:4],
-                fp8_init=lambda: llama.init_fp8_states(cfg), **kw,
+                loss_fn=lambda p, b: (
+                    llama.loss_fn(p, b, cfg), {"tokens": jnp.zeros(())}),
+                init_fn=lambda r: llama.init_params(r, cfg),
+                optimizer=optax.adamw(1e-2),
+                sample_batch={"tokens": toks},
+                strategy=Strategy(mesh=MeshSpec(dp=2), quant_grads=True),
+                devices=cpu_mesh_devices[:2],
             )
 
     def test_space_only_offers_pure_dp_points(self):
@@ -225,7 +250,6 @@ class TestQuantGradsStrategy:
                 getattr(s.mesh, a) <= 1
                 for a in ("pp", "fsdp", "ep", "tp")
             )
-            assert not s.fp8
 
     def test_strategy_roundtrips(self):
         from dlrover_tpu.parallel.strategy_search import (

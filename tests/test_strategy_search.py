@@ -54,16 +54,19 @@ class TestSerialization:
         assert s2.grad_accum == s.grad_accum
         assert jnp.dtype(s2.compute_dtype) == jnp.dtype(s.compute_dtype)
 
-
-class TestSpace:
-    def test_no_pp_fp8_points(self):
-        """pp>1 x fp8 can't be honored by the pipelined loss path
-        (takes no fp8_states) — such points must be pruned from the
-        grid, not burn a compile and die as a TypeError (ADVICE r3)."""
-        space = default_space(8, fp8=(False, True), allow_pp=True)
-        assert any(s.fp8 for s in space)
-        assert any(s.mesh.pp > 1 for s in space)
-        assert not any(s.fp8 and s.mesh.pp > 1 for s in space)
+    @pytest.mark.parametrize("stored", [{}, {"fp8": False}, {"fp8": True}],
+                             ids=["no key", "false", "true"])
+    def test_a_stored_strategy_of_the_fp8_era(self, stored):
+        """Stored strategies come from outside the process (the master's
+        cache, a JSON file): one that ran without fp8 loads as it did, one
+        that was scored with it is refused by the key's name — not run in
+        bf16 under its old score."""
+        d = dict(strategy_to_dict(Strategy(mesh=MeshSpec(dp=2))), **stored)
+        if stored.get("fp8"):
+            with pytest.raises(ValueError, match="'fp8': true"):
+                strategy_from_dict(d)
+        else:
+            assert strategy_from_dict(d) == Strategy(mesh=MeshSpec(dp=2))
 
 
 class TestBayesSearch:
@@ -360,13 +363,12 @@ class TestWidenedSpace:
             default_space,
         )
 
-        space = default_space(8, fp8=(False, True))
+        space = default_space(8)
         assert any(s.mesh.pp > 1 for s in space), "no pp points"
         assert any(s.offload_opt for s in space), "no offload_opt points"
         assert any(s.remat == "offload" for s in space)
         assert any(s.remat == "block" for s in space)
         assert any(s.grad_accum == 8 for s in space)
-        assert any(s.fp8 for s in space)
         assert set(REMAT_CHOICES) == {
             "none", "dots", "full", "block", "offload"
         }
