@@ -32,14 +32,23 @@ from dlrover_tpu.ops.flash_attention import (
     SAVED_NAMES as FLASH_SAVED_NAMES,
     flash_attention,
 )
+from dlrover_tpu.ops.gated_delta import gated_delta_chunked
 from dlrover_tpu.ops.gather_sum import gather_sum, weighted_sum
 from dlrover_tpu.ops.grouped_matmul import TILING, grouped_matmul_ragged
 from dlrover_tpu.ops.rmsnorm import rmsnorm
 from dlrover_tpu.ops.ssd import causal_conv1d, ssd_chunked
 
 
-#: the kinds of mixer a layer may have (``LlamaConfig.layer_types``)
-MIXER_KINDS = ("attention", "mamba", "conv")
+#: the kinds of mixer a layer may have (``LlamaConfig.layer_types``), each
+#: with its block scope, which is also the key of the layer dict that holds
+#: its leaves (the attention leaves sit in the layer dict itself) and, with
+#: ``_layers``, the name :func:`program_facts` counts its layers under.  A
+#: new kind adds a row.
+MIXER_KINDS = {"attention": "attention", "mamba": "ssm", "conv": "conv",
+               "linear_attention": "gdn"}
+#: positions a chunk of the gated delta rule holds (``ops.gated_delta``): a
+#: shape decision of the op, not a setting
+GDN_CHUNK = 64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,11 +169,12 @@ class LlamaConfig:
     # output, predicts ``t_{i+2}`` through the shared head
     # (:func:`forward_hidden`, :func:`loss_fn`).
     mtp_layers: int = 0
-    # The kind of each layer's MIXER, "attention", "mamba" or "conv" (a
-    # tuple of ``n_layer`` names; empty: every layer is an attention
-    # layer), under the same pre-norm and residual add; which MLP follows
-    # (dense or routed) is :meth:`is_moe_layer`'s, whatever the mixer — but
-    # for "mamba", whose MLP is dense.  A "conv" layer's mixer is LFM2's
+    # The kind of each layer's MIXER, "attention", "mamba", "conv" or
+    # "linear_attention" (a tuple of ``n_layer`` names; empty: every layer
+    # is an attention layer), under the same pre-norm and residual add;
+    # which MLP follows (dense or routed) is :meth:`is_moe_layer`'s,
+    # whatever the mixer — but for "mamba", whose MLP is dense.  A "conv"
+    # layer's mixer is LFM2's
     # double-gated short convolution (:func:`_conv_mixer`): ``[B | C | X]
     # = u in_proj``, a causal depthwise convolution of ``conv_taps`` taps
     # over ``B * X``, times ``C``, ``out_proj``; no bias, no activation.  A
@@ -202,6 +212,32 @@ class LlamaConfig:
     # The head reads ``embed`` transposed: ONE leaf, no ``lm_head``, and
     # the gradient of ``embed`` is the sum of both uses.
     tie_word_embeddings: bool = False
+    # A "linear_attention" layer's mixer is the Gated DeltaNet one
+    # (:func:`_gdn_mixer`, ``ops.gated_delta``): ``gdn_k_heads`` key heads
+    # and ``gdn_v_heads`` value heads (a multiple; each key head serves
+    # ``gdn_v_heads / gdn_k_heads`` of them) of ``gdn_d_head`` dims, behind
+    # a causal depthwise convolution of ``gdn_d_conv`` taps without bias.
+    # Experts may follow it, as they may a "conv" mixer.
+    gdn_k_heads: int = 0
+    gdn_v_heads: int = 0
+    gdn_d_head: int = 0
+    gdn_d_conv: int = 4
+    # The attention layers' head size where it is not ``d_model / n_head``
+    # (0: it is; latent attention states its own).
+    attn_head_dim: int = 0
+    # A gate on the attention output: ``wq`` is twice as wide, each head's
+    # columns ``[q | gate]``, and ``out = (attn * sigmoid(gate)) wo``.
+    attn_output_gate: bool = False
+    # The share of a head's dims that rotate, the FIRST ``head_dim *
+    # partial_rotary_factor`` of them in pairs ``(j, j + half)``; the rest
+    # carry no position.
+    partial_rotary_factor: float = 1.0
+    # Gains stored as ``w`` and applied as ``1 + w``, initialised 0: the
+    # block's two norms, the final norm and the per-head q/k norms.
+    norm_plus_one: bool = False
+    # The shared expert behind a gate of its own: ``sigmoid(h @ w_sg) *
+    # Shared(h)``, ``w_sg [d_model, 1]`` (``moe["shared_gate"]``).
+    shared_expert_gate: bool = False
 
     def __post_init__(self):
         if (self.loop_passes > 1) != (self.exit_gate_beta is not None):
@@ -266,7 +302,7 @@ class LlamaConfig:
                       or set(kinds) - set(MIXER_KINDS)):
             raise ValueError(
                 f"LlamaConfig: layer_types={kinds} is not n_layer="
-                f"{self.n_layer} names out of {MIXER_KINDS}")
+                f"{self.n_layer} names out of {tuple(MIXER_KINDS)}")
         if self.conv_layers and (
                 self.conv_taps <= 0 or self.loop_passes > 1
                 or self.mtp_layers):
@@ -297,28 +333,87 @@ class LlamaConfig:
                     f"LlamaConfig: 'mamba' layers with num_experts="
                     f"{self.num_experts}, loop_passes={self.loop_passes} or "
                     f"mtp_layers={self.mtp_layers}: a state-space layer's "
-                    "MLP is dense (experts beside a 'conv' or an "
-                    "'attention' mixer are built, beside a 'mamba' one not "
-                    "yet) and the stack runs once, with no prediction "
+                    "MLP is dense (experts beside a 'conv', a "
+                    "'linear_attention' or an 'attention' mixer are built, "
+                    "beside a 'mamba' one not yet) and the stack runs "
+                    "once, with no prediction "
                     "block")
+        if self.gdn_layers and (
+                min(self.gdn_k_heads, self.gdn_d_head, self.gdn_d_conv) <= 0
+                or self.gdn_v_heads % max(self.gdn_k_heads, 1)
+                or self.gdn_v_heads <= 0 or self.loop_passes > 1
+                or self.mtp_layers):
+            raise ValueError(
+                f"LlamaConfig: 'linear_attention' layers with gdn_k_heads="
+                f"{self.gdn_k_heads}, gdn_v_heads={self.gdn_v_heads}, "
+                f"gdn_d_head={self.gdn_d_head}, gdn_d_conv="
+                f"{self.gdn_d_conv}, loop_passes={self.loop_passes} or "
+                f"mtp_layers={self.mtp_layers}: the value heads are a "
+                "positive multiple of the key heads, the sizes positive, "
+                "and the stack runs once, with no prediction block")
+        rotary = self.head_dim * self.partial_rotary_factor
+        if self.partial_rotary_factor != 1.0 and (
+                not 0 < rotary < self.head_dim or rotary % 2):
+            raise ValueError(
+                f"LlamaConfig: partial_rotary_factor="
+                f"{self.partial_rotary_factor} of head_dim={self.head_dim}: "
+                "the rotary dims are an even number of a head's dims")
+        plain = (self.kv_lora_rank == 0 and not self.branch_norm
+                 and not self.mtp_layers and self.loop_passes == 1)
+        for name, off in (("attn_head_dim", 0), ("attn_output_gate", False),
+                          ("partial_rotary_factor", 1.0),
+                          ("norm_plus_one", False)):
+            if getattr(self, name) != off and not plain:
+                raise ValueError(
+                    f"LlamaConfig: {name}={getattr(self, name)!r} with "
+                    f"kv_lora_rank={self.kv_lora_rank}, branch_norm="
+                    f"{self.branch_norm}, mtp_layers={self.mtp_layers} or "
+                    f"loop_passes={self.loop_passes}: it is a setting of "
+                    "the plain q, k and v projections and of a stack that "
+                    "runs once with two norms a block")
+        if self.shared_expert_gate and self.n_shared_experts <= 0:
+            raise ValueError(
+                "LlamaConfig: shared_expert_gate with n_shared_experts="
+                f"{self.n_shared_experts}: there is no shared expert to "
+                "gate")
 
     def mixer_kind(self, i: int) -> str:
         """Layer ``i``'s mixer: one of :data:`MIXER_KINDS`."""
         return self.layer_types[i] if self.layer_types else "attention"
 
+    def layers_of(self, kind: str) -> int:
+        """Layers whose mixer is of ``kind`` (of :data:`MIXER_KINDS`)."""
+        return sum(self.mixer_kind(i) == kind for i in range(self.n_layer))
+
     @property
     def ssm_layers(self) -> int:
         """Layers whose mixer is the state-space one."""
-        return sum(kind == "mamba" for kind in self.layer_types)
+        return self.layers_of("mamba")
 
     @property
     def conv_layers(self) -> int:
         """Layers whose mixer is the gated short convolution."""
-        return sum(kind == "conv" for kind in self.layer_types)
+        return self.layers_of("conv")
+
+    @property
+    def gdn_layers(self) -> int:
+        """Layers whose mixer is the gated delta rule."""
+        return self.layers_of("linear_attention")
 
     @property
     def attention_layers(self) -> int:
-        return self.n_layer - self.ssm_layers - self.conv_layers
+        return self.layers_of("attention")
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        """Channels of the delta rule's convolution: q, k and v side by
+        side."""
+        return (2 * self.gdn_k_heads + self.gdn_v_heads) * self.gdn_d_head
+
+    @property
+    def rotary_dim(self) -> int:
+        """The dims of a head that rotate (the first ones)."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def mamba_d_inner(self) -> int:
@@ -334,7 +429,7 @@ class LlamaConfig:
     def head_dim(self) -> int:
         if self.kv_lora_rank > 0:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
-        return self.d_model // self.n_head
+        return self.attn_head_dim or self.d_model // self.n_head
 
     @property
     def block_applications(self) -> int:
@@ -434,24 +529,65 @@ def _init_conv(key: jax.Array, cfg: LlamaConfig) -> Dict:
     }
 
 
+def _init_gdn(key: jax.Array, cfg: LlamaConfig) -> Dict:
+    """A Gated DeltaNet mixer's parameters: projections N(0, 0.02); the
+    convolution PyTorch's ``Conv1d`` default as :func:`_init_ssm` draws it,
+    stored ``[taps, channels]``; ``A_log = log U(0, 16)``; ``dt_bias`` and
+    the gated norm's gain 1."""
+    k = jax.random.split(key, 5)
+    hk, hv, d = cfg.gdn_k_heads, cfg.gdn_v_heads, cfg.gdn_d_head
+    bound = cfg.gdn_d_conv ** -0.5
+    return {
+        # per KEY head [q | k | v of its value heads | z of them]
+        "in_proj_qkvz": _dense(k[0], cfg.d_model, 2 * (hk + hv) * d),
+        # per key head [b of its value heads | a of them]
+        "in_proj_ba": _dense(k[1], cfg.d_model, 2 * hv),
+        "conv_w": jax.random.uniform(
+            k[2], (cfg.gdn_d_conv, cfg.gdn_conv_dim), jnp.float32,
+            -bound, bound),
+        "dt_bias": jnp.ones((hv,), jnp.float32),
+        "A_log": jnp.log(jax.random.uniform(
+            k[3], (hv,), jnp.float32, 1e-6, 16.0)),
+        "norm": jnp.ones((d,), jnp.float32),
+        "out_proj": _dense(k[4], hv * d, cfg.d_model),
+    }
+
+
+def _gain(w, cfg: "LlamaConfig"):
+    """A norm's gain as applied: the leaf, or ``1 + w`` where
+    ``cfg.norm_plus_one``."""
+    return w + 1.0 if cfg.norm_plus_one else w
+
+
+def _gain_leaf(width: int, cfg: "LlamaConfig"):
+    """A gain of 1 as its leaf is stored: ones, or zeros where
+    ``cfg.norm_plus_one``."""
+    return (jnp.zeros if cfg.norm_plus_one else jnp.ones)(
+        (width,), jnp.float32)
+
+
 def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool,
                 mixer: str = "attention") -> Dict:
     """One block's parameters: the mixer's (``mixer``, one of
     :data:`MIXER_KINDS`) and the MLP's (``routed`` or dense), chosen apart.
     The leaves every earlier configuration has draw from the same eight
     keys as ever; what latent attention, the shared expert, a state-space
-    mixer (``layer["ssm"]``) and a convolution mixer (``layer["conv"]``,
-    either in place of the attention leaves) add draws from keys folded out
-    of the layer's."""
+    mixer (``layer["ssm"]``) and a convolution mixer (``layer["conv"]``),
+    a delta-rule mixer (``layer["gdn"]``, each of the three in place of the
+    attention leaves) and the shared expert's gate add draws from keys
+    folded out of the layer's.  A gain is 1, or 0 where
+    ``cfg.norm_plus_one``."""
     k = jax.random.split(key, 8)
     more = jax.random.split(jax.random.fold_in(key, 1), 5)
     hd = cfg.head_dim
     attention = mixer == "attention"
-    layer = {"ln1": jnp.ones((cfg.d_model,), jnp.float32)}
+    layer = {"ln1": _gain_leaf(cfg.d_model, cfg)}
     if mixer == "mamba":
         layer["ssm"] = _init_ssm(jax.random.fold_in(key, 2), cfg)
     elif mixer == "conv":
         layer["conv"] = _init_conv(jax.random.fold_in(key, 3), cfg)
+    elif mixer == "linear_attention":
+        layer["gdn"] = _init_gdn(jax.random.fold_in(key, 4), cfg)
     elif cfg.kv_lora_rank > 0:
         layer["wq_a"] = _dense(k[0], cfg.d_model, cfg.q_lora_rank)
         layer["q_a_norm"] = jnp.ones((cfg.q_lora_rank,), jnp.float32)
@@ -463,18 +599,21 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool,
             more[1], cfg.kv_lora_rank,
             cfg.n_head * (cfg.qk_nope_head_dim + cfg.v_head_dim))
     else:
-        layer["wq"] = _dense(k[0], cfg.d_model, cfg.n_head * hd)
+        # with the output gate each head's columns are [q | gate]
+        layer["wq"] = _dense(
+            k[0], cfg.d_model,
+            cfg.n_head * hd * (2 if cfg.attn_output_gate else 1))
         layer["wk"] = _dense(k[1], cfg.d_model, cfg.n_kv_head * hd)
         layer["wv"] = _dense(k[2], cfg.d_model, cfg.n_kv_head * hd)
     if attention:
         layer["wo"] = _dense(k[3], cfg.n_head * hd, cfg.d_model)
-    layer["ln2"] = jnp.ones((cfg.d_model,), jnp.float32)
+    layer["ln2"] = _gain_leaf(cfg.d_model, cfg)
     if cfg.qk_norm and attention:
         per_head = cfg.qk_norm_per_head
-        layer["q_norm"] = jnp.ones(
-            (hd if per_head else cfg.n_head * hd,), jnp.float32)
-        layer["k_norm"] = jnp.ones(
-            (hd if per_head else cfg.n_kv_head * hd,), jnp.float32)
+        layer["q_norm"] = _gain_leaf(
+            hd if per_head else cfg.n_head * hd, cfg)
+        layer["k_norm"] = _gain_leaf(
+            hd if per_head else cfg.n_kv_head * hd, cfg)
     if cfg.branch_norm:
         layer["ln1_out"] = jnp.ones((cfg.d_model,), jnp.float32)
         layer["ln2_out"] = jnp.ones((cfg.d_model,), jnp.float32)
@@ -499,6 +638,9 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool,
                 "w_up": _dense(more[3], cfg.d_model, shared),
                 "w_down": _dense(more[4], shared, cfg.d_model),
             }
+        if cfg.shared_expert_gate:
+            layer["moe"]["shared_gate"] = _dense(
+                jax.random.fold_in(key, 5), cfg.d_model, 1)
     else:
         layer["mlp"] = {
             "w_gate": _dense(k[4], cfg.d_model, cfg.d_ff),
@@ -513,7 +655,7 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
     params: Dict = {
         "embed": _dense(keys[0], cfg.vocab_size, cfg.d_model),
         "lm_head": _dense(keys[1], cfg.d_model, cfg.vocab_size),
-        "ln_f": jnp.ones((cfg.d_model,), jnp.float32),
+        "ln_f": _gain_leaf(cfg.d_model, cfg),
         "layers": [
             _init_layer(keys[2 + i], cfg, cfg.is_moe_layer(i),
                         mixer=cfg.mixer_kind(i))
@@ -565,6 +707,13 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
                 "norm": (None,), "out_proj": (None, "embed")}
             if cfg.mamba_conv_bias:
                 ax["ssm"]["conv_b"] = (None,)
+        elif mixer == "linear_attention":
+            # as the state-space mixer: no ``tp`` rule yet
+            del ax["wo"]
+            ax["gdn"] = {
+                "in_proj_qkvz": ("embed", None), "in_proj_ba": ("embed", None),
+                "conv_w": (None, None), "dt_bias": (None,), "A_log": (None,),
+                "norm": (None,), "out_proj": (None, "embed")}
         elif cfg.kv_lora_rank > 0:
             ax.update(wq_a=("embed", None), q_a_norm=(None,),
                       wq_b=(None, "heads"), wkv_a=("embed", None),
@@ -593,6 +742,8 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
                     "w_up": ("embed", "mlp"),
                     "w_down": ("mlp", "embed"),
                 }
+            if cfg.shared_expert_gate:
+                ax["moe"]["shared_gate"] = ("embed", None)
         else:
             ax["mlp"] = {
                 "w_gate": ("embed", "mlp"),
@@ -639,6 +790,18 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     ).astype(x.dtype)
 
 
+def _rope_part(x: jax.Array, positions: jax.Array, cfg: "LlamaConfig"):
+    """:func:`_rope` over the first ``cfg.rotary_dim`` dims of each head
+    (all of them at ``partial_rotary_factor`` 1), pairs ``(j, j +
+    rotary_dim / 2)``; the other dims pass untouched."""
+    rot = cfg.rotary_dim
+    if rot == x.shape[-1]:
+        return _rope(x, positions, cfg.rope_theta)
+    return jnp.concatenate(
+        [_rope(x[..., :rot], positions, cfg.rope_theta), x[..., rot:]],
+        axis=-1)
+
+
 def qk_normed(q, k, layer, cfg: "LlamaConfig"):
     """q [..., H*D], k [..., KV*D] as projected -> the same, RMS-normalised
     over the whole projection width where ``cfg.qk_norm`` (the kernel
@@ -648,11 +811,11 @@ def qk_normed(q, k, layer, cfg: "LlamaConfig"):
     and the KV-cache decoder both call it."""
     if not cfg.qk_norm:
         return q, k
+    q_gain, k_gain = _gain(layer["q_norm"], cfg), _gain(layer["k_norm"], cfg)
     if cfg.qk_norm_per_head:
-        return (_rms_per_head(q, layer["q_norm"], cfg),
-                _rms_per_head(k, layer["k_norm"], cfg))
-    return (rmsnorm(q, layer["q_norm"], eps=cfg.rms_eps),
-            rmsnorm(k, layer["k_norm"], eps=cfg.rms_eps))
+        return (_rms_per_head(q, q_gain, cfg), _rms_per_head(k, k_gain, cfg))
+    return (rmsnorm(q, q_gain, eps=cfg.rms_eps),
+            rmsnorm(k, k_gain, eps=cfg.rms_eps))
 
 
 def _rms_per_head(x, gain, cfg: "LlamaConfig"):
@@ -711,18 +874,23 @@ def _attention(
     H, KV, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     dt = cfg.dtype
     latent = cfg.kv_lora_rank > 0
+    gate = None
     if latent:
         q, k, v = _mla_qkv(x, layer, cfg, positions)
     else:
         q = x @ layer["wq"].astype(dt)
         k = x @ layer["wk"].astype(dt)
         v = x @ layer["wv"].astype(dt)
+    if cfg.attn_output_gate:
+        # each head's columns are [q | gate]
+        q = q.reshape(B, S, H, 2 * D)
+        q, gate = q[..., :D].reshape(B, S, H * D), q[..., D:]
     if not latent:
         q, k = qk_normed(q, k, layer, cfg)
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
         if cfg.rope:
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q = _rope_part(q, positions, cfg)
+            k = _rope_part(k, positions, cfg)
         v = v.reshape(B, S, KV, D)
     if cfg.attention_multiplier is not None:
         # every backend scales the scores by 1 / sqrt(D): the rest of the
@@ -769,6 +937,9 @@ def _attention(
             window=cfg.sliding_window,
         )
         out = o.transpose(0, 2, 1, 3)
+    if gate is not None:
+        out = (out.astype(jnp.float32)
+               * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
     out = out.reshape(B, S, H * D)
     with (jax.named_scope("mla_out") if latent
           else contextlib.nullcontext()):
@@ -836,6 +1007,79 @@ def _conv_mixer(u, conv, cfg: LlamaConfig):
             gate_b.astype(f32) * x.astype(f32), conv["conv_w"])
     with jax.named_scope("conv_out"):
         return y.astype(dt) @ conv["out_proj"].astype(dt)
+
+
+def _gdn_mixer(u, gdn, cfg: LlamaConfig) -> tuple:
+    """The Gated DeltaNet mixer on the normed stream ``u [B, S, C]`` ->
+    ``(out [B, S, C], stats)``.  ``in_proj_qkvz``'s output viewed per KEY
+    head ``[q | k | v | z]`` (``D``, ``D``, ``R D``, ``R D`` columns with
+    ``R`` value heads a key head), ``in_proj_ba``'s ``[b | a]`` (``R``
+    each); ``[q | k | v]`` flattened, through a causal depthwise convolution
+    without bias and ``silu``; ``beta = sigmoid(b)``, ``g = -exp(A_log) *
+    softplus(a + dt_bias)`` in float32 per value head; q and k
+    L2-normalised over a head's ``D`` dims in float32 (``x / sqrt(sum x^2 +
+    1e-6)``), q scaled by ``D^-1/2``, each key head repeated under its
+    ``R`` value heads; the gated delta rule
+    (``ops.gated_delta.gated_delta_chunked`` at :data:`GDN_CHUNK`); ``y =
+    norm * rms(o) * silu(z)`` per head in float32 — the norm BEFORE the
+    gate, a plain gain; ``out = y out_proj``.  Scopes ``gdn_in``,
+    ``gdn_conv``, ``gdn_scan``, ``gdn_gate`` and ``gdn_out`` sit inside the
+    block's ``gdn``.  ``stats``: ``gdn_state_rms`` (of the state the
+    sequence leaves) and ``gdn_decay_min`` (the least ``exp(sum g)`` over a
+    chunk: 0 says a chunk's decay underflowed float32, which the rule
+    allows)."""
+    B, S, _ = u.shape
+    hk, hv, D = cfg.gdn_k_heads, cfg.gdn_v_heads, cfg.gdn_d_head
+    R, dt, f32 = hv // hk, cfg.dtype, jnp.float32
+
+    # Between the projections the mixer keeps nothing for the backward pass
+    # but its inputs: the convolution's float32 output, the rule's [Q, Q]
+    # arrays and the chunks' states are recomputed when its own backward
+    # runs, so that they are not held while the routed block's runs (2 GB
+    # of a chip's 16 at two sequences of 8,192).
+    @jax.checkpoint
+    def core(qkvz, ba, conv_w, a_log, dt_bias, gain):
+        z = qkvz[..., (2 + R) * D:].reshape(B, S, hv, D)
+        with jax.named_scope("gdn_conv"):
+            flat = lambda a: a.reshape(B, S, -1)  # noqa: E731
+            qkv = jnp.concatenate(
+                [flat(qkvz[..., :D]), flat(qkvz[..., D:2 * D]),
+                 flat(qkvz[..., 2 * D:(2 + R) * D])], axis=-1)
+            qkv = jax.nn.silu(causal_conv1d(qkv, conv_w)).astype(dt)
+        with jax.named_scope("gdn_scan"):
+            beta = jax.nn.sigmoid(ba[..., :R].astype(f32)).reshape(B, S, hv)
+            g = -jnp.exp(a_log) * jax.nn.softplus(
+                ba[..., R:].astype(f32).reshape(B, S, hv) + dt_bias)
+
+            def unit(a, scale):
+                a = a.reshape(B, S, hk, D).astype(f32)
+                a = a * (jax.lax.rsqrt(
+                    jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
+                    * scale)
+                return jnp.repeat(a.astype(dt), R, axis=2)
+
+            o, state, decay_min = gated_delta_chunked(
+                unit(qkv[..., :hk * D], D ** -0.5),
+                unit(qkv[..., hk * D:2 * hk * D], 1.0),
+                qkv[..., 2 * hk * D:].reshape(B, S, hv, D), g, beta,
+                GDN_CHUNK)
+            stats = jax.lax.stop_gradient({
+                "gdn_state_rms": jnp.sqrt(jnp.mean(jnp.square(state))),
+                "gdn_decay_min": decay_min})
+        with jax.named_scope("gdn_gate"):
+            y = o * jax.lax.rsqrt(
+                jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.rms_eps)
+            y = (gain * y) * jax.nn.silu(z.astype(f32))
+        return y.astype(dt).reshape(B, S, hv * D), stats
+
+    with jax.named_scope("gdn_in"):
+        qkvz = (u @ gdn["in_proj_qkvz"].astype(dt)).reshape(
+            B, S, hk, (2 + 2 * R) * D)
+        ba = (u @ gdn["in_proj_ba"].astype(dt)).reshape(B, S, hk, 2 * R)
+    y, stats = core(qkvz, ba, gdn["conv_w"], gdn["A_log"], gdn["dt_bias"],
+                    gdn["norm"])
+    with jax.named_scope("gdn_out"):
+        return y @ gdn["out_proj"].astype(dt), stats
 
 
 def _swiglu(x, mlp, dt):
@@ -1218,7 +1462,12 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
             (order, inverse, group_sizes, held_pairs))
     if "shared" in moe:
         with jax.named_scope("moe_shared"):
-            out = out + _swiglu(tokens.astype(dt), moe["shared"], dt)
+            shared = _swiglu(tokens.astype(dt), moe["shared"], dt)
+            if "shared_gate" in moe:
+                shared = (shared.astype(f32) * jax.nn.sigmoid(
+                    (tokens @ moe["shared_gate"].astype(dt)).astype(f32))
+                ).astype(dt)
+            out = out + shared
     with jax.named_scope("moe_router"):
         # the two loss terms, over real tokens only
         w = jnp.ones((N,), f32) if valid_n is None else valid_n.astype(f32)
@@ -1273,12 +1522,14 @@ def block_apply(
     moe_capacity: Optional[int] = None,
 ) -> tuple:
     """One transformer block: (x, layer) -> (x, stats).  The mixer is the
-    one the layer dict holds — a state-space one (``"ssm"``, scope ``ssm``),
-    a gated short convolution (``"conv"``, scope ``conv``) or attention (the
-    attention leaves, scope ``attention``) — and the MLP the one it holds,
-    routed (``"moe"``) or dense (``"mlp"``), each chosen apart from the
-    other.  ``stats`` is what the mixer reports (:func:`_ssm_mixer`:
-    ``ssm_state_rms``, ``ssm_decay_min``; the other two nothing) with what
+    one the layer dict holds (:data:`MIXER_KINDS`) — a state-space one
+    (``"ssm"``, scope ``ssm``), a gated short convolution (``"conv"``, scope
+    ``conv``), a gated delta rule (``"gdn"``, scope ``gdn``) or attention
+    (the attention leaves, scope ``attention``) — and the MLP the one it
+    holds, routed (``"moe"``) or dense (``"mlp"``), each chosen apart from
+    the other.  ``stats`` is what the mixer reports (:func:`_ssm_mixer`:
+    ``ssm_state_rms``, ``ssm_decay_min``; :func:`_gdn_mixer`:
+    ``gdn_state_rms``, ``gdn_decay_min``; the other two nothing) with what
     a routed MLP's :func:`_moe_swiglu` reports (``moe_aux``, ``moe_z``,
     ``experts``, ``tokens_per_expert``); empty for a dense attention layer.
     The unit the pipeline stage partitioner groups (``models.llama_pp``).
@@ -1297,20 +1548,23 @@ def block_apply(
         return x + branch
 
     stats = {}
-    kind = next((k for k in ("ssm", "conv") if k in layer), "attention")
+    named, kind = next(
+        (row for row in MIXER_KINDS.items() if row[1] in layer),
+        ("attention", "attention"))
     if kind != "attention" and (
             segment_ids is not None or attn_fn is not None):
-        named = {"ssm": "mamba", "conv": "conv"}[kind]
         raise NotImplementedError(
             f"block_apply: a {named!r} layer with segment_ids or a custom "
             "attn_fn: the scan and the convolution know no document "
             "boundary and no cache")
-    # outermost ``ssm`` / ``conv`` as ``attention`` is for the other kind;
-    # the mixer's own scopes nest inside it (``subscopes``)
+    # outermost ``ssm`` / ``conv`` / ``gdn`` as ``attention`` is for the
+    # other kind; the mixer's own scopes nest inside it (``subscopes``)
     with jax.named_scope(kind):
-        h = rmsnorm(x, layer["ln1"], eps=cfg.rms_eps)
+        h = rmsnorm(x, _gain(layer["ln1"], cfg), eps=cfg.rms_eps)
         if kind == "ssm":
             mixed, stats = _ssm_mixer(h, layer["ssm"], cfg)
+        elif kind == "gdn":
+            mixed, stats = _gdn_mixer(h, layer["gdn"], cfg)
         elif kind == "conv":
             mixed = _conv_mixer(h, layer["conv"], cfg)
         elif attn_fn is not None:
@@ -1323,7 +1577,7 @@ def block_apply(
         x = add(x, mixed)
     if "moe" in layer:
         with jax.named_scope("moe_router"):
-            h = rmsnorm(x, layer["ln2"], eps=cfg.rms_eps)
+            h = rmsnorm(x, _gain(layer["ln2"], cfg), eps=cfg.rms_eps)
         delta, routed = _moe_swiglu(
             h, layer["moe"], cfg, capacity=moe_capacity,
             valid=None if segment_ids is None else segment_ids >= 0,
@@ -1335,7 +1589,7 @@ def block_apply(
             x = add(x, delta)
         return x, stats
     with jax.named_scope("mlp"):
-        h = rmsnorm(x, layer["ln2"], eps=cfg.rms_eps)
+        h = rmsnorm(x, _gain(layer["ln2"], cfg), eps=cfg.rms_eps)
         out_m = _swiglu(h, layer["mlp"], cfg.dtype)
         if cfg.branch_norm:
             out_m = rmsnorm(out_m, layer["ln2_out"], eps=cfg.rms_eps)
@@ -1408,7 +1662,9 @@ def forward_hidden(
     A model with state-space layers (``cfg.layer_types``) adds
     ``ssm_state_rms`` (float32 ``[mamba layers]``: the RMS of the state each
     layer's scan leaves) and ``ssm_decay_min`` (the least decay over a
-    chunk, any layer and head).  The embedding's rows are scaled by
+    chunk, any layer and head), one with delta-rule layers
+    ``gdn_state_rms`` and ``gdn_decay_min`` likewise.  The embedding's rows
+    are scaled by
     ``cfg.embedding_multiplier`` here; the head's side of a tied or scaled
     head is :func:`head_operands`'."""
     B, S = tokens.shape
@@ -1424,7 +1680,9 @@ def forward_hidden(
     moe_aux = jnp.zeros((), jnp.float32)
     moe_z = jnp.zeros((), jnp.float32)
     experts, per_expert, held_pairs, buffer_rows = {}, [], [], []
-    state_rms, decay_min = [], []
+    # what the recurrent mixers report, by their scope
+    state_rms = {"ssm": [], "gdn": []}
+    decay_min = {"ssm": [], "gdn": []}
 
     def collect(block, stats):
         """A routed block's statistics into the aux dict's entries."""
@@ -1459,12 +1717,13 @@ def forward_hidden(
             x = checkpoint_name(x, "block_out")
             if "moe_aux" in stats:
                 collect(i, stats)
-            if "ssm_state_rms" in stats:
-                state_rms.append(stats["ssm_state_rms"])
-                decay_min.append(stats["ssm_decay_min"])
+            for scope in state_rms:
+                if f"{scope}_state_rms" in stats:
+                    state_rms[scope].append(stats[f"{scope}_state_rms"])
+                    decay_min[scope].append(stats[f"{scope}_decay_min"])
         z = x  # the last layer's output, what the prediction block reads
         with jax.named_scope("final_norm"):
-            x = rmsnorm(x, params["ln_f"], eps=cfg.rms_eps)
+            x = rmsnorm(x, _gain(params["ln_f"], cfg), eps=cfg.rms_eps)
         if cfg.loop_passes > 1:
             streams.append(x)
             with jax.named_scope("exit_gate"):
@@ -1499,9 +1758,11 @@ def forward_hidden(
         out_aux["moe_held_pairs"] = jnp.stack(held_pairs)
     if buffer_rows:
         out_aux["moe_buffer_rows"] = jnp.stack(buffer_rows)
-    if state_rms:
-        out_aux.update(ssm_state_rms=jnp.stack(state_rms),
-                       ssm_decay_min=jnp.min(jnp.stack(decay_min)))
+    for scope, rms in state_rms.items():
+        if rms:
+            out_aux[f"{scope}_state_rms"] = jnp.stack(rms)
+            out_aux[f"{scope}_decay_min"] = jnp.min(
+                jnp.stack(decay_min[scope]))
     return x, out_aux
 
 
@@ -1577,7 +1838,8 @@ def loss_fn(
     ``[routed layers, E]``, ``moe_aux``, ``moe_z``): ``accelerate()``'s
     step hands them out beside ``loss`` and ``grad_norm``.  A dense model
     returns the scalar alone either way; one with state-space layers
-    returns ``ssm_state_rms`` ``[mamba layers]`` and ``ssm_decay_min``.
+    returns ``ssm_state_rms`` ``[mamba layers]`` and ``ssm_decay_min``, one
+    with delta-rule layers ``gdn_state_rms`` and ``gdn_decay_min``.
     The head is ``lm_head``, or ``embed`` transposed where
     ``cfg.tie_word_embeddings``, behind ``1 / cfg.logits_scaling``
     (:func:`head_operands`).
@@ -1693,9 +1955,10 @@ def loss_fn(
         loss = loss + moe_z_weight * aux["moe_z"]
     if not metrics:
         return loss
-    if "ssm_state_rms" in aux:
-        counters.update(ssm_state_rms=aux["ssm_state_rms"],
-                        ssm_decay_min=aux["ssm_decay_min"])
+    for name in ("ssm_state_rms", "ssm_decay_min", "gdn_state_rms",
+                 "gdn_decay_min"):
+        if name in aux:
+            counters[name] = aux[name]
     if "moe_z" in aux:
         counters.update(
             moe_tokens_per_expert=aux["moe_tokens_per_expert"],
@@ -1862,13 +2125,18 @@ TRAINING_PATH_ONLY = (
     ("kv_lora_rank", 0, "latent attention"),
     ("experts_held", 0, "a share of the experts"),
     ("mtp_layers", 0, "the multi-token-prediction block"),
-    ("layer_types", MIXER_KINDS[0], "a layer whose mixer is not attention"),
+    ("layer_types", "attention", "a layer whose mixer is not attention"),
     ("rope", True, "attention without rotary position"),
     ("attention_multiplier", None, "attention at a stated scale"),
     ("embedding_multiplier", 1.0, "a scalar on the embedding"),
     ("residual_multiplier", 1.0, "a scalar on each branch"),
     ("logits_scaling", 1.0, "a scalar on the logits"),
     ("tie_word_embeddings", False, "a head tied to the embedding"),
+    ("attn_head_dim", 0, "a head size that is not d_model / n_head"),
+    ("attn_output_gate", False, "a gate on the attention output"),
+    ("partial_rotary_factor", 1.0, "rotation of a part of each head"),
+    ("norm_plus_one", False, "gains stored as 1 + w"),
+    ("shared_expert_gate", False, "a gate on the shared expert"),
 )
 
 
@@ -1901,15 +2169,15 @@ def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:
     loss function carries it as its ``program_facts`` attribute): how many
     layers are of each kind, and the chunks the scan carries a state over
     in a sequence of ``seq_len``.  Empty for every other model."""
-    facts = {}
-    if cfg.ssm_layers:
-        facts.update(
-            ssm_layers=cfg.ssm_layers,
-            ssm_chunks_per_sequence=-(-seq_len // cfg.mamba_chunk_size))
-    if cfg.conv_layers:
-        facts["conv_layers"] = cfg.conv_layers
+    facts = {f"{scope}_layers": cfg.layers_of(kind)
+             for kind, scope in MIXER_KINDS.items()
+             if kind != "attention" and cfg.layers_of(kind)}
     if facts:
         facts["attention_layers"] = cfg.attention_layers
+    # the chunks a recurrent mixer's scan carries its state over
+    for scope, chunk in (("ssm", cfg.mamba_chunk_size), ("gdn", GDN_CHUNK)):
+        if f"{scope}_layers" in facts:
+            facts[f"{scope}_chunks_per_sequence"] = -(-seq_len // chunk)
     return facts
 
 
@@ -1925,7 +2193,11 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     state-space layer counts its two projections and its MLP, and per token
     the recurrence's update and read (``4 * H * P * N``) and the
     convolution's taps; a convolution layer its two projections, its MLP
-    and its taps."""
+    and its taps; a delta-rule layer its three projections, its MLP, its
+    taps and the chunked rule's matmuls (per value head and token, forward:
+    ``k k^T``, ``q k^T`` and the two products with ``T`` and the one with
+    ``u`` over a chunk's ``Q`` positions, ``10 Q D``, and the three against
+    the state, ``6 D^2``)."""
     if cfg.kv_lora_rank > 0:  # latent attention's five projections
         qkv = (
             cfg.d_model * cfg.q_lora_rank
@@ -1957,6 +2229,13 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     scan = 4 * inner * cfg.mamba_d_state + 2 * cfg.mamba_d_conv * conv
     p_conv = 4 * cfg.d_model * cfg.d_model + mlp  # in_proj, out_proj
     taps = 2 * cfg.conv_taps * cfg.d_model
-    return (6.0 * (dense + cfg.ssm_layers * p_ssm + cfg.conv_layers * p_conv)
+    hv, gd = cfg.gdn_v_heads, cfg.gdn_d_head
+    p_gdn = (cfg.d_model * (2 * (cfg.gdn_k_heads + hv) * gd + 2 * hv)
+             + hv * gd * cfg.d_model + mlp)
+    rule = (hv * (10 * GDN_CHUNK * gd + 6 * gd * gd)
+            + 2 * cfg.gdn_d_conv * cfg.gdn_conv_dim)
+    return (6.0 * (dense + cfg.ssm_layers * p_ssm + cfg.conv_layers * p_conv
+                   + cfg.gdn_layers * p_gdn)
             + 6.0 * attn
-            + 3.0 * (cfg.ssm_layers * scan + cfg.conv_layers * taps))
+            + 3.0 * (cfg.ssm_layers * scan + cfg.conv_layers * taps
+                     + cfg.gdn_layers * rule))

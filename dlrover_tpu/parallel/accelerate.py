@@ -434,9 +434,11 @@ def program_summary(hlo_text: str) -> dict:
     where the program nests scopes of its own) :func:`scope_tables`'
     second table.  ``accelerate()`` adds the loss function's
     ``program_facts`` attribute (a dict; ``models.llama.program_facts``:
-    ``ssm_layers``, ``conv_layers``, ``attention_layers``,
-    ``ssm_chunks_per_sequence`` of a model whose layers are not all
-    attention layers), where it carries one."""
+    ``ssm_layers``, ``conv_layers``, ``gdn_layers``, ``attention_layers``,
+    ``ssm_chunks_per_sequence``, ``gdn_chunks_per_sequence`` of a model
+    whose layers are not all attention layers), where it carries one.
+    Block remat names nothing of a delta-rule layer to keep: its mixer
+    recomputes from its projections' outputs (``llama._gdn_mixer``)."""
     kernels: dict = {}
     applications = 0
     for line in hlo_text.splitlines():

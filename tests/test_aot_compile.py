@@ -727,6 +727,87 @@ def test_conv_step_token_side_builds_no_pick_sized_array(conv_step):
 
 
 @pytest.fixture(scope="module")
+def gdn_step(topo):
+    """``(job, compiled text, cfg)`` of the Qwen3-Next cell's step from
+    shapes, depth cut to ONE delta-rule layer and the attention layer,
+    widths, held experts, batch and sequence length whole."""
+    from dlrover_tpu.models import llama
+
+    cfg = llama.LlamaConfig(
+        vocab_size=18992, n_layer=2, n_head=16, n_kv_head=2, d_model=2048,
+        d_ff=5120, max_seq_len=8192, rope_theta=1e7, rms_eps=1e-6,
+        remat_block=True, layer_types=("linear_attention", "attention"),
+        gdn_k_heads=16, gdn_v_heads=32, gdn_d_head=128, gdn_d_conv=4,
+        attn_head_dim=256, attn_output_gate=True, partial_rotary_factor=0.25,
+        norm_plus_one=True, qk_norm=True, qk_norm_per_head=True,
+        num_experts=512, top_k=10, moe_every=1, d_ff_expert=512,
+        n_shared_experts=1, shared_expert_gate=True, balance_all_k=True,
+        experts_held=32)
+
+    def loss(params, batch):
+        return llama.loss_fn(params, batch, cfg, moe_aux_weight=1e-3,
+                             metrics=True)
+
+    loss.program_facts = llama.program_facts(cfg, 8192)
+    return (*_step_and_text(topo, loss, cfg, 2, 8192), cfg)
+
+
+def test_gdn_step_compiles_at_published_widths(gdn_step):
+    """The flash kernels run in the ONE attention layer at 16/2 heads of
+    256 behind the per-head ``1 + w`` norm, the partial rotary pass and
+    under the output gate; a delta-rule layer's routed MLP runs the grouped
+    matmuls over its 32 of 512 experts; the compiled step's tables name the
+    mixer's nested scopes in every phase; and the cut fits the chip."""
+    job, _, cfg = gdn_step
+    kernels = job.program["kernels"]
+    assert (kernels["flash_fwd"], kernels["flash_bwd_dq"],
+            kernels["flash_bwd_dkv"]) == (1, 1, 1)
+    assert job.program["block_applications"] == cfg.block_applications == 1
+    assert (job.program["gdn_layers"], job.program["attention_layers"],
+            job.program["gdn_chunks_per_sequence"]) == (1, 1, 128)
+    # two routed blocks: nine grouped matmuls and three transposes each in
+    # the sized buffer's branch
+    assert (kernels["gmm"], kernels["tgmm"]) == (2 * 9, 2 * 3)
+    found = {tuple(v) for v in job.program["scopes"].values()}
+    assert {("forward", "gdn"), ("backward", "gdn"), ("recompute", "gdn"),
+            ("forward", "attention"), ("backward", "moe_experts"),
+            ("forward", "moe_shared"), ("forward", "lm_head_loss")} <= found
+    by_inner = {}
+    for name, inner in job.program["subscopes"].items():
+        by_inner.setdefault(inner, set()).add(job.program["scopes"][name][0])
+    for inner in ("gdn_in", "gdn_conv", "gdn_scan", "gdn_gate", "gdn_out"):
+        assert {"forward", "backward", "recompute"} <= by_inner[inner], inner
+    assert job.memory["peak_bytes"] < 16_909_336_064
+
+
+def test_gdn_step_sizes_its_sorted_buffer_and_keeps_the_rule_in_float32(
+        gdn_step):
+    """Two sequences of 8,192 tokens take 10 of 512 experts and the chip
+    holds 32: the routed blocks choose between 12,800 rows and all 163,840.
+    The rule's ``[Q, Q]`` arrays are float32 whole matrices of 64 x 64 (no
+    block smaller than the chunk reaches HBM: a TPU pads the last two dims
+    of an array to its tiles), and the inverse's products ask for the
+    float32 passes by name."""
+    from dlrover_tpu.models import llama
+
+    _, text, _ = gdn_step
+    assert llama._moe_buffer_bounds(2 * 8192, 10, 512, 32) == (12800, 163840)
+    seen, small = 0, 0
+    for line in text.splitlines():
+        if "gdn_scan" not in line or " = " not in line:
+            continue
+        seen += 1
+        shapes = line.split(" = ", 1)[1].split("metadata=", 1)[0]
+        for dtype, dims in re.findall(r"(\w+)\[([0-9,]+)\]", shapes):
+            dims = [int(d) for d in dims.split(",")]
+            if dims[-2:] == [64, 64] and len(dims) >= 4:
+                assert dtype in ("f32", "bf16", "pred"), line[:200]
+            small += len(dims) >= 5 and dims[-1] in (2, 4, 8) and (
+                dims[-2] == dims[-1])
+    assert seen > 100 and small == 0
+
+
+@pytest.fixture(scope="module")
 def olmoe_step(topo):
     """``(job, compiled text, cfg)`` of the OLMoE cell's step from shapes:
     its one layer at published widths, eight sequences of 4,096, every
