@@ -26,23 +26,59 @@ m_t)`` solves a unit lower-triangular system (the WY form)::
     o  = (q exp(gamma)) S + tril(q k^T exp(gamma_i - gamma_j)) u
     S <- exp(gamma_Q) S + (k exp(gamma_Q - gamma))^T u
 
-and a ``lax.scan`` over the chunks carries the state.  Where float32 stays:
-``g``, ``gamma``, every ``exp``, ``A``, ``T`` (its products at ``highest``
-matmul precision: a TPU's default rounds float32 operands to bfloat16) and
-the state ``S``.  Every exponent is a difference ``gamma_i - gamma_j`` with
-``i >= j`` (or ``gamma`` itself), so never positive: a head may decay by
-``e^-21`` a token and ``e^-1340`` a chunk, ``exp(-gamma)`` alone would
-overflow, and the difference is MASKED before the ``exp`` so that no ``inf *
-0`` forms in the backward pass.  The matmuls against the state (``W S``, ``(q
-exp(gamma)) S``, ``k^T u``) and the masked ``q k^T`` product against ``u``
-take operands rounded to ``v``'s dtype and accumulate in float32.  The
-backward pass is JAX's through this form.
+and the state passes from chunk to chunk.  Where float32 stays: ``g``,
+``gamma``, every ``exp``, ``A``, ``T``, ``W`` and ``U`` (their products at
+``highest`` matmul precision: a TPU's default rounds float32 operands to
+bfloat16) and the state ``S``.  Every exponent is a difference ``gamma_i -
+gamma_j`` with ``i >= j`` (or ``gamma`` itself), so never positive: a head
+may decay by ``e^-21`` a token and ``e^-1340`` a chunk, ``exp(-gamma)`` alone
+would overflow, and the difference is MASKED before the ``exp`` so that no
+``inf * 0`` forms in the backward pass.  The matmuls against the state (``W
+S``, ``(q exp(gamma)) S``, ``k^T u``) and the masked ``q k^T`` product against
+``u`` take operands rounded to ``v``'s dtype and accumulate in float32.
+
+Two forms of the same arithmetic.  In plain ``jax.numpy``
+(:func:`_chunked_xla`): the ``[Q, Q]`` arrays of every chunk at once, a
+``lax.scan`` over the chunks, JAX's backward through both.  It is what the
+CPU runs, the kernels' reference and the fall-back for a shape they do not
+tile.  On a TPU a Pallas kernel pair under one ``jax.custom_vjp``
+(``gdn_chunk_fwd``, ``gdn_chunk_bwd``), grid ``(batch row, block of heads,
+chunk)`` with the chunk axis last and ``"arbitrary"``:
+
+- **forward**: a grid step reads the chunk's ``q``, ``k``, ``v`` blocks of
+  ``[B, S, H D]`` and a ``[8, 128]`` block each of ``gamma`` and ``beta``
+  (one row a tile of heads), forms every array above in VMEM, and writes
+  ``o`` and — only when called under differentiation — the state that
+  entered the chunk, rounded to ``v``'s dtype as every product takes it.
+  The block's float32 state ``[hb Dk, Dv]`` is the kernel's second output,
+  whose block index does not move along the chunk axis: it stays in VMEM
+  from a sequence's first chunk to its last, and leaves as the final state.
+- **backward**: one kernel walks the chunks last to first with the state's
+  cotangent in a VMEM scratch, recomputes the chunk's arrays from the same
+  inputs and the saved entering state (the only residual besides the
+  inputs), and writes ``dq``, ``dk``, ``dv``, ``d gamma``, ``d beta``.
+  Neither the scan body's ``jax.checkpoint`` nor :func:`unit_lower_inverse`'s
+  ``custom_vjp`` is on this path.
+
+No array with a ``[Q, Q]`` dimension and no float32 state is written to or
+read from HBM in either.  ``gamma`` (a cumulative sum), its layout and the
+least decay are ``jax.numpy`` around the kernels.  The rule
+(:func:`_kernel_heads`): ``jax.default_backend() == "tpu"``, ``chunk`` 64 or
+128, ``Dk`` and ``Dv`` multiples of 128 lanes, and ``H`` a multiple of the
+``128 // chunk`` heads that share a tile.
 """
 
 from __future__ import annotations
 
+import functools
+from types import SimpleNamespace
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+
+from dlrover_tpu.ops.flash_attention import NEG_INF as _NEG, _vmem_params
+from dlrover_tpu.ops.per_shard import P as Spec, per_shard, shard_axes
 
 F32 = jnp.float32
 
@@ -92,19 +128,12 @@ def _unit_lower_inverse_bwd(t, g):
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64):
-    """The chunked form -> ``(o [B, S, H, Dv] float32, final state [B, H,
-    Dk, Dv] float32, the least decay over a chunk, a float32 scalar)``.  A
-    sequence that ``chunk`` does not divide is padded with positions of ``g
-    = 0`` and ``beta = 0``, which neither decay nor write."""
+def _chunked_xla(q, k, v, g, beta, qn: int):
+    """The chunked form in plain ``jax.numpy`` on a sequence that ``qn``
+    divides -> ``(o, final state, least decay over a chunk)``."""
     bsz, s, h, dk = k.shape
-    dv, dt, qn = v.shape[-1], v.dtype, chunk
-    pad = -s % qn
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-            for x in (q, k, v, g, beta))
-    c = (s + pad) // qn
+    dv, dt = v.shape[-1], v.dtype
+    c = s // qn
     # [B, H, c, Q, ...]: a head's chunks side by side
     rows = lambda x: jnp.moveaxis(  # noqa: E731
         x.reshape((bsz, c, qn) + x.shape[2:]), 3, 1)
@@ -153,8 +182,499 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64):
         tuple(chunks_first(x) for x in (
             w.astype(dt), u, qk, q_grown, k_to_end, chunk_decay)))
     # [c, B, H, Q, Dv] -> [B, S, H, Dv]
-    out = jnp.moveaxis(out, (0, 3), (1, 2)).reshape(bsz, s + pad, h, dv)
-    return out[:, :s], final, jnp.min(chunk_decay)
+    out = jnp.moveaxis(out, (0, 3), (1, 2)).reshape(bsz, s, h, dv)
+    return out, final, jnp.min(chunk_decay)
+
+
+# -- the same as a Pallas kernel pair -----------------------------------------
+#
+# Layouts, per grid step (b, block j of hb heads, chunk i).  In HBM ``q``,
+# ``k``, ``v``, ``o`` and their cotangents are ``[B, S, H D]`` as the mixer
+# holds them: a head's chunk is a ``[Q, D]`` block, no copy in front of the
+# kernels.  A chunk shorter than the 128 rows of an MXU pass shares a TILE
+# with its neighbours: ``per = 128 // Q`` heads stacked along the rows,
+# ``[per Q, D]``, so that every ``[Q, Q]`` array of the rule is one ``[128,
+# 128]`` array whose diagonal blocks are the heads' (the others masked) and
+# the inverse by doubling runs on the tile up to blocks of ``Q``.  What is one
+# number a head and position (``gamma``, ``beta``) comes as ``[8, per Q]``
+# rows, one a tile of the block (the rest zeros: a float32 tile's sublanes),
+# lane-dense in HBM; a row broadcasts down the sublanes as it is, and one
+# whole-tile transpose a grid step (:func:`_columns`) gives the columns that
+# broadcast along the lanes.  The backward's sums over a head's lanes come
+# out as columns and are turned back the same way.  The state of the block's
+# heads ``[hb Dk, Dv]`` float32 is the forward's second output, whose block
+# does not move along the chunk axis (the last, ``"arbitrary"``), so it stays
+# in VMEM from a sequence's first chunk to its last; the backward holds the
+# state's cotangent in a scratch the same way and walks the chunks last to
+# first.
+
+#: lanes of ``q`` (heads x Dk) one grid step of the kernels takes: 8 heads
+#: of 128, four tiles run in lockstep (:func:`_in_lockstep`).  A shape
+#: decision, not a knob.
+_BLOCK_LANES = 1024
+_HIGHEST = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _kernel_heads(chunk: int, h: int, dk: int, dv: int) -> int:
+    """Heads one grid step takes, 0 where the kernels do not tile the
+    shapes: ``chunk`` 64 or 128, ``Dk`` and ``Dv`` multiples of 128 lanes,
+    and the largest divisor of ``H`` that fills whole tiles within
+    :data:`_BLOCK_LANES` (one tile where that is wider)."""
+    if chunk not in (64, 128) or dk % 128 or dv % 128:
+        return 0
+    per = 128 // chunk
+    fits = [r for r in range(per, min(h, per * _ROWS) + 1, per) if h % r == 0
+            and r * max(dk, dv) <= max(_BLOCK_LANES, per * max(dk, dv))]
+    return max(fits, default=0)
+
+
+def _nt(a, b, precision=None):
+    """``a b^T``, float32."""
+    return jax.lax.dot_general(a, b, _NT, precision=precision,
+                               preferred_element_type=F32)
+
+
+def _tn(a, b, precision=None):
+    """``a^T b``, float32."""
+    return jax.lax.dot_general(a, b, _TN, precision=precision,
+                               preferred_element_type=F32)
+
+
+def _dot(a, b, precision=None):
+    return jnp.dot(a, b, precision=precision, preferred_element_type=F32)
+
+
+def _in_lockstep(tiles):
+    """Run the tiles' generators a stage at a time -> their return values.
+    The kernels' bodies are straight-line code and Mosaic schedules it much
+    as written: a tile's chunk is a chain of products each waiting for the
+    last (the inverse alone is ten), and a ``yield`` between two stages puts
+    the other tiles' same stage between them, so that the MXU takes one
+    tile's product while another's drains."""
+    tiles, results = list(tiles), {}
+    while len(results) < len(tiles):
+        for i, tile in enumerate(tiles):
+            if i not in results:
+                try:
+                    next(tile)
+                except StopIteration as stop:
+                    results[i] = stop.value
+    return [results[i] for i in range(len(tiles))]
+
+
+def _tile_inverse(a, qn: int):
+    """``(I + a)^-1`` of a float32 tile whose ``[qn, qn]`` diagonal blocks
+    are strictly lower-triangular and whose other blocks are zero:
+    :func:`unit_lower_inverse`'s doubling on the whole tile, stopped at
+    blocks of ``qn``.  The first level's inverses are identities, so it
+    costs no product; from blocks of 8 rows on (a float32 tile's sublanes)
+    only the rows under the pairs' diagonals, half the tile's, go through
+    the two products: the others of ``inv L inv`` are zero.  A generator
+    (:func:`_in_lockstep`) that returns the inverse."""
+    n = a.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    inv = jnp.where(row == col, 1.0, 0.0).astype(F32)
+    b = 1
+    while b < qn:
+        shift = (2 * b).bit_length() - 1
+        under = ((jax.lax.shift_right_logical(row, shift)
+                  == jax.lax.shift_right_logical(col, shift))
+                 & ((row & b) != 0) & ((col & b) == 0))
+        step = jnp.where(under, a, 0.0)
+        if b == 1:
+            inv = inv - step
+        elif b < 8:
+            step = _dot(inv, step, _HIGHEST)
+            yield
+            inv = inv - _dot(step, inv, _HIGHEST)
+            yield
+        else:
+            starts = range(b, n, 2 * b)
+            step = _dot(jnp.concatenate([inv[r:r + b] for r in starts]),
+                        step, _HIGHEST)
+            yield
+            step = _dot(step, inv, _HIGHEST)
+            yield
+            inv = jnp.concatenate([x for i, r in enumerate(starts) for x in (
+                inv[r - b:r], inv[r:r + b] - step[i * b:(i + 1) * b])])
+        b *= 2
+    return inv
+
+
+def _rows_of(values, qn: int, n: int):
+    """``[n, 1]`` from one ``[1, 1]`` value a head: each over its head's
+    ``qn`` rows of the tile."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+    out = jnp.broadcast_to(values[0], (n, 1))
+    for p in range(1, len(values)):
+        out = jnp.where(row >= p * qn, values[p], out)
+    return out
+
+
+def _stack(ref, heads, d: int):
+    """The heads' ``[Q, d]`` blocks of ``ref [Q, hb d]`` -> ``[per Q, d]``."""
+    return jnp.concatenate([ref[:, h * d:(h + 1) * d] for h in heads], axis=0)
+
+
+def _per_head(fn, per: int, qn: int):
+    """``fn(p, rows of head p)`` of every head of a tile, stacked."""
+    return jnp.concatenate(
+        [fn(p, slice(p * qn, (p + 1) * qn)) for p in range(per)], axis=0)
+
+
+def _columns(rows):
+    """``[8, 128]`` rows, one a tile -> ``[128, 128]`` whose column ``t``
+    is row ``t``: a whole-tile transpose, the one Mosaic has for float32."""
+    return jnp.concatenate(
+        [rows, jnp.zeros((128 - rows.shape[0], 128), F32)], axis=0).T
+
+
+def _chunk_terms(q, k, v, gc, gr, bc, lows, qn: int):
+    """What a tile's chunk is made of, forward and backward alike: ``q``,
+    ``k [n, Dk]``, ``v [n, Dv]`` (``n = per qn`` rows), ``gc``, ``bc [n,
+    1]`` and ``gr [1, n]`` float32, ``lows`` the heads' entering states
+    rounded to ``v``'s dtype.  A generator (:func:`_in_lockstep`) that
+    returns the terms."""
+    n, dt, per = q.shape[0], v.dtype, q.shape[0] // qn
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    head = lambda x: jax.lax.shift_right_logical(  # noqa: E731
+        x, qn.bit_length() - 1)
+    same = head(row) == head(col) if per > 1 else True
+    t = SimpleNamespace(strict=same & (row > col))
+    # masked inside the exp: above the diagonal (and between heads) the
+    # difference may be positive, and exp of the filler is 0 exactly
+    t.decay = jnp.exp(jnp.where(same & (row >= col), gc - gr, _NEG))
+    t.kkd = _nt(k, k) * t.decay
+    t.a = jnp.where(t.strict, bc * t.kkd, 0.0)
+    t.qkd = _nt(q, k) * t.decay
+    t.qk = t.qkd.astype(dt)
+    yield
+    t.inv = yield from _tile_inverse(t.a, qn)
+    t.qf, t.kf, t.vf = q.astype(F32), k.astype(F32), v.astype(F32)
+    t.grown = jnp.exp(gc)  # from the chunk's start to i
+    t.kg = t.kf * t.grown
+    t.w = _dot(t.inv, t.kg * bc, _HIGHEST)
+    t.u = _dot(t.inv, t.vf * bc, _HIGHEST)
+    t.w_low = t.w.astype(dt)
+    t.qg = (t.qf * t.grown).astype(dt)
+    t.totals = [gc[(p + 1) * qn - 1:(p + 1) * qn] for p in range(per)]
+    t.to_end = jnp.exp(_rows_of(t.totals, qn, n) - gc)  # from j to the end
+    t.ke = (t.kf * t.to_end).astype(dt)
+    # [1, Dv] rows: Mosaic broadcasts one number along the lanes or down
+    # the sublanes, not both at once
+    t.chunk_decay = [jnp.exp(jnp.broadcast_to(x, (1, v.shape[1])))
+                     for x in t.totals]
+    yield
+    t.new = t.u - _per_head(lambda p, r: _dot(t.w_low[r], lows[p]), per, qn)
+    t.new_low = t.new.astype(dt)
+    yield
+    return t
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, state_ref,
+                *entering_ref, qn, dk, dv):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _new_sequence():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    per, dt = max(1, 128 // qn), v_ref.dtype
+    g_cols, b_cols = _columns(g_ref[...]), _columns(b_ref[...])
+
+    def a_tile(tile):
+        heads = range(tile * per, (tile + 1) * per)
+        states = [state_ref[h * dk:(h + 1) * dk, :] for h in heads]
+        lows = [s.astype(dt) for s in states]
+        t = yield from _chunk_terms(
+            _stack(q_ref, heads, dk), _stack(k_ref, heads, dk),
+            _stack(v_ref, heads, dv), g_cols[:, tile:tile + 1],
+            g_ref[tile:tile + 1, :], b_cols[:, tile:tile + 1], lows, qn)
+        out = _per_head(lambda p, r: _dot(t.qg[r], lows[p]), per, qn) + _dot(
+            t.qk, t.new_low)
+        for p, h in enumerate(heads):
+            r = slice(p * qn, (p + 1) * qn)
+            o_ref[:, h * dv:(h + 1) * dv] = out[r]
+            if entering_ref:
+                entering_ref[0][h * dk:(h + 1) * dk, :] = lows[p]
+            state_ref[h * dk:(h + 1) * dk, :] = (
+                t.chunk_decay[p] * states[p] + _tn(t.ke[r], t.new_low[r]))
+
+    _in_lockstep(a_tile(tile) for tile in range(q_ref.shape[1] // (per * dk)))
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entering_ref, do_ref,
+                dstate_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *,
+                qn, dk, dv):
+    """One chunk of the backward pass, from the chunk's own inputs, the
+    state that entered it and the cotangents of ``o`` and of the state it
+    left (``ds_ref``, resident).  With ``M`` the sum of ``dA o A`` and ``d(q
+    k^T o decay) o (q k^T o decay)``, ``gamma``'s cotangent through the decay
+    mask is ``M``'s row sums less its column sums.  What comes out as a
+    column a tile (the sums over a head's lanes) is turned into rows once, at
+    the end.  ``dA = -T^T (dW Kb^T + dU Vb^T) T^T`` is ``-(dKb W^T + dVb
+    U^T)`` with ``dKb = T^T dW`` and ``dVb = T^T dU``, which ``k``, ``v`` and
+    ``beta`` need anyway."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _sequence_end():
+        ds_ref[...] = dstate_ref[...]
+
+    per, dt, n = max(1, 128 // qn), v_ref.dtype, 128
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+    over_lanes = lambda x: jnp.sum(x, axis=1, keepdims=True)  # noqa: E731
+    g_cols, b_cols = _columns(g_ref[...]), _columns(b_ref[...])
+
+    def a_tile(tile):
+        """-> the tile's ``(d gamma, d beta)`` as columns, ``d gamma``'s
+        other part as a row."""
+        heads = range(tile * per, (tile + 1) * per)
+        lows = [entering_ref[h * dk:(h + 1) * dk, :] for h in heads]
+        q, k = _stack(q_ref, heads, dk), _stack(k_ref, heads, dk)
+        gc, bc = g_cols[:, tile:tile + 1], b_cols[:, tile:tile + 1]
+        t = yield from _chunk_terms(q, k, _stack(v_ref, heads, dv), gc,
+                                    g_ref[tile:tile + 1, :], bc, lows, qn)
+        do_low = _stack(do_ref, heads, dv).astype(dt)
+        left = [ds_ref[h * dk:(h + 1) * dk, :] for h in heads]
+        left_low = [x.astype(dt) for x in left]
+        # o = qg S + qk new; S' = chunk_decay S + ke^T new; new = u - w S
+        d_qg = _per_head(lambda p, r: _nt(do_low[r], lows[p]), per, qn)
+        d_qk = _nt(do_low, t.new_low)
+        d_ke = _per_head(lambda p, r: _nt(t.new_low[r], left_low[p]), per, qn)
+        d_new = _tn(t.qk, do_low) + _per_head(
+            lambda p, r: _dot(t.ke[r], left_low[p]), per, qn)
+        d_new_low = d_new.astype(dt)
+        yield
+        d_w = -_per_head(lambda p, r: _nt(d_new_low[r], lows[p]), per, qn)
+        d_total = []  # of each head's whole sum of g, through chunk_decay
+        for p, h in enumerate(heads):
+            r = slice(p * qn, (p + 1) * qn)
+            d_total.append(jnp.sum(over_lanes(
+                t.chunk_decay[p] * left[p] * lows[p].astype(F32)),
+                axis=0, keepdims=True))
+            ds_ref[h * dk:(h + 1) * dk, :] = (
+                t.chunk_decay[p] * left[p] + _tn(t.qg[r], do_low[r])
+                - _tn(t.w_low[r], d_new_low[r]))
+        yield
+        # w = T (k beta grown), u = T (v beta), T = (I + A)^-1
+        inv_t = t.inv.T
+        d_kb = _dot(inv_t, d_w, _HIGHEST)
+        d_vb = _dot(inv_t, d_new, _HIGHEST)
+        yield
+        d_a = -jnp.where(t.strict, _nt(d_kb, t.w, _HIGHEST)
+                         + _nt(d_vb, t.u, _HIGHEST), 0.0)
+        yield
+        through_mask = d_a * t.a + d_qk * t.qkd
+        d_kk = (d_a * bc * t.decay).astype(dt)
+        d_qkd = (d_qk * t.decay).astype(dt)
+        by_kg = over_lanes(d_kb * t.kg)
+        d_to_end = over_lanes(d_ke * t.kf) * t.to_end
+        d_total = [d_total[p] + jnp.sum(
+            d_to_end[p * qn:(p + 1) * qn], axis=0, keepdims=True)
+            for p in range(per)]
+        d_gc = (over_lanes(through_mask) + bc * by_kg
+                + over_lanes(d_qg * t.qf) * t.grown - d_to_end)
+        for p in range(per):
+            d_gc = d_gc + jnp.where(row == (p + 1) * qn - 1, d_total[p], 0.0)
+        d_q = _dot(d_qkd, k) + d_qg * t.grown
+        d_k = (_dot(d_kk, k) + _tn(d_kk, k) + _tn(d_qkd, q)
+               + d_kb * (bc * t.grown) + d_ke * t.to_end)
+        d_v = d_vb * bc
+        for p, h in enumerate(heads):
+            r = slice(p * qn, (p + 1) * qn)
+            dq_ref[:, h * dk:(h + 1) * dk] = d_q[r].astype(dq_ref.dtype)
+            dk_ref[:, h * dk:(h + 1) * dk] = d_k[r].astype(dk_ref.dtype)
+            dv_ref[:, h * dv:(h + 1) * dv] = d_v[r].astype(dv_ref.dtype)
+        return (d_gc, over_lanes(d_a * t.kkd) + by_kg
+                + over_lanes(d_vb * t.vf),
+                -jnp.sum(through_mask, axis=0, keepdims=True))
+
+    sums = _in_lockstep(
+        a_tile(tile) for tile in range(q_ref.shape[1] // (per * dk)))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    sublane = jax.lax.broadcasted_iota(jnp.int32, g_ref.shape, 0)
+    dg_cols, db_cols = jnp.zeros((n, n), F32), jnp.zeros((n, n), F32)
+    dg_rows = jnp.zeros(g_ref.shape, F32)
+    for tile, (d_gc, d_bc, d_gr) in enumerate(sums):
+        dg_cols = jnp.where(lane == tile, d_gc, dg_cols)
+        db_cols = jnp.where(lane == tile, d_bc, db_cols)
+        dg_rows = jnp.where(sublane == tile, d_gr, dg_rows)
+    dg_ref[...] = dg_rows + dg_cols.T[:dg_ref.shape[0]]
+    db_ref[...] = db_cols.T[:db_ref.shape[0]]
+
+
+#: rows of the per-position block (``gamma``, ``beta``): a float32 tile's
+#: sublanes, one a tile of heads, the rest zeros
+_ROWS = 8
+#: the kernels' first operands by the name of their block spec: q, k, v,
+#: then gamma and beta, which share one (as their cotangents do)
+_INPUTS = ("q", "k", "v", "g", "g")
+
+
+def _block_specs(bsz, c, blocks, qn, hb, dk, dv, backward=False):
+    """The grid ``(B, H / hb, chunks)`` and the block specs by name; the
+    backward's index maps walk the chunks last to first."""
+    from jax.experimental import pallas as pl
+
+    at = (lambda i: c - 1 - i) if backward else (lambda i: i)
+    per_chunk = lambda *shape: pl.BlockSpec(  # noqa: E731
+        (None, None, None) + shape, lambda b, j, i: (b, at(i), j, 0, 0))
+    rows = lambda d: pl.BlockSpec(  # noqa: E731
+        (None, qn, hb * d), lambda b, j, i: (b, at(i), j))
+    specs = dict(
+        q=rows(dk), k=rows(dk), v=rows(dv), g=per_chunk(_ROWS, 128),
+        entering=per_chunk(hb * dk, dv),
+        state=pl.BlockSpec((None, None, hb * dk, dv),
+                           lambda b, j, i: (b, j, 0, 0)))
+    return (bsz, blocks, c), specs
+
+
+def _call_params(resident_bytes: int) -> dict:
+    from jax.experimental.pallas import tpu as pltpu
+
+    # a block of heads' chunks run in turn: the state passes between them
+    raised = _vmem_params(resident_bytes).get("compiler_params")
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=raised and raised.vmem_limit_bytes)}
+
+
+def _chunk_fwd(q, k, v, g, b, dims, interpret, keep_entering):
+    """``q``, ``k``, ``v [B, S, H D]``; ``g`` (the cumulative sums) and ``b
+    [B, c, J, 8, 128]`` float32 -> ``(o [B, S, H Dv] float32, final state
+    [B, J, hb Dk, Dv] float32, the states that entered the chunks [B, c, J,
+    hb Dk, Dv] in ``v``'s dtype or None)``."""
+    from jax.experimental import pallas as pl
+
+    qn, hb, dk, dv = dims
+    bsz, c, blocks = g.shape[:3]
+    size, n = v.dtype.itemsize, 128
+    grid, specs = _block_specs(bsz, c, blocks, qn, hb, dk, dv)
+    out_specs = [specs["v"], specs["state"]]
+    out_shape = [jax.ShapeDtypeStruct(v.shape, F32),
+                 jax.ShapeDtypeStruct((bsz, blocks, hb * dk, dv), F32)]
+    if keep_entering:
+        out_specs.append(specs["entering"])
+        out_shape.append(jax.ShapeDtypeStruct(
+            (bsz, c, blocks, hb * dk, dv), v.dtype))
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, qn=qn, dk=dk, dv=dv),
+        grid=grid,
+        in_specs=[specs[name] for name in _INPUTS],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=interpret,
+        name="gdn_chunk_fwd",
+        # blocks twice (the pipeline's two buffers): q, k, v, o, the state
+        # and its rounding; every tile's [n, n] and [n, D] temporaries at
+        # once (the tiles run in lockstep)
+        **_call_params(2 * hb * (qn * (2 * dk + dv) * size + qn * dv * 4
+                                 + dk * dv * (4 + size))
+                       + hb * qn // n * 4 * n * (10 * n + 16 * max(dk, dv))),
+    )(q, k, v, g, b)
+    return (*out, None)[:3]
+
+
+def _chunk_bwd(q, k, v, g, b, entering, do, dstate, dims, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    qn, hb, dk, dv = dims
+    bsz, c, blocks = g.shape[:3]
+    size, n = v.dtype.itemsize, 128
+    grid, specs = _block_specs(bsz, c, blocks, qn, hb, dk, dv, backward=True)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, qn=qn, dk=dk, dv=dv),
+        grid=grid,
+        in_specs=[specs[name] for name in _INPUTS + (
+            "entering", "v", "state")],
+        out_specs=[specs[name] for name in _INPUTS],
+        out_shape=[like(a) for a in (q, k, v, g, b)],
+        scratch_shapes=[pltpu.VMEM((hb * dk, dv), F32)],
+        interpret=interpret,
+        name="gdn_chunk_bwd",
+        **_call_params(2 * hb * (2 * qn * (2 * dk + dv) * size + qn * dv * 4
+                                 + dk * dv * (4 + size)) + hb * dk * dv * 4
+                       + hb * qn // n * 4 * n * (16 * n + 32 * max(dk, dv))),
+    )(q, k, v, g, b, entering, do, dstate)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _chunked_kernels(q, k, v, g, b, dims, interpret):
+    return _chunk_fwd(q, k, v, g, b, dims, interpret, False)[:2]
+
+
+def _kernels_fwd(q, k, v, g, b, dims, interpret):
+    o, final, entering = _chunk_fwd(q, k, v, g, b, dims, interpret, True)
+    return (o, final), (q, k, v, g, b, entering)
+
+
+def _kernels_bwd(dims, interpret, res, cotangents):
+    return tuple(_chunk_bwd(*res, *cotangents, dims, interpret))
+
+
+_chunked_kernels.defvjp(_kernels_fwd, _kernels_bwd)
+
+
+def _chunked_pallas(q, k, v, g, beta, qn: int, hb: int, interpret: bool):
+    """:func:`_chunked_xla` by the kernel pair, one call per shard of the
+    mesh in scope (the batch dim split, as ``ops/ssd.py``).  The cumulative
+    sums, their layout and the least decay are ``jax.numpy``."""
+    bsz, s, h, dk = k.shape
+    dv, c, per = v.shape[-1], s // qn, max(1, 128 // qn)
+    tiles, blocks = hb // per, h // hb
+    gamma = jnp.cumsum(g.astype(F32).reshape(bsz, c, qn, h), axis=2)
+
+    def rows(x):
+        """``[B, c, Q, H] -> [B, c, J, 8, per Q]``: a tile's heads one
+        after the other along a row, a block's tiles down the rows."""
+        x = x.reshape(bsz, c, qn, blocks, tiles, per).transpose(
+            0, 1, 3, 4, 5, 2).reshape(bsz, c, blocks, tiles, per * qn)
+        return jnp.pad(x, ((0, 0),) * 3 + ((0, _ROWS - tiles), (0, 0)))
+
+    flat = lambda x: x.reshape(bsz, s, -1)  # noqa: E731
+    free, batch_axes, _ = shard_axes(bsz)
+    first = lambda nd: Spec(batch_axes, *([None] * (nd - 1)))  # noqa: E731
+    o, final = per_shard(
+        lambda *ops: _chunked_kernels(*ops, (qn, hb, dk, dv), interpret),
+        free, (first(3),) * 3 + (first(5),) * 2, (first(3), first(4)),
+    )(flat(q), flat(k), flat(v), rows(gamma),
+      rows(beta.astype(F32).reshape(bsz, c, qn, h)))
+    return (o.reshape(bsz, s, h, dv), final.reshape(bsz, h, dk, dv),
+            jnp.min(jnp.exp(gamma[:, :, -1])))
+
+
+def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
+                        backend: Optional[str] = None,
+                        interpret: bool = False):
+    """The chunked form -> ``(o [B, S, H, Dv] float32, final state [B, H,
+    Dk, Dv] float32, the least decay over a chunk, a float32 scalar)``.  A
+    sequence that ``chunk`` does not divide is padded with positions of ``g
+    = 0`` and ``beta = 0``, which neither decay nor write.  ``backend``
+    (``"pallas"`` / ``"reference"``; None: by the device) and ``interpret``
+    are for tests of the kernels on the CPU; a shape the kernels do not tile
+    (:func:`_kernel_heads`) runs the ``jax.numpy`` form on any backend."""
+    if backend is None:
+        backend = "pallas" if jax.default_backend() == "tpu" else "reference"
+    s, h, dk = k.shape[1:]
+    hb = _kernel_heads(chunk, h, dk, v.shape[-1]) if backend == "pallas" else 0
+    pad = -s % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    if hb:
+        out, final, decay_min = _chunked_pallas(q, k, v, g, beta, chunk, hb,
+                                                interpret)
+    else:
+        out, final, decay_min = _chunked_xla(q, k, v, g, beta, chunk)
+    return out[:, :s], final, decay_min
 
 
 def gated_delta_sequential(q, k, v, g, beta):

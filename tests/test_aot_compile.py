@@ -66,7 +66,8 @@ FLASH_BWD = {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
 #: the repo's pallas_call names (XLA lowers some of its own ops, such as
 #: ``lax.ragged_dot``, to Mosaic kernels too: not ours to count)
 OURS = set(FLASH_BWD) | {
-    "rmsnorm_fwd", "softmax_xent_fwd", "quantize_blockwise"}
+    "rmsnorm_fwd", "softmax_xent_fwd", "quantize_blockwise",
+    "gdn_chunk_fwd", "gdn_chunk_bwd"}
 
 
 def _sq(x):
@@ -175,6 +176,27 @@ def _grouped_matmul(backend):
                  ((64,), i32)], {})
 
 
+def _gated_delta(grad, chunk=64):
+    """The delta rule's kernel pair at the Qwen3-Next cell's shapes: two
+    sequences of 8,192, 32 heads of 128, four tiles of two heads a grid
+    step (one of a head at a chunk of 128)."""
+    from dlrover_tpu.ops.gated_delta import gated_delta_chunked
+
+    def fwd(q, k, v, g, beta):
+        return gated_delta_chunked(q, k, v, g, beta, chunk,
+                                   backend="pallas")
+
+    fn = fwd
+    if grad:
+        def fn(*ops):
+            return jax.grad(lambda *o: sum(_sq(x) for x in fwd(*o)[:2]),
+                            argnums=range(5))(*ops)
+    wide, thin = ((2, 8192, 32, 128), bf16), ((2, 8192, 32), f32)
+    return (fn, [wide, wide, wide, thin, thin],
+            {"gdn_chunk_fwd": 1, "gdn_chunk_bwd": 1} if grad
+            else {"gdn_chunk_fwd": 1})
+
+
 def _bwd_block_q_128():
     """Round 4's hand record has this tuning point stalling the device for
     900 s.  The compiler accepts it — so that was a run-time matter, and
@@ -202,6 +224,9 @@ KERNEL_CASES = {
     "grouped_matmul-grad": lambda: _grouped_matmul("pallas"),
     "grouped_matmul_reference-grad": lambda: _grouped_matmul("reference"),
     "flash_causal-bwd_block_q128": _bwd_block_q_128,
+    "gated_delta-fwd": lambda: _gated_delta(False),
+    "gated_delta-grad": lambda: _gated_delta(True),
+    "gated_delta_chunk128-grad": lambda: _gated_delta(True, 128),
 }
 
 
@@ -780,31 +805,36 @@ def test_gdn_step_compiles_at_published_widths(gdn_step):
     assert job.memory["peak_bytes"] < 16_909_336_064
 
 
-def test_gdn_step_sizes_its_sorted_buffer_and_keeps_the_rule_in_float32(
+def test_gdn_step_sizes_its_sorted_buffer_and_keeps_the_rule_in_vmem(
         gdn_step):
     """Two sequences of 8,192 tokens take 10 of 512 experts and the chip
     holds 32: the routed blocks choose between 12,800 rows and all 163,840.
-    The rule's ``[Q, Q]`` arrays are float32 whole matrices of 64 x 64 (no
-    block smaller than the chunk reaches HBM: a TPU pads the last two dims
-    of an array to its tiles), and the inverse's products ask for the
-    float32 passes by name."""
+    The rule is the kernel pair: under block remat and the mixer's own
+    checkpoint the step journals ``gdn_chunk_fwd`` three times a delta-rule
+    layer (forward, the block's recomputation, the mixer's) and
+    ``gdn_chunk_bwd`` once; no instruction under ``gdn_scan`` — a fusion's
+    inner ones included — has a result or an operand with two chunk-length
+    dimensions (the ``[Q, Q]`` arrays stay in VMEM) or is a loop (the scan
+    over the 128 chunks is the kernels' grid), and the step's peak is under
+    what the ``jax.numpy`` form's was (14.553 GB; ledger, PR 52)."""
     from dlrover_tpu.models import llama
 
-    _, text, _ = gdn_step
+    job, text, cfg = gdn_step
     assert llama._moe_buffer_bounds(2 * 8192, 10, 512, 32) == (12800, 163840)
-    seen, small = 0, 0
+    kernels, layers = job.program["kernels"], job.program["gdn_layers"]
+    assert kernels["gdn_chunk_fwd"] == 3 * layers
+    assert kernels["gdn_chunk_bwd"] == layers
+    q, seen = llama.GDN_CHUNK, 0
     for line in text.splitlines():
         if "gdn_scan" not in line or " = " not in line:
             continue
         seen += 1
+        assert " while(" not in line, line[:200]
         shapes = line.split(" = ", 1)[1].split("metadata=", 1)[0]
-        for dtype, dims in re.findall(r"(\w+)\[([0-9,]+)\]", shapes):
-            dims = [int(d) for d in dims.split(",")]
-            if dims[-2:] == [64, 64] and len(dims) >= 4:
-                assert dtype in ("f32", "bf16", "pred"), line[:200]
-            small += len(dims) >= 5 and dims[-1] in (2, 4, 8) and (
-                dims[-2] == dims[-1])
-    assert seen > 100 and small == 0
+        for dims in re.findall(r"\[([0-9,]+)\]", shapes):
+            assert [int(d) for d in dims.split(",")][-2:] != [q, q], line[:200]
+    assert seen > 100  # the scope's instructions were there to be read
+    assert job.memory["peak_bytes"] < 14_553_000_000
 
 
 @pytest.fixture(scope="module")

@@ -1,0 +1,128 @@
+"""The gated delta rule alone, on the chip: ``ops.gated_delta``'s kernel pair
+against its ``jax.numpy`` chunked form at the Qwen3-Next cell's shapes (two
+sequences of 8,192, 32 value heads of 128, chunks of 64), forward and every
+gradient.
+
+    chiprun -- python3 tools/gated_delta_bench.py [--iters 5]
+
+``q`` and ``k`` are unit vectors in bfloat16 (``q`` scaled by ``D^-1/2``),
+``v`` bfloat16, ``beta = sigmoid(N(0, 1))`` and ``g = -exp(A_log) softplus(a
++ dt_bias)`` in float32 with ``A_log = log U(0, 16)``, ``dt_bias = 1`` and
+``a ~ N(0, 1)`` — as ``models.llama._gdn_mixer`` forms them from a fresh
+``_init_gdn``: some heads decay by ``e^-16`` a token and underflow a chunk.
+``fwd`` is the call; ``grad`` the five gradients of ``sum(o * cot) +
+sum(state^2)``.  One ``GATED_DELTA`` line a phase and candidate: median
+milliseconds of ``--iters`` calls and, for the kernels, each result's
+distance from the ``jax.numpy`` form's (the norm of the difference over the
+norm).  ``--block_lanes`` times the kernels at other widths of a grid step
+than the module's.  The table is also written to
+``chiprun_out/gated_delta_bench.json``; ``--toy`` rehearses it off the chip
+(short sequences, the kernels in interpret mode).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+from unittest import mock
+
+import numpy as np
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+NAMES = ("q", "k", "v", "g", "beta")
+
+
+def _median_ms(fn, args, iters):
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(iters):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return float(np.median(times) * 1e3)
+
+
+def _distance(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--iters", type=int, default=5)
+    ap.add_argument("--seed", type=int, default=53)
+    ap.add_argument("--block_lanes", type=int, nargs="*", default=[])
+    ap.add_argument("--toy", action="store_true")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.ops import gated_delta as gd
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    bsz, s, h, d, chunk = (1, 256, 8, 128, 64) if args.toy else (
+        2, 8192, 32, 128, 64)
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), 7)
+    unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+        jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+    a_log = jnp.log(jax.random.uniform(keys[0], (h,), f32, 1e-6, 16.0))
+    operands = (
+        (unit(jax.random.normal(keys[1], (bsz, s, h, d))) * d ** -0.5
+         ).astype(bf16),
+        unit(jax.random.normal(keys[2], (bsz, s, h, d))).astype(bf16),
+        jax.random.normal(keys[3], (bsz, s, h, d)).astype(bf16),
+        -jnp.exp(a_log) * jax.nn.softplus(
+            jax.random.normal(keys[4], (bsz, s, h)) + 1.0),
+        jax.nn.sigmoid(jax.random.normal(keys[5], (bsz, s, h))))
+    cot = jax.random.normal(keys[6], (bsz, s, h, d))
+
+    def candidate(backend):
+        def fwd(*ops):
+            return gd.gated_delta_chunked(
+                *ops, chunk, backend=backend, interpret=args.toy)
+
+        def loss(*ops):
+            o, state, _ = fwd(*ops)
+            return jnp.sum(o * cot) + jnp.sum(jnp.square(state))
+        return {"fwd": jax.jit(fwd),
+                "grad": jax.jit(jax.grad(loss, argnums=range(5)))}
+
+    device = jax.devices()[0]
+    print(f"DEVICE platform={device.platform} kind={device.device_kind}",
+          flush=True)
+    table, base = [], {}
+    runs = [("jax_numpy", "reference", None), ("kernels", "pallas", None)] + [
+        (f"kernels_{lanes}_lanes", "pallas", lanes)
+        for lanes in args.block_lanes]
+    for label, backend, lanes in runs:
+        with mock.patch.object(gd, "_BLOCK_LANES",
+                               lanes or gd._BLOCK_LANES):
+            for phase, fn in candidate(backend).items():
+                ms = _median_ms(fn, operands, args.iters)
+                out = fn(*operands)
+                base.setdefault(phase, out)
+                names = ("o", "state", "decay_min") if phase == "fwd" else [
+                    "d" + n for n in NAMES]
+                line = {"phase": phase, "candidate": label,
+                        "ms": round(ms, 3),
+                        "distance": {n: _distance(x, y) for n, x, y in zip(
+                            names, out, base[phase])}}
+                table.append(line)
+                print("GATED_DELTA " + json.dumps(line), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/gated_delta_bench.json", "w") as f:
+        json.dump(table, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
