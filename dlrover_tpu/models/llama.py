@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from dlrover_tpu.ops.conv_silu import causal_conv1d_silu
 from dlrover_tpu.ops.cross_entropy import (
     linear_softmax_cross_entropy_sum,
     softmax_cross_entropy,
@@ -968,8 +969,7 @@ def _ssm_mixer(u, ssm, cfg: LlamaConfig) -> tuple:
         xbc = zxbcdt[..., inner:inner + conv]
         step = zxbcdt[..., inner + conv:]
     with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(causal_conv1d(
-            xbc, ssm["conv_w"], ssm.get("conv_b"))).astype(dt)
+        xbc = causal_conv1d_silu(xbc, ssm["conv_w"], ssm.get("conv_b"))
     with jax.named_scope("ssm_scan"):
         step = jax.nn.softplus(step.astype(jnp.float32) + ssm["dt_bias"])
         y, state, decay_min = ssd_chunked(
@@ -1045,7 +1045,7 @@ def _gdn_mixer(u, gdn, cfg: LlamaConfig) -> tuple:
             qkv = jnp.concatenate(
                 [flat(qkvz[..., :D]), flat(qkvz[..., D:2 * D]),
                  flat(qkvz[..., 2 * D:(2 + R) * D])], axis=-1)
-            qkv = jax.nn.silu(causal_conv1d(qkv, conv_w)).astype(dt)
+            qkv = causal_conv1d_silu(qkv, conv_w)
         with jax.named_scope("gdn_scan"):
             beta = jax.nn.sigmoid(ba[..., :R].astype(f32)).reshape(B, S, hv)
             g = -jnp.exp(a_log) * jax.nn.softplus(

@@ -67,7 +67,7 @@ FLASH_BWD = {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
 #: ``lax.ragged_dot``, to Mosaic kernels too: not ours to count)
 OURS = set(FLASH_BWD) | {
     "rmsnorm_fwd", "softmax_xent_fwd", "quantize_blockwise",
-    "gdn_chunk_fwd", "gdn_chunk_bwd"}
+    "gdn_chunk_fwd", "gdn_chunk_bwd", "conv_silu_fwd", "conv_silu_bwd"}
 
 
 def _sq(x):
@@ -197,6 +197,27 @@ def _gated_delta(grad, chunk=64):
             else {"gdn_chunk_fwd": 1})
 
 
+def _conv_silu(grad, channels, bias):
+    """The convolution and its ``silu`` at the two cells' shapes: two
+    sequences of 8,192 by the delta-rule mixer's 8,192 channels (16 blocks
+    of four lane tiles, no bias) and by the state-space mixer's 4,352 (17
+    blocks of two, with its bias), four taps, tiles of 512 rows."""
+    from dlrover_tpu.ops.conv_silu import causal_conv1d_silu
+
+    def fwd(*ops):
+        return causal_conv1d_silu(*ops, backend="pallas")
+
+    fn = fwd
+    if grad:
+        def fn(*ops):
+            return jax.grad(lambda *o: _sq(fwd(*o)),
+                            argnums=range(len(ops)))(*ops)
+    shapes = [((2, 8192, channels), bf16), ((4, channels), f32)] + (
+        [((channels,), f32)] if bias else [])
+    return (fn, shapes, {"conv_silu_fwd": 1, "conv_silu_bwd": 1} if grad
+            else {"conv_silu_fwd": 1})
+
+
 def _bwd_block_q_128():
     """Round 4's hand record has this tuning point stalling the device for
     900 s.  The compiler accepts it — so that was a run-time matter, and
@@ -227,6 +248,10 @@ KERNEL_CASES = {
     "gated_delta-fwd": lambda: _gated_delta(False),
     "gated_delta-grad": lambda: _gated_delta(True),
     "gated_delta_chunk128-grad": lambda: _gated_delta(True, 128),
+    "conv_silu_gdn-fwd": lambda: _conv_silu(False, 8192, False),
+    "conv_silu_gdn-grad": lambda: _conv_silu(True, 8192, False),
+    "conv_silu_ssm-fwd": lambda: _conv_silu(False, 4352, True),
+    "conv_silu_ssm-grad": lambda: _conv_silu(True, 4352, True),
 }
 
 
@@ -561,7 +586,15 @@ def test_hybrid_step_compiles_at_published_widths(hybrid_step):
     for name, inner in job.program["subscopes"].items():
         by_inner.setdefault(inner, set()).add(job.program["scopes"][name][0])
     for inner in ("ssm_in", "ssm_conv", "ssm_scan", "ssm_gate", "ssm_out"):
-        assert {"forward", "backward", "recompute"} <= by_inner[inner], inner
+        # since the convolution is a kernel (PR 54) XLA no longer writes
+        # the recomputed ``y * silu(z)`` out: it forms it again inside the
+        # two backward fusions that read it (the gate's own and the scan's
+        # transpose), and what is left of the gate's recomputation at the
+        # top level is the ``rmsnorm_fwd`` call, filed under its own name
+        phases = {"forward", "backward"} | (
+            set() if inner == "ssm_gate" else {"recompute"})
+        assert phases <= by_inner[inner], inner
+    assert "recompute" in by_inner["rmsnorm_fwd"]
     # 137 M parameters of state and two sequences of 8,192: the step's
     # temporaries stay under 4 GB, which all heads' masks at once would not
     assert job.memory["temp_bytes"] < 4 * 1024 ** 3
@@ -591,6 +624,35 @@ def test_hybrid_step_forms_no_decay_mask_in_hbm(hybrid_step):
             assert not (dims.count(q) >= 2
                         and np.prod(dims) > per_group * q * q), line[:200]
     assert seen > 100  # the scope's instructions were there to be read
+
+
+def _conv_scope_holds_no_float32_sequence(text, scope, channels):
+    """No instruction under ``scope`` that reads or writes HBM — the top
+    level's, not a fusion's inner ones, whose values stay in registers — has
+    a float32 result of two sequences of 8,192 by the convolution's
+    channels: the pre-activation stays in VMEM."""
+    seen, fused = 0, False
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            fused = "fused_computation" in line.split()[0]
+        if fused or scope not in line or " = " not in line:
+            continue
+        seen += 1
+        shapes = line.split(" = ", 1)[1].split("metadata=", 1)[0]
+        assert f"f32[2,8192,{channels}]" not in shapes, line[:200]
+    assert seen > 4  # the scope's instructions were there to be read
+
+
+def test_hybrid_step_keeps_the_convolutions_float32_in_vmem(hybrid_step):
+    """The convolution and its ``silu`` are the kernel pair: under block
+    remat the step journals ``conv_silu_fwd`` twice a state-space layer
+    (forward, recomputation) and ``conv_silu_bwd`` once."""
+    job, text, cfg = hybrid_step
+    kernels, layers = job.program["kernels"], job.program["ssm_layers"]
+    assert kernels["conv_silu_fwd"] == 2 * layers
+    assert kernels["conv_silu_bwd"] == layers
+    _conv_scope_holds_no_float32_sequence(text, "ssm_conv",
+                                          cfg.mamba_conv_dim)
 
 
 def _step_and_text(topo, loss, cfg, sequences, seq_len):
@@ -835,6 +897,20 @@ def test_gdn_step_sizes_its_sorted_buffer_and_keeps_the_rule_in_vmem(
             assert [int(d) for d in dims.split(",")][-2:] != [q, q], line[:200]
     assert seen > 100  # the scope's instructions were there to be read
     assert job.memory["peak_bytes"] < 14_553_000_000
+
+
+def test_gdn_step_keeps_the_convolutions_float32_in_vmem(gdn_step):
+    """Under block remat and the mixer's own checkpoint the step journals
+    ``conv_silu_fwd`` three times a delta-rule layer and ``conv_silu_bwd``
+    once; what XLA keeps under ``gdn_conv`` is the concatenation of ``q``,
+    ``k`` and ``v`` in bf16 and its transpose."""
+    job, text, cfg = gdn_step
+    kernels, layers = job.program["kernels"], job.program["gdn_layers"]
+    assert kernels["conv_silu_fwd"] == 3 * layers
+    assert kernels["conv_silu_bwd"] == layers
+    _conv_scope_holds_no_float32_sequence(
+        text, "gdn_conv", (2 * cfg.gdn_k_heads + cfg.gdn_v_heads)
+        * cfg.gdn_d_head)
 
 
 @pytest.fixture(scope="module")

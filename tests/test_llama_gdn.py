@@ -14,7 +14,9 @@ cannot compute a new setting refuses it by name.
 """
 
 import dataclasses
+import functools
 import importlib
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +62,7 @@ def _rel(a, b):
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
-def _decisive(params, seed=7):
+def _decisive(params, seed=0):
     """Gains off their initial value, a router 40 times and the delta
     rule's projections 10 times larger: at initialisation the softmax over
     the experts is flat, ``1 + w`` is 1 whatever reads it and the rule's
@@ -69,7 +71,10 @@ def _decisive(params, seed=7):
 
     def leaf(path, a):
         name = jax.tree_util.keystr(path)
-        k = jax.random.fold_in(key, hash(name) % (2 ** 31))
+        # crc32, not hash(): a str's hash differs from process to process,
+        # and with it the draws (PYTHONHASHSEED=1 draws a near-tie in a
+        # router whose pick flips between the two formulations)
+        k = jax.random.fold_in(key, zlib.crc32(name.encode()) % (2 ** 31))
         if name.endswith("['router']"):
             return 40.0 * a
         if name.endswith("['in_proj_qkvz']") or name.endswith(
@@ -321,6 +326,51 @@ def test_bf16_streams_keep_the_rule_in_float32():
     assert stats["gdn_state_rms"].dtype == jnp.float32
     want = _gdn_by_position(u.astype(jnp.bfloat16).astype(F32), gdn, cfg)
     assert _rel(got.astype(F32), want) < 3e-2
+
+
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_mixer_through_the_convolutions_kernels_equals_the_numpy_form(
+        dtype, monkeypatch):
+    """``_gdn_mixer`` with ``ops.conv_silu``'s kernel pair (interpret mode)
+    against the mixer with the ``jax.numpy`` form, through the mixer's own
+    checkpoint: the output, the stream's gradient and every leaf's, at the
+    tolerances this file holds the mixer to against the loop over positions
+    (``A_log``'s gradient is a sum over 1,024 positions that cancels: 3e-5
+    here), on two row tiles of 128 channels (one key head of 32 under two
+    value heads)."""
+    from dlrover_tpu.ops import conv_silu
+
+    s = 2 * conv_silu._ROW_TILE
+    cfg, gdn, _ = _gdn_leaves(gdn_k_heads=1, gdn_v_heads=2, gdn_d_head=32,
+                              max_seq_len=s, dtype=dtype)
+    assert gdn["conv_w"].shape == (4, 128)
+    u = jax.random.normal(jax.random.PRNGKey(5), (B, s, D)).astype(dtype)
+
+    def loss(gdn, u):
+        out, stats = llama._gdn_mixer(u, gdn, cfg)
+        return jnp.sum(jnp.sin(out.astype(F32))), (out, stats)
+
+    run = lambda: jax.value_and_grad(  # noqa: E731
+        loss, (0, 1), has_aux=True)(gdn, u)
+    (_, (want, want_stats)), want_grads = run()
+    monkeypatch.setattr(llama, "causal_conv1d_silu", functools.partial(
+        conv_silu.causal_conv1d_silu, backend="pallas", interpret=True))
+    text = str(jax.make_jaxpr(jax.grad(lambda g_, u_: loss(g_, u_)[0]))(
+        gdn, u))
+    assert "conv_silu_fwd" in text and "conv_silu_bwd" in text
+    (_, (got, stats)), grads = run()
+    tol = 1e-4 if dtype == F32 else 1e-2
+    assert got.dtype == dtype and _rel(
+        got.astype(F32), want.astype(F32)) < (2e-5 if dtype == F32 else tol)
+    assert float(stats["gdn_state_rms"]) == pytest.approx(
+        float(want_stats["gdn_state_rms"]), rel=tol)
+    flat, tree = jax.tree_util.tree_flatten_with_path(grads)
+    flat_w, tree_w = jax.tree_util.tree_flatten(want_grads)
+    assert tree == tree_w
+    for (path, g), w in zip(flat, flat_w):
+        assert g.dtype == w.dtype
+        assert _rel(g.astype(F32), w.astype(F32)) < tol, (
+            jax.tree_util.keystr(path))
 
 
 # -- attention: the three changes, one by one ---------------------------------

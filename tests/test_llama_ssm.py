@@ -546,6 +546,51 @@ def test_block_remat_of_a_mixed_stack_equals_no_remat(via, monkeypatch):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_mixer_through_the_convolutions_kernels_equals_the_numpy_form(
+        dtype, monkeypatch):
+    """``_ssm_mixer`` with ``ops.conv_silu``'s kernel pair (interpret mode)
+    against the mixer with the ``jax.numpy`` form, the bias among its
+    leaves: the output, the stream's gradient and every leaf's, at the
+    tolerances the scan's kernels are held to, on two row tiles of 384
+    channels (two heads of 64 and one group's B and C of 128)."""
+    from dlrover_tpu.ops import conv_silu
+
+    s = 2 * conv_silu._ROW_TILE
+    cfg = _hybrid(d_model=64, mamba_n_heads=2, mamba_d_head=64,
+                  mamba_d_state=128, mamba_chunk_size=128, max_seq_len=s,
+                  dtype=dtype)
+    assert cfg.mamba_conv_dim == 384
+    ssm = llama._init_ssm(jax.random.PRNGKey(0), cfg)
+    u = jax.random.normal(jax.random.PRNGKey(1), (B, s, 64)).astype(dtype)
+
+    def loss(ssm, u):
+        out, stats = llama._ssm_mixer(u, ssm, cfg)
+        return jnp.sum(jnp.sin(out.astype(F32))), (out, stats)
+
+    run = lambda: jax.value_and_grad(  # noqa: E731
+        loss, (0, 1), has_aux=True)(ssm, u)
+    (_, (want, want_stats)), want_grads = run()
+    monkeypatch.setattr(llama, "causal_conv1d_silu", functools.partial(
+        conv_silu.causal_conv1d_silu, backend="pallas", interpret=True))
+    text = str(jax.make_jaxpr(jax.grad(lambda s_, u_: loss(s_, u_)[0]))(
+        ssm, u))
+    assert "conv_silu_fwd" in text and "conv_silu_bwd" in text
+    (_, (got, stats)), grads = run()
+    tol = 1e-5 if dtype == F32 else 1e-2
+    assert got.dtype == dtype and _rel(
+        got.astype(F32), want.astype(F32)) < tol
+    assert float(stats["ssm_state_rms"]) == pytest.approx(
+        float(want_stats["ssm_state_rms"]), rel=tol)
+    flat, tree = jax.tree_util.tree_flatten_with_path(grads)
+    flat_w, tree_w = jax.tree_util.tree_flatten(want_grads)
+    assert tree == tree_w and "conv_b" in grads[0]
+    for (path, g), w in zip(flat, flat_w):
+        assert g.dtype == w.dtype
+        assert _rel(g.astype(F32), w.astype(F32)) < tol, (
+            jax.tree_util.keystr(path))
+
+
 # -- the tied head and the multipliers ---------------------------------------
 
 
