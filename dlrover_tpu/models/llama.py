@@ -35,7 +35,11 @@ from dlrover_tpu.ops.flash_attention import (
 )
 from dlrover_tpu.ops.gated_delta import gated_delta_chunked
 from dlrover_tpu.ops.gather_sum import gather_sum, weighted_sum
-from dlrover_tpu.ops.grouped_matmul import TILING, grouped_matmul_ragged
+from dlrover_tpu.ops.grouped_matmul import (
+    TILING,
+    backend_for as expert_backend_for,
+    grouped_matmul_ragged,
+)
 from dlrover_tpu.ops.rmsnorm import rmsnorm
 from dlrover_tpu.ops.ssd import causal_conv1d, ssd_chunked
 
@@ -47,6 +51,13 @@ from dlrover_tpu.ops.ssd import causal_conv1d, ssd_chunked
 #: new kind adds a row.
 MIXER_KINDS = {"attention": "attention", "mamba": "ssm", "conv": "conv",
                "linear_attention": "gdn"}
+#: what ``LlamaConfig.layer_types`` may name as a layer's ONLY branch where
+#: ``one_branch``: the dense MLP and the routed block, each by the key of the
+#: layer dict that holds its leaves
+MLP_KINDS = ("mlp", "moe")
+#: the forms of an MLP (``LlamaConfig.mlp_form``): ``down(silu(gate x) * up
+#: x)``, three matrices, or ``down(relu(up x)^2)``, two
+MLP_FORMS = ("swiglu", "relu2")
 #: positions a chunk of the gated delta rule holds (``ops.gated_delta``): a
 #: shape decision of the op, not a setting
 GDN_CHUNK = 64
@@ -174,15 +185,18 @@ class LlamaConfig:
     # "linear_attention" (a tuple of ``n_layer`` names; empty: every layer
     # is an attention layer), under the same pre-norm and residual add;
     # which MLP follows (dense or routed) is :meth:`is_moe_layer`'s,
-    # whatever the mixer — but for "mamba", whose MLP is dense.  A "conv"
-    # layer's mixer is LFM2's
+    # whatever the mixer.  A "conv" layer's mixer is LFM2's
     # double-gated short convolution (:func:`_conv_mixer`): ``[B | C | X]
     # = u in_proj``, a causal depthwise convolution of ``conv_taps`` taps
     # over ``B * X``, times ``C``, ``out_proj``; no bias, no activation.  A
     # "mamba" layer's mixer is the Mamba-2 one (:func:`_ssm_mixer`,
-    # ``ops.ssd``): ``mamba_n_heads`` heads of ``mamba_d_head``
-    # (together ``mamba_expand * d_model`` wide), a state of
-    # ``mamba_d_state`` per head dim, B and C in ``mamba_n_groups`` groups,
+    # ``ops.ssd``): ``mamba_n_heads`` heads of ``mamba_d_head`` — together
+    # the mixer's inner width (:attr:`mamba_d_inner`), whatever
+    # ``mamba_expand * d_model`` is: ``mamba_expand`` is the source's name
+    # for their ratio where it holds (Granite: 2, exact; Nemotron-H states 2
+    # and reads heads x head size) and nothing here reads it —, a state of
+    # ``mamba_d_state`` per head dim, B, C and the gated norm in
+    # ``mamba_n_groups`` groups,
     # a causal depthwise convolution ``mamba_d_conv`` wide (with a bias
     # where ``mamba_conv_bias``) and chunks of ``mamba_chunk_size``
     # positions.  ``mamba_proj_bias`` must stay False: the two projections
@@ -239,6 +253,19 @@ class LlamaConfig:
     # The shared expert behind a gate of its own: ``sigmoid(h @ w_sg) *
     # Shared(h)``, ``w_sg [d_model, 1]`` (``moe["shared_gate"]``).
     shared_expert_gate: bool = False
+    # Layers that are ONE branch each (Nemotron-H): ``x + branch(norm(x))``
+    # with one norm a layer, the branch a mixer (the layer holds ``ln1`` and
+    # the mixer's leaves, no MLP) or an MLP (``ln2`` and ``mlp`` or ``moe``,
+    # no mixer).  ``layer_types`` then names, beside the mixers, the two MLP
+    # kinds (:data:`MLP_KINDS`): "mlp" the dense one, "moe" the routed block,
+    # which are the routed layers whatever ``moe_every`` and
+    # ``first_k_dense`` say.
+    one_branch: bool = False
+    # The form of every MLP (:data:`MLP_FORMS`) — dense, expert and shared
+    # expert: "swiglu", ``down(silu(gate x) * up x)`` (leaves ``w_gate``,
+    # ``w_up``, ``w_down``; experts ``wg``, ``wi``, ``wo``), or "relu2",
+    # ``down(relu(up x)^2)``, TWO matrices and no gate leaf.
+    mlp_form: str = "swiglu"
 
     def __post_init__(self):
         if (self.loop_passes > 1) != (self.exit_gate_beta is not None):
@@ -299,11 +326,29 @@ class LlamaConfig:
 
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         kinds = self.layer_types
-        if kinds and (len(kinds) != self.n_layer
-                      or set(kinds) - set(MIXER_KINDS)):
+        names = tuple(MIXER_KINDS) + (MLP_KINDS if self.one_branch else ())
+        if (kinds or self.one_branch) and (
+                len(kinds) != self.n_layer or set(kinds) - set(names)):
             raise ValueError(
                 f"LlamaConfig: layer_types={kinds} is not n_layer="
-                f"{self.n_layer} names out of {tuple(MIXER_KINDS)}")
+                f"{self.n_layer} names out of {names} (the MLP kinds "
+                f"{MLP_KINDS} name a layer's only branch, under one_branch)")
+        if self.mlp_form not in MLP_FORMS:
+            raise ValueError(
+                f"LlamaConfig: mlp_form={self.mlp_form!r} is none of "
+                f"{MLP_FORMS}")
+        if self.one_branch and (
+                ("moe" in kinds) != (self.num_experts > 0)
+                or self.loop_passes > 1 or self.mtp_layers
+                or self.branch_norm):
+            raise ValueError(
+                f"LlamaConfig: one_branch with layer_types={kinds}, "
+                f"num_experts={self.num_experts}, loop_passes="
+                f"{self.loop_passes}, mtp_layers={self.mtp_layers} or "
+                f"branch_norm={self.branch_norm}: the routed layers are the "
+                "'moe' entries (some, if there are experts; none, if not), "
+                "the stack runs once, and the prediction block and the "
+                "sandwich norms are built for layers of two branches")
         if self.conv_layers and (
                 self.conv_taps <= 0 or self.loop_passes > 1
                 or self.mtp_layers):
@@ -314,31 +359,26 @@ class LlamaConfig:
                 "least one tap and the stack runs once, with no prediction "
                 "block")
         if self.ssm_layers:
-            if (self.mamba_n_heads * self.mamba_d_head
-                    != self.mamba_expand * self.d_model
-                    or min(self.mamba_d_state, self.mamba_d_conv,
-                           self.mamba_chunk_size, self.mamba_n_groups) <= 0
+            if (min(self.mamba_n_heads, self.mamba_d_head,
+                    self.mamba_d_state, self.mamba_d_conv,
+                    self.mamba_chunk_size, self.mamba_n_groups) <= 0
                     or self.mamba_n_heads % self.mamba_n_groups
                     or self.mamba_proj_bias):
                 raise ValueError(
-                    f"LlamaConfig: a 'mamba' layer needs mamba_n_heads x "
-                    f"mamba_d_head ({self.mamba_n_heads} x "
-                    f"{self.mamba_d_head}) == mamba_expand x d_model "
-                    f"({self.mamba_expand} x {self.d_model}), heads that "
+                    f"LlamaConfig: a 'mamba' layer needs positive "
+                    f"mamba_n_heads x mamba_d_head ({self.mamba_n_heads} x "
+                    f"{self.mamba_d_head}: the mixer's inner width, "
+                    "whatever mamba_expand x d_model is), heads that "
                     f"mamba_n_groups={self.mamba_n_groups} divides, "
                     "positive mamba_d_state, mamba_d_conv and "
                     "mamba_chunk_size, and mamba_proj_bias False")
-            if self.num_experts > 0 or self.loop_passes > 1 or (
-                    self.mtp_layers):
+            if self.loop_passes > 1 or self.mtp_layers:
                 raise ValueError(
-                    f"LlamaConfig: 'mamba' layers with num_experts="
-                    f"{self.num_experts}, loop_passes={self.loop_passes} or "
-                    f"mtp_layers={self.mtp_layers}: a state-space layer's "
-                    "MLP is dense (experts beside a 'conv', a "
-                    "'linear_attention' or an 'attention' mixer are built, "
-                    "beside a 'mamba' one not yet) and the stack runs "
-                    "once, with no prediction "
-                    "block")
+                    f"LlamaConfig: 'mamba' layers with loop_passes="
+                    f"{self.loop_passes} or mtp_layers={self.mtp_layers}: "
+                    "the stack runs once, with no prediction block "
+                    "(experts beside a 'mamba' mixer are built, as beside "
+                    "every other kind)")
         if self.gdn_layers and (
                 min(self.gdn_k_heads, self.gdn_d_head, self.gdn_d_conv) <= 0
                 or self.gdn_v_heads % max(self.gdn_k_heads, 1)
@@ -378,9 +418,18 @@ class LlamaConfig:
                 f"{self.n_shared_experts}: there is no shared expert to "
                 "gate")
 
-    def mixer_kind(self, i: int) -> str:
-        """Layer ``i``'s mixer: one of :data:`MIXER_KINDS`."""
-        return self.layer_types[i] if self.layer_types else "attention"
+    def mixer_kind(self, i: int) -> Optional[str]:
+        """Layer ``i``'s mixer: one of :data:`MIXER_KINDS`, or None where
+        the layer's one branch is an MLP (``one_branch``)."""
+        kind = self.layer_types[i] if self.layer_types else "attention"
+        return None if kind in MLP_KINDS else kind
+
+    def mlp_routed(self, i: int) -> Optional[bool]:
+        """Layer ``i``'s MLP: routed (True), dense (False), or None where
+        the layer's one branch is a mixer (``one_branch``)."""
+        if self.one_branch and self.mixer_kind(i) is not None:
+            return None
+        return self.is_moe_layer(i)
 
     def layers_of(self, kind: str) -> int:
         """Layers whose mixer is of ``kind`` (of :data:`MIXER_KINDS`)."""
@@ -450,9 +499,16 @@ class LlamaConfig:
     def is_moe_layer(self, i: int) -> bool:
         """Single source of truth for MoE placement (init_params,
         param_logical_axes must agree)."""
+        if self.one_branch:
+            return self.layer_types[i] == "moe"
         return self.num_experts > 0 and i >= self.first_k_dense and (
             i % self.moe_every == self.moe_every - 1
         )
+
+    @property
+    def moe_layers(self) -> int:
+        """Layers whose MLP is the routed block."""
+        return sum(self.is_moe_layer(i) for i in range(self.n_layer))
 
     @classmethod
     def llama2_7b(cls) -> "LlamaConfig":
@@ -567,29 +623,36 @@ def _gain_leaf(width: int, cfg: "LlamaConfig"):
         (width,), jnp.float32)
 
 
-def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool,
-                mixer: str = "attention") -> Dict:
+def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: Optional[bool],
+                mixer: Optional[str] = "attention") -> Dict:
     """One block's parameters: the mixer's (``mixer``, one of
-    :data:`MIXER_KINDS`) and the MLP's (``routed`` or dense), chosen apart.
+    :data:`MIXER_KINDS`) and the MLP's (``routed`` or dense), chosen apart;
+    a layer of ONE branch (``cfg.one_branch``) has no mixer half (``mixer``
+    None: no ``ln1``) or no MLP half (``routed`` None: no ``ln2``).
     The leaves every earlier configuration has draw from the same eight
     keys as ever; what latent attention, the shared expert, a state-space
     mixer (``layer["ssm"]``) and a convolution mixer (``layer["conv"]``),
     a delta-rule mixer (``layer["gdn"]``, each of the three in place of the
     attention leaves) and the shared expert's gate add draws from keys
     folded out of the layer's.  A gain is 1, or 0 where
-    ``cfg.norm_plus_one``."""
+    ``cfg.norm_plus_one``.  Where ``cfg.mlp_form`` is "relu2" an MLP has no
+    gate leaf (``w_gate``; an expert's ``wg``): two matrices, drawn from the
+    keys the three-matrix form draws its up and down ones from."""
     k = jax.random.split(key, 8)
     more = jax.random.split(jax.random.fold_in(key, 1), 5)
     hd = cfg.head_dim
     attention = mixer == "attention"
-    layer = {"ln1": _gain_leaf(cfg.d_model, cfg)}
+    gated = cfg.mlp_form == "swiglu"
+    layer = {}
+    if mixer is not None:
+        layer["ln1"] = _gain_leaf(cfg.d_model, cfg)
     if mixer == "mamba":
         layer["ssm"] = _init_ssm(jax.random.fold_in(key, 2), cfg)
     elif mixer == "conv":
         layer["conv"] = _init_conv(jax.random.fold_in(key, 3), cfg)
     elif mixer == "linear_attention":
         layer["gdn"] = _init_gdn(jax.random.fold_in(key, 4), cfg)
-    elif cfg.kv_lora_rank > 0:
+    elif attention and cfg.kv_lora_rank > 0:
         layer["wq_a"] = _dense(k[0], cfg.d_model, cfg.q_lora_rank)
         layer["q_a_norm"] = jnp.ones((cfg.q_lora_rank,), jnp.float32)
         layer["wq_b"] = _dense(more[0], cfg.q_lora_rank, cfg.n_head * hd)
@@ -599,7 +662,7 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool,
         layer["wkv_b"] = _dense(
             more[1], cfg.kv_lora_rank,
             cfg.n_head * (cfg.qk_nope_head_dim + cfg.v_head_dim))
-    else:
+    elif attention:
         # with the output gate each head's columns are [q | gate]
         layer["wq"] = _dense(
             k[0], cfg.d_model,
@@ -608,7 +671,8 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool,
         layer["wv"] = _dense(k[2], cfg.d_model, cfg.n_kv_head * hd)
     if attention:
         layer["wo"] = _dense(k[3], cfg.n_head * hd, cfg.d_model)
-    layer["ln2"] = _gain_leaf(cfg.d_model, cfg)
+    if routed is not None:
+        layer["ln2"] = _gain_leaf(cfg.d_model, cfg)
     if cfg.qk_norm and attention:
         per_head = cfg.qk_norm_per_head
         layer["q_norm"] = _gain_leaf(
@@ -624,30 +688,34 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: bool,
             "router": _dense(k[4], cfg.d_model, cfg.num_experts),
             "wi": jax.random.normal(
                 k[5], (held, cfg.d_model, width), jnp.float32) * 0.02,
-            "wg": jax.random.normal(
-                k[6], (held, cfg.d_model, width), jnp.float32) * 0.02,
             "wo": jax.random.normal(
                 k[7], (held, width, cfg.d_model), jnp.float32) * 0.02,
         }
+        if gated:
+            layer["moe"]["wg"] = jax.random.normal(
+                k[6], (held, cfg.d_model, width), jnp.float32) * 0.02
         if cfg.router_bias_rate is not None:
             layer["moe"]["router_bias"] = jnp.zeros(
                 (cfg.num_experts,), jnp.float32)
         if cfg.n_shared_experts > 0:
             shared = cfg.n_shared_experts * width
             layer["moe"]["shared"] = {
-                "w_gate": _dense(more[2], cfg.d_model, shared),
                 "w_up": _dense(more[3], cfg.d_model, shared),
                 "w_down": _dense(more[4], shared, cfg.d_model),
             }
+            if gated:
+                layer["moe"]["shared"]["w_gate"] = _dense(
+                    more[2], cfg.d_model, shared)
         if cfg.shared_expert_gate:
             layer["moe"]["shared_gate"] = _dense(
                 jax.random.fold_in(key, 5), cfg.d_model, 1)
-    else:
+    elif routed is not None:
         layer["mlp"] = {
-            "w_gate": _dense(k[4], cfg.d_model, cfg.d_ff),
             "w_up": _dense(k[5], cfg.d_model, cfg.d_ff),
             "w_down": _dense(k[6], cfg.d_ff, cfg.d_model),
         }
+        if gated:
+            layer["mlp"]["w_gate"] = _dense(k[4], cfg.d_model, cfg.d_ff)
     return layer
 
 
@@ -658,7 +726,7 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
         "lm_head": _dense(keys[1], cfg.d_model, cfg.vocab_size),
         "ln_f": _gain_leaf(cfg.d_model, cfg),
         "layers": [
-            _init_layer(keys[2 + i], cfg, cfg.is_moe_layer(i),
+            _init_layer(keys[2 + i], cfg, cfg.mlp_routed(i),
                         mixer=cfg.mixer_kind(i))
             for i in range(cfg.n_layer)],
     }
@@ -687,9 +755,24 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
     """Logical-axis names per parameter (consumed by
     ``parallel.sharding.tree_logical_to_specs``)."""
 
-    def layer_axes(has_moe: bool, mixer: str = "attention") -> Dict:
+    gated = cfg.mlp_form == "swiglu"
+
+    def mlp_axes() -> Dict:
+        ax = {"w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
+        if gated:
+            ax["w_gate"] = ("embed", "mlp")
+        return ax
+
+    def layer_axes(has_moe: Optional[bool],
+                   mixer: Optional[str] = "attention") -> Dict:
+        """As :func:`_init_layer`: ``mixer`` None is a layer without a
+        mixer half, ``has_moe`` None one without an MLP half."""
         ax = {"ln1": (None,), "wo": ("heads", "embed"), "ln2": (None,)}
         attention = mixer == "attention"
+        if mixer is None:
+            del ax["ln1"], ax["wo"]
+        if has_moe is None:
+            del ax["ln2"]
         if mixer == "conv":
             # ``in_proj`` by columns, ``out_proj`` by rows, the taps along
             # their channels: a depthwise convolution mixes no channels, so
@@ -715,11 +798,11 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
                 "in_proj_qkvz": ("embed", None), "in_proj_ba": ("embed", None),
                 "conv_w": (None, None), "dt_bias": (None,), "A_log": (None,),
                 "norm": (None,), "out_proj": (None, "embed")}
-        elif cfg.kv_lora_rank > 0:
+        elif attention and cfg.kv_lora_rank > 0:
             ax.update(wq_a=("embed", None), q_a_norm=(None,),
                       wq_b=(None, "heads"), wkv_a=("embed", None),
                       kv_a_norm=(None,), wkv_b=(None, "heads"))
-        else:
+        elif attention:
             ax.update(wq=("embed", "heads"), wk=("embed", "heads"),
                       wv=("embed", "heads"))
         if cfg.qk_norm and attention:
@@ -732,30 +815,23 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
             ax["moe"] = {
                 "router": (None, None),
                 "wi": ("expert", "embed", "expert_mlp"),
-                "wg": ("expert", "embed", "expert_mlp"),
                 "wo": ("expert", "expert_mlp", "embed"),
             }
+            if gated:
+                ax["moe"]["wg"] = ("expert", "embed", "expert_mlp")
             if cfg.router_bias_rate is not None:
                 ax["moe"]["router_bias"] = (None,)
             if cfg.n_shared_experts > 0:
-                ax["moe"]["shared"] = {
-                    "w_gate": ("embed", "mlp"),
-                    "w_up": ("embed", "mlp"),
-                    "w_down": ("mlp", "embed"),
-                }
+                ax["moe"]["shared"] = mlp_axes()
             if cfg.shared_expert_gate:
                 ax["moe"]["shared_gate"] = ("embed", None)
-        else:
-            ax["mlp"] = {
-                "w_gate": ("embed", "mlp"),
-                "w_up": ("embed", "mlp"),
-                "w_down": ("mlp", "embed"),
-            }
+        elif has_moe is not None:
+            ax["mlp"] = mlp_axes()
         return ax
 
     layers = []
     for i in range(cfg.n_layer):
-        layers.append(layer_axes(cfg.is_moe_layer(i), cfg.mixer_kind(i)))
+        layers.append(layer_axes(cfg.mlp_routed(i), cfg.mixer_kind(i)))
     axes = {
         "embed": ("vocab", "embed"),
         "lm_head": ("embed", "vocab"),
@@ -954,7 +1030,9 @@ def _ssm_mixer(u, ssm, cfg: LlamaConfig) -> tuple:
     dt_bias)`` in float32, unclamped; ``A = -exp(A_log)``; the scan
     (``ops.ssd.ssd_chunked`` at ``cfg.mamba_chunk_size``) gives ``y_t = h_t
     C_t + D x_t``; ``y = rms(y * silu(z)) * norm`` — the gate BEFORE the
-    norm, one group over the whole width; ``out = y out_proj``.  Scopes
+    norm, the mean square taken over each of ``cfg.mamba_n_groups`` groups
+    of the width by itself (:func:`_rms_per_group`; one group: the RMSNorm
+    kernel over the whole width); ``out = y out_proj``.  Scopes
     ``ssm_in``, ``ssm_conv``, ``ssm_scan``, ``ssm_gate`` and ``ssm_out`` sit
     inside the block's ``ssm``.  ``stats``: ``ssm_state_rms`` (of the state
     the sequence leaves) and ``ssm_decay_min`` (the least ``exp(sum dt A)``
@@ -983,9 +1061,24 @@ def _ssm_mixer(u, ssm, cfg: LlamaConfig) -> tuple:
             "ssm_decay_min": decay_min})
     with jax.named_scope("ssm_gate"):
         y = y.reshape(B, S, inner) * jax.nn.silu(z.astype(jnp.float32))
-        y = rmsnorm(y.astype(dt), ssm["norm"], eps=cfg.rms_eps)
+        if G == 1:
+            y = rmsnorm(y.astype(dt), ssm["norm"], eps=cfg.rms_eps)
+        else:
+            y = _rms_per_group(y, ssm["norm"], G, cfg.rms_eps).astype(dt)
     with jax.named_scope("ssm_out"):
         return y @ ssm["out_proj"].astype(dt), stats
+
+
+def _rms_per_group(x, gain, groups: int, eps: float):
+    """float32 ``x [..., W]`` -> the same, each of ``groups`` equal groups
+    of the last dim RMS-normalised by its own mean square, times ``gain
+    [W]`` (HF's ``MambaRMSNormGated`` at ``group_size = W / groups``):
+    elementwise work and a short reduction that XLA fuses with the gate in
+    front of it."""
+    parts = x.reshape(x.shape[:-1] + (groups, -1))
+    inv = jax.lax.rsqrt(
+        jnp.mean(jnp.square(parts), axis=-1, keepdims=True) + eps)
+    return (parts * inv).reshape(x.shape) * gain
 
 
 def _conv_mixer(u, conv, cfg: LlamaConfig):
@@ -1086,6 +1179,19 @@ def _swiglu(x, mlp, dt):
     g = x @ mlp["w_gate"].astype(dt)
     u = x @ mlp["w_up"].astype(dt)
     return (jax.nn.silu(g) * u) @ mlp["w_down"].astype(dt)
+
+
+def _relu2(x, mlp, dt):
+    """``down(relu(up x)^2)``: the two-matrix MLP (``cfg.mlp_form``
+    "relu2")."""
+    u = x @ mlp["w_up"].astype(dt)
+    return jnp.square(jax.nn.relu(u)) @ mlp["w_down"].astype(dt)
+
+
+def _mlp(x, mlp, dt):
+    """The MLP of the form its leaves say (``cfg.mlp_form``): with a gate
+    matrix :func:`_swiglu`, without one :func:`_relu2`."""
+    return (_swiglu if "w_gate" in mlp else _relu2)(x, mlp, dt)
 
 
 def _live_mask(rows: int, live_rows):
@@ -1201,17 +1307,19 @@ def _expert_ffn(rows, wg, wi, wo, group_sizes, dt, backend):
     backward pass: the rows and the two ``[N*K, F]`` products; recomputed
     there: the bf16 casts of the weights and ``silu(g) * u`` (elementwise
     passes, in place of 0.8 GB of bf16 weights and a third ``[N*K, F]``
-    buffer at OLMoE's widths)."""
-    g = checkpoint_name(
-        grouped_matmul_ragged(rows, wg.astype(dt), group_sizes,
-                              backend=backend),
-        "moe_gate_up")
-    u = checkpoint_name(
-        grouped_matmul_ragged(rows, wi.astype(dt), group_sizes,
-                              backend=backend),
-        "moe_gate_up")
-    return grouped_matmul_ragged(
-        jax.nn.silu(g) * u, wo.astype(dt), group_sizes, backend=backend)
+    buffer at OLMoE's widths).  ``wg`` None is the two-matrix form
+    (``cfg.mlp_form`` "relu2"): ``relu(rows wi)^2 wo``, the one product
+    kept."""
+    def product(a, w):
+        return grouped_matmul_ragged(a, w.astype(dt), group_sizes,
+                                     backend=backend)
+
+    if wg is None:
+        u = checkpoint_name(product(rows, wi), "moe_gate_up")
+        return product(jnp.square(jax.nn.relu(u)), wo)
+    g = checkpoint_name(product(rows, wg), "moe_gate_up")
+    u = checkpoint_name(product(rows, wi), "moe_gate_up")
+    return product(jax.nn.silu(g) * u, wo)
 
 
 def _moe_buffer_bounds(n: int, k: int, e: int, held: int) -> tuple:
@@ -1319,7 +1427,8 @@ _routed_sum_sized.defvjp(_routed_sum_sized_fwd, _routed_sum_sized_bwd)
 
 def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
                 valid=None):
-    """Routed SwiGLU block, sorted and ragged: the ``N*K`` (token, expert)
+    """Routed block, sorted and ragged (its experts SwiGLU, or the
+    two-matrix form where they hold no ``wg``): the ``N*K`` (token, expert)
     pairs are sorted by expert (stable, so a pair's rank inside its
     expert's group follows the token order), the token rows gathered in
     that order, ``wg``/``wi``/``wo`` applied as grouped matmuls over the
@@ -1442,9 +1551,11 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
                 keep_sorted[inverse].reshape(N, K), gate_vals, 0.0)
         tokens = tokens.astype(dt)
 
+    # wg, wi, wo; no wg in the two-matrix form
+    weights = (moe.get("wg"), moe["wi"], moe["wo"])
+
     def ffn(rows):
-        return _expert_ffn(rows, moe["wg"], moe["wi"], moe["wo"],
-                           group_sizes, dt, None)
+        return _expert_ffn(rows, *weights, group_sizes, dt, None)
 
     bounds = _moe_buffer_bounds(N, K, E, held)
     if len(bounds) == 1:
@@ -1457,12 +1568,11 @@ def _moe_swiglu(x, moe, cfg: LlamaConfig, capacity: Optional[int] = None,
             size = sum(held_pairs >= rows for rows in bounds[:-1])
         # outside every scope: a branch's instructions name their own
         out = _routed_sum_sized(
-            bounds, dt, size, tokens, gate_vals,
-            (moe["wg"], moe["wi"], moe["wo"]),
+            bounds, dt, size, tokens, gate_vals, weights,
             (order, inverse, group_sizes, held_pairs))
     if "shared" in moe:
         with jax.named_scope("moe_shared"):
-            shared = _swiglu(tokens.astype(dt), moe["shared"], dt)
+            shared = _mlp(tokens.astype(dt), moe["shared"], dt)
             if "shared_gate" in moe:
                 shared = (shared.astype(f32) * jax.nn.sigmoid(
                     (tokens @ moe["shared_gate"].astype(dt)).astype(f32))
@@ -1527,7 +1637,10 @@ def block_apply(
     ``conv``), a gated delta rule (``"gdn"``, scope ``gdn``) or attention
     (the attention leaves, scope ``attention``) — and the MLP the one it
     holds, routed (``"moe"``) or dense (``"mlp"``), each chosen apart from
-    the other.  ``stats`` is what the mixer reports (:func:`_ssm_mixer`:
+    the other; a layer of ONE branch (``cfg.one_branch``) holds the mixer's
+    half (``ln1`` and the mixer) or the MLP's (``ln2`` and the MLP) and the
+    other half is not run: the same wiring with a half absent.  ``stats``
+    is what the mixer reports (:func:`_ssm_mixer`:
     ``ssm_state_rms``, ``ssm_decay_min``; :func:`_gdn_mixer`:
     ``gdn_state_rms``, ``gdn_decay_min``; the other two nothing) with what
     a routed MLP's :func:`_moe_swiglu` reports (``moe_aux``, ``moe_z``,
@@ -1548,33 +1661,36 @@ def block_apply(
         return x + branch
 
     stats = {}
-    named, kind = next(
-        (row for row in MIXER_KINDS.items() if row[1] in layer),
-        ("attention", "attention"))
-    if kind != "attention" and (
-            segment_ids is not None or attn_fn is not None):
-        raise NotImplementedError(
-            f"block_apply: a {named!r} layer with segment_ids or a custom "
-            "attn_fn: the scan and the convolution know no document "
-            "boundary and no cache")
-    # outermost ``ssm`` / ``conv`` / ``gdn`` as ``attention`` is for the
-    # other kind; the mixer's own scopes nest inside it (``subscopes``)
-    with jax.named_scope(kind):
-        h = rmsnorm(x, _gain(layer["ln1"], cfg), eps=cfg.rms_eps)
-        if kind == "ssm":
-            mixed, stats = _ssm_mixer(h, layer["ssm"], cfg)
-        elif kind == "gdn":
-            mixed, stats = _gdn_mixer(h, layer["gdn"], cfg)
-        elif kind == "conv":
-            mixed = _conv_mixer(h, layer["conv"], cfg)
-        elif attn_fn is not None:
-            mixed = attn_fn(h, layer, cfg, positions)
-        else:
-            mixed = _attention(
-                h, layer, cfg, positions, attn_impl, mesh, segment_ids)
-        if cfg.branch_norm:
-            mixed = rmsnorm(mixed, layer["ln1_out"], eps=cfg.rms_eps)
-        x = add(x, mixed)
+    if "ln1" in layer:  # the mixer's half; a one-branch MLP layer has none
+        named, kind = next(
+            (row for row in MIXER_KINDS.items() if row[1] in layer),
+            ("attention", "attention"))
+        if kind != "attention" and (
+                segment_ids is not None or attn_fn is not None):
+            raise NotImplementedError(
+                f"block_apply: a {named!r} layer with segment_ids or a "
+                "custom attn_fn: the scan and the convolution know no "
+                "document boundary and no cache")
+        # outermost ``ssm`` / ``conv`` / ``gdn`` as ``attention`` is for the
+        # other kind; the mixer's own scopes nest inside it (``subscopes``)
+        with jax.named_scope(kind):
+            h = rmsnorm(x, _gain(layer["ln1"], cfg), eps=cfg.rms_eps)
+            if kind == "ssm":
+                mixed, stats = _ssm_mixer(h, layer["ssm"], cfg)
+            elif kind == "gdn":
+                mixed, stats = _gdn_mixer(h, layer["gdn"], cfg)
+            elif kind == "conv":
+                mixed = _conv_mixer(h, layer["conv"], cfg)
+            elif attn_fn is not None:
+                mixed = attn_fn(h, layer, cfg, positions)
+            else:
+                mixed = _attention(
+                    h, layer, cfg, positions, attn_impl, mesh, segment_ids)
+            if cfg.branch_norm:
+                mixed = rmsnorm(mixed, layer["ln1_out"], eps=cfg.rms_eps)
+            x = add(x, mixed)
+    if "ln2" not in layer:  # a one-branch mixer layer: no MLP's half
+        return x, stats
     if "moe" in layer:
         with jax.named_scope("moe_router"):
             h = rmsnorm(x, _gain(layer["ln2"], cfg), eps=cfg.rms_eps)
@@ -1590,7 +1706,7 @@ def block_apply(
         return x, stats
     with jax.named_scope("mlp"):
         h = rmsnorm(x, _gain(layer["ln2"], cfg), eps=cfg.rms_eps)
-        out_m = _swiglu(h, layer["mlp"], cfg.dtype)
+        out_m = _mlp(h, layer["mlp"], cfg.dtype)
         if cfg.branch_norm:
             out_m = rmsnorm(out_m, layer["ln2_out"], eps=cfg.rms_eps)
         x = add(x, out_m)
@@ -2137,6 +2253,8 @@ TRAINING_PATH_ONLY = (
     ("partial_rotary_factor", 1.0, "rotation of a part of each head"),
     ("norm_plus_one", False, "gains stored as 1 + w"),
     ("shared_expert_gate", False, "a gate on the shared expert"),
+    ("one_branch", False, "layers that are one branch each"),
+    ("mlp_form", "swiglu", "an MLP that is not SwiGLU"),
 )
 
 
@@ -2168,7 +2286,14 @@ def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:
     are not all attention layers, for the ``accelerate.program`` event (a
     loss function carries it as its ``program_facts`` attribute): how many
     layers are of each kind, and the chunks the scan carries a state over
-    in a sequence of ``seq_len``.  Empty for every other model."""
+    in a sequence of ``seq_len``.  Where the layers are ONE branch each
+    (``cfg.one_branch``) the MLP kinds are layers of their own and are
+    counted too (``mlp_layers``, ``moe_layers``), with the form of the MLPs
+    (``mlp_form``) and, of a routed model, the backend its experts' grouped
+    matmuls take at their widths on this device (``moe_expert_backend``:
+    ``ops.grouped_matmul.backend_for``; the fall-back buffer of every pick
+    takes the reference whatever the widths).  Empty for every other
+    model."""
     facts = {f"{scope}_layers": cfg.layers_of(kind)
              for kind, scope in MIXER_KINDS.items()
              if kind != "attention" and cfg.layers_of(kind)}
@@ -2178,6 +2303,14 @@ def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:
     for scope, chunk in (("ssm", cfg.mamba_chunk_size), ("gdn", GDN_CHUNK)):
         if f"{scope}_layers" in facts:
             facts[f"{scope}_chunks_per_sequence"] = -(-seq_len // chunk)
+    if cfg.one_branch:
+        facts.update({f"{kind}_layers": cfg.layer_types.count(kind)
+                      for kind in MLP_KINDS if kind in cfg.layer_types},
+                     attention_layers=cfg.attention_layers,
+                     mlp_form=cfg.mlp_form)
+        if cfg.moe_layers:
+            facts["moe_expert_backend"] = expert_backend_for(
+                cfg.dtype, cfg.d_model, cfg.expert_width)
     return facts
 
 
@@ -2197,7 +2330,13 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     taps and the chunked rule's matmuls (per value head and token, forward:
     ``k k^T``, ``q k^T`` and the two products with ``T`` and the one with
     ``u`` over a chunk's ``Q`` positions, ``10 Q D``, and the three against
-    the state, ``6 D^2``)."""
+    the state, ``6 D^2``).  An MLP is three matrices or, at ``mlp_form``
+    "relu2", two.  Where ``cfg.one_branch`` a mixer layer counts no MLP, a
+    "mlp" layer its MLP alone and a "moe" layer its router, its shared
+    expert and the share of a token's ``top_k`` picks that meet an expert
+    held here (every other routed model's routed layers count as dense ones
+    of ``d_ff``, as they always have)."""
+    mats = 3 if cfg.mlp_form == "swiglu" else 2
     if cfg.kv_lora_rank > 0:  # latent attention's five projections
         qkv = (
             cfg.d_model * cfg.q_lora_rank
@@ -2209,10 +2348,12 @@ def flops_per_token(cfg: LlamaConfig) -> float:
         qkv = (
             cfg.d_model * cfg.n_head * cfg.head_dim  # wq
             + 2 * cfg.d_model * cfg.n_kv_head * cfg.head_dim)  # wk, wv
+    # the MLP behind every mixer; a one-branch layer's mixer has none
+    mlp = 0 if cfg.one_branch else mats * cfg.d_model * cfg.d_ff
     p_layer = (
         qkv
         + cfg.n_head * cfg.head_dim * cfg.d_model  # wo
-        + 3 * cfg.d_model * cfg.d_ff  # swiglu
+        + mlp
     )
     head = cfg.vocab_size * cfg.d_model
     if cfg.exit_gate_beta is not None:
@@ -2221,7 +2362,6 @@ def flops_per_token(cfg: LlamaConfig) -> float:
              + cfg.vocab_size * cfg.d_model)
     attn = (2 * cfg.block_applications * cfg.max_seq_len
             * cfg.n_head * cfg.head_dim)
-    mlp = 3 * cfg.d_model * cfg.d_ff
     inner, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
     p_ssm = (cfg.d_model * (inner + conv + cfg.mamba_n_heads)  # in_proj
              + inner * cfg.d_model  # out_proj
@@ -2234,8 +2374,17 @@ def flops_per_token(cfg: LlamaConfig) -> float:
              + hv * gd * cfg.d_model + mlp)
     rule = (hv * (10 * GDN_CHUNK * gd + 6 * gd * gd)
             + 2 * cfg.gdn_d_conv * cfg.gdn_conv_dim)
+    alone = 0.0  # the layers whose one branch is an MLP
+    if cfg.one_branch:
+        expert = mats * cfg.d_model * cfg.expert_width
+        routed = (cfg.d_model * cfg.num_experts
+                  + cfg.n_shared_experts * expert
+                  + cfg.top_k * cfg.experts_here / max(cfg.num_experts, 1)
+                  * expert)
+        alone = (cfg.layer_types.count("mlp") * mats * cfg.d_model * cfg.d_ff
+                 + cfg.moe_layers * routed)
     return (6.0 * (dense + cfg.ssm_layers * p_ssm + cfg.conv_layers * p_conv
-                   + cfg.gdn_layers * p_gdn)
+                   + cfg.gdn_layers * p_gdn + alone)
             + 6.0 * attn
             + 3.0 * (cfg.ssm_layers * scan + cfg.conv_layers * taps
                      + cfg.gdn_layers * rule))

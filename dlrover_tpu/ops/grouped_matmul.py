@@ -31,13 +31,33 @@ from dlrover_tpu.ops.per_shard import free_axes
 TILING = (512, 1024, 1024)
 
 
+def kernel_takes(dtype, m: int, k: int, n: int) -> bool:
+    """Whether the kernel's tiling divides ``[m, k] x [groups, k, n]``:
+    whole row tiles of bf16, and a contraction and columns of whole
+    128-lane vectors.  An expert 1,856 wide (14.5 x 128) is not taken, in
+    either of its matmuls: ``lax.ragged_dot`` runs it, unpadded."""
+    return (dtype == jnp.bfloat16 and m % TILING[0] == 0
+            and k % 128 == 0 and n % 128 == 0)
+
+
 def _kernel_fits(tokens: jax.Array, weights: jax.Array) -> bool:
-    """The kernel tiles whole blocks of bf16 rows, and GSPMD cannot
-    partition a Mosaic kernel: under a mesh with a free axis the
-    reference goes, which the partitioner splits itself."""
-    (m, k), n = tokens.shape, weights.shape[2]
-    return (tokens.dtype == jnp.bfloat16 and m % TILING[0] == 0
-            and k % 128 == 0 and n % 128 == 0 and not free_axes()[0])
+    """:func:`kernel_takes`, and GSPMD cannot partition a Mosaic kernel:
+    under a mesh with a free axis the reference goes, which the
+    partitioner splits itself."""
+    return (kernel_takes(tokens.dtype, *tokens.shape, weights.shape[2])
+            and not free_axes()[0])
+
+
+def backend_for(dtype, k: int, n: int) -> str:
+    """The backend :func:`grouped_matmul_ragged` chooses for itself on this
+    device for an expert's two matmuls, ``[rows, k] x [k, n]`` and ``[rows,
+    n] x [n, k]``, at a buffer of whole row tiles outside a mesh's free
+    axes: what ``models.llama.program_facts`` journals as
+    ``moe_expert_backend``."""
+    # the rule is the same with ``k`` and ``n`` exchanged
+    fits = kernel_takes(dtype, TILING[0], k, n)
+    return "pallas" if jax.default_backend() == "tpu" and fits else (
+        "reference")
 
 
 def grouped_matmul_ragged(

@@ -57,7 +57,9 @@ def _rmsnorm(x, w, eps, use_pallas, interpret):
     if use_pallas:
         shape = x.shape
         D = shape[-1]
-        block_rows = max(8, min(512, (4 << 20) // max(1, D * 4)))
+        # whole sublane tiles: 2,688 columns would give 390 rows, which
+        # Mosaic refuses
+        block_rows = max(8, min(512, (4 << 20) // max(1, D * 4)) // 8 * 8)
         out = _pallas_fwd(
             x.reshape(-1, D), w, eps, block_rows, interpret
         )

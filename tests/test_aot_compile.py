@@ -67,7 +67,8 @@ FLASH_BWD = {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
 #: ``lax.ragged_dot``, to Mosaic kernels too: not ours to count)
 OURS = set(FLASH_BWD) | {
     "rmsnorm_fwd", "softmax_xent_fwd", "quantize_blockwise",
-    "gdn_chunk_fwd", "gdn_chunk_bwd", "conv_silu_fwd", "conv_silu_bwd"}
+    "gdn_chunk_fwd", "gdn_chunk_bwd", "conv_silu_fwd", "conv_silu_bwd",
+    "ssd_chunk_fwd", "ssd_chunk_bwd"}
 
 
 def _sq(x):
@@ -104,7 +105,9 @@ def _flash(case, grad):
     return fn, shapes, FLASH_BWD if grad else {"flash_fwd": 1}
 
 
-def _rmsnorm(grad):
+def _rmsnorm(grad, width=2048):
+    """``width`` 2,688 (21 lane tiles) is the stream whose 4 MB row block
+    is 390 rows before it is cut to whole sublane tiles."""
     from dlrover_tpu.ops.rmsnorm import rmsnorm
 
     def fwd(x, w):
@@ -115,7 +118,7 @@ def _rmsnorm(grad):
         def fn(x, w):
             return jax.value_and_grad(
                 lambda x, w: _sq(fwd(x, w)), argnums=(0, 1))(x, w)
-    return (fn, [((4 * 2048, 2048), bf16), ((2048,), bf16)],
+    return (fn, [((4 * 2048, width), bf16), ((width,), bf16)],
             {"rmsnorm_fwd": 1})
 
 
@@ -218,6 +221,59 @@ def _conv_silu(grad, channels, bias):
             else {"conv_silu_fwd": 1})
 
 
+def _ssd(grad):
+    """The scan's kernel pair at the one-branch hybrid cell's shapes: 64
+    heads of 64 in EIGHT groups (a block is a group's 8 heads, 512 lanes),
+    a state of 128, chunks of 128 positions."""
+    from dlrover_tpu.ops.ssd import ssd_chunked
+
+    def fwd(x, dt, a, b, c, d):
+        return ssd_chunked(x, dt, a, b, c, 128, D=d, backend="pallas")
+
+    fn = fwd
+    if grad:
+        def fn(*ops):
+            return jax.grad(lambda *o: sum(_sq(x) for x in fwd(*o)[:2]),
+                            argnums=range(6))(*ops)
+    group = ((2, 8192, 8, 128), bf16)
+    return (fn, [((2, 8192, 64, 64), bf16), ((2, 8192, 64), f32),
+                 ((64,), f32), group, group, ((64,), f32)],
+            {"ssd_chunk_fwd": 1, "ssd_chunk_bwd": 1} if grad
+            else {"ssd_chunk_fwd": 1})
+
+
+def _flash_gqa16(grad):
+    """32 query heads on 2 key heads of 128 (a group of 16), 8,192
+    positions, no window."""
+    def fwd(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, backend="pallas")
+
+    fn = fwd
+    if grad:
+        def fn(q, k, v):
+            return jax.grad(lambda q, k, v: _sq(fwd(q, k, v)),
+                            argnums=(0, 1, 2))(q, k, v)
+    kv = ((2, 2, 8192, 128), bf16)
+    return (fn, [((2, 32, 8192, 128), bf16), kv, kv],
+            FLASH_BWD if grad else {"flash_fwd": 1})
+
+
+def _grouped_matmul_1856(backend):
+    """An expert 1,856 wide (14.5 lane tiles), both of its matmuls'
+    shapes, forward and both gradients, by ``lax.ragged_dot``: the backend
+    ``ops.grouped_matmul`` chooses for this width."""
+    from dlrover_tpu.ops.grouped_matmul import grouped_matmul_ragged
+
+    def fn(x, up, down, sizes):
+        def both(x, up, down):
+            h = grouped_matmul_ragged(x, up, sizes, backend=backend)
+            return _sq(grouped_matmul_ragged(h, down, sizes,
+                                             backend=backend))
+        return jax.grad(both, argnums=(0, 1, 2))(x, up, down)
+    return (fn, [((15360, 2688), bf16), ((8, 2688, 1856), bf16),
+                 ((8, 1856, 2688), bf16), ((8,), i32)], {})
+
+
 def _bwd_block_q_128():
     """Round 4's hand record has this tuning point stalling the device for
     900 s.  The compiler accepts it — so that was a run-time matter, and
@@ -252,6 +308,15 @@ KERNEL_CASES = {
     "conv_silu_gdn-grad": lambda: _conv_silu(True, 8192, False),
     "conv_silu_ssm-fwd": lambda: _conv_silu(False, 4352, True),
     "conv_silu_ssm-grad": lambda: _conv_silu(True, 4352, True),
+    "rmsnorm_2688-fwd": lambda: _rmsnorm(False, 2688),
+    "rmsnorm_2688-grad": lambda: _rmsnorm(True, 2688),
+    "conv_silu_ssm_6144-fwd": lambda: _conv_silu(False, 6144, True),
+    "conv_silu_ssm_6144-grad": lambda: _conv_silu(True, 6144, True),
+    "ssd_groups8_chunk128-fwd": lambda: _ssd(False),
+    "ssd_groups8_chunk128-grad": lambda: _ssd(True),
+    "flash_gqa_32_on_2-fwd": lambda: _flash_gqa16(False),
+    "flash_gqa_32_on_2-bwd": lambda: _flash_gqa16(True),
+    "grouped_matmul_1856-grad": lambda: _grouped_matmul_1856(None),
 }
 
 

@@ -1,0 +1,107 @@
+"""The one-branch hybrid cell's step (``nemotron3_nano_30b_a3b-l9.
+train-decayed``: published layers 0-8 of Nemotron-3-Nano-30B-A3B, 8 of 128
+experts held, an eighth of the vocabulary) compiled ahead of time for ONE
+described v5e at FULL depth, from shapes: the number behind the cell's
+``batch_sequences`` and its ``why``.  A file of its own, so that the two
+whole-depth compiles run beside ``tests/test_aot_compile.py``'s and not
+behind them.
+
+A compile that passes is not a chip run: nothing here says anything about
+results or times.
+"""
+
+import pytest
+from test_aot_compile import _step_and_text, topo  # noqa: F401
+
+#: ``bytes_limit`` of one v5e chip as ``memory_stats()`` reported it (PR 21)
+V5E_BYTES_LIMIT = 16_909_336_064
+
+
+def _cell_step(topo, sequences):  # noqa: F811
+    from dlrover_tpu.models import llama
+
+    kinds = {"M": "mamba", "*": "attention", "E": "moe"}
+    cfg = llama.LlamaConfig(
+        vocab_size=16384, n_layer=9, n_head=32, n_kv_head=2, d_model=2688,
+        d_ff=1856, max_seq_len=8192, rms_eps=1e-5, remat_block=True,
+        one_branch=True, mlp_form="relu2",
+        layer_types=tuple(kinds[c] for c in "MEMEM*EME"),
+        mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128,
+        mamba_n_groups=8, mamba_chunk_size=128, rope=False,
+        attn_head_dim=128, num_experts=128, top_k=6, d_ff_expert=1856,
+        n_shared_experts=2, router_score="sigmoid", routed_scaling=2.5,
+        router_bias_rate=1e-3, balance_all_k=True, experts_held=8)
+
+    def loss(params, batch):
+        return llama.loss_fn(params, batch, cfg, moe_aux_weight=1e-4,
+                             metrics=True)
+
+    loss.rule_leaves = llama.rule_leaves(cfg)
+    loss.program_facts = llama.program_facts(cfg, 8192)
+    return (*_step_and_text(topo, loss, cfg, sequences, 8192), cfg)
+
+
+@pytest.fixture(scope="module")
+def step_at_three(topo):  # noqa: F811
+    return _cell_step(topo, 3)
+
+
+def test_the_cell_fits_at_three_sequences_with_a_twentieth_free(
+        step_at_three):
+    """Three sequences of 8,192: XLA's buffer assignment peaks at 14.89 GB,
+    11.9 % of ``bytes_limit`` free (the issue's rule: the largest of 4, 3,
+    2 that leaves at least 5 %)."""
+    job, _, _ = step_at_three
+    peak = job.memory["peak_bytes"]
+    assert peak <= 0.95 * V5E_BYTES_LIMIT, peak
+    assert 14.0e9 < peak < 15.3e9, peak  # 14,891,292,160 when written
+
+
+def test_the_cell_at_three_runs_the_layers_by_kind(step_at_three):
+    """Four Mamba-2 layers, one attention layer, four routed ones: under
+    block remat the scan's and the convolution's forward kernels twice a
+    layer and their backward once, flash in ONE layer; the experts' width
+    of 1,856 goes to ``lax.ragged_dot`` (no ``gmm``, no ``tgmm``), which the
+    program says of itself; the routed layers choose between 11,776 rows
+    and all 147,456; the mixer's five scopes and the routed block's are
+    named in every phase."""
+    from dlrover_tpu.models import llama
+
+    job, _, cfg = step_at_three
+    program, kernels = job.program, job.program["kernels"]
+    assert (program["ssm_layers"], program["attention_layers"],
+            program["moe_layers"], program["ssm_chunks_per_sequence"],
+            program["block_applications"]) == (4, 1, 4, 64, 1)
+    assert (program["mlp_form"], program["moe_expert_backend"]) == (
+        "relu2", "reference")
+    assert (kernels["ssd_chunk_fwd"], kernels["ssd_chunk_bwd"],
+            kernels["conv_silu_fwd"], kernels["conv_silu_bwd"]) == (
+                8, 4, 8, 4)
+    assert (kernels["flash_fwd"], kernels["flash_bwd_dq"],
+            kernels["flash_bwd_dkv"]) == (1, 1, 1)
+    assert "gmm" not in kernels and "tgmm" not in kernels
+    assert llama._moe_buffer_bounds(3 * 8192, 6, 128, 8) == (11776, 147456)
+    found = {tuple(v) for v in program["scopes"].values()}
+    assert {("forward", "ssm"), ("backward", "ssm"), ("recompute", "ssm"),
+            ("forward", "attention"), ("backward", "moe_experts"),
+            ("recompute", "moe_experts"), ("forward", "moe_shared"),
+            ("forward", "lm_head_loss")} <= found
+    by_inner = {}
+    for name, inner in program["subscopes"].items():
+        by_inner.setdefault(inner, set()).add(program["scopes"][name][0])
+    for inner in ("ssm_in", "ssm_scan", "ssm_gate"):
+        assert {"forward", "backward", "recompute"} <= by_inner[inner], inner
+    # nothing of a layer's backward reads ``out_proj``'s product again
+    assert {"forward", "backward"} <= by_inner["ssm_out"]
+    # going forward the convolution is its kernel alone, which the table
+    # names by the kernel
+    assert "backward" in by_inner["ssm_conv"]
+    assert {"forward", "recompute"} <= by_inner["conv_silu_fwd"]
+
+
+def test_the_cell_at_four_sequences_leaves_under_a_twentieth(topo):  # noqa: F811
+    """The next larger batch: 16.23 GB at the peak, 4.0 % free — under the
+    rule's 5 %, so the cell runs three."""
+    job, _, _ = _cell_step(topo, 4)
+    peak = job.memory["peak_bytes"]
+    assert 0.95 * V5E_BYTES_LIMIT < peak, peak

@@ -500,11 +500,24 @@ def test_initialisation_is_the_mixers_own():
     (dict(mtp_layers=1), "mtp_layers=1"),
     (dict(qk_norm=False), "qk_norm_per_head"),
     (dict(layer_types=("mamba", "attention", "conv"), mamba_n_heads=8,
-          mamba_d_head=8, mamba_d_state=16), "beside a 'mamba' one not yet"),
+          mamba_d_head=8, mamba_d_state=0), "mamba_d_state"),
 ])
 def test_config_refuses_what_is_not_built(over, match):
     with pytest.raises(ValueError, match=match):
         _lfm(**over)
+
+
+def test_experts_may_follow_all_three_kinds():
+    """A 'mamba' mixer beside this config's experts was refused by name
+    ("beside a 'mamba' one not yet") until the one-branch hybrid needed the
+    pair: it builds, and the routed layers are ``is_moe_layer``'s whatever
+    the mixer."""
+    cfg = _lfm(layer_types=("mamba", "attention", "conv"), mamba_n_heads=8,
+               mamba_d_head=8, mamba_d_state=16)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    assert [("moe" in layer) for layer in params["layers"]] == [
+        cfg.is_moe_layer(i) for i in range(3)]
+    assert "ssm" in params["layers"][0] and "conv" in params["layers"][2]
 
 
 def test_a_dense_stack_may_mix_all_three_kinds():

@@ -232,6 +232,35 @@ def test_the_scan_through_the_kernels_equals_the_recurrence(chunk, groups,
         assert _rel(g, w) < (5e-5 if name == "A" else 2e-5), name
 
 
+@pytest.mark.parametrize("via", ["numpy", "kernels"])
+def test_the_scan_at_eight_groups_of_eight_heads_equals_the_recurrence(via):
+    """64 heads of 64 in EIGHT groups, a state of 128, chunks of 128 (the
+    one-branch hybrid cell's shapes; every other cell has one group): a
+    grid step of the kernels takes a group's 8 heads, 512 lanes.  Values and
+    every gradient against ``ssd_sequential`` at 2e-5, float32, on a
+    sequence the chunk does not divide."""
+    ops = _scan_operands(128, 8, 64, F32, heads=8)
+    assert ssd._kernel_heads(128, 8, 64, 128) == 8
+    weights = jax.random.normal(jax.random.PRNGKey(9), ops[0].shape)
+    kw = KERNELS if via == "kernels" else {}
+    chunked = lambda x, dt, a, b, c, d: ssd.ssd_chunked(  # noqa: E731
+        x, dt, a, b, c, 128, D=d, **kw)
+    sequential = lambda x, dt, a, b, c, d: ssd.ssd_sequential(  # noqa: E731
+        x, dt, a, b, c, D=d)
+    y, state, _ = chunked(*ops)
+    y_seq, state_seq = sequential(*ops)
+    assert _rel(y, y_seq) < 2e-5 and _rel(state, state_seq) < 2e-5
+    # a head reads ITS group's B and C: with the groups rolled by one the
+    # result is another
+    rolled = ops[:3] + (jnp.roll(ops[3], 1, axis=2),
+                        jnp.roll(ops[4], 1, axis=2)) + ops[5:]
+    assert _rel(chunked(*rolled)[0], y_seq) > 1e-1
+    got, want = (jax.grad(_scan_loss(f, weights), argnums=tuple(range(6)))(
+        *ops) for f in (chunked, sequential))
+    for name, g, w in zip(("x", "dt", "A", "B", "C", "D"), got, want):
+        assert _rel(g, w) < (5e-5 if name == "A" else 2e-5), name
+
+
 @pytest.mark.parametrize("chunk,groups,head", SHAPES[2:6])
 def test_the_kernels_take_bf16_operands_and_accumulate_in_float32(
         chunk, groups, head):
@@ -729,11 +758,11 @@ def test_initialisation_is_the_mixers_own():
 @pytest.mark.parametrize("over,match", [
     (dict(layer_types=("mamba", "attention")), "n_layer=3"),
     (dict(layer_types=("mamba", "mamba", "linear")), "layer_types"),
-    (dict(mamba_n_heads=7), "mamba_n_heads"),
+    (dict(mamba_n_heads=0), "mamba_n_heads"),
     (dict(mamba_d_state=0), "mamba_d_state"),
     (dict(mamba_n_groups=3), "mamba_n_groups=3"),
     (dict(mamba_proj_bias=True), "mamba_proj_bias"),
-    (dict(num_experts=4), "num_experts=4"),
+    (dict(mamba_d_head=0), "mamba_d_head"),
     (dict(loop_passes=2, exit_gate_beta=0.1), "loop_passes=2"),
     (dict(mtp_layers=1), "mtp_layers=1"),
 ])
