@@ -764,6 +764,21 @@ def _row_gathers(text, width, only=""):
     return found
 
 
+def _sized_branch_holds_no_pick_sized_array(text, n, k, c):
+    """No instruction of the sized buffer's branch of a routed block has
+    ``n * k`` rows by ``c`` columns in any arrangement, forward, recomputed
+    or backward."""
+    picks = {f"[{n * k},{c}]", f"[{n},{k},{c}]", f"[{k},{n},{c}]"}
+    seen = 0
+    for line in text.splitlines():
+        if "branch_0_fun" not in line or " = " not in line:
+            continue
+        seen += 1
+        shapes = line.split(" = ", 1)[1].split("metadata=", 1)[0]
+        assert not picks & set(re.findall(r"\[[\d,]+\]", shapes)), line[:200]
+    assert seen > 100  # the branch's instructions were there to be read
+
+
 @pytest.fixture(scope="module")
 def conv_step(topo):
     """``(job, compiled text, cfg)`` of the LFM2 cell's step from shapes,
@@ -854,15 +869,7 @@ def test_conv_step_token_side_builds_no_pick_sized_array(conv_step):
     assert (kernels["gmm"], kernels["tgmm"]) == (2 * 9, 2 * 3)
     assert kernels["gather_sum"] == 2 * 2 * 2
     n, k, c = 4 * 8192, cfg.top_k, cfg.d_model
-    picks = {f"[{n * k},{c}]", f"[{n},{k},{c}]", f"[{k},{n},{c}]"}
-    seen = 0
-    for line in text.splitlines():
-        if "branch_0_fun" not in line or " = " not in line:
-            continue
-        seen += 1
-        shapes = line.split(" = ", 1)[1].split("metadata=", 1)[0]
-        assert not picks & set(re.findall(r"\[[\d,]+\]", shapes)), line[:200]
-    assert seen > 100  # the branch's instructions were there to be read
+    _sized_branch_holds_no_pick_sized_array(text, n, k, c)
     gathers = {("forward", "moe_permute"): 2, ("recompute", "moe_permute"): 2,
                ("backward", "moe_combine"): 2}
     for branch, rows in (("branch_0_fun", 40960), ("branch_1_fun", n * k)):
@@ -962,6 +969,18 @@ def test_gdn_step_sizes_its_sorted_buffer_and_keeps_the_rule_in_vmem(
             assert [int(d) for d in dims.split(",")][-2:] != [q, q], line[:200]
     assert seen > 100  # the scope's instructions were there to be read
     assert job.memory["peak_bytes"] < 14_553_000_000
+
+
+def test_gdn_step_token_side_builds_no_pick_sized_array(gdn_step):
+    """Ten picks a token and 12,800 rows: ``gather_sum`` takes any K, so
+    the kernel runs twice a routed block in each size's branch (the combine
+    forward, the dispatch's transpose backward) and no instruction of the
+    sized buffer's branch has 163,840 rows by 2,048 columns in any
+    arrangement."""
+    job, text, cfg = gdn_step
+    assert job.program["kernels"]["gather_sum"] == 2 * 2 * 2
+    _sized_branch_holds_no_pick_sized_array(
+        text, 2 * 8192, cfg.top_k, cfg.d_model)
 
 
 def test_gdn_step_keeps_the_convolutions_float32_in_vmem(gdn_step):

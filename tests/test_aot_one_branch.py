@@ -11,7 +11,8 @@ results or times.
 """
 
 import pytest
-from test_aot_compile import _step_and_text, topo  # noqa: F401
+from test_aot_compile import (  # noqa: F401
+    _sized_branch_holds_no_pick_sized_array, _step_and_text, topo)
 
 #: ``bytes_limit`` of one v5e chip as ``memory_stats()`` reported it (PR 21)
 V5E_BYTES_LIMIT = 16_909_336_064
@@ -50,11 +51,14 @@ def test_the_cell_fits_at_three_sequences_with_a_twentieth_free(
         step_at_three):
     """Three sequences of 8,192: XLA's buffer assignment peaks at 14.89 GB,
     11.9 % of ``bytes_limit`` free (the issue's rule: the largest of 4, 3,
-    2 that leaves at least 5 %)."""
+    2 that leaves at least 5 %).  The peak is not the routed layers': with
+    their ``[N*K, C]`` arrays gone (PR 56) it reads what it read."""
     job, _, _ = step_at_three
     peak = job.memory["peak_bytes"]
     assert peak <= 0.95 * V5E_BYTES_LIMIT, peak
-    assert 14.0e9 < peak < 15.3e9, peak  # 14,891,292,160 when written
+    # 14,891,308,544 with ``gather_sum`` on the token side; 14,891,292,160
+    # with XLA's gathers (PR 55)
+    assert 14.6e9 < peak < 15.1e9, peak
 
 
 def test_the_cell_at_three_runs_the_layers_by_kind(step_at_three):
@@ -81,6 +85,12 @@ def test_the_cell_at_three_runs_the_layers_by_kind(step_at_three):
             kernels["flash_bwd_dkv"]) == (1, 1, 1)
     assert "gmm" not in kernels and "tgmm" not in kernels
     assert llama._moe_buffer_bounds(3 * 8192, 6, 128, 8) == (11776, 147456)
+    # the token side at 21 lane tiles and six picks: the kernel twice a
+    # routed layer in each size's branch, and nothing of 147,456 rows by
+    # 2,688 columns in the sized one's
+    assert kernels["gather_sum"] == 4 * 2 * 2
+    _sized_branch_holds_no_pick_sized_array(
+        step_at_three[1], 3 * 8192, cfg.top_k, cfg.d_model)
     found = {tuple(v) for v in program["scopes"].values()}
     assert {("forward", "ssm"), ("backward", "ssm"), ("recompute", "ssm"),
             ("forward", "attention"), ("backward", "moe_experts"),
@@ -101,7 +111,8 @@ def test_the_cell_at_three_runs_the_layers_by_kind(step_at_three):
 
 def test_the_cell_at_four_sequences_leaves_under_a_twentieth(topo):  # noqa: F811
     """The next larger batch: 16.23 GB at the peak, 4.0 % free — under the
-    rule's 5 %, so the cell runs three."""
+    rule's 5 %, so the cell runs three (16,226,880,000 with ``gather_sum``
+    on the token side, 16,231,708,672 with XLA's gathers)."""
     job, _, _ = _cell_step(topo, 4)
     peak = job.memory["peak_bytes"]
     assert 0.95 * V5E_BYTES_LIMIT < peak, peak

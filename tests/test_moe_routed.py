@@ -491,42 +491,111 @@ def test_combine_rows_check_grads():
 
 @pytest.fixture
 def small_tiles(monkeypatch):
-    """32 picks a grid step: several steps at a toy token count."""
-    monkeypatch.setattr(gather_sum_op, "_TILE_PICKS", 32)
+    """Result tiles of 16 tokens and 8 rows in flight: several tiles, and
+    several chunks a tile, at a toy token count."""
+    monkeypatch.setattr(gather_sum_op, "_TILE_TOKENS", 16)
+    monkeypatch.setattr(gather_sum_op, "_CHUNK", 8)
 
 
-@pytest.mark.parametrize("share", [False, True], ids=["all_held", "share"])
-@pytest.mark.parametrize("k", [1, 4, 8])
-def test_gather_sum_kernel_is_its_reference_bit_for_bit(small_tiles, k, share):
-    """The Pallas kernel in interpret mode: the dead picks start no DMA
-    and contribute a selected zero (their rows hold NaN, and the buffer
-    they would have landed in is unwritten)."""
-    rows, weights, _, inverse, _ = _token_side(
-        k, share, jnp.bfloat16, n=64, c=1024)
-    index = inverse.reshape(weights.shape)
+def _assert_kernel_is_reference(rows, index, weights):
     assert gather_sum_op._kernel_fits(rows, index)
     want = gather_sum_op.gather_sum(rows, index, weights, backend="reference")
     got = gather_sum_op.gather_sum(rows, index, weights, backend="pallas",
                                    interpret=True)
+    assert got.shape == want.shape == (index.shape[0], rows.shape[1])
     assert np.isfinite(np.asarray(got, np.float32)).all()
     assert (got == want).all()
+    return got
 
 
-@pytest.mark.parametrize("case", ["float32", "narrow", "ragged_tile", "mesh"])
+# 48 tokens: three result tiles, and no multiple of the 32 // k tokens the
+# kernel's tile was when it followed k
+@pytest.mark.parametrize("n", [64, 48])
+@pytest.mark.parametrize("c", [1024, 384], ids=["8_lane_tiles",
+                                                "3_lane_tiles"])
+@pytest.mark.parametrize("share", [False, True, "every_pick"],
+                         ids=["all_held", "share", "share_every_pick"])
+@pytest.mark.parametrize("k", [1, 4, 6, 8, 10])
+def test_gather_sum_kernel_is_its_reference_bit_for_bit(small_tiles, k, share,
+                                                        c, n):
+    """The Pallas kernel in interpret mode, at any K and any whole number
+    of lane tiles: the dead picks start no DMA and contribute a selected
+    zero (their rows hold NaN); with every expert held, and in the buffer
+    a skewed step falls back to (``R = N*K``), a tile's picks are all live
+    and take several chunks of rows in flight."""
+    rows, weights, _, inverse, _ = _token_side(
+        k, share, jnp.bfloat16, n=n, c=c)
+    _assert_kernel_is_reference(rows, inverse.reshape(weights.shape), weights)
+
+
+def _live_in(tokens, n, k, c=384, seed=5):
+    """``(rows, index, weights)`` with every pick of ``tokens`` live, each
+    on a row of its own, and every other pick dead on a row of NaN."""
+    rng = np.random.RandomState(seed)
+    live = np.zeros((n, k), bool)
+    live[tokens] = True
+    r = int(live.sum()) + 3
+    index = np.full((n, k), r - 1, np.int32)
+    index[live] = rng.permutation(r - 3)
+    rows = rng.randn(r, c)
+    rows[r - 3:] = np.nan
+    weights = np.where(live, rng.rand(n, k) + 0.1, 0.0)
+    return (jnp.asarray(rows, jnp.bfloat16), jnp.asarray(index),
+            jnp.asarray(weights, jnp.bfloat16))
+
+
+@pytest.mark.parametrize("tokens", [
+    slice(16, 32), slice(40, 41), slice(0, 1), slice(63, 64), slice(0, 0)],
+    ids=["one_tile_whole", "one_token", "first_token", "last_token", "none"])
+def test_gather_sum_kernel_where_the_live_picks_crowd(small_tiles, tokens):
+    """What a loop over the live picks can get wrong: every live pick of
+    the call in ONE tile of tokens (96 of them, twelve chunks of rows in
+    flight, the tiles around it none), a token whose K picks are all live
+    and no other, the first and the last token of the call, and a call
+    with no live pick at all.  A tile without a live pick is zeros, and the
+    rows of NaN are never read."""
+    rows, index, weights = _live_in(tokens, n=64, k=6)
+    got = np.asarray(_assert_kernel_is_reference(rows, index, weights),
+                     np.float32)
+    dead = np.ones(64, bool)
+    dead[tokens] = False
+    assert not got[dead].any() and got[~dead].all()
+
+
+def test_gather_sum_kernel_sums_a_token_in_ascending_k(small_tiles):
+    """One token, its K picks all live on rows that cancel unless they
+    are added in ascending k: ``(big + small) - big`` in float32."""
+    values = (2.0 ** 20, 1.0, -(2.0 ** 20), 2.0 ** -8)
+    k = len(values)
+    rows = jnp.asarray(np.stack([np.full(256, v) for v in values]),
+                       jnp.bfloat16)
+    index = jnp.zeros((16, k), jnp.int32).at[3].set(jnp.arange(k))
+    weights = jnp.zeros((16, k), jnp.bfloat16).at[3].set(1.0)
+    got = _assert_kernel_is_reference(rows, index, weights)
+    acc = np.float32(0)
+    for v in values:
+        acc = np.float32(acc + np.float32(v))
+    assert float(got[3, 0]) == float(jnp.asarray(acc, jnp.bfloat16))
+
+
+@pytest.mark.parametrize("case", ["float32", "narrow", "ragged_tile", "mesh",
+                                  "k_10", "21_lane_tiles", "three_tiles"])
 def test_gather_sum_kernel_chooses_itself_by_shape(small_tiles, case):
-    """bfloat16 rows of whole 1,024-column tiles, whole tiles of tokens, no
-    free mesh axis: anything else is the ``jax.numpy`` form."""
+    """bfloat16 rows of whole 128-column lane tiles, a power of two of at
+    least 8 tokens that divides the token count, no free mesh axis:
+    anything else is the ``jax.numpy`` form.  K is no part of the rule."""
     dtype = jnp.float32 if case == "float32" else jnp.bfloat16
-    rows = jnp.zeros((16, 256 if case == "narrow" else 1024), dtype)
-    index = jnp.zeros((60 if case == "ragged_tile" else 64, 4), jnp.int32)
+    width = {"narrow": 192, "21_lane_tiles": 2688}.get(case, 1024)
+    tokens = {"ragged_tile": 60, "three_tiles": 24576}.get(case, 64)
+    rows = jnp.zeros((16, width), dtype)
+    index = jnp.zeros((tokens, 10 if case == "k_10" else 4), jnp.int32)
     if case == "mesh":
         from dlrover_tpu.parallel.mesh import MeshSpec, build_mesh
 
         with jax.set_mesh(build_mesh(MeshSpec(fsdp=2), jax.devices()[:2])):
             assert not gather_sum_op._kernel_fits(rows, index)
-        assert gather_sum_op._kernel_fits(rows, index)
-    else:
-        assert not gather_sum_op._kernel_fits(rows, index)
+    fits = case in ("mesh", "k_10", "21_lane_tiles", "three_tiles")
+    assert gather_sum_op._kernel_fits(rows, index) == fits
 
 
 @pytest.mark.parametrize("held", [8, 2], ids=["all_held", "share"])
