@@ -399,7 +399,7 @@ class AsyncCheckpointSaver:
         leave restorable FULL shards, not orphan slices) and refs
         tensors whose dirty fence has not tripped since their holder
         step."""
-        with span("ckpt.persist.write", "ckpt", step=step) as sp:
+        with span("ckpt.persist.write", "ckpt", host=True, step=step) as sp:
             stats = self._write_shard(
                 ckpt_dir, step, pid, tensors, extra, lr, sliced, world)
             sp.set(bytes=int(stats["total_bytes"]),

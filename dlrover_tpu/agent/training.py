@@ -27,6 +27,7 @@ import sys
 import threading
 import time
 import uuid
+from collections import deque
 from typing import Dict, List, Optional
 
 from dlrover_tpu.agent.master_client import MasterClient
@@ -44,7 +45,14 @@ from dlrover_tpu.common.jax_env import (
 )
 from dlrover_tpu.common.log import logger
 from dlrover_tpu.common.rpc import find_free_port, local_ip
-from dlrover_tpu.obs import ENV_DIR, ENV_PROCESS, get_recorder, span
+from dlrover_tpu.obs import (
+    ENV_DIR,
+    ENV_PARENT,
+    ENV_PROCESS,
+    current_span_id,
+    get_recorder,
+    span,
+)
 
 
 @dataclasses.dataclass
@@ -108,6 +116,84 @@ class RunResult:
     STOP_JOB = "stop_job"
     RESTART_REQUESTED = "restart_requested"
     RELAUNCH_REQUESTED = "relaunch_requested"
+
+
+class MonitorWatch:
+    """What one call of ``ElasticTrainingAgent._monitor`` did with its
+    time: the ``args`` of its ``agent.monitor`` span.  Each turn of the
+    loop is three parts — its sleep, its polls of the workers, its
+    question to the master — and a monotonic stamp closes each; the sums
+    live here and nothing is recorded per turn (the span is journalled
+    once, as ``_monitor`` returns; a record a second for the life of a job
+    is not low-rate).
+
+    ``unseen_s`` runs from the end of the newest poll pass in which no
+    worker had a non-zero exit code (the entry to ``_monitor`` if none)
+    to the end of the pass that saw one: the most the agent itself can
+    have sat on a worker the kernel had already made waitable.  Near one
+    interval, with ``busy_max_s`` (the longest ``poll + rpc`` of one turn:
+    how long the loop was away from its sleep) near 0, the loop is sound
+    and whatever else lies between a worker's death and the agent's
+    notice of it is a process that polled as alive."""
+
+    PARTS = 3  # sleep, poll, rpc
+
+    def __init__(self):
+        self._at = self._clean_at = time.monotonic()
+        self._turn: List[float] = []  # the open turn's parts so far
+        self._last: deque = deque(maxlen=4)  # the last turns' parts
+        self.turns = 0
+        self.sums = [0.0] * self.PARTS
+        self.turn_max_s = self.busy_max_s = 0.0
+        self.rpc_errors = 0
+        self.unseen_s: Optional[float] = None
+
+    def _stamp(self) -> float:
+        now = time.monotonic()
+        self._turn.append(now - self._at)
+        self._at = now
+        return now
+
+    def slept(self) -> None:
+        self._stamp()
+
+    def polled(self, failed: bool) -> None:
+        now = self._stamp()
+        if failed:
+            self.unseen_s = now - self._clean_at
+        else:
+            self._clean_at = now
+
+    def asked(self, error: bool) -> None:
+        self._stamp()
+        self.rpc_errors += error
+        self._close_turn()
+
+    def _close_turn(self) -> None:
+        """A turn that returned before its later parts has them as 0."""
+        if not self._turn:
+            return
+        parts = self._turn + [0.0] * (self.PARTS - len(self._turn))
+        self._turn = []
+        self.turns += 1
+        self.sums = [a + b for a, b in zip(self.sums, parts)]
+        self.turn_max_s = max(self.turn_max_s, sum(parts))
+        self.busy_max_s = max(self.busy_max_s, parts[1] + parts[2])
+        self._last.append(parts)
+
+    def args(self) -> dict:
+        self._close_turn()
+        sleep_s, poll_s, rpc_s = (round(x, 6) for x in self.sums)
+        out = {
+            "turns": self.turns, "sleep_s": sleep_s, "poll_s": poll_s,
+            "rpc_s": rpc_s, "turn_max_s": round(self.turn_max_s, 6),
+            "busy_max_s": round(self.busy_max_s, 6),
+            "rpc_errors": self.rpc_errors,
+            "last_turns": [[round(x, 6) for x in t] for t in self._last],
+        }
+        if self.unseen_s is not None:
+            out["unseen_s"] = round(self.unseen_s, 6)
+        return out
 
 
 class ElasticTrainingAgent:
@@ -447,6 +533,9 @@ class ElasticTrainingAgent:
                 env[ENV_DIR] = get_recorder().out_dir
             env[ENV_PROCESS] = (
                 f"worker-r{base + lr}-i{self._restart_count}")
+            # ... and the span that starts it (agent.start_workers), the
+            # parent of the worker's bootstrap: one tree per restart
+            env[ENV_PARENT] = current_span_id()
             log_file = None
             stdout = stderr = None
             if cfg.log_dir:
@@ -508,9 +597,24 @@ class ElasticTrainingAgent:
 
     # -- monitor loop (reference training.py:886) ---------------------------
     def _monitor(self) -> str:
+        """One ``agent.monitor`` span a call, closed as it returns (and
+        journalled then: it is there when the agent is killed a moment
+        later); ``agent.restart`` starts where it ends."""
+        watch = MonitorWatch()
+        result = ""
+        sp = span("agent.monitor", "agent",
+                  interval=self.config.monitor_interval).start()
+        try:
+            result = self._watch_workers(watch)
+            return result
+        finally:
+            sp.end(result=str(result), **watch.args())
+
+    def _watch_workers(self, watch: MonitorWatch) -> str:
         cfg = self.config
         while True:
             time.sleep(cfg.monitor_interval)
+            watch.slept()
             # 1. master-pushed actions (via heartbeat thread)
             action = self._pending_action
             self._pending_action = None
@@ -522,9 +626,11 @@ class ElasticTrainingAgent:
                 return RunResult.RESTART_REQUESTED
             # 2. worker process health
             codes = [w.poll() for w in self._workers]
+            failed = any(c is not None and c != 0 for c in codes)
+            watch.polled(failed)
             if all(c == 0 for c in codes):
                 return RunResult.SUCCEEDED
-            if any(c is not None and c != 0 for c in codes):
+            if failed:
                 bad = [
                     (w.local_rank, c)
                     for w, c in zip(self._workers, codes)
@@ -535,11 +641,15 @@ class ElasticTrainingAgent:
                 return RunResult.FAILED
             # 3. membership change -> re-rendezvous (reference
             #    _membership_changed :1028)
+            rpc_error = False
             try:
                 if self.client.num_nodes_waiting(RendezvousName.TRAINING) > 0:
                     return RunResult.MEMBERSHIP_CHANGED
             except Exception as e:  # noqa: BLE001
+                rpc_error = True
                 logger.warning("num_nodes_waiting failed: %s", e)
+            finally:
+                watch.asked(rpc_error)
 
     # -- main entry (reference _invoke_run :863) ----------------------------
     def run(self) -> int:

@@ -69,6 +69,24 @@ def ckpt_stat_name(job_name: str) -> str:
     return env_utils.run_scoped(f"{job_name}-ckptstat")
 
 
+def _walk_args(fetched: list, t0: float) -> dict:
+    """Where ``flatten_to_shards`` waited, from its ``(bytes, start, end)``
+    a device shard (the ``args`` of ``ckpt.save.d2h.fetch``): ``leaves``,
+    ``asarray_s`` (its seconds inside ``np.asarray``, all shards),
+    ``largest`` (the three largest shards, ``[bytes, seconds]``) and
+    ``first_leaf_s`` (from ``t0`` until the first one's host array
+    exists)."""
+    args = {
+        "leaves": len(fetched),
+        "asarray_s": round(sum(end - start for _, start, end in fetched), 6),
+        "largest": [[n, round(end - start, 6)] for n, start, end
+                    in sorted(fetched, reverse=True)[:3]],
+    }
+    if fetched:
+        args["first_leaf_s"] = round(fetched[0][2] - t0, 6)
+    return args
+
+
 class CheckpointEngine:
     def __init__(
         self,
@@ -157,9 +175,14 @@ class CheckpointEngine:
                     pass
             return None
 
-        with span("ckpt.save.d2h", "ckpt") as sp:
-            jax.tree_util.tree_map(_prefetch, state)
-            tensors, info = tree_utils.flatten_to_shards(state)
+        with span("ckpt.save.d2h", "ckpt", host=True) as sp:
+            with span("ckpt.save.d2h.issue", "ckpt"):
+                jax.tree_util.tree_map(_prefetch, state)
+            with span("ckpt.save.d2h.fetch", "ckpt") as fetch:
+                t0 = time.monotonic()
+                fetched: list = []
+                tensors, info = tree_utils.flatten_to_shards(state, fetched)
+                fetch.set(**_walk_args(fetched, t0))
             self._last_staged_bytes = sum(
                 int(np.asarray(a).nbytes) for a in tensors.values()
             )
@@ -181,7 +204,7 @@ class CheckpointEngine:
             self._fence_arena()
         try:
             self._first_touch = self._arena.will_allocate(tensors)
-            with span("ckpt.save.arena_write", "ckpt",
+            with span("ckpt.save.arena_write", "ckpt", host=True,
                       bytes=self._last_staged_bytes,
                       first_touch=self._first_touch):
                 self._arena.write_state(tensors, extra=extra)
@@ -391,7 +414,7 @@ class CheckpointEngine:
         bandwidth scales with world size) and skips tensors whose dirty
         fence has not tripped since their holder step (a meta ref
         instead of a rewrite)."""
-        with span("ckpt.persist.write", "ckpt", step=step) as sp:
+        with span("ckpt.persist.write", "ckpt", host=True, step=step) as sp:
             self._write_shard(step, tensors, extra, sp)
 
     def _write_shard(self, step: int, tensors, extra, write_span) -> None:
@@ -736,7 +759,7 @@ class CheckpointEngine:
         meta.setdefault("step", extra.get("step", 0))
         if target is None:
             return source, meta
-        with span("ckpt.load.device_put", "ckpt") as sp:
+        with span("ckpt.load.device_put", "ckpt", host=True) as sp:
             tally: Dict[str, int] = {}
             state = tree_utils.restore_to_target(target, source, tally)
             # device_put returns before the bytes are on the device, and

@@ -14,6 +14,7 @@ jax.Arrays / ShapeDtypeStructs whose structure names the paths.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -57,6 +58,7 @@ def _box_owners(leaf, gshape):
 
 def flatten_to_shards(
     state: Any,
+    fetched: Optional[List[Tuple[int, float, float]]] = None,
 ) -> Tuple[Dict[str, np.ndarray], Dict[str, dict]]:
     """Flatten a pytree of arrays into this process's shard dict.
 
@@ -66,6 +68,10 @@ def flatten_to_shards(
     holding this same box, from the global indices map) for device arrays
     and ``host: True`` for host leaves (identical on every rank by the
     same assumption the restore path already makes).
+
+    ``fetched``, where a list is given, takes ``(bytes, start, end)`` of
+    each device shard's ``np.asarray`` in the order of the walk, on the
+    monotonic clock: where the device-to-host copy is waited for.
     """
     leaves = tree_flatten_with_path(state)[0]
     tensors: Dict[str, np.ndarray] = {}
@@ -79,7 +85,11 @@ def flatten_to_shards(
                 idx = _norm_index(shard.index, gshape)
                 if idx in seen:
                     continue
+                t0 = time.monotonic()
                 seen[idx] = np.asarray(shard.data)
+                if fetched is not None:
+                    fetched.append(
+                        (seen[idx].nbytes, t0, time.monotonic()))
             owners_by_box = _box_owners(leaf, gshape)
             for k, (idx, arr) in enumerate(sorted(seen.items())):
                 key = f"{name}|{k}"

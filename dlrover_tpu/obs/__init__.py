@@ -30,13 +30,16 @@ agent record here, and the chip belongs to their worker):
 
 Enabled by ``DLROVER_TPU_OBS_DIR`` (dump directory; unset = ring-only,
 still live-scrapeable).  ``DLROVER_TPU_OBS_PROCESS`` names the process
-in dumps and merged traces.  A job under ``python -m dlrover_tpu.run``
+in dumps and merged traces; ``DLROVER_TPU_OBS_PARENT`` is the agent's
+to set for its workers (the ``sid`` of the span that started them), so
+that a restart is one tree across both processes.  A job under ``python -m dlrover_tpu.run``
 has a directory without asking: ``<tmp>/dlrover_tpu_obs/<job>-<run
 id>``, removed when the job ends with rc 0 (:func:`job_dir`).
 """
 
 from dlrover_tpu.obs.recorder import (  # noqa: F401
     ENV_DIR,
+    ENV_PARENT,
     ENV_PROCESS,
     FlightRecorder,
     configure,

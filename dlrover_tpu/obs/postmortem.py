@@ -15,6 +15,11 @@ answers the three questions an operator asks after a kill:
   process's dump, with the process that recorded the effective
   terminal (the failover/replay destination).
 
+For a training job's journal directory it also answers **what each
+restart cost** (:func:`restart_accounts`): from the moment the agent
+could first have known of the failure to the new incarnation's first
+step, part by part across both processes, and the seconds no span names.
+
 ``--out`` additionally writes the merged Perfetto-loadable chrome
 trace (:func:`dlrover_tpu.obs.collect.build_chrome_trace`).
 """
@@ -23,7 +28,7 @@ from __future__ import annotations
 
 import argparse
 import json
-from typing import Any, Dict, List
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 from dlrover_tpu.obs.collect import (
     load_dir,
@@ -35,6 +40,159 @@ from dlrover_tpu.obs.collect import (
 
 def _fmt_ts(us: float) -> str:
     return f"{us / 1e6:.3f}s"
+
+
+# -- what a restart cost ------------------------------------------------------
+
+#: results of ``agent.monitor`` that the agent answers with a restart
+_RESTARTING = ("failed", "membership_changed", "restart_requested")
+#: the new worker's named parts, in the order they run
+_WORKER_PARTS = ("bootstrap.init", "bootstrap.backend_init",
+                 "accelerate.build", "accelerate.create_state",
+                 "ckpt.load", "accelerate.first_call")
+
+
+def _end(rec: Dict[str, Any]) -> float:
+    return float(rec["ts"]) + float(rec.get("dur", 0.0))
+
+
+def _gaps(intervals: Iterable[Tuple[float, float]],
+          lo: float, hi: float) -> List[Tuple[float, float]]:
+    """The parts of ``[lo, hi]`` that no interval covers, in order."""
+    gaps, reach = [], lo
+    for start, end in sorted(intervals):
+        if start > reach:
+            gaps.append((reach, min(start, hi)))
+        reach = max(reach, end)
+        if reach >= hi:
+            break
+    if reach < hi:
+        gaps.append((reach, hi))
+    return gaps
+
+
+def _interpreter(events: List[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
+    """``bootstrap.process_start`` counted as a span: the event's
+    ``[ts - since_process_start_s, ts]``, the new interpreter's start and
+    the imports before ``bootstrap.init``."""
+    for e in events:
+        if e.get("kind") == "bootstrap.process_start":
+            dur = float(e.get("since_process_start_s", 0.0)) * 1e6
+            return {"ts": float(e["ts"]) - dur, "dur": dur,
+                    "psid": e.get("psid", "")}
+    return None
+
+
+def restart_accounts(dumps: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """One account a restart, oldest first: for each ``agent.monitor``
+    span that ended ``failed`` / ``membership_changed`` /
+    ``restart_requested`` and was followed by an ``agent.restart``, the
+    interval from that span's end less its ``unseen_s`` (the first moment
+    the agent could have known) to the end of the new incarnation's
+    ``accelerate.first_call`` (its last span, where it made no such
+    call).  The new incarnation is the worker whose
+    ``bootstrap.process_start`` names the restart's
+    ``agent.start_workers`` as ``psid``; of several, the last to finish.
+
+    ``parts`` holds the seconds of each named part inside the interval
+    (they may nest or overlap by milliseconds: no sum is meant) and
+    ``unspanned_s`` the interval less the UNION of every span the agent
+    and that worker recorded inside it, the interpreter's start among
+    them (and not the agent's next watch, which only waits): what the
+    program still cannot name — the user script's own code between the
+    spans; ``holes`` are its three longest stretches, each ``[seconds
+    into the interval, seconds, the span that ended last before it]``."""
+    accounts = []
+    for agent in dumps:
+        spans = sorted((e for e in agent["events"] if e.get("k") == "span"),
+                       key=lambda e: e["ts"])
+        watches = [s for s in spans if s.get("name") == "agent.monitor"]
+        for restart in spans:
+            if restart.get("name") != "agent.restart":
+                continue
+            # the restart starts where its watch ended (0.2 us: the
+            # rounding of two stamps)
+            before = [w for w in watches if _end(w) <= restart["ts"] + 0.2]
+            if not before or (before[-1].get("args") or {}).get(
+                    "result") not in _RESTARTING:
+                continue
+            spawns = {s["sid"] for s in spans
+                      if s.get("name") == "agent.start_workers"
+                      and s.get("psid") == restart["sid"]}
+            account = _account(agent, spans, before[-1], restart, [
+                d for d in dumps if d is not agent
+                and (_interpreter(d["events"]) or {}).get("psid") in spawns])
+            if account is not None:
+                accounts.append(account)
+    return sorted(accounts, key=lambda a: a["start_us"])
+
+
+def _account(agent, agent_spans, watch, restart, workers):
+    def done(dump) -> float:
+        spans = [e for e in dump["events"] if e.get("k") == "span"]
+        first = [s for s in spans
+                 if s.get("name") == "accelerate.first_call"]
+        return _end(first[0]) if first else max(
+            map(_end, spans), default=0.0)
+
+    if not workers:
+        return None
+    worker = max(workers, key=done)
+    unseen_us = float((watch.get("args") or {}).get("unseen_s", 0.0)) * 1e6
+    lo, hi = _end(watch) - unseen_us, done(worker)
+    if hi <= lo:
+        return None
+    start = dict(_interpreter(worker["events"]), name="interpreter")
+    worker_spans = [e for e in worker["events"] if e.get("k") == "span"]
+
+    def within(spans, name=""):
+        """The spans (of this name) that reach into the interval."""
+        return [s for s in spans if (not name or s.get("name") == name)
+                and _end(s) > lo and float(s["ts"]) < hi]
+
+    def clipped(spans):
+        return [(max(float(s["ts"]), lo), min(_end(s), hi)) for s in spans]
+
+    def secs(spans) -> float:
+        return round(sum(e - s for s, e in clipped(spans)) * 1e-6, 6)
+
+    persist = secs(within(agent_spans, "ckpt.persist"))
+    parts = {
+        "unseen": round(unseen_us * 1e-6, 6),
+        "ckpt.persist": persist,
+        # stop + rendezvous + spawn: the restart less the persist in it
+        "agent.restart": round(secs(within([restart])) - persist, 6),
+        "interpreter": secs(within([start])),
+    }
+    for name in _WORKER_PARTS:
+        parts[name] = secs(within(worker_spans, name))
+    # the watch on the NEW workers spans all that follows their start and
+    # names none of it: of the agent's watches only the one that saw the
+    # failure counts
+    named = within([s for s in agent_spans
+                    if s.get("name") != "agent.monitor" or s is watch]
+                   + worker_spans + [start])
+    gaps = _gaps(clipped(named), lo, hi)
+
+    def hole(gap):
+        """``[seconds into the interval, seconds, the span that ended last
+        before it]``."""
+        before = [s for s in named if _end(s) <= gap[0] + 0.2]
+        return [round((gap[0] - lo) * 1e-6, 6),
+                round((gap[1] - gap[0]) * 1e-6, 6),
+                max(before, key=_end)["name"] if before else ""]
+
+    return {
+        "agent": str(agent["meta"].get("process", "")),
+        "worker": str(worker["meta"].get("process", "")),
+        "result": (watch.get("args") or {}).get("result", ""),
+        "start_us": lo, "interval_s": round((hi - lo) * 1e-6, 6),
+        "parts": parts,
+        "unspanned_s": round(sum(e - s for s, e in gaps) * 1e-6, 6),
+        # the three longest stretches under no span
+        "holes": sorted(map(hole, sorted(
+            gaps, key=lambda g: g[0] - g[1])[:3])),
+    }
 
 
 def analyze(dump_dir: str) -> Dict[str, Any]:
@@ -116,6 +274,7 @@ def analyze(dump_dir: str) -> Dict[str, Any]:
         ),
         "traces": len(traces),
         "rerouted": rerouted,
+        "restarts": restart_accounts(dumps),
     }
 
 
@@ -144,6 +303,20 @@ def render(report: Dict[str, Any]) -> str:
             )
             for ev in proc["journal_tail"]:
                 lines.append(f"    last journal: {json.dumps(ev)}")
+    if report.get("restarts"):
+        lines.append("what each restart cost (seconds):")
+        for r in report["restarts"]:
+            lines.append(
+                f"  {r['agent']} -> {r['worker']} ({r['result']}): "
+                f"{r['interval_s']:.3f} to the first step = "
+                # "unseen" is a bound: the agent's sleep may hold the death
+                + " + ".join(
+                    f"{'unseen <=' if name == 'unseen' else name} {s:.3f}"
+                    for name, s in r["parts"].items() if s)
+                + f"; unspanned {r['unspanned_s']:.3f}" + "".join(
+                    f", {secs:.3f} after {after or 'the start'}"
+                    for _, secs, after in r["holes"])
+            )
     if report["rerouted"]:
         lines.append("requests that crossed processes:")
         for r in report["rerouted"]:

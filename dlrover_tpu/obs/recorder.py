@@ -31,6 +31,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import resource
 import signal
 import sys
 import threading
@@ -43,6 +44,10 @@ from dlrover_tpu.obs.span import EPOCH_ANCHOR, anchored_us, new_span_id
 
 ENV_DIR = "DLROVER_TPU_OBS_DIR"
 ENV_PROCESS = "DLROVER_TPU_OBS_PROCESS"
+#: the ``sid`` of the agent's span that started this process
+#: (``agent.start_workers``): set by the agent for its workers, never by
+#: a user — what joins a worker's journal to the restart that caused it
+ENV_PARENT = "DLROVER_TPU_OBS_PARENT"
 ENV_CAPACITY = "DLROVER_TPU_OBS_CAPACITY"
 
 
@@ -390,6 +395,15 @@ class Span:
     outlives one block (the agent's restart runs across loop turns) use
     :meth:`start` / :meth:`end`.
 
+    ``host=True`` (a span whose seconds are bytes moving: a copy, a
+    write) also reads the process's CPU clock and its page-fault counts
+    at both ends — ``time.process_time()`` and ``getrusage(RUSAGE_SELF)``,
+    every thread of the process, so the runtime's copy threads count —
+    and adds ``cpu_s``, and where the platform counts faults at all
+    ``minflt`` and ``majflt``, to ``args``: ``cpu_s`` far under the span's
+    seconds says the process slept (on a DMA, on a disk); near or above
+    them, that the host was copying or faulting pages in.
+
     When — and only when — JAX is already loaded in this process the
     span also enters ``jax.profiler.TraceAnnotation(name)``, so under
     any profiler session it lies on the trace's host plane beside the
@@ -397,16 +411,18 @@ class Span:
     JAX: the launcher and the agent use it."""
 
     __slots__ = ("name", "cat", "args", "sid", "psid", "ring_only",
-                 "_t0", "_annotation")
+                 "host", "_t0", "_annotation", "_host0")
 
     def __init__(self, name: str, cat: str, ring_only: bool = False,
-                 parent: str = "", **args: Any):
+                 parent: str = "", host: bool = False, **args: Any):
         self.name, self.cat, self.args = name, cat, args
         self.ring_only = ring_only
+        self.host = host
         self.sid = new_span_id()
         self.psid = parent
         self._t0: Optional[float] = None
         self._annotation = None
+        self._host0 = None
 
     def set(self, **args: Any) -> None:
         self.args.update(args)
@@ -421,6 +437,8 @@ class Span:
 
             self._annotation = TraceAnnotation(self.name)
             self._annotation.__enter__()
+        if self.host:
+            self._host0 = _host_cost()
         self._t0 = time.monotonic()
         return self
 
@@ -428,6 +446,14 @@ class Span:
         if self._t0 is None:
             return  # never started, or ended already
         t1 = time.monotonic()
+        if self._host0 is not None:
+            cpu, minflt, majflt = _host_cost()
+            cpu0, minflt0, majflt0 = self._host0
+            self._host0 = None
+            self.args["cpu_s"] = round(cpu - cpu0, 6)
+            if minflt or majflt:  # a platform that counts none reads 0
+                self.args.update(minflt=minflt - minflt0,
+                                 majflt=majflt - majflt0)
         if self._annotation is not None:
             self._annotation.__exit__(None, None, None)
             self._annotation = None
@@ -450,6 +476,13 @@ class Span:
         self.end()
 
 
+def _host_cost() -> Tuple[float, int, int]:
+    """CPU seconds of every thread of this process, and its minor and
+    major page faults, so far."""
+    usage = resource.getrusage(resource.RUSAGE_SELF)
+    return time.process_time(), usage.ru_minflt, usage.ru_majflt
+
+
 _tls = threading.local()
 
 
@@ -469,12 +502,15 @@ def current_span_id() -> str:
 
 
 def span(name: str, cat: str, ring_only: bool = False, parent: str = "",
-         **args: Any) -> Span:
+         host: bool = False, **args: Any) -> Span:
     """The span primitive (see :class:`Span`).  Low-rate spans (save,
     load, persist, restart, bootstrap, build) are journalled to disk as
     they end; ``ring_only`` keeps a per-step span in the ring alone.
-    ``parent`` names the causing span's ``sid`` across threads."""
-    return Span(name, cat, ring_only=ring_only, parent=parent, **args)
+    ``parent`` names the causing span's ``sid`` across threads — or
+    across processes (:data:`ENV_PARENT`); ``host`` adds the process's
+    CPU seconds and page faults between the span's ends."""
+    return Span(name, cat, ring_only=ring_only, parent=parent, host=host,
+                **args)
 
 
 def record_span(name: str, cat: str, start_s: float, end_s: float,

@@ -25,7 +25,7 @@ from dlrover_tpu.common.jax_env import (
     process_age_s,
 )
 from dlrover_tpu.common.log import logger, set_role
-from dlrover_tpu.obs import journal, span
+from dlrover_tpu.obs import ENV_PARENT, journal, span
 
 
 class ElasticContext:
@@ -162,12 +162,16 @@ def init(connect_master: bool = True) -> ElasticContext:
         return _ctx
     ctx = ElasticContext()
     set_role(f"worker-{ctx.process_id}")
+    # the agent's span that started this process (agent.start_workers):
+    # the restart is one tree across both processes' journals
+    parent = os.environ.get(ENV_PARENT, "")
     # what the new interpreter and its imports took before this line
     journal("bootstrap.process_start", durable=True,
             since_process_start_s=round(process_age_s(), 3),
-            rank=ctx.process_id, restart_count=ctx.restart_count)
-    with span("bootstrap.init", "bootstrap", rank=ctx.process_id,
-              restart_count=ctx.restart_count):
+            rank=ctx.process_id, restart_count=ctx.restart_count,
+            **({"psid": parent} if parent else {}))
+    with span("bootstrap.init", "bootstrap", parent=parent,
+              rank=ctx.process_id, restart_count=ctx.restart_count):
         _bring_up(ctx, connect_master)
     _ctx = ctx
     return ctx

@@ -39,6 +39,10 @@ def _inside(child, parent) -> bool:
             <= parent["ts"] + parent["dur"] + 0.2)
 
 
+#: what ``obs.span(..., host=True)`` adds to a span's ``args``
+HOST_ARGS = ("cpu_s", "minflt", "majflt")
+
+
 def _unlink_arena(job: str) -> None:
     from dlrover_tpu.common.shm import arena_name
 
@@ -253,7 +257,8 @@ class TestCheckpointSpans:
         assert second["args"]["first_touch"] is False
         assert {"stall_ms", "mbps", "rank"} <= set(first["args"])
         d2h = [s for s in spans if s["name"] == "ckpt.save.d2h"][0]
-        assert d2h["args"] == {"bytes": nbytes, "tensors": 2}
+        assert {k: v for k, v in d2h["args"].items()
+                if k not in HOST_ARGS} == {"bytes": nbytes, "tensors": 2}
         load = [s for s in spans if s["name"] == "ckpt.load"][0]
         assert load["args"]["source"] == "shm"
         assert load["args"]["step"] == 6
@@ -334,6 +339,504 @@ class TestAgentSaverSpans:
         # the commit runs on a pool thread and still names its persist
         assert kids == {"ckpt.persist.lock_wait", "ckpt.persist.write",
                         "ckpt.persist.commit"}
+
+
+class TestHostCost:
+    """``obs.span(..., host=True)``: the process's CPU seconds and page
+    faults between the span's ends."""
+
+    def test_host_adds_cpu_seconds_of_the_whole_process(self, recorder):
+        with obs.span("t.copy", "t", host=True, bytes=8):
+            deadline = time.monotonic() + 0.05
+            while time.monotonic() < deadline:  # the host is busy
+                pass
+        with obs.span("t.wait", "t", host=True):
+            time.sleep(0.05)  # the process sleeps, as on a DMA
+        by = {s["name"]: s for s in _spans(recorder, "t.")}
+        copy, wait = by["t.copy"]["args"], by["t.wait"]["args"]
+        assert copy["bytes"] == 8
+        assert 0.02 < copy["cpu_s"] <= by["t.copy"]["dur"] * 1e-6 * 1.5 + 0.05
+        assert 0 <= wait["cpu_s"] < 0.04 < by["t.wait"]["dur"] * 1e-6
+        # the fault counts come together, and only where the platform
+        # counts them at all
+        for args in (copy, wait):
+            assert ("minflt" in args) == ("majflt" in args)
+            assert set(args) <= {"bytes", *HOST_ARGS}
+
+    def test_fault_counts_are_left_out_where_the_platform_counts_none(
+            self, recorder, monkeypatch):
+        from dlrover_tpu.obs import recorder as rec_mod
+
+        monkeypatch.setattr(rec_mod, "_host_cost",
+                            lambda: (time.process_time(), 0, 0))
+        with obs.span("t.nofaults", "t", host=True):
+            pass
+        (rec,) = _spans(recorder, "t.nofaults")
+        assert set(rec["args"]) == {"cpu_s"}
+
+    def test_a_span_without_it_is_recorded_as_before(self, recorder,
+                                                      monkeypatch):
+        from dlrover_tpu.obs import recorder as rec_mod
+
+        def never():
+            raise AssertionError("a plain span read the host's cost")
+
+        monkeypatch.setattr(rec_mod, "_host_cost", never)
+        with obs.span("t.plain", "t", n=1):
+            pass
+        with obs.span("t.bare", "t"):
+            pass
+        by = {s["name"]: s for s in _spans(recorder, "t.")}
+        assert by["t.plain"]["args"] == {"n": 1}
+        # byte for byte the record a span has always left
+        assert list(by["t.plain"]) == [
+            "k", "name", "cat", "ts", "dur", "tid", "sid", "args", "seq"]
+        assert list(by["t.bare"]) == [
+            "k", "name", "cat", "ts", "dur", "tid", "sid", "seq"]
+
+
+class TestSaveCopySpans:
+    """Under ``ckpt.save.d2h``: the issue of the copies and the walk that
+    waits for them, and the host's cost on the four spans whose seconds
+    are bytes moving."""
+
+    @pytest.fixture
+    def spans(self, tmp_path, monkeypatch, recorder):
+        import jax.numpy as jnp
+
+        from dlrover_tpu.checkpoint.checkpointer import FlashCheckpointer
+
+        job = f"obs-copy-{os.getpid()}"
+        monkeypatch.setenv("DLROVER_TPU_JOB_NAME", job)
+        ckpt = FlashCheckpointer(str(tmp_path / "ckpt"), job_name=job)
+        state = {"w": [jnp.ones((n, 64)) for n in (8, 64, 2, 32, 16)],
+                 "host": np.arange(4), "count": jnp.array(3)}
+        try:
+            ckpt.save(state, meta={"step": 1}, storage=True)
+            assert ckpt.wait(timeout=60)
+            ckpt.load(target=state)
+        finally:
+            ckpt.close()
+            _unlink_arena(job)
+        return _spans(recorder, "ckpt.")
+
+    def test_d2h_has_two_children_that_sum_to_it(self, spans):
+        (d2h,) = [s for s in spans if s["name"] == "ckpt.save.d2h"]
+        kids = {s["name"]: s for s in spans if s.get("psid") == d2h["sid"]}
+        assert set(kids) == {"ckpt.save.d2h.issue", "ckpt.save.d2h.fetch"}
+        assert all(_inside(k, d2h) for k in kids.values())
+        issue, fetch = kids["ckpt.save.d2h.issue"], kids["ckpt.save.d2h.fetch"]
+        assert issue["ts"] + issue["dur"] <= fetch["ts"] + 0.2
+        # within a millisecond (the spans are in microseconds)
+        assert 0 <= d2h["dur"] - issue["dur"] - fetch["dur"] < 1000
+        assert "args" not in issue
+
+    def test_the_walk_says_where_it_waited(self, spans):
+        (fetch,) = [s for s in spans if s["name"] == "ckpt.save.d2h.fetch"]
+        args = fetch["args"]
+        assert args["leaves"] == 6  # the device leaves; "host" is none
+        assert [n for n, _ in args["largest"]] == [
+            64 * 64 * 4, 32 * 64 * 4, 16 * 64 * 4]  # three, largest first
+        assert all(0 <= secs <= fetch["dur"] * 1e-6
+                   for _, secs in args["largest"])
+        assert 0 <= args["first_leaf_s"] <= fetch["dur"] * 1e-6
+        assert (sum(secs for _, secs in args["largest"]) - 1e-5
+                <= args["asarray_s"] <= fetch["dur"] * 1e-6)
+        json.dumps(args)
+
+    @pytest.mark.parametrize("name", [
+        "ckpt.save.d2h", "ckpt.save.arena_write", "ckpt.load.device_put",
+        "ckpt.persist.write"])
+    def test_spans_whose_seconds_are_bytes_moving_carry_the_hosts_cost(
+            self, spans, name):
+        found = [s for s in spans if s["name"] == name]
+        assert found
+        for s in found:
+            assert s["args"]["cpu_s"] >= 0
+        others = [s for s in spans if s["name"] not in (
+            "ckpt.save.d2h", "ckpt.save.arena_write",
+            "ckpt.load.device_put", "ckpt.persist.write")]
+        assert others and not [
+            s for s in others if "cpu_s" in (s.get("args") or {})]
+
+    def test_flatten_stamps_only_when_asked(self):
+        import jax.numpy as jnp
+
+        from dlrover_tpu.checkpoint import tree_utils
+
+        state = {"a": jnp.ones((4, 4)), "b": np.ones(3)}
+        fetched = []
+        stamped = tree_utils.flatten_to_shards(state, fetched)
+        plain = tree_utils.flatten_to_shards(state)
+        assert [(n, t1 >= t0) for n, t0, t1 in fetched] == [(64, True)]
+        assert stamped[1] == plain[1]
+        assert all((stamped[0][k] == plain[0][k]).all() for k in plain[0])
+
+
+class FakeWorker:
+    """Polls as alive until turn ``exits_on`` of the agent's loop, then
+    as exited with ``code``."""
+
+    def __init__(self, exits_on=None, code=1, local_rank=0):
+        self.local_rank, self.exits_on, self.code = local_rank, exits_on, code
+        self.polls = 0
+
+    def poll(self):
+        self.polls += 1
+        if self.exits_on is not None and self.polls >= self.exits_on:
+            return self.code
+        return None
+
+
+class FakeClient:
+    """``num_nodes_waiting`` answers from ``waiting`` turn by turn (the
+    last answer for ever; an exception is raised), ``delay`` seconds
+    late."""
+
+    def __init__(self, waiting=(0,), delay=0.0):
+        self.waiting, self.delay, self.calls = list(waiting), delay, 0
+
+    def num_nodes_waiting(self, name):
+        self.calls += 1
+        time.sleep(self.delay)
+        answer = self.waiting[min(self.calls, len(self.waiting)) - 1]
+        if isinstance(answer, Exception):
+            raise answer
+        return answer
+
+
+def _agent(monkeypatch, workers, client=None, interval=0.05):
+    from dlrover_tpu.agent import config_tuner
+    from dlrover_tpu.agent.training import (
+        ElasticLaunchConfig,
+        ElasticTrainingAgent,
+    )
+
+    # the tuner's constructor exports its path; not this test's to leave
+    monkeypatch.setenv(config_tuner.CONFIG_PATH_ENV, "")
+    agent = ElasticTrainingAgent(
+        ElasticLaunchConfig(monitor_interval=interval), ["true"], "",
+        client=client or FakeClient())
+    agent._workers = workers
+    return agent
+
+
+class TestAgentMonitorSpan:
+    """``agent.monitor``: one durable span a call of ``_monitor``, whose
+    ``args`` say what the loop did with its time."""
+
+    INTERVAL = 0.05
+
+    def _monitor(self, recorder, agent):
+        result = agent._monitor()
+        (rec,) = _spans(recorder, "agent.monitor")
+        assert rec["args"]["result"] == result
+        assert rec["args"]["interval"] == agent.config.monitor_interval
+        return rec, rec["args"], rec["dur"] * 1e-6
+
+    @pytest.mark.parametrize("turn", [1, 3])
+    def test_a_worker_that_exits_nonzero_on_turn_n(self, recorder,
+                                                   monkeypatch, turn):
+        agent = _agent(monkeypatch, [FakeWorker(exits_on=turn, code=-9)])
+        _, args, dur = self._monitor(recorder, agent)
+        assert args["result"] == "failed" and args["turns"] == turn
+        assert agent._last_failures == [(0, -9)]
+        # from the last clean pass (the entry, on turn 1) to the pass that
+        # saw the exit code: one sleep and what the loop did around it
+        assert self.INTERVAL <= args["unseen_s"] <= dur
+        assert args["unseen_s"] < 2 * self.INTERVAL + 0.1
+        parts = args["sleep_s"] + args["poll_s"] + args["rpc_s"]
+        assert parts == pytest.approx(dur, abs=1e-3)
+        assert args["sleep_s"] >= turn * self.INTERVAL
+        assert args["turn_max_s"] >= self.INTERVAL > args["busy_max_s"]
+        assert args["rpc_errors"] == 0
+        # the last turns (at most four), each [sleep, poll, rpc]; the one
+        # that saw the failure never asked the master
+        assert len(args["last_turns"]) == min(turn, 4)
+        assert args["last_turns"][-1][2] == 0.0
+        assert all(len(t) == 3 and t[0] >= self.INTERVAL
+                   for t in args["last_turns"])
+
+    def test_last_turns_keeps_four(self, recorder, monkeypatch):
+        agent = _agent(monkeypatch, [FakeWorker(exits_on=6)], interval=0.01)
+        _, args, _ = self._monitor(recorder, agent)
+        assert args["turns"] == 6 and len(args["last_turns"]) == 4
+
+    def test_a_slow_question_to_the_master_shows(self, recorder,
+                                                 monkeypatch):
+        client = FakeClient(delay=0.08)
+        agent = _agent(monkeypatch, [FakeWorker(exits_on=3)], client)
+        _, args, dur = self._monitor(recorder, agent)
+        assert client.calls == 2 and args["turns"] == 3
+        assert args["rpc_s"] >= 0.16 and args["busy_max_s"] >= 0.08
+        assert args["turn_max_s"] >= self.INTERVAL + 0.08
+        # the loop was away from its polls for the RPC too
+        assert args["unseen_s"] >= self.INTERVAL + 0.08
+        assert args["unseen_s"] <= dur
+
+    def test_a_failed_question_is_counted_and_the_loop_goes_on(
+            self, recorder, monkeypatch):
+        client = FakeClient(waiting=[RuntimeError("master away"), 0])
+        agent = _agent(monkeypatch, [FakeWorker(exits_on=3)], client)
+        _, args, _ = self._monitor(recorder, agent)
+        assert args["result"] == "failed" and args["rpc_errors"] == 1
+
+    def test_success_closes_the_span(self, recorder, monkeypatch):
+        agent = _agent(monkeypatch, [FakeWorker(exits_on=2, code=0)])
+        _, args, dur = self._monitor(recorder, agent)
+        assert args["result"] == "succeeded" and args["turns"] == 2
+        assert "unseen_s" not in args  # nothing failed
+        assert (args["sleep_s"] + args["poll_s"] + args["rpc_s"]
+                == pytest.approx(dur, abs=1e-3))
+
+    def test_membership_change_closes_the_span(self, recorder, monkeypatch):
+        agent = _agent(monkeypatch, [FakeWorker()], FakeClient([0, 1]))
+        _, args, _ = self._monitor(recorder, agent)
+        assert args["result"] == "membership_changed"
+        assert args["turns"] == 2 and "unseen_s" not in args
+        assert args["last_turns"][-1][2] > 0  # it asked, and was told
+
+    def test_a_pushed_action_closes_the_span_before_any_poll(
+            self, recorder, monkeypatch):
+        from dlrover_tpu.common.constants import DiagnosisActionType
+
+        worker = FakeWorker()
+        agent = _agent(monkeypatch, [worker])
+        agent._pending_action = DiagnosisActionType.RESTART_WORKER
+        _, args, _ = self._monitor(recorder, agent)
+        assert args["result"] == "restart_requested" and worker.polls == 0
+        assert args["turns"] == 1
+        assert args["last_turns"][0][1:] == [0.0, 0.0]
+
+    def test_the_span_is_on_disk_when_the_agent_is_killed(self, tmp_path):
+        res = _run_python(
+            "import os, signal\n"
+            "from unittest import mock\n"
+            "from dlrover_tpu.agent.training import (\n"
+            "    ElasticLaunchConfig, ElasticTrainingAgent, WorkerProcess)\n"
+            "client = mock.Mock()\n"
+            "client.num_nodes_waiting.return_value = 0\n"
+            "agent = ElasticTrainingAgent(\n"
+            "    ElasticLaunchConfig(monitor_interval=0.02), ['true'], '',\n"
+            "    client=client)\n"
+            "proc = mock.Mock()\n"
+            "proc.poll.side_effect = [None, -9]\n"
+            "agent._workers = [WorkerProcess(0, proc)]\n"
+            "assert agent._monitor() == 'failed'\n"
+            "os.kill(os.getpid(), signal.SIGKILL)\n",
+            DLROVER_TPU_OBS_DIR=str(tmp_path),
+            DLROVER_TPU_OBS_PROCESS="agent-n0")
+        assert res.returncode == -signal.SIGKILL, res.stderr
+        (dump,) = load_dir(str(tmp_path))
+        assert dump["meta"]["reason"] == "journal"  # no hook ever ran
+        (rec,) = [e for e in dump["events"]
+                  if e.get("name") == "agent.monitor"]
+        assert rec["args"]["result"] == "failed"
+        assert rec["args"]["turns"] == 2 and rec["args"]["unseen_s"] > 0
+
+
+class TestRestartIsOneTree:
+    """The agent hands its worker the ``sid`` of the span that starts it;
+    the worker's bootstrap names it."""
+
+    def test_spawn_hands_the_worker_the_open_spans_id(
+            self, recorder, monkeypatch, tmp_path):
+        out = tmp_path / "parent.txt"
+        agent = _agent(monkeypatch, [])
+        agent.entrypoint = [
+            sys.executable, "-c",
+            "import os; open(%r, 'w').write(os.environ.get("
+            "'DLROVER_TPU_OBS_PARENT', 'unset') + ' ' + "
+            "os.environ['DLROVER_TPU_OBS_PROCESS'])" % str(out)]
+        agent._start_workers({
+            "round": 1, "my_rank": 0, "coordinator": "localhost:1",
+            "num_processes": 1,
+            "world": {0: {"node_id": 0, "process_id_base": 0,
+                          "local_world_size": 1}}})
+        try:
+            assert agent._workers[0].proc.wait(timeout=60) == 0
+        finally:
+            agent._stop_workers("test over", grace=1.0)
+        (start,) = _spans(recorder, "agent.start_workers")
+        assert out.read_text() == f"{start['sid']} worker-r0-i0"
+
+    def test_the_worker_journals_it_and_its_init_names_it(self, tmp_path):
+        res = _run_python(
+            "import dlrover_tpu.trainer as t\n"
+            "t.init(connect_master=False)\n",
+            DLROVER_TPU_OBS_DIR=str(tmp_path),
+            DLROVER_TPU_OBS_PROCESS="worker-r0-i1",
+            DLROVER_TPU_OBS_PARENT="feedfacefeedface",
+            DLROVER_TPU_RESTART_COUNT="1")
+        assert res.returncode == 0, res.stderr
+        (dump,) = load_dir(str(tmp_path))
+        by = {e.get("name") or e.get("kind"): e for e in dump["events"]}
+        assert by["bootstrap.process_start"]["psid"] == "feedfacefeedface"
+        assert by["bootstrap.init"]["psid"] == "feedfacefeedface"
+
+    def test_a_worker_nobody_started_names_no_parent(self, tmp_path):
+        res = _run_python(
+            "import dlrover_tpu.trainer as t\n"
+            "t.init(connect_master=False)\n",
+            DLROVER_TPU_OBS_DIR=str(tmp_path),
+            DLROVER_TPU_OBS_PROCESS="worker-r0-i0")
+        assert res.returncode == 0, res.stderr
+        (dump,) = load_dir(str(tmp_path))
+        by = {e.get("name") or e.get("kind"): e for e in dump["events"]}
+        assert "psid" not in by["bootstrap.process_start"]
+        assert "psid" not in by["bootstrap.init"]
+
+
+def write_restart_journal(out_dir, unseen_s=1.0, monitor_result="failed"):
+    """A two-process journal of one restart on a clock of round seconds,
+    written by the recorder itself (``benchmark/tests/
+    test_restart_readers.py`` reads the same one): the agent's watch ends
+    at 110 having seen the failure at most ``unseen_s`` late; the restart
+    runs 110-120 with a persist 111-117 inside its stop; the new worker's
+    interpreter starts at 119.5, inside ``agent.start_workers``; its spans
+    overlap (build 131-140 under an outer 130-141) and leave holes
+    (124-125, 128-130, 141-142, 150-151)."""
+    from dlrover_tpu.obs import FlightRecorder
+
+    agent = FlightRecorder(process="agent-n0", out_dir=str(out_dir))
+    worker = FlightRecorder(process="worker-r0-i1", out_dir=str(out_dir))
+    # both files would be named by this process's pid
+    agent.dump_path = lambda: os.path.join(str(out_dir),
+                                           "flight-agent-n0-1.jsonl")
+    worker.dump_path = lambda: os.path.join(str(out_dir),
+                                            "flight-worker-r0-i1-2.jsonl")
+
+    def span(rec, name, start, end, sid="", parent="", **args):
+        return rec.span(name, name.split(".")[0], start, end,
+                        span_id=sid or None, parent=parent,
+                        args=args or None, durable=True)
+
+    watch_args = {"result": monitor_result, "turns": 10, "sleep_s": 9.9,
+                  "poll_s": 0.05, "rpc_s": 0.05, "busy_max_s": 0.02}
+    if monitor_result == "failed":
+        watch_args["unseen_s"] = unseen_s
+    span(agent, "agent.monitor", 100.0, 110.0, **watch_args)
+    span(agent, "ckpt.persist.write", 112.0, 116.0, parent="persist")
+    span(agent, "ckpt.persist", 111.0, 117.0, sid="persist",
+         parent="stop", reason="breakpoint")
+    span(agent, "agent.stop_workers", 110.5, 118.0, sid="stop",
+         parent="restart")
+    span(agent, "agent.rendezvous", 118.0, 119.0, parent="restart")
+    span(agent, "agent.start_workers", 119.0, 120.0, sid="spawn",
+         parent="restart")
+    span(agent, "agent.restart", 110.0, 120.0, sid="restart",
+         reason=monitor_result)
+    # the next watch, on the new workers, which succeed
+    span(agent, "agent.monitor", 120.0, 160.0, result="succeeded", turns=40)
+    worker._clock = lambda: 123.0
+    worker.event("bootstrap.process_start", durable=True,
+                 since_process_start_s=3.5, psid="spawn", restart_count=1)
+    span(worker, "bootstrap.init", 123.0, 124.0, parent="spawn")
+    span(worker, "bootstrap.backend_init", 125.0, 128.0, first=True)
+    span(worker, "accelerate.compile", 132.0, 139.0, parent="build")
+    span(worker, "accelerate.build", 131.0, 140.0, sid="build")
+    span(worker, "user.outer", 130.0, 141.0)
+    span(worker, "accelerate.create_state", 142.0, 144.0)
+    span(worker, "ckpt.load", 144.0, 150.0, source="shm")
+    span(worker, "accelerate.first_call", 151.0, 152.0)
+    span(worker, "accelerate.first_call", 170.0, 171.0)  # a later program
+    agent.close()
+    worker.close()
+
+
+class TestRestartAccounts:
+    def test_parts_and_the_unspanned_rest(self, tmp_path):
+        from dlrover_tpu.obs import postmortem
+
+        write_restart_journal(tmp_path)
+        (acct,) = postmortem.restart_accounts(load_dir(str(tmp_path)))
+        assert (acct["agent"], acct["worker"]) == ("agent-n0", "worker-r0-i1")
+        assert acct["result"] == "failed"
+        # from 110 - 1 (the first moment the agent could have known) to
+        # the end of the first first_call, 152
+        assert acct["interval_s"] == pytest.approx(43.0)
+        assert acct["parts"] == pytest.approx({
+            "unseen": 1.0, "ckpt.persist": 6.0,
+            "agent.restart": 4.0,  # 10 less the persist
+            "interpreter": 3.5, "bootstrap.init": 1.0,
+            "bootstrap.backend_init": 3.0, "accelerate.build": 9.0,
+            "accelerate.create_state": 2.0, "ckpt.load": 6.0,
+            "accelerate.first_call": 1.0})
+        # the union covers 109-124 (watch, restart, the interpreter from
+        # 119.5 on, init), 125-128, 130-141, 142-150, 151-152: holes
+        # 124-125, 128-130, 141-142, 150-151
+        assert acct["unspanned_s"] == pytest.approx(5.0)
+        # the three longest, in order: seconds into the interval, seconds,
+        # and the span that ended last before each
+        assert acct["holes"] == [
+            [15.0, 1.0, "bootstrap.init"],
+            [19.0, 2.0, "bootstrap.backend_init"],
+            [32.0, 1.0, "user.outer"]]
+
+    def test_unseen_moves_the_start_and_nothing_else(self, tmp_path):
+        from dlrover_tpu.obs import postmortem
+
+        write_restart_journal(tmp_path, unseen_s=0.25)
+        (acct,) = postmortem.restart_accounts(load_dir(str(tmp_path)))
+        assert acct["interval_s"] == pytest.approx(42.25)
+        assert acct["parts"]["unseen"] == pytest.approx(0.25)
+        assert acct["unspanned_s"] == pytest.approx(5.0)
+
+    def test_a_membership_change_has_its_account_too(self, tmp_path):
+        from dlrover_tpu.obs import postmortem
+
+        write_restart_journal(tmp_path, monitor_result="membership_changed")
+        (acct,) = postmortem.restart_accounts(load_dir(str(tmp_path)))
+        assert acct["result"] == "membership_changed"
+        assert acct["parts"]["unseen"] == 0.0
+        assert acct["interval_s"] == pytest.approx(42.0)
+
+    def test_gaps_of_overlapping_and_nested_intervals(self):
+        from dlrover_tpu.obs.postmortem import _gaps
+
+        spans = [(0, 10), (2, 5), (8, 14), (20, 30), (25, 27), (40, 60)]
+        assert _gaps(spans, 1, 50) == [(14, 20), (30, 40)]
+        assert _gaps(spans, 12, 35) == [(14, 20), (30, 35)]
+        assert _gaps([], 1, 50) == [(1, 50)]
+        assert _gaps([(5, 6)], 1, 50) == [(1, 5), (6, 50)]
+        assert _gaps([(60, 70)], 1, 50) == [(1, 50)]
+
+    def test_no_restart_no_account(self, tmp_path):
+        from dlrover_tpu.obs import FlightRecorder, postmortem
+
+        rec = FlightRecorder(process="agent-n0", out_dir=str(tmp_path))
+        rec.span("agent.monitor", "agent", 1.0, 5.0, durable=True,
+                 args={"result": "succeeded", "turns": 4})
+        # a failure the agent gave up on: a restart that started no worker
+        rec.span("agent.monitor", "agent", 6.0, 9.0, durable=True,
+                 args={"result": "failed", "turns": 3, "unseen_s": 1.0})
+        rec.span("agent.restart", "agent", 9.0, 9.5, durable=True,
+                 args={"gave_up": True})
+        rec.close()
+        assert postmortem.restart_accounts(load_dir(str(tmp_path))) == []
+
+    def test_the_cli_prints_one_line_a_restart(self, tmp_path, capsys):
+        from dlrover_tpu.obs import postmortem
+
+        write_restart_journal(tmp_path)
+        assert postmortem.main([str(tmp_path)]) == 0
+        out = capsys.readouterr().out
+        (line,) = [ln for ln in out.splitlines() if "->" in ln
+                   and "worker-r0-i1" in ln]
+        assert line.strip() == (
+            "agent-n0 -> worker-r0-i1 (failed): 43.000 to the first step = "
+            "unseen <= 1.000 + ckpt.persist 6.000 + agent.restart 4.000 + "
+            "interpreter 3.500 + bootstrap.init 1.000 + "
+            "bootstrap.backend_init 3.000 + accelerate.build 9.000 + "
+            "accelerate.create_state 2.000 + ckpt.load 6.000 + "
+            "accelerate.first_call 1.000; unspanned 5.000, 1.000 after "
+            "bootstrap.init, 2.000 after bootstrap.backend_init, 1.000 "
+            "after user.outer")
+        assert "what each restart cost" in out
+        # a serving fleet's report has no such section
+        assert postmortem.render(dict(
+            postmortem.analyze(str(tmp_path)), restarts=[])).count(
+                "restart cost") == 0
 
 
 class TestBuildSpansAndScopes:
