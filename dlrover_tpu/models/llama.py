@@ -33,7 +33,10 @@ from dlrover_tpu.ops.flash_attention import (
     SAVED_NAMES as FLASH_SAVED_NAMES,
     flash_attention,
 )
-from dlrover_tpu.ops.gated_delta import gated_delta_chunked
+from dlrover_tpu.ops.gated_delta import (
+    SAVED_NAMES as GDN_SAVED_NAMES,
+    gated_delta_chunked,
+)
 from dlrover_tpu.ops.gather_sum import gather_sum, weighted_sum
 from dlrover_tpu.ops.grouped_matmul import (
     TILING,
@@ -102,9 +105,10 @@ class LlamaConfig:
     # flash path only, kernels skip out-of-window blocks).
     sliding_window: int = 0
     # Per-block rematerialization: save the residual stream at layer
-    # boundaries and the flash kernel's output and log-sum-exp, recompute
-    # the projections' and the MLP's internals in the backward pass.  The
-    # kernel's two outputs cost as much to recompute as they cost to
+    # boundaries and the flash kernel's output and log-sum-exp (of a
+    # delta-rule layer its kernel's three outputs: ``forward_hidden``),
+    # recompute the projections' and the MLP's internals in the backward
+    # pass.  The flash kernel's two outputs cost as much to recompute as
     # compute and are small to keep: per block application
     # ``B*S*(D + H*Dv)`` bf16 and ``4*B*H*S`` bytes stay, twice what the
     # stream alone takes where ``H*Dv == D``.  Far better peak-HBM than
@@ -1120,57 +1124,58 @@ def _gdn_mixer(u, gdn, cfg: LlamaConfig) -> tuple:
     block's ``gdn``.  ``stats``: ``gdn_state_rms`` (of the state the
     sequence leaves) and ``gdn_decay_min`` (the least ``exp(sum g)`` over a
     chunk: 0 says a chunk's decay underflowed float32, which the rule
-    allows)."""
+    allows).
+
+    The mixer has no checkpoint of its own: it is rematerialised as the
+    rest of its block is (``cfg.remat_block``: once, in front of the block's
+    backward; else not at all).  Of it block remat keeps what the rule's
+    forward kernel put out and the backward reads
+    (``ops.gated_delta.SAVED_NAMES``: ``o`` in float32 and the states that
+    entered the chunks in ``cfg.dtype``, ``B S hv D (4 + 2 D / GDN_CHUNK)``
+    bytes a layer at bf16; the final state leaves under ``stop_gradient``
+    alone and nothing holds it), so ``gdn_chunk_fwd`` runs once a step and
+    layer; the projections, the convolution, the norms and the gate around
+    it run again.  On the ``jax.numpy`` form of the rule nothing is named
+    and all of it does."""
     B, S, _ = u.shape
     hk, hv, D = cfg.gdn_k_heads, cfg.gdn_v_heads, cfg.gdn_d_head
     R, dt, f32 = hv // hk, cfg.dtype, jnp.float32
-
-    # Between the projections the mixer keeps nothing for the backward pass
-    # but its inputs: the convolution's float32 output, the rule's [Q, Q]
-    # arrays and the chunks' states are recomputed when its own backward
-    # runs, so that they are not held while the routed block's runs (2 GB
-    # of a chip's 16 at two sequences of 8,192).
-    @jax.checkpoint
-    def core(qkvz, ba, conv_w, a_log, dt_bias, gain):
-        z = qkvz[..., (2 + R) * D:].reshape(B, S, hv, D)
-        with jax.named_scope("gdn_conv"):
-            flat = lambda a: a.reshape(B, S, -1)  # noqa: E731
-            qkv = jnp.concatenate(
-                [flat(qkvz[..., :D]), flat(qkvz[..., D:2 * D]),
-                 flat(qkvz[..., 2 * D:(2 + R) * D])], axis=-1)
-            qkv = causal_conv1d_silu(qkv, conv_w)
-        with jax.named_scope("gdn_scan"):
-            beta = jax.nn.sigmoid(ba[..., :R].astype(f32)).reshape(B, S, hv)
-            g = -jnp.exp(a_log) * jax.nn.softplus(
-                ba[..., R:].astype(f32).reshape(B, S, hv) + dt_bias)
-
-            def unit(a, scale):
-                a = a.reshape(B, S, hk, D).astype(f32)
-                a = a * (jax.lax.rsqrt(
-                    jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
-                    * scale)
-                return jnp.repeat(a.astype(dt), R, axis=2)
-
-            o, state, decay_min = gated_delta_chunked(
-                unit(qkv[..., :hk * D], D ** -0.5),
-                unit(qkv[..., hk * D:2 * hk * D], 1.0),
-                qkv[..., 2 * hk * D:].reshape(B, S, hv, D), g, beta,
-                GDN_CHUNK)
-            stats = jax.lax.stop_gradient({
-                "gdn_state_rms": jnp.sqrt(jnp.mean(jnp.square(state))),
-                "gdn_decay_min": decay_min})
-        with jax.named_scope("gdn_gate"):
-            y = o * jax.lax.rsqrt(
-                jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.rms_eps)
-            y = (gain * y) * jax.nn.silu(z.astype(f32))
-        return y.astype(dt).reshape(B, S, hv * D), stats
 
     with jax.named_scope("gdn_in"):
         qkvz = (u @ gdn["in_proj_qkvz"].astype(dt)).reshape(
             B, S, hk, (2 + 2 * R) * D)
         ba = (u @ gdn["in_proj_ba"].astype(dt)).reshape(B, S, hk, 2 * R)
-    y, stats = core(qkvz, ba, gdn["conv_w"], gdn["A_log"], gdn["dt_bias"],
-                    gdn["norm"])
+    z = qkvz[..., (2 + R) * D:].reshape(B, S, hv, D)
+    with jax.named_scope("gdn_conv"):
+        flat = lambda a: a.reshape(B, S, -1)  # noqa: E731
+        qkv = jnp.concatenate(
+            [flat(qkvz[..., :D]), flat(qkvz[..., D:2 * D]),
+             flat(qkvz[..., 2 * D:(2 + R) * D])], axis=-1)
+        qkv = causal_conv1d_silu(qkv, gdn["conv_w"])
+    with jax.named_scope("gdn_scan"):
+        beta = jax.nn.sigmoid(ba[..., :R].astype(f32)).reshape(B, S, hv)
+        g = -jnp.exp(gdn["A_log"]) * jax.nn.softplus(
+            ba[..., R:].astype(f32).reshape(B, S, hv) + gdn["dt_bias"])
+
+        def unit(a, scale):
+            a = a.reshape(B, S, hk, D).astype(f32)
+            a = a * (jax.lax.rsqrt(
+                jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
+                * scale)
+            return jnp.repeat(a.astype(dt), R, axis=2)
+
+        o, state, decay_min = gated_delta_chunked(
+            unit(qkv[..., :hk * D], D ** -0.5),
+            unit(qkv[..., hk * D:2 * hk * D], 1.0),
+            qkv[..., 2 * hk * D:].reshape(B, S, hv, D), g, beta, GDN_CHUNK)
+        stats = jax.lax.stop_gradient({
+            "gdn_state_rms": jnp.sqrt(jnp.mean(jnp.square(state))),
+            "gdn_decay_min": decay_min})
+    with jax.named_scope("gdn_gate"):
+        y = o * jax.lax.rsqrt(
+            jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.rms_eps)
+        y = (gdn["norm"] * y) * jax.nn.silu(z.astype(f32))
+    y = y.astype(dt).reshape(B, S, hv * D)
     with jax.named_scope("gdn_out"):
         return y @ gdn["out_proj"].astype(dt), stats
 
@@ -1759,8 +1764,15 @@ def forward_hidden(
     the T normed streams stacked, ``[T, B, S, D]`` (pass t's is pass
     t+1's input), with ``aux["exit_logits"]`` (float32 ``[T, B, S]``, the
     exit gate's logit on each).  Each block APPLICATION is rematerialised
-    (keeping its flash kernel's output and log-sum-exp) and named
-    ``block_out`` on its own.
+    and named ``block_out`` on its own.
+
+    What block remat (``cfg.remat_block``) keeps of a block application
+    beside its inputs: the flash kernel's output and log-sum-exp
+    (``ops.flash_attention.SAVED_NAMES``) and the delta rule's kernel's
+    output, final state and entering states
+    (``ops.gated_delta.SAVED_NAMES``) — what costs as much to recompute as
+    to compute and is small to keep, so neither forward kernel runs again
+    in front of the block's backward.  Everything else of the block does.
 
     With ``cfg.mtp_layers`` and ``next_tokens`` ([B, S], token i+1 under
     position i: the targets) the multi-token-prediction block runs too
@@ -1820,7 +1832,7 @@ def forward_hidden(
         apply = jax.checkpoint(
             apply, static_argnums=(2,),
             policy=jax.checkpoint_policies.save_only_these_names(
-                *FLASH_SAVED_NAMES))
+                *FLASH_SAVED_NAMES, *GDN_SAVED_NAMES))
     streams, exit_logits = [], []
     for _ in range(cfg.loop_passes):
         for i, layer in enumerate(params["layers"]):

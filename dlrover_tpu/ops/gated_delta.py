@@ -60,6 +60,15 @@ chunk)`` with the chunk axis last and ``"arbitrary"``:
   Neither the scan body's ``jax.checkpoint`` nor :func:`unit_lower_inverse`'s
   ``custom_vjp`` is on this path.
 
+The forward kernel's three outputs reach the backward rule under the names
+of :data:`SAVED_NAMES` (``o``, the final state, the entering states), as
+``ops/flash_attention.py``'s two do: a remat policy that keeps them
+(``models/llama.py``'s block remat does) runs ``gdn_chunk_fwd`` once per
+layer application, not again in front of the block's backward, and holds
+those of the three that the backward reads.  The names are identities under
+any other policy, and the ``jax.numpy`` form, which keeps its scan body's
+own checkpoint, emits none.
+
 No array with a ``[Q, Q]`` dimension and no float32 state is written to or
 read from HBM in either.  ``gamma`` (a cumulative sum), its layout and the
 least decay are ``jax.numpy`` around the kernels.  The rule
@@ -76,11 +85,15 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.ops.flash_attention import NEG_INF as _NEG, _vmem_params
 from dlrover_tpu.ops.per_shard import P as Spec, per_shard, shard_axes
 
 F32 = jnp.float32
+#: the forward kernel's outputs as the backward rule receives them: ``o``, the
+#: final state, the states that entered the chunks (the module's docstring)
+SAVED_NAMES = ("gdn_out", "gdn_state", "gdn_entering")
 
 
 @jax.custom_vjp
@@ -611,7 +624,8 @@ def _chunked_kernels(q, k, v, g, b, dims, interpret):
 
 
 def _kernels_fwd(q, k, v, g, b, dims, interpret):
-    o, final, entering = _chunk_fwd(q, k, v, g, b, dims, interpret, True)
+    o, final, entering = map(checkpoint_name, _chunk_fwd(
+        q, k, v, g, b, dims, interpret, True), SAVED_NAMES)
     return (o, final), (q, k, v, g, b, entering)
 
 

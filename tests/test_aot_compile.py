@@ -943,10 +943,10 @@ def test_gdn_step_sizes_its_sorted_buffer_and_keeps_the_rule_in_vmem(
         gdn_step):
     """Two sequences of 8,192 tokens take 10 of 512 experts and the chip
     holds 32: the routed blocks choose between 12,800 rows and all 163,840.
-    The rule is the kernel pair: under block remat and the mixer's own
-    checkpoint the step journals ``gdn_chunk_fwd`` three times a delta-rule
-    layer (forward, the block's recomputation, the mixer's) and
-    ``gdn_chunk_bwd`` once; no instruction under ``gdn_scan`` — a fusion's
+    The rule is the kernel pair: block remat keeps the forward kernel's
+    three outputs and the mixer has no checkpoint of its own, so the step
+    journals ``gdn_chunk_fwd`` once a delta-rule layer and ``gdn_chunk_bwd``
+    once; no instruction under ``gdn_scan`` — a fusion's
     inner ones included — has a result or an operand with two chunk-length
     dimensions (the ``[Q, Q]`` arrays stay in VMEM) or is a loop (the scan
     over the 128 chunks is the kernels' grid), and the step's peak is under
@@ -956,8 +956,7 @@ def test_gdn_step_sizes_its_sorted_buffer_and_keeps_the_rule_in_vmem(
     job, text, cfg = gdn_step
     assert llama._moe_buffer_bounds(2 * 8192, 10, 512, 32) == (12800, 163840)
     kernels, layers = job.program["kernels"], job.program["gdn_layers"]
-    assert kernels["gdn_chunk_fwd"] == 3 * layers
-    assert kernels["gdn_chunk_bwd"] == layers
+    assert kernels["gdn_chunk_fwd"] == kernels["gdn_chunk_bwd"] == layers
     q, seen = llama.GDN_CHUNK, 0
     for line in text.splitlines():
         if "gdn_scan" not in line or " = " not in line:
@@ -984,13 +983,13 @@ def test_gdn_step_token_side_builds_no_pick_sized_array(gdn_step):
 
 
 def test_gdn_step_keeps_the_convolutions_float32_in_vmem(gdn_step):
-    """Under block remat and the mixer's own checkpoint the step journals
-    ``conv_silu_fwd`` three times a delta-rule layer and ``conv_silu_bwd``
+    """Under block remat, which keeps nothing of the convolution, the step
+    journals ``conv_silu_fwd`` twice a delta-rule layer and ``conv_silu_bwd``
     once; what XLA keeps under ``gdn_conv`` is the concatenation of ``q``,
     ``k`` and ``v`` in bf16 and its transpose."""
     job, text, cfg = gdn_step
     kernels, layers = job.program["kernels"], job.program["gdn_layers"]
-    assert kernels["conv_silu_fwd"] == 3 * layers
+    assert kernels["conv_silu_fwd"] == 2 * layers
     assert kernels["conv_silu_bwd"] == layers
     _conv_scope_holds_no_float32_sequence(
         text, "gdn_conv", (2 * cfg.gdn_k_heads + cfg.gdn_v_heads)

@@ -332,9 +332,9 @@ def test_bf16_streams_keep_the_rule_in_float32():
 def test_the_mixer_through_the_convolutions_kernels_equals_the_numpy_form(
         dtype, monkeypatch):
     """``_gdn_mixer`` with ``ops.conv_silu``'s kernel pair (interpret mode)
-    against the mixer with the ``jax.numpy`` form, through the mixer's own
-    checkpoint: the output, the stream's gradient and every leaf's, at the
-    tolerances this file holds the mixer to against the loop over positions
+    against the mixer with the ``jax.numpy`` form: the output, the stream's
+    gradient and every leaf's, at the tolerances this file holds the mixer
+    to against the loop over positions
     (``A_log``'s gradient is a sum over 1,024 positions that cancels: 3e-5
     here), on two row tiles of 128 channels (one key head of 32 under two
     value heads)."""
