@@ -51,9 +51,15 @@ from dlrover_tpu.ops.ssd import causal_conv1d, ssd_chunked
 #: with its block scope, which is also the key of the layer dict that holds
 #: its leaves (the attention leaves sit in the layer dict itself) and, with
 #: ``_layers``, the name :func:`program_facts` counts its layers under.  A
-#: new kind adds a row.
+#: new kind adds a row.  The two attention kinds share scope, leaves and
+#: :func:`_attention`: a "window_attention" layer attends the last
+#: ``sliding_window`` positions where an "attention" layer of the same model
+#: attends them all.
 MIXER_KINDS = {"attention": "attention", "mamba": "ssm", "conv": "conv",
-               "linear_attention": "gdn"}
+               "linear_attention": "gdn", "window_attention": "attention"}
+#: the attention kinds, each with the scope around its flash call INSIDE the
+#: block's ``attention`` (entered where a model has layers of both)
+ATTENTION_KINDS = {"attention": "attn_full", "window_attention": "attn_window"}
 #: what ``LlamaConfig.layer_types`` may name as a layer's ONLY branch where
 #: ``one_branch``: the dense MLP and the routed block, each by the key of the
 #: layer dict that holds its leaves
@@ -64,6 +70,53 @@ MLP_FORMS = ("swiglu", "relu2")
 #: positions a chunk of the gated delta rule holds (``ops.gated_delta``): a
 #: shape decision of the op, not a setting
 GDN_CHUNK = 64
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """The rotary table of one kind of attention layer
+    (``LlamaConfig.rotary_by_kind``): the base and, where ``factor`` is not
+    1, YaRN's blend (arXiv:2309.00071 as HF's ``_compute_yarn_parameters``
+    computes it).  With ``f_j = theta^(-j / half)`` and ``d(n) = dim *
+    ln(original_max_position_embeddings / (2 pi n)) / (2 ln theta)``: ``low
+    = floor(d(beta_fast))``, ``high = ceil(d(beta_slow))`` clipped to ``[0,
+    dim - 1]``, ``ramp_j = clip((j - low) / (high - low), 0, 1)`` and
+    ``inv_freq_j = f_j / factor * ramp_j + f_j * (1 - ramp_j)``: the fast
+    dims keep their frequency, the slow ones are stretched ``factor`` times.
+    Cos and sin are both multiplied by ``attention_factor``, so the scores
+    carry its square."""
+
+    theta: float
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def correction_range(self, dim: int) -> tuple:
+        """YaRN's ``(low, high)`` over the ``dim`` rotary dims of a head."""
+        def d(rotations):
+            return (dim * np.log(self.original_max_position_embeddings
+                                 / (rotations * 2 * np.pi))
+                    / (2 * np.log(self.theta)))
+
+        return (max(int(np.floor(d(self.beta_fast))), 0),
+                min(int(np.ceil(d(self.beta_slow))), dim - 1))
+
+    def inv_freq(self, dim: int) -> jax.Array:
+        """float32 ``[dim / 2]``; at ``factor`` 1 the plain table's, bit for
+        bit (:func:`_rope` computes the same expression)."""
+        half = dim // 2
+        freqs = 1.0 / (
+            self.theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
+        )
+        if self.factor == 1.0:
+            return freqs
+        low, high = self.correction_range(dim)
+        ramp = jnp.clip(
+            (jnp.arange(half, dtype=jnp.float32) - low)
+            / (high - low if high != low else 0.001), 0.0, 1.0)
+        return freqs / self.factor * ramp + freqs * (1.0 - ramp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,8 +155,17 @@ class LlamaConfig:
     qk_norm_per_head: bool = False
     # Sliding-window attention (>0: each position attends the last
     # `sliding_window` positions only — Mistral-style long-context;
-    # flash path only, kernels skip out-of-window blocks).
+    # flash path only, kernels skip out-of-window blocks).  Where
+    # ``layer_types`` names "window_attention" layers it is THEIR window and
+    # the "attention" layers attend every earlier position; where it names
+    # none, every attention layer has it.
     sliding_window: int = 0
+    # The rotary table of a KIND of attention layer where it is not the
+    # plain one at ``rope_theta``: ``{kind: Rotary}`` (kept as a sorted
+    # tuple of pairs), kinds of :data:`ATTENTION_KINDS` that ``layer_types``
+    # names.  The tables are then built once a step and kind
+    # (:func:`forward_hidden`), not once a layer.
+    rotary_by_kind: tuple = ()
     # Per-block rematerialization: save the residual stream at layer
     # boundaries and the flash kernel's output and log-sum-exp (of a
     # delta-rule layer its kernel's three outputs: ``forward_hidden``),
@@ -341,6 +403,34 @@ class LlamaConfig:
             raise ValueError(
                 f"LlamaConfig: mlp_form={self.mlp_form!r} is none of "
                 f"{MLP_FORMS}")
+        if self.window_layers and (
+                self.sliding_window <= 0 or self.kv_lora_rank > 0):
+            raise ValueError(
+                f"LlamaConfig: 'window_attention' layers with "
+                f"sliding_window={self.sliding_window} or kv_lora_rank="
+                f"{self.kv_lora_rank}: the kind attends the last "
+                "sliding_window > 0 positions through the plain q, k and v "
+                "projections, not latent attention's")
+        by_kind = self.rotary_by_kind
+        object.__setattr__(self, "rotary_by_kind", tuple(sorted(
+            by_kind.items() if isinstance(by_kind, dict) else by_kind)))
+        for kind, rotary in self.rotary_by_kind:
+            if (kind not in ATTENTION_KINDS or not self.layers_of(kind)
+                    or not self.rope or self.kv_lora_rank > 0):
+                raise ValueError(
+                    f"LlamaConfig: rotary_by_kind names {kind!r} with "
+                    f"layer_types={kinds}, rope={self.rope} and "
+                    f"kv_lora_rank={self.kv_lora_rank}: a rotary table "
+                    f"belongs to a kind of {tuple(ATTENTION_KINDS)} that "
+                    "the model has a layer of, under rotary position on "
+                    "the plain q and k projections")
+            if not isinstance(rotary, Rotary) or rotary.factor < 1.0 or (
+                    rotary.factor != 1.0
+                    and rotary.original_max_position_embeddings <= 0):
+                raise ValueError(
+                    f"LlamaConfig: rotary_by_kind[{kind!r}]={rotary!r} is "
+                    "no Rotary with factor >= 1 and, where it scales, "
+                    "original_max_position_embeddings > 0")
         if self.one_branch and (
                 ("moe" in kinds) != (self.num_experts > 0)
                 or self.loop_passes > 1 or self.mtp_layers
@@ -456,7 +546,21 @@ class LlamaConfig:
 
     @property
     def attention_layers(self) -> int:
-        return self.layers_of("attention")
+        """Layers whose mixer is attention, of either kind."""
+        return sum(self.layers_of(kind) for kind in ATTENTION_KINDS)
+
+    @property
+    def window_layers(self) -> int:
+        """Layers of the "window_attention" kind."""
+        return self.layers_of("window_attention")
+
+    def window_of(self, kind: str) -> int:
+        """The window a layer of an attention ``kind`` attends (0: every
+        earlier position): ``sliding_window`` for the "window_attention"
+        kind and, where the model has no layer of that kind, for all."""
+        if kind == "window_attention" or not self.window_layers:
+            return self.sliding_window
+        return 0
 
     @property
     def gdn_conv_dim(self) -> int:
@@ -645,7 +749,7 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: Optional[bool],
     k = jax.random.split(key, 8)
     more = jax.random.split(jax.random.fold_in(key, 1), 5)
     hd = cfg.head_dim
-    attention = mixer == "attention"
+    attention = mixer in ATTENTION_KINDS
     gated = cfg.mlp_form == "swiglu"
     layer = {}
     if mixer is not None:
@@ -772,7 +876,7 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
         """As :func:`_init_layer`: ``mixer`` None is a layer without a
         mixer half, ``has_moe`` None one without an MLP half."""
         ax = {"ln1": (None,), "wo": ("heads", "embed"), "ln2": (None,)}
-        attention = mixer == "attention"
+        attention = mixer in ATTENTION_KINDS
         if mixer is None:
             del ax["ln1"], ax["wo"]
         if has_moe is None:
@@ -854,16 +958,24 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
     return axes
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, S, H, D]; rotate pairs (d, d + D/2)."""
-    B, S, H, D = x.shape
-    half = D // 2
-    freqs = 1.0 / (
-        theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
-    )
+def _rotary_table(positions: jax.Array, rotary: Rotary, dim: int) -> tuple:
+    """``(cos, sin)``, float32 ``[B, S, 1, dim / 2]``, of integer
+    ``positions [B, S]``: the angles ``position * inv_freq_j``, both scaled
+    by ``rotary.attention_factor`` where it is not 1."""
+    freqs = rotary.inv_freq(dim)
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]
     cos = jnp.cos(angles)[:, :, None, :]  # [B, S, 1, half]
     sin = jnp.sin(angles)[:, :, None, :]
+    if rotary.attention_factor != 1.0:
+        cos, sin = (cos * rotary.attention_factor,
+                    sin * rotary.attention_factor)
+    return cos, sin
+
+
+def _rotate(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """x: [B, S, H, D]; rotate pairs (d, d + D/2) by a table of
+    :func:`_rotary_table`."""
+    half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     return jnp.concatenate(
@@ -871,16 +983,29 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     ).astype(x.dtype)
 
 
-def _rope_part(x: jax.Array, positions: jax.Array, cfg: "LlamaConfig"):
+def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """x: [B, S, H, D]; rotate pairs (d, d + D/2) by the plain table at
+    ``theta``, built here."""
+    return _rotate(x, *_rotary_table(positions, Rotary(theta), x.shape[-1]))
+
+
+def _rope_part(x: jax.Array, positions: jax.Array, cfg: "LlamaConfig",
+               table: Optional[tuple] = None):
     """:func:`_rope` over the first ``cfg.rotary_dim`` dims of each head
     (all of them at ``partial_rotary_factor`` 1), pairs ``(j, j +
-    rotary_dim / 2)``; the other dims pass untouched."""
+    rotary_dim / 2)``; the other dims pass untouched.  ``table``: the
+    layer's kind's ``(cos, sin)`` where the step built one
+    (``cfg.rotary_by_kind``), in place of the plain one at
+    ``cfg.rope_theta``."""
+    def turn(part):
+        if table is not None:
+            return _rotate(part, *table)
+        return _rope(part, positions, cfg.rope_theta)
+
     rot = cfg.rotary_dim
     if rot == x.shape[-1]:
-        return _rope(x, positions, cfg.rope_theta)
-    return jnp.concatenate(
-        [_rope(x[..., :rot], positions, cfg.rope_theta), x[..., rot:]],
-        axis=-1)
+        return turn(x)
+    return jnp.concatenate([turn(x[..., :rot]), x[..., rot:]], axis=-1)
 
 
 def qk_normed(q, k, layer, cfg: "LlamaConfig"):
@@ -949,8 +1074,12 @@ def _mla_qkv(x, layer, cfg: LlamaConfig, positions) -> tuple:
 
 def _attention(
     x, layer, cfg: LlamaConfig, positions, attn_impl: str, mesh,
-    segment_ids=None,
+    segment_ids=None, kind: str = "attention", rotary=None,
 ):
+    """Both attention kinds (:data:`ATTENTION_KINDS`): ``kind`` sets the
+    window (``cfg.window_of``) and, in a model with layers of both, the
+    scope around the flash call; ``rotary`` is the kind's ``(cos, sin)``
+    where the step built one (``cfg.rotary_by_kind``)."""
     B, S, C = x.shape
     H, KV, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     dt = cfg.dtype
@@ -970,8 +1099,8 @@ def _attention(
         q, k = qk_normed(q, k, layer, cfg)
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
         if cfg.rope:
-            q = _rope_part(q, positions, cfg)
-            k = _rope_part(k, positions, cfg)
+            q = _rope_part(q, positions, cfg, rotary)
+            k = _rope_part(k, positions, cfg, rotary)
         v = v.reshape(B, S, KV, D)
     if cfg.attention_multiplier is not None:
         # every backend scales the scores by 1 / sqrt(D): the rest of the
@@ -984,9 +1113,11 @@ def _attention(
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
 
-    if cfg.sliding_window > 0 and attn_impl in ("ring", "ulysses"):
+    window = cfg.window_of(kind)
+    if window > 0 and attn_impl in ("ring", "ulysses"):
         raise NotImplementedError(
-            "sliding_window requires the flash attention path"
+            f"sliding_window (a {kind!r} layer's window of {window}) "
+            f"requires the flash attention path, not {attn_impl!r}"
         )
     if attn_impl == "ring" and mesh is not None:
         if segment_ids is not None:
@@ -1007,17 +1138,21 @@ def _attention(
 
         out = ulysses_attention(q, k, v, mesh, causal=True)
     else:
-        # [B,S,H,D] -> [B,H,S,D] for the flash kernel.
-        o = flash_attention(
-            q.transpose(0, 2, 1, 3),
-            k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            causal=True,
-            segment_ids=segment_ids,
-            backend=None if attn_impl == "auto" else attn_impl,
-            window=cfg.sliding_window,
-        )
-        out = o.transpose(0, 2, 1, 3)
+        # [B,S,H,D] -> [B,H,S,D] for the flash kernel; where the model has
+        # both kinds the call says which it is (``attn_window`` /
+        # ``attn_full``, inside the block's ``attention``)
+        with (jax.named_scope(ATTENTION_KINDS[kind]) if cfg.window_layers
+              else contextlib.nullcontext()):
+            o = flash_attention(
+                q.transpose(0, 2, 1, 3),
+                k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3),
+                causal=True,
+                segment_ids=segment_ids,
+                backend=None if attn_impl == "auto" else attn_impl,
+                window=window,
+            )
+            out = o.transpose(0, 2, 1, 3)
     if gate is not None:
         out = (out.astype(jnp.float32)
                * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
@@ -1635,6 +1770,8 @@ def block_apply(
     segment_ids=None,
     attn_fn=None,  # (h, layer, cfg, positions) -> attn out; overrides
     moe_capacity: Optional[int] = None,
+    attn_kind: str = "attention",
+    rotary=None,
 ) -> tuple:
     """One transformer block: (x, layer) -> (x, stats).  The mixer is the
     one the layer dict holds (:data:`MIXER_KINDS`) — a state-space one
@@ -1652,7 +1789,11 @@ def block_apply(
     ``experts``, ``tokens_per_expert``); empty for a dense attention layer.
     The unit the pipeline stage partitioner groups (``models.llama_pp``).
     ``attn_fn`` swaps the attention implementation (the KV-cache decoder
-    plugs in here, so train and decode share one block wiring)."""
+    plugs in here, so train and decode share one block wiring).  The two
+    attention kinds hold the same leaves, so ``attn_kind`` (of
+    :data:`ATTENTION_KINDS`) says which an attention layer is, and
+    ``rotary`` hands it its kind's ``(cos, sin)`` where the step built
+    one."""
     # The scopes (``attention``, ``mlp``, and the routed block's four:
     # ``moe_router`` with its norm, ``moe_permute``, ``moe_experts``,
     # ``moe_combine`` with the residual add) go into every instruction's
@@ -1690,7 +1831,8 @@ def block_apply(
                 mixed = attn_fn(h, layer, cfg, positions)
             else:
                 mixed = _attention(
-                    h, layer, cfg, positions, attn_impl, mesh, segment_ids)
+                    h, layer, cfg, positions, attn_impl, mesh, segment_ids,
+                    attn_kind, rotary)
             if cfg.branch_norm:
                 mixed = rmsnorm(mixed, layer["ln1_out"], eps=cfg.rms_eps)
             x = add(x, mixed)
@@ -1824,19 +1966,33 @@ def forward_hidden(
         if "buffer_rows" in stats:
             buffer_rows.append(stats["buffer_rows"])
 
-    apply = functools.partial(
-        block_apply, attn_impl=attn_impl, mesh=mesh,
-        segment_ids=segment_ids,
-    )
-    if cfg.remat_block:
-        apply = jax.checkpoint(
-            apply, static_argnums=(2,),
-            policy=jax.checkpoint_policies.save_only_these_names(
-                *FLASH_SAVED_NAMES, *GDN_SAVED_NAMES))
+    def applier(**of_the_kind):
+        fn = functools.partial(
+            block_apply, attn_impl=attn_impl, mesh=mesh,
+            segment_ids=segment_ids, **of_the_kind)
+        if cfg.remat_block:
+            fn = jax.checkpoint(
+                fn, static_argnums=(2,),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *FLASH_SAVED_NAMES, *GDN_SAVED_NAMES))
+        return fn
+
+    apply = applier()
+    # the window kind's layers say so to the block they share with the full
+    # kind's; a kind with a rotary table of its own is handed it, built
+    # here once for all its layers
+    apply_by_kind = {"window_attention": applier(
+        attn_kind="window_attention")} if cfg.window_layers else {}
+    with jax.named_scope("rotary"):
+        tables = {kind: {"rotary": _rotary_table(
+            positions, rotary, cfg.rotary_dim)}
+            for kind, rotary in cfg.rotary_by_kind}
     streams, exit_logits = [], []
     for _ in range(cfg.loop_passes):
         for i, layer in enumerate(params["layers"]):
-            x, stats = apply(layer, x, cfg, positions)
+            kind = cfg.mixer_kind(i)
+            x, stats = apply_by_kind.get(kind, apply)(
+                layer, x, cfg, positions, **tables.get(kind, {}))
             # Identity unless a remat policy references the name: lets
             # Strategy(remat="offload") park the inter-block residual
             # stream in host DRAM (reference
@@ -2245,7 +2401,8 @@ def exit_expectation_loss(x, exit_logits, lm_head, targets,
 #: hold every expert, and know one head of their own and no scalar on the
 #: stream.  For ``layer_types`` the value is the one kind of layer they
 #: know (a state-space or a convolution mixer's decode needs recurrent
-#: state beside keys and values).  A new architecture adds a row.
+#: state beside keys and values, a window kind beside a full one a cache of
+#: two entry sizes).  A new architecture adds a row.
 TRAINING_PATH_ONLY = (
     ("loop_passes", 1, "layers applied more than once"),
     ("branch_norm", False, "a norm on each branch's output"),
@@ -2253,7 +2410,8 @@ TRAINING_PATH_ONLY = (
     ("kv_lora_rank", 0, "latent attention"),
     ("experts_held", 0, "a share of the experts"),
     ("mtp_layers", 0, "the multi-token-prediction block"),
-    ("layer_types", "attention", "a layer whose mixer is not attention"),
+    ("layer_types", "attention",
+     "a layer whose mixer is not attention over every earlier position"),
     ("rope", True, "attention without rotary position"),
     ("attention_multiplier", None, "attention at a stated scale"),
     ("embedding_multiplier", 1.0, "a scalar on the embedding"),
@@ -2267,6 +2425,7 @@ TRAINING_PATH_ONLY = (
     ("shared_expert_gate", False, "a gate on the shared expert"),
     ("one_branch", False, "layers that are one branch each"),
     ("mlp_form", "swiglu", "an MLP that is not SwiGLU"),
+    ("rotary_by_kind", (), "a rotary table of a kind of layer's own"),
 )
 
 
@@ -2304,11 +2463,24 @@ def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:
     (``mlp_form``) and, of a routed model, the backend its experts' grouped
     matmuls take at their widths on this device (``moe_expert_backend``:
     ``ops.grouped_matmul.backend_for``; the fall-back buffer of every pick
-    takes the reference whatever the widths).  Empty for every other
-    model."""
+    takes the reference whatever the widths).  A model with
+    "window_attention" layers says how many they are
+    (``window_attention_layers``, of ``attention_layers``) and the pairs one
+    sequence attends in a layer of each kind
+    (``attn_window_pairs_per_sequence``, ``attn_full_pairs_per_sequence``).
+    Empty for every other model."""
     facts = {f"{scope}_layers": cfg.layers_of(kind)
              for kind, scope in MIXER_KINDS.items()
-             if kind != "attention" and cfg.layers_of(kind)}
+             if kind not in ATTENTION_KINDS and cfg.layers_of(kind)}
+    if cfg.window_layers:
+        # both kinds run under the ``attention`` scope: the count of the
+        # window kind's layers beside that of all, and the (query, key)
+        # pairs a layer of each kind attends in a sequence, by the scope
+        # around its flash call
+        facts["window_attention_layers"] = cfg.window_layers
+        for kind, scope in ATTENTION_KINDS.items():
+            facts[f"{scope}_pairs_per_sequence"] = attended_pairs(
+                seq_len, cfg.window_of(kind))
     if facts:
         facts["attention_layers"] = cfg.attention_layers
     # the chunks a recurrent mixer's scan carries its state over
@@ -2324,6 +2496,15 @@ def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:
             facts["moe_expert_backend"] = expert_backend_for(
                 cfg.dtype, cfg.d_model, cfg.expert_width)
     return facts
+
+
+def attended_pairs(seq_len: int, window: int) -> int:
+    """(query, key) pairs of one causal sequence: query ``t`` attends the
+    keys ``s`` with ``0 <= t - s < window`` (``window`` 0: every ``s <=
+    t``)."""
+    if window <= 0 or window >= seq_len:
+        return seq_len * (seq_len + 1) // 2
+    return window * (window + 1) // 2 + (seq_len - window) * window
 
 
 def num_params(params: Dict) -> int:
@@ -2347,7 +2528,8 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     "mlp" layer its MLP alone and a "moe" layer its router, its shared
     expert and the share of a token's ``top_k`` picks that meet an expert
     held here (every other routed model's routed layers count as dense ones
-    of ``d_ff``, as they always have)."""
+    of ``d_ff``, as they always have).  A "window_attention" layer's scores
+    are counted over its window's keys."""
     mats = 3 if cfg.mlp_form == "swiglu" else 2
     if cfg.kv_lora_rank > 0:  # latent attention's five projections
         qkv = (
@@ -2372,8 +2554,13 @@ def flops_per_token(cfg: LlamaConfig) -> float:
         head += cfg.d_model
     dense = (cfg.block_applications * p_layer + cfg.loop_passes * head
              + cfg.vocab_size * cfg.d_model)
-    attn = (2 * cfg.block_applications * cfg.max_seq_len
-            * cfg.n_head * cfg.head_dim)
+    # a "window_attention" layer meets ``sliding_window`` keys a query, not
+    # the sequence's all (one global window on every layer counts the whole
+    # sequence, as it always has)
+    keys = (cfg.block_applications * cfg.max_seq_len
+            - cfg.window_layers * cfg.loop_passes
+            * max(cfg.max_seq_len - cfg.sliding_window, 0))
+    attn = 2 * keys * cfg.n_head * cfg.head_dim
     inner, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
     p_ssm = (cfg.d_model * (inner + conv + cfg.mamba_n_heads)  # in_proj
              + inner * cfg.d_model  # out_proj

@@ -432,7 +432,11 @@ def program_summary(hlo_text: str) -> dict:
     collectives are counted by opcode (async ``-start`` forms included
     once); ``scopes`` is :func:`scope_table`, and ``subscopes`` (only
     where the program nests scopes of its own) :func:`scope_tables`'
-    second table.  ``accelerate()`` adds the loss function's
+    second table; ``kernel_scopes`` (only where a Mosaic kernel's call sits
+    under nested scopes) names, by the calling instruction, the innermost
+    scope ABOVE the kernel's own name, which is all ``subscopes`` says of a
+    kernel (``attention/attn_window/flash_fwd/pallas_call``:
+    ``attn_window``).  ``accelerate()`` adds the loss function's
     ``program_facts`` attribute (a dict; ``models.llama.program_facts``:
     ``ssm_layers``, ``conv_layers``, ``gdn_layers``, ``attention_layers``,
     ``ssm_chunks_per_sequence``, ``gdn_chunks_per_sequence`` of a model
@@ -440,6 +444,7 @@ def program_summary(hlo_text: str) -> dict:
     Block remat names nothing of a delta-rule layer to keep: its mixer
     recomputes from its projections' outputs (``llama._gdn_mixer``)."""
     kernels: dict = {}
+    kernel_scopes: dict = {}
     applications = 0
     for line in hlo_text.splitlines():
         if 'custom_call_target="tpu_custom_call"' not in line:
@@ -447,6 +452,12 @@ def program_summary(hlo_text: str) -> dict:
         m = re.search(r'op_name="([^"]*?(\w+)\)*/pallas_call)', line)
         name = m.group(2) if m else "unnamed"
         kernels[name] = kernels.get(name, 0) + 1
+        called = _INSTRUCTION.match(line)
+        if m and called:
+            # the path above ``<kernel>/pallas_call``
+            above = list(_program_scopes(m.group(1).split("/")[:-2]))
+            if len(above) > 1 and above[-1] != above[0]:
+                kernel_scopes[called.group(1)] = above[-1]
         if name == BLOCK_KERNEL:
             verdict = phase_and_scope(m.group(1))
             applications += verdict is None or verdict[0] not in (
@@ -463,6 +474,8 @@ def program_summary(hlo_text: str) -> dict:
     # ``kernels`` already
     if set(inners.values()) - set(kernels):
         summary["subscopes"] = inners
+    if kernel_scopes:
+        summary["kernel_scopes"] = kernel_scopes
     return summary
 
 
