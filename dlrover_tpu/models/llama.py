@@ -37,6 +37,7 @@ from dlrover_tpu.ops.gated_delta import (
     SAVED_NAMES as GDN_SAVED_NAMES,
     gated_delta_chunked,
 )
+from dlrover_tpu.ops.gated_norm import gated_norm
 from dlrover_tpu.ops.gather_sum import gather_sum, weighted_sum
 from dlrover_tpu.ops.grouped_matmul import (
     TILING,
@@ -1170,12 +1171,13 @@ def _ssm_mixer(u, ssm, cfg: LlamaConfig) -> tuple:
     (``ops.ssd.ssd_chunked`` at ``cfg.mamba_chunk_size``) gives ``y_t = h_t
     C_t + D x_t``; ``y = rms(y * silu(z)) * norm`` — the gate BEFORE the
     norm, the mean square taken over each of ``cfg.mamba_n_groups`` groups
-    of the width by itself (:func:`_rms_per_group`; one group: the RMSNorm
-    kernel over the whole width); ``out = y out_proj``.  Scopes
-    ``ssm_in``, ``ssm_conv``, ``ssm_scan``, ``ssm_gate`` and ``ssm_out`` sit
-    inside the block's ``ssm``.  ``stats``: ``ssm_state_rms`` (of the state
-    the sequence leaves) and ``ssm_decay_min`` (the least ``exp(sum dt A)``
-    over a chunk: 0 says a chunk's decay underflowed float32)."""
+    of the width by itself (``ops.gated_norm``, gate and norm one pass; one
+    group: the RMSNorm kernel over the whole width); ``out = y out_proj``.
+    Scopes ``ssm_in``, ``ssm_conv``, ``ssm_scan``, ``ssm_gate`` and
+    ``ssm_out`` sit inside the block's ``ssm``.  ``stats``:
+    ``ssm_state_rms`` (of the state the sequence leaves) and
+    ``ssm_decay_min`` (the least ``exp(sum dt A)`` over a chunk: 0 says a
+    chunk's decay underflowed float32)."""
     B, S, _ = u.shape
     H, P, G, N = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_n_groups,
                   cfg.mamba_d_state)
@@ -1199,25 +1201,15 @@ def _ssm_mixer(u, ssm, cfg: LlamaConfig) -> tuple:
             "ssm_state_rms": jnp.sqrt(jnp.mean(jnp.square(state))),
             "ssm_decay_min": decay_min})
     with jax.named_scope("ssm_gate"):
-        y = y.reshape(B, S, inner) * jax.nn.silu(z.astype(jnp.float32))
+        y = y.reshape(B, S, inner)
         if G == 1:
+            y = y * jax.nn.silu(z.astype(jnp.float32))
             y = rmsnorm(y.astype(dt), ssm["norm"], eps=cfg.rms_eps)
         else:
-            y = _rms_per_group(y, ssm["norm"], G, cfg.rms_eps).astype(dt)
+            y = gated_norm(y, z, ssm["norm"], group=inner // G,
+                           eps=cfg.rms_eps, gate_first=True)
     with jax.named_scope("ssm_out"):
         return y @ ssm["out_proj"].astype(dt), stats
-
-
-def _rms_per_group(x, gain, groups: int, eps: float):
-    """float32 ``x [..., W]`` -> the same, each of ``groups`` equal groups
-    of the last dim RMS-normalised by its own mean square, times ``gain
-    [W]`` (HF's ``MambaRMSNormGated`` at ``group_size = W / groups``):
-    elementwise work and a short reduction that XLA fuses with the gate in
-    front of it."""
-    parts = x.reshape(x.shape[:-1] + (groups, -1))
-    inv = jax.lax.rsqrt(
-        jnp.mean(jnp.square(parts), axis=-1, keepdims=True) + eps)
-    return (parts * inv).reshape(x.shape) * gain
 
 
 def _conv_mixer(u, conv, cfg: LlamaConfig):
@@ -1254,8 +1246,9 @@ def _gdn_mixer(u, gdn, cfg: LlamaConfig) -> tuple:
     ``R`` value heads; the gated delta rule
     (``ops.gated_delta.gated_delta_chunked`` at :data:`GDN_CHUNK`); ``y =
     norm * rms(o) * silu(z)`` per head in float32 — the norm BEFORE the
-    gate, a plain gain; ``out = y out_proj``.  Scopes ``gdn_in``,
-    ``gdn_conv``, ``gdn_scan``, ``gdn_gate`` and ``gdn_out`` sit inside the
+    gate, a plain gain (``ops.gated_norm``, a head a group); ``out = y
+    out_proj``.  Scopes ``gdn_in``, ``gdn_conv``, ``gdn_scan``, ``gdn_gate``
+    and ``gdn_out`` sit inside the
     block's ``gdn``.  ``stats``: ``gdn_state_rms`` (of the state the
     sequence leaves) and ``gdn_decay_min`` (the least ``exp(sum g)`` over a
     chunk: 0 says a chunk's decay underflowed float32, which the rule
@@ -1280,7 +1273,7 @@ def _gdn_mixer(u, gdn, cfg: LlamaConfig) -> tuple:
         qkvz = (u @ gdn["in_proj_qkvz"].astype(dt)).reshape(
             B, S, hk, (2 + 2 * R) * D)
         ba = (u @ gdn["in_proj_ba"].astype(dt)).reshape(B, S, hk, 2 * R)
-    z = qkvz[..., (2 + R) * D:].reshape(B, S, hv, D)
+    z = qkvz[..., (2 + R) * D:].reshape(B, S, hv * D)
     with jax.named_scope("gdn_conv"):
         flat = lambda a: a.reshape(B, S, -1)  # noqa: E731
         qkv = jnp.concatenate(
@@ -1307,10 +1300,9 @@ def _gdn_mixer(u, gdn, cfg: LlamaConfig) -> tuple:
             "gdn_state_rms": jnp.sqrt(jnp.mean(jnp.square(state))),
             "gdn_decay_min": decay_min})
     with jax.named_scope("gdn_gate"):
-        y = o * jax.lax.rsqrt(
-            jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.rms_eps)
-        y = (gdn["norm"] * y) * jax.nn.silu(z.astype(f32))
-    y = y.astype(dt).reshape(B, S, hv * D)
+        # ``o`` in the flat form the rule's kernel writes, a head a group
+        y = gated_norm(o.reshape(B, S, hv * D), z, jnp.tile(gdn["norm"], hv),
+                       group=D, eps=cfg.rms_eps, gate_first=False)
     with jax.named_scope("gdn_out"):
         return y @ gdn["out_proj"].astype(dt), stats
 

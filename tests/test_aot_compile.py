@@ -68,7 +68,7 @@ FLASH_BWD = {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
 OURS = set(FLASH_BWD) | {
     "rmsnorm_fwd", "softmax_xent_fwd", "quantize_blockwise",
     "gdn_chunk_fwd", "gdn_chunk_bwd", "conv_silu_fwd", "conv_silu_bwd",
-    "ssd_chunk_fwd", "ssd_chunk_bwd"}
+    "ssd_chunk_fwd", "ssd_chunk_bwd", "gated_norm_fwd", "gated_norm_bwd"}
 
 
 def _sq(x):
@@ -242,6 +242,28 @@ def _ssd(grad):
             else {"ssd_chunk_fwd": 1})
 
 
+def _gated_norm(grad, batch, group, gate_first):
+    """The gated norm at the two cells' shapes: float32 rows of 4,096
+    columns under a bfloat16 gate, blocks of 1,024 rows by 512 lanes — two
+    sequences of 8,192 in groups of 128, norm then gate (the delta-rule
+    mixer's), three in groups of 512, gate then norm (the state-space
+    mixer's eight)."""
+    from dlrover_tpu.ops.gated_norm import gated_norm
+
+    def fwd(x, z, gain):
+        return gated_norm(x, z, gain, group=group, eps=1e-6,
+                          gate_first=gate_first, backend="pallas")
+
+    fn = fwd
+    if grad:
+        def fn(*ops):
+            return jax.grad(lambda *o: _sq(fwd(*o)), argnums=(0, 1, 2))(*ops)
+    rows = (batch, 8192, 4096)
+    return (fn, [(rows, f32), (rows, bf16), ((4096,), f32)],
+            {"gated_norm_fwd": 1, "gated_norm_bwd": 1} if grad
+            else {"gated_norm_fwd": 1})
+
+
 def _flash_gqa16(grad):
     """32 query heads on 2 key heads of 128 (a group of 16), 8,192
     positions, no window."""
@@ -317,6 +339,10 @@ KERNEL_CASES = {
     "flash_gqa_32_on_2-fwd": lambda: _flash_gqa16(False),
     "flash_gqa_32_on_2-bwd": lambda: _flash_gqa16(True),
     "grouped_matmul_1856-grad": lambda: _grouped_matmul_1856(None),
+    "gated_norm_gdn-fwd": lambda: _gated_norm(False, 2, 128, False),
+    "gated_norm_gdn-grad": lambda: _gated_norm(True, 2, 128, False),
+    "gated_norm_ssm_groups8-fwd": lambda: _gated_norm(False, 3, 512, True),
+    "gated_norm_ssm_groups8-grad": lambda: _gated_norm(True, 3, 512, True),
 }
 
 
@@ -403,10 +429,17 @@ def _sharded_rmsnorm():
             [P(("dp", "fsdp"), None, None), P()], {"rmsnorm_fwd": 1})
 
 
+def _sharded_gated_norm():
+    fn, shapes, kernels = _gated_norm(True, 4, 128, False)
+    rows = P(("dp", "fsdp"), None, None)
+    return fn, shapes, [rows, rows, P()], kernels
+
+
 SHARDED_CASES = {
     "flash_gqa-fwd": lambda: _sharded_flash(False),
     "flash_gqa-bwd": lambda: _sharded_flash(True),
     "rmsnorm-fwd": _sharded_rmsnorm,
+    "gated_norm-grad": _sharded_gated_norm,
 }
 
 

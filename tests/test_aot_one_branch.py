@@ -49,22 +49,27 @@ def step_at_three(topo):  # noqa: F811
 
 def test_the_cell_fits_at_three_sequences_with_a_twentieth_free(
         step_at_three):
-    """Three sequences of 8,192: XLA's buffer assignment peaks at 14.89 GB,
-    11.9 % of ``bytes_limit`` free (the issue's rule: the largest of 4, 3,
+    """Three sequences of 8,192: XLA's buffer assignment peaks at 14.34 GB,
+    15.2 % of ``bytes_limit`` free (the issue's rule: the largest of 4, 3,
     2 that leaves at least 5 %).  The peak is not the routed layers': with
-    their ``[N*K, C]`` arrays gone (PR 56) it reads what it read."""
+    their ``[N*K, C]`` arrays gone (PR 56) it read what it read.  It is
+    partly the mixers': with the gated norm a kernel pair whose residuals
+    are its inputs (PR 60) the float32 arrays XLA's fusions passed between
+    them are gone."""
     job, _, _ = step_at_three
     peak = job.memory["peak_bytes"]
     assert peak <= 0.95 * V5E_BYTES_LIMIT, peak
-    # 14,891,308,544 with ``gather_sum`` on the token side; 14,891,292,160
-    # with XLA's gathers (PR 55)
-    assert 14.6e9 < peak < 15.1e9, peak
+    # 14,336,300,544 since ``ops.gated_norm`` (PR 60); 14,891,308,544 with
+    # ``gather_sum`` on the token side (PR 56); 14,891,292,160 with XLA's
+    # gathers (PR 55)
+    assert 14.0e9 < peak < 14.6e9, peak
 
 
 def test_the_cell_at_three_runs_the_layers_by_kind(step_at_three):
     """Four Mamba-2 layers, one attention layer, four routed ones: under
     block remat the scan's and the convolution's forward kernels twice a
-    layer and their backward once, flash in ONE layer; the experts' width
+    layer and their backward once, and so the gated norm's pair in eight
+    groups, flash in ONE layer; the experts' width
     of 1,856 goes to ``lax.ragged_dot`` (no ``gmm``, no ``tgmm``), which the
     program says of itself; the routed layers choose between 11,776 rows
     and all 147,456; the mixer's five scopes and the routed block's are
@@ -81,6 +86,7 @@ def test_the_cell_at_three_runs_the_layers_by_kind(step_at_three):
     assert (kernels["ssd_chunk_fwd"], kernels["ssd_chunk_bwd"],
             kernels["conv_silu_fwd"], kernels["conv_silu_bwd"]) == (
                 8, 4, 8, 4)
+    assert (kernels["gated_norm_fwd"], kernels["gated_norm_bwd"]) == (8, 4)
     assert (kernels["flash_fwd"], kernels["flash_bwd_dq"],
             kernels["flash_bwd_dkv"]) == (1, 1, 1)
     assert "gmm" not in kernels and "tgmm" not in kernels
@@ -99,7 +105,7 @@ def test_the_cell_at_three_runs_the_layers_by_kind(step_at_three):
     by_inner = {}
     for name, inner in program["subscopes"].items():
         by_inner.setdefault(inner, set()).add(program["scopes"][name][0])
-    for inner in ("ssm_in", "ssm_scan", "ssm_gate"):
+    for inner in ("ssm_in", "ssm_scan"):
         assert {"forward", "backward", "recompute"} <= by_inner[inner], inner
     # nothing of a layer's backward reads ``out_proj``'s product again
     assert {"forward", "backward"} <= by_inner["ssm_out"]
@@ -107,6 +113,10 @@ def test_the_cell_at_three_runs_the_layers_by_kind(step_at_three):
     # names by the kernel
     assert "backward" in by_inner["ssm_conv"]
     assert {"forward", "recompute"} <= by_inner["conv_silu_fwd"]
+    # and so is the gated norm; its backward kernel leaves the gain's sums
+    # to XLA
+    assert "backward" in by_inner["ssm_gate"]
+    assert {"forward", "recompute"} <= by_inner["gated_norm_fwd"]
 
 
 def test_the_cell_at_four_sequences_leaves_under_a_twentieth(topo):  # noqa: F811

@@ -289,20 +289,26 @@ def test_a_layer_is_its_branch_behind_its_norm(i):
 
 @pytest.mark.parametrize("groups", [1, 2, 4, 8])
 def test_the_grouped_norm_equals_a_loop_over_groups(groups):
+    """``ops.gated_norm``'s ``jax.numpy`` form, gate then norm, as the mixer
+    calls it where it has more groups than one."""
+    from dlrover_tpu.ops.gated_norm import gated_norm
+
     x = jax.random.normal(jax.random.PRNGKey(0), (B, S, 64)) * 3.0
+    z = jax.random.normal(jax.random.PRNGKey(2), (B, S, 64))
     gain = 1.0 + 0.3 * jax.random.normal(jax.random.PRNGKey(1), (64,))
-    got = llama._rms_per_group(x, gain, groups, EPS)
+    norm = lambda groups: gated_norm(  # noqa: E731
+        x, z, gain, group=64 // groups, eps=EPS, gate_first=True)
+    got, gated = norm(groups), x * jax.nn.silu(z)
     width = 64 // groups
     parts = []
     for g in range(groups):
-        part = x[..., g * width:(g + 1) * width]
+        part = gated[..., g * width:(g + 1) * width]
         parts.append(part / jnp.sqrt(
             jnp.mean(part * part, -1, keepdims=True) + EPS))
     want = jnp.concatenate(parts, -1) * gain
     assert _rel(got, want) < 1e-6
     if groups > 1:  # and it is not the norm over the whole width
-        whole = llama._rms_per_group(x, gain, 1, EPS)
-        assert _rel(got, whole) > 1e-2
+        assert _rel(got, norm(1)) > 1e-2
 
 
 @pytest.mark.parametrize("groups", [1, 2, 8])

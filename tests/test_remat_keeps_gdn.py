@@ -10,6 +10,10 @@ time in front of the mixer's.  Here, on the CPU, in the manner of
 in interpret mode (the dispatcher would pick the ``jax.numpy`` form, which
 names nothing), the kernel counted in the jaxpr, the saved residuals listed,
 no value changed, and the names doing nothing where no policy asks for them.
+The gated norm behind the rule (``ops.gated_norm``) is steered to its kernels
+too: it names nothing and keeps nothing, so it runs again in block remat's
+pass — ``gated_norm_fwd`` twice a layer and ``gated_norm_bwd`` once, the
+counts a compiled step's kernel table is held to on the chip.
 """
 
 import dataclasses
@@ -30,6 +34,7 @@ from test_remat_keeps_flash import _kernel_calls  # noqa: I100 - shared
 
 from dlrover_tpu.models import llama
 from dlrover_tpu.ops import gated_delta as gd
+from dlrover_tpu.ops import gated_norm as gn
 from dlrover_tpu.parallel.accelerate import REMAT_POLICIES
 
 #: two chunks of ``llama.GDN_CHUNK`` a sequence, so a state ENTERS a chunk
@@ -43,6 +48,8 @@ HV, D = 2, 128
 def kernels(monkeypatch):
     monkeypatch.setattr(llama, "gated_delta_chunked", functools.partial(
         gd.gated_delta_chunked, backend="pallas", interpret=True))
+    monkeypatch.setattr(llama, "gated_norm", functools.partial(
+        gn.gated_norm, backend="pallas", interpret=True))
 
 
 def _cfg(**over):
@@ -86,6 +93,9 @@ def test_the_gradient_runs_gdn_chunk_fwd_once_per_layer(kernels, remat):
     calls = _kernel_calls(_grad_jaxpr(cfg, params, batch))
     assert cfg.gdn_layers == 2
     assert calls["gdn_chunk_fwd"] == calls["gdn_chunk_bwd"] == 2, calls
+    # the gated norm keeps nothing: forward, and again in block remat's pass
+    assert calls["gated_norm_fwd"] == (4 if remat else 2), calls
+    assert calls["gated_norm_bwd"] == 2, calls
 
 
 def test_a_policy_without_the_names_runs_gdn_chunk_fwd_twice(
@@ -130,6 +140,8 @@ def test_one_application_keeps_its_inputs_and_the_kernels_outputs(kernels):
         jnp.zeros((B, S, cfg.d_model), cfg.dtype),
         jnp.broadcast_to(jnp.arange(S), (B, S)))
     inside = _inside(kept)
+    # (the gated norm's kernels are in the mixer here and add nothing: their
+    # residuals are their inputs)
     # ``o [B, S, H Dv]`` leaves the rule as the primal output too, and JAX
     # passes such a residual through a ``reduce_precision`` that changes
     # nothing; the entering states ``[B, c, J, hb Dk, Dv]`` with the one
