@@ -34,6 +34,8 @@ from dlrover_tpu.ops.flash_attention import (
     flash_attention,
 )
 from dlrover_tpu.ops.gated_delta import (
+    CHANNEL_CHUNK as KDA_CHUNK,
+    CHANNEL_SAVED_NAMES as KDA_SAVED_NAMES,
     SAVED_NAMES as GDN_SAVED_NAMES,
     gated_delta_chunked,
 )
@@ -57,7 +59,8 @@ from dlrover_tpu.ops.ssd import causal_conv1d, ssd_chunked
 #: ``sliding_window`` positions where an "attention" layer of the same model
 #: attends them all.
 MIXER_KINDS = {"attention": "attention", "mamba": "ssm", "conv": "conv",
-               "linear_attention": "gdn", "window_attention": "attention"}
+               "linear_attention": "gdn", "window_attention": "attention",
+               "kda": "kda"}
 #: the attention kinds, each with the scope around its flash call INSIDE the
 #: block's ``attention`` (entered where a model has layers of both)
 ATTENTION_KINDS = {"attention": "attn_full", "window_attention": "attn_window"}
@@ -200,8 +203,12 @@ class LlamaConfig:
     # without position + ``qk_rope_head_dim`` rotary dims whose key part is
     # one vector a token under every head.  The head size is their sum,
     # whatever ``d_model / n_head`` is; training expands keys and values per
-    # head, so the flash kernels see plain MHA and ``v_head_dim`` must equal
-    # that sum (:func:`_mla_qkv`).
+    # head, so the flash kernels see plain MHA, with values ``v_head_dim``
+    # wide whatever that sum is (:func:`_mla_qkv`).  ``q_lora_rank`` 0: the
+    # queries come from ONE matrix (``wq``), no latent and no norm of their
+    # own (Kimi Linear).  With ``rope`` False neither part is rotated: the
+    # ``qk_rope_head_dim`` dims stay the token's one shared key part and
+    # carry no position.
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -304,6 +311,17 @@ class LlamaConfig:
     gdn_v_heads: int = 0
     gdn_d_head: int = 0
     gdn_d_conv: int = 4
+    # A "kda" layer's mixer is Kimi Delta Attention (:func:`_kda_mixer`,
+    # arXiv:2510.26692): ``kda_heads`` heads of ``kda_d_head`` dims, keys and
+    # values alike, q, k and v each from a projection of its own behind a
+    # causal depthwise convolution of ``kda_d_conv`` taps without bias, and
+    # the delta rule with a decay per key CHANNEL
+    # (``ops.gated_delta.gated_delta_chunked`` with ``g [B, S, H, D]``).  The
+    # decay's and the output gate's low-rank projections are ``kda_d_head``
+    # wide in the middle.
+    kda_heads: int = 0
+    kda_d_head: int = 0
+    kda_d_conv: int = 4
     # The attention layers' head size where it is not ``d_model / n_head``
     # (0: it is; latent attention states its own).
     attn_head_dim: int = 0
@@ -347,18 +365,16 @@ class LlamaConfig:
                 f"num_experts={self.num_experts}: the routed block's "
                 "counters are kept per layer, not per (pass, layer)")
         if self.kv_lora_rank > 0:
-            if min(self.q_lora_rank, self.qk_nope_head_dim,
-                   self.qk_rope_head_dim) <= 0 or self.qk_rope_head_dim % 2:
+            if min(self.qk_nope_head_dim, self.qk_rope_head_dim,
+                   self.v_head_dim) <= 0 or self.qk_rope_head_dim % 2 or (
+                       self.q_lora_rank < 0):
                 raise ValueError(
                     f"LlamaConfig: kv_lora_rank={self.kv_lora_rank} needs "
-                    "q_lora_rank > 0, qk_nope_head_dim > 0 and an even "
-                    f"qk_rope_head_dim > 0, not {self.q_lora_rank}, "
-                    f"{self.qk_nope_head_dim} and {self.qk_rope_head_dim}")
-            if self.v_head_dim != self.head_dim:
-                raise ValueError(
-                    f"LlamaConfig: v_head_dim={self.v_head_dim} with "
-                    f"qk_nope_head_dim + qk_rope_head_dim={self.head_dim}: "
-                    "the flash kernels take one head size for q, k and v")
+                    "q_lora_rank >= 0 (0: one query matrix), "
+                    "qk_nope_head_dim > 0, an even qk_rope_head_dim > 0 and "
+                    f"v_head_dim > 0, not {self.q_lora_rank}, "
+                    f"{self.qk_nope_head_dim}, {self.qk_rope_head_dim} and "
+                    f"{self.v_head_dim}")
             if self.n_kv_head != self.n_head or self.qk_norm:
                 raise ValueError(
                     f"LlamaConfig: kv_lora_rank={self.kv_lora_rank} with "
@@ -487,6 +503,17 @@ class LlamaConfig:
                 f"mtp_layers={self.mtp_layers}: the value heads are a "
                 "positive multiple of the key heads, the sizes positive, "
                 "and the stack runs once, with no prediction block")
+        if self.kda_layers and (
+                min(self.kda_heads, self.kda_d_head, self.kda_d_conv) <= 0
+                or self.loop_passes > 1 or self.mtp_layers
+                or self.one_branch):
+            raise ValueError(
+                f"LlamaConfig: 'kda' layers with kda_heads={self.kda_heads}, "
+                f"kda_d_head={self.kda_d_head}, kda_d_conv={self.kda_d_conv}, "
+                f"loop_passes={self.loop_passes}, mtp_layers="
+                f"{self.mtp_layers} or one_branch={self.one_branch}: the "
+                "sizes are positive and the stack runs once on layers of two "
+                "branches, with no prediction block")
         rotary = self.head_dim * self.partial_rotary_factor
         if self.partial_rotary_factor != 1.0 and (
                 not 0 < rotary < self.head_dim or rotary % 2):
@@ -546,6 +573,11 @@ class LlamaConfig:
         return self.layers_of("linear_attention")
 
     @property
+    def kda_layers(self) -> int:
+        """Layers whose mixer is the delta rule with a per-channel decay."""
+        return self.layers_of("kda")
+
+    @property
     def attention_layers(self) -> int:
         """Layers whose mixer is attention, of either kind."""
         return sum(self.layers_of(kind) for kind in ATTENTION_KINDS)
@@ -589,6 +621,13 @@ class LlamaConfig:
         if self.kv_lora_rank > 0:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.attn_head_dim or self.d_model // self.n_head
+
+    @property
+    def value_head_dim(self) -> int:
+        """The width of a head's values, and of its share of ``wo``'s rows:
+        latent attention states its own, every other model's is the
+        head's."""
+        return self.v_head_dim if self.kv_lora_rank > 0 else self.head_dim
 
     @property
     def block_applications(self) -> int:
@@ -719,6 +758,38 @@ def _init_gdn(key: jax.Array, cfg: LlamaConfig) -> Dict:
     }
 
 
+def _init_kda(key: jax.Array, cfg: LlamaConfig) -> Dict:
+    """A Kimi Delta Attention mixer's parameters: projections N(0, 0.02),
+    the output gate's bias 0; the three convolutions PyTorch's ``Conv1d``
+    default as :func:`_init_ssm` draws them, stored ``[taps, channels]``;
+    ``A_log = log U(1, 16)`` a head; ``dt_bias`` a channel, the inverse
+    softplus of a log-uniform draw in [1e-3, 1e-1] (as :func:`_init_ssm`'s);
+    the gated norm's gain 1."""
+    k = jax.random.split(key, 14)
+    H, D, C = cfg.kda_heads, cfg.kda_d_head, cfg.d_model
+    bound = cfg.kda_d_conv ** -0.5
+    taps = lambda key: jax.random.uniform(  # noqa: E731
+        key, (cfg.kda_d_conv, H * D), jnp.float32, -bound, bound)
+    dt = jnp.exp(jax.random.uniform(
+        k[12], (H * D,), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    return {
+        "wq": _dense(k[0], C, H * D), "wk": _dense(k[1], C, H * D),
+        "wv": _dense(k[2], C, H * D),
+        "conv_q": taps(k[3]), "conv_k": taps(k[4]), "conv_v": taps(k[5]),
+        # the decay's gate, low rank: f_b(f_a(x)) + dt_bias, a key channel
+        "f_a": _dense(k[6], C, D), "f_b": _dense(k[7], D, H * D),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jax.random.uniform(
+            k[13], (H,), jnp.float32, 1.0, 16.0)),
+        "w_beta": _dense(k[8], C, H),
+        # the output gate, low rank with a bias: g_b(g_a(x)) + g_bias
+        "g_a": _dense(k[9], C, D), "g_b": _dense(k[10], D, H * D),
+        "g_bias": jnp.zeros((H * D,), jnp.float32),
+        "norm": jnp.ones((D,), jnp.float32),
+        "out_proj": _dense(k[11], H * D, C),
+    }
+
+
 def _gain(w, cfg: "LlamaConfig"):
     """A norm's gain as applied: the leaf, or ``1 + w`` where
     ``cfg.norm_plus_one``."""
@@ -741,7 +812,8 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: Optional[bool],
     The leaves every earlier configuration has draw from the same eight
     keys as ever; what latent attention, the shared expert, a state-space
     mixer (``layer["ssm"]``) and a convolution mixer (``layer["conv"]``),
-    a delta-rule mixer (``layer["gdn"]``, each of the three in place of the
+    a delta-rule mixer (``layer["gdn"]``; with a per-channel decay
+    ``layer["kda"]``; each of the four in place of the
     attention leaves) and the shared expert's gate add draws from keys
     folded out of the layer's.  A gain is 1, or 0 where
     ``cfg.norm_plus_one``.  Where ``cfg.mlp_form`` is "relu2" an MLP has no
@@ -761,10 +833,15 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: Optional[bool],
         layer["conv"] = _init_conv(jax.random.fold_in(key, 3), cfg)
     elif mixer == "linear_attention":
         layer["gdn"] = _init_gdn(jax.random.fold_in(key, 4), cfg)
+    elif mixer == "kda":
+        layer["kda"] = _init_kda(jax.random.fold_in(key, 6), cfg)
     elif attention and cfg.kv_lora_rank > 0:
-        layer["wq_a"] = _dense(k[0], cfg.d_model, cfg.q_lora_rank)
-        layer["q_a_norm"] = jnp.ones((cfg.q_lora_rank,), jnp.float32)
-        layer["wq_b"] = _dense(more[0], cfg.q_lora_rank, cfg.n_head * hd)
+        if cfg.q_lora_rank > 0:
+            layer["wq_a"] = _dense(k[0], cfg.d_model, cfg.q_lora_rank)
+            layer["q_a_norm"] = jnp.ones((cfg.q_lora_rank,), jnp.float32)
+            layer["wq_b"] = _dense(more[0], cfg.q_lora_rank, cfg.n_head * hd)
+        else:
+            layer["wq"] = _dense(k[0], cfg.d_model, cfg.n_head * hd)
         layer["wkv_a"] = _dense(
             k[1], cfg.d_model, cfg.kv_lora_rank + cfg.qk_rope_head_dim)
         layer["kv_a_norm"] = jnp.ones((cfg.kv_lora_rank,), jnp.float32)
@@ -779,7 +856,8 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: Optional[bool],
         layer["wk"] = _dense(k[1], cfg.d_model, cfg.n_kv_head * hd)
         layer["wv"] = _dense(k[2], cfg.d_model, cfg.n_kv_head * hd)
     if attention:
-        layer["wo"] = _dense(k[3], cfg.n_head * hd, cfg.d_model)
+        layer["wo"] = _dense(
+            k[3], cfg.n_head * cfg.value_head_dim, cfg.d_model)
     if routed is not None:
         layer["ln2"] = _gain_leaf(cfg.d_model, cfg)
     if cfg.qk_norm and attention:
@@ -907,10 +985,25 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
                 "in_proj_qkvz": ("embed", None), "in_proj_ba": ("embed", None),
                 "conv_w": (None, None), "dt_bias": (None,), "A_log": (None,),
                 "norm": (None,), "out_proj": (None, "embed")}
+        elif mixer == "kda":
+            # as the state-space mixer: no ``tp`` rule yet
+            del ax["wo"]
+            ax["kda"] = dict(
+                {name: ("embed", None) for name in (
+                    "wq", "wk", "wv", "f_a", "w_beta", "g_a")},
+                **{name: (None, None) for name in (
+                    "conv_q", "conv_k", "conv_v", "f_b", "g_b")},
+                **{name: (None,) for name in (
+                    "dt_bias", "A_log", "g_bias", "norm")},
+                out_proj=(None, "embed"))
         elif attention and cfg.kv_lora_rank > 0:
-            ax.update(wq_a=("embed", None), q_a_norm=(None,),
-                      wq_b=(None, "heads"), wkv_a=("embed", None),
-                      kv_a_norm=(None,), wkv_b=(None, "heads"))
+            ax.update(wkv_a=("embed", None), kv_a_norm=(None,),
+                      wkv_b=(None, "heads"))
+            if cfg.q_lora_rank > 0:
+                ax.update(wq_a=("embed", None), q_a_norm=(None,),
+                          wq_b=(None, "heads"))
+            else:
+                ax["wq"] = ("embed", "heads")
         elif attention:
             ax.update(wq=("embed", "heads"), wk=("embed", "heads"),
                       wv=("embed", "heads"))
@@ -1039,31 +1132,39 @@ def _rms_per_head(x, gain, cfg: "LlamaConfig"):
 
 def _mla_qkv(x, layer, cfg: LlamaConfig, positions) -> tuple:
     """Latent attention's projections: normed ``x [B, S, C]`` -> ``(q, k,
-    v)``, each ``[B, S, H, head_dim]``, plain multi-head operands for any
-    attention backend.  Per token: ``c_q = rms(x wq_a)``, ``[q_nope_i;
-    q_rope_i] = c_q wq_b`` per head i; ``[c_kv; k_rope] = x wkv_a``,
+    v)``, ``q`` and ``k [B, S, H, head_dim]``, ``v [B, S, H, v_head_dim]``,
+    plain multi-head operands for any attention backend.  Per token: ``c_q
+    = rms(x wq_a)``, ``[q_nope_i; q_rope_i] = c_q wq_b`` per head i (at
+    ``q_lora_rank`` 0 ``x wq``, one matrix); ``[c_kv; k_rope] = x wkv_a``,
     ``c_kv = rms(c_kv)``, ``[k_nope_i; v_i] = c_kv wkv_b``; ``q_i =
     [q_nope_i; rope(q_rope_i)]``, ``k_i = [k_nope_i; rope(k_rope)]`` with
     the token's one ``k_rope`` under every head.  RoPE turns the pairs
     ``(j, j + qk_rope_head_dim / 2)`` of the rotary dims, as :func:`_rope`
-    does everywhere (HF's ``rotate_half``).  Scopes ``mla_q`` and
-    ``mla_kv`` sit inside the block's ``attention``."""
+    does everywhere (HF's ``rotate_half``); where ``cfg.rope`` is False
+    neither is turned and the model has no position of its own.  Scopes
+    ``mla_q`` and ``mla_kv`` sit inside the block's ``attention``."""
     B, S, _ = x.shape
     H, dt = cfg.n_head, cfg.dtype
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     with jax.named_scope("mla_q"):
-        c_q = rmsnorm(x @ layer["wq_a"].astype(dt), layer["q_a_norm"],
-                      eps=cfg.rms_eps)
-        q = (c_q @ layer["wq_b"].astype(dt)).reshape(B, S, H, nope + rope)
-        q = jnp.concatenate(
-            [q[..., :nope], _rope(q[..., nope:], positions, cfg.rope_theta)],
-            axis=-1)
+        if cfg.q_lora_rank > 0:
+            c_q = rmsnorm(x @ layer["wq_a"].astype(dt), layer["q_a_norm"],
+                          eps=cfg.rms_eps)
+            q = c_q @ layer["wq_b"].astype(dt)
+        else:
+            q = x @ layer["wq"].astype(dt)
+        q = q.reshape(B, S, H, nope + rope)
+        if cfg.rope:
+            q = jnp.concatenate(
+                [q[..., :nope],
+                 _rope(q[..., nope:], positions, cfg.rope_theta)], axis=-1)
     with jax.named_scope("mla_kv"):
         down = x @ layer["wkv_a"].astype(dt)
         c_kv = rmsnorm(down[..., :cfg.kv_lora_rank], layer["kv_a_norm"],
                        eps=cfg.rms_eps)
-        k_rope = _rope(down[..., None, cfg.kv_lora_rank:], positions,
-                       cfg.rope_theta)  # [B, S, 1, rope]
+        k_rope = down[..., None, cfg.kv_lora_rank:]  # [B, S, 1, rope]
+        if cfg.rope:
+            k_rope = _rope(k_rope, positions, cfg.rope_theta)
         kv = (c_kv @ layer["wkv_b"].astype(dt)).reshape(
             B, S, H, nope + cfg.v_head_dim)
         k = jnp.concatenate(
@@ -1114,6 +1215,10 @@ def _attention(
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
 
+    if v.shape[-1] != D and attn_impl in ("ring", "ulysses"):
+        raise NotImplementedError(
+            f"v_head_dim={v.shape[-1]} under {D}-wide q and k requires the "
+            f"flash attention path, not {attn_impl!r}")
     window = cfg.window_of(kind)
     if window > 0 and attn_impl in ("ring", "ulysses"):
         raise NotImplementedError(
@@ -1157,7 +1262,7 @@ def _attention(
     if gate is not None:
         out = (out.astype(jnp.float32)
                * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
-    out = out.reshape(B, S, H * D)
+    out = out.reshape(B, S, H * out.shape[-1])
     with (jax.named_scope("mla_out") if latent
           else contextlib.nullcontext()):
         return out @ layer["wo"].astype(dt)
@@ -1305,6 +1410,69 @@ def _gdn_mixer(u, gdn, cfg: LlamaConfig) -> tuple:
                        group=D, eps=cfg.rms_eps, gate_first=False)
     with jax.named_scope("gdn_out"):
         return y @ gdn["out_proj"].astype(dt), stats
+
+
+def _kda_mixer(u, kda, cfg: LlamaConfig) -> tuple:
+    """The Kimi Delta Attention mixer (arXiv:2510.26692) on the normed
+    stream ``u [B, S, C]`` -> ``(out [B, S, C], stats)``.  ``q``, ``k`` and
+    ``v`` each from a projection of its own (``wq``, ``wk``, ``wv``: ``H D``
+    columns) through a causal depthwise convolution without bias and ``silu``
+    (``ops.conv_silu``, three calls); q and k L2-normalised over a head's
+    ``D`` dims in float32 (``x / sqrt(sum x^2 + 1e-6)``), q scaled by
+    ``D^-1/2``; ``beta = sigmoid(u w_beta)`` a head; the decay a key CHANNEL,
+    ``g = -exp(A_log[h]) * softplus((u f_a) f_b + dt_bias)`` in float32
+    ``[B, S, H, D]``; the delta rule whose state's rows decay each on its
+    own (``ops.gated_delta.gated_delta_chunked`` at :data:`KDA_CHUNK`); ``y =
+    norm * rms(o) * sigmoid((u g_a) g_b + g_bias)`` per head in float32 —
+    the norm BEFORE the gate, the gate a sigmoid (``ops.gated_norm``, a head
+    a group); ``out = y out_proj``.  Scopes ``kda_in``, ``kda_conv``,
+    ``kda_scan``, ``kda_gate`` and ``kda_out`` sit inside the block's
+    ``kda``.  ``stats``: ``kda_state_rms`` (of the state the sequence
+    leaves) and ``kda_decay_min`` (the least ``exp(sum g)`` over a chunk and
+    channel: 0 says a channel's decay underflowed float32 in a chunk, which
+    the rule allows).
+
+    As :func:`_gdn_mixer` the mixer has no checkpoint of its own; block remat
+    keeps what the rule's forward kernel put out
+    (``ops.gated_delta.CHANNEL_SAVED_NAMES``: ``o`` in float32 and the states
+    that entered the chunks in ``cfg.dtype``, ``B S H D (4 + 2 D /
+    KDA_CHUNK)`` bytes a layer at bf16), so ``kda_chunk_fwd`` runs once a
+    step and layer."""
+    B, S, _ = u.shape
+    H, D = cfg.kda_heads, cfg.kda_d_head
+    dt, f32 = cfg.dtype, jnp.float32
+    with jax.named_scope("kda_in"):
+        q, k, v = (u @ kda[name].astype(dt) for name in ("wq", "wk", "wv"))
+        f = (u @ kda["f_a"].astype(dt)) @ kda["f_b"].astype(dt)
+        z = ((u @ kda["g_a"].astype(dt)) @ kda["g_b"].astype(dt)
+             + kda["g_bias"].astype(dt))
+        b = u @ kda["w_beta"].astype(dt)
+    with jax.named_scope("kda_conv"):
+        q, k, v = (causal_conv1d_silu(x, kda[name]) for x, name in (
+            (q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+    with jax.named_scope("kda_scan"):
+        beta = jax.nn.sigmoid(b.astype(f32))
+        g = -jnp.exp(kda["A_log"])[:, None] * jax.nn.softplus(
+            f.astype(f32).reshape(B, S, H, D) + kda["dt_bias"].reshape(H, D))
+
+        def unit(a, scale):
+            a = a.reshape(B, S, H, D).astype(f32)
+            return (a * (jax.lax.rsqrt(
+                jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
+                * scale)).astype(dt)
+
+        o, state, decay_min = gated_delta_chunked(
+            unit(q, D ** -0.5), unit(k, 1.0), v.reshape(B, S, H, D), g, beta,
+            KDA_CHUNK)
+        stats = jax.lax.stop_gradient({
+            "kda_state_rms": jnp.sqrt(jnp.mean(jnp.square(state))),
+            "kda_decay_min": decay_min})
+    with jax.named_scope("kda_gate"):
+        y = gated_norm(o.reshape(B, S, H * D), z, jnp.tile(kda["norm"], H),
+                       group=D, eps=cfg.rms_eps, gate_first=False,
+                       activation="sigmoid")
+    with jax.named_scope("kda_out"):
+        return y @ kda["out_proj"].astype(dt), stats
 
 
 def _swiglu(x, mlp, dt):
@@ -1768,7 +1936,8 @@ def block_apply(
     """One transformer block: (x, layer) -> (x, stats).  The mixer is the
     one the layer dict holds (:data:`MIXER_KINDS`) — a state-space one
     (``"ssm"``, scope ``ssm``), a gated short convolution (``"conv"``, scope
-    ``conv``), a gated delta rule (``"gdn"``, scope ``gdn``) or attention
+    ``conv``), a gated delta rule (``"gdn"``, scope ``gdn``; with a decay per
+    key channel ``"kda"``, scope ``kda``) or attention
     (the attention leaves, scope ``attention``) — and the MLP the one it
     holds, routed (``"moe"``) or dense (``"mlp"``), each chosen apart from
     the other; a layer of ONE branch (``cfg.one_branch``) holds the mixer's
@@ -1776,7 +1945,8 @@ def block_apply(
     other half is not run: the same wiring with a half absent.  ``stats``
     is what the mixer reports (:func:`_ssm_mixer`:
     ``ssm_state_rms``, ``ssm_decay_min``; :func:`_gdn_mixer`:
-    ``gdn_state_rms``, ``gdn_decay_min``; the other two nothing) with what
+    ``gdn_state_rms``, ``gdn_decay_min``; :func:`_kda_mixer`:
+    ``kda_state_rms``, ``kda_decay_min``; the other two nothing) with what
     a routed MLP's :func:`_moe_swiglu` reports (``moe_aux``, ``moe_z``,
     ``experts``, ``tokens_per_expert``); empty for a dense attention layer.
     The unit the pipeline stage partitioner groups (``models.llama_pp``).
@@ -1809,14 +1979,17 @@ def block_apply(
                 f"block_apply: a {named!r} layer with segment_ids or a "
                 "custom attn_fn: the scan and the convolution know no "
                 "document boundary and no cache")
-        # outermost ``ssm`` / ``conv`` / ``gdn`` as ``attention`` is for the
-        # other kind; the mixer's own scopes nest inside it (``subscopes``)
+        # outermost ``ssm`` / ``conv`` / ``gdn`` / ``kda`` as ``attention`` is
+        # for the other kind; the mixer's own scopes nest inside it
+        # (``subscopes``)
         with jax.named_scope(kind):
             h = rmsnorm(x, _gain(layer["ln1"], cfg), eps=cfg.rms_eps)
             if kind == "ssm":
                 mixed, stats = _ssm_mixer(h, layer["ssm"], cfg)
             elif kind == "gdn":
                 mixed, stats = _gdn_mixer(h, layer["gdn"], cfg)
+            elif kind == "kda":
+                mixed, stats = _kda_mixer(h, layer["kda"], cfg)
             elif kind == "conv":
                 mixed = _conv_mixer(h, layer["conv"], cfg)
             elif attn_fn is not None:
@@ -1904,7 +2077,8 @@ def forward_hidden(
     beside its inputs: the flash kernel's output and log-sum-exp
     (``ops.flash_attention.SAVED_NAMES``) and the delta rule's kernel's
     output, final state and entering states
-    (``ops.gated_delta.SAVED_NAMES``) — what costs as much to recompute as
+    (``ops.gated_delta.SAVED_NAMES``; under a per-channel decay
+    ``CHANNEL_SAVED_NAMES``) — what costs as much to recompute as
     to compute and is small to keep, so neither forward kernel runs again
     in front of the block's backward.  Everything else of the block does.
 
@@ -1925,7 +2099,8 @@ def forward_hidden(
     ``ssm_state_rms`` (float32 ``[mamba layers]``: the RMS of the state each
     layer's scan leaves) and ``ssm_decay_min`` (the least decay over a
     chunk, any layer and head), one with delta-rule layers
-    ``gdn_state_rms`` and ``gdn_decay_min`` likewise.  The embedding's rows
+    ``gdn_state_rms`` and ``gdn_decay_min`` likewise, one with "kda" layers
+    ``kda_state_rms`` and ``kda_decay_min``.  The embedding's rows
     are scaled by
     ``cfg.embedding_multiplier`` here; the head's side of a tied or scaled
     head is :func:`head_operands`'."""
@@ -1943,8 +2118,8 @@ def forward_hidden(
     moe_z = jnp.zeros((), jnp.float32)
     experts, per_expert, held_pairs, buffer_rows = {}, [], [], []
     # what the recurrent mixers report, by their scope
-    state_rms = {"ssm": [], "gdn": []}
-    decay_min = {"ssm": [], "gdn": []}
+    state_rms = {"ssm": [], "gdn": [], "kda": []}
+    decay_min = {"ssm": [], "gdn": [], "kda": []}
 
     def collect(block, stats):
         """A routed block's statistics into the aux dict's entries."""
@@ -1966,7 +2141,7 @@ def forward_hidden(
             fn = jax.checkpoint(
                 fn, static_argnums=(2,),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    *FLASH_SAVED_NAMES, *GDN_SAVED_NAMES))
+                    *FLASH_SAVED_NAMES, *GDN_SAVED_NAMES, *KDA_SAVED_NAMES))
         return fn
 
     apply = applier()
@@ -2115,7 +2290,8 @@ def loss_fn(
     step hands them out beside ``loss`` and ``grad_norm``.  A dense model
     returns the scalar alone either way; one with state-space layers
     returns ``ssm_state_rms`` ``[mamba layers]`` and ``ssm_decay_min``, one
-    with delta-rule layers ``gdn_state_rms`` and ``gdn_decay_min``.
+    with delta-rule layers ``gdn_state_rms`` and ``gdn_decay_min``, one
+    with "kda" layers ``kda_state_rms`` and ``kda_decay_min``.
     The head is ``lm_head``, or ``embed`` transposed where
     ``cfg.tie_word_embeddings``, behind ``1 / cfg.logits_scaling``
     (:func:`head_operands`).
@@ -2232,7 +2408,7 @@ def loss_fn(
     if not metrics:
         return loss
     for name in ("ssm_state_rms", "ssm_decay_min", "gdn_state_rms",
-                 "gdn_decay_min"):
+                 "gdn_decay_min", "kda_state_rms", "kda_decay_min"):
         if name in aux:
             counters[name] = aux[name]
     if "moe_z" in aux:
@@ -2437,6 +2613,17 @@ def refuse_training_path_only(cfg: LlamaConfig, where: str) -> None:
                     f"{cfg.n_layer} layers)")
         elif value == computed:
             continue
+        elif name == "kv_lora_rank":
+            # the forms of latent attention, each by its name
+            said = (f"kv_lora_rank={value!r} with q_lora_rank="
+                    f"{cfg.q_lora_rank}, v_head_dim={cfg.v_head_dim} under "
+                    f"{cfg.head_dim}-wide keys and rope={cfg.rope}")
+            what += "".join(text for met, text in (
+                (cfg.q_lora_rank == 0, ", its queries from one matrix"),
+                (cfg.v_head_dim != cfg.head_dim,
+                 ", values of another width than the keys"),
+                (not cfg.rope, ", no rotary position on either part"))
+                if met)
         else:
             said = f"{name}={value!r}"
         raise ValueError(
@@ -2476,7 +2663,8 @@ def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:
     if facts:
         facts["attention_layers"] = cfg.attention_layers
     # the chunks a recurrent mixer's scan carries its state over
-    for scope, chunk in (("ssm", cfg.mamba_chunk_size), ("gdn", GDN_CHUNK)):
+    for scope, chunk in (("ssm", cfg.mamba_chunk_size), ("gdn", GDN_CHUNK),
+                         ("kda", KDA_CHUNK)):
         if f"{scope}_layers" in facts:
             facts[f"{scope}_chunks_per_sequence"] = -(-seq_len // chunk)
     if cfg.one_branch:
@@ -2515,7 +2703,9 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     taps and the chunked rule's matmuls (per value head and token, forward:
     ``k k^T``, ``q k^T`` and the two products with ``T`` and the one with
     ``u`` over a chunk's ``Q`` positions, ``10 Q D``, and the three against
-    the state, ``6 D^2``).  An MLP is three matrices or, at ``mlp_form``
+    the state, ``6 D^2``); a "kda" layer likewise, with its four
+    projections, two low-rank gates and three convolutions.  An MLP is three
+    matrices or, at ``mlp_form``
     "relu2", two.  Where ``cfg.one_branch`` a mixer layer counts no MLP, a
     "mlp" layer its MLP alone and a "moe" layer its router, its shared
     expert and the share of a token's ``top_k`` picks that meet an expert
@@ -2525,8 +2715,11 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     mats = 3 if cfg.mlp_form == "swiglu" else 2
     if cfg.kv_lora_rank > 0:  # latent attention's five projections
         qkv = (
-            cfg.d_model * cfg.q_lora_rank
-            + cfg.q_lora_rank * cfg.n_head * cfg.head_dim
+            # the queries through their latent, or from one matrix
+            (cfg.d_model * cfg.q_lora_rank
+             + cfg.q_lora_rank * cfg.n_head * cfg.head_dim
+             if cfg.q_lora_rank > 0
+             else cfg.d_model * cfg.n_head * cfg.head_dim)
             + cfg.d_model * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
             + cfg.kv_lora_rank * cfg.n_head
             * (cfg.qk_nope_head_dim + cfg.v_head_dim))
@@ -2538,7 +2731,7 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     mlp = 0 if cfg.one_branch else mats * cfg.d_model * cfg.d_ff
     p_layer = (
         qkv
-        + cfg.n_head * cfg.head_dim * cfg.d_model  # wo
+        + cfg.n_head * cfg.value_head_dim * cfg.d_model  # wo
         + mlp
     )
     head = cfg.vocab_size * cfg.d_model
@@ -2552,7 +2745,8 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     keys = (cfg.block_applications * cfg.max_seq_len
             - cfg.window_layers * cfg.loop_passes
             * max(cfg.max_seq_len - cfg.sliding_window, 0))
-    attn = 2 * keys * cfg.n_head * cfg.head_dim
+    # scores over a head's key dims, the output over its value dims
+    attn = keys * cfg.n_head * (cfg.head_dim + cfg.value_head_dim)
     inner, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
     p_ssm = (cfg.d_model * (inner + conv + cfg.mamba_n_heads)  # in_proj
              + inner * cfg.d_model  # out_proj
@@ -2565,6 +2759,14 @@ def flops_per_token(cfg: LlamaConfig) -> float:
              + hv * gd * cfg.d_model + mlp)
     rule = (hv * (10 * GDN_CHUNK * gd + 6 * gd * gd)
             + 2 * cfg.gdn_d_conv * cfg.gdn_conv_dim)
+    # a "kda" layer: q, k, v and the output projection, the two low-rank
+    # gates and beta; the rule as the delta-rule layer's at its own chunk,
+    # and three convolutions
+    kh, kd = cfg.kda_heads, cfg.kda_d_head
+    p_kda = (cfg.d_model * (3 * kh * kd + 2 * kd + kh) + 2 * kd * kh * kd
+             + kh * kd * cfg.d_model + mlp)
+    rule_kda = (kh * (10 * KDA_CHUNK * kd + 6 * kd * kd)
+                + 2 * cfg.kda_d_conv * 3 * kh * kd)
     alone = 0.0  # the layers whose one branch is an MLP
     if cfg.one_branch:
         expert = mats * cfg.d_model * cfg.expert_width
@@ -2575,7 +2777,7 @@ def flops_per_token(cfg: LlamaConfig) -> float:
         alone = (cfg.layer_types.count("mlp") * mats * cfg.d_model * cfg.d_ff
                  + cfg.moe_layers * routed)
     return (6.0 * (dense + cfg.ssm_layers * p_ssm + cfg.conv_layers * p_conv
-                   + cfg.gdn_layers * p_gdn + alone)
+                   + cfg.gdn_layers * p_gdn + cfg.kda_layers * p_kda + alone)
             + 6.0 * attn
             + 3.0 * (cfg.ssm_layers * scan + cfg.conv_layers * taps
-                     + cfg.gdn_layers * rule))
+                     + cfg.gdn_layers * rule + cfg.kda_layers * rule_kda))

@@ -9,6 +9,10 @@ matrix.  Forward saves per-row logsumexp; backward recomputes block scores
 
 Layout [B, H, S, D]; D padded to the 128-lane register width by the caller
 or the dispatcher.  Causal masking skips fully-masked K blocks via the grid.
+``v`` (and with it ``o`` and their cotangents) carries a width of its own,
+``[B, KV, S, Dv]``: scores are ``[bq, D] x [bk, D]^T`` at ``1 / sqrt(D)`` and
+the output ``p x [bk, Dv]``, so values narrower than the keys (latent
+attention: 192-wide q and k over 128-wide v) cost no padded column.
 """
 
 from __future__ import annotations
@@ -70,7 +74,8 @@ def reference_attention(
     restricts attention to same-segment pairs (packed sequences).  GQA:
     k/v may carry KV < H heads (H % KV == 0).  ``window > 0`` adds
     sliding-window attention: position q attends only keys with
-    ``0 <= q - k < window``."""
+    ``0 <= q - k < window``.  ``v [B, KV, S, Dv]`` may be of another width
+    than ``q`` and ``k``: the output is ``[B, H, S, Dv]``."""
     if k.shape[1] != q.shape[1]:  # GQA: broadcast kv heads
         rep = q.shape[1] // k.shape[1]
         k = jnp.repeat(k, rep, axis=1)
@@ -115,7 +120,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal,
     from jax.experimental import pallas as pl
 
     # Blocks carry a leading unit (batch*head) dim:
-    # q_ref: [1, block_q, D]; k_ref/v_ref: [1, S, D]; o_ref: [1, block_q, D];
+    # q_ref: [1, block_q, D]; k_ref: [1, S, D]; v_ref: [1, S, Dv]; o_ref:
+    # [1, block_q, Dv];
     # lse_ref: [1, 1, block_q]; segmented adds seg_ref: [1, 1, S_pad] int32.
     if segmented:
         seg_ref, o_ref, lse_ref = rest
@@ -123,7 +129,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal,
         seg_ref = None
         o_ref, lse_ref = rest
     block_q = q_ref.shape[1]
-    d = q_ref.shape[2]
+    d = v_ref.shape[2]
     qi = pl.program_id(1)
     q_start = qi * block_q
 
@@ -255,7 +261,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
     from jax.experimental import pallas as pl
 
     B, H, S, D = q.shape
-    KV = k.shape[1]
+    KV, Dv = k.shape[1], v.shape[3]
     sm_scale = 1.0 / np.sqrt(D)
     # Pad the sequence to block multiples: pl.ds clamps out-of-bounds
     # starts (dynamic_slice semantics), which would silently shift the
@@ -268,7 +274,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
 
     q3 = q.reshape(B * H, S_pad, D)
     k3 = k.reshape(B * KV, S_pad, D)
-    v3 = v.reshape(B * KV, S_pad, D)
+    v3 = v.reshape(B * KV, S_pad, Dv)
     kv_map = _kv_row_map(H, KV)
 
     segmented = segment_ids is not None
@@ -279,7 +285,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         pl.BlockSpec((1, S_pad, D), kv_map),
-        pl.BlockSpec((1, S_pad, D), kv_map),
+        pl.BlockSpec((1, S_pad, Dv), kv_map),
     ]
     inputs = [q3, k3, v3]
     if segmented:
@@ -292,19 +298,19 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, S_pad, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, S_pad, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, S_pad), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
-        **_vmem_params(2 * 2 * S_pad * D * k.dtype.itemsize),
+        **_vmem_params(2 * S_pad * (D + Dv) * k.dtype.itemsize),
     )(*inputs)
     return (
-        out.reshape(B, H, S_pad, D)[:, :, :S],
+        out.reshape(B, H, S_pad, Dv)[:, :, :S],
         lse.reshape(B, H, S_pad)[:, :, :S],
     )
 
@@ -328,7 +334,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, *rest,
                    segmented=False, window=0):
     from jax.experimental import pallas as pl
 
-    # q_ref/g_ref/dq_ref: [1, block_q, D]; k_ref/v_ref: [1, S_pad, D];
+    # q_ref/dq_ref: [1, block_q, D]; g_ref: [1, block_q, Dv]; k_ref: [1,
+    # S_pad, D]; v_ref: [1, S_pad, Dv];
     # lse_ref/delta_ref: [1, 1, block_q]; seg_ref: [1, 1, S_pad] int32.
     if segmented:
         seg_ref, dq_ref = rest
@@ -413,7 +420,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         seg_ref = None
         dk_ref, dv_ref = rest
     block_k = k_ref.shape[1]
-    d = k_ref.shape[2]
     ki = pl.program_id(1)
     r = pl.program_id(2)
     k_start = ki * block_k
@@ -475,9 +481,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         )  # ds^T @ q -> [block_k, D]
         return dk_acc, dv_acc
 
-    zeros = jnp.zeros((block_k, d), jnp.float32)
     dk_acc, dv_acc = jax.lax.fori_loop(
-        start_qi, num_q_blocks, body, (zeros, zeros)
+        start_qi, num_q_blocks, body,
+        (jnp.zeros(k_ref.shape[1:], jnp.float32),
+         jnp.zeros(v_ref.shape[1:], jnp.float32)),
     )
 
     @pl.when(r == 0)
@@ -496,7 +503,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
     from jax.experimental import pallas as pl
 
     B, H, S, D = q.shape
-    KV = k.shape[1]
+    KV, Dv = k.shape[1], v.shape[3]
     rep = H // KV
     sm_scale = 1.0 / np.sqrt(D)
     block_q, block_k, S_pad = _block_sizes(S, block_q, block_k)
@@ -510,9 +517,9 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         lse = jnp.pad(lse, pad3)
         delta = jnp.pad(delta, pad3)
 
-    q3, g3 = (t.reshape(B * H, S_pad, D) for t in (q, g))
+    q3, g3 = q.reshape(B * H, S_pad, D), g.reshape(B * H, S_pad, Dv)
     k3 = k.reshape(B * KV, S_pad, D)
-    v3 = v.reshape(B * KV, S_pad, D)
+    v3 = v.reshape(B * KV, S_pad, Dv)
     kv_map = _kv_row_map(H, KV)
     lse2 = lse.reshape(B * H, 1, S_pad).astype(jnp.float32)
     delta2 = delta.reshape(B * H, 1, S_pad)
@@ -536,8 +543,8 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, S_pad, D), kv_map),
-            pl.BlockSpec((1, S_pad, D), kv_map),
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, S_pad, Dv), kv_map),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ] + seg_spec,
@@ -545,7 +552,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((B * H, S_pad, D), q.dtype),
         interpret=interpret,
         name="flash_bwd_dq",
-        **_vmem_params(2 * 2 * S_pad * D * k.dtype.itemsize),
+        **_vmem_params(2 * S_pad * (D + Dv) * k.dtype.itemsize),
     )(*common)
 
     # dkv: grid (B*KV, k_blocks, rep) — the innermost axis streams the
@@ -553,7 +560,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
     # block (fp32 accumulation), so dk/dv never exist at query-head size
     # in HBM and per-program VMEM stays at one head's footprint.
     q4 = q3.reshape(B * KV, rep, S_pad, D)
-    g4 = g3.reshape(B * KV, rep, S_pad, D)
+    g4 = g3.reshape(B * KV, rep, S_pad, Dv)
     lse4 = lse2.reshape(B * KV, rep, 1, S_pad)
     delta4 = delta2.reshape(B * KV, rep, 1, S_pad)
     dkv_in = [q4, k3, v3, g4, lse4, delta4]
@@ -573,28 +580,28 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, 1, S_pad, D), lambda b, i, r: (b, r, 0, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, r: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, r: (b, i, 0)),
-            pl.BlockSpec((1, 1, S_pad, D), lambda b, i, r: (b, r, 0, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, r: (b, i, 0)),
+            pl.BlockSpec((1, 1, S_pad, Dv), lambda b, i, r: (b, r, 0, 0)),
             pl.BlockSpec((1, 1, 1, S_pad), lambda b, i, r: (b, r, 0, 0)),
             pl.BlockSpec((1, 1, 1, S_pad), lambda b, i, r: (b, r, 0, 0)),
         ] + dkv_seg_spec,
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, i, r: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, r: (b, i, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, r: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * KV, S_pad, D), jnp.float32),
-            jax.ShapeDtypeStruct((B * KV, S_pad, D), jnp.float32),
+            jax.ShapeDtypeStruct((B * KV, S_pad, Dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
-        **_vmem_params(2 * 2 * S_pad * D * q.dtype.itemsize),
+        **_vmem_params(2 * S_pad * (D + Dv) * q.dtype.itemsize),
     )(*dkv_in)
 
     return (
         dq.reshape(B, H, S_pad, D)[:, :, :S],
         dk.reshape(B, KV, S_pad, D)[:, :, :S].astype(k.dtype),
-        dv.reshape(B, KV, S_pad, D)[:, :, :S].astype(v.dtype),
+        dv.reshape(B, KV, S_pad, Dv)[:, :, :S].astype(v.dtype),
     )
 
 

@@ -75,6 +75,16 @@ least decay are ``jax.numpy`` around the kernels.  The rule
 (:func:`_kernel_heads`): ``jax.default_backend() == "tpu"``, ``chunk`` 64 or
 128, ``Dk`` and ``Dv`` multiples of 128 lanes, and ``H`` a multiple of the
 ``128 // chunk`` heads that share a tile.
+
+``g [B, S, H, Dk]`` is a decay a key CHANNEL (Kimi Delta Attention,
+arXiv:2510.26692): each row of the state decays on its own, the exponent
+sits inside the sum over a head's channels, and the scalar factorisation
+above does not hold.  The same three forms for it — the sequential one (this
+module's, with ``g`` of ``k``'s rank), a chunked ``jax.numpy`` one and a
+kernel pair of its own (``kda_chunk_fwd``, ``kda_chunk_bwd``; outputs named
+by :data:`CHANNEL_SAVED_NAMES`) — are the section "a decay per key CHANNEL"
+below, which says how it keeps every exponent that is evaluated at or under
+0.  :func:`gated_delta_chunked` takes either.
 """
 
 from __future__ import annotations
@@ -664,26 +674,374 @@ def _chunked_pallas(q, k, v, g, beta, qn: int, hb: int, interpret: bool):
             jnp.min(jnp.exp(gamma[:, :, -1])))
 
 
+# -- a decay per key CHANNEL (Kimi Delta Attention, arXiv:2510.26692) ---------
+#
+# ``g [B, S, H, Dk]``: each of the state's ``Dk`` rows decays on its own, so
+# ``Gamma = cumsum(g)`` inside a chunk is ``[Q, Dk]`` and the exponent of
+#
+#     A_ij  = beta_i sum_d k_id k_jd exp(Gamma_id - Gamma_jd)      (i > j)
+#     QK_ij =        sum_d q_id k_jd exp(Gamma_id - Gamma_jd)      (i >= j)
+#
+# sits INSIDE the sum over ``d``: ``A`` is no ``(k k^T) * decay``, and
+# ``exp(-Gamma_j)`` alone overflows as ever.  The module's rule — no exponent
+# that is evaluated is ever positive — is kept by halving: a pair ``(i, j)``
+# with ``i > j`` lies, at exactly one block size ``b`` of ``1, 2, 4, ..., Q /
+# 2``, in the two halves of one aligned block of ``2 b`` positions, ``i`` in
+# the second and ``j`` in the first.  With ``r`` the ``Gamma`` of the first
+# half's last position, ``Gamma_i - Gamma_j = (Gamma_i - r) + (r - Gamma_j)``
+# and neither bracket is positive, so a level is ONE product of two bounded
+# operands on the MXU::
+#
+#     (k . exp(Gamma - r))  (k . exp(r - Gamma))^T     masked to the level's
+#                                                      off-diagonal blocks
+#
+# (rows of the wrong half get the filler exponent and are zero exactly);
+# ``log2 Q`` levels cover every pair once, and the diagonal of ``QK`` has the
+# exponent 0.  No ``[Q, Q, Dk]`` array and no pairwise work on the VPU.  ``r``
+# is picked out of ``Gamma`` by a product with a 0/1 matrix at ``highest``
+# (exact).  The rest is the scalar rule's with ``exp(Gamma)`` a ``[Q, Dk]``
+# array: ``W = T (beta k . exp(Gamma))``, ``o = (q . exp(Gamma)) S + QK u``,
+# ``S <- diag(exp(Gamma_Q)) S + (k . exp(Gamma_Q - Gamma))^T u``.  The state
+# is held TRANSPOSED, ``[Dv, Dk]``, so that a channel's decay runs along the
+# lanes.  Float32 stays where it stays above; the products that feed the
+# inverse (``A``) take float32 operands at ``highest``, those rounded anyway
+# (``QK``, and every product against the state) operands in ``v``'s dtype.
+#
+# ONE function computes a chunk (:func:`_channel_chunk`), from whole-tile
+# operations alone.  The ``jax.numpy`` form maps it over heads and scans it
+# over chunks; the forward kernel (``kda_chunk_fwd``) calls it on a head's
+# chunk in VMEM; the backward kernel (``kda_chunk_bwd``) takes ``jax.vjp`` of
+# it THERE, on the same inputs and the saved entering state, so that the
+# chunk's backward is derived and not written a second time.  Its matmuls are
+# :func:`_product`, whose own rule rounds a cotangent as the forward rounds
+# an operand.  The scalar rule's kernels are as they were.
+
+#: the kernels' outputs under a per-channel decay, as :data:`SAVED_NAMES`
+CHANNEL_SAVED_NAMES = ("kda_out", "kda_state", "kda_entering")
+#: positions of a chunk the per-channel kernels take: one head a tile
+CHANNEL_CHUNK = 128
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 3))
+def _product(kind, a, b, dt):
+    """``a b`` (``kind`` "nn"), ``a b^T`` ("nt") or ``a^T b`` ("tn") of
+    float32 2-D operands -> float32: the operands rounded to ``dt`` on the
+    way in, or, at ``dt`` float32, whole at ``highest``.  The backward rounds
+    the cotangent the same way."""
+    precision = _HIGHEST if dt == F32 else None
+    fn = {"nn": _dot, "nt": _nt, "tn": _tn}[kind]
+    return fn(a.astype(dt), b.astype(dt), precision)
+
+
+def _product_fwd(kind, a, b, dt):
+    return _product(kind, a, b, dt), (a, b)
+
+
+def _product_bwd(kind, dt, res, g):
+    a, b = res
+    nn, nt, tn = (functools.partial(_product, x, dt=dt)
+                  for x in ("nn", "nt", "tn"))
+    return {"nn": lambda: (nt(g, b), tn(a, g)),
+            "nt": lambda: (nn(g, b), tn(g, a)),
+            "tn": lambda: (nt(b, g), nn(a, g))}[kind]()
+
+
+_product.defvjp(_product_fwd, _product_bwd)
+
+
+@jax.custom_vjp
+def _rows_at(pick, x):
+    """``pick x`` at ``highest``: the rows of float32 ``x`` that the 0/1
+    matrix ``pick`` names, exactly."""
+    return _dot(pick, x, _HIGHEST)
+
+
+_rows_at.defvjp(lambda pick, x: (_rows_at(pick, x), pick),
+                lambda pick, g: (jnp.zeros_like(pick),
+                                 _tn(pick, g, _HIGHEST)))
+
+
+@jax.custom_vjp
+def _whole_tile_inverse(a):
+    """:func:`_tile_inverse` of one head's ``[Q, Q]`` tile, with
+    :func:`unit_lower_inverse`'s cotangent."""
+    steps = _tile_inverse(a, a.shape[0])
+    try:
+        while True:
+            next(steps)
+    except StopIteration as stop:
+        return stop.value
+
+
+def _whole_tile_inverse_bwd(t, g):
+    tt = t.T
+    return (-_dot(_dot(tt, g, _HIGHEST), tt, _HIGHEST),)
+
+
+_whole_tile_inverse.defvjp(
+    lambda a: (_whole_tile_inverse(a),) * 2, _whole_tile_inverse_bwd)
+
+
+def _channel_chunk(q, k, v, gam, bc, state, dt, inverse):
+    """One head's chunk of ``n`` positions under a per-channel decay:
+    ``q``, ``k``, ``gam [n, Dk]``, ``v [n, Dv]``, ``bc [n, 1]`` and the
+    state that enters, transposed, ``[Dv, Dk]``, all float32 -> ``(o [n,
+    Dv], the state that leaves [Dv, Dk])``.  ``gam`` is the cumulative sum
+    of ``g`` inside the chunk; ``inverse`` computes ``(I + A)^-1``."""
+    n, dk = k.shape
+    iota = jax.lax.broadcasted_iota
+    row, col = iota(jnp.int32, (n, n), 0), iota(jnp.int32, (n, n), 1)
+    at = iota(jnp.int32, (n, dk), 0)
+    a = jnp.zeros((n, n), F32)
+    qk = jnp.where(row == col, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
+    b = 1
+    while b < n:
+        shift = (2 * b).bit_length() - 1
+        block = lambda x: jax.lax.shift_right_logical(x, shift)  # noqa: E731
+        under = ((block(row) == block(col)) & ((row & b) != 0)
+                 & ((col & b) == 0))
+        # the Gamma of the last position of the first half of a row's block
+        ref = _rows_at(jnp.where(
+            col == jax.lax.shift_left(block(row), shift) + (b - 1), 1.0,
+            0.0).astype(F32), gam)
+        second = (at & b) != 0
+        # masked inside the exp: in the other half the bracket is positive
+        from_ref = jnp.exp(jnp.where(second, gam - ref, _NEG))
+        to_ref = jnp.exp(jnp.where(second, _NEG, ref - gam))
+        k_to = k * to_ref
+        a = a + jnp.where(under, _product("nt", k * from_ref, k_to, F32), 0.0)
+        qk = qk + jnp.where(under, _product("nt", q * from_ref, k_to, dt),
+                            0.0)
+        b *= 2
+    inv = inverse(bc * a)
+    grown = jnp.exp(gam)  # from the chunk's start to i, a channel
+    w = _product("nn", inv, k * grown * bc, F32)
+    u = _product("nn", inv, v * bc, F32)
+    new = u - _product("nt", w, state, dt)
+    out = (_product("nt", q * grown, state, dt)
+           + _product("nn", qk, new, dt))
+    total = jnp.sum(jnp.where(at == n - 1, gam, 0.0), axis=0, keepdims=True)
+    k_to_end = k * jnp.exp(total - gam)  # from j to the chunk's end
+    return out, (jnp.exp(total) * state
+                 + _product("tn", new, k_to_end, dt))
+
+
+def _chunked_channel_xla(q, k, v, g, beta, qn: int):
+    """:func:`_chunked_xla` under a per-channel decay: :func:`_channel_chunk`
+    mapped over batch rows and heads, a ``lax.scan`` over the chunks."""
+    bsz, s, h, dk = k.shape
+    dv, dt, c = v.shape[-1], v.dtype, s // qn
+    # [c, B, H, Q, ...]: the chunks first, a head's rows together
+    rows = lambda x: jnp.moveaxis(  # noqa: E731
+        x.astype(F32).reshape((bsz, c, qn) + x.shape[2:]), (1, 3), (0, 2))
+    gam = jnp.cumsum(rows(g), axis=3)
+    chunk = functools.partial(_channel_chunk, dt=dt,
+                              inverse=unit_lower_inverse)
+
+    @jax.checkpoint
+    def carry(state, inputs):
+        out, state = jax.vmap(jax.vmap(chunk))(*inputs, state)
+        return state, out
+
+    final, out = jax.lax.scan(
+        carry, jnp.zeros((bsz, h, dv, dk), F32),
+        (rows(q), rows(k), rows(v), gam, rows(beta)[..., None]))
+    out = jnp.moveaxis(out, (0, 3), (1, 2)).reshape(bsz, s, h, dv)
+    return (out, jnp.swapaxes(final, -1, -2),
+            jnp.min(jnp.exp(gam[:, :, :, -1])))
+
+
+def _channel_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, state_ref,
+                        *entering_ref):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _new_sequence():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    dt, state = v_ref.dtype, state_ref[...]
+    if entering_ref:
+        entering_ref[0][...] = state.astype(dt)
+    o_ref[...], state_ref[...] = _channel_chunk(
+        q_ref[...].astype(F32), k_ref[...].astype(F32),
+        v_ref[...].astype(F32), g_ref[...], _columns(b_ref[...])[:, :1],
+        state, dt, _whole_tile_inverse)
+
+
+def _channel_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entering_ref,
+                        do_ref, dstate_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                        db_ref, ds_ref):
+    """One chunk of the backward pass: ``jax.vjp`` of :func:`_channel_chunk`
+    on the chunk's own inputs and the state that entered it (as saved,
+    rounded: every product takes it so, and the decay's own cotangent reads
+    it as the scalar rule's backward does), pulled back from the cotangents
+    of ``o`` and of the state the chunk left (``ds_ref``, resident)."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _sequence_end():
+        ds_ref[...] = dstate_ref[...]
+
+    dt = v_ref.dtype
+    _, pull = jax.vjp(
+        functools.partial(_channel_chunk, dt=dt, inverse=_whole_tile_inverse),
+        q_ref[...].astype(F32), k_ref[...].astype(F32),
+        v_ref[...].astype(F32), g_ref[...], _columns(b_ref[...])[:, :1],
+        entering_ref[...].astype(F32))
+    d_q, d_k, d_v, d_g, d_b, ds_ref[...] = pull((do_ref[...], ds_ref[...]))
+    dq_ref[...] = d_q.astype(dq_ref.dtype)
+    dk_ref[...] = d_k.astype(dk_ref.dtype)
+    dv_ref[...] = d_v.astype(dv_ref.dtype)
+    dg_ref[...] = d_g
+    lane = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
+    db_ref[...] = jnp.where(lane == 0, d_b, 0.0).T[:db_ref.shape[0]]
+
+
+def _channel_specs(bsz, c, h, dk, dv, backward=False):
+    """The grid ``(B, H, chunks)`` — a head a grid step — and the block
+    specs by name, as :func:`_block_specs`."""
+    from jax.experimental import pallas as pl
+
+    at = (lambda i: c - 1 - i) if backward else (lambda i: i)
+    rows = lambda d: pl.BlockSpec(  # noqa: E731
+        (None, CHANNEL_CHUNK, d), lambda b, j, i: (b, at(i), j))
+    per_chunk = lambda *shape: pl.BlockSpec(  # noqa: E731
+        (None, None, None) + shape, lambda b, j, i: (b, at(i), j, 0, 0))
+    return (bsz, h, c), dict(
+        q=rows(dk), k=rows(dk), g=rows(dk), v=rows(dv),
+        b=per_chunk(_ROWS, 128), entering=per_chunk(dv, dk),
+        state=pl.BlockSpec((None, None, dv, dk),
+                           lambda b, j, i: (b, j, 0, 0)))
+
+
+#: what a chunk's ``[128, 128]`` float32 temporaries may hold in VMEM beside
+#: the blocks: seven levels of a dozen arrays forward, and the backward keeps
+#: the forward's while it walks back
+_CHANNEL_VMEM = 32 * 2 ** 20
+_CHANNEL_INPUTS = ("q", "k", "v", "g", "b")
+
+
+def _channel_fwd(q, k, v, g, b, dims, interpret, keep_entering):
+    """``q``, ``k [B, S, H Dk]``, ``v [B, S, H Dv]``, ``g [B, S, H Dk]`` (the
+    cumulative sums) and ``b [B, c, H, 8, 128]`` float32 -> ``(o [B, S, H Dv]
+    float32, final state [B, H, Dv, Dk] float32, the entering states [B, c,
+    H, Dv, Dk] in ``v``'s dtype or None)``."""
+    from jax.experimental import pallas as pl
+
+    h, dk, dv = dims
+    bsz, c = b.shape[:2]
+    grid, specs = _channel_specs(bsz, c, h, dk, dv)
+    out_specs = [specs["v"], specs["state"]]
+    out_shape = [jax.ShapeDtypeStruct(v.shape, F32),
+                 jax.ShapeDtypeStruct((bsz, h, dv, dk), F32)]
+    if keep_entering:
+        out_specs.append(specs["entering"])
+        out_shape.append(jax.ShapeDtypeStruct((bsz, c, h, dv, dk), v.dtype))
+    out = pl.pallas_call(
+        _channel_fwd_kernel, grid=grid,
+        in_specs=[specs[name] for name in _CHANNEL_INPUTS],
+        out_specs=out_specs, out_shape=out_shape, interpret=interpret,
+        name="kda_chunk_fwd", **_call_params(_CHANNEL_VMEM),
+    )(q, k, v, g, b)
+    return (*out, None)[:3]
+
+
+def _channel_bwd(q, k, v, g, b, entering, do, dstate, dims, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    h, dk, dv = dims
+    bsz, c = b.shape[:2]
+    grid, specs = _channel_specs(bsz, c, h, dk, dv, backward=True)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    return pl.pallas_call(
+        _channel_bwd_kernel, grid=grid,
+        in_specs=[specs[name] for name in _CHANNEL_INPUTS + (
+            "entering", "v", "state")],
+        out_specs=[specs[name] for name in _CHANNEL_INPUTS],
+        out_shape=[like(a) for a in (q, k, v, g, b)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), F32)],
+        interpret=interpret, name="kda_chunk_bwd",
+        **_call_params(2 * _CHANNEL_VMEM),
+    )(q, k, v, g, b, entering, do, dstate)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _channel_kernels(q, k, v, g, b, dims, interpret):
+    return _channel_fwd(q, k, v, g, b, dims, interpret, False)[:2]
+
+
+def _channel_kernels_fwd(q, k, v, g, b, dims, interpret):
+    o, final, entering = map(checkpoint_name, _channel_fwd(
+        q, k, v, g, b, dims, interpret, True), CHANNEL_SAVED_NAMES)
+    return (o, final), (q, k, v, g, b, entering)
+
+
+def _channel_kernels_bwd(dims, interpret, res, cotangents):
+    return tuple(_channel_bwd(*res, *cotangents, dims, interpret))
+
+
+_channel_kernels.defvjp(_channel_kernels_fwd, _channel_kernels_bwd)
+
+
+def _chunked_channel_pallas(q, k, v, g, beta, interpret: bool):
+    """:func:`_chunked_channel_xla` by the kernel pair at
+    :data:`CHANNEL_CHUNK`, one call per shard of the mesh in scope; the
+    cumulative sums, ``beta``'s layout (a head's chunk a lane-dense row) and
+    the least decay are ``jax.numpy``, as :func:`_chunked_pallas`'s."""
+    bsz, s, h, dk = k.shape
+    dv, qn = v.shape[-1], CHANNEL_CHUNK
+    c = s // qn
+    gam = jnp.cumsum(g.astype(F32).reshape(bsz, c, qn, h, dk), axis=2)
+    rows = jnp.pad(
+        beta.astype(F32).reshape(bsz, c, qn, h).transpose(0, 1, 3, 2)[
+            :, :, :, None], ((0, 0),) * 3 + ((0, _ROWS - 1), (0, 0)))
+    flat = lambda x: x.reshape(bsz, s, -1)  # noqa: E731
+    free, batch_axes, _ = shard_axes(bsz)
+    first = lambda nd: Spec(batch_axes, *([None] * (nd - 1)))  # noqa: E731
+    o, final = per_shard(
+        lambda *ops: _channel_kernels(*ops, (h, dk, dv), interpret),
+        free, (first(3),) * 4 + (first(5),), (first(3), first(4)),
+    )(flat(q), flat(k), flat(v), flat(gam), rows)
+    return (o.reshape(bsz, s, h, dv), jnp.swapaxes(final, -1, -2),
+            jnp.min(jnp.exp(gam[:, :, -1])))
+
+
 def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
                         backend: Optional[str] = None,
                         interpret: bool = False):
     """The chunked form -> ``(o [B, S, H, Dv] float32, final state [B, H,
-    Dk, Dv] float32, the least decay over a chunk, a float32 scalar)``.  A
-    sequence that ``chunk`` does not divide is padded with positions of ``g
-    = 0`` and ``beta = 0``, which neither decay nor write.  ``backend``
-    (``"pallas"`` / ``"reference"``; None: by the device) and ``interpret``
-    are for tests of the kernels on the CPU; a shape the kernels do not tile
-    (:func:`_kernel_heads`) runs the ``jax.numpy`` form on any backend."""
+    Dk, Dv] float32, the least decay over a chunk, a float32 scalar)``.  ``g
+    [B, S, H]`` is a decay a head, ``g [B, S, H, Dk]`` one a key channel
+    (the least decay is then over channels too).  A sequence that ``chunk``
+    does not divide is padded with positions of ``g = 0`` and ``beta = 0``,
+    which neither decay nor write.  ``backend`` (``"pallas"`` /
+    ``"reference"``; None: by the device) and ``interpret`` are for tests of
+    the kernels on the CPU; a shape the kernels do not tile
+    (:func:`_kernel_heads`; per channel: ``chunk`` :data:`CHANNEL_CHUNK` and
+    whole lane tiles) runs the ``jax.numpy`` form on any backend."""
     if backend is None:
         backend = "pallas" if jax.default_backend() == "tpu" else "reference"
     s, h, dk = k.shape[1:]
-    hb = _kernel_heads(chunk, h, dk, v.shape[-1]) if backend == "pallas" else 0
+    dv, channel = v.shape[-1], g.ndim == k.ndim
+    # heads a grid step of the kernels takes: 0 where they do not tile
+    if backend != "pallas":
+        hb = 0
+    elif channel:
+        hb = int(chunk == CHANNEL_CHUNK and not (dk % 128 or dv % 128))
+    else:
+        hb = _kernel_heads(chunk, h, dk, dv)
     pad = -s % chunk
     if pad:
         q, k, v, g, beta = (
             jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
             for x in (q, k, v, g, beta))
-    if hb:
+    if channel and hb:
+        out, final, decay_min = _chunked_channel_pallas(
+            q, k, v, g, beta, interpret)
+    elif channel:
+        out, final, decay_min = _chunked_channel_xla(q, k, v, g, beta, chunk)
+    elif hb:
         out, final, decay_min = _chunked_pallas(q, k, v, g, beta, chunk, hb,
                                                 interpret)
     else:
@@ -694,12 +1052,15 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
 def gated_delta_sequential(q, k, v, g, beta):
     """The recurrence as written, one position at a time in float32 at
     ``highest`` matmul precision -> ``(o [B, S, H, Dv], final state [B, H,
-    Dk, Dv])``.  The chunked form's reference; nothing trains through it."""
+    Dk, Dv])``.  ``g [B, S, H]`` or, a number a key channel, ``[B, S, H,
+    Dk]``.  The chunked forms' reference; nothing trains through it."""
     bsz, _, h, dk = k.shape
 
     def step(state, inputs):
-        q_t, k_t, v_t, g_t, b_t = inputs  # [B, H, D] x 3, [B, H] x 2
-        state = jnp.exp(g_t)[..., None, None] * state
+        # [B, H, D] x 3, g [B, H] or [B, H, Dk], beta [B, H]
+        q_t, k_t, v_t, g_t, b_t = inputs
+        decay = jnp.exp(g_t)[..., None]
+        state = (decay if g_t.ndim == 3 else decay[..., None]) * state
         held = jnp.einsum("bhk,bhkv->bhv", k_t, state, precision="highest")
         state = state + k_t[..., :, None] * (
             b_t[..., None] * (v_t - held))[..., None, :]

@@ -1,13 +1,15 @@
 """The gated RMS norm over SHORT groups of lanes as one op with its own
 backward: each row of ``x [..., W]`` normalised by the mean square of each
 of its ``W / group`` groups of ``group`` columns by itself, a gain ``[W]``,
-and the gate ``silu(z)`` on one side of the norm or the other:
+and a gate ``act(z)`` on one side of the norm or the other, ``act`` one of
+:data:`ACTIVATIONS` (``silu`` unless said):
 
-    norm then gate   gain * x * rsqrt(mean_g(x^2) + eps) * silu(z)
-    gate then norm   gain * v * rsqrt(mean_g(v^2) + eps),  v = x * silu(z)
+    norm then gate   gain * x * rsqrt(mean_g(x^2) + eps) * act(z)
+    gate then norm   gain * v * rsqrt(mean_g(v^2) + eps),  v = x * act(z)
 
 The first is the gated delta-rule mixer's (a group is a value head's 128
-lanes), the second the state-space mixer's in more groups than one (Mamba-2's
+lanes; with ``sigmoid`` the per-channel rule's, Kimi Delta Attention), the
+second the state-space mixer's in more groups than one (Mamba-2's
 ``MambaRMSNormGated`` at ``group_size = W / n_groups``: 512 lanes a group in
 Nemotron's eight).  One group over the whole width is ``ops.rmsnorm``'s case
 and not this op's.  XLA's fusions of this arithmetic move 110-130 GB/s of the
@@ -20,7 +22,7 @@ the squares of a group summed in float32, one rounding to the output's dtype
 the residuals are the inputs alone.  With ``n = v * inv`` the normalised
 row, ``gw`` the cotangent of ``n`` and ``c = mean_g(gw * n)``:
 
-    dv = inv * (gw - n * c)        dgain = sum_rows dy * n [* silu(z)]
+    dv = inv * (gw - n * c)        dgain = sum_rows dy * n [* act(z)]
 
 and the gate's two factors by the product rule on whichever side it sits.
 
@@ -71,10 +73,29 @@ _BLOCK_LANES = 512
 _STEP_REGISTERS = 32
 #: rows of the gain's sums: one float32 register's
 _SUM_ROWS = 8
+#: the gate's activations: ``(act, act')``, each of float32 ``z`` and
+#: ``sigmoid(z)``
+ACTIVATIONS = {
+    "silu": (lambda z, sig: z * sig,
+             lambda z, sig: sig * (1.0 + z * (1.0 - sig))),
+    "sigmoid": (lambda z, sig: sig, lambda z, sig: sig * (1.0 - sig)),
+}
 
 
-def _reference(x, z, gain, group, eps, gate_first):
-    xf, s = x.astype(F32), jax.nn.silu(z.astype(F32))
+def _gate(z, activation):
+    """``act(z)`` of float32 ``z``: all the forward needs."""
+    return ACTIVATIONS[activation][0](z, jax.nn.sigmoid(z))
+
+
+def _gate_terms(z, activation):
+    """``(act(z), act'(z))`` of float32 ``z``."""
+    sig = jax.nn.sigmoid(z)
+    act, slope = ACTIVATIONS[activation]
+    return act(z, sig), slope(z, sig)
+
+
+def _reference(x, z, gain, group, eps, gate_first, activation="silu"):
+    xf, s = x.astype(F32), _gate(z.astype(F32), activation)
     v = xf * s if gate_first else xf
     parts = v.reshape(v.shape[:-1] + (-1, group))
     inv = jax.lax.rsqrt(
@@ -116,12 +137,6 @@ def _fold(a):
     return jnp.sum(a.reshape(-1, _SUM_ROWS, a.shape[-1]), axis=0)
 
 
-def _silu_terms(z):
-    """``(silu(z), silu'(z))`` of float32 ``z``."""
-    sig = jax.nn.sigmoid(z)
-    return z * sig, sig * (1.0 + z * (1.0 - sig))
-
-
 def _walk(block: int, step: int, body):
     """``body(first row of the step)`` down a block of rows: a loop, so that
     a kernel's text holds one step a group and not every step of a block —
@@ -140,7 +155,7 @@ def _groups(width: int, group: int):
 
 
 def _fwd_kernel(x_ref, z_ref, gain_ref, y_ref, *, group, eps, gate_first,
-                step):
+                step, activation):
     from jax.experimental import pallas as pl
 
     block, width = x_ref.shape
@@ -150,8 +165,7 @@ def _fwd_kernel(x_ref, z_ref, gain_ref, y_ref, *, group, eps, gate_first,
         def body(at, lanes=lanes, gain=gain):
             rows = pl.ds(at, step)
             x = x_ref[rows, lanes].astype(F32)
-            z = z_ref[rows, lanes].astype(F32)
-            s = z * jax.nn.sigmoid(z)
+            s = _gate(z_ref[rows, lanes].astype(F32), activation)
             v = x * s if gate_first else x
             n = v * jax.lax.rsqrt(_group_mean(v * v, group) + eps)
             y = n * gain if gate_first else (gain * n) * s
@@ -161,7 +175,7 @@ def _fwd_kernel(x_ref, z_ref, gain_ref, y_ref, *, group, eps, gate_first,
 
 
 def _bwd_kernel(x_ref, z_ref, gain_ref, dy_ref, dx_ref, dz_ref, dgain_ref, *,
-                group, eps, gate_first, step, live_rows):
+                group, eps, gate_first, step, live_rows, activation):
     from jax.experimental import pallas as pl
 
     block, width = x_ref.shape
@@ -178,7 +192,7 @@ def _bwd_kernel(x_ref, z_ref, gain_ref, dy_ref, dx_ref, dz_ref, dgain_ref, *,
             rows = pl.ds(at, step)
             x = x_ref[rows, lanes].astype(F32)
             dy = dy_ref[rows, lanes].astype(F32)
-            s, ds = _silu_terms(z_ref[rows, lanes].astype(F32))
+            s, ds = _gate_terms(z_ref[rows, lanes].astype(F32), activation)
             v = x * s if gate_first else x
             # the cotangent of the normalised row n = v * inv
             gw = dy * gain if gate_first else (dy * gain) * s
@@ -226,15 +240,17 @@ def _call_params(resident_bytes: int) -> dict:
 # once a call site (``ops/conv_silu.py``: a step has several, and an unjitted
 # wrapper cost seconds of ``setup_s``).
 @functools.partial(jax.jit, static_argnames=(
-    "group", "eps", "gate_first", "tile", "interpret"))
-def _norm_fwd(x, z, gain, group, eps, gate_first, tile, interpret):
+    "group", "eps", "gate_first", "tile", "interpret", "activation"))
+def _norm_fwd(x, z, gain, group, eps, gate_first, tile, interpret,
+              activation):
     from jax.experimental import pallas as pl
 
     (rows, width), (block, lanes, step) = x.shape, tile
     grid, specs = _specs(rows, width, block, lanes)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, group=group, eps=eps,
-                          gate_first=gate_first, step=step),
+                          gate_first=gate_first, step=step,
+                          activation=activation),
         grid=grid,
         in_specs=[specs["rows"], specs["rows"], specs["gain"]],
         out_specs=specs["rows"],
@@ -248,15 +264,17 @@ def _norm_fwd(x, z, gain, group, eps, gate_first, tile, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "group", "eps", "gate_first", "tile", "interpret"))
-def _norm_bwd(x, z, gain, dy, group, eps, gate_first, tile, interpret):
+    "group", "eps", "gate_first", "tile", "interpret", "activation"))
+def _norm_bwd(x, z, gain, dy, group, eps, gate_first, tile, interpret,
+              activation):
     from jax.experimental import pallas as pl
 
     (rows, width), (block, lanes, step) = x.shape, tile
     grid, specs = _specs(rows, width, block, lanes)
     dx, dz, sums = pl.pallas_call(
         functools.partial(_bwd_kernel, group=group, eps=eps,
-                          gate_first=gate_first, step=step, live_rows=rows),
+                          gate_first=gate_first, step=step, live_rows=rows,
+                          activation=activation),
         grid=grid,
         in_specs=[specs["rows"], specs["rows"], specs["gain"], specs["rows"]],
         out_specs=[specs["rows"], specs["rows"], specs["sums"]],
@@ -276,12 +294,14 @@ def _flat(a):
     return a.reshape(-1, a.shape[-1])
 
 
-def _forward(x, z, gain, group, eps, gate_first, tile, interpret):
+def _forward(x, z, gain, group, eps, gate_first, tile, interpret,
+             activation):
     return _norm_fwd(_flat(x), _flat(z), gain, group, eps, gate_first, tile,
-                     interpret).reshape(x.shape)
+                     interpret, activation).reshape(x.shape)
 
 
-_gated_norm_kernels = jax.custom_vjp(_forward, nondiff_argnums=(3, 4, 5, 6, 7))
+_gated_norm_kernels = jax.custom_vjp(
+    _forward, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 
 
 def _kernels_fwd(x, z, gain, *static):
@@ -289,10 +309,11 @@ def _kernels_fwd(x, z, gain, *static):
     return _forward(x, z, gain, *static), (x, z, gain)
 
 
-def _kernels_bwd(group, eps, gate_first, tile, interpret, res, dy):
+def _kernels_bwd(group, eps, gate_first, tile, interpret, activation, res,
+                 dy):
     x, z, gain = res
     dx, dz, dgain = _norm_bwd(_flat(x), _flat(z), gain, _flat(dy), group, eps,
-                              gate_first, tile, interpret)
+                              gate_first, tile, interpret, activation)
     return dx.reshape(x.shape), dz.reshape(z.shape), dgain
 
 
@@ -300,27 +321,33 @@ _gated_norm_kernels.defvjp(_kernels_fwd, _kernels_bwd)
 
 
 def gated_norm(x: jax.Array, z: jax.Array, gain: jax.Array, *, group: int,
-               eps: float, gate_first: bool, backend: Optional[str] = None,
+               eps: float, gate_first: bool, activation: str = "silu",
+               backend: Optional[str] = None,
                interpret: bool = False) -> jax.Array:
     """The gated norm of the module docstring: ``x [B, ..., W]`` (float32 or
     bfloat16), ``z`` of ``x``'s shape, ``gain [W]`` -> ``[B, ..., W]`` in
-    ``z``'s dtype.  ``gate_first``: gate then norm (else norm then gate).
+    ``z``'s dtype.  ``gate_first``: gate then norm (else norm then gate);
+    ``activation``: the gate's, of :data:`ACTIVATIONS`.
     By the kernel pair where the module's rule allows, one call per shard of
     the mesh in scope, else in ``jax.numpy``.  ``backend`` (``"pallas"`` /
     ``"reference"``; None: by the device) and ``interpret`` are for tests of
     the kernels on the CPU."""
     if backend is None:
         backend = "pallas" if jax.default_backend() == "tpu" else "reference"
+    if activation not in ACTIVATIONS:
+        raise ValueError(
+            f"gated_norm: activation={activation!r} is none of "
+            f"{tuple(ACTIVATIONS)}")
     if backend != "pallas":
-        return _reference(x, z, gain, group, eps, gate_first)
+        return _reference(x, z, gain, group, eps, gate_first, activation)
 
     def shard(x, z, gain):
         tile = _tile(x.size // x.shape[-1], x.shape[-1], group,
                      (x.dtype, z.dtype))
         if tile is None:
-            return _reference(x, z, gain, group, eps, gate_first)
+            return _reference(x, z, gain, group, eps, gate_first, activation)
         return _gated_norm_kernels(x, z, gain, group, eps, gate_first, tile,
-                                   interpret)
+                                   interpret, activation)
 
     free, batch_axes, _ = shard_axes(x.shape[0])
     rows = Spec(batch_axes, *([None] * (x.ndim - 1)))

@@ -438,11 +438,16 @@ def program_summary(hlo_text: str) -> dict:
     kernel (``attention/attn_window/flash_fwd/pallas_call``:
     ``attn_window``).  ``accelerate()`` adds the loss function's
     ``program_facts`` attribute (a dict; ``models.llama.program_facts``:
-    ``ssm_layers``, ``conv_layers``, ``gdn_layers``, ``attention_layers``,
-    ``ssm_chunks_per_sequence``, ``gdn_chunks_per_sequence`` of a model
-    whose layers are not all attention layers), where it carries one.
-    Block remat names nothing of a delta-rule layer to keep: its mixer
-    recomputes from its projections' outputs (``llama._gdn_mixer``)."""
+    ``ssm_layers``, ``conv_layers``, ``gdn_layers``, ``kda_layers``,
+    ``attention_layers``, ``ssm_chunks_per_sequence``,
+    ``gdn_chunks_per_sequence``, ``kda_chunks_per_sequence`` of a model
+    whose layers are not all attention layers), where it carries one.  What
+    block remat keeps of a delta-rule layer is its rule's kernel's outputs,
+    by the names the op gives them (``ops.gated_delta.SAVED_NAMES``,
+    ``CHANNEL_SAVED_NAMES``; ``llama.forward_hidden``'s policy): the two
+    kernel pairs ``gdn_chunk_fwd`` / ``gdn_chunk_bwd`` and ``kda_chunk_fwd``
+    / ``kda_chunk_bwd`` sit under ``gdn/gdn_scan`` and ``kda/kda_scan`` in
+    ``kernel_scopes``."""
     kernels: dict = {}
     kernel_scopes: dict = {}
     applications = 0

@@ -1,0 +1,178 @@
+"""``ops.gated_delta`` with a decay per key CHANNEL (``g [B, S, H, Dk]``,
+Kimi Delta Attention): the chunked ``jax.numpy`` form against the sequential
+form, forward and all five gradients, over decays from ``e^-1e-3`` to
+``e^-20`` a token in single channels; ``g`` constant over a head's channels
+against the scalar op; the kernel pair ``kda_chunk_fwd`` / ``kda_chunk_bwd``
+in interpret mode against the ``jax.numpy`` form; what block remat keeps of
+it; and the shapes the kernels do not tile."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from test_remat_keeps_flash import _kernel_calls  # noqa: I100 - shared
+
+from dlrover_tpu.ops import gated_delta as gd
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+NAMES = ("q", "k", "v", "g", "beta")
+
+
+def _operands(seed=0, b=1, s=160, h=2, dk=128, dv=128, dtype=F32,
+              slow=1e-3, fast=20.0):
+    """Unit q and k (q scaled), normal v, ``beta = sigmoid(N(0, 1))`` and a
+    rate a CHANNEL and position, log-uniform between ``slow`` and ``fast``:
+    in one head some channels keep nearly all of the state over a chunk and
+    some underflow float32 within five positions."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+        jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+    return (
+        (unit(jax.random.normal(keys[0], (b, s, h, dk))) * dk ** -0.5
+         ).astype(dtype),
+        unit(jax.random.normal(keys[1], (b, s, h, dk))).astype(dtype),
+        jax.random.normal(keys[2], (b, s, h, dv)).astype(dtype),
+        -jnp.exp(jax.random.uniform(keys[3], (b, s, h, dk), F32,
+                                    np.log(slow), np.log(fast))),
+        jax.nn.sigmoid(jax.random.normal(keys[4], (b, s, h))))
+
+
+def _rel(a, b):
+    a, b = (np.asarray(x, np.float64) for x in (a, b))
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def _scalar_loss(fn):
+    def loss(*ops):
+        out = fn(*ops)
+        return jnp.sum(jnp.sin(out[0])) + 0.1 * jnp.sum(jnp.square(out[1]))
+    return loss
+
+
+def _chunked(chunk, backend="reference"):
+    return lambda *ops: gd.gated_delta_chunked(
+        *ops, chunk, backend=backend, interpret=True)
+
+
+@pytest.mark.parametrize("chunk", [16, 64, 128])
+def test_the_chunked_form_equals_the_recurrence(chunk):
+    """A sequence the chunk does not divide (160 = 1.25 x 128), decays
+    ``e^-1e-3`` to ``e^-20`` a token: output, final state and the five
+    gradients at float32's noise, and nothing infinite or NaN although a
+    chunk's least decay underflows to 0."""
+    ops = _operands()
+    out, state, decay_min = jax.jit(_chunked(chunk))(*ops)
+    want_out, want_state = gd.gated_delta_sequential(*ops)
+    assert _rel(out, want_out) < 2e-5 and _rel(state, want_state) < 2e-5
+    assert float(decay_min) == 0.0  # e^-20 x 16 positions and more
+    got = jax.jit(jax.grad(_scalar_loss(_chunked(chunk)), range(5)))(*ops)
+    want = jax.jit(jax.grad(
+        _scalar_loss(gd.gated_delta_sequential), range(5)))(*ops)
+    for name, g, w in zip(NAMES, got, want):
+        assert bool(jnp.isfinite(g).all()), name
+        assert g.shape == w.shape and _rel(g, w) < 5e-5, name
+
+
+def test_no_exponent_that_is_evaluated_is_positive(monkeypatch):
+    """Every ``exp`` of a chunk (``_channel_chunk``, what both forms run)
+    sees an argument <= 0, the filler included, at decays whose plain
+    ``exp(-Gamma)`` would overflow: two a level of the halving, and the
+    three of the rule's own."""
+    seen = []
+    real = jnp.exp
+
+    def watched(x):
+        seen.append(float(jnp.max(x)))
+        return real(x)
+
+    q, k, v, g, beta = _operands(s=128, h=1, slow=5.0, fast=20.0)
+    gam = jnp.cumsum(g[0, :, 0], axis=0)
+    assert float(jnp.min(gam)) < -1500.0  # exp(-gam) is inf in float32
+    monkeypatch.setattr(gd.jnp, "exp", watched)
+    out, state = gd._channel_chunk(
+        q[0, :, 0], k[0, :, 0], v[0, :, 0], gam, beta[0, :, :1],
+        jnp.ones((128, 128), F32), F32, gd.unit_lower_inverse)
+    assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(state).all())
+    assert len(seen) == 2 * 7 + 3 and max(seen) <= 0.0
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_a_decay_constant_over_a_heads_channels_is_the_scalar_rule(chunk):
+    """``g [B, S, H]`` broadcast over the channels: the sequential form bit
+    for bit, the chunked form to float32's noise against the scalar op's
+    own chunked form."""
+    q, k, v, g, beta = _operands(seed=1, fast=3.0)
+    per_head = g[..., 0]
+    wide = jnp.broadcast_to(per_head[..., None], g.shape)
+    a = gd.gated_delta_sequential(q, k, v, per_head, beta)
+    b = gd.gated_delta_sequential(q, k, v, wide, beta)
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    got = _chunked(chunk)(q, k, v, wide, beta)
+    want = _chunked(chunk)(q, k, v, per_head, beta)
+    assert _rel(got[0], want[0]) < 1e-5 and _rel(got[1], want[1]) < 1e-5
+    assert float(got[2]) == pytest.approx(float(want[2]), rel=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_the_kernel_pair_equals_the_numpy_form(dtype):
+    """``kda_chunk_fwd`` / ``kda_chunk_bwd`` in interpret mode against the
+    ``jax.numpy`` form at the same chunk: the same function computes a chunk
+    in both, so float32 differs by the inverse's order of operations alone
+    and bfloat16 operands by nothing more."""
+    ops = _operands(seed=2, s=256, dtype=dtype)
+    kernels, numpy_form = _chunked(128, "pallas"), _chunked(128)
+    got, want = kernels(*ops), numpy_form(*ops)
+    assert got[0].dtype == F32 and got[1].shape == (1, 2, 128, 128)
+    assert _rel(got[0], want[0]) < 1e-5 and _rel(got[1], want[1]) < 1e-5
+    assert float(got[2]) == float(want[2])
+    g_got = jax.grad(_scalar_loss(kernels), range(5))(*ops)
+    g_want = jax.grad(_scalar_loss(numpy_form), range(5))(*ops)
+    for name, g, w, op in zip(NAMES, g_got, g_want, ops):
+        assert g.dtype == op.dtype and g.shape == op.shape, name
+        assert _rel(g.astype(F32), w.astype(F32)) < (
+            1e-4 if dtype == F32 else 1e-2), name
+
+
+def test_the_kernels_run_once_each_and_name_what_remat_keeps():
+    ops = _operands(seed=3, s=256, dtype=BF16)
+    kernels = _chunked(128, "pallas")
+    assert _kernel_calls(jax.make_jaxpr(kernels)(*ops).jaxpr) == {
+        "kda_chunk_fwd": 1}
+    grad = jax.grad(_scalar_loss(kernels), range(5))
+    assert _kernel_calls(jax.make_jaxpr(grad)(*ops).jaxpr) == {
+        "kda_chunk_fwd": 1, "kda_chunk_bwd": 1}
+    # under a policy that keeps the three names the forward kernel does not
+    # run again in front of the backward
+    kept = jax.checkpoint(
+        _scalar_loss(kernels),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *gd.CHANNEL_SAVED_NAMES))
+    assert _kernel_calls(jax.make_jaxpr(jax.grad(kept, range(5)))(
+        *ops).jaxpr) == {"kda_chunk_fwd": 1, "kda_chunk_bwd": 1}
+    assert gd.CHANNEL_SAVED_NAMES == ("kda_out", "kda_state", "kda_entering")
+    assert not set(gd.CHANNEL_SAVED_NAMES) & set(gd.SAVED_NAMES)
+
+
+@pytest.mark.parametrize("why,chunk,dk", [
+    ("a chunk that is not the kernels'", 64, 128),
+    ("a head that is no whole lane tile", 128, 64),
+])
+def test_what_the_kernels_do_not_tile_runs_the_numpy_form(why, chunk, dk):
+    ops = _operands(seed=4, s=128, dk=dk, dv=dk)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        gd.gated_delta_chunked, chunk=chunk, backend="pallas",
+        interpret=True))(*ops)
+    assert not _kernel_calls(jaxpr.jaxpr), why
+
+
+def test_the_scalar_rules_kernels_are_as_they_were():
+    """A decay a head still takes ``gdn_chunk_fwd`` / ``gdn_chunk_bwd``."""
+    q, k, v, g, beta = _operands(seed=5, s=128, dtype=BF16)
+    grad = jax.grad(_scalar_loss(lambda *ops: gd.gated_delta_chunked(
+        *ops, 64, backend="pallas", interpret=True)), range(5))
+    assert _kernel_calls(jax.make_jaxpr(grad)(
+        q, k, v, g[..., 0], beta).jaxpr) == {
+            "gdn_chunk_fwd": 1, "gdn_chunk_bwd": 1}

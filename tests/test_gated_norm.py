@@ -43,10 +43,10 @@ def _operands(x_dtype, z_dtype, shape=SHAPE, seed=0):
             jax.random.normal(keys[3], shape))
 
 
-def _by_hand(x, z, gain, group, gate_first):
+def _by_hand(x, z, gain, group, gate_first, activation="silu"):
     """The op in float64 numpy, group by group."""
     x, z, gain = (np.asarray(a.astype(F32), np.float64) for a in (x, z, gain))
-    s = z / (1.0 + np.exp(-z))
+    s = (z if activation == "silu" else 1.0) / (1.0 + np.exp(-z))
     v = x * s if gate_first else x
     out = np.empty_like(v)
     for at in range(0, v.shape[-1], group):
@@ -68,11 +68,13 @@ def _grads(fn, x, z, gain, cot, **kw):
     return jax.value_and_grad(scalar, (0, 1, 2), has_aux=True)(x, z, gain)
 
 
+@pytest.mark.parametrize("activation", sorted(gn.ACTIVATIONS))
 @pytest.mark.parametrize("x_dtype", [F32, BF16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("group", [128, 512])
 @pytest.mark.parametrize("gate_first", [False, True],
                          ids=["norm_then_gate", "gate_then_norm"])
-def test_the_kernels_equal_the_float32_reference(gate_first, group, x_dtype):
+def test_the_kernels_equal_the_float32_reference(gate_first, group, x_dtype,
+                                                 activation):
     """Output, ``dx``, ``dz`` and ``dgain`` at float32 tolerances, with a
     float32 gate so that no rounding to bfloat16 hides the arithmetic.  The
     output element by element against float64: a group's mean square that
@@ -80,13 +82,14 @@ def test_the_kernels_equal_the_float32_reference(gate_first, group, x_dtype):
     x, z, gain, cot = _operands(x_dtype, F32)
     assert gn._tile(200, SHAPE[-1], group, (x_dtype, F32)) == (
         192, 512, {128: 192, 512: 64}[group])
-    kw = dict(group=group, eps=EPS, gate_first=gate_first)
+    kw = dict(group=group, eps=EPS, gate_first=gate_first,
+              activation=activation)
     (_, y), (dx, dz, dgain) = _grads(kernels, x, z, gain, cot, **kw)
     (_, y_ref), want = _grads(gn.gated_norm, x, z, gain, cot,
                               backend="reference", **kw)
     assert y.dtype == F32 and dx.dtype == x_dtype and dz.dtype == F32
     assert dgain.dtype == gain.dtype and dgain.shape == gain.shape
-    by_hand = _by_hand(x, z, gain, group, gate_first)
+    by_hand = _by_hand(x, z, gain, group, gate_first, activation)
     big = np.abs(by_hand) > 1e-3
     assert np.max(np.abs(np.asarray(y, np.float64) / np.where(
         big, by_hand, 1.0) - 1.0)[big]) < 1e-6
@@ -97,15 +100,17 @@ def test_the_kernels_equal_the_float32_reference(gate_first, group, x_dtype):
     assert _rel(dgain, want[2]) < 1e-6
 
 
-@pytest.mark.parametrize("group,gate_first", [(128, False), (512, True)],
-                         ids=["delta_rule", "state_space"])
-def test_the_kernels_in_the_cells_dtypes(group, gate_first):
+@pytest.mark.parametrize("group,gate_first,activation", [
+    (128, False, "silu"), (512, True, "silu"), (128, False, "sigmoid")],
+    ids=["delta_rule", "state_space", "delta_rule_per_channel"])
+def test_the_kernels_in_the_cells_dtypes(group, gate_first, activation):
     """float32 ``x`` (what the rule's and the scan's kernels put out) and a
     bfloat16 gate: ``y`` and ``dz`` leave in bfloat16, one rounding of the
     float32 result — which the reference's own rounding meets on all but a
     few elements that sit on a bfloat16 tie."""
     x, z, gain, cot = _operands(F32, BF16, seed=1)
-    kw = dict(group=group, eps=EPS, gate_first=gate_first)
+    kw = dict(group=group, eps=EPS, gate_first=gate_first,
+              activation=activation)
     (_, y), (dx, dz, dgain) = _grads(kernels, x, z, gain, cot, **kw)
     (_, y_ref), want = _grads(gn.gated_norm, x, z, gain, cot,
                               backend="reference", **kw)
@@ -118,12 +123,17 @@ def test_the_kernels_in_the_cells_dtypes(group, gate_first):
     assert _rel(dgain, want[2]) < 1e-6
 
 
-def test_the_reference_is_the_arithmetic_by_hand():
+@pytest.mark.parametrize("activation", sorted(gn.ACTIVATIONS))
+def test_the_reference_is_the_arithmetic_by_hand(activation):
     for gate_first, group in [(False, 128), (True, 512), (True, 1024)]:
         x, z, gain, _ = _operands(F32, F32)
         got = gn.gated_norm(x, z, gain, group=group, eps=EPS,
-                            gate_first=gate_first)
-        assert _rel(got, _by_hand(x, z, gain, group, gate_first)) < 1e-6
+                            gate_first=gate_first, activation=activation)
+        assert _rel(got, _by_hand(
+            x, z, gain, group, gate_first, activation)) < 1e-6
+    with pytest.raises(ValueError, match="activation='tanh'"):
+        gn.gated_norm(x, z, gain, group=128, eps=EPS, gate_first=False,
+                      activation="tanh")
 
 
 @pytest.mark.parametrize("why,rows,width,group,dtypes", [

@@ -593,12 +593,13 @@ def test_defaults_are_todays_and_name_no_delta_rule_layer():
             cfg.shared_expert_gate) == (0, 0, 0, 4, 0, False, 1.0, False,
                                         False)
     assert (cfg.gdn_layers, cfg.head_dim, cfg.rotary_dim) == (0, 128, 128)
-    # 56 before this mixer, its nine, the one-branch layers' two and the
-    # rotary table a kind of attention layer may have of its own
-    assert len(dataclasses.fields(llama.LlamaConfig)) == 56 + 9 + 2 + 1
+    # 56 before this mixer, its nine, the one-branch layers' two, the
+    # rotary table a kind of attention layer may have of its own and the
+    # per-channel rule's three
+    assert len(dataclasses.fields(llama.LlamaConfig)) == 56 + 9 + 2 + 1 + 3
     assert tuple(llama.MIXER_KINDS) == (
         "attention", "mamba", "conv", "linear_attention",
-        "window_attention")
+        "window_attention", "kda")
     assert llama.program_facts(cfg, 4096) == {}
     assert llama.program_facts(_next(), 4096) == {
         "gdn_layers": 3, "attention_layers": 1,

@@ -289,9 +289,11 @@ def test_flash_kernels_ask_for_vmem_only_past_the_default():
 
 
 @pytest.mark.parametrize("over,match", [
-    (dict(v_head_dim=24), "v_head_dim=24"),
+    # (values of a width of their own and one query matrix are built
+    # since PR 61: tests/test_llama_kda_mla.py)
+    (dict(v_head_dim=0), "v_head_dim > 0"),
     (dict(qk_rope_head_dim=7, v_head_dim=31), "qk_rope_head_dim"),
-    (dict(q_lora_rank=0), "q_lora_rank > 0"),
+    (dict(q_lora_rank=-1), "q_lora_rank >= 0"),
     (dict(n_kv_head=2), "n_kv_head=2"),
     (dict(router_score="tanh"), "router_score='tanh'"),
     (dict(experts_held=12, experts_held_first=8), "experts_held=12"),

@@ -17,7 +17,10 @@ distance from the ``jax.numpy`` form's (the norm of the difference over the
 norm).  ``--block_lanes`` times the kernels at other widths of a grid step
 than the module's.  The table is also written to
 ``chiprun_out/gated_delta_bench.json``; ``--toy`` rehearses it off the chip
-(short sequences, the kernels in interpret mode).
+(short sequences, the kernels in interpret mode).  ``--channel`` times the
+rule with a decay per key CHANNEL (``kda_chunk_fwd`` / ``kda_chunk_bwd``) at
+the Kimi Linear cell's shapes: one sequence of 16,384, 32 heads of 128,
+chunks of 128, ``g [B, S, H, 128]`` with a rate drawn per channel.
 """
 
 from __future__ import annotations
@@ -61,6 +64,7 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=53)
     ap.add_argument("--block_lanes", type=int, nargs="*", default=[])
     ap.add_argument("--toy", action="store_true")
+    ap.add_argument("--channel", action="store_true")
     args = ap.parse_args()
 
     import jax
@@ -71,17 +75,21 @@ def main() -> int:
     f32, bf16 = jnp.float32, jnp.bfloat16
     bsz, s, h, d, chunk = (1, 256, 8, 128, 64) if args.toy else (
         2, 8192, 32, 128, 64)
+    if args.channel:
+        chunk = gd.CHANNEL_CHUNK
+        bsz, s = (1, 256) if args.toy else (1, 16384)
+    rates = (h, d) if args.channel else (h,)
     keys = jax.random.split(jax.random.PRNGKey(args.seed), 7)
     unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
         jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
-    a_log = jnp.log(jax.random.uniform(keys[0], (h,), f32, 1e-6, 16.0))
+    a_log = jnp.log(jax.random.uniform(keys[0], rates, f32, 1e-6, 16.0))
     operands = (
         (unit(jax.random.normal(keys[1], (bsz, s, h, d))) * d ** -0.5
          ).astype(bf16),
         unit(jax.random.normal(keys[2], (bsz, s, h, d))).astype(bf16),
         jax.random.normal(keys[3], (bsz, s, h, d)).astype(bf16),
         -jnp.exp(a_log) * jax.nn.softplus(
-            jax.random.normal(keys[4], (bsz, s, h)) + 1.0),
+            jax.random.normal(keys[4], (bsz, s) + rates) + 1.0),
         jax.nn.sigmoid(jax.random.normal(keys[5], (bsz, s, h))))
     cot = jax.random.normal(keys[6], (bsz, s, h, d))
 
