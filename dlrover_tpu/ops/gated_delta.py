@@ -698,8 +698,10 @@ def _chunked_pallas(q, k, v, g, beta, qn: int, hb: int, interpret: bool):
 # (rows of the wrong half get the filler exponent and are zero exactly);
 # ``log2 Q`` levels cover every pair once, and the diagonal of ``QK`` has the
 # exponent 0.  No ``[Q, Q, Dk]`` array and no pairwise work on the VPU.  ``r``
-# is picked out of ``Gamma`` by a product with a 0/1 matrix at ``highest``
-# (exact).  The rest is the scalar rule's with ``exp(Gamma)`` a ``[Q, Dk]``
+# is a row of ``Gamma`` copied down its block, and costs the MXU nothing: two
+# turns of the rows and two selects a level (:func:`_halving_references`;
+# sublane rotations in the kernels, exact; the cotangent is the same moves
+# back).  The rest is the scalar rule's with ``exp(Gamma)`` a ``[Q, Dk]``
 # array: ``W = T (beta k . exp(Gamma))``, ``o = (q . exp(Gamma)) S + QK u``,
 # ``S <- diag(exp(Gamma_Q)) S + (k . exp(Gamma_Q - Gamma))^T u``.  The state
 # is held TRANSPOSED, ``[Dv, Dk]``, so that a channel's decay runs along the
@@ -750,18 +752,6 @@ _product.defvjp(_product_fwd, _product_bwd)
 
 
 @jax.custom_vjp
-def _rows_at(pick, x):
-    """``pick x`` at ``highest``: the rows of float32 ``x`` that the 0/1
-    matrix ``pick`` names, exactly."""
-    return _dot(pick, x, _HIGHEST)
-
-
-_rows_at.defvjp(lambda pick, x: (_rows_at(pick, x), pick),
-                lambda pick, g: (jnp.zeros_like(pick),
-                                 _tn(pick, g, _HIGHEST)))
-
-
-@jax.custom_vjp
 def _whole_tile_inverse(a):
     """:func:`_tile_inverse` of one head's ``[Q, Q]`` tile, with
     :func:`unit_lower_inverse`'s cotangent."""
@@ -782,28 +772,76 @@ _whole_tile_inverse.defvjp(
     lambda a: (_whole_tile_inverse(a),) * 2, _whole_tile_inverse_bwd)
 
 
-def _channel_chunk(q, k, v, gam, bc, state, dt, inverse):
+def _reads(b, x):
+    """The rows of ``x`` that :func:`_half_rows` moves."""
+    second = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) & abs(b)) != 0
+    return second if b > 0 else ~second
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _half_rows(roll, b, x):
+    """Float32 ``x [n, d]`` with one half of every aligned block of ``2 |b|``
+    rows reading from the other: at ``b > 0`` row ``i`` of a second half
+    takes row ``i - b``, at ``b < 0`` row ``i`` of a first half takes row ``i
+    + |b|``; the other half keeps its own.  ``roll(x, shift)`` turns the rows
+    as ``jnp.roll`` along axis 0; the rows that wrap are never taken.  The
+    cotangent is the same move back, added to the row it came from."""
+    return jnp.where(_reads(b, x), roll(x, b), x)
+
+
+_half_rows.defvjp(
+    lambda roll, b, x: (_half_rows(roll, b, x), None),
+    lambda roll, b, _, g: (
+        jnp.where(_reads(b, g), 0.0, g + roll(g, -b)),))
+
+
+def _rolled_rows(x, shift):
+    """``jnp.roll`` along the rows: the ``jax.numpy`` form's moves."""
+    return jnp.roll(x, shift, axis=0)
+
+
+def _rotated_sublanes(x, shift):
+    """The same in a kernel, a rotation of the sublanes (shifts of 8 and
+    more move whole vregs, 1, 2 and 4 go through the XLU)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.roll(x, shift % x.shape[0], 0)
+
+
+def _halving_references(gam, roll):
+    """For every block size ``b = 1, 2, 4, ... n / 2`` of the halving, ``(b,
+    ref)`` with ``ref[i] = gam[(i & ~(2 b - 1)) | (b - 1)]``: the ``Gamma``
+    of the last position of the first half of row ``i``'s aligned block of
+    ``2 b``, by moves of ``gam``'s rows alone.  ``held`` has ``gam[i | (b -
+    1)]`` in row ``i``; clearing bit ``b`` of the row gives ``ref``, setting
+    it the next level's ``held``."""
+    held, b = gam, 1
+    while True:
+        yield b, _half_rows(roll, b, held)
+        if 2 * b >= gam.shape[0]:
+            return
+        held, b = _half_rows(roll, -b, held), 2 * b
+
+
+def _channel_chunk(q, k, v, gam, bc, state, dt, inverse, roll=_rolled_rows):
     """One head's chunk of ``n`` positions under a per-channel decay:
     ``q``, ``k``, ``gam [n, Dk]``, ``v [n, Dv]``, ``bc [n, 1]`` and the
     state that enters, transposed, ``[Dv, Dk]``, all float32 -> ``(o [n,
     Dv], the state that leaves [Dv, Dk])``.  ``gam`` is the cumulative sum
-    of ``g`` inside the chunk; ``inverse`` computes ``(I + A)^-1``."""
+    of ``g`` inside the chunk; ``inverse`` computes ``(I + A)^-1`` and
+    ``roll`` turns a tile's rows (:func:`_half_rows`)."""
     n, dk = k.shape
     iota = jax.lax.broadcasted_iota
     row, col = iota(jnp.int32, (n, n), 0), iota(jnp.int32, (n, n), 1)
     at = iota(jnp.int32, (n, dk), 0)
     a = jnp.zeros((n, n), F32)
     qk = jnp.where(row == col, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
-    b = 1
-    while b < n:
+    # ref: the Gamma of the last position of the first half of a row's block
+    for b, ref in _halving_references(gam, roll):
         shift = (2 * b).bit_length() - 1
         block = lambda x: jax.lax.shift_right_logical(x, shift)  # noqa: E731
         under = ((block(row) == block(col)) & ((row & b) != 0)
                  & ((col & b) == 0))
-        # the Gamma of the last position of the first half of a row's block
-        ref = _rows_at(jnp.where(
-            col == jax.lax.shift_left(block(row), shift) + (b - 1), 1.0,
-            0.0).astype(F32), gam)
         second = (at & b) != 0
         # masked inside the exp: in the other half the bracket is positive
         from_ref = jnp.exp(jnp.where(second, gam - ref, _NEG))
@@ -812,7 +850,6 @@ def _channel_chunk(q, k, v, gam, bc, state, dt, inverse):
         a = a + jnp.where(under, _product("nt", k * from_ref, k_to, F32), 0.0)
         qk = qk + jnp.where(under, _product("nt", q * from_ref, k_to, dt),
                             0.0)
-        b *= 2
     inv = inverse(bc * a)
     grown = jnp.exp(gam)  # from the chunk's start to i, a channel
     w = _product("nn", inv, k * grown * bc, F32)
@@ -865,7 +902,7 @@ def _channel_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, state_ref,
     o_ref[...], state_ref[...] = _channel_chunk(
         q_ref[...].astype(F32), k_ref[...].astype(F32),
         v_ref[...].astype(F32), g_ref[...], _columns(b_ref[...])[:, :1],
-        state, dt, _whole_tile_inverse)
+        state, dt, _whole_tile_inverse, _rotated_sublanes)
 
 
 def _channel_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entering_ref,
@@ -884,7 +921,8 @@ def _channel_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entering_ref,
 
     dt = v_ref.dtype
     _, pull = jax.vjp(
-        functools.partial(_channel_chunk, dt=dt, inverse=_whole_tile_inverse),
+        functools.partial(_channel_chunk, dt=dt, inverse=_whole_tile_inverse,
+                          roll=_rotated_sublanes),
         q_ref[...].astype(F32), k_ref[...].astype(F32),
         v_ref[...].astype(F32), g_ref[...], _columns(b_ref[...])[:, :1],
         entering_ref[...].astype(F32))

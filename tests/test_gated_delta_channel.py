@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from test_remat_keeps_flash import _kernel_calls  # noqa: I100 - shared
+from test_ops import _eqns  # noqa: I100 - shared
+from test_remat_keeps_flash import _kernel_calls
 
 from dlrover_tpu.ops import gated_delta as gd
 
@@ -176,3 +177,77 @@ def test_the_scalar_rules_kernels_are_as_they_were():
     assert _kernel_calls(jax.make_jaxpr(grad)(
         q, k, v, g[..., 0], beta).jaxpr) == {
             "gdn_chunk_fwd": 1, "gdn_chunk_bwd": 1}
+
+
+# -- the halving's reference rows: moves of Gamma's rows, no product ----------
+
+
+def _level_rows(form, level, gam, cot):
+    """``(the reference rows of block size 2^level, the cotangent of gam
+    from cot on those rows alone)`` by the ``jax.numpy`` form's moves or, in
+    a kernel in interpret mode, by the kernels'."""
+    def rows(roll, gam, cot):
+        ref, pull = jax.vjp(lambda g: [r for _, r in gd._halving_references(
+            g, roll)][level], gam)
+        return ref, pull(cot)[0]
+
+    if form == "numpy":
+        return jax.jit(functools.partial(rows, gd._rolled_rows))(gam, cot)
+    from jax.experimental import pallas as pl
+
+    def kernel(gam_ref, cot_ref, ref_ref, d_ref):
+        ref_ref[...], d_ref[...] = rows(
+            gd._rotated_sublanes, gam_ref[...], cot_ref[...])
+    return pl.pallas_call(
+        kernel, out_shape=[jax.ShapeDtypeStruct(gam.shape, F32)] * 2,
+        interpret=True)(gam, cot)
+
+
+@pytest.mark.parametrize("form", ["numpy", "interpret"])
+@pytest.mark.parametrize("level", range(7))
+def test_the_reference_rows_are_moved_not_multiplied(level, form):
+    """``ref[i] = gam[(i & ~(2 b - 1)) | (b - 1)]`` bit for bit at every
+    block size of a chunk of 128, and the cotangent the sum of a block's
+    ``2 b`` rows, placed on its reference row."""
+    b, n = 2 ** level, gd.CHANNEL_CHUNK
+    keys = jax.random.split(jax.random.PRNGKey(level), 2)
+    # decays that underflow beside ones that do not, as a chunk's Gamma has
+    gam = -jnp.cumsum(jnp.exp(jax.random.uniform(
+        keys[0], (n, 128), F32, np.log(1e-3), np.log(20.0))), axis=0)
+    cot = jax.random.normal(keys[1], (n, 128), F32)
+    at = (np.arange(n) & ~(2 * b - 1)) | (b - 1)
+    ref, d_gam = _level_rows(form, level, gam, cot)
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(gam)[at])
+    want = np.zeros((n, 128), np.float64)
+    np.add.at(want, at, np.asarray(cot, np.float64))
+    assert not np.asarray(d_gam)[np.setdiff1d(np.arange(n), at)].any()
+    assert _rel(d_gam, want) < 1e-6
+
+
+def test_no_product_of_a_chunk_picks_rows():
+    """Every product of ``_channel_chunk`` contracts operands that come
+    from the chunk's inputs — none takes a 0/1 matrix built from iotas —
+    and the MXU passes of a chunk of 128, as ``tools/gated_delta_bench.py
+    --channel`` counts them, are 113 forward and 255 through ``jax.vjp``."""
+    from jax.extend.core import Var
+    from tools.gated_delta_bench import channel_chunk_passes
+
+    chunk = functools.partial(gd._channel_chunk, dt=BF16,
+                              inverse=gd._whole_tile_inverse)
+    jaxpr = jax.make_jaxpr(chunk)(*(jnp.zeros(s, F32) for s in (
+        (128, 128),) * 4 + ((128, 1), (128, 128)))).jaxpr
+    fed, products = set(jaxpr.invars), 0
+    for eqn in jaxpr.eqns:
+        inputs = [v for v in eqn.invars if isinstance(v, Var)]
+        inside = [e for sub in jax.core.jaxprs_in_params(eqn.params)
+                  for e in _eqns(sub)]
+        if any(e.primitive.name == "dot_general" for e in [eqn] + inside):
+            products += 1
+            assert all(v in fed for v in inputs), eqn
+        if any(v in fed for v in inputs):
+            fed.update(eqn.outvars)
+    assert products == 2 * 7 + 1 + 6  # the levels', the inverse, the rule's
+    assert channel_chunk_passes() == {
+        "fwd": {"highest": 102.0, "default": 11.0},
+        "vjp": {"highest": 222.0, "default": 33.0}}
+

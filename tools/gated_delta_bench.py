@@ -20,12 +20,16 @@ than the module's.  The table is also written to
 (short sequences, the kernels in interpret mode).  ``--channel`` times the
 rule with a decay per key CHANNEL (``kda_chunk_fwd`` / ``kda_chunk_bwd``) at
 the Kimi Linear cell's shapes: one sequence of 16,384, 32 heads of 128,
-chunks of 128, ``g [B, S, H, 128]`` with a rate drawn per channel.
+chunks of 128, ``g [B, S, H, 128]`` with a rate drawn per channel; and
+counts, in one ``GATED_DELTA_PASSES`` line, the MXU passes of one head's
+chunk as the kernels compute it, forward and through ``jax.vjp``
+(:func:`mxu_passes`).
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -56,6 +60,54 @@ def _median_ms(fn, args, iters):
 def _distance(a, b):
     a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def mxu_passes(fn, *args) -> dict:
+    """The MXU passes of ``fn(*args)`` by its jaxpr: every ``dot_general``,
+    sub-jaxprs included, as tiles of 128 (a product of ``[m, 128]`` and
+    ``[128, 128]`` is ``m / 128`` passes), six times at ``highest`` ->
+    ``{"highest": ..., "default": ...}`` pass-equivalents."""
+    import jax
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub)
+
+    passes = {"highest": 0.0, "default": 0.0}
+    for eqn in eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eqn.primitive.name != "dot_general":
+            continue
+        (contract, _), _ = eqn.params["dimension_numbers"]
+        lhs, out = eqn.invars[0].aval.shape, eqn.outvars[0].aval.shape
+        tiles = np.prod(out[:-1]) / 128 * -(-out[-1] // 128) * np.prod(
+            [-(-lhs[d] // 128) for d in contract])
+        highest = "HIGHEST" in str(eqn.params["precision"])
+        passes["highest" if highest else "default"] += (
+            6 if highest else 1) * float(tiles)
+    return passes
+
+
+def channel_chunk_passes() -> dict:
+    """:func:`mxu_passes` of one head's chunk under a per-channel decay as
+    ``kda_chunk_fwd`` computes it (bfloat16 operands, the whole-tile
+    inverse) and of its ``jax.vjp`` as ``kda_chunk_bwd`` takes it."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.ops import gated_delta as gd
+
+    chunk = functools.partial(gd._channel_chunk, dt=jnp.bfloat16,
+                              inverse=gd._whole_tile_inverse)
+    n = gd.CHANNEL_CHUNK
+    ops = [jnp.zeros(shape, jnp.float32) for shape in (
+        (n, 128),) * 4 + ((n, 1), (128, 128))]
+
+    def pulled(*ops):
+        out, pull = jax.vjp(chunk, *ops)
+        return pull(out)
+    return {"fwd": mxu_passes(chunk, *ops), "vjp": mxu_passes(pulled, *ops)}
 
 
 def main() -> int:
@@ -108,6 +160,9 @@ def main() -> int:
     print(f"DEVICE platform={device.platform} kind={device.device_kind}",
           flush=True)
     table, base = [], {}
+    if args.channel:
+        table.append({"passes": channel_chunk_passes()})
+        print("GATED_DELTA_PASSES " + json.dumps(table[0]), flush=True)
     runs = [("jax_numpy", "reference", None), ("kernels", "pallas", None)] + [
         (f"kernels_{lanes}_lanes", "pallas", lanes)
         for lanes in args.block_lanes]
