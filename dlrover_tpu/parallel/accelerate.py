@@ -118,50 +118,11 @@ class Strategy:
     compute_dtype: Any = jnp.bfloat16
     grad_accum: int = 1
     donate: bool = True
-    # Park optimizer state in host DRAM (ZeRO-Offload analogue,
-    # optim/offload.py): XLA streams it through HBM during the update.
-    offload_opt: bool = False
-    # Compress the dp-axis gradient reduction to int8 (blockwise
-    # quantize -> all_to_all of int8 shard-partials -> local dequant
-    # reduce -> one-hot int8 psum to re-replicate; all_gather is
-    # deliberately NOT used — its output is not statically replicated,
-    # which breaks check_vma), the reference's quant_reduce.cu
-    # capability (``atorch/ops/csrc/quantization/quant_reduce.cu``).
-    # The win is bandwidth on a DCN-crossing dp axis (multislice hybrid
-    # mesh); needs mesh.dp > 1.
-    quant_grads: bool = False
-
     def describe(self) -> str:
         return (
             f"mesh={self.mesh.describe()} remat={self.remat} "
             f"accum={self.grad_accum}"
-            + (" offload_opt" if self.offload_opt else "")
-            + (" quant_grads" if self.quant_grads else "")
         )
-
-
-def quant_grads_incompat(strategy: "Strategy") -> Optional[str]:
-    """The ONE source of truth for quant_grads compatibility (used by
-    the pre-flight check, candidate compilation, and the search-space
-    generator): returns a reason string when the strategy cannot run
-    with compressed gradient reduction, else None."""
-    if not strategy.quant_grads:
-        return None
-    m = strategy.mesh
-    if any(getattr(m, a) > 1 for a in ("pp", "fsdp", "ep", "tp")):
-        return (
-            "Strategy(quant_grads=True) needs a pure-dp mesh (got "
-            f"{m.describe()}); compressed DCN sync for hybrid/sharded "
-            "layouts goes through local_sgd's quantized outer step "
-            "instead"
-        )
-    if m.dp <= 1:
-        return (
-            "Strategy(quant_grads=True) needs mesh.dp > 1 (got "
-            f"{m.describe()}): there is no dp gradient reduction to "
-            "compress"
-        )
-    return None
 
 
 def infer_param_specs(params: Any, spec: MeshSpec) -> Any:
@@ -489,8 +450,6 @@ def _build_train_step(
     tx,
     strategy: Strategy,
     has_frozen: bool = False,
-    mesh: Optional[Mesh] = None,
-    batch_axes: Any = None,  # resolved PartitionSpec tree (quant path)
     rule_leaves: tuple = (),  # the loss function's (see RULE_UPDATES)
 ):
     """state={'params','opt_state','step'}; batch pytree; returns jittable
@@ -513,134 +472,10 @@ def _build_train_step(
     if strategy.remat not in ("none", "block"):
         lfn = jax.checkpoint(loss_fn, policy=remat_policy)
 
-    quant_on = (
-        strategy.quant_grads and quant_grads_incompat(strategy) is None
-    )
-    if rule_leaves and (quant_on or strategy.grad_accum > 1):
+    if rule_leaves and strategy.grad_accum > 1:
         raise ValueError(
-            f"rule_leaves={rule_leaves} with grad_accum="
-            f"{strategy.grad_accum} or quant_grads={strategy.quant_grads}: "
-            "a rule reads one step's counters, not a microbatch's, and "
-            "the compressed reduction hands out no metrics")
-
-    def _quant_loss_and_grads(params, batch, frozen):
-        """Full-step (loss, grads) with int8-compressed dp reduction.
-
-        Each dp shard differentiates its LOCAL batch shard (all
-        grad-accum microbatches accumulate locally), then ONE explicit
-        int8-compressed reduction replaces the gradient psum XLA would
-        have inserted implicitly — the quant_reduce.cu role.
-
-        Semantics: pmean of per-shard mean losses/grads — identical to
-        DDP's per-rank averaging (the reference's own data plane).  For
-        batches whose loss normalizes by a data-dependent count (packed
-        sequences), shards with fewer valid tokens are up-weighted
-        exactly as under DDP, and differ from the single-global-mean
-        GSPMD path by that same factor.
-
-        The shard_map is FULL-manual over the job's mesh (every other
-        axis has size 1): partial-manual (axis_names=) with any extra
-        mesh axis — even size 1 — hard-crashes this XLA build's
-        partitioner ("Invalid binary instruction opcode copy"), which is
-        why quant_grads requires a pure-dp mesh; hybrid/fsdp layouts get
-        compressed DCN sync via local_sgd's outer step instead."""
-        from dlrover_tpu.ops.quant_collectives import (
-            tree_quantized_pmean,
-        )
-
-        A = strategy.grad_accum
-
-        def scalar_loss(params, mb, **kw):
-            out = lfn(params, mb, **kw)
-            if isinstance(out, tuple):
-                raise ValueError(
-                    "Strategy(quant_grads=True): the loss function "
-                    "returned (loss, metrics); the int8-compressed dp "
-                    "reduction hands out no metrics")
-            return out
-
-        def local(params, b_local, frozen):
-            kw_l = {"frozen": frozen} if has_frozen else {}
-            # pcast to varying: custom-VJP rules (rmsnorm, flash
-            # attention, fused lm-head) emit per-shard cotangents, and
-            # the vma type check requires input/cotangent variance to
-            # match (invariance is restored by the reduction below).
-            params = jax.tree_util.tree_map(
-                lambda x: jax.lax.pcast(x, "dp", to="varying"), params
-            )
-            if has_frozen:
-                kw_l["frozen"] = jax.tree_util.tree_map(
-                    lambda x: jax.lax.pcast(x, "dp", to="varying"),
-                    kw_l["frozen"],
-                )
-
-            if A > 1:
-                micro = jax.tree_util.tree_map(
-                    lambda x: x.reshape((A, -1) + x.shape[1:]), b_local
-                )
-
-                def acc_fn(carry, mb):
-                    loss_sum, grads_sum = carry
-                    loss, grads = jax.value_and_grad(scalar_loss)(
-                        params, mb, **kw_l
-                    )
-                    return (
-                        loss_sum + loss,
-                        jax.tree_util.tree_map(
-                            jnp.add, grads_sum, grads
-                        ),
-                    ), None
-
-                # The whole carry is dp-varying (local sums).
-                zero = jax.tree_util.tree_map(
-                    lambda p: jax.lax.pcast(
-                        jnp.zeros(np.shape(p), jnp.float32), "dp",
-                        to="varying",
-                    ),
-                    (jnp.zeros(()), params),
-                )
-                (loss, grads), _ = jax.lax.scan(acc_fn, zero, micro)
-                loss = loss / A
-                grads = jax.tree_util.tree_map(
-                    lambda g: g / A, grads
-                )
-            else:
-                loss, grads = jax.value_and_grad(scalar_loss)(
-                    params, b_local, **kw_l
-                )
-            # ONE compressed reduction per step, after accumulation —
-            # not one per microbatch (the DCN bytes are the point).
-            return (
-                jax.lax.pmean(loss, "dp"),
-                tree_quantized_pmean(grads, "dp"),
-            )
-
-        def dp_only(spec):
-            # Honor the caller's batch placement, reduced to 'dp': axes
-            # entries containing 'dp' keep it (('dp','fsdp') == 'dp'
-            # here: the mesh is pure-dp, and the step's reductions name
-            # 'dp' alone), all others are replicated.  Force-sharding every leaf P('dp')
-            # would silently split replicated batch leaves.
-            parts = []
-            for part in spec:
-                if part == "dp" or (
-                    isinstance(part, (tuple, list)) and "dp" in part
-                ):
-                    parts.append("dp")
-                else:
-                    parts.append(None)
-            return P(*parts)
-
-        mb_specs = jax.tree_util.tree_map(
-            dp_only, batch_axes, is_leaf=lambda s: isinstance(s, P)
-        )
-        frozen_arg = frozen if has_frozen else jnp.zeros(())
-        return jax.shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(P(), mb_specs, P()),
-            out_specs=(P(), P()),
-        )(params, batch, frozen_arg)
+            f"rule_leaves={rule_leaves} with grad_accum={strategy.grad_accum}"
+            ": a rule reads one step's counters, not a microbatch's")
 
     def _value_and_grad(params, mb, frozen):
         """(loss, grads, metrics) for one microbatch.  A loss function
@@ -659,12 +494,7 @@ def _build_train_step(
     def train_step(state, batch, frozen=None):
         params = state["params"]
 
-        if quant_on:
-            # Accumulation happens INSIDE the sharded local step; one
-            # compressed reduction per optimizer step.
-            loss, grads = _quant_loss_and_grads(params, batch, frozen)
-            metrics = {}
-        elif strategy.grad_accum > 1:
+        if strategy.grad_accum > 1:
             micro = jax.tree_util.tree_map(
                 lambda x: x.reshape(
                     (strategy.grad_accum, -1) + x.shape[1:]
@@ -819,29 +649,6 @@ def accelerate(
             dataclasses.replace(c, grad_accum=grad_accum)
             for c in candidates
         ]
-    # Judge quant_grads on the NORMALIZED mesh: wildcard (-1) axes and
-    # implicit dp must resolve to real sizes first, or dp=-1 over 8
-    # devices would be rejected as dp<=1.  A mesh that doesn't fit the
-    # device count at all is NOT a quant_grads problem — leave those to
-    # the candidate loop's own per-candidate rejection.
-    def _qg_reason(c):
-        if not c.quant_grads:
-            return None
-        try:
-            norm = c.mesh.normalized(len(devs))
-        except ValueError:
-            return None
-        return quant_grads_incompat(
-            dataclasses.replace(c, mesh=norm)
-        )
-
-    qg_reasons = [_qg_reason(c) for c in candidates]
-    if qg_reasons and all(qg_reasons):
-        # Every candidate is an incompatible quant_grads combination
-        # (hybrid mesh, or dp<=1): fail fast with the real cause —
-        # an explicit-Strategy caller would otherwise only see the
-        # generic "no viable strategy found".
-        raise ValueError(qg_reasons[0])
     if loss_fn_builder is None and any(
         c.remat == "block" for c in candidates
     ):
@@ -1089,14 +896,6 @@ def _compile_candidate(
             f_specs = infer_param_specs(frozen_shape, mesh_spec)
         state_specs["frozen"] = f_specs
     state_sharding = named_sharding_tree(state_specs, mesh)
-    if strategy.offload_opt:
-        from dlrover_tpu.optim.offload import host_shardings_for
-
-        state_sharding = dict(
-            state_sharding,
-            opt_state=host_shardings_for(state_sharding["opt_state"]),
-        )
-
     if batch_axes is None:
         batch_axes = jax.tree_util.tree_map(
             lambda x: P(("dp", "fsdp")) if np.ndim(x) >= 1 else P(),
@@ -1104,13 +903,9 @@ def _compile_candidate(
         )
     batch_sharding = named_sharding_tree(batch_axes, mesh)
 
-    if strategy.quant_grads:
-        reason = quant_grads_incompat(strategy)
-        if reason:
-            raise ValueError(reason)
     step_fn = _build_train_step(
         loss_fn, optimizer, strategy, has_frozen=frozen is not None,
-        mesh=mesh, batch_axes=batch_axes, rule_leaves=rule_leaves,
+        rule_leaves=rule_leaves,
     )
     # The frozen tree is a separate, never-donated jit argument (see
     # _build_train_step); the public train_step keeps the state-dict API.
@@ -1125,17 +920,13 @@ def _compile_candidate(
         out_shardings=(step_state_sharding, None),
         donate_argnums=(0,) if strategy.donate else (),
     )
-    if strategy.remat == "offload" and not strategy.offload_opt:
+    if strategy.remat == "offload":
         # XLA's SPMD partitioner (jax 0.9) RET_CHECKs on the unsharded
         # device-placement custom-calls that explicit out_shardings
         # insert once host memories are in play ("Side-effect HLO must
         # have sharding").  Outputs inherit the state shardings from
         # in_shardings by inference, so dropping out_shardings is
-        # placement-equivalent here.  With offload_opt the opt_state
-        # OUTPUT must keep its explicit pinned_host sharding (inference
-        # could re-materialize it in HBM) — keep out_shardings there and
-        # let the candidate self-reject in the sweep if the partitioner
-        # still objects on this jax version.
+        # placement-equivalent here.
         jit_kwargs.pop("out_shardings")
     jitted = jax.jit(step_fn, **jit_kwargs)
 

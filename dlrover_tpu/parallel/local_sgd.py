@@ -43,11 +43,6 @@ class LocalSGDSync:
     outer_momentum: float = 0.9
     sync_every: int = 16
     dp_axis: str = "dp"
-    # int8-compress the outer drift reduction (ops.quant_collectives;
-    # the reference's quant_reduce.cu role).  THE bandwidth lever for
-    # DiLoCo across DCN-linked slices: the outer sync is exactly the
-    # traffic that crosses slices in the hybrid mesh.
-    quant_sync: bool = False
 
     def init(self, params: Any) -> Tuple[Any, Any]:
         """(anchor=copy of params, zero outer momentum) — both dp-invariant
@@ -166,14 +161,7 @@ class LocalSGDSync:
 
             def leaf(p_l, a_l, m_l):
                 delta = (a_l - p_l[0]) * w_l  # this replica's drift
-                if self.quant_sync:
-                    from dlrover_tpu.ops.quant_collectives import (
-                        quantized_psum,
-                    )
-
-                    delta = quantized_psum(delta, self.dp_axis) / w_sum
-                else:
-                    delta = jax.lax.psum(delta, self.dp_axis) / w_sum
+                delta = jax.lax.psum(delta, self.dp_axis) / w_sum
                 new_m = self.outer_momentum * m_l + delta
                 step = self.outer_momentum * new_m + delta  # Nesterov
                 new_p = a_l - self.outer_lr * step

@@ -52,8 +52,6 @@ def strategy_to_dict(strategy) -> dict:
         "compute_dtype": jnp.dtype(strategy.compute_dtype).name,
         "grad_accum": strategy.grad_accum,
         "donate": strategy.donate,
-        "offload_opt": strategy.offload_opt,
-        "quant_grads": strategy.quant_grads,
     }
 
 
@@ -62,21 +60,21 @@ def strategy_from_dict(d: dict):
 
     from dlrover_tpu.parallel.accelerate import Strategy
 
-    if d.get("fp8"):
-        # Stored strategies come from outside the process (the master's
-        # cache): one found under the fp8 option this tree no longer has
-        # is refused, not run in bf16 under its old score.
-        raise ValueError(
-            "stored strategy has 'fp8': true; Strategy has no fp8 option "
-            "(the step computes in compute_dtype): search again")
+    for key in ("fp8", "quant_grads", "offload_opt"):
+        if d.get(key):
+            # Stored strategies come from outside the process (a cache
+            # file, the master's KV): one scored under an option this
+            # tree no longer has is refused, not run without it under
+            # its old score.
+            raise ValueError(
+                f"stored strategy has {key!r}: true; Strategy has no "
+                f"{key} option: search again")
     return Strategy(
         mesh=MeshSpec(**d["mesh"]),
         remat=d["remat"],
         compute_dtype=jnp.dtype(d["compute_dtype"]),
         grad_accum=int(d["grad_accum"]),
         donate=bool(d.get("donate", True)),
-        offload_opt=bool(d.get("offload_opt", False)),
-        quant_grads=bool(d.get("quant_grads", False)),
     )
 
 
@@ -92,16 +90,14 @@ def default_space(
     accum: Sequence[int] = ACCUM_CHOICES,
     allow_ep: bool = False,
     allow_pp: bool = True,
-    offload_opt: Sequence[bool] = (False, True),
-    quant_grads: Sequence[bool] = (False,),
     base=None,
 ) -> List[Any]:
     """The discrete Strategy grid for ``n_devices`` (the combination half
     of reference ``combination_sg.py`` crossed with tunables).
 
     Covers every lever the bench sweeps by hand (r2 NOTES "next perf
-    wins"): pp factorizations, per-block/offload remat, host-offloaded
-    optimizer state and grad-accum up to 8."""
+    wins"): pp factorizations, per-block/offload remat and grad-accum
+    up to 8."""
     from dlrover_tpu.parallel.accelerate import Strategy
 
     base = base or Strategy()
@@ -111,23 +107,8 @@ def default_space(
     ):
         for r in remat:
             for a in accum:
-                for oo in offload_opt:
-                    for qg in quant_grads:
-                        cand = dataclasses.replace(
-                            base, mesh=spec, remat=r,
-                            grad_accum=a, offload_opt=oo,
-                            quant_grads=qg,
-                        )
-                        if qg:
-                            from dlrover_tpu.parallel.accelerate \
-                                import quant_grads_incompat
-
-                            # Incompatible combination (no dp axis
-                            # to compress, hybrid mesh): skip rather
-                            # than burn a compile.
-                            if quant_grads_incompat(cand):
-                                continue
-                        out.append(cand)
+                out.append(dataclasses.replace(
+                    base, mesh=spec, remat=r, grad_accum=a))
     return out
 
 
@@ -149,8 +130,7 @@ def estimate_step_hbm_bytes(
       assignment) tp does not reduce peak, because the gathered bf16
       working copies the tp matmuls need erase the sharding's saving
       (observed peak == state/fsdp exactly, with or without tp).
-    - optimizer state: ``opt_state_multiplier`` x params (0 when
-      ``offload_opt`` parks it host-side)
+    - optimizer state: ``opt_state_multiplier`` x params
     - gradients: one more params-worth
     - activations: tokens_per_device x d_model x ~24 residual-stream
       copies for remat="none", scaled down by remat policy and
@@ -171,9 +151,7 @@ def estimate_step_hbm_bytes(
     model_shards = max(1, m.fsdp) * max(1, m.pp)
     params_dev = 4.0 / _avg_dtype_bytes(params_shape) * p_bytes \
         / model_shards  # master f32 copy
-    opt_dev = 0.0 if strategy.offload_opt else (
-        opt_state_multiplier * params_dev
-    )
+    opt_dev = opt_state_multiplier * params_dev
     grads_dev = params_dev
 
     batch_leaves = [
@@ -279,8 +257,6 @@ def _features(strategy) -> np.ndarray:
             if strategy.remat in REMAT_CHOICES
             else 1.0,
             np.log2(max(1, strategy.grad_accum)),
-            float(strategy.offload_opt),
-            float(strategy.quant_grads),
         ],
         dtype=np.float64,
     )

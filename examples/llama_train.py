@@ -42,9 +42,6 @@ def parse_args():
     p.add_argument("--strategy", default="dp",
                    choices=["dp", "auto"])
     p.add_argument("--remat_block", action="store_true")
-    p.add_argument("--quant_grads", action="store_true",
-                   help="int8-compress the dp gradient reduction "
-                        "(pure-dp mesh; the DCN-bandwidth lever)")
     p.add_argument("--lora_rank", type=int, default=0,
                    help=">0: LoRA fine-tuning — train rank-r (A,B) "
                         "factors on the targeted projections, base "
@@ -113,10 +110,7 @@ def main() -> int:
     )
     strategy = (
         "auto" if args.strategy == "auto"
-        else Strategy(
-            mesh=MeshSpec(dp=len(jax.devices())),
-            quant_grads=args.quant_grads,
-        )
+        else Strategy(mesh=MeshSpec(dp=len(jax.devices())))
     )
 
     if args.init_from and args.lora_rank == 0:

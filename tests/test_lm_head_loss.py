@@ -160,17 +160,13 @@ ACCELERATE_PATHS = {
     "remat_dots": (Strategy(mesh=MeshSpec(), remat="dots"), {}),
     "metrics": (Strategy(mesh=MeshSpec()), {"metrics": True}),
     "fsdp2_tp2": (Strategy(mesh=MeshSpec(fsdp=2, tp=2)), {}),
-    "quant_grads": (Strategy(mesh=MeshSpec(dp=2), quant_grads=True), {}),
 }
 
 
 @pytest.mark.parametrize("path", sorted(ACCELERATE_PATHS))
 def test_one_step_of_every_accelerate_path_matches_the_per_token_loss(path):
     """One SGD step from the same state: the loss it reports, the gradient
-    norm and every updated parameter are those of the per-token formula
-    (of the unfused head under the int8 reduction, whose ``shard_map`` the
-    per-token op's backward scan never typed in: its carry starts
-    unvarying)."""
+    norm and every updated parameter are those of the per-token formula."""
     strategy, kw = ACCELERATE_PATHS[path]
     routed = bool(kw)
     cfg = _cfg(routed)
@@ -178,9 +174,7 @@ def test_one_step_of_every_accelerate_path_matches_the_per_token_loss(path):
     out = []
     for f in (lambda p, b: llama.loss_fn(
             p, b, cfg, fused_lm_head=True, **kw),
-            (lambda p, b: llama.loss_fn(p, b, cfg, fused_lm_head=False))
-            if path == "quant_grads" else
-            (lambda p, b: per_token_loss_fn(p, b, cfg, **kw))):
+            lambda p, b: per_token_loss_fn(p, b, cfg, **kw)):
         job = _job(f, cfg, strategy)
         state = job.create_state(jax.random.PRNGKey(0))
         state, m = job.train_step(state, {
@@ -188,8 +182,7 @@ def test_one_step_of_every_accelerate_path_matches_the_per_token_loss(path):
                 batch["tokens"], job.batch_sharding["tokens"])})
         out.append((m, state["params"]))
     (got_m, got_p), (want_m, want_p) = out
-    tol = 2e-3 if path == "quant_grads" else 1e-5  # int8 reduction noise
     np.testing.assert_allclose(got_m["loss"], want_m["loss"], rtol=1e-5)
     np.testing.assert_allclose(
-        got_m["grad_norm"], want_m["grad_norm"], rtol=max(tol, 1e-4))
-    _assert_trees_close(got_p, want_p, atol=tol)
+        got_m["grad_norm"], want_m["grad_norm"], rtol=1e-4)
+    _assert_trees_close(got_p, want_p, atol=1e-5)

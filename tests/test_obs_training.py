@@ -345,18 +345,29 @@ class TestHostCost:
     """``obs.span(..., host=True)``: the process's CPU seconds and page
     faults between the span's ends."""
 
-    def test_host_adds_cpu_seconds_of_the_whole_process(self, recorder):
+    def test_host_adds_cpu_seconds_of_the_whole_process(self, recorder,
+                                                        monkeypatch):
+        from dlrover_tpu.obs import recorder as rec_mod
+
         with obs.span("t.copy", "t", host=True, bytes=8):
-            deadline = time.monotonic() + 0.05
-            while time.monotonic() < deadline:  # the host is busy
+            burnt = time.process_time() + 0.02
+            while time.process_time() < burnt:  # the host is busy
                 pass
         with obs.span("t.wait", "t", host=True):
-            time.sleep(0.05)  # the process sleeps, as on a DMA
-        by = {s["name"]: s for s in _spans(recorder, "t.")}
-        copy, wait = by["t.copy"]["args"], by["t.wait"]["args"]
+            time.sleep(0.01)  # the process sleeps, as on a DMA
+        # what the span does with two readings, on readings the test owns:
+        # the machine's other load is in neither
+        readings = iter([(1.0, 10, 1), (1.25, 14, 1)])
+        monkeypatch.setattr(rec_mod, "_host_cost", lambda: next(readings))
+        with obs.span("t.owned", "t", host=True):
+            pass
+        by = {s["name"]: s["args"] for s in _spans(recorder, "t.")}
+        copy, wait = by["t.copy"], by["t.wait"]
         assert copy["bytes"] == 8
-        assert 0.02 < copy["cpu_s"] <= by["t.copy"]["dur"] * 1e-6 * 1.5 + 0.05
-        assert 0 <= wait["cpu_s"] < 0.04 < by["t.wait"]["dur"] * 1e-6
+        # the process's own CPU clock, read at both ends: no less than the
+        # loop burnt by that clock, and no sign the other way
+        assert copy["cpu_s"] >= 0.02 and wait["cpu_s"] >= 0
+        assert by["t.owned"] == {"cpu_s": 0.25, "minflt": 4, "majflt": 0}
         # the fault counts come together, and only where the platform
         # counts them at all
         for args in (copy, wait):
@@ -477,12 +488,14 @@ class FakeWorker:
     """Polls as alive until turn ``exits_on`` of the agent's loop, then
     as exited with ``code``."""
 
-    def __init__(self, exits_on=None, code=1, local_rank=0):
+    def __init__(self, exits_on=None, code=1, local_rank=0,
+                 on_poll=lambda: None):
         self.local_rank, self.exits_on, self.code = local_rank, exits_on, code
-        self.polls = 0
+        self.polls, self.on_poll = 0, on_poll
 
     def poll(self):
         self.polls += 1
+        self.on_poll()
         if self.exits_on is not None and self.polls >= self.exits_on:
             return self.code
         return None
@@ -503,6 +516,25 @@ class FakeClient:
         if isinstance(answer, Exception):
             raise answer
         return answer
+
+
+class OwnedClock:
+    """Stands in for the ``time`` module where the code under test reads
+    it: ``monotonic`` is the test's and ``sleep`` moves it and returns at
+    once, so what a span says of its seconds is arithmetic and the
+    machine's load is in none of it.  The rest is the real module's."""
+
+    def __init__(self):
+        self.now = time.monotonic()
+
+    def monotonic(self):
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += seconds
+
+    def __getattr__(self, name):
+        return getattr(time, name)
 
 
 def _agent(monkeypatch, workers, client=None, interval=0.05):
@@ -537,25 +569,40 @@ class TestAgentMonitorSpan:
     @pytest.mark.parametrize("turn", [1, 3])
     def test_a_worker_that_exits_nonzero_on_turn_n(self, recorder,
                                                    monkeypatch, turn):
-        agent = _agent(monkeypatch, [FakeWorker(exits_on=turn, code=-9)])
+        from dlrover_tpu.agent import training
+        from dlrover_tpu.obs import recorder as rec_mod
+
+        clock, poll = OwnedClock(), 0.002
+        monkeypatch.setattr(training, "time", clock)
+        monkeypatch.setattr(rec_mod, "time", clock)
+        agent = _agent(monkeypatch, [FakeWorker(
+            exits_on=turn, code=-9, on_poll=lambda: clock.sleep(poll))])
         _, args, dur = self._monitor(recorder, agent)
         assert args["result"] == "failed" and args["turns"] == turn
         assert agent._last_failures == [(0, -9)]
+        # on the test's clock a turn is its sleep and a poll of ``poll``
+        # seconds, and the master answers in no time
+        a_turn = self.INTERVAL + poll
+
+        def near(seconds):  # the recorder rounds to a microsecond
+            return pytest.approx(seconds, abs=1e-6)
+
+        assert dur == near(turn * a_turn)
         # from the last clean pass (the entry, on turn 1) to the pass that
-        # saw the exit code: one sleep and what the loop did around it
-        assert self.INTERVAL <= args["unseen_s"] <= dur
-        assert args["unseen_s"] < 2 * self.INTERVAL + 0.1
+        # saw the exit code: one sleep and the poll after it
+        assert args["unseen_s"] == near(a_turn)
         parts = args["sleep_s"] + args["poll_s"] + args["rpc_s"]
-        assert parts == pytest.approx(dur, abs=1e-3)
-        assert args["sleep_s"] >= turn * self.INTERVAL
-        assert args["turn_max_s"] >= self.INTERVAL > args["busy_max_s"]
+        assert parts == near(dur)
+        assert args["sleep_s"] == near(turn * self.INTERVAL)
+        assert args["poll_s"] == near(turn * poll)
+        assert args["rpc_s"] == 0.0
+        assert args["turn_max_s"] == near(a_turn)
+        assert args["busy_max_s"] == near(poll)
         assert args["rpc_errors"] == 0
         # the last turns (at most four), each [sleep, poll, rpc]; the one
         # that saw the failure never asked the master
-        assert len(args["last_turns"]) == min(turn, 4)
-        assert args["last_turns"][-1][2] == 0.0
-        assert all(len(t) == 3 and t[0] >= self.INTERVAL
-                   for t in args["last_turns"])
+        assert args["last_turns"] == [
+            near([self.INTERVAL, poll, 0.0])] * min(turn, 4)
 
     def test_last_turns_keeps_four(self, recorder, monkeypatch):
         agent = _agent(monkeypatch, [FakeWorker(exits_on=6)], interval=0.01)

@@ -501,6 +501,20 @@ class TestQuant:
         assert state.mu["w"].codes.dtype == jnp.int8
         assert state.mu["w"].codes.shape == (3, 128)  # ceil(300/128) blocks
 
+    def test_pallas_matches_jnp_path(self):
+        rs = np.random.RandomState(3)
+        x = jnp.asarray(rs.randn(1000) * 10, jnp.float32)
+        cj, sj = quantize_blockwise(x, backend="jnp")
+        cp, sp = quantize_blockwise(x, backend="pallas", interpret=True)
+        np.testing.assert_array_equal(np.asarray(cj), np.asarray(cp))
+        np.testing.assert_allclose(
+            np.asarray(sj), np.asarray(sp), rtol=1e-6
+        )
+        back = dequantize_blockwise(cp, sp, x.shape)
+        assert float(jnp.max(jnp.abs(back - x))) <= float(
+            jnp.max(sp)
+        )  # within one quantization step
+
 
 class TestGroupedMatmul:
     def test_kernel_matches_reference(self):

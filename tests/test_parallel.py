@@ -164,6 +164,62 @@ class TestAccelerate:
         assert specs["tiny"] == P()  # 3 not divisible by 8
         assert specs["scalar"] == P()
 
+    def test_offload_remat_matches_none_and_places_on_host(
+        self, cpu_mesh_devices
+    ):
+        """Strategy(remat='offload'): block residuals parked in host DRAM
+        (reference selective_offloading_checkpoint.py:252)."""
+        from dlrover_tpu.models import llama
+
+        cfg = llama.LlamaConfig.tiny(n_layer=2)
+        rng = np.random.RandomState(0)
+        sample = {"tokens": rng.randint(0, 250, size=(8, 17)).astype(
+            np.int32)}
+
+        def job_for(remat):
+            return accelerate(
+                loss_fn=lambda p, b: llama.loss_fn(
+                    p, b, cfg, moe_aux_weight=0.0
+                ),
+                init_fn=lambda r: llama.init_params(r, cfg),
+                optimizer=optax.adamw(1e-3),
+                sample_batch=sample,
+                strategy=Strategy(mesh=MeshSpec(dp=2), remat=remat),
+                devices=cpu_mesh_devices[:2],
+            )
+
+        j_off = job_for("offload")
+        j_none = job_for("none")
+        batch = {"tokens": jnp.asarray(sample["tokens"])}
+        s_off = j_off.create_state(jax.random.PRNGKey(0))
+        s_none = j_none.create_state(jax.random.PRNGKey(0))
+        for _ in range(2):
+            s_off, m_off = j_off.train_step(s_off, batch)
+            s_none, m_none = j_none.train_step(s_none, batch)
+        # Rematerialization reorders bf16 reductions: tiny drift is
+        # expected, equality is not.
+        np.testing.assert_allclose(
+            float(m_off["loss"]), float(m_none["loss"]), rtol=1e-3
+        )
+        # The host-placement effect itself is only observable on TPU
+        # runtimes (the single-memory CPU backend elides pinned_host
+        # transfers entirely — verified: even an explicit in-jit
+        # device_put to pinned_host lowers with no memory annotation).
+        # What IS checkable everywhere: the policy names the tagged
+        # residual and requests offload, not save.
+        from dlrover_tpu.parallel.accelerate import REMAT_POLICIES
+        from jax._src.ad_checkpoint import name_p
+        from jax._src.interpreters.partial_eval import Offloadable
+
+        pol = REMAT_POLICIES["offload"]
+        # Policy contract: the tagged residual offloads device->host;
+        # everything else rematerializes.
+        decision = pol(name_p, name="block_out")
+        assert isinstance(decision, Offloadable)
+        assert (decision.src, decision.dst) == ("device", "pinned_host")
+        assert not isinstance(pol(name_p, name="other"), Offloadable)
+        assert not isinstance(pol(None), Offloadable)
+
 
 class TestUlyssesSP:
     def test_matches_single_device_attention(self, cpu_mesh_devices):

@@ -10,6 +10,7 @@ import pytest
 from dlrover_tpu.data.shm_dataloader import (
     ShmDataLoader,
     ShmRing,
+    _READY,
     _pack_batch,
     _unpack_batch,
 )
@@ -103,28 +104,29 @@ class TestLoader:
             loader.close()
 
     def test_prefetch_overlaps_fetch_with_consumption(self):
-        """Pipelined wall-clock must beat serial fetch+consume."""
-        n = 10
+        """While the consumer still holds batch k, the producer fetches
+        batch k+1 and leaves it READY in the ring: shown by order — the
+        consumer takes no next batch until it has seen the one after
+        waiting — so that the machine's load is in none of it.  A loader
+        that fetched on demand would never show it."""
+        n = 6
         batches = [np.array([i]) for i in range(n)]
-        consume_s = 0.05
-
-        # Steady-state measurement: the first batch absorbs the one-time
-        # producer spawn (process start + imports); overlap is a property
-        # of the remaining stream.
         with ShmDataLoader(fetch_slow, batches, n_slots=4) as loader:
-            it = iter(loader)
-            next(it)
-            t0 = time.perf_counter()
-            for _ in it:
-                time.sleep(consume_s)  # the "train step"
-            pipelined = time.perf_counter() - t0
-
-        t0 = time.perf_counter()
-        for b in batches[1:]:
-            fetch_slow(b)
-            time.sleep(consume_s)
-        serial = time.perf_counter() - t0
-        assert pipelined < serial * 0.85, (pipelined, serial)
+            ring = loader._ring
+            for k, batch in enumerate(loader):
+                assert int(batch["y"][0]) == k * k
+                if k + 1 == n:
+                    break
+                # the "train step": it ends when the next batch is there
+                deadline = time.monotonic() + 60.0
+                while True:
+                    state, _, seq = ring._hdr((k + 1) % ring.n_slots)
+                    if state == _READY and seq == k + 1:
+                        break
+                    assert time.monotonic() < deadline, (
+                        f"batch {k + 1} was not fetched while batch {k} "
+                        "was held")
+                    time.sleep(0.001)
 
     def test_from_sampler_preserves_position(self):
         sampler = ElasticSampler(
