@@ -29,6 +29,16 @@ from dlrover_tpu.ops.per_shard import P, per_shard, shard_axes
 
 # Defaults tuned on v5e at [8,16,2048,64]: large blocks amortize MXU
 # pipeline fill (128x128 blocks ran at ~5% of peak; 512x512 at ~17%).
+# The forward and dq kernels work QUERY-major ([block_q, block_k] scores,
+# one query block a grid step); the dkv kernel works KEY-major
+# ([block_k, block_q], one key block a grid step): with the keys on
+# sublanes its two sums over queries, dv += p^T g and dk += ds^T q, are
+# plain products, and lse and delta (sequence along lanes, the only
+# layout Mosaic takes for them) broadcast down sublanes as they are.
+# Query-major, each of the two sums contracts its left operand over its
+# first axis, a [block_q, block_k] float32 transpose before the product,
+# and lse and delta change from lanes to sublanes, every turn of the loop
+# (PERF.md, PR 64).
 # Env overrides (read once at import) let a hardware tuning sweep try
 # block shapes per subprocess without touching call sites:
 # DLROVER_TPU_FLASH_BLOCK_{Q,K} / DLROVER_TPU_FLASH_BWD_BLOCK_{Q,K}.
@@ -322,9 +332,10 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
 #   dq kernel : grid (B*H, q_blocks); inner loop over K blocks recomputes
 #               p = exp(q k^T * scale - lse), ds = p (dp - delta) scale,
 #               accumulates dq += ds @ k.
-#   dkv kernel: grid (B*H, k_blocks); inner loop over Q blocks (starting at
-#               the first causally-unmasked Q block) accumulates
-#               dv += p^T g and dk += ds^T q.
+#   dkv kernel: grid (B*KV, k_blocks, group); inner loop over Q blocks
+#               (starting at the first causally-unmasked Q block)
+#               recomputes p^T = exp(k q^T * scale - lse) key-major and
+#               accumulates dv += p^T @ g and dk += ds^T @ q.
 # delta = rowsum(o * do) is precomputed outside (cheap fused elementwise).
 # ---------------------------------------------------------------------------
 
@@ -401,6 +412,15 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, *rest,
     dq_ref[0] = acc.astype(dq_ref.dtype)
 
 
+#: query blocks a turn of the dkv kernel's loop.  One block's chain (product,
+#: exp, product, products) leaves the MXU and the VPU waiting on each other,
+#: and a loop with traced bounds is not pipelined across its turns; with
+#: several blocks in one body the scheduler fills one's waits with another's
+#: work.  A full layer's call at S 16,384, 32/4 heads of 128 on a v5e: 39.8
+#: ms at 1, 33.5 at 2, 30.2 at 4 (tools/flash_bench.py; PERF.md, PR 64).
+_DKV_UNROLL = 4
+
+
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                     *rest, block_q, causal, sm_scale, seq_len,
                     padded_len, segmented=False, window=0):
@@ -414,6 +434,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     # lse_ref/delta_ref: [1, 1, 1, S_pad] (the unit dim keeps the block's
     # last two dims (1, S_pad) whole-axis, which Mosaic requires when
     # rep > 1); seg_ref: [1, 1, S_pad] int32.
+    # KEY-MAJOR (the header says why): s^T, p^T, dp^T and ds^T are
+    # [block_k, block_q], keys on sublanes and queries on lanes; lse and
+    # delta are read as [1, block_q] rows.
     if segmented:
         seg_ref, dk_ref, dv_ref = rest
     else:
@@ -424,8 +447,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     r = pl.program_id(2)
     k_start = ki * block_k
 
-    kb = k_ref[0].astype(jnp.float32)
-    vb = v_ref[0].astype(jnp.float32)
+    # Operands go to the MXU in the dtype they arrive in, p^T and ds^T
+    # narrowed to it where they are made: the MXU's one pass narrows a
+    # float32 operand to bfloat16 itself, so from bfloat16 operands dk and
+    # dv are the same bits either way (tools/flash_bench.py --against).
+    kb = k_ref[0].astype(q_ref.dtype)
+    vb = v_ref[0].astype(g_ref.dtype)
+    if segmented:
+        seg_k = seg_ref[0, 0, pl.ds(k_start, block_k)][:, None]
 
     num_q_blocks = pl.cdiv(padded_len, block_q)
     # Q blocks whose last row precedes k_start are fully causally masked.
@@ -441,50 +470,61 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     def body(qi, carry):
         dk_acc, dv_acc = carry
         q_start = qi * block_q
-        qpos = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
         kpos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
+            jnp.int32, (block_k, block_q), 0
         )
-        qb = q_ref[0, 0, pl.ds(q_start, block_q), :].astype(jnp.float32)
-        gb = g_ref[0, 0, pl.ds(q_start, block_q), :].astype(jnp.float32)
-        lse_b = lse_ref[0, 0, 0, pl.ds(q_start, block_q)]
-        delta_b = delta_ref[0, 0, 0, pl.ds(q_start, block_q)]
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
+        qpos = q_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 1
+        )
+        qb = q_ref[0, 0, pl.ds(q_start, block_q), :]
+        gb = g_ref[0, 0, pl.ds(q_start, block_q), :]
+        lse_row = lse_ref[0, 0, :, pl.ds(q_start, block_q)]  # [1, block_q]
+        delta_row = delta_ref[0, 0, :, pl.ds(q_start, block_q)]
+        sT = jax.lax.dot_general(
+            kb, qb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * sm_scale  # [block_q, block_k]
-        s = jnp.where(qpos < seq_len, s, NEG_INF)
-        s = jnp.where(kpos < seq_len, s, NEG_INF)
+        ) * sm_scale  # k q^T -> [block_k, block_q]
+        if padded_len != seq_len:
+            sT = jnp.where(qpos < seq_len, sT, NEG_INF)
+            sT = jnp.where(kpos < seq_len, sT, NEG_INF)
         if causal:
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
+            sT = jnp.where(qpos >= kpos, sT, NEG_INF)
         if window > 0:
-            s = jnp.where(qpos - kpos < window, s, NEG_INF)
+            sT = jnp.where(qpos - kpos < window, sT, NEG_INF)
         if segmented:
-            seg_q = seg_ref[0, 0, pl.ds(q_start, block_q)]
-            seg_k = seg_ref[0, 0, pl.ds(k_start, block_k)]
-            s = jnp.where(seg_q[:, None] == seg_k[None, :], s, NEG_INF)
-        p = jnp.exp(s - lse_b[:, None])
+            seg_q = seg_ref[0, :, pl.ds(q_start, block_q)]  # [1, block_q]
+            sT = jnp.where(seg_k == seg_q, sT, NEG_INF)
+        pT = jnp.exp(sT - lse_row)
         dv_acc = dv_acc + jax.lax.dot_general(
-            p, gb, (((0,), (0,)), ((), ())),
+            pT.astype(gb.dtype), gb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # p^T @ g -> [block_k, D]
-        dp = jax.lax.dot_general(
-            gb, vb, (((1,), (1,)), ((), ())),
+        )  # p^T @ g -> [block_k, Dv]
+        dpT = jax.lax.dot_general(
+            vb, gb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_b[:, None]) * sm_scale
+        )  # v g^T -> [block_k, block_q]
+        dsT = pT * (dpT - delta_row) * sm_scale
         dk_acc = dk_acc + jax.lax.dot_general(
-            ds, qb, (((0,), (0,)), ((), ())),
+            dsT.astype(qb.dtype), qb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # ds^T @ q -> [block_k, D]
         return dk_acc, dv_acc
 
-    dk_acc, dv_acc = jax.lax.fori_loop(
-        start_qi, num_q_blocks, body,
+    # _DKV_UNROLL query blocks a turn of the loop, then the blocks left
+    # over one a turn: the same blocks in the same order, so the same sums.
+    def several(j, carry):
+        for i in range(_DKV_UNROLL):
+            carry = body(start_qi + _DKV_UNROLL * j + i, carry)
+        return carry
+
+    whole = (num_q_blocks - start_qi) // _DKV_UNROLL
+    carry = jax.lax.fori_loop(
+        0, whole, several,
         (jnp.zeros(k_ref.shape[1:], jnp.float32),
          jnp.zeros(v_ref.shape[1:], jnp.float32)),
+    )
+    dk_acc, dv_acc = jax.lax.fori_loop(
+        start_qi + _DKV_UNROLL * whole, num_q_blocks, body, carry
     )
 
     @pl.when(r == 0)

@@ -675,6 +675,109 @@ class TestSlidingWindow:
             flash_attention(q, k, v, causal=False, window=4)
 
 
+#: name -> (H, KV, S, D, Dv, window, segmented, backward blocks q and k):
+#: the head sizes, groups and windows the cells run, two or more blocks each
+#: way (the defaults, 256 and 512, at 2,048 positions), B = 1
+DKV_CASES = {
+    "head64_group4": (4, 1, 256, 64, 64, 0, False, 64, 128),
+    "head128_group1": (2, 2, 256, 128, 128, 0, False, 64, 128),
+    "head128_group16": (16, 1, 256, 128, 128, 0, False, 64, 128),
+    "head192_value128": (2, 2, 256, 192, 128, 0, False, 64, 128),
+    "head256_group1": (1, 1, 256, 256, 256, 0, False, 64, 128),
+    "window1024_of_2048_group4": (4, 1, 2048, 128, 128, 1024, False,
+                                  256, 512),
+    "window_past_the_sequence": (2, 1, 256, 128, 128, 1000, False, 64, 128),
+    "ragged_300_pads_to_512": (4, 2, 300, 64, 64, 0, False, 64, 128),
+    "ragged_300_window_100": (2, 1, 300, 128, 128, 100, False, 64, 128),
+    "segments_group4": (4, 1, 256, 128, 128, 0, True, 64, 128),
+    "segments_ragged_window": (2, 2, 300, 64, 64, 100, True, 64, 128),
+}
+
+
+def _dkv_operands(case, dtype):
+    H, KV, S, D, Dv, window, segmented, bq, bk = DKV_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(64), 4)
+    dims = ((1, H, S, D), (1, KV, S, D), (1, KV, S, Dv), (1, H, S, Dv))
+    q, k, v, cot = (jax.random.normal(key, d, jnp.float32)
+                    for key, d in zip(keys, dims))
+    # dk and dv sum a group's heads: of order 1 at every group size
+    q, k, v, cot = (t.astype(dtype)
+                    for t in (q, k, v, cot * (H // KV) ** -0.5))
+    seg = None
+    if segmented:  # three documents, no boundary on a block's edge
+        seg = jnp.asarray(np.searchsorted([S // 3 + 5, 2 * S // 3 - 7],
+                                          np.arange(S), "right")[None])
+    return (q, k, v, cot), dict(segment_ids=seg, window=window), (bq, bk)
+
+
+class TestFlashDkvKeyMajor:
+    """``flash_bwd_dkv`` works on ``[block_k, block_q]`` arrays: ``dk`` and
+    ``dv`` (and ``dq`` beside them) against float32 autodiff of the
+    reference, and the two properties of the kernel's body that the
+    orientation buys."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", list(DKV_CASES))
+    def test_gradients_match_float32_autodiff(self, case, dtype):
+        (q, k, v, cot), kw, (bq, bk) = _dkv_operands(case, dtype)
+        f32 = [t.astype(jnp.float32) for t in (q, k, v)]
+        _, pull = jax.vjp(
+            lambda q, k, v: reference_attention(
+                q, k, v, True, kw["segment_ids"], kw["window"]), *f32)
+        want = pull(cot.astype(jnp.float32))
+        _, pull = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, backend="pallas", interpret=True,
+                block_q=bk, block_k=bk, bwd_block_q=bq, bwd_block_k=bk,
+                **kw), q, k, v)
+        got = pull(cot)
+        # the file's tolerances: float32 gradients, bfloat16 operands
+        tol = dict(atol=5e-4) if dtype == jnp.float32 else dict(
+            atol=2e-2, rtol=2e-2)
+        for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+            assert a.dtype == dtype and a.shape == b.shape
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b), err_msg=name,
+                **tol)
+
+    @pytest.mark.parametrize("case", ["head128_group16",
+                                      "head192_value128",
+                                      "segments_ragged_window"])
+    def test_no_transposed_product_and_no_float32_into_the_mxu(self, case):
+        """Every product of the kernel's body contracts its LEFT operand
+        over its LAST axis (one contracted over its first is transposed on
+        the way in, a whole ``[block_q, block_k]`` float32 array a
+        product), nothing is transposed by name, and with bfloat16 operands
+        no product is handed a float32 array."""
+        from dlrover_tpu.ops.flash_attention import (
+            _DKV_UNROLL,
+            _flash_bwd_pallas,
+            _flash_fwd,
+        )
+
+        (q, k, v, cot), kw, (bq, bk) = _dkv_operands(case, jnp.bfloat16)
+        out, lse = _flash_fwd(q, k, v, True, bk, bk, True, **kw)
+        jaxpr = jax.make_jaxpr(lambda *a: _flash_bwd_pallas(
+            *a, True, bq, bk, True, **kw))(q, k, v, out, lse, cot)
+        (body,) = [e.params["jaxpr"] for e in jaxpr.jaxpr.eqns
+                   if e.primitive.name == "pallas_call"
+                   and e.params["name"] == "flash_bwd_dkv"]
+        products = [e for e in _eqns(body)
+                    if e.primitive.name == "dot_general"]
+        # s^T, dv, dp^T and dk of a query block: the blocks of one turn of
+        # the loop, and the loop of the blocks left over
+        assert len(products) == 4 * (_DKV_UNROLL + 1)
+        for e in products:
+            (lhs, _), batch = e.params["dimension_numbers"]
+            assert tuple(lhs) == (1,) and batch == ((), ())
+            assert [str(x.aval.dtype) for x in e.invars] == ["bfloat16"] * 2
+            assert str(e.outvars[0].aval.dtype) == "float32"
+            assert e.params["precision"] is None
+        assert not [e for e in _eqns(body)
+                    if e.primitive.name == "transpose"]
+
+
 class TestSlidingWindowLlama:
     def test_llama_windowed_loss_and_decode_parity(self):
         """LlamaConfig.sliding_window flows through training (flash path)
