@@ -168,7 +168,13 @@ class LlamaConfig:
     # plain one at ``rope_theta``: ``{kind: Rotary}`` (kept as a sorted
     # tuple of pairs), kinds of :data:`ATTENTION_KINDS` that ``layer_types``
     # names.  The tables are then built once a step and kind
-    # (:func:`forward_hidden`), not once a layer.
+    # (:func:`forward_hidden`), not once a layer.  ``{kind: None}``: the
+    # layers of that kind carry NO rotary position (:meth:`unrotated`; no
+    # table is built for them) beside a kind that rotates — window layers
+    # that rotate among full layers that do not.  It combines with
+    # everything the plain q, k and v projections combine with
+    # (``branch_norm``, ``attn_output_gate``, ``attn_head_dim``, the q/k
+    # norms, experts); latent attention and ``rope`` False refuse it.
     rotary_by_kind: tuple = ()
     # Per-block rematerialization: save the residual stream at layer
     # boundaries and the flash kernel's output and log-sum-exp (of a
@@ -188,7 +194,15 @@ class LlamaConfig:
     # input.
     loop_passes: int = 1
     # Sandwich norm: an RMSNorm on each branch's OUTPUT too (gains
-    # ``ln1_out`` / ``ln2_out``), before the residual add.
+    # ``ln1_out`` / ``ln2_out``, plain gains initialised 1; scope
+    # ``branch_norm`` inside the branch's own), before the residual add.  Of
+    # a routed block it norms the combined result — the shared expert and
+    # the pairs of the experts held here, so with ``experts_held`` a PARTIAL
+    # sum.  It runs beside a looped stack, experts (``experts_held`` too),
+    # block remat, ``attn_output_gate``, ``attn_head_dim`` and the q/k
+    # norms; still refused beside it: ``one_branch``, ``norm_plus_one`` (the
+    # output gains are stored plain) and ``partial_rotary_factor`` (never
+    # run under it).
     branch_norm: bool = False
     # The exit gate of a looped model: ``sigmoid(z_t @ w + b)`` per token
     # and pass (``params["exit_gate"]``) makes a distribution over the
@@ -287,7 +301,9 @@ class LlamaConfig:
     mamba_conv_bias: bool = True
     mamba_proj_bias: bool = False
     # False: attention without rotary position (``position_embedding_type:
-    # "nope"``).
+    # "nope"``) in EVERY attention layer, latent ones too.  A model of which
+    # only one kind of layer is without keeps this True and says which in
+    # ``rotary_by_kind`` (``{kind: None}``).
     rope: bool = True
     # The softmax scale of attention; None: ``1 / sqrt(head_dim)``.
     attention_multiplier: Optional[float] = None
@@ -326,7 +342,12 @@ class LlamaConfig:
     # (0: it is; latent attention states its own).
     attn_head_dim: int = 0
     # A gate on the attention output: ``wq`` is twice as wide, each head's
-    # columns ``[q | gate]``, and ``out = (attn * sigmoid(gate)) wo``.
+    # columns ``[q | gate]``, and ``out = (attn * sigmoid(gate)) wo``, the
+    # gate per element (scope ``attn_gate`` inside ``attention``).  With
+    # ``attn_head_dim`` a setting of the plain q, k and v projections of a
+    # stack that runs once: both run under two norms a block or four
+    # (``branch_norm``), and are refused beside latent attention, the
+    # prediction block and a looped stack.
     attn_output_gate: bool = False
     # The share of a head's dims that rotate, the FIRST ``head_dim *
     # partial_rotary_factor`` of them in pairs ``(j, j + half)``; the rest
@@ -441,13 +462,16 @@ class LlamaConfig:
                     f"belongs to a kind of {tuple(ATTENTION_KINDS)} that "
                     "the model has a layer of, under rotary position on "
                     "the plain q and k projections")
+            if rotary is None:  # the kind carries no rotary position
+                continue
             if not isinstance(rotary, Rotary) or rotary.factor < 1.0 or (
                     rotary.factor != 1.0
                     and rotary.original_max_position_embeddings <= 0):
                 raise ValueError(
                     f"LlamaConfig: rotary_by_kind[{kind!r}]={rotary!r} is "
                     "no Rotary with factor >= 1 and, where it scales, "
-                    "original_max_position_embeddings > 0")
+                    "original_max_position_embeddings > 0, nor None (no "
+                    "rotary position)")
         if self.one_branch and (
                 ("moe" in kinds) != (self.num_experts > 0)
                 or self.loop_passes > 1 or self.mtp_layers
@@ -521,19 +545,34 @@ class LlamaConfig:
                 f"LlamaConfig: partial_rotary_factor="
                 f"{self.partial_rotary_factor} of head_dim={self.head_dim}: "
                 "the rotary dims are an even number of a head's dims")
-        plain = (self.kv_lora_rank == 0 and not self.branch_norm
-                 and not self.mtp_layers and self.loop_passes == 1)
-        for name, off in (("attn_head_dim", 0), ("attn_output_gate", False),
-                          ("partial_rotary_factor", 1.0),
-                          ("norm_plus_one", False)):
-            if getattr(self, name) != off and not plain:
+        # settings of the plain q, k and v projections of a stack that runs
+        # once; the two that touch the norms or were never run under four
+        # norms a block are refused beside ``branch_norm`` as well
+        once = (self.kv_lora_rank == 0 and not self.mtp_layers
+                and self.loop_passes == 1)
+        for name, off in (("attn_head_dim", 0), ("attn_output_gate", False)):
+            if getattr(self, name) != off and not once:
                 raise ValueError(
                     f"LlamaConfig: {name}={getattr(self, name)!r} with "
-                    f"kv_lora_rank={self.kv_lora_rank}, branch_norm="
-                    f"{self.branch_norm}, mtp_layers={self.mtp_layers} or "
-                    f"loop_passes={self.loop_passes}: it is a setting of "
+                    f"kv_lora_rank={self.kv_lora_rank}, mtp_layers="
+                    f"{self.mtp_layers} or loop_passes={self.loop_passes}: "
+                    "it is a setting of the plain q, k and v projections "
+                    "and of a stack that runs once (latent attention states "
+                    "its own head size and splits no gate off its queries; "
+                    "the prediction block and a looped stack have not run "
+                    "with it)")
+        for name, off in (("partial_rotary_factor", 1.0),
+                          ("norm_plus_one", False)):
+            if getattr(self, name) != off and (self.branch_norm or not once):
+                raise ValueError(
+                    f"LlamaConfig: {name}={getattr(self, name)!r} with "
+                    f"kv_lora_rank={self.kv_lora_rank}, mtp_layers="
+                    f"{self.mtp_layers}, loop_passes={self.loop_passes} or "
+                    f"branch_norm={self.branch_norm}: it is a setting of "
                     "the plain q, k and v projections and of a stack that "
-                    "runs once with two norms a block")
+                    "runs once with two norms a block (the output norms' "
+                    "gains are stored plain, and no rotation of a part of a "
+                    "head has run under them)")
         if self.shared_expert_gate and self.n_shared_experts <= 0:
             raise ValueError(
                 "LlamaConfig: shared_expert_gate with n_shared_experts="
@@ -594,6 +633,18 @@ class LlamaConfig:
         if kind == "window_attention" or not self.window_layers:
             return self.sliding_window
         return 0
+
+    def unrotated(self, kind: str) -> bool:
+        """Whether the layers of an attention ``kind`` carry no rotary
+        position: all of them where ``rope`` is False, else the kinds that
+        ``rotary_by_kind`` maps to None."""
+        return not self.rope or (kind, None) in self.rotary_by_kind
+
+    @property
+    def unrotated_layers(self) -> int:
+        """Attention layers without rotary position, of either kind."""
+        return sum(self.layers_of(kind) for kind in ATTENTION_KINDS
+                   if self.unrotated(kind))
 
     @property
     def gdn_conv_dim(self) -> int:
@@ -1179,9 +1230,11 @@ def _attention(
     segment_ids=None, kind: str = "attention", rotary=None,
 ):
     """Both attention kinds (:data:`ATTENTION_KINDS`): ``kind`` sets the
-    window (``cfg.window_of``) and, in a model with layers of both, the
-    scope around the flash call; ``rotary`` is the kind's ``(cos, sin)``
-    where the step built one (``cfg.rotary_by_kind``)."""
+    window (``cfg.window_of``), whether q and k rotate at all
+    (``cfg.unrotated``) and, in a model with layers of both, the scope
+    around the flash call; ``rotary`` is the kind's ``(cos, sin)`` where the
+    step built one (``cfg.rotary_by_kind``).  The output gate's multiply
+    sits under ``attn_gate``, inside the block's ``attention``."""
     B, S, C = x.shape
     H, KV, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     dt = cfg.dtype
@@ -1200,7 +1253,7 @@ def _attention(
     if not latent:
         q, k = qk_normed(q, k, layer, cfg)
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
-        if cfg.rope:
+        if not cfg.unrotated(kind):
             q = _rope_part(q, positions, cfg, rotary)
             k = _rope_part(k, positions, cfg, rotary)
         v = v.reshape(B, S, KV, D)
@@ -1260,8 +1313,9 @@ def _attention(
             )
             out = o.transpose(0, 2, 1, 3)
     if gate is not None:
-        out = (out.astype(jnp.float32)
-               * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
+        with jax.named_scope("attn_gate"):
+            out = (out.astype(jnp.float32)
+                   * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
     out = out.reshape(B, S, H * out.shape[-1])
     with (jax.named_scope("mla_out") if latent
           else contextlib.nullcontext()):
@@ -1961,12 +2015,20 @@ def block_apply(
     # ``moe_combine`` with the residual add) go into every instruction's
     # ``op_name`` of the compiled step: ``accelerate.program_summary``
     # reads them back, outermost scope only, hence siblings.  A branch's
-    # output norm (``cfg.branch_norm``) sits in its branch's scope.
+    # output norm (``cfg.branch_norm``) sits in its branch's scope, under
+    # ``branch_norm`` there.
     def add(x, branch):
         """``x + residual_multiplier * branch``; at 1.0 the add alone."""
         if cfg.residual_multiplier != 1.0:
             branch = branch * cfg.residual_multiplier
         return x + branch
+
+    def out_norm(branch, gain: str):
+        """The norm on a branch's output where ``cfg.branch_norm``."""
+        if not cfg.branch_norm:
+            return branch
+        with jax.named_scope("branch_norm"):
+            return rmsnorm(branch, layer[gain], eps=cfg.rms_eps)
 
     stats = {}
     if "ln1" in layer:  # the mixer's half; a one-branch MLP layer has none
@@ -1998,9 +2060,7 @@ def block_apply(
                 mixed = _attention(
                     h, layer, cfg, positions, attn_impl, mesh, segment_ids,
                     attn_kind, rotary)
-            if cfg.branch_norm:
-                mixed = rmsnorm(mixed, layer["ln1_out"], eps=cfg.rms_eps)
-            x = add(x, mixed)
+            x = add(x, out_norm(mixed, "ln1_out"))
     if "ln2" not in layer:  # a one-branch mixer layer: no MLP's half
         return x, stats
     if "moe" in layer:
@@ -2012,16 +2072,11 @@ def block_apply(
         )
         stats = dict(stats, **routed)
         with jax.named_scope("moe_combine"):
-            if cfg.branch_norm:
-                delta = rmsnorm(delta, layer["ln2_out"], eps=cfg.rms_eps)
-            x = add(x, delta)
+            x = add(x, out_norm(delta, "ln2_out"))
         return x, stats
     with jax.named_scope("mlp"):
         h = rmsnorm(x, _gain(layer["ln2"], cfg), eps=cfg.rms_eps)
-        out_m = _mlp(h, layer["mlp"], cfg.dtype)
-        if cfg.branch_norm:
-            out_m = rmsnorm(out_m, layer["ln2_out"], eps=cfg.rms_eps)
-        x = add(x, out_m)
+        x = add(x, out_norm(_mlp(h, layer["mlp"], cfg.dtype), "ln2_out"))
     return x, stats
 
 
@@ -2147,13 +2202,14 @@ def forward_hidden(
     apply = applier()
     # the window kind's layers say so to the block they share with the full
     # kind's; a kind with a rotary table of its own is handed it, built
-    # here once for all its layers
+    # here once for all its layers (none for a kind without rotary
+    # position: ``cfg.unrotated``)
     apply_by_kind = {"window_attention": applier(
         attn_kind="window_attention")} if cfg.window_layers else {}
     with jax.named_scope("rotary"):
         tables = {kind: {"rotary": _rotary_table(
             positions, rotary, cfg.rotary_dim)}
-            for kind, rotary in cfg.rotary_by_kind}
+            for kind, rotary in cfg.rotary_by_kind if rotary is not None}
     streams, exit_logits = [], []
     for _ in range(cfg.loop_passes):
         for i, layer in enumerate(params["layers"]):
@@ -2593,7 +2649,8 @@ TRAINING_PATH_ONLY = (
     ("shared_expert_gate", False, "a gate on the shared expert"),
     ("one_branch", False, "layers that are one branch each"),
     ("mlp_form", "swiglu", "an MLP that is not SwiGLU"),
-    ("rotary_by_kind", (), "a rotary table of a kind of layer's own"),
+    ("rotary_by_kind", (),
+     "a rotary table of a kind of layer's own, or a kind without position"),
 )
 
 
@@ -2646,8 +2703,10 @@ def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:
     "window_attention" layers says how many they are
     (``window_attention_layers``, of ``attention_layers``) and the pairs one
     sequence attends in a layer of each kind
-    (``attn_window_pairs_per_sequence``, ``attn_full_pairs_per_sequence``).
-    Empty for every other model."""
+    (``attn_window_pairs_per_sequence``, ``attn_full_pairs_per_sequence``),
+    and one of whose attention kinds ONE carries no rotary position
+    (``rotary_by_kind``) how many layers that is
+    (``unrotated_attention_layers``).  Empty for every other model."""
     facts = {f"{scope}_layers": cfg.layers_of(kind)
              for kind, scope in MIXER_KINDS.items()
              if kind not in ATTENTION_KINDS and cfg.layers_of(kind)}
@@ -2660,6 +2719,8 @@ def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:
         for kind, scope in ATTENTION_KINDS.items():
             facts[f"{scope}_pairs_per_sequence"] = attended_pairs(
                 seq_len, cfg.window_of(kind))
+    if cfg.rope and cfg.unrotated_layers:
+        facts["unrotated_attention_layers"] = cfg.unrotated_layers
     if facts:
         facts["attention_layers"] = cfg.attention_layers
     # the chunks a recurrent mixer's scan carries its state over
@@ -2711,7 +2772,9 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     expert and the share of a token's ``top_k`` picks that meet an expert
     held here (every other routed model's routed layers count as dense ones
     of ``d_ff``, as they always have).  A "window_attention" layer's scores
-    are counted over its window's keys."""
+    are counted over its window's keys; an output gate's columns of ``wq``
+    with the queries'.  Rotation, by kind or not at all, is no matmul and
+    counts nothing."""
     mats = 3 if cfg.mlp_form == "swiglu" else 2
     if cfg.kv_lora_rank > 0:  # latent attention's five projections
         qkv = (
@@ -2725,7 +2788,9 @@ def flops_per_token(cfg: LlamaConfig) -> float:
             * (cfg.qk_nope_head_dim + cfg.v_head_dim))
     else:
         qkv = (
-            cfg.d_model * cfg.n_head * cfg.head_dim  # wq
+            # wq, with the output gate's columns beside each head's
+            cfg.d_model * cfg.n_head * cfg.head_dim
+            * (2 if cfg.attn_output_gate else 1)
             + 2 * cfg.d_model * cfg.n_kv_head * cfg.head_dim)  # wk, wv
     # the MLP behind every mixer; a one-branch layer's mixer has none
     mlp = 0 if cfg.one_branch else mats * cfg.d_model * cfg.d_ff

@@ -633,10 +633,11 @@ def test_published_keys_count_the_parameters_of_the_cut():
     assert llama._moe_buffer_bounds(4 * 8192, 10, 512, 32) == (25600, 327680)
     # 6 x the matmul parameters of every layer as ONE dense MLP wide (the
     # estimator's convention), the causal square of the one attention
-    # layer, head and lookup, and 3 x the rule's matmuls and taps
+    # layer, head and lookup, and 3 x the rule's matmuls and taps; ``wq``
+    # with the output gate's columns beside the queries' (2 x 4096 wide)
     mlp = 3 * 2048 * 5120
     gdn = 2048 * (12288 + 64) + 4096 * 2048 + mlp
-    attn = 2048 * 4096 + 2 * 2048 * 512 + 4096 * 2048 + mlp
+    attn = 2048 * 2 * 4096 + 2 * 2048 * 512 + 4096 * 2048 + mlp
     rule = 32 * (10 * 64 * 128 + 6 * 128 * 128) + 2 * 4 * 8192
     assert llama.flops_per_token(cfg) == (
         6.0 * (3 * gdn + attn + 2 * 18992 * 2048)

@@ -47,6 +47,8 @@ from tools.gated_delta_bench import _distance, _median_ms  # noqa: E402
 SHAPES = {
     "mellum_window": (1, 32, 4, 16384, 128, 128, 1024),
     "mellum_full": (1, 32, 4, 16384, 128, 128, 0),
+    # the Trinity-Mini cell's window layers (its full layer is mellum_full)
+    "trinity_window": (1, 32, 4, 16384, 128, 128, 2048),
     "mistral": (2, 32, 8, 8192, 128, 128, 4096),
     "ouro": (2, 16, 16, 4096, 128, 128, 0),
     "olmoe": (8, 16, 16, 4096, 128, 128, 0),
