@@ -39,6 +39,13 @@ from dlrover_tpu.ops.per_shard import P, per_shard, shard_axes
 # first axis, a [block_q, block_k] float32 transpose before the product,
 # and lse and delta change from lanes to sublanes, every turn of the loop
 # (PERF.md, PR 64).
+# The dkv grid's innermost axis is the group's query head r, and Q and dO
+# are blocked by (b, r): their block index changes EVERY grid step, so the
+# pipeline fetches the block anew every step.  A window layer's step is
+# therefore handed only the rows its key block can reach (block_k + window
+# - 1, in whole query blocks: _dkv_query_rows), at an element offset that
+# follows the key block; the head's whole sequence, 2 x 4 MiB at S 16,384,
+# took 11.1 us a step against 5.5 us of work (PERF.md, PR 66).
 # Env overrides (read once at import) let a hardware tuning sweep try
 # block shapes per subprocess without touching call sites:
 # DLROVER_TPU_FLASH_BLOCK_{Q,K} / DLROVER_TPU_FLASH_BWD_BLOCK_{Q,K}.
@@ -251,9 +258,10 @@ _DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
 
 
 def _vmem_params(resident_bytes: int) -> dict:
-    """``pallas_call`` keywords for a kernel whose whole-sequence operands
-    (K and V forward and for dq, Q and dO for dkv: ``[1, S, D]`` each,
-    double-buffered) hold ``resident_bytes`` at once.  Under the compiler's
+    """``pallas_call`` keywords for a kernel whose resident operands (K
+    and V forward and for dq, ``[1, S, D]`` each; Q and dO for dkv, the
+    rows a grid step holds; double-buffered) hold ``resident_bytes`` at
+    once.  Under the compiler's
     default limit nothing is passed and the kernel compiles as it always
     has (S 8,192 at D 128: 8 MiB); past it (S 8,192 at D 256: 16 MiB
     before any block) the limit is raised to what the kernel holds plus
@@ -427,7 +435,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     from jax.experimental import pallas as pl
 
     # Grid (B*KV, k_blocks, rep): the innermost r axis streams one GQA
-    # query head at a time (VMEM holds ONE [1,1,S_pad,D] q/g block, not
+    # query head at a time (VMEM holds ONE [1,1,rows,D] q/g block, not
     # the whole group), revisiting the same compact [1, block_k, D]
     # dk/dv output block — r==0 initializes it, r>0 accumulates (fp32
     # output; cast to the param dtype happens outside).
@@ -459,6 +467,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     num_q_blocks = pl.cdiv(padded_len, block_q)
     # Q blocks whose last row precedes k_start are fully causally masked.
     start_qi = (k_start // block_q) if causal else 0
+    # q_ref and g_ref hold the head's whole sequence, or (a window layer:
+    # _dkv_query_rows) the rows this key block can reach, from query block
+    # first_qi on: a block is read at its place behind that one.
+    rows = q_ref.shape[2]
+    first_qi = 0
+    if rows < padded_len:
+        first_qi = jnp.minimum(start_qi, (padded_len - rows) // block_q)
     if window > 0:
         # Q rows beyond k_start + block_k - 1 + window - 1 see none of
         # this K block.
@@ -476,8 +491,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         qpos = q_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_k, block_q), 1
         )
-        qb = q_ref[0, 0, pl.ds(q_start, block_q), :]
-        gb = g_ref[0, 0, pl.ds(q_start, block_q), :]
+        held = pl.ds((qi - first_qi) * block_q, block_q)
+        qb = q_ref[0, 0, held, :]
+        gb = g_ref[0, 0, held, :]
         lse_row = lse_ref[0, 0, :, pl.ds(q_start, block_q)]  # [1, block_q]
         delta_row = delta_ref[0, 0, :, pl.ds(q_start, block_q)]
         sT = jax.lax.dot_general(
@@ -536,6 +552,21 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     def _accum():
         dk_ref[0] = dk_ref[0] + dk_acc
         dv_ref[0] = dv_ref[0] + dv_acc
+
+
+def _dkv_query_rows(window: int, block_q: int, block_k: int,
+                    S_pad: int) -> int:
+    """Rows of Q and dO that ``flash_bwd_dkv`` holds a grid step.  The query
+    blocks a key block's loop visits under a causal window lie between the
+    block's first key and the last query that sees its last key, ``window -
+    1`` behind it: ``block_k + window - 1`` rows from the key block's first
+    query block on, in whole query blocks (1,536 at a window of 1,024 and
+    blocks of 256 and 512, 2,560 at 2,048, 4,608 at 4,096).  No window, or
+    one that reaches that far: the head's whole padded sequence."""
+    if window <= 0:
+        return S_pad
+    reach = max(block_q, block_k) + window - 2
+    return min((reach // block_q + 1) * block_q, S_pad)
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
@@ -604,6 +635,25 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
     lse4 = lse2.reshape(B * KV, rep, 1, S_pad)
     delta4 = delta2.reshape(B * KV, rep, 1, S_pad)
     dkv_in = [q4, k3, v3, g4, lse4, delta4]
+    # Q and dO change with r, the innermost axis: the pipeline fetches their
+    # block anew EVERY grid step.  A window layer's step is handed the rows
+    # its key block can reach and no more (the header says why), from the
+    # key block's first query block on, or from where the array's last
+    # ``rows`` rows start if that is earlier: no read leaves the array.
+    rows = _dkv_query_rows(window if causal else 0, block_q, block_k, S_pad)
+
+    def q_spec(width):
+        if rows == S_pad:
+            return pl.BlockSpec((1, 1, S_pad, width),
+                                lambda b, i, r: (b, r, 0, 0))
+        # an element offset on one axis makes every axis of the block one;
+        # Mosaic takes the row's only with its multiple stated
+        return pl.BlockSpec(
+            tuple(pl.Element(n) for n in (1, 1, rows, width)),
+            lambda b, i, r: (b, r, pl.multiple_of(jnp.minimum(
+                jax.lax.div(i * block_k, block_q) * block_q,
+                S_pad - rows), block_q), 0))
+
     dkv_seg_spec = []
     if segmented:
         dkv_in.append(common[-1])
@@ -618,10 +668,10 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         ),
         grid=(B * KV, pl.cdiv(S_pad, block_k), rep),
         in_specs=[
-            pl.BlockSpec((1, 1, S_pad, D), lambda b, i, r: (b, r, 0, 0)),
+            q_spec(D),
             pl.BlockSpec((1, block_k, D), lambda b, i, r: (b, i, 0)),
             pl.BlockSpec((1, block_k, Dv), lambda b, i, r: (b, i, 0)),
-            pl.BlockSpec((1, 1, S_pad, Dv), lambda b, i, r: (b, r, 0, 0)),
+            q_spec(Dv),
             pl.BlockSpec((1, 1, 1, S_pad), lambda b, i, r: (b, r, 0, 0)),
             pl.BlockSpec((1, 1, 1, S_pad), lambda b, i, r: (b, r, 0, 0)),
         ] + dkv_seg_spec,
@@ -635,7 +685,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
-        **_vmem_params(2 * S_pad * (D + Dv) * q.dtype.itemsize),
+        **_vmem_params(2 * rows * (D + Dv) * q.dtype.itemsize),
     )(*dkv_in)
 
     return (

@@ -413,10 +413,9 @@ def _mesh(topo):
     return build_mesh(MeshSpec(fsdp=2, tp=2), topo.devices)
 
 
-def _sharded_flash(grad):
+def _sharded_flash(grad, shape=(8, 16, 4, 2048, 64), **kw):
     # GQA with KV % tp == 0: each tp shard keeps whole query groups
-    fn, shapes, n = _flash(
-        {"shape": (8, 16, 4, 2048, 64), "kw": {}}, grad)
+    fn, shapes, n = _flash({"shape": shape, "kw": kw}, grad)
     spec = P(("dp", "fsdp"), "tp", None, None)
     return fn, shapes, [spec] * 3, n
 
@@ -438,6 +437,10 @@ def _sharded_gated_norm():
 SHARDED_CASES = {
     "flash_gqa-fwd": lambda: _sharded_flash(False),
     "flash_gqa-bwd": lambda: _sharded_flash(True),
+    # the four-chip window cell's call: a shard's dkv step holds 4,608 of
+    # its 8,192 rows of Q and dO at an element offset
+    "flash_window_4096_of_8192-bwd": lambda: _sharded_flash(
+        True, (2, 32, 8, 8192, 128), window=4096),
     "rmsnorm-fwd": _sharded_rmsnorm,
     "gated_norm-grad": _sharded_gated_norm,
 }

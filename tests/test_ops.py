@@ -691,6 +691,40 @@ DKV_CASES = {
     "ragged_300_window_100": (2, 1, 300, 128, 128, 100, False, 64, 128),
     "segments_group4": (4, 1, 256, 128, 128, 0, True, 64, 128),
     "segments_ragged_window": (2, 2, 300, 64, 64, 100, True, 64, 128),
+    # a window layer's dkv call holds a SPAN of Q and dO (the rows a key
+    # block can reach, ``_dkv_query_rows``) shorter than the padded
+    # sequence: 256 of 512 rows up to a window of 129, the window's edge
+    # on the key block's boundary at 128, and 320 rows from 130 on; the
+    # last key blocks read a span whose start is held at S_pad - rows
+    "window127_of_512": (2, 1, 512, 64, 64, 127, False, 64, 128),
+    "window128_of_512_on_the_key_block": (2, 1, 512, 64, 64, 128, False,
+                                          64, 128),
+    "window129_of_512_fills_its_span": (2, 1, 512, 64, 64, 129, False,
+                                        64, 128),
+    "window130_of_512_one_block_more": (2, 1, 512, 64, 64, 130, False,
+                                        64, 128),
+    "window200_of_1024_group8": (8, 1, 1024, 128, 128, 200, False, 64, 128),
+    "window64_of_512_three_clamped_blocks": (4, 2, 512, 128, 128, 64, False,
+                                             64, 128),
+    "ragged_300_window_65_group4": (4, 1, 300, 64, 64, 65, False, 64, 128),
+    "segments_window128_group4": (4, 1, 512, 128, 128, 128, True, 64, 128),
+    "window100_query_block_wider": (2, 1, 512, 64, 64, 100, False, 128, 64),
+    "window100_head192_value128": (2, 2, 512, 192, 128, 100, False, 64, 128),
+}
+
+#: name -> ((B, H, KV, S, D, Dv, window), rows of Q and dO a grid step of
+#: ``flash_bwd_dkv`` holds at the backward's default blocks): the window
+#: cells' flash calls, and the calls that keep the whole sequence
+DKV_FETCHED = {
+    "mellum_window": ((1, 32, 4, 16384, 128, 128, 1024), 1536),
+    "trinity_window": ((1, 32, 4, 16384, 128, 128, 2048), 2560),
+    "mistral_window": ((2, 32, 8, 8192, 128, 128, 4096), 4608),
+    "mellum_full": ((1, 32, 4, 16384, 128, 128, 0), 16384),
+    "kimi_latent": ((1, 32, 32, 16384, 192, 128, 0), 16384),
+    "window_past_the_sequence": ((1, 32, 4, 8192, 128, 128, 8192), 8192),
+    "window_that_reaches_every_row": ((1, 8, 2, 2048, 128, 128, 1537),
+                                      2048),
+    "ragged_3000_window_1024": ((1, 8, 2, 3000, 128, 128, 1024), 1536),
 }
 
 
@@ -776,6 +810,85 @@ class TestFlashDkvKeyMajor:
             assert e.params["precision"] is None
         assert not [e for e in _eqns(body)
                     if e.primitive.name == "transpose"]
+
+
+    @pytest.mark.parametrize("case", list(DKV_FETCHED))
+    def test_rows_of_q_and_do_a_grid_step_holds(self, case):
+        """A window layer's call is handed, a grid step, the rows of Q and dO
+        its key block can reach, from the key block's first row on and never
+        past the array's end; every other call the head's whole padded
+        sequence under the block specs it always had (the group's ``r`` axis
+        is innermost, so either block is fetched anew every step)."""
+        import importlib
+
+        from jax.experimental import pallas as pl
+
+        from tools.flash_bench import dkv_call
+
+        # (the package exports the function under the module's name)
+        fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
+        shape, rows = DKV_FETCHED[case]
+        B, H, KV, S, D, Dv, _ = shape
+        call = dkv_call(fa, shape)
+        block_q, block_k, S_pad = fa._block_sizes(
+            S, fa.DEFAULT_BWD_BLOCK_Q, fa.DEFAULT_BWD_BLOCK_K)
+        n_k = S_pad // block_k
+        maps = call.params["grid_mapping"].block_mappings
+        for bm, width in ((maps[0], D), (maps[3], Dv)):
+            assert bm.array_aval.shape == (B * KV, H // KV, S_pad, width)
+            assert bm.block_aval.shape == (1, 1, rows, width)
+            spanned = rows < S_pad
+            kind = pl.Element if spanned else pl.Blocked
+            assert all(isinstance(d, kind) for d in bm.block_shape)
+            index = bm.index_map_jaxpr
+            for i in (0, 1, n_k // 2, n_k - 2, n_k - 1):
+                b, r, row, col = (int(x) for x in jax.core.eval_jaxpr(
+                    index.jaxpr, index.consts, B * KV - 1, i, 3))
+                assert (b, r, col) == (B * KV - 1, 3, 0)
+                assert row == (min(i * block_k, S_pad - rows)
+                               if spanned else 0)
+                assert row % block_q == 0 and row + rows <= S_pad
+        # lse and delta: a head's whole row, as ever
+        for bm in maps[4:6]:
+            assert bm.block_aval.shape == (1, 1, 1, S_pad)
+        # the span fits the compiler's own VMEM limit; a whole sequence of
+        # 16,384 asks for more, as it did
+        raised = "vmem_limit_bytes" in str(call.params["compiler_params"])
+        assert raised == (2 * rows * (D + Dv) * 2 + 4 * 2 ** 20
+                          > 16 * 2 ** 20)
+
+    @pytest.mark.parametrize("query", [256, 319, 511],
+                             ids=["last_block_of_the_span", "inside_a_span",
+                                  "clamped_span"])
+    def test_the_windows_edge_in_dk_and_dv(self, query):
+        """One query row's cotangent reaches exactly the keys it sees: the
+        key ``window - 1`` behind it (in another key block, whose span of
+        query rows ENDS with this query's block) gets a ``dk`` and a ``dv``,
+        the key ``window`` behind it and every key after the query none."""
+        window, S = 130, 512
+        keys = jax.random.split(jax.random.PRNGKey(66), 4)
+        q, k, v, cot = (jax.random.normal(key, (1, 2, S, 64), jnp.float32)
+                        for key in keys)
+        k, v = k[:, :1], v[:, :1]
+        cot = cot * (jnp.arange(S) == query)[None, None, :, None]
+        _, pull = jax.vjp(
+            lambda k, v: flash_attention(
+                q, k, v, causal=True, backend="pallas", interpret=True,
+                block_q=128, block_k=128, bwd_block_q=64, bwd_block_k=128,
+                window=window), k, v)
+        _, want = jax.vjp(
+            lambda k, v: reference_attention(q, k, v, True, None, window),
+            k, v)
+        first = query - window + 1
+        for got, ref, name in zip(pull(cot), want(cot), ("dk", "dv")):
+            got = np.asarray(got[0, 0])
+            np.testing.assert_allclose(got, np.asarray(ref[0, 0]),
+                                       atol=5e-4, err_msg=name)
+            seen = np.abs(got).max(axis=-1) > 0
+            assert seen[first] and seen[query], name
+            assert not seen[:first].any() and not seen[query + 1:].any(), \
+                name
+            assert seen[first:query + 1].all(), name
 
 
 class TestSlidingWindowLlama:

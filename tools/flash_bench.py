@@ -7,12 +7,18 @@ bfloat16, each timed in a program that holds only it.
 
 One ``FLASH_BENCH`` line a shape: median milliseconds of ``--iters`` calls of
 each kernel, milliseconds a product (dq runs three a block pair, dkv four)
-and their ratio; and, at the same head sizes, group and window cut to one
-key head and 2,048 positions, the distance of ``dk`` and ``dv`` from the
-float32 autodiff of ``reference_attention`` (the norm of the difference over
-the norm).  ``--against DIR`` times another checkout's kernels (``DIR`` holds
-a ``dlrover_tpu/ops/flash_attention.py``, a parent unpacked by ``git
-archive``) on the same operands in the same process and adds its numbers and
+and their ratio; the rows and bytes of Q and dO that a ``flash_bwd_dkv`` grid
+step is handed (``dkv_q_do_a_step``: a window layer's span or the head's
+whole sequence, read from the call's block shapes); under ``loops`` each of
+the three kernels, ``flash_fwd`` too, against the block pairs its loops visit
+(microseconds a pair, the share of their scores that no mask drops, and the
+MXU's least time for them over the time: a window layer against a full one
+tells what a grid step costs beside its blocks); and, at the same head sizes,
+group and window cut to one key head and 2,048 positions, the distance of
+``dk`` and ``dv`` from the float32 autodiff of ``reference_attention`` (the
+norm of the difference over the norm).  ``--against DIR`` times another
+checkout's kernels (``DIR`` holds a ``dlrover_tpu/ops/flash_attention.py``,
+a parent unpacked by ``git archive``) on the same operands in the same process and adds its numbers and
 the distance between the two trees' ``dk`` and ``dv``: 0.0 where both feed
 the MXU the same bits.  ``--passes`` times one ``[512, 256] x [256, 128]``
 product a turn of a kernel's loop with float32 operands as the flash kernels
@@ -21,7 +27,8 @@ bfloat16, and at ``highest``: a float32 product that costs what the bfloat16
 one costs is one bfloat16 pass.  ``--calls`` reads a ``benchmark/run.py
 --dump-trace`` file instead and prints each flash kernel's per-call device
 milliseconds in the order the calls ran (a window layer's and a full layer's
-calls differ).  The table is also written to
+calls differ), and a ``FLASH_FETCH`` line: ``dkv_q_do_a_step`` of every shape
+(traced from shapes: no chip).  The table is also written to
 ``chiprun_out/flash_bench.json``; ``--toy`` rehearses it off the chip (short
 sequences, the kernels in interpret mode).
 """
@@ -58,7 +65,7 @@ SHAPES = {
     "glm": (3, 20, 20, 8192, 256, 256, 0),
     "qwen3_next": (2, 16, 2, 8192, 256, 256, 0),
 }
-TOY = {"toy_window": (1, 4, 2, 256, 128, 128, 64),
+TOY = {"toy_window": (1, 4, 2, 1024, 128, 128, 128),
        "toy_latent": (1, 2, 2, 256, 192, 128, 0)}
 
 
@@ -97,6 +104,90 @@ def _kernels(mod, window, interpret):
     return (jax.jit(lambda *a: bwd(*a)[0]), jax.jit(lambda *a: bwd(*a)[1:]))
 
 
+def dkv_call(mod, shape):
+    """The ``flash_bwd_dkv`` ``pallas_call`` equation of ``mod``'s backward
+    at ``shape`` in bfloat16 and the backward's default blocks, traced from
+    shapes alone (nothing runs: needs no chip)."""
+    import jax
+    import jax.numpy as jnp
+
+    B, H, KV, S, D, Dv, window = shape
+    dims = ((B, H, S, D), (B, KV, S, D), (B, KV, S, Dv), (B, H, S, Dv),
+            (B, H, S), (B, H, S, Dv))
+    args = [jax.ShapeDtypeStruct(d, jnp.float32 if len(d) == 3
+                                 else jnp.bfloat16) for d in dims]
+    jaxpr = jax.make_jaxpr(lambda *a: mod._flash_bwd_pallas(
+        *a, True, mod.DEFAULT_BWD_BLOCK_Q, mod.DEFAULT_BWD_BLOCK_K, True,
+        window=window))(*args)
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"
+               and e.params["name"] == "flash_bwd_dkv"]
+    return call
+
+
+def dkv_fetch(mod, shape):
+    """What ``mod``'s ``flash_bwd_dkv`` is handed of Q and dO a grid step, by
+    the block shapes of its call: rows of the padded sequence, and the bytes
+    of the two blocks."""
+    call = dkv_call(mod, shape)
+    blocks = [bm.block_aval.shape
+              for bm in call.params["grid_mapping"].block_mappings]
+    q_block, g_block = blocks[0], blocks[3]
+    return {"rows": q_block[2], "of": call.invars[0].aval.shape[2],
+            "bytes": 2 * int(np.prod(q_block) + np.prod(g_block))}
+
+
+#: kernel -> products a block pair
+PRODUCTS = {"fwd": 2, "dq": 3, "dkv": 4}
+
+
+def visited_pairs(kernel, S, window, block_q, block_k):
+    """``(pairs, unmasked)``: the ``[block_q, block_k]`` block pairs one
+    head's loops visit under the causal mask and the window, as the
+    kernels' own bounds give them, and the share of their scores that is not
+    masked."""
+    pairs = 0
+    if kernel == "dkv":  # a key block's query blocks
+        for k_start in range(0, S, block_k):
+            last = S // block_q
+            if window > 0:
+                last = min(last, (k_start + block_k + window - 2)
+                           // block_q + 1)
+            pairs += last - k_start // block_q
+    else:  # a query block's key blocks
+        for q_start in range(0, S, block_q):
+            first = max(0, (q_start - window + 1) // block_k) if window else 0
+            pairs += min(S // block_k,
+                         (q_start + block_q - 1) // block_k + 1) - first
+    seen = (S * (S + 1) // 2 if window <= 0 else
+            sum(min(i + 1, window) for i in range(S)))
+    return pairs, seen / (pairs * block_q * block_k)
+
+
+def loop_work(mod, shape, times):
+    """Each kernel's time against the MXU's least time for the block pairs
+    its loops visit (masked halves included: the loop computes them):
+    ``{kernel: {pairs, unmasked, us_a_pair, mxu_share}}``."""
+    from benchmark.harness.peaks import PEAKS
+
+    B, H, KV, S, D, Dv, window = shape
+    peak = PEAKS["TPU v5 lite"]["bf16_flops"]  # the shapes are a v5e's
+    out = {}
+    for kernel, ms in times.items():
+        bq, bk = ((mod.DEFAULT_BLOCK_Q, mod.DEFAULT_BLOCK_K)
+                  if kernel == "fwd" else
+                  (mod.DEFAULT_BWD_BLOCK_Q, mod.DEFAULT_BWD_BLOCK_K))
+        bq, bk, S_pad = mod._block_sizes(S, bq, bk)
+        pairs, unmasked = visited_pairs(kernel, S_pad, window, bq, bk)
+        pairs *= B * H
+        # products over D and over Dv alternate: half of each
+        flops = pairs * PRODUCTS[kernel] * bq * bk * (D + Dv)
+        out[kernel] = {"pairs": pairs, "unmasked": round(unmasked, 4),
+                       "us_a_pair": ms * 1e3 / pairs,
+                       "mxu_share": flops / peak / (ms / 1e3)}
+    return out
+
+
 def bench_shape(name, shape, trees, iters, interpret, seed=0):
     import jax
     import jax.numpy as jnp
@@ -104,9 +195,10 @@ def bench_shape(name, shape, trees, iters, interpret, seed=0):
     here = trees["change"]
     window = shape[-1]
     q, k, v, g = _operands(shape, seed)
-    out, lse = jax.jit(lambda q, k, v: here._flash_fwd(
+    fwd = jax.jit(lambda q, k, v: here._flash_fwd(
         q, k, v, True, here.DEFAULT_BLOCK_Q, here.DEFAULT_BLOCK_K,
-        interpret, window=window))(q, k, v)
+        interpret, window=window))
+    out, lse = fwd(q, k, v)
     row = {"shape": name, "dims": list(shape)}
     got = {}
     for tree, mod in trees.items():
@@ -115,7 +207,11 @@ def bench_shape(name, shape, trees, iters, interpret, seed=0):
         dkv_ms = _median_ms(dkv_fn, (q, k, v, out, lse, g), iters)
         got[tree] = dkv_fn(q, k, v, out, lse, g)
         row[tree] = {"dq_ms": dq_ms, "dkv_ms": dkv_ms,
-                     "dkv_over_dq_a_product": (dkv_ms / 4) / (dq_ms / 3)}
+                     "dkv_over_dq_a_product": (dkv_ms / 4) / (dq_ms / 3),
+                     "dkv_q_do_a_step": dkv_fetch(mod, shape)}
+    row["loops"] = loop_work(here, shape, {
+        "fwd": _median_ms(fwd, (q, k, v), iters),
+        "dq": row["change"]["dq_ms"], "dkv": row["change"]["dkv_ms"]})
     if len(got) == 2:
         row["dk_dv_between_trees"] = [
             _distance(a, b) for a, b in zip(got["change"], got["against"])]
@@ -211,6 +307,10 @@ def main() -> int:
 
     if args.calls:
         print("FLASH_CALLS", json.dumps(calls_of(args.calls)), flush=True)
+        here = _load(REPO)
+        print("FLASH_FETCH", json.dumps(
+            {name: dkv_fetch(here, shape) for name, shape in SHAPES.items()}),
+            flush=True)
         return 0
 
     import jax
