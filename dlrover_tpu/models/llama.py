@@ -1471,12 +1471,16 @@ def _kda_mixer(u, kda, cfg: LlamaConfig) -> tuple:
     stream ``u [B, S, C]`` -> ``(out [B, S, C], stats)``.  ``q``, ``k`` and
     ``v`` each from a projection of its own (``wq``, ``wk``, ``wv``: ``H D``
     columns) through a causal depthwise convolution without bias and ``silu``
-    (``ops.conv_silu``, three calls); q and k L2-normalised over a head's
-    ``D`` dims in float32 (``x / sqrt(sum x^2 + 1e-6)``), q scaled by
-    ``D^-1/2``; ``beta = sigmoid(u w_beta)`` a head; the decay a key CHANNEL,
-    ``g = -exp(A_log[h]) * softplus((u f_a) f_b + dt_bias)`` in float32
-    ``[B, S, H, D]``; the delta rule whose state's rows decay each on its
-    own (``ops.gated_delta.gated_delta_chunked`` at :data:`KDA_CHUNK`); ``y =
+    (``ops.conv_silu``, three calls); ``beta = sigmoid(u w_beta)`` a head;
+    the decay a key CHANNEL, ``g = -exp(A_log[h]) * softplus((u f_a) f_b +
+    dt_bias)`` in float32 ``[B, S, H, D]``; the delta rule whose state's
+    rows decay each on its own (``ops.gated_delta.gated_delta_chunked`` at
+    :data:`KDA_CHUNK`), which is handed the convolutions' q and k and ``g``
+    as they are: the L2 norms over a head's ``D`` dims in float32 (``x /
+    sqrt(sum x^2 + 1e-6)``, q scaled by ``D^-1/2``: its ``unit_scales``) and
+    the cumulative sum of ``g`` are each chunk's own, on the tile the rule's
+    kernels hold anyway, and of ``kda_scan`` XLA keeps ``g``, ``beta`` and
+    the statistics; ``y =
     norm * rms(o) * sigmoid((u g_a) g_b + g_bias)`` per head in float32 —
     the norm BEFORE the gate, the gate a sigmoid (``ops.gated_norm``, a head
     a group); ``out = y out_proj``.  Scopes ``kda_in``, ``kda_conv``,
@@ -1506,18 +1510,14 @@ def _kda_mixer(u, kda, cfg: LlamaConfig) -> tuple:
             (q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
     with jax.named_scope("kda_scan"):
         beta = jax.nn.sigmoid(b.astype(f32))
-        g = -jnp.exp(kda["A_log"])[:, None] * jax.nn.softplus(
-            f.astype(f32).reshape(B, S, H, D) + kda["dt_bias"].reshape(H, D))
-
-        def unit(a, scale):
-            a = a.reshape(B, S, H, D).astype(f32)
-            return (a * (jax.lax.rsqrt(
-                jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
-                * scale)).astype(dt)
-
+        # formed FLAT, as the projection put ``f`` out and as the rule's
+        # kernels read it: by heads it is another tiling, 268 MB moved
+        g = -jnp.repeat(jnp.exp(kda["A_log"]), D) * jax.nn.softplus(
+            f.astype(f32) + kda["dt_bias"])
+        heads = lambda a: a.reshape(B, S, H, D)  # noqa: E731
         o, state, decay_min = gated_delta_chunked(
-            unit(q, D ** -0.5), unit(k, 1.0), v.reshape(B, S, H, D), g, beta,
-            KDA_CHUNK)
+            heads(q), heads(k), heads(v), heads(g), beta, KDA_CHUNK,
+            unit_scales=(D ** -0.5, 1.0))
         stats = jax.lax.stop_gradient({
             "kda_state_rms": jnp.sqrt(jnp.mean(jnp.square(state))),
             "kda_decay_min": decay_min})

@@ -12,7 +12,8 @@ The recurrence, per head with state ``S in R^{Dk x Dv}`` and ``S_0 = 0``::
     o_t  = q_t^T S
 
 ``q``, ``k [B, S, H, Dk]`` (the caller's L2 norms and the scale of ``q``
-already applied), ``v [B, S, H, Dv]``, ``g [B, S, H]`` float32 and never
+already applied; the per-channel rule can take them raw, ``unit_scales``
+below), ``v [B, S, H, Dv]``, ``g [B, S, H]`` float32 and never
 positive (the log of the decay), ``beta [B, S, H]`` in (0, 1).
 
 :func:`gated_delta_chunked` splits a sequence into chunks of ``Q = chunk``
@@ -84,7 +85,10 @@ module's, with ``g`` of ``k``'s rank), a chunked ``jax.numpy`` one and a
 kernel pair of its own (``kda_chunk_fwd``, ``kda_chunk_bwd``; outputs named
 by :data:`CHANNEL_SAVED_NAMES`) — are the section "a decay per key CHANNEL"
 below, which says how it keeps every exponent that is evaluated at or under
-0.  :func:`gated_delta_chunked` takes either.
+0.  There the chunk's prologue is the chunk's own: the kernels read ``g``
+itself and, with ``unit_scales``, q and k before their L2 norms, and form
+``Gamma`` and the unit rows on the tile they hold in VMEM.
+:func:`gated_delta_chunked` takes either.
 """
 
 from __future__ import annotations
@@ -710,11 +714,22 @@ def _chunked_pallas(q, k, v, g, beta, qn: int, hb: int, interpret: bool):
 # (``QK``, and every product against the state) operands in ``v``'s dtype.
 #
 # ONE function computes a chunk (:func:`_channel_chunk`), from whole-tile
-# operations alone.  The ``jax.numpy`` form maps it over heads and scans it
-# over chunks; the forward kernel (``kda_chunk_fwd``) calls it on a head's
-# chunk in VMEM; the backward kernel (``kda_chunk_bwd``) takes ``jax.vjp`` of
-# it THERE, on the same inputs and the saved entering state, so that the
-# chunk's backward is derived and not written a second time.  Its matmuls are
+# operations alone, its prologue included: ``Gamma`` is the running sum of the
+# chunk's ``g`` down its rows, ``log2 Q`` turns of the rows each added where it
+# did not wrap (:func:`_running_sum`; the halving's moves, no product; the
+# cotangent the same sum from the chunk's end), and with ``unit_scales`` q and
+# k are L2-normalised over their lanes there (:func:`_unit_rows`), in float32,
+# rounded where an operand in HBM would be.  So nothing along ``[B, S, H Dk]``
+# runs outside but what forms ``g`` and ``beta`` and the least decay, a sum of
+# ``g`` over each chunk.  A tree of sums rounds otherwise than a run: where a
+# ``g`` is under ``Gamma``'s last bit an exponent may come out positive by that
+# bit, which overflows nothing.
+#
+# The ``jax.numpy`` form maps the function over heads and scans it over
+# chunks; the forward kernel (``kda_chunk_fwd``) calls it on a head's chunk in
+# VMEM; the backward kernel (``kda_chunk_bwd``) takes ``jax.vjp`` of it THERE,
+# on the same inputs and the saved entering state, so that the chunk's
+# backward is derived and not written a second time.  Its matmuls are
 # :func:`_product`, whose own rule rounds a cotangent as the forward rounds
 # an operand.  The scalar rule's kernels are as they were.
 
@@ -823,13 +838,79 @@ def _halving_references(gam, roll):
         held, b = _half_rows(roll, -b, held), 2 * b
 
 
-def _channel_chunk(q, k, v, gam, bc, state, dt, inverse, roll=_rolled_rows):
+def _summed_rows(roll, x, back):
+    """Row ``i`` of float32 ``x [n, d]`` plus every row before it (``back``:
+    after it), by doubling: ``log2 n`` turns of the rows, each added where
+    it did not wrap.  No product."""
+    n = x.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    s = 1
+    while s < n:
+        from_inside = (row < n - s) if back else (row >= s)
+        x = x + jnp.where(from_inside, roll(x, -s if back else s), 0.0)
+        s *= 2
+    return x
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _running_sum(roll, g):
+    """``cumsum`` along the rows of ``g [n, d]`` (:func:`_summed_rows`): a
+    chunk's ``Gamma`` from its ``g``, a tree of sums and not a run (the
+    section's comment says what that rounds).  The cotangent is the same sum
+    run from the chunk's end."""
+    return _summed_rows(roll, g, False)
+
+
+_running_sum.defvjp(
+    lambda roll, g: (_running_sum(roll, g), None),
+    lambda roll, _, cot: (_summed_rows(roll, cot, True),))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _rounded(x, dt, in_kernel):
+    """Float32 ``x`` rounded to ``dt`` and back; the cotangent passes whole
+    (it is rounded once, where the kernel writes it).  A kernel converts
+    there and back, which Mosaic runs as written.  XLA may take such a pair
+    for nothing (``xla_allow_excess_precision``, a TPU's default) in SOME of
+    the value's uses: the chunk's exponents then cancel between a rounded k
+    and an unrounded one, and ``dg`` of a fast channel came out 90 times
+    its size (builder, PR 67).  ``reduce_precision`` is the same rounding
+    and not XLA's to drop; Mosaic does not know it."""
+    if in_kernel:
+        return x.astype(dt).astype(F32)
+    info = jnp.finfo(dt)
+    return jax.lax.reduce_precision(x, info.nexp, info.nmant)
+
+
+_rounded.defvjp(lambda x, dt, in_kernel: (_rounded(x, dt, in_kernel), None),
+                lambda dt, in_kernel, _, cot: (cot,))
+
+
+def _unit_rows(x, scale, dt, in_kernel):
+    """The rows of float32 ``x [n, d]`` L2-normalised (``x / sqrt(sum x^2 +
+    1e-6)``) and scaled, rounded to ``dt`` as an operand in HBM would be
+    (:func:`_rounded`): a row of zeros (a padded position) stays one."""
+    return _rounded(x * (jax.lax.rsqrt(
+        jnp.sum(jnp.square(x), axis=1, keepdims=True) + 1e-6) * scale), dt,
+        in_kernel)
+
+
+def _channel_chunk(q, k, v, g, bc, state, dt, inverse, roll=_rolled_rows,
+                   unit_scales=None):
     """One head's chunk of ``n`` positions under a per-channel decay:
-    ``q``, ``k``, ``gam [n, Dk]``, ``v [n, Dv]``, ``bc [n, 1]`` and the
+    ``q``, ``k``, ``g [n, Dk]``, ``v [n, Dv]``, ``bc [n, 1]`` and the
     state that enters, transposed, ``[Dv, Dk]``, all float32 -> ``(o [n,
-    Dv], the state that leaves [Dv, Dk])``.  ``gam`` is the cumulative sum
-    of ``g`` inside the chunk; ``inverse`` computes ``(I + A)^-1`` and
-    ``roll`` turns a tile's rows (:func:`_half_rows`)."""
+    Dv], the state that leaves [Dv, Dk])``.  The chunk's prologue is its
+    own: ``Gamma`` is the running sum of ``g`` down the chunk's rows
+    (:func:`_running_sum`) and, with ``unit_scales = (q's, k's)``, q and k
+    are the RAW rows, normalised and scaled here (:func:`_unit_rows`);
+    without, the caller's.  ``inverse`` computes ``(I + A)^-1`` and ``roll``
+    turns a tile's rows (:func:`_half_rows`); the kernels' ``roll`` also
+    says that a kernel is where this runs (:func:`_rounded`)."""
+    if unit_scales is not None:
+        q, k = (_unit_rows(x, scale, dt, roll is _rotated_sublanes)
+                for x, scale in zip((q, k), unit_scales))
+    gam = _running_sum(roll, g)
     n, dk = k.shape
     iota = jax.lax.broadcasted_iota
     row, col = iota(jnp.int32, (n, n), 0), iota(jnp.int32, (n, n), 1)
@@ -863,7 +944,16 @@ def _channel_chunk(q, k, v, gam, bc, state, dt, inverse, roll=_rolled_rows):
                  + _product("tn", new, k_to_end, dt))
 
 
-def _chunked_channel_xla(q, k, v, g, beta, qn: int):
+def _least_channel_decay(g, qn: int):
+    """``min exp(sum of g over a chunk)`` of ``g [B, S, H, Dk]`` over
+    chunks, heads and channels: the one thing of the per-channel rule that
+    reads ``g`` outside :func:`_channel_chunk`."""
+    bsz, s = g.shape[:2]
+    return jnp.min(jnp.exp(jnp.sum(
+        g.astype(F32).reshape(bsz, s // qn, qn, -1), axis=2)))
+
+
+def _chunked_channel_xla(q, k, v, g, beta, qn: int, unit_scales=None):
     """:func:`_chunked_xla` under a per-channel decay: :func:`_channel_chunk`
     mapped over batch rows and heads, a ``lax.scan`` over the chunks."""
     bsz, s, h, dk = k.shape
@@ -871,9 +961,9 @@ def _chunked_channel_xla(q, k, v, g, beta, qn: int):
     # [c, B, H, Q, ...]: the chunks first, a head's rows together
     rows = lambda x: jnp.moveaxis(  # noqa: E731
         x.astype(F32).reshape((bsz, c, qn) + x.shape[2:]), (1, 3), (0, 2))
-    gam = jnp.cumsum(rows(g), axis=3)
     chunk = functools.partial(_channel_chunk, dt=dt,
-                              inverse=unit_lower_inverse)
+                              inverse=unit_lower_inverse,
+                              unit_scales=unit_scales)
 
     @jax.checkpoint
     def carry(state, inputs):
@@ -882,14 +972,13 @@ def _chunked_channel_xla(q, k, v, g, beta, qn: int):
 
     final, out = jax.lax.scan(
         carry, jnp.zeros((bsz, h, dv, dk), F32),
-        (rows(q), rows(k), rows(v), gam, rows(beta)[..., None]))
+        (rows(q), rows(k), rows(v), rows(g), rows(beta)[..., None]))
     out = jnp.moveaxis(out, (0, 3), (1, 2)).reshape(bsz, s, h, dv)
-    return (out, jnp.swapaxes(final, -1, -2),
-            jnp.min(jnp.exp(gam[:, :, :, -1])))
+    return (out, jnp.swapaxes(final, -1, -2), _least_channel_decay(g, qn))
 
 
 def _channel_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, state_ref,
-                        *entering_ref):
+                        *entering_ref, unit_scales):
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
@@ -902,17 +991,19 @@ def _channel_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, state_ref,
     o_ref[...], state_ref[...] = _channel_chunk(
         q_ref[...].astype(F32), k_ref[...].astype(F32),
         v_ref[...].astype(F32), g_ref[...], _columns(b_ref[...])[:, :1],
-        state, dt, _whole_tile_inverse, _rotated_sublanes)
+        state, dt, _whole_tile_inverse, _rotated_sublanes, unit_scales)
 
 
 def _channel_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entering_ref,
                         do_ref, dstate_ref, dq_ref, dk_ref, dv_ref, dg_ref,
-                        db_ref, ds_ref):
+                        db_ref, ds_ref, *, unit_scales):
     """One chunk of the backward pass: ``jax.vjp`` of :func:`_channel_chunk`
     on the chunk's own inputs and the state that entered it (as saved,
     rounded: every product takes it so, and the decay's own cotangent reads
     it as the scalar rule's backward does), pulled back from the cotangents
-    of ``o`` and of the state the chunk left (``ds_ref``, resident)."""
+    of ``o`` and of the state the chunk left (``ds_ref``, resident).  The
+    chunk's prologue is inside the ``vjp``: ``dg`` is the cotangent of ``g``
+    itself and ``dq``, ``dk`` those of the rows as they were read."""
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
@@ -922,7 +1013,7 @@ def _channel_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entering_ref,
     dt = v_ref.dtype
     _, pull = jax.vjp(
         functools.partial(_channel_chunk, dt=dt, inverse=_whole_tile_inverse,
-                          roll=_rotated_sublanes),
+                          roll=_rotated_sublanes, unit_scales=unit_scales),
         q_ref[...].astype(F32), k_ref[...].astype(F32),
         v_ref[...].astype(F32), g_ref[...], _columns(b_ref[...])[:, :1],
         entering_ref[...].astype(F32))
@@ -960,13 +1051,13 @@ _CHANNEL_INPUTS = ("q", "k", "v", "g", "b")
 
 
 def _channel_fwd(q, k, v, g, b, dims, interpret, keep_entering):
-    """``q``, ``k [B, S, H Dk]``, ``v [B, S, H Dv]``, ``g [B, S, H Dk]`` (the
-    cumulative sums) and ``b [B, c, H, 8, 128]`` float32 -> ``(o [B, S, H Dv]
-    float32, final state [B, H, Dv, Dk] float32, the entering states [B, c,
-    H, Dv, Dk] in ``v``'s dtype or None)``."""
+    """``q``, ``k [B, S, H Dk]``, ``v [B, S, H Dv]``, ``g [B, S, H Dk]`` and
+    ``b [B, c, H, 8, 128]`` float32 -> ``(o [B, S, H Dv] float32, final
+    state [B, H, Dv, Dk] float32, the entering states [B, c, H, Dv, Dk] in
+    ``v``'s dtype or None)``.  ``dims``: ``(H, Dk, Dv, unit_scales)``."""
     from jax.experimental import pallas as pl
 
-    h, dk, dv = dims
+    h, dk, dv, unit_scales = dims
     bsz, c = b.shape[:2]
     grid, specs = _channel_specs(bsz, c, h, dk, dv)
     out_specs = [specs["v"], specs["state"]]
@@ -976,7 +1067,8 @@ def _channel_fwd(q, k, v, g, b, dims, interpret, keep_entering):
         out_specs.append(specs["entering"])
         out_shape.append(jax.ShapeDtypeStruct((bsz, c, h, dv, dk), v.dtype))
     out = pl.pallas_call(
-        _channel_fwd_kernel, grid=grid,
+        functools.partial(_channel_fwd_kernel, unit_scales=unit_scales),
+        grid=grid,
         in_specs=[specs[name] for name in _CHANNEL_INPUTS],
         out_specs=out_specs, out_shape=out_shape, interpret=interpret,
         name="kda_chunk_fwd", **_call_params(_CHANNEL_VMEM),
@@ -988,12 +1080,13 @@ def _channel_bwd(q, k, v, g, b, entering, do, dstate, dims, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    h, dk, dv = dims
+    h, dk, dv, unit_scales = dims
     bsz, c = b.shape[:2]
     grid, specs = _channel_specs(bsz, c, h, dk, dv, backward=True)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
     return pl.pallas_call(
-        _channel_bwd_kernel, grid=grid,
+        functools.partial(_channel_bwd_kernel, unit_scales=unit_scales),
+        grid=grid,
         in_specs=[specs[name] for name in _CHANNEL_INPUTS + (
             "entering", "v", "state")],
         out_specs=[specs[name] for name in _CHANNEL_INPUTS],
@@ -1022,15 +1115,17 @@ def _channel_kernels_bwd(dims, interpret, res, cotangents):
 _channel_kernels.defvjp(_channel_kernels_fwd, _channel_kernels_bwd)
 
 
-def _chunked_channel_pallas(q, k, v, g, beta, interpret: bool):
+def _chunked_channel_pallas(q, k, v, g, beta, interpret: bool,
+                            unit_scales=None):
     """:func:`_chunked_channel_xla` by the kernel pair at
-    :data:`CHANNEL_CHUNK`, one call per shard of the mesh in scope; the
-    cumulative sums, ``beta``'s layout (a head's chunk a lane-dense row) and
-    the least decay are ``jax.numpy``, as :func:`_chunked_pallas`'s."""
+    :data:`CHANNEL_CHUNK`, one call per shard of the mesh in scope.  The
+    kernels read ``g`` and q and k as they are handed over: the cumulative
+    sums and, with ``unit_scales``, the L2 norms are the chunk's own
+    (:func:`_channel_chunk`).  What stays ``jax.numpy`` is ``beta``'s layout
+    (a head's chunk a lane-dense row) and the least decay."""
     bsz, s, h, dk = k.shape
     dv, qn = v.shape[-1], CHANNEL_CHUNK
     c = s // qn
-    gam = jnp.cumsum(g.astype(F32).reshape(bsz, c, qn, h, dk), axis=2)
     rows = jnp.pad(
         beta.astype(F32).reshape(bsz, c, qn, h).transpose(0, 1, 3, 2)[
             :, :, :, None], ((0, 0),) * 3 + ((0, _ROWS - 1), (0, 0)))
@@ -1038,14 +1133,16 @@ def _chunked_channel_pallas(q, k, v, g, beta, interpret: bool):
     free, batch_axes, _ = shard_axes(bsz)
     first = lambda nd: Spec(batch_axes, *([None] * (nd - 1)))  # noqa: E731
     o, final = per_shard(
-        lambda *ops: _channel_kernels(*ops, (h, dk, dv), interpret),
+        lambda *ops: _channel_kernels(
+            *ops, (h, dk, dv, unit_scales), interpret),
         free, (first(3),) * 4 + (first(5),), (first(3), first(4)),
-    )(flat(q), flat(k), flat(v), flat(gam), rows)
+    )(flat(q), flat(k), flat(v), flat(g.astype(F32)), rows)
     return (o.reshape(bsz, s, h, dv), jnp.swapaxes(final, -1, -2),
-            jnp.min(jnp.exp(gam[:, :, -1])))
+            _least_channel_decay(g, qn))
 
 
 def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
+                        unit_scales: Optional[tuple] = None,
                         backend: Optional[str] = None,
                         interpret: bool = False):
     """The chunked form -> ``(o [B, S, H, Dv] float32, final state [B, H,
@@ -1053,7 +1150,12 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
     [B, S, H]`` is a decay a head, ``g [B, S, H, Dk]`` one a key channel
     (the least decay is then over channels too).  A sequence that ``chunk``
     does not divide is padded with positions of ``g = 0`` and ``beta = 0``,
-    which neither decay nor write.  ``backend`` (``"pallas"`` /
+    which neither decay nor write.  ``unit_scales = (q's, k's)``, for the
+    per-channel rule alone: q and k are the RAW rows and each chunk
+    L2-normalises them itself in float32 (``x / sqrt(sum x^2 + 1e-6)``
+    times the scale, rounded to ``v``'s dtype), so the caller runs no norm
+    of its own; without it q and k arrive normalised, as the module's
+    docstring has them.  ``backend`` (``"pallas"`` /
     ``"reference"``; None: by the device) and ``interpret`` are for tests of
     the kernels on the CPU; a shape the kernels do not tile
     (:func:`_kernel_heads`; per channel: ``chunk`` :data:`CHANNEL_CHUNK` and
@@ -1062,6 +1164,9 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
         backend = "pallas" if jax.default_backend() == "tpu" else "reference"
     s, h, dk = k.shape[1:]
     dv, channel = v.shape[-1], g.ndim == k.ndim
+    if unit_scales is not None and not channel:
+        raise ValueError("gated_delta_chunked: unit_scales is the "
+                         "per-channel rule's (g [B, S, H, Dk])")
     # heads a grid step of the kernels takes: 0 where they do not tile
     if backend != "pallas":
         hb = 0
@@ -1076,9 +1181,10 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
             for x in (q, k, v, g, beta))
     if channel and hb:
         out, final, decay_min = _chunked_channel_pallas(
-            q, k, v, g, beta, interpret)
+            q, k, v, g, beta, interpret, unit_scales)
     elif channel:
-        out, final, decay_min = _chunked_channel_xla(q, k, v, g, beta, chunk)
+        out, final, decay_min = _chunked_channel_xla(q, k, v, g, beta, chunk,
+                                                     unit_scales)
     elif hb:
         out, final, decay_min = _chunked_pallas(q, k, v, g, beta, chunk, hb,
                                                 interpret)

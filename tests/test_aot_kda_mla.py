@@ -12,6 +12,8 @@ A compile that passes is not a chip run: nothing here says anything about
 results or times.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -100,15 +102,41 @@ def test_each_rule_kernel_runs_once_a_layer_under_its_scope(step):
             ("forward", "attention"), ("backward", "moe_experts")} <= found
 
 
+def test_the_chunks_prologue_has_left_xla(step):
+    """Under ``kda_scan`` XLA runs no cumulative sum and no ``rsqrt``: the
+    running sum of ``g`` and the L2 norms of q and k are each chunk's own,
+    inside ``kda_chunk_fwd`` / ``kda_chunk_bwd`` (whose bodies the compiled
+    text does not spell out), forward, recomputed and backward; what it
+    still runs there forms ``g`` (its ``softplus``) and ``beta``."""
+    _, text, _ = step
+    under, left = [], []
+    for line in text.splitlines():
+        op = re.search(r'op_name="([^"]*)"', line)
+        if not op or "/kda_scan/" not in op.group(1) or (
+                "tpu_custom_call" in line):
+            continue
+        under.append(op.group(1))
+        if re.search(r"\b(reduce-window|rsqrt)\(", line) or re.search(
+                r"cumsum|reduce_window|rsqrt", op.group(1)):
+            left.append(line.strip()[:160])
+    assert not left, left[:4]
+    assert any("softplus" in name for name in under)
+
+
+@pytest.mark.parametrize("raw", [False, True],
+                         ids=["unit-operands", "raw-operands"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
-def test_the_per_channel_rule_compiles_for_v5e(topo, grad):  # noqa: F811
+def test_the_per_channel_rule_compiles_for_v5e(topo, grad, raw):  # noqa: F811
     """The kernel pair alone at the cell's shapes: one sequence of 16,384,
-    32 heads of 128, a float32 decay a channel."""
+    32 heads of 128, a float32 decay a channel; q and k normalised by the
+    caller, and raw with the norms in the chunk (``unit_scales``, what the
+    mixer hands over)."""
     from dlrover_tpu.ops.gated_delta import CHANNEL_CHUNK, gated_delta_chunked
 
     def fwd(q, k, v, g, beta):
-        return gated_delta_chunked(q, k, v, g, beta, CHANNEL_CHUNK,
-                                   backend="pallas")
+        return gated_delta_chunked(
+            q, k, v, g, beta, CHANNEL_CHUNK, backend="pallas",
+            unit_scales=(128 ** -0.5, 1.0) if raw else None)
 
     fn = fwd
     if grad:

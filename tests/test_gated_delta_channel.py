@@ -4,7 +4,9 @@ form, forward and all five gradients, over decays from ``e^-1e-3`` to
 ``e^-20`` a token in single channels; ``g`` constant over a head's channels
 against the scalar op; the kernel pair ``kda_chunk_fwd`` / ``kda_chunk_bwd``
 in interpret mode against the ``jax.numpy`` form; what block remat keeps of
-it; and the shapes the kernels do not tile."""
+it; the shapes the kernels do not tile; and the chunk's own prologue — the
+running sum of ``g`` down a chunk's rows and, with ``unit_scales``, the L2
+norms of the raw q and k — against ``jnp.cumsum`` and the caller's norms."""
 
 import functools
 
@@ -52,9 +54,9 @@ def _scalar_loss(fn):
     return loss
 
 
-def _chunked(chunk, backend="reference"):
+def _chunked(chunk, backend="reference", **kwargs):
     return lambda *ops: gd.gated_delta_chunked(
-        *ops, chunk, backend=backend, interpret=True)
+        *ops, chunk, backend=backend, interpret=True, **kwargs)
 
 
 @pytest.mark.parametrize("chunk", [16, 64, 128])
@@ -93,7 +95,7 @@ def test_no_exponent_that_is_evaluated_is_positive(monkeypatch):
     assert float(jnp.min(gam)) < -1500.0  # exp(-gam) is inf in float32
     monkeypatch.setattr(gd.jnp, "exp", watched)
     out, state = gd._channel_chunk(
-        q[0, :, 0], k[0, :, 0], v[0, :, 0], gam, beta[0, :, :1],
+        q[0, :, 0], k[0, :, 0], v[0, :, 0], g[0, :, 0], beta[0, :, :1],
         jnp.ones((128, 128), F32), F32, gd.unit_lower_inverse)
     assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(state).all())
     assert len(seen) == 2 * 7 + 3 and max(seen) <= 0.0
@@ -182,25 +184,31 @@ def test_the_scalar_rules_kernels_are_as_they_were():
 # -- the halving's reference rows: moves of Gamma's rows, no product ----------
 
 
-def _level_rows(form, level, gam, cot):
-    """``(the reference rows of block size 2^level, the cotangent of gam
-    from cot on those rows alone)`` by the ``jax.numpy`` form's moves or, in
+def _moved(form, fn, x, cot):
+    """``(fn(roll, x), the cotangent of x from cot)`` of a function that
+    moves ``x``'s rows by ``roll``: by the ``jax.numpy`` form's moves or, in
     a kernel in interpret mode, by the kernels'."""
-    def rows(roll, gam, cot):
-        ref, pull = jax.vjp(lambda g: [r for _, r in gd._halving_references(
-            g, roll)][level], gam)
-        return ref, pull(cot)[0]
+    def rows(roll, x, cot):
+        out, pull = jax.vjp(functools.partial(fn, roll), x)
+        return out, pull(cot)[0]
 
     if form == "numpy":
-        return jax.jit(functools.partial(rows, gd._rolled_rows))(gam, cot)
+        return jax.jit(functools.partial(rows, gd._rolled_rows))(x, cot)
     from jax.experimental import pallas as pl
 
-    def kernel(gam_ref, cot_ref, ref_ref, d_ref):
-        ref_ref[...], d_ref[...] = rows(
-            gd._rotated_sublanes, gam_ref[...], cot_ref[...])
+    def kernel(x_ref, cot_ref, out_ref, d_ref):
+        out_ref[...], d_ref[...] = rows(
+            gd._rotated_sublanes, x_ref[...], cot_ref[...])
     return pl.pallas_call(
-        kernel, out_shape=[jax.ShapeDtypeStruct(gam.shape, F32)] * 2,
-        interpret=True)(gam, cot)
+        kernel, out_shape=[jax.ShapeDtypeStruct(x.shape, F32)] * 2,
+        interpret=True)(x, cot)
+
+
+def _level_rows(form, level, gam, cot):
+    """``(the reference rows of block size 2^level, the cotangent of gam
+    from cot on those rows alone)``."""
+    return _moved(form, lambda roll, g: [
+        r for _, r in gd._halving_references(g, roll)][level], gam, cot)
 
 
 @pytest.mark.parametrize("form", ["numpy", "interpret"])
@@ -224,16 +232,142 @@ def test_the_reference_rows_are_moved_not_multiplied(level, form):
     assert _rel(d_gam, want) < 1e-6
 
 
-def test_no_product_of_a_chunk_picks_rows():
+@pytest.mark.parametrize("form", ["numpy", "interpret"])
+@pytest.mark.parametrize("n", [16, 128])
+def test_the_running_sum_of_a_chunk_is_cumsum(n, form):
+    """``_running_sum`` of a chunk's ``g`` against ``cumsum`` in float64,
+    channels that underflow float32 within the chunk beside ones that keep
+    nearly all, and its cotangent, the running sum from the chunk's end:
+    both by turns of the rows, at every chunk size the forms run."""
+    keys = jax.random.split(jax.random.PRNGKey(n), 3)
+    # a rate a channel, e^-1e-3 to e^-20 a token, half to one and a half of
+    # it a position
+    g = -jnp.exp(jax.random.uniform(
+        keys[0], (1, 128), F32, np.log(1e-3), np.log(20.0))
+    ) * jax.random.uniform(keys[2], (n, 128), F32, 0.5, 1.5)
+    cot = jax.random.normal(keys[1], (n, 128), F32)
+    gam, d_g = _moved(form, gd._running_sum, g, cot)
+    want = np.cumsum(np.asarray(g, np.float64), axis=0)
+    assert want[-1].min() < -100.0 and want[-1].max() > -1.0
+    assert _rel(gam, want) < 1e-6
+    assert float(np.max(np.abs(np.asarray(gam) - want) / np.abs(want))) < 1e-6
+    assert _rel(d_g, np.cumsum(
+        np.asarray(cot, np.float64)[::-1], axis=0)[::-1]) < 1e-6
+
+
+# -- the L2 norms of q and k inside the chunk (``unit_scales``) ---------------
+
+SCALES = (128 ** -0.5, 1.0)
+
+
+def _raw_operands(dtype, **kwargs):
+    """:func:`_operands` with q and k as a convolution puts them out: rows
+    of lengths from a tenth to ten."""
+    q, k, v, g, beta = _operands(dtype=F32, **kwargs)
+    keys = jax.random.split(jax.random.PRNGKey(7), 2)
+    long = lambda key, x: (x / jnp.linalg.norm(  # noqa: E731
+        x, axis=-1, keepdims=True) * jnp.exp(jax.random.uniform(
+            key, x.shape[:-1] + (1,), F32, np.log(0.1), np.log(10.0)))
+    ).astype(dtype)
+    return long(keys[0], q), long(keys[1], k), v.astype(dtype), g, beta
+
+
+def _on_unit_rows(fn):
+    """``fn`` on q and k normalised as ``models.llama._kda_mixer`` did in
+    front of the op (float32, rounded to the operands' dtype), a function
+    of the RAW q and k."""
+    def unit(a, scale):
+        x = a.astype(F32)
+        return (x * (jax.lax.rsqrt(jnp.sum(
+            jnp.square(x), axis=-1, keepdims=True) + 1e-6) * scale)).astype(
+                a.dtype)
+
+    return lambda q, k, *rest: fn(
+        unit(q, SCALES[0]), unit(k, SCALES[1]), *rest)
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_raw_q_and_k_with_unit_scales_are_the_normalised_operands(
+        dtype, backend):
+    """The op on raw q and k with ``unit_scales`` against the op on
+    operands normalised in front of it: ``o``, the final state, the least
+    decay and all five gradients, those of q and k with respect to the RAW
+    rows on both sides (in bfloat16 the chunk rounds their cotangent once,
+    the caller's norm twice)."""
+    ops = _raw_operands(dtype, seed=6, s=256)
+    inside = _chunked(128, backend, unit_scales=SCALES)
+    outside = _on_unit_rows(_chunked(128, backend))
+    got, want = inside(*ops), outside(*ops)
+    tol = 2e-5 if dtype == F32 else 1e-2
+    assert _rel(got[0], want[0]) < tol and _rel(got[1], want[1]) < tol
+    assert float(got[2]) == float(want[2])
+    g_got = jax.grad(_scalar_loss(inside), range(5))(*ops)
+    g_want = jax.grad(_scalar_loss(outside), range(5))(*ops)
+    for name, g, w, op in zip(NAMES, g_got, g_want, ops):
+        assert g.dtype == op.dtype and g.shape == op.shape, name
+        assert bool(jnp.isfinite(g.astype(F32)).all()), name
+        assert _rel(g.astype(F32), w.astype(F32)) < tol, name
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_padded_rows_of_zeros_stay_zero_under_the_chunks_norm(backend):
+    """160 positions in chunks of 128: the 96 padded rows of q and k are
+    zeros, ``0 * rsqrt(1e-6)`` is 0, and the answer is the recurrence's on
+    the 160 — output, state and gradients, nothing NaN."""
+    ops = _raw_operands(F32, seed=8, s=160)
+    inside = _chunked(128, backend, unit_scales=SCALES)
+    sequential = _on_unit_rows(gd.gated_delta_sequential)
+    out, state, _ = inside(*ops)
+    want_out, want_state = sequential(*ops)
+    assert out.shape == want_out.shape
+    assert _rel(out, want_out) < 2e-5 and _rel(state, want_state) < 2e-5
+    got = jax.grad(_scalar_loss(inside), range(5))(*ops)
+    want = jax.grad(_scalar_loss(sequential), range(5))(*ops)
+    for name, g, w in zip(NAMES, got, want):
+        assert bool(jnp.isfinite(g).all()), name
+        assert g.shape == w.shape and _rel(g, w) < 5e-5, name
+
+
+def test_the_numpy_form_rounds_its_unit_rows_by_reduce_precision():
+    """Two ``reduce_precision`` in the ``jax.numpy`` form's chunk and no
+    conversion to bfloat16 and back, which a TPU's XLA drops in some uses
+    of the value and not in others (``dg`` of a fast channel 90 times its
+    size on the chip; builder, PR 67); the same bits as the conversion."""
+    chunk = functools.partial(gd._channel_chunk, dt=BF16,
+                              inverse=gd.unit_lower_inverse,
+                              unit_scales=SCALES)
+    names = [e.primitive.name for e in _eqns(jax.make_jaxpr(chunk)(*(
+        jnp.zeros(s, F32) for s in ((128, 128),) * 4 + (
+            (128, 1), (128, 128)))).jaxpr)]
+    assert names.count("reduce_precision") == 2
+    x = jax.random.normal(jax.random.PRNGKey(0), (128, 128)) * 1e3
+    np.testing.assert_array_equal(
+        np.asarray(gd._rounded(x, BF16, False)),
+        np.asarray(gd._rounded(x, BF16, True)))
+
+
+def test_unit_scales_is_the_per_channel_rules_alone():
+    q, k, v, g, beta = _operands(seed=9, s=128)
+    with pytest.raises(ValueError, match="unit_scales"):
+        gd.gated_delta_chunked(q, k, v, g[..., 0], beta, 64,
+                               unit_scales=SCALES)
+
+
+@pytest.mark.parametrize("unit_scales", [None, SCALES],
+                         ids=["unit-operands", "raw-operands"])
+def test_no_product_of_a_chunk_picks_rows(unit_scales):
     """Every product of ``_channel_chunk`` contracts operands that come
     from the chunk's inputs — none takes a 0/1 matrix built from iotas —
     and the MXU passes of a chunk of 128, as ``tools/gated_delta_bench.py
-    --channel`` counts them, are 113 forward and 255 through ``jax.vjp``."""
+    --channel`` counts them, are 113 forward and 255 through ``jax.vjp``:
+    the chunk's prologue, the running sum and the norms, adds no product."""
     from jax.extend.core import Var
     from tools.gated_delta_bench import channel_chunk_passes
 
     chunk = functools.partial(gd._channel_chunk, dt=BF16,
-                              inverse=gd._whole_tile_inverse)
+                              inverse=gd._whole_tile_inverse,
+                              unit_scales=unit_scales)
     jaxpr = jax.make_jaxpr(chunk)(*(jnp.zeros(s, F32) for s in (
         (128, 128),) * 4 + ((128, 1), (128, 128)))).jaxpr
     fed, products = set(jaxpr.invars), 0
@@ -247,7 +381,7 @@ def test_no_product_of_a_chunk_picks_rows():
         if any(v in fed for v in inputs):
             fed.update(eqn.outvars)
     assert products == 2 * 7 + 1 + 6  # the levels', the inverse, the rule's
-    assert channel_chunk_passes() == {
+    assert channel_chunk_passes(unit_scales=unit_scales) == {
         "fwd": {"highest": 102.0, "default": 11.0},
         "vjp": {"highest": 222.0, "default": 33.0}}
 

@@ -16,20 +16,30 @@ milliseconds of ``--iters`` calls and, for the kernels, each result's
 distance from the ``jax.numpy`` form's (the norm of the difference over the
 norm).  ``--block_lanes`` times the kernels at other widths of a grid step
 than the module's.  The table is also written to
-``chiprun_out/gated_delta_bench.json``; ``--toy`` rehearses it off the chip
+``chiprun_out/gated_delta_bench.json`` (``--root .parent``:
+``gated_delta_bench.parent.json``); ``--toy`` rehearses it off the chip
 (short sequences, the kernels in interpret mode).  ``--channel`` times the
 rule with a decay per key CHANNEL (``kda_chunk_fwd`` / ``kda_chunk_bwd``) at
 the Kimi Linear cell's shapes: one sequence of 16,384, 32 heads of 128,
 chunks of 128, ``g [B, S, H, 128]`` with a rate drawn per channel; and
 counts, in one ``GATED_DELTA_PASSES`` line, the MXU passes of one head's
 chunk as the kernels compute it, forward and through ``jax.vjp``
-(:func:`mxu_passes`).
+(:func:`mxu_passes`).  Beside the op alone it times, as ``scope_*``, all of
+``models.llama._kda_mixer``'s scope ``kda_scan`` — the decay from its
+projection (``g = -exp(A_log) softplus(f + dt_bias)``, ``f`` bfloat16),
+``beta``'s sigmoid, q and k as the convolutions put them out with their L2
+norms wherever the tree in ``--root`` runs them, and the op — with the
+gradients of everything it reads.  ``--root DIR`` takes ``dlrover_tpu`` from
+another checkout (the parent's: ``--root .parent``), whose op may know no
+``unit_scales`` and then gets its q and k normalised in ``jax.numpy`` as its
+mixer did; two calls, one a tree, put both trees' lines side by side.
 """
 
 from __future__ import annotations
 
 import argparse
 import functools
+import inspect
 import json
 import os
 import sys
@@ -43,6 +53,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 NAMES = ("q", "k", "v", "g", "beta")
+SCOPE_NAMES = ("q", "k", "v", "f", "b", "A_log", "dt_bias")
 
 
 def _median_ms(fn, args, iters):
@@ -89,17 +100,18 @@ def mxu_passes(fn, *args) -> dict:
     return passes
 
 
-def channel_chunk_passes() -> dict:
+def channel_chunk_passes(**chunk_kwargs) -> dict:
     """:func:`mxu_passes` of one head's chunk under a per-channel decay as
     ``kda_chunk_fwd`` computes it (bfloat16 operands, the whole-tile
-    inverse) and of its ``jax.vjp`` as ``kda_chunk_bwd`` takes it."""
+    inverse; ``chunk_kwargs``: ``unit_scales``) and of its ``jax.vjp`` as
+    ``kda_chunk_bwd`` takes it."""
     import jax
     import jax.numpy as jnp
 
     from dlrover_tpu.ops import gated_delta as gd
 
     chunk = functools.partial(gd._channel_chunk, dt=jnp.bfloat16,
-                              inverse=gd._whole_tile_inverse)
+                              inverse=gd._whole_tile_inverse, **chunk_kwargs)
     n = gd.CHANNEL_CHUNK
     ops = [jnp.zeros(shape, jnp.float32) for shape in (
         (n, 128),) * 4 + ((n, 1), (128, 128))]
@@ -117,7 +129,11 @@ def main() -> int:
     ap.add_argument("--block_lanes", type=int, nargs="*", default=[])
     ap.add_argument("--toy", action="store_true")
     ap.add_argument("--channel", action="store_true")
+    ap.add_argument("--root", default="",
+                    help="take dlrover_tpu from this checkout")
     args = ap.parse_args()
+    if args.root:
+        sys.path.insert(0, os.path.abspath(args.root))
 
     import jax
     import jax.numpy as jnp
@@ -144,20 +160,49 @@ def main() -> int:
             jax.random.normal(keys[4], (bsz, s) + rates) + 1.0),
         jax.nn.sigmoid(jax.random.normal(keys[5], (bsz, s, h))))
     cot = jax.random.normal(keys[6], (bsz, s, h, d))
+    raw = jax.random.split(keys[6], 4)
+    # what ``kda_scan`` reads: q and k before their norms (rows of many
+    # lengths), the decay's projection in bfloat16 and its two parameters
+    scope_operands = None if not args.channel else (
+        (jax.random.normal(raw[0], (bsz, s, h, d)) * jnp.exp(
+            jax.random.normal(raw[1], (bsz, s, h, 1)))).astype(bf16),
+        jax.random.normal(raw[2], (bsz, s, h, d)).astype(bf16),
+        operands[2], jax.random.normal(keys[4], (bsz, s, h * d)).astype(bf16),
+        jax.random.normal(keys[5], (bsz, s, h)).astype(bf16), a_log,
+        jnp.ones(rates, f32))
+    takes_raw = "unit_scales" in inspect.signature(
+        gd.gated_delta_chunked).parameters
 
     def candidate(backend):
-        def fwd(*ops):
+        def fwd(*ops, **kwargs):
             return gd.gated_delta_chunked(
-                *ops, chunk, backend=backend, interpret=args.toy)
+                *ops, chunk, backend=backend, interpret=args.toy, **kwargs)
 
-        def loss(*ops):
-            o, state, _ = fwd(*ops)
-            return jnp.sum(o * cot) + jnp.sum(jnp.square(state))
-        return {"fwd": jax.jit(fwd),
-                "grad": jax.jit(jax.grad(loss, argnums=range(5)))}
+        def scope(q, k, v, f, b, a_log, dt_bias):
+            """``_kda_mixer``'s ``kda_scan``, as the tree at hand runs it."""
+            beta = jax.nn.sigmoid(b.astype(f32))
+            g = -jnp.exp(a_log) * jax.nn.softplus(
+                f.astype(f32).reshape(bsz, s, h, d) + dt_bias)
+            if takes_raw:
+                return fwd(q, k, v, g, beta, unit_scales=(d ** -0.5, 1.0))
+            return fwd((unit(q.astype(f32)) * d ** -0.5).astype(bf16),
+                       unit(k.astype(f32)).astype(bf16), v, g, beta)
+
+        def summed(fn):
+            def loss(*ops):
+                o, state, _ = fn(*ops)
+                return jnp.sum(o * cot) + jnp.sum(jnp.square(state))
+            return loss
+        out = {"fwd": jax.jit(fwd),
+               "grad": jax.jit(jax.grad(summed(fwd), argnums=range(5)))}
+        if args.channel:
+            out.update(scope_fwd=jax.jit(scope), scope_grad=jax.jit(
+                jax.grad(summed(scope), argnums=range(7))))
+        return out
 
     device = jax.devices()[0]
-    print(f"DEVICE platform={device.platform} kind={device.device_kind}",
+    print(f"DEVICE platform={device.platform} kind={device.device_kind} "
+          f"ops={os.path.relpath(os.path.dirname(gd.__file__), REPO)}",
           flush=True)
     table, base = [], {}
     if args.channel:
@@ -170,19 +215,24 @@ def main() -> int:
         with mock.patch.object(gd, "_BLOCK_LANES",
                                lanes or gd._BLOCK_LANES):
             for phase, fn in candidate(backend).items():
-                ms = _median_ms(fn, operands, args.iters)
-                out = fn(*operands)
+                ops = scope_operands if "scope" in phase else operands
+                ms = _median_ms(fn, ops, args.iters)
+                out = fn(*ops)
                 base.setdefault(phase, out)
-                names = ("o", "state", "decay_min") if phase == "fwd" else [
-                    "d" + n for n in NAMES]
+                names = ("o", "state", "decay_min") if "fwd" in phase else [
+                    "d" + n for n in (
+                        SCOPE_NAMES if "scope" in phase else NAMES)]
                 line = {"phase": phase, "candidate": label,
+                        "tree": args.root or ".",
                         "ms": round(ms, 3),
                         "distance": {n: _distance(x, y) for n, x, y in zip(
                             names, out, base[phase])}}
                 table.append(line)
                 print("GATED_DELTA " + json.dumps(line), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/gated_delta_bench.json", "w") as f:
+    tree = os.path.basename(os.path.abspath(args.root)).strip(".")
+    with open("chiprun_out/gated_delta_bench%s.json" % (
+            "." + tree if tree else ""), "w") as f:
         json.dump(table, f, indent=1)
     return 0
 
