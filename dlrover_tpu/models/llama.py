@@ -47,6 +47,11 @@ from dlrover_tpu.ops.grouped_matmul import (
     grouped_matmul_ragged,
 )
 from dlrover_tpu.ops.rmsnorm import rmsnorm
+from dlrover_tpu.ops.selective_scan import (
+    CHUNK as S6_CHUNK,
+    SAVED_NAMES as S6_SAVED_NAMES,
+    selective_scan,
+)
 from dlrover_tpu.ops.ssd import causal_conv1d, ssd_chunked
 
 
@@ -54,16 +59,25 @@ from dlrover_tpu.ops.ssd import causal_conv1d, ssd_chunked
 #: with its block scope, which is also the key of the layer dict that holds
 #: its leaves (the attention leaves sit in the layer dict itself) and, with
 #: ``_layers``, the name :func:`program_facts` counts its layers under.  A
-#: new kind adds a row.  The two attention kinds share scope, leaves and
+#: new kind adds a row.  The attention kinds share scope and
 #: :func:`_attention`: a "window_attention" layer attends the last
 #: ``sliding_window`` positions where an "attention" layer of the same model
-#: attends them all.
+#: attends them all, and a "cross_attention" layer projects queries alone and
+#: attends the keys and values ANOTHER layer computed
+#: (``LlamaConfig.shared_kv_layer``).  "mamba1" is the Mamba-1 mixer (the
+#: selective scan: "mamba" is Mamba-2's), "gmu" the Gated Memory Unit, which
+#: reads another layer's scan output (``LlamaConfig.memory_layer``).
 MIXER_KINDS = {"attention": "attention", "mamba": "ssm", "conv": "conv",
                "linear_attention": "gdn", "window_attention": "attention",
-               "kda": "kda"}
+               "kda": "kda", "mamba1": "s6", "gmu": "gmu",
+               "cross_attention": "attention"}
 #: the attention kinds, each with the scope around its flash call INSIDE the
-#: block's ``attention`` (entered where a model has layers of both)
-ATTENTION_KINDS = {"attention": "attn_full", "window_attention": "attn_window"}
+#: block's ``attention`` (entered where a model has layers of more than one)
+ATTENTION_KINDS = {"attention": "attn_full", "window_attention": "attn_window",
+                   "cross_attention": "attn_cross"}
+#: the forms of the block's two norms and the final norm
+#: (``LlamaConfig.norm_form``)
+NORM_FORMS = ("rmsnorm", "layernorm")
 #: what ``LlamaConfig.layer_types`` may name as a layer's ONLY branch where
 #: ``one_branch``: the dense MLP and the routed block, each by the key of the
 #: layer dict that holds its leaves
@@ -372,6 +386,56 @@ class LlamaConfig:
     # ``w_up``, ``w_down``; experts ``wg``, ``wi``, ``wo``), or "relu2",
     # ``down(relu(up x)^2)``, TWO matrices and no gate leaf.
     mlp_form: str = "swiglu"
+    # A "mamba1" layer's mixer is the Mamba-1 one (:func:`_s6_mixer`,
+    # ``ops.selective_scan``): ``s6_d_inner`` channels, each with a state of
+    # ``s6_d_state`` numbers that decays at a rate of ITS OWN (``A [d_inner,
+    # d_state]``), the step ``dt`` a channel out of a projection of rank
+    # ``s6_dt_rank``, B and C shared by all channels, behind a causal
+    # depthwise convolution of ``s6_d_conv`` taps with a bias.  The sizes are
+    # its own: ``mamba_n_heads`` / ``mamba_d_head`` mean Mamba-2.
+    s6_d_inner: int = 0
+    s6_d_state: int = 16
+    s6_d_conv: int = 4
+    s6_dt_rank: int = 0
+    # What crosses layers (SambaY, arXiv:2507.06607), by layer index.  The
+    # scan output ``y`` of layer ``memory_layer`` (a "mamba1" layer; with its
+    # ``D`` skip, before its gate) is the MEMORY that every later "gmu" layer
+    # reads: ``(memory * silu(u in_proj)) out_proj``.  The keys and values of
+    # layer ``shared_kv_layer`` (an "attention" or "window_attention" layer;
+    # as its flash call takes them) are what every later "cross_attention"
+    # layer attends, causally over every earlier position, with queries,
+    # ``wo`` and differential leaves of its own.  :func:`forward_hidden`
+    # carries both from block to block; under ``remat_block`` they are
+    # outputs of the block that makes them and inputs of the blocks that read
+    # them.  A "gmu" layer at or before ``memory_layer``, a "cross_attention"
+    # layer at or before ``shared_kv_layer``, and either beside ``loop_passes
+    # > 1``, ``one_branch`` or ``mtp_layers`` are refused.
+    memory_layer: Optional[int] = None
+    shared_kv_layer: Optional[int] = None
+    # Differential attention (arXiv:2410.05258) in EVERY attention layer of
+    # any kind; the tuple holds ``lambda_init`` of each layer (``n_layer``
+    # floats; a layer that is no attention layer's is read by nobody), which
+    # a model states by its PUBLISHED layer index: ``0.8 - 0.6 exp(-0.3 l)``.
+    # Query heads ``(2p, 2p + 1)`` are a pair, key heads ``(2r, 2r + 1)``
+    # too, value heads ``(2r, 2r + 1)`` are joined to one of twice the
+    # width, and query pair ``p`` reads key/value pair ``p // (n_head /
+    # n_kv_head)``: ``o = (softmax(q1 k1^T) - lambda softmax(q2 k2^T)) v``,
+    # ``lambda = exp(lambda_q1 . lambda_k1) - exp(lambda_q2 . lambda_k2) +
+    # lambda_init`` (four leaves of ``head_dim``), then an RMSNorm per pair
+    # over the ``2 head_dim`` (gain ``subln``) times ``1 - lambda_init``.
+    # Both softmaxes run through ONE flash call, heads reordered so that its
+    # GQA map holds (scope ``attn_diff`` holds the rest).  Refused beside
+    # latent attention, ``attn_output_gate`` and odd head counts.
+    diff_attention: tuple = ()
+    # The form of the block's two norms and the final norm
+    # (:data:`NORM_FORMS`): "layernorm" subtracts the mean and adds a bias,
+    # and ``ln1``, ``ln2`` and ``ln_f`` then hold ``{"gain", "bias"}``.
+    # ``attn_bias``: a bias on each of the attention projections (``bq``,
+    # ``bk``, ``bv``, ``bo``).  Both are refused beside ``branch_norm``,
+    # ``norm_plus_one``, latent attention, a looped stack and the prediction
+    # block: they have never run beside them.
+    norm_form: str = "rmsnorm"
+    attn_bias: bool = False
 
     def __post_init__(self):
         if (self.loop_passes > 1) != (self.exit_gate_beta is not None):
@@ -578,6 +642,77 @@ class LlamaConfig:
                 "LlamaConfig: shared_expert_gate with n_shared_experts="
                 f"{self.n_shared_experts}: there is no shared expert to "
                 "gate")
+        if self.s6_layers and min(
+                self.s6_d_inner, self.s6_d_state, self.s6_d_conv,
+                self.s6_dt_rank) <= 0:
+            raise ValueError(
+                f"LlamaConfig: a 'mamba1' layer needs positive s6_d_inner, "
+                f"s6_d_state, s6_d_conv and s6_dt_rank, not "
+                f"{self.s6_d_inner}, {self.s6_d_state}, {self.s6_d_conv} and "
+                f"{self.s6_dt_rank} (mamba_n_heads and mamba_d_head are "
+                "Mamba-2's)")
+        crossing = (self.s6_layers or self.gmu_layers or self.cross_layers
+                    or self.memory_layer is not None
+                    or self.shared_kv_layer is not None)
+        if crossing and (self.loop_passes > 1 or self.one_branch
+                         or self.mtp_layers):
+            raise ValueError(
+                f"LlamaConfig: 'mamba1', 'gmu' or 'cross_attention' layers, "
+                f"memory_layer={self.memory_layer} or shared_kv_layer="
+                f"{self.shared_kv_layer} with loop_passes={self.loop_passes}, "
+                f"one_branch={self.one_branch} or mtp_layers="
+                f"{self.mtp_layers}: what crosses layers is carried through "
+                "a stack of two-branch layers that runs once, with no "
+                "prediction block")
+        for setting, maker, reader in (
+                ("memory_layer", ("mamba1",), "gmu"),
+                ("shared_kv_layer", ("attention", "window_attention"),
+                 "cross_attention")):
+            made = getattr(self, setting)
+            readers = [i for i in range(self.n_layer)
+                       if self.mixer_kind(i) == reader]
+            if made is None and not readers:
+                continue
+            if (made is None or not 0 <= made < self.n_layer
+                    or self.mixer_kind(made) not in maker
+                    or any(i <= made for i in readers)):
+                raise ValueError(
+                    f"LlamaConfig: {setting}={made} with {reader!r} layers "
+                    f"{readers} and layer_types={kinds}: {setting} names a "
+                    f"layer of {maker}, and every {reader!r} layer comes "
+                    "after it (it reads what that layer made)")
+        object.__setattr__(self, "diff_attention", tuple(
+            float(x) for x in self.diff_attention))
+        if self.diff_attention and (
+                len(self.diff_attention) != self.n_layer
+                or self.kv_lora_rank > 0 or self.attn_output_gate
+                or self.n_head % 2 or self.n_kv_head % 2):
+            raise ValueError(
+                f"LlamaConfig: diff_attention of {len(self.diff_attention)} "
+                f"entries with n_layer={self.n_layer}, kv_lora_rank="
+                f"{self.kv_lora_rank}, attn_output_gate="
+                f"{self.attn_output_gate}, n_head={self.n_head} and "
+                f"n_kv_head={self.n_kv_head}: it holds lambda_init of every "
+                "layer and pairs the heads of the plain q, k and v "
+                "projections (even counts), without latent attention or the "
+                "output gate")
+        if self.norm_form not in NORM_FORMS:
+            raise ValueError(
+                f"LlamaConfig: norm_form={self.norm_form!r} is none of "
+                f"{NORM_FORMS}")
+        for name, off in (("norm_form", "rmsnorm"), ("attn_bias", False)):
+            if getattr(self, name) != off and (
+                    self.branch_norm or self.norm_plus_one
+                    or self.kv_lora_rank > 0 or self.loop_passes > 1
+                    or self.mtp_layers):
+                raise ValueError(
+                    f"LlamaConfig: {name}={getattr(self, name)!r} with "
+                    f"branch_norm={self.branch_norm}, norm_plus_one="
+                    f"{self.norm_plus_one}, kv_lora_rank={self.kv_lora_rank}, "
+                    f"loop_passes={self.loop_passes} or mtp_layers="
+                    f"{self.mtp_layers}: it has never run beside a norm on "
+                    "each branch's output, gains stored as 1 + w, latent "
+                    "attention, a looped stack or the prediction block")
 
     def mixer_kind(self, i: int) -> Optional[str]:
         """Layer ``i``'s mixer: one of :data:`MIXER_KINDS`, or None where
@@ -617,8 +752,23 @@ class LlamaConfig:
         return self.layers_of("kda")
 
     @property
+    def s6_layers(self) -> int:
+        """Layers whose mixer is the Mamba-1 one (the selective scan)."""
+        return self.layers_of("mamba1")
+
+    @property
+    def gmu_layers(self) -> int:
+        """Layers whose mixer is the Gated Memory Unit."""
+        return self.layers_of("gmu")
+
+    @property
+    def cross_layers(self) -> int:
+        """Layers of the "cross_attention" kind."""
+        return self.layers_of("cross_attention")
+
+    @property
     def attention_layers(self) -> int:
-        """Layers whose mixer is attention, of either kind."""
+        """Layers whose mixer is attention, of any kind."""
         return sum(self.layers_of(kind) for kind in ATTENTION_KINDS)
 
     @property
@@ -739,6 +889,10 @@ class LlamaConfig:
         )
 
 
+#: the four vectors of a differential attention layer's ``lambda``
+_LAMBDA_LEAVES = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+
+
 def _dense(key, fan_in, fan_out, std=0.02):
     return jax.random.normal(key, (fan_in, fan_out), jnp.float32) * std
 
@@ -841,6 +995,46 @@ def _init_kda(key: jax.Array, cfg: LlamaConfig) -> Dict:
     }
 
 
+def _init_s6(key: jax.Array, cfg: LlamaConfig) -> Dict:
+    """A Mamba-1 mixer's parameters as the reference implementation draws
+    them: ``in_proj``, ``x_proj`` and ``out_proj`` N(0, 0.02); the
+    convolution and its bias PyTorch's ``Conv1d`` default as
+    :func:`_init_ssm` draws them, stored ``[taps, channels]``; ``dt_proj``
+    uniform in +-``s6_dt_rank^-1/2``; ``dt_bias`` the inverse softplus of a
+    log-uniform draw in [1e-3, 1e-1]; ``A_log = log(1..N)`` in every channel;
+    ``D`` 1."""
+    k = jax.random.split(key, 7)
+    inner, N, R = cfg.s6_d_inner, cfg.s6_d_state, cfg.s6_dt_rank
+    bound = cfg.s6_d_conv ** -0.5
+    dt = jnp.exp(jax.random.uniform(
+        k[5], (inner,), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    return {
+        # [x | z]
+        "in_proj": _dense(k[0], cfg.d_model, 2 * inner),
+        "conv_w": jax.random.uniform(
+            k[1], (cfg.s6_d_conv, inner), jnp.float32, -bound, bound),
+        "conv_b": jax.random.uniform(
+            k[2], (inner,), jnp.float32, -bound, bound),
+        # [delta | B | C]
+        "x_proj": _dense(k[3], inner, R + 2 * N),
+        "dt_proj": jax.random.uniform(
+            k[4], (R, inner), jnp.float32, -R ** -0.5, R ** -0.5),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)), (inner, N)),
+        "D": jnp.ones((inner,), jnp.float32),
+        "out_proj": _dense(k[6], inner, cfg.d_model),
+    }
+
+
+def _init_gmu(key: jax.Array, cfg: LlamaConfig) -> Dict:
+    """A Gated Memory Unit's two projections, N(0, 0.02); it is as wide as
+    the memory it reads (``s6_d_inner``)."""
+    k = jax.random.split(key, 2)
+    return {"in_proj": _dense(k[0], cfg.d_model, cfg.s6_d_inner),
+            "out_proj": _dense(k[1], cfg.s6_d_inner, cfg.d_model)}
+
+
 def _gain(w, cfg: "LlamaConfig"):
     """A norm's gain as applied: the leaf, or ``1 + w`` where
     ``cfg.norm_plus_one``."""
@@ -852,6 +1046,30 @@ def _gain_leaf(width: int, cfg: "LlamaConfig"):
     ``cfg.norm_plus_one``."""
     return (jnp.zeros if cfg.norm_plus_one else jnp.ones)(
         (width,), jnp.float32)
+
+
+def _norm_leaf(width: int, cfg: "LlamaConfig"):
+    """The leaf of one of the block's two norms or of the final norm at its
+    initial values: a gain (:func:`_gain_leaf`), or ``{"gain", "bias"}`` where
+    ``cfg.norm_form`` is "layernorm"."""
+    if cfg.norm_form == "layernorm":
+        return {"gain": jnp.ones((width,), jnp.float32),
+                "bias": jnp.zeros((width,), jnp.float32)}
+    return _gain_leaf(width, cfg)
+
+
+def _norm(x, leaf, cfg: "LlamaConfig"):
+    """One of the block's two norms or the final norm, in the form
+    ``cfg.norm_form`` names: the RMSNorm kernel, or LayerNorm — the mean
+    subtracted, over ``sqrt(var + rms_eps)``, gain and bias, in float32 and
+    rounded once (an elementwise chain XLA fuses)."""
+    if cfg.norm_form == "rmsnorm":
+        return rmsnorm(x, _gain(leaf, cfg), eps=cfg.rms_eps)
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    inv = jax.lax.rsqrt(
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + cfg.rms_eps)
+    return (x32 * inv * leaf["gain"] + leaf["bias"]).astype(x.dtype)
 
 
 def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: Optional[bool],
@@ -873,13 +1091,20 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: Optional[bool],
     k = jax.random.split(key, 8)
     more = jax.random.split(jax.random.fold_in(key, 1), 5)
     hd = cfg.head_dim
+    # (a Mamba-1 mixer ``layer["s6"]``, a Gated Memory Unit ``layer["gmu"]``,
+    # the attention projections' biases and the differential leaves draw
+    # from keys folded out of the layer's as well)
     attention = mixer in ATTENTION_KINDS
     gated = cfg.mlp_form == "swiglu"
     layer = {}
     if mixer is not None:
-        layer["ln1"] = _gain_leaf(cfg.d_model, cfg)
+        layer["ln1"] = _norm_leaf(cfg.d_model, cfg)
     if mixer == "mamba":
         layer["ssm"] = _init_ssm(jax.random.fold_in(key, 2), cfg)
+    elif mixer == "mamba1":
+        layer["s6"] = _init_s6(jax.random.fold_in(key, 7), cfg)
+    elif mixer == "gmu":
+        layer["gmu"] = _init_gmu(jax.random.fold_in(key, 8), cfg)
     elif mixer == "conv":
         layer["conv"] = _init_conv(jax.random.fold_in(key, 3), cfg)
     elif mixer == "linear_attention":
@@ -904,13 +1129,25 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, routed: Optional[bool],
         layer["wq"] = _dense(
             k[0], cfg.d_model,
             cfg.n_head * hd * (2 if cfg.attn_output_gate else 1))
-        layer["wk"] = _dense(k[1], cfg.d_model, cfg.n_kv_head * hd)
-        layer["wv"] = _dense(k[2], cfg.d_model, cfg.n_kv_head * hd)
+        if mixer != "cross_attention":  # it reads another layer's
+            layer["wk"] = _dense(k[1], cfg.d_model, cfg.n_kv_head * hd)
+            layer["wv"] = _dense(k[2], cfg.d_model, cfg.n_kv_head * hd)
     if attention:
         layer["wo"] = _dense(
             k[3], cfg.n_head * cfg.value_head_dim, cfg.d_model)
+    if attention and cfg.attn_bias:
+        widths = {"bq": cfg.n_head * hd, "bk": cfg.n_kv_head * hd,
+                  "bv": cfg.n_kv_head * hd, "bo": cfg.d_model}
+        for name, width in widths.items():
+            if "w" + name[1] in layer:
+                layer[name] = jnp.zeros((width,), jnp.float32)
+    if attention and cfg.diff_attention:
+        lam = jax.random.split(jax.random.fold_in(key, 9), 4)
+        for name, lam_key in zip(_LAMBDA_LEAVES, lam):
+            layer[name] = 0.1 * jax.random.normal(lam_key, (hd,), jnp.float32)
+        layer["subln"] = jnp.ones((2 * hd,), jnp.float32)
     if routed is not None:
-        layer["ln2"] = _gain_leaf(cfg.d_model, cfg)
+        layer["ln2"] = _norm_leaf(cfg.d_model, cfg)
     if cfg.qk_norm and attention:
         per_head = cfg.qk_norm_per_head
         layer["q_norm"] = _gain_leaf(
@@ -962,7 +1199,7 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
     params: Dict = {
         "embed": _dense(keys[0], cfg.vocab_size, cfg.d_model),
         "lm_head": _dense(keys[1], cfg.d_model, cfg.vocab_size),
-        "ln_f": _gain_leaf(cfg.d_model, cfg),
+        "ln_f": _norm_leaf(cfg.d_model, cfg),
         "layers": [
             _init_layer(keys[2 + i], cfg, cfg.mlp_routed(i),
                         mixer=cfg.mixer_kind(i))
@@ -1001,11 +1238,15 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
             ax["w_gate"] = ("embed", "mlp")
         return ax
 
+    # one of the block's two norms or the final norm (:func:`_norm_leaf`)
+    norm = ({"gain": (None,), "bias": (None,)}
+            if cfg.norm_form == "layernorm" else (None,))
+
     def layer_axes(has_moe: Optional[bool],
                    mixer: Optional[str] = "attention") -> Dict:
         """As :func:`_init_layer`: ``mixer`` None is a layer without a
         mixer half, ``has_moe`` None one without an MLP half."""
-        ax = {"ln1": (None,), "wo": ("heads", "embed"), "ln2": (None,)}
+        ax = {"ln1": norm, "wo": ("heads", "embed"), "ln2": norm}
         attention = mixer in ATTENTION_KINDS
         if mixer is None:
             del ax["ln1"], ax["wo"]
@@ -1036,6 +1277,19 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
                 "in_proj_qkvz": ("embed", None), "in_proj_ba": ("embed", None),
                 "conv_w": (None, None), "dt_bias": (None,), "A_log": (None,),
                 "norm": (None,), "out_proj": (None, "embed")}
+        elif mixer == "mamba1":
+            # as the state-space mixer: no ``tp`` rule yet
+            del ax["wo"]
+            ax["s6"] = {
+                "in_proj": ("embed", None), "conv_w": (None, None),
+                "conv_b": (None,), "x_proj": (None, None),
+                "dt_proj": (None, None), "dt_bias": (None,),
+                "A_log": (None, None), "D": (None,),
+                "out_proj": (None, "embed")}
+        elif mixer == "gmu":
+            del ax["wo"]
+            ax["gmu"] = {"in_proj": ("embed", None),
+                         "out_proj": (None, "embed")}
         elif mixer == "kda":
             # as the state-space mixer: no ``tp`` rule yet
             del ax["wo"]
@@ -1058,6 +1312,14 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
         elif attention:
             ax.update(wq=("embed", "heads"), wk=("embed", "heads"),
                       wv=("embed", "heads"))
+            if mixer == "cross_attention":
+                del ax["wk"], ax["wv"]
+        if attention and cfg.attn_bias:
+            ax.update({"b" + name[1]: (None,)
+                       for name in ("wq", "wk", "wv", "wo") if name in ax})
+        if attention and cfg.diff_attention:
+            ax.update({name: (None,) for name in _LAMBDA_LEAVES},
+                      subln=(None,))
         if cfg.qk_norm and attention:
             ax["q_norm"] = (None,)
             ax["k_norm"] = (None,)
@@ -1088,7 +1350,7 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict:
     axes = {
         "embed": ("vocab", "embed"),
         "lm_head": ("embed", "vocab"),
-        "ln_f": (None,),
+        "ln_f": norm,
         "layers": layers,
     }
     if cfg.tie_word_embeddings:
@@ -1225,38 +1487,89 @@ def _mla_qkv(x, layer, cfg: LlamaConfig, positions) -> tuple:
     return q, k, v
 
 
+def _diff_heads(q, k, v):
+    """The heads of differential attention reordered so that ONE flash call
+    with its GQA map (query head ``h`` reads key/value head ``h // (H /
+    KV)``) computes both softmaxes of every pair: ``q [B, S, H, D]`` -> all
+    the pairs' first heads, then all their second ones; ``k [B, S, KV, D]``
+    likewise; ``v [B, S, KV, D]`` -> each pair's two heads joined, ``[B, S,
+    KV / 2, 2 D]``, once under the first keys and once under the second."""
+    first_then_second = lambda a: jnp.concatenate(  # noqa: E731
+        [a[:, :, 0::2], a[:, :, 1::2]], axis=2)
+    B, S, KV, D = v.shape
+    v = v.reshape(B, S, KV // 2, 2 * D)
+    return (first_then_second(q), first_then_second(k),
+            jnp.concatenate([v, v], axis=2))
+
+
+def _diff_combine(out, layer, cfg: LlamaConfig, lambda_init: float):
+    """What follows differential attention's flash call (scope
+    ``attn_diff``): ``out [B, S, H, 2 D]``, the pairs' first heads then
+    their second ones -> ``[B, S, H / 2, 2 D]``: ``o1 - lambda o2``, an
+    RMSNorm per pair (gain ``subln``), times ``1 - lambda_init``, in
+    float32 and rounded once."""
+    f32 = jnp.float32
+    with jax.named_scope("attn_diff"):
+        dots = [jnp.sum(layer[a] * layer[b]) for a, b in (
+            _LAMBDA_LEAVES[:2], _LAMBDA_LEAVES[2:])]
+        lam = jnp.exp(dots[0]) - jnp.exp(dots[1]) + lambda_init
+        pairs = out.shape[2] // 2
+        o = out[:, :, :pairs].astype(f32) - lam * out[:, :, pairs:].astype(f32)
+        inv = jax.lax.rsqrt(
+            jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.rms_eps)
+        return (o * inv * (layer["subln"] * (1.0 - lambda_init))).astype(
+            out.dtype)
+
+
 def _attention(
     x, layer, cfg: LlamaConfig, positions, attn_impl: str, mesh,
     segment_ids=None, kind: str = "attention", rotary=None,
+    shared_kv=None, lambda_init: float = 0.0, with_kv: bool = False,
 ):
-    """Both attention kinds (:data:`ATTENTION_KINDS`): ``kind`` sets the
-    window (``cfg.window_of``), whether q and k rotate at all
-    (``cfg.unrotated``) and, in a model with layers of both, the scope
-    around the flash call; ``rotary`` is the kind's ``(cos, sin)`` where the
-    step built one (``cfg.rotary_by_kind``).  The output gate's multiply
-    sits under ``attn_gate``, inside the block's ``attention``."""
+    """The attention kinds (:data:`ATTENTION_KINDS`) -> ``out``, or with
+    ``with_kv`` ``(out, (k, v))``: ``kind`` sets the window
+    (``cfg.window_of``), whether q and k rotate at all (``cfg.unrotated``) and, in a model with layers of more than one,
+    the scope around the flash call; ``rotary`` is the kind's ``(cos, sin)``
+    where the step built one (``cfg.rotary_by_kind``).  The output gate's
+    multiply sits under ``attn_gate``, inside the block's ``attention``.
+    ``(k, v)`` are the keys and values as the flash call's reordering takes
+    them (``[B, S, KV, D]``: after the bias, the norms and the rotation),
+    which is what a "cross_attention" layer is handed as ``shared_kv`` in
+    place of projections of its own.  Under ``cfg.diff_attention`` the heads
+    are paired (:func:`_diff_heads`, :func:`_diff_combine` with this layer's
+    ``lambda_init``)."""
     B, S, C = x.shape
     H, KV, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     dt = cfg.dtype
     latent = cfg.kv_lora_rank > 0
     gate = None
+
+    def projected(name):
+        y = x @ layer["w" + name].astype(dt)
+        return y + layer["b" + name].astype(dt) if cfg.attn_bias else y
+
     if latent:
         q, k, v = _mla_qkv(x, layer, cfg, positions)
+    elif shared_kv is not None:
+        q, (k, v) = projected("q"), shared_kv
     else:
-        q = x @ layer["wq"].astype(dt)
-        k = x @ layer["wk"].astype(dt)
-        v = x @ layer["wv"].astype(dt)
+        q, k, v = projected("q"), projected("k"), projected("v")
     if cfg.attn_output_gate:
         # each head's columns are [q | gate]
         q = q.reshape(B, S, H, 2 * D)
         q, gate = q[..., :D].reshape(B, S, H * D), q[..., D:]
-    if not latent:
+    if shared_kv is not None:
+        q = q.reshape(B, S, H, D)
+    elif not latent:
         q, k = qk_normed(q, k, layer, cfg)
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
         if not cfg.unrotated(kind):
             q = _rope_part(q, positions, cfg, rotary)
             k = _rope_part(k, positions, cfg, rotary)
         v = v.reshape(B, S, KV, D)
+    made = (k, v)
+    if cfg.diff_attention:
+        q, k, v = _diff_heads(q, k, v)
     if cfg.attention_multiplier is not None:
         # every backend scales the scores by 1 / sqrt(D): the rest of the
         # stated scale goes onto q (Granite: 1/64 at D = 64, so 1/8, exact)
@@ -1300,7 +1613,8 @@ def _attention(
         # [B,S,H,D] -> [B,H,S,D] for the flash kernel; where the model has
         # both kinds the call says which it is (``attn_window`` /
         # ``attn_full``, inside the block's ``attention``)
-        with (jax.named_scope(ATTENTION_KINDS[kind]) if cfg.window_layers
+        with (jax.named_scope(ATTENTION_KINDS[kind])
+              if cfg.window_layers or cfg.cross_layers
               else contextlib.nullcontext()):
             o = flash_attention(
                 q.transpose(0, 2, 1, 3),
@@ -1316,10 +1630,15 @@ def _attention(
         with jax.named_scope("attn_gate"):
             out = (out.astype(jnp.float32)
                    * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
-    out = out.reshape(B, S, H * out.shape[-1])
+    if cfg.diff_attention:
+        out = _diff_combine(out, layer, cfg, lambda_init)
+    out = out.reshape(B, S, -1)
     with (jax.named_scope("mla_out") if latent
           else contextlib.nullcontext()):
-        return out @ layer["wo"].astype(dt)
+        out = out @ layer["wo"].astype(dt)
+        if cfg.attn_bias:
+            out = out + layer["bo"].astype(dt)
+        return (out, made) if with_kv else out
 
 
 def _ssm_mixer(u, ssm, cfg: LlamaConfig) -> tuple:
@@ -1369,6 +1688,60 @@ def _ssm_mixer(u, ssm, cfg: LlamaConfig) -> tuple:
                            eps=cfg.rms_eps, gate_first=True)
     with jax.named_scope("ssm_out"):
         return y @ ssm["out_proj"].astype(dt), stats
+
+
+def _s6_mixer(u, s6, cfg: LlamaConfig) -> tuple:
+    """The Mamba-1 mixer on the normed stream ``u [B, S, C]`` -> ``(out [B,
+    S, C], stats, y)``.  ``[x | z] = u in_proj``; ``x = silu(conv(x) + b)``,
+    causal, depthwise (``ops.conv_silu``); ``[delta | B | C] = x x_proj``
+    (``s6_dt_rank``, ``s6_d_state``, ``s6_d_state`` columns); ``dt =
+    softplus(delta dt_proj + dt_bias)`` in float32 a channel; ``A =
+    -exp(A_log)`` ``[d_inner, d_state]``; the selective scan
+    (``ops.selective_scan``) gives ``y_t = s_t C_t + D x_t`` in float32;
+    ``out = (y * silu(z)) out_proj``.  ``y`` — with the ``D`` skip, BEFORE
+    the gate — is returned too: it is the memory where this layer is
+    ``cfg.memory_layer``.  Scopes ``s6_in``, ``s6_conv``, ``s6_dt``,
+    ``s6_scan``, ``s6_gate`` and ``s6_out`` sit inside the block's ``s6``.
+    ``stats``: ``s6_state_rms`` (of the state the sequence leaves) and
+    ``s6_decay_min`` (the least ``exp(sum dt A)`` over a chunk, channel and
+    state).  Block remat keeps the scan's output and the states that enter
+    its chunks (``ops.selective_scan.SAVED_NAMES``: ``B S d_inner (4 + 4
+    d_state / S6_CHUNK)`` bytes a layer), so ``s6_scan_fwd`` runs once a
+    step and layer."""
+    inner, N, R = cfg.s6_d_inner, cfg.s6_d_state, cfg.s6_dt_rank
+    dt, f32 = cfg.dtype, jnp.float32
+    with jax.named_scope("s6_in"):
+        xz = u @ s6["in_proj"].astype(dt)
+        x, z = xz[..., :inner], xz[..., inner:]
+    with jax.named_scope("s6_conv"):
+        x = causal_conv1d_silu(x, s6["conv_w"], s6["conv_b"])
+    with jax.named_scope("s6_dt"):
+        dbc = x @ s6["x_proj"].astype(dt)
+        step = jax.nn.softplus(
+            (dbc[..., :R] @ s6["dt_proj"].astype(dt)).astype(f32)
+            + s6["dt_bias"])
+    with jax.named_scope("s6_scan"):
+        y, state, decay_min = selective_scan(
+            x, step, -jnp.exp(s6["A_log"]), dbc[..., R:R + N],
+            dbc[..., R + N:], s6["D"])
+        stats = jax.lax.stop_gradient({
+            "s6_state_rms": jnp.sqrt(jnp.mean(jnp.square(state))),
+            "s6_decay_min": decay_min})
+    with jax.named_scope("s6_gate"):
+        gated = (y * jax.nn.silu(z.astype(f32))).astype(dt)
+    with jax.named_scope("s6_out"):
+        return gated @ s6["out_proj"].astype(dt), stats, y
+
+
+def _gmu_mixer(u, gmu, memory, cfg: LlamaConfig):
+    """The Gated Memory Unit on the normed stream ``u [B, S, C]`` and the
+    memory ``[B, S, s6_d_inner]`` (``cfg.memory_layer``'s scan output, in
+    ``cfg.dtype``) -> ``[B, S, C]``: ``(memory * silu(u in_proj))
+    out_proj``, the gate in float32 and rounded once; no scan, no
+    convolution, no bias."""
+    dt, f32 = cfg.dtype, jnp.float32
+    gate = jax.nn.silu((u @ gmu["in_proj"].astype(dt)).astype(f32))
+    return (memory.astype(f32) * gate).astype(dt) @ gmu["out_proj"].astype(dt)
 
 
 def _conv_mixer(u, conv, cfg: LlamaConfig):
@@ -1986,6 +2359,10 @@ def block_apply(
     moe_capacity: Optional[int] = None,
     attn_kind: str = "attention",
     rotary=None,
+    memory=None,
+    shared_kv=None,
+    keep: Optional[str] = None,
+    lambda_init: float = 0.0,
 ) -> tuple:
     """One transformer block: (x, layer) -> (x, stats).  The mixer is the
     one the layer dict holds (:data:`MIXER_KINDS`) — a state-space one
@@ -2009,7 +2386,13 @@ def block_apply(
     attention kinds hold the same leaves, so ``attn_kind`` (of
     :data:`ATTENTION_KINDS`) says which an attention layer is, and
     ``rotary`` hands it its kind's ``(cos, sin)`` where the step built
-    one."""
+    one, ``lambda_init`` its differential attention's (``cfg.diff_attention``
+    of its index).  What crosses layers: ``memory`` is handed to a "gmu"
+    layer and ``shared_kv`` to a "cross_attention" layer, and the layer that
+    MAKES one returns it under ``stats["carried"]`` where ``keep`` names it
+    (``"memory"``: a "mamba1" layer's scan output in ``cfg.dtype``;
+    ``"shared_kv"``: an attention layer's ``(k, v)``) — inputs and outputs
+    of the block, so of its checkpoint under ``cfg.remat_block``."""
     # The scopes (``attention``, ``mlp``, and the routed block's four:
     # ``moe_router`` with its norm, ``moe_permute``, ``moe_experts``,
     # ``moe_combine`` with the residual add) go into every instruction's
@@ -2030,7 +2413,7 @@ def block_apply(
         with jax.named_scope("branch_norm"):
             return rmsnorm(branch, layer[gain], eps=cfg.rms_eps)
 
-    stats = {}
+    stats, carried = {}, {}
     if "ln1" in layer:  # the mixer's half; a one-branch MLP layer has none
         named, kind = next(
             (row for row in MIXER_KINDS.items() if row[1] in layer),
@@ -2041,13 +2424,19 @@ def block_apply(
                 f"block_apply: a {named!r} layer with segment_ids or a "
                 "custom attn_fn: the scan and the convolution know no "
                 "document boundary and no cache")
-        # outermost ``ssm`` / ``conv`` / ``gdn`` / ``kda`` as ``attention`` is
-        # for the other kind; the mixer's own scopes nest inside it
-        # (``subscopes``)
+        # outermost ``ssm`` / ``conv`` / ``gdn`` / ``kda`` / ``s6`` / ``gmu``
+        # as ``attention`` is for the other kind; the mixer's own scopes nest
+        # inside it (``subscopes``)
         with jax.named_scope(kind):
-            h = rmsnorm(x, _gain(layer["ln1"], cfg), eps=cfg.rms_eps)
+            h = _norm(x, layer["ln1"], cfg)
             if kind == "ssm":
                 mixed, stats = _ssm_mixer(h, layer["ssm"], cfg)
+            elif kind == "s6":
+                mixed, stats, y = _s6_mixer(h, layer["s6"], cfg)
+                if keep == "memory":
+                    carried["memory"] = y.astype(cfg.dtype)
+            elif kind == "gmu":
+                mixed = _gmu_mixer(h, layer["gmu"], memory, cfg)
             elif kind == "gdn":
                 mixed, stats = _gdn_mixer(h, layer["gdn"], cfg)
             elif kind == "kda":
@@ -2059,13 +2448,18 @@ def block_apply(
             else:
                 mixed = _attention(
                     h, layer, cfg, positions, attn_impl, mesh, segment_ids,
-                    attn_kind, rotary)
+                    attn_kind, rotary, shared_kv, lambda_init,
+                    with_kv=keep == "shared_kv")
+                if keep == "shared_kv":
+                    mixed, carried["shared_kv"] = mixed
             x = add(x, out_norm(mixed, "ln1_out"))
+    if carried:
+        stats = dict(stats, carried=carried)
     if "ln2" not in layer:  # a one-branch mixer layer: no MLP's half
         return x, stats
     if "moe" in layer:
         with jax.named_scope("moe_router"):
-            h = rmsnorm(x, _gain(layer["ln2"], cfg), eps=cfg.rms_eps)
+            h = _norm(x, layer["ln2"], cfg)
         delta, routed = _moe_swiglu(
             h, layer["moe"], cfg, capacity=moe_capacity,
             valid=None if segment_ids is None else segment_ids >= 0,
@@ -2075,7 +2469,7 @@ def block_apply(
             x = add(x, out_norm(delta, "ln2_out"))
         return x, stats
     with jax.named_scope("mlp"):
-        h = rmsnorm(x, _gain(layer["ln2"], cfg), eps=cfg.rms_eps)
+        h = _norm(x, layer["ln2"], cfg)
         x = add(x, out_norm(_mlp(h, layer["mlp"], cfg.dtype), "ln2_out"))
     return x, stats
 
@@ -2133,7 +2527,9 @@ def forward_hidden(
     (``ops.flash_attention.SAVED_NAMES``) and the delta rule's kernel's
     output, final state and entering states
     (``ops.gated_delta.SAVED_NAMES``; under a per-channel decay
-    ``CHANNEL_SAVED_NAMES``) — what costs as much to recompute as
+    ``CHANNEL_SAVED_NAMES``) and the selective scan's output and entering
+    states (``ops.selective_scan.SAVED_NAMES``) — what costs as much to
+    recompute as
     to compute and is small to keep, so neither forward kernel runs again
     in front of the block's backward.  Everything else of the block does.
 
@@ -2155,7 +2551,13 @@ def forward_hidden(
     layer's scan leaves) and ``ssm_decay_min`` (the least decay over a
     chunk, any layer and head), one with delta-rule layers
     ``gdn_state_rms`` and ``gdn_decay_min`` likewise, one with "kda" layers
-    ``kda_state_rms`` and ``kda_decay_min``.  The embedding's rows
+    ``kda_state_rms`` and ``kda_decay_min``, one with "mamba1" layers
+    ``s6_state_rms`` and ``s6_decay_min``.  What crosses layers
+    (``cfg.memory_layer``'s scan output, ``cfg.shared_kv_layer``'s keys and
+    values) is kept here from the block that makes it, handed to every later
+    block that reads it (:func:`block_apply`) and returned under
+    ``aux["carried"]`` (``{"memory": [B, S, s6_d_inner], "shared_kv": (k,
+    v)}``); its gradient is the sum over its readers.  The embedding's rows
     are scaled by
     ``cfg.embedding_multiplier`` here; the head's side of a tied or scaled
     head is :func:`head_operands`'."""
@@ -2173,8 +2575,8 @@ def forward_hidden(
     moe_z = jnp.zeros((), jnp.float32)
     experts, per_expert, held_pairs, buffer_rows = {}, [], [], []
     # what the recurrent mixers report, by their scope
-    state_rms = {"ssm": [], "gdn": [], "kda": []}
-    decay_min = {"ssm": [], "gdn": [], "kda": []}
+    state_rms = {"ssm": [], "gdn": [], "kda": [], "s6": []}
+    decay_min = {"ssm": [], "gdn": [], "kda": [], "s6": []}
 
     def collect(block, stats):
         """A routed block's statistics into the aux dict's entries."""
@@ -2196,16 +2598,39 @@ def forward_hidden(
             fn = jax.checkpoint(
                 fn, static_argnums=(2,),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    *FLASH_SAVED_NAMES, *GDN_SAVED_NAMES, *KDA_SAVED_NAMES))
+                    *FLASH_SAVED_NAMES, *GDN_SAVED_NAMES, *KDA_SAVED_NAMES,
+                    *S6_SAVED_NAMES))
         return fn
 
     apply = applier()
-    # the window kind's layers say so to the block they share with the full
-    # kind's; a kind with a rotary table of its own is handed it, built
-    # here once for all its layers (none for a kind without rotary
-    # position: ``cfg.unrotated``)
-    apply_by_kind = {"window_attention": applier(
-        attn_kind="window_attention")} if cfg.window_layers else {}
+    appliers = {}
+
+    def apply_layer(i: int):
+        """The block as layer ``i`` applies it: what is static of it — its
+        attention kind where that is not the plain one (the kinds share a
+        block), what it keeps for later layers (``cfg.memory_layer``,
+        ``cfg.shared_kv_layer``), its ``lambda_init`` — bound once per
+        distinct set."""
+        kind, static = cfg.mixer_kind(i), {}
+        if kind in ATTENTION_KINDS and kind != "attention":
+            static["attn_kind"] = kind
+        if i == cfg.memory_layer:
+            static["keep"] = "memory"
+        elif i == cfg.shared_kv_layer:
+            static["keep"] = "shared_kv"
+        if cfg.diff_attention and kind in ATTENTION_KINDS:
+            static["lambda_init"] = cfg.diff_attention[i]
+        if not static:
+            return apply
+        key = tuple(sorted(static.items()))
+        if key not in appliers:
+            appliers[key] = applier(**static)
+        return appliers[key]
+
+    # a kind with a rotary table of its own is handed it, built here once
+    # for all its layers (none for a kind without rotary position:
+    # ``cfg.unrotated``)
+    carried = {}  # what crosses layers: "memory", "shared_kv"
     with jax.named_scope("rotary"):
         tables = {kind: {"rotary": _rotary_table(
             positions, rotary, cfg.rotary_dim)}
@@ -2214,8 +2639,13 @@ def forward_hidden(
     for _ in range(cfg.loop_passes):
         for i, layer in enumerate(params["layers"]):
             kind = cfg.mixer_kind(i)
-            x, stats = apply_by_kind.get(kind, apply)(
-                layer, x, cfg, positions, **tables.get(kind, {}))
+            handed = dict(tables.get(kind, {}))
+            if kind == "gmu":
+                handed["memory"] = carried["memory"]
+            elif kind == "cross_attention":
+                handed["shared_kv"] = carried["shared_kv"]
+            x, stats = apply_layer(i)(layer, x, cfg, positions, **handed)
+            carried.update(stats.pop("carried", {}))
             # Identity unless a remat policy references the name: lets
             # Strategy(remat="offload") park the inter-block residual
             # stream in host DRAM (reference
@@ -2230,7 +2660,7 @@ def forward_hidden(
                     decay_min[scope].append(stats[f"{scope}_decay_min"])
         z = x  # the last layer's output, what the prediction block reads
         with jax.named_scope("final_norm"):
-            x = rmsnorm(x, _gain(params["ln_f"], cfg), eps=cfg.rms_eps)
+            x = _norm(x, params["ln_f"], cfg)
         if cfg.loop_passes > 1:
             streams.append(x)
             with jax.named_scope("exit_gate"):
@@ -2255,6 +2685,8 @@ def forward_hidden(
             u = rmsnorm(u, mtp["ln_f"], eps=cfg.rms_eps)
         x = jnp.stack([x, u])
     out_aux = {"moe_aux": moe_aux}
+    if carried:
+        out_aux["carried"] = carried
     if streams:
         x = jnp.stack(streams)
         out_aux["exit_logits"] = jnp.stack(exit_logits)
@@ -2347,7 +2779,8 @@ def loss_fn(
     returns the scalar alone either way; one with state-space layers
     returns ``ssm_state_rms`` ``[mamba layers]`` and ``ssm_decay_min``, one
     with delta-rule layers ``gdn_state_rms`` and ``gdn_decay_min``, one
-    with "kda" layers ``kda_state_rms`` and ``kda_decay_min``.
+    with "kda" layers ``kda_state_rms`` and ``kda_decay_min``, one with
+    "mamba1" layers ``s6_state_rms`` and ``s6_decay_min``.
     The head is ``lm_head``, or ``embed`` transposed where
     ``cfg.tie_word_embeddings``, behind ``1 / cfg.logits_scaling``
     (:func:`head_operands`).
@@ -2464,7 +2897,8 @@ def loss_fn(
     if not metrics:
         return loss
     for name in ("ssm_state_rms", "ssm_decay_min", "gdn_state_rms",
-                 "gdn_decay_min", "kda_state_rms", "kda_decay_min"):
+                 "gdn_decay_min", "kda_state_rms", "kda_decay_min",
+                 "s6_state_rms", "s6_decay_min"):
         if name in aux:
             counters[name] = aux[name]
     if "moe_z" in aux:
@@ -2651,6 +3085,11 @@ TRAINING_PATH_ONLY = (
     ("mlp_form", "swiglu", "an MLP that is not SwiGLU"),
     ("rotary_by_kind", (),
      "a rotary table of a kind of layer's own, or a kind without position"),
+    ("memory_layer", None, "a scan output that later layers read"),
+    ("shared_kv_layer", None, "keys and values that later layers attend"),
+    ("diff_attention", (), "differential attention"),
+    ("norm_form", "rmsnorm", "a norm that is not RMSNorm"),
+    ("attn_bias", False, "biases on the attention projections"),
 )
 
 
@@ -2706,26 +3145,41 @@ def program_facts(cfg: LlamaConfig, seq_len: int) -> Dict:
     (``attn_window_pairs_per_sequence``, ``attn_full_pairs_per_sequence``),
     and one of whose attention kinds ONE carries no rotary position
     (``rotary_by_kind``) how many layers that is
-    (``unrotated_attention_layers``).  Empty for every other model."""
+    (``unrotated_attention_layers``).  A model with "mamba1", "gmu" or
+    "cross_attention" layers counts them (``s6_layers``, ``gmu_layers``,
+    ``cross_attention_layers``) and says what its blocks hand on, in bytes a
+    sequence (``memory_bytes_per_sequence``,
+    ``shared_kv_bytes_per_sequence``).  Empty for every other model."""
     facts = {f"{scope}_layers": cfg.layers_of(kind)
              for kind, scope in MIXER_KINDS.items()
              if kind not in ATTENTION_KINDS and cfg.layers_of(kind)}
     if cfg.window_layers:
-        # both kinds run under the ``attention`` scope: the count of the
+        # the kinds run under the ``attention`` scope: the count of the
         # window kind's layers beside that of all, and the (query, key)
         # pairs a layer of each kind attends in a sequence, by the scope
         # around its flash call
         facts["window_attention_layers"] = cfg.window_layers
         for kind, scope in ATTENTION_KINDS.items():
-            facts[f"{scope}_pairs_per_sequence"] = attended_pairs(
-                seq_len, cfg.window_of(kind))
+            if kind != "cross_attention" or cfg.cross_layers:
+                facts[f"{scope}_pairs_per_sequence"] = attended_pairs(
+                    seq_len, cfg.window_of(kind))
+    if cfg.cross_layers:
+        facts["cross_attention_layers"] = cfg.cross_layers
+    # what crosses layers, in bytes a sequence: the memory in ``cfg.dtype``,
+    # the shared keys and values (``n_kv_head`` heads of ``head_dim`` each)
+    size = jnp.dtype(cfg.dtype).itemsize
+    if cfg.memory_layer is not None:
+        facts["memory_bytes_per_sequence"] = seq_len * cfg.s6_d_inner * size
+    if cfg.shared_kv_layer is not None:
+        facts["shared_kv_bytes_per_sequence"] = (
+            2 * seq_len * cfg.n_kv_head * cfg.head_dim * size)
     if cfg.rope and cfg.unrotated_layers:
         facts["unrotated_attention_layers"] = cfg.unrotated_layers
     if facts:
         facts["attention_layers"] = cfg.attention_layers
     # the chunks a recurrent mixer's scan carries its state over
     for scope, chunk in (("ssm", cfg.mamba_chunk_size), ("gdn", GDN_CHUNK),
-                         ("kda", KDA_CHUNK)):
+                         ("kda", KDA_CHUNK), ("s6", S6_CHUNK)):
         if f"{scope}_layers" in facts:
             facts[f"{scope}_chunks_per_sequence"] = -(-seq_len // chunk)
     if cfg.one_branch:
@@ -2765,7 +3219,12 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     ``k k^T``, ``q k^T`` and the two products with ``T`` and the one with
     ``u`` over a chunk's ``Q`` positions, ``10 Q D``, and the three against
     the state, ``6 D^2``); a "kda" layer likewise, with its four
-    projections, two low-rank gates and three convolutions.  An MLP is three
+    projections, two low-rank gates and three convolutions; a "mamba1" layer
+    its four projections, its MLP, its taps and the recurrence's ``~9 d_inner
+    d_state`` a token; a "gmu" layer its two projections and its MLP; a
+    "cross_attention" layer no key and no value projection; differential
+    attention ``head_dim + 2 head_dim`` a query head and attended pair (a
+    pair's two value heads are joined).  An MLP is three
     matrices or, at ``mlp_form``
     "relu2", two.  Where ``cfg.one_branch`` a mixer layer counts no MLP, a
     "mlp" layer its MLP alone and a "moe" layer its router, its shared
@@ -2803,15 +3262,20 @@ def flops_per_token(cfg: LlamaConfig) -> float:
     if cfg.exit_gate_beta is not None:
         head += cfg.d_model
     dense = (cfg.block_applications * p_layer + cfg.loop_passes * head
-             + cfg.vocab_size * cfg.d_model)
+             + cfg.vocab_size * cfg.d_model
+             # a "cross_attention" layer projects no keys and no values
+             - cfg.cross_layers * 2 * cfg.d_model * cfg.n_kv_head
+             * cfg.head_dim)
     # a "window_attention" layer meets ``sliding_window`` keys a query, not
     # the sequence's all (one global window on every layer counts the whole
     # sequence, as it always has)
     keys = (cfg.block_applications * cfg.max_seq_len
             - cfg.window_layers * cfg.loop_passes
             * max(cfg.max_seq_len - cfg.sliding_window, 0))
-    # scores over a head's key dims, the output over its value dims
-    attn = keys * cfg.n_head * (cfg.head_dim + cfg.value_head_dim)
+    # scores over a head's key dims, the output over its value dims (under
+    # differential attention a pair's two joined: twice the head's)
+    attn = keys * cfg.n_head * (cfg.head_dim + (
+        2 * cfg.head_dim if cfg.diff_attention else cfg.value_head_dim))
     inner, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
     p_ssm = (cfg.d_model * (inner + conv + cfg.mamba_n_heads)  # in_proj
              + inner * cfg.d_model  # out_proj
@@ -2832,6 +3296,15 @@ def flops_per_token(cfg: LlamaConfig) -> float:
              + kh * kd * cfg.d_model + mlp)
     rule_kda = (kh * (10 * KDA_CHUNK * kd + 6 * kd * kd)
                 + 2 * cfg.kda_d_conv * 3 * kh * kd)
+    # a "mamba1" layer: ``in_proj`` (x and z), ``x_proj``, ``dt_proj`` and
+    # ``out_proj``, the recurrence (per channel and state: the decay's
+    # product and ``exp``, the update's three and the read's two, ~9) and
+    # the taps; a "gmu" layer its two projections
+    si, sn = cfg.s6_d_inner, cfg.s6_d_state
+    p_s6 = (cfg.d_model * 2 * si + si * (cfg.s6_dt_rank + 2 * sn)
+            + cfg.s6_dt_rank * si + si * cfg.d_model + mlp)
+    scan_s6 = 9 * si * sn + 2 * cfg.s6_d_conv * si
+    p_gmu = 2 * cfg.d_model * si + mlp
     alone = 0.0  # the layers whose one branch is an MLP
     if cfg.one_branch:
         expert = mats * cfg.d_model * cfg.expert_width
@@ -2842,7 +3315,9 @@ def flops_per_token(cfg: LlamaConfig) -> float:
         alone = (cfg.layer_types.count("mlp") * mats * cfg.d_model * cfg.d_ff
                  + cfg.moe_layers * routed)
     return (6.0 * (dense + cfg.ssm_layers * p_ssm + cfg.conv_layers * p_conv
-                   + cfg.gdn_layers * p_gdn + cfg.kda_layers * p_kda + alone)
+                   + cfg.gdn_layers * p_gdn + cfg.kda_layers * p_kda
+                   + cfg.s6_layers * p_s6 + cfg.gmu_layers * p_gmu + alone)
             + 6.0 * attn
             + 3.0 * (cfg.ssm_layers * scan + cfg.conv_layers * taps
-                     + cfg.gdn_layers * rule + cfg.kda_layers * rule_kda))
+                     + cfg.gdn_layers * rule + cfg.kda_layers * rule_kda
+                     + cfg.s6_layers * scan_s6))

@@ -257,7 +257,15 @@ def _kv_row_map(H: int, KV: int):
 _DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
 
 
-def _vmem_params(resident_bytes: int) -> dict:
+#: what the flash kernels' blocks and temporaries take beside the resident
+#: operands: at S 16,384 with 64-wide q and k under 128-wide v the operands
+#: hold 12 MiB and the forward's blocks 4.54 more (the compiler's count),
+#: which a room of 4 let through at the default limit.  No other shape that
+#: runs holds between 11 and 12 MiB, so none takes another limit than before.
+_FLASH_ROOM = 5 * 2 ** 20
+
+
+def _vmem_params(resident_bytes: int, room: int = 4 * 2 ** 20) -> dict:
     """``pallas_call`` keywords for a kernel whose resident operands (K
     and V forward and for dq, ``[1, S, D]`` each; Q and dO for dkv, the
     rows a grid step holds; double-buffered) hold ``resident_bytes`` at
@@ -265,8 +273,10 @@ def _vmem_params(resident_bytes: int) -> dict:
     default limit nothing is passed and the kernel compiles as it always
     has (S 8,192 at D 128: 8 MiB); past it (S 8,192 at D 256: 16 MiB
     before any block) the limit is raised to what the kernel holds plus
-    room for its blocks and temporaries."""
-    if resident_bytes + 4 * 2 ** 20 <= _DEFAULT_SCOPED_VMEM:
+    room for its blocks and temporaries.  ``room`` is what the caller
+    expects those to take: 4 MiB, and :data:`_FLASH_ROOM` for the flash
+    kernels."""
+    if resident_bytes + room <= _DEFAULT_SCOPED_VMEM:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
@@ -325,7 +335,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
         ],
         interpret=interpret,
         name="flash_fwd",
-        **_vmem_params(2 * S_pad * (D + Dv) * k.dtype.itemsize),
+        **_vmem_params(2 * S_pad * (D + Dv) * k.dtype.itemsize, _FLASH_ROOM),
     )(*inputs)
     return (
         out.reshape(B, H, S_pad, Dv)[:, :, :S],
@@ -623,7 +633,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((B * H, S_pad, D), q.dtype),
         interpret=interpret,
         name="flash_bwd_dq",
-        **_vmem_params(2 * S_pad * (D + Dv) * k.dtype.itemsize),
+        **_vmem_params(2 * S_pad * (D + Dv) * k.dtype.itemsize, _FLASH_ROOM),
     )(*common)
 
     # dkv: grid (B*KV, k_blocks, rep) — the innermost axis streams the
@@ -685,7 +695,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
-        **_vmem_params(2 * rows * (D + Dv) * q.dtype.itemsize),
+        **_vmem_params(2 * rows * (D + Dv) * q.dtype.itemsize, _FLASH_ROOM),
     )(*dkv_in)
 
     return (

@@ -594,12 +594,15 @@ def test_defaults_are_todays_and_name_no_delta_rule_layer():
                                         False)
     assert (cfg.gdn_layers, cfg.head_dim, cfg.rotary_dim) == (0, 128, 128)
     # 56 before this mixer, its nine, the one-branch layers' two, the
-    # rotary table a kind of attention layer may have of its own and the
-    # per-channel rule's three
-    assert len(dataclasses.fields(llama.LlamaConfig)) == 56 + 9 + 2 + 1 + 3
+    # rotary table a kind of attention layer may have of its own, the
+    # per-channel rule's three, the Mamba-1 mixer's four, the two layers
+    # that make what crosses layers, differential attention, the norm's
+    # form and the attention biases
+    assert len(dataclasses.fields(llama.LlamaConfig)) == (
+        56 + 9 + 2 + 1 + 3 + 4 + 2 + 1 + 2)
     assert tuple(llama.MIXER_KINDS) == (
         "attention", "mamba", "conv", "linear_attention",
-        "window_attention", "kda")
+        "window_attention", "kda", "mamba1", "gmu", "cross_attention")
     assert llama.program_facts(cfg, 4096) == {}
     assert llama.program_facts(_next(), 4096) == {
         "gdn_layers": 3, "attention_layers": 1,

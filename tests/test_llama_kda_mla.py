@@ -404,7 +404,7 @@ def test_plain_latent_attention_is_refused_as_it_was():
     assert str(e.value).endswith(
         "rope=True: latent attention, training path only "
         "(llama.forward_hidden / loss_fn)")
-    assert len(llama.TRAINING_PATH_ONLY) == 21
+    assert len(llama.TRAINING_PATH_ONLY) == 26
     assert "kda" in llama.MIXER_KINDS and llama.MIXER_KINDS["kda"] == "kda"
 
 

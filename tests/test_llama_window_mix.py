@@ -457,8 +457,12 @@ def test_the_refusal_names_the_setting_and_the_path(where, path, setting):
 
 def test_the_table_of_refusals_gained_the_rotary_row():
     names = [row[0] for row in llama.TRAINING_PATH_ONLY]
-    assert names[-1] == "rotary_by_kind"
-    assert len(names) == len(set(names)) == 21
+    assert names[20] == "rotary_by_kind"
+    # and, since, five rows for what crosses layers, differential
+    # attention, the norm's form and the attention biases
+    assert names[21:] == ["memory_layer", "shared_kv_layer",
+                          "diff_attention", "norm_form", "attn_bias"]
+    assert len(names) == len(set(names)) == 26
     for name, computed, _ in llama.TRAINING_PATH_ONLY:
         if name != "layer_types":
             assert getattr(llama.LlamaConfig(), name) == computed, name
