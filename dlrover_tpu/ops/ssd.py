@@ -30,16 +30,19 @@ group, block of heads) forms ``C B^T`` once for the group, and each head's
 mask and the product rounded to ``x``'s dtype in VMEM, so no ``[Q, Q]``
 array is written to or read from HBM in any phase; the backward recomputes
 them from the same inputs, which are its only residuals.  ``x`` comes in,
-and ``y`` goes out, with the positions on the last dim, as XLA lays the
-mixer's arrays out around the scan; a block is turned round in VMEM.  The
-rule (:func:`_kernel_heads`): ``jax.default_backend() == "tpu"``, ``chunk``
-and ``N`` multiples of 128, and a block of a group's heads whose ``r * P``
-is a multiple of 128 lanes (at most :data:`_BLOCK_LANES`; ``P`` a divisor or
-a multiple of 128).  Any other shape or backend runs :func:`_chunk_outputs`,
-the same arithmetic in plain ``jax.numpy`` (:func:`_intra_chunk` is its
-``[Q, Q]`` part), differentiated by JAX and the kernels' reference.  The
-cumulative sums, the states the chunks leave and the scan over the chunks
-are ``jax.numpy`` everywhere.
+and ``y`` and ``dx`` go out, channels-last — ``[B, S, G R P]``, the array as
+:func:`ssd_chunked` holds it, reshaped — as the convolution's kernel in
+front of the scan writes it and the gated norm behind it reads it
+(``ops/conv_silu.py``, ``ops/gated_norm.py``): no copy stands between the
+three, and a block arrives as the ``[Q, lanes]`` tile the kernels compute
+on.  The rule (:func:`_kernel_heads`): ``jax.default_backend() == "tpu"``,
+``chunk`` and ``N`` multiples of 128, and a block of a group's heads whose
+``r * P`` is a multiple of 128 lanes (at most :data:`_BLOCK_LANES`; ``P`` a
+divisor or a multiple of 128).  Any other shape or backend runs
+:func:`_chunk_outputs`, the same arithmetic in plain ``jax.numpy``
+(:func:`_intra_chunk` is its ``[Q, Q]`` part), differentiated by JAX and the
+kernels' reference.  The cumulative sums, the states the chunks leave and
+the scan over the chunks are ``jax.numpy`` everywhere.
 """
 
 from __future__ import annotations
@@ -107,10 +110,10 @@ def _chunk_outputs(x, dt, cs, bc, cc, entering, D):
 #
 # Layouts, per grid step (b, chunk, g, block j of the group's heads; hb heads
 # a block, W = hb * P lanes).  In HBM ``x``, ``y``, ``dy`` and ``dx`` are ``[B,
-# G R P, c Q]``, positions last (:func:`_lanes_first`); a block ``[W, Q]`` is
-# turned round in VMEM, and from there on the kernels work on ``[Q, W]``,
-# heads side by side along the lanes.  What is one number a head and position
-# comes in ready for its broadcast, so none of it is transposed in the kernel:
+# c Q, G R P]``, channels last: a block is ``[Q, W]`` at ``(b, chunk, g J +
+# j)``, heads side by side along the lanes, a DMA row W lanes long, and the
+# kernels work on it as it comes.  What is one number a head and position
+# comes in ready for its broadcast, so nothing is transposed in the kernel:
 # the cumulative sums twice, ``[hb, Q]`` (a head's row broadcasts down the
 # sublanes) and ``[Q, hb]`` (its column broadcasts along the lanes), ``dt``
 # ``[Q, hb]``.  ``B``, ``C`` [Q, N]; the entering state ``[W, N]``; ``D``
@@ -194,7 +197,7 @@ def _fwd_kernel(x_ref, dt_ref, row_ref, col_ref, b_ref, c_ref, ent_ref,
     grown = jnp.exp(col_ref[...])  # [Q, hb]
     for u in range(units):
         lanes = slice(u * width, (u + 1) * width)
-        x32 = x_ref[lanes, :].astype(jnp.float32).T
+        x32 = x_ref[:, lanes].astype(jnp.float32)
         xu = (x32 * _spread(dt, u * per, per, width, P)).astype(x_ref.dtype)
         y = None
         for k in range(per):
@@ -203,9 +206,9 @@ def _fwd_kernel(x_ref, dt_ref, row_ref, col_ref, b_ref, c_ref, ent_ref,
             yk = jnp.dot(m, xu, preferred_element_type=jnp.float32)
             y = yk if y is None else jnp.where(
                 _head_lanes(width, P, k), yk, y)
-        y_ref[lanes, :] = (
+        y_ref[:, lanes] = (
             y + from_state[:, lanes] * _spread(grown, u * per, per, width, P)
-            + x32 * d_ref[:, lanes]).T
+            + x32 * d_ref[:, lanes])
 
 
 def _bwd_kernel(x_ref, dt_ref, row_ref, col_ref, b_ref, c_ref, ent_ref, d_ref,
@@ -242,10 +245,10 @@ def _bwd_kernel(x_ref, dt_ref, row_ref, col_ref, b_ref, c_ref, ent_ref, d_ref,
     grown = jnp.exp(col_ref[...])
     for u in range(units):
         lanes = slice(u * width, (u + 1) * width)
-        x32 = x_ref[lanes, :].astype(f32).T
+        x32 = x_ref[:, lanes].astype(f32)
         dtu = _spread(dt, u * per, per, width, P)
         xu = (x32 * dtu).astype(x_ref.dtype)
-        dy32 = dy_ref[lanes, :].T
+        dy32 = dy_ref[:, lanes]
         # rounded once, for the matmuls AND the sums below: row and column
         # sums of the same products must cancel over a chunk
         dyu = dy32.astype(xu.dtype)
@@ -265,7 +268,7 @@ def _bwd_kernel(x_ref, dt_ref, row_ref, col_ref, b_ref, c_ref, ent_ref, d_ref,
         dcs_acc[:, lanes] = (dyu.astype(f32) * y - xu.astype(f32) * dxdt
                              + g * from_state[:, lanes])
         ddt_acc[:, lanes] = dxdt * x32
-        dx_ref[lanes, :] = (dxdt * dtu + dy32 * d_ref[:, lanes]).T.astype(
+        dx_ref[:, lanes] = (dxdt * dtu + dy32 * d_ref[:, lanes]).astype(
             dx_ref.dtype)
         dd_ref[:, lanes] = jnp.sum(dy32 * x32, axis=0, keepdims=True)
     over_lanes = functools.partial(
@@ -295,7 +298,7 @@ def _kernel_operands(x, dt, cs, bc, cc, entering, D, hb):
         0, 1, 3, 4, 2, 5)
     by_group = lambda a: a.transpose(0, 1, 3, 2, 4)  # noqa: E731
     arrays = dict(
-        x=_lanes_first(x), dt=cols(dt), col=cols(cs),
+        x=x.reshape(B, c * Q, G * R * P), dt=cols(dt), col=cols(cs),
         row=cs.reshape(B, c, Q, G, J, hb).transpose(0, 1, 3, 4, 5, 2),
         b=by_group(bc), c=by_group(cc),
         ent=entering.reshape(c, B, G, J, W, N),
@@ -305,27 +308,13 @@ def _kernel_operands(x, dt, cs, bc, cc, entering, D, hb):
     group = pl.BlockSpec((None, None, None, Q, N),
                          lambda b, i, g, j: (b, i, g, 0, 0))
     specs = dict(
-        x=pl.BlockSpec((None, W, Q), lambda b, i, g, j: (b, g * J + j, i)),
+        x=pl.BlockSpec((None, Q, W), lambda b, i, g, j: (b, i, g * J + j)),
         dt=col, col=col, b=group, c=group,
         row=pl.BlockSpec((None, None, None, None, hb, Q), a_block),
         ent=pl.BlockSpec((None, None, None, None, W, N),
                          lambda b, i, g, j: (i, b, g, j, 0, 0)),
         d=pl.BlockSpec((None, None, 1, W), lambda b, i, g, j: (g, j, 0, 0)))
     return arrays, specs, (B, c, G, J)
-
-
-def _lanes_first(a):
-    """``[B, c, Q, G, R, P] -> [B, G R P, c Q]``: positions last, as XLA
-    lays the mixer's arrays out (its projections put the sequence on the
-    lanes), so that no copy stands between them and the kernels, which
-    turn a block round in VMEM."""
-    B, c, Q = a.shape[:3]
-    return a.reshape(B, c * Q, -1).transpose(0, 2, 1)
-
-
-def _positions_first(a, shape):
-    """:func:`_lanes_first` undone."""
-    return a.transpose(0, 2, 1).reshape(shape)
 
 
 _INPUTS = ("x", "dt", "row", "col", "b", "c", "ent", "d")
@@ -363,7 +352,7 @@ def _chunk_fwd(x, dt, cs, bc, cc, entering, D, hb, interpret):
         **_call_params(2 * (Q * W * (size + 4) + (2 * Q + W) * N * size)
                        + Q * W * 4 + 5 * Q * Q * 4),
     )(*(arrays[n] for n in _INPUTS))
-    return _positions_first(y, x.shape)
+    return y.reshape(x.shape)
 
 
 def _chunk_bwd(x, dt, cs, bc, cc, entering, D, dy, hb, interpret):
@@ -374,7 +363,7 @@ def _chunk_bwd(x, dt, cs, bc, cc, entering, D, dy, hb, interpret):
     N, J, W, size = bc.shape[-1], R // hb, hb * P, x.dtype.itemsize
     f32 = jnp.float32
     arrays, specs, grid = _kernel_operands(x, dt, cs, bc, cc, entering, D, hb)
-    arrays["dy"] = _lanes_first(dy.astype(f32))
+    arrays["dy"] = dy.astype(f32).reshape(arrays["x"].shape)
     specs["dy"] = specs["x"]
     # seg[l, h] = 1 where lane l is head h's, 128 columns for the MXU
     arrays["seg"] = (jnp.arange(W)[:, None] // P
@@ -406,7 +395,7 @@ def _chunk_bwd(x, dt, cs, bc, cc, entering, D, dy, hb, interpret):
     rows = lambda a: a.transpose(0, 1, 4, 2, 3, 5).reshape(  # noqa: E731
         cs.shape)
     by_group = lambda a: a.transpose(0, 1, 3, 2, 4)  # noqa: E731
-    return (_positions_first(dx, x.shape), rows(ddt), rows(dcs), by_group(db),
+    return (dx.reshape(x.shape), rows(ddt), rows(dcs), by_group(db),
             by_group(dc), dent.reshape(entering.shape),
             dd.reshape(B, c, G, R, P).sum((0, 1, 4)).astype(D.dtype))
 
@@ -427,15 +416,22 @@ def _kernels_bwd(hb, interpret, res, dy):
 _chunk_outputs_kernels.defvjp(_kernels_fwd, _kernels_bwd)
 
 
+def _block_heads(backend: Optional[str], Q: int, R: int, P: int,
+                 N: int) -> int:
+    """:func:`_kernel_heads` where the kernels run (``backend`` None: by
+    the device), else 0."""
+    if backend is None:
+        backend = "pallas" if jax.default_backend() == "tpu" else "reference"
+    return _kernel_heads(Q, R, P, N) if backend == "pallas" else 0
+
+
 def chunk_outputs(x, dt, cs, bc, cc, entering, D, *,
                   backend: Optional[str] = None, interpret: bool = False):
     """:func:`_chunk_outputs` by the kernel pair where the module's rule
     allows, one call per shard of the mesh in scope (the batch dim split,
     as ``ops/rmsnorm.py``), else in ``jax.numpy``."""
-    if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "reference"
     _, _, Q, _, R, P = x.shape
-    hb = _kernel_heads(Q, R, P, bc.shape[-1]) if backend == "pallas" else 0
+    hb = _block_heads(backend, Q, R, P, bc.shape[-1])
     if not hb:
         return _chunk_outputs(x, dt, cs, bc, cc, entering, D)
     free, batch_axes, _ = shard_axes(x.shape[0])
@@ -474,9 +470,20 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, D=None, *,
     # -- the state each chunk leaves, and the scan over the chunks -----------
     total = cs[:, :, -1]  # [B, c, G, R]: the chunk's whole sum of dt A
     to_end = jnp.exp(total[:, :, None] - cs)  # decay from j to the end
+    xs = xc
+    if _block_heads(backend, Q, R, P, N):
+        # The kernels pin ``x`` channels-last, and XLA wants the positions
+        # minor in this einsum's operand, so it turns ``x`` for it.  Left
+        # alone it turns ``x``'s float32 conversion (twice the bytes) and,
+        # going back, turns the kernels' ``dx`` to add it in the einsum's
+        # layout and turns the sum back.  Behind the two barriers it turns
+        # the bfloat16 ``x`` once, and the two cotangents meet as ``[B, S, H
+        # P]``, where the kernels' lies as it is.
+        xs = jax.lax.optimization_barrier(jax.lax.optimization_barrier(
+            x.reshape(Bsz, S + pad, H * P)).reshape(xc.shape))
     left = jnp.einsum(
         "bcjgrp,bcjgn->bcgrpn",
-        (xc.astype(f32) * dtc[..., None] * to_end[..., None]).astype(x.dtype),
+        (xs.astype(f32) * dtc[..., None] * to_end[..., None]).astype(x.dtype),
         bc,
         preferred_element_type=f32)
     chunk_decay = jnp.exp(total)

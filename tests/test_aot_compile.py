@@ -756,6 +756,59 @@ def test_hybrid_step_keeps_the_convolutions_float32_in_vmem(hybrid_step):
                                           cfg.mamba_conv_dim)
 
 
+def _mixer_keeps_the_channels_minor(text, batch, seq_len, width, layers):
+    """No instruction under ``ssm_scan``, ``ssm_gate`` or ``ssm_conv`` that
+    reads or writes HBM — the top level's, not a fusion's inner ones — has
+    a result or an operand of ``batch`` whole sequences by the mixer's width
+    with the SEQUENCE minor, ``[batch, width, seq_len]`` or ``[batch,
+    seq_len, width]`` in another layout than ``{2,1,0}``: the scan's kernels
+    take ``x`` and hand ``y`` and ``dx`` over as the convolution's and the
+    gated norm's lay them out, and nothing between the three is a
+    transposing copy.  ONE turn a pass is left, and is XLA's own: the chunk
+    states' einsum contracts the positions, XLA wants them minor in its
+    operand, and it turns the BFLOAT16 ``x`` for that behind
+    ``ssd_chunked``'s barriers — forward, recomputed, and the cotangent's
+    way back (the parent's einsum read the turn the kernels needed; S13
+    (ii), the chunk states as a kernel, takes it).  Never a float32 one."""
+    turned = re.compile(
+        rf"\[{batch},{width},{seq_len}\]"
+        rf"|\[{batch},{seq_len},{width}\]\{{(?!2,1,0[:}}])")
+    seen, fused, for_the_states = 0, False, 0
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            fused = "fused_computation" in line.split()[0]
+        if fused or " = " not in line or not any(
+                scope in line for scope in ("ssm_scan", "ssm_gate",
+                                            "ssm_conv")):
+            continue
+        seen += 1
+        shapes, meta = line.split(" = ", 1)[1].split("metadata=", 1)
+        if not turned.search(shapes):
+            continue
+        assert shapes.startswith(
+            f"bf16[{batch},{seq_len},{width}]{{1,2,0") and re.search(
+                r"/ssm_scan/(optimization_barrier|reshape)\"", meta), \
+            line[:300]
+        for_the_states += 1
+    assert seen > 100  # the scopes' instructions were there to be read
+    assert for_the_states <= 3 * layers
+
+
+def test_hybrid_step_keeps_the_mixers_channels_minor(hybrid_step):
+    """The scan's kernels read ``x`` and write ``y``, ``dx`` as ``[B, S, G R
+    P]``, channels on the lanes (PR 69): the parent's text held thirteen
+    turned instructions a state-space layer, a ``copy
+    f32[2,4096,8192]{1,2,0}`` behind every ``ssd_chunk_fwd`` among them.  The
+    kernels' counts stand."""
+    job, text, cfg = hybrid_step
+    kernels, layers = job.program["kernels"], job.program["ssm_layers"]
+    assert (kernels["ssd_chunk_fwd"], kernels["ssd_chunk_bwd"],
+            kernels["conv_silu_fwd"], kernels["conv_silu_bwd"]) == (
+                2 * layers, layers, 2 * layers, layers)
+    _mixer_keeps_the_channels_minor(
+        text, 2, 8192, cfg.mamba_n_heads * cfg.mamba_d_head, layers)
+
+
 def _step_and_text(topo, loss, cfg, sequences, seq_len):
     """``(job, compiled text)`` of ``loss``'s training step on one described
     chip, from shapes."""

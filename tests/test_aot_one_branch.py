@@ -12,7 +12,8 @@ results or times.
 
 import pytest
 from test_aot_compile import (  # noqa: F401
-    _sized_branch_holds_no_pick_sized_array, _step_and_text, topo)
+    _mixer_keeps_the_channels_minor, _sized_branch_holds_no_pick_sized_array,
+    _step_and_text, topo)
 
 #: ``bytes_limit`` of one v5e chip as ``memory_stats()`` reported it (PR 21)
 V5E_BYTES_LIMIT = 16_909_336_064
@@ -117,6 +118,22 @@ def test_the_cell_at_three_runs_the_layers_by_kind(step_at_three):
     # to XLA
     assert "backward" in by_inner["ssm_gate"]
     assert {"forward", "recompute"} <= by_inner["gated_norm_fwd"]
+
+
+def test_the_cell_at_three_keeps_the_mixers_channels_minor(step_at_three):
+    """In eight groups as in one: the scan's kernels take ``x`` and hand
+    ``y`` and ``dx`` over channels-last (PR 69), as ``conv_silu_*`` writes
+    and ``gated_norm_*`` reads, and no ``[3, 4096, 8192]`` stands between
+    them (the parent's text held 48 turned instructions); the kernels'
+    counts as they were."""
+    job, text, cfg = step_at_three
+    kernels = job.program["kernels"]
+    assert (kernels["ssd_chunk_fwd"], kernels["ssd_chunk_bwd"],
+            kernels["gated_norm_fwd"], kernels["gated_norm_bwd"]) == (
+                8, 4, 8, 4)
+    _mixer_keeps_the_channels_minor(
+        text, 3, 8192, cfg.mamba_n_heads * cfg.mamba_d_head,
+        job.program["ssm_layers"])
 
 
 def test_the_cell_at_four_sequences_leaves_under_a_twentieth(topo):  # noqa: F811
